@@ -580,9 +580,28 @@ def resolve_inflate_backend(config: "HBamConfig | None") -> str:
 
 
 def _probe_auto_plane() -> str:
+    import logging
+
+    from hadoop_bam_tpu.utils.metrics import METRICS
     try:
         from hadoop_bam_tpu.ops.inflate_device import probe_device_plane
         probe = probe_device_plane()
+        _PLANE_CACHE["probe"] = probe
         return "device" if probe.get("device_wins") else "native"
-    except Exception:  # noqa: BLE001 — selection must never fail a run
+    except Exception as e:  # noqa: BLE001 — selection must never fail a run
+        # ...but a probe that raised (a device-plane compile failure on
+        # the chip, say) must not vanish: logged, counted, and kept for
+        # `hbam explain` / chip_smoke.py to show
+        logging.getLogger(__name__).exception(
+            "device decode plane probe failed; resolving 'auto' to the "
+            "native plane")
+        METRICS.count("pipeline.plane_probe_failed")
+        _PLANE_CACHE["probe"] = {"error": f"{type(e).__name__}: {e}"}
         return "native"
+
+
+def plane_probe_report() -> "dict | None":
+    """What the once-per-process "auto" probe measured (its timings and
+    decision, or {"error": ...} when it raised); None before any "auto"
+    resolution."""
+    return _PLANE_CACHE.get("probe")

@@ -273,19 +273,39 @@ class BamBatch:
     @property
     def tlen(self): return self._col("tlen", 32, 4, True)
 
-    # Derived payload offset columns
+    # Derived payload offset columns — cached like the fixed fields: the
+    # per-record accessors below index them once per record, and
+    # rebuilding a whole-batch vector per access made every per-record
+    # loop (the host sort / markdup oracles) quadratic in the batch size
+    def _derived(self, name: str, build) -> np.ndarray:
+        if name not in self._cache:
+            self._cache[name] = build()
+        return self._cache[name]
+
     @property
-    def name_offset(self): return self.offsets + FIXED_RECORD_PREFIX
+    def name_offset(self):
+        return self._derived(
+            "name_offset", lambda: self.offsets + FIXED_RECORD_PREFIX)
     @property
-    def cigar_offset(self): return self.name_offset + self.l_read_name
+    def cigar_offset(self):
+        return self._derived(
+            "cigar_offset", lambda: self.name_offset + self.l_read_name)
     @property
-    def seq_offset(self): return self.cigar_offset + 4 * self.n_cigar
+    def seq_offset(self):
+        return self._derived(
+            "seq_offset", lambda: self.cigar_offset + 4 * self.n_cigar)
     @property
-    def qual_offset(self): return self.seq_offset + (self.l_seq + 1) // 2
+    def qual_offset(self):
+        return self._derived(
+            "qual_offset", lambda: self.seq_offset + (self.l_seq + 1) // 2)
     @property
-    def tags_offset(self): return self.qual_offset + self.l_seq
+    def tags_offset(self):
+        return self._derived(
+            "tags_offset", lambda: self.qual_offset + self.l_seq)
     @property
-    def record_end(self): return self.offsets + 4 + self.block_size
+    def record_end(self):
+        return self._derived(
+            "record_end", lambda: self.offsets + 4 + self.block_size)
 
     def reference_span(self) -> np.ndarray:
         """Per-record alignment span on the reference (bases consumed by

@@ -9,9 +9,8 @@ Paths, in preference order:
   span level).
 - ``device``: two-stage device DEFLATE (ops/inflate_device.py) — host
   Huffman tokenize (native, threaded) + on-device LZ77 copy resolution by
-  pointer doubling.  Measured, not default: the Huffman stage dominates
-  inflate cost and is bit-serial, so the host stage bounds throughput; see
-  BASELINE.md "Device DEFLATE" for the numbers.
+  pointer doubling.  Not the default: the Huffman stage is bit-serial, so
+  the host stage bounds throughput; PERF.md holds the measured numbers.
 
 All paths share one contract: given the raw compressed span bytes and the
 parsed block table, produce a contiguous inflated buffer + per-block inflated

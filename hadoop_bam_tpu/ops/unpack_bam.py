@@ -6,22 +6,16 @@ Input is the inflated span bytes (uint8, padded to a static capacity) and the
 record start offsets (int32, padded); output is one int32 column per fixed
 field [SPEC record layout, formats/bam.py docstring].
 
-Two implementations with identical semantics:
-
-- ``unpack_fixed_fields``: pure jnp.  The single gather
-  ``data[offsets[:, None] + arange(36)]`` pulls each record's fixed 36-byte
-  prefix into an [N, 36] tile; field extraction is then fused elementwise
-  arithmetic.  XLA lowers this well on TPU and it is the default.
-- ``unpack_fixed_fields_pallas``: Pallas kernel tiling the offset vector, with
-  the span bytes resident in VMEM; useful when fusing unpack with downstream
-  per-record compute in one kernel.
+``unpack_fixed_fields`` is pure jnp: the single gather
+``data[offsets[:, None] + arange(36)]`` pulls each record's fixed 36-byte
+prefix into an [N, 36] tile; field extraction is then fused elementwise
+arithmetic.
 
 Padding convention: offsets[i] for i >= n_records MUST point at valid bytes
 (use 0); consumers mask with ``valid = arange(N) < n_records``.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
@@ -108,46 +102,6 @@ def unpack_fixed_fields(data: jnp.ndarray, offsets: jnp.ndarray
     idx = offsets[:, None] + jnp.arange(PREFIX, dtype=offsets.dtype)[None, :]
     tile = data[idx]  # [N, 36] uint8 gather
     return unpack_fixed_fields_tile(tile)
-
-
-def unpack_fixed_fields_pallas(data: jnp.ndarray, offsets: jnp.ndarray,
-                               block_n: int = 1024) -> Dict[str, jnp.ndarray]:
-    """Pallas variant: grid over offset tiles; span bytes stay in ANY/HBM and
-    each tile gathers through dynamic indexing.
-
-    Note: on TPU, arbitrary-offset gathers inside a kernel serialize through
-    scalar loads, so this variant mainly exists as the fusion point for
-    later kernels (unpack + filter + reduce in one pass); the jnp gather above
-    is the throughput path today."""
-    from jax.experimental import pallas as pl
-
-    n = offsets.shape[0]
-    assert n % block_n == 0, "pad offsets to a multiple of block_n"
-
-    def kernel(data_ref, offs_ref, *out_refs):
-        offs = offs_ref[:]  # [block_n]
-        idx = offs[:, None] + jax.lax.broadcasted_iota(
-            jnp.int32, (block_n, PREFIX), 1)
-        tile = data_ref[idx]
-        cols = unpack_fixed_fields_tile(tile)
-        for ref, name in zip(out_refs, FIXED_FIELDS):
-            ref[:] = cols[name]
-
-    out_shapes = tuple(jax.ShapeDtypeStruct((n,), jnp.int32)
-                       for _ in FIXED_FIELDS)
-    outs = pl.pallas_call(
-        kernel,
-        grid=(n // block_n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_specs=tuple(pl.BlockSpec((block_n,), lambda i: (i,))
-                        for _ in FIXED_FIELDS),
-        out_shape=out_shapes,
-        interpret=jax.default_backend() == "cpu",
-    )(data, offsets)
-    return dict(zip(FIXED_FIELDS, outs))
 
 
 @jax.jit

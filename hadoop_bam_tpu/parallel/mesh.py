@@ -12,23 +12,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — every step builder imports it here
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# shard_map moved twice across jax releases (jax.experimental.shard_map ->
-# jax.shard_map) and its replication-check kwarg was renamed (check_rep ->
-# check_vma).  One shim here so every step builder works on any of them.
-try:
-    from jax import shard_map as _shard_map_impl          # jax >= 0.6
-    _CHECK_KW = "check_vma"
-except ImportError:                                       # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    kwargs = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
 
 
 def make_mesh(shape: Optional[Tuple[int, ...]] = None,

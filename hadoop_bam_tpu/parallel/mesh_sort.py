@@ -61,6 +61,7 @@ import numpy as np
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.formats.bam import SAMHeader
 from hadoop_bam_tpu.utils.errors import PlanError
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 _I32_SENTINEL = np.int32(2**31 - 1)
 GLOBAL_INDEX_CEILING = 2**31 - 2     # int32 global record indices
@@ -342,6 +343,8 @@ def _agree_round_geometry(counts_vec: np.ndarray, max_len: int,
         # coarsen single-host bucket boundaries
         if err is not None:
             raise err
+        METRICS.count("mesh_sort.rounds")
+        METRICS.count_per_device("mesh_sort.device_rows", counts_vec)
         return (counts_vec, max_len,
                 list(his) if want_sample else None,
                 list(los) if want_sample else None)
@@ -368,6 +371,8 @@ def _agree_round_geometry(counts_vec: np.ndarray, max_len: int,
     if int(g_meta[:, n_dev + 2].max()) > 0:
         raise RuntimeError("mesh sort: decode failed on another host")
     counts_out = g_meta[:, :n_dev].sum(axis=0)
+    METRICS.count("mesh_sort.rounds")
+    METRICS.count_per_device("mesh_sort.device_rows", counts_out)
     max_out = int(g_meta[:, n_dev].max())
     shis = slos = None
     if want_sample:
@@ -547,7 +552,6 @@ def _sort_bam_mesh_bytes_spill_impl(input_path: str, output_path: str, *,
     )
     from hadoop_bam_tpu.parallel.pipeline import _decode_span_core
     from hadoop_bam_tpu.split.planners import plan_bam_spans_balanced
-    from hadoop_bam_tpu.utils.metrics import METRICS
     from hadoop_bam_tpu.utils.sort import _sorted_header
 
     mesh_devs = list(mesh.devices.ravel())
@@ -1282,6 +1286,8 @@ def _sort_bam_mesh_index(input_path: str, output_path: str, *, mesh,
         his.append(h)
         los.append(l)
     counts = [o.size for _, o in raw]
+    METRICS.count("mesh_sort.rounds")
+    METRICS.count_per_device("mesh_sort.device_rows", counts)
     total = int(sum(counts))
     base = np.zeros(n_dev, dtype=np.int32)
     if counts:

@@ -323,6 +323,8 @@ def _markdup_bam_mesh_impl(input_path: str, output_path: str, *, mesh,
                 bhi_g = replicated(bhi, jnp.uint32)
                 blo_g = replicated(blo, jnp.uint32)
 
+            METRICS.count("mesh_sort.rounds")
+            METRICS.count_per_device("mesh_sort.device_rows", counts_vec)
             round_total = int(counts_vec.sum())
             check_global_index_ceiling(prefix_total + round_total,
                                        "fused markdup (mid-run backstop)")

@@ -91,6 +91,13 @@ class Metrics:
         with self._lock:
             self.counters[name] += n
 
+    def count_per_device(self, name: str, counts) -> None:
+        """``name.<d>`` += counts[d] for every mesh position d: what a
+        mesh phase handed each device, so a position that was fed
+        nothing shows up as a zero instead of hiding in a total."""
+        for d, c in enumerate(counts):
+            self.count(f"{name}.{d}", int(c))
+
     def get(self, name: str) -> int:
         """Read one counter without mutating the defaultdict (a bare
         ``counters[name]`` probe would materialize a zero entry)."""

@@ -3,17 +3,30 @@
 The reference reached native code through java.util.zip's JNI; we compile
 native/hbam_native.cpp on first use with g++ and bind via ctypes (no pybind11
 in this image).  Every caller must tolerate ``load() is None`` — the NumPy /
-zlib-module fallbacks keep the framework fully functional without a compiler.
+zlib-module fallbacks keep the framework functional without a compiler —
+but a failed build is never silent: the compiler's message is logged and
+kept (``build_info()["error"]``).
+
+The artifact is named by a digest of the source bytes, the compile
+command and this host's CPU model + feature flags (``-march=native``
+bakes the build host's ISA in), so a library built from other source,
+with other flags or on another machine is never loaded: a tree copied
+between hosts rebuilds from native/hbam_native.cpp.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
-from typing import Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -27,31 +40,105 @@ _OUT_DIR = os.path.join(_REPO_ROOT, "native", "build")
 # the runtime (libasan/libtsan) is preloaded; tests spawn a subprocess with
 # LD_PRELOAD set (tests/test_native_sanitize.py).
 _SANITIZE = os.environ.get("HBAM_NATIVE_SANITIZE", "")
-_SO = os.path.join(
-    _OUT_DIR, f"libhbam_native_{_SANITIZE}.so" if _SANITIZE
-    else "libhbam_native.so")
+
+# Build flavours in preference order: libdeflate (~2x zlib inflate
+# speed) when the toolchain has it, plain zlib otherwise.
+_FLAVOURS: Tuple[Tuple[str, List[str]], ...] = (
+    ("libdeflate", ["-DHBAM_USE_LIBDEFLATE", "-lz", "-ldeflate"]),
+    ("zlib", ["-lz"]),
+)
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_info: Dict[str, Optional[str]] = {"path": None, "flavour": None,
+                                   "error": None}
 
 
-def _compile() -> bool:
-    os.makedirs(_OUT_DIR, exist_ok=True)
-    base = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-pthread",
-            _SRC, "-o", _SO]
+def _host_cpu_signature() -> str:
+    """CPU model + ISA feature flags of this host — what ``-march=native``
+    compiles for."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            lines = f.read().split("\n\n", 1)[0].splitlines()
+        keep = [ln.split(":", 1)[1].strip() for ln in lines
+                if ln.split(":", 1)[0].strip() in ("model name", "flags",
+                                                   "Features")]
+        if keep:
+            return "|".join(keep)
+    except OSError:
+        pass
+    return f"{platform.machine()}|{platform.processor()}"
+
+
+def _cflags() -> List[str]:
+    flags = ["-O3", "-march=native", "-shared", "-fPIC", "-pthread"]
     if _SANITIZE:
-        base[1:1] = [f"-fsanitize={_SANITIZE}", "-fno-omit-frame-pointer",
-                     "-g"]
-    # Prefer libdeflate (~2x zlib inflate speed); fall back to plain zlib.
-    for extra in (["-DHBAM_USE_LIBDEFLATE", "-lz", "-ldeflate"], ["-lz"]):
+        flags = [f"-fsanitize={_SANITIZE}", "-fno-omit-frame-pointer",
+                 "-g"] + flags
+    return flags
+
+
+def artifact_path(flavour: str, extra: List[str]) -> str:
+    """The digest-keyed artifact for one build flavour on this host."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(["g++"] + _cflags() + extra).encode())
+    h.update(_host_cpu_signature().encode())
+    tag = f"_{_SANITIZE}" if _SANITIZE else ""
+    return os.path.join(
+        _OUT_DIR, f"libhbam_native{tag}-{flavour}-{h.hexdigest()[:16]}.so")
+
+
+def _compile(out: str, extra: List[str]) -> Optional[str]:
+    """Build one flavour to ``out``; None on success, else the
+    compiler's message."""
+    os.makedirs(_OUT_DIR, exist_ok=True)
+    # build under a private name and rename: a concurrent process never
+    # loads a half-written library
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++"] + _cflags() + [_SRC, "-o", tmp] + extra
+    try:
+        r = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
+    if r.returncode != 0:
         try:
-            subprocess.run(base + extra, check=True, capture_output=True,
-                           timeout=120)
-            return True
-        except Exception:
-            continue
-    return False
+            os.unlink(tmp)
+        except OSError:
+            pass
+        return (r.stderr or r.stdout or f"g++ exited {r.returncode}").strip()
+    os.replace(tmp, out)
+    return None
+
+
+def _find_or_build() -> Optional[Tuple[str, str]]:
+    """(path, flavour) of a library built for THIS source, command and
+    CPU — an existing digest-named artifact, else a fresh build."""
+    paths = [(artifact_path(name, extra), name, extra)
+             for name, extra in _FLAVOURS]
+    for path, name, _extra in paths:
+        if os.path.exists(path):
+            return path, name
+    errors = []
+    for path, name, extra in paths:
+        err = _compile(path, extra)
+        if err is None:
+            return path, name
+        errors.append(f"[{name}] {err}")
+    _info["error"] = "\n".join(errors)
+    logger.error("native library build failed; host decode degrades to "
+                 "Python zlib:\n%s", _info["error"])
+    return None
+
+
+def build_info() -> Dict[str, Optional[str]]:
+    """{"path", "flavour" ("libdeflate" | "zlib"), "error" (the
+    compiler's message when the build or load failed)} of this process's
+    native library, loading it if needed."""
+    load()
+    return dict(_info)
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -62,15 +149,20 @@ def load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         if not os.path.exists(_SRC):
+            _info["error"] = f"native source missing: {_SRC}"
+            logger.error(_info["error"])
             return None
-        stale = (not os.path.exists(_SO)
-                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        if stale and not _compile():
+        found = _find_or_build()
+        if found is None:
             return None
+        path, flavour = found
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            lib = ctypes.CDLL(path)
+        except OSError as e:
+            _info["error"] = f"cannot load {path}: {e}"
+            logger.error(_info["error"])
             return None
+        _info["path"], _info["flavour"] = path, flavour
         i8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)
         i32p = ctypes.POINTER(ctypes.c_int32)
@@ -343,9 +435,8 @@ def itf8_decode_batch(buf: np.ndarray, count: int
 
 
 def fused_available() -> bool:
-    """True when the loaded library exposes the fused span-decode entry
-    points (a stale pre-fused .so rebuilds on the next source touch; until
-    then callers fall back to the two-pass path)."""
+    """True when the native library loaded and exposes the fused
+    span-decode entry points."""
     lib = load()
     return lib is not None and hasattr(lib, "hbam_fused_start")
 

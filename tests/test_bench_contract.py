@@ -454,3 +454,22 @@ def test_snapshot_mutation_not_duplicated_by_compact(bench):
     assert out["status"] == "partial"          # downgraded, not "ok"
     note = "headline measurement failed; see components"
     assert bench._STATE["notes"].count(note) == 1
+
+
+def test_exit_code_says_whether_the_json_can_be_trusted(bench):
+    """JSON always comes out; the exit code is 0 only when the headline
+    was measured and no row (scaling rows included) errored."""
+    _fill_state(bench)
+    assert bench._exit_code() == 1                 # broken_row errored
+    bench._STATE["components"] = [
+        c for c in bench._STATE["components"] if "error" not in c]
+    assert bench._exit_code() == 0                 # skipped rows are fine
+    bench._STATE["scaling"]["devices"][1] = {"n_devices": 8,
+                                             "error": "timeout"}
+    assert bench._exit_code() == 1
+    bench._STATE["scaling"] = {"error": "scaling fixture: boom"}
+    assert bench._exit_code() == 1
+    bench._STATE["scaling"] = None
+    assert bench._exit_code() == 0
+    bench._STATE["headline"] = None
+    assert bench._exit_code() == 1

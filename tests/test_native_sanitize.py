@@ -224,11 +224,11 @@ def _san_runtime(lib):
 # MLIR thread-local cache teardown) are theirs, not ours: suppress by
 # module so findings in native/hbam_native.cpp still fail the test.
 _TSAN_SUPPRESSIONS = """\
-race:xla_extension.so
+race:libjax_common.so
 race:libjaxlib_mlir_capi.so
 race:_mlir.so
 race:_multiarray_umath
-called_from_lib:xla_extension.so
+called_from_lib:libjax_common.so
 called_from_lib:libjaxlib_mlir_capi.so
 """
 

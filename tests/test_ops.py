@@ -1,5 +1,5 @@
-"""Device-op tests (run on CPU JAX per conftest): the jnp/Pallas unpack paths
-must agree bit-for-bit with the host NumPy reference (formats/bam.BamBatch)."""
+"""Device-op tests (run on CPU JAX per conftest): the jnp unpack path must
+agree bit-for-bit with the host NumPy reference (formats/bam.BamBatch)."""
 import numpy as np
 import pytest
 
@@ -11,7 +11,6 @@ from hadoop_bam_tpu.ops.flagstat import flagstat_from_columns, format_flagstat
 from hadoop_bam_tpu.ops.seq_decode import decode_qual, decode_seq
 from hadoop_bam_tpu.ops.unpack_bam import (
     FIXED_FIELDS, pad_data, pad_offsets, unpack_fixed_fields,
-    unpack_fixed_fields_pallas,
 )
 from hadoop_bam_tpu.utils import native
 
@@ -46,17 +45,6 @@ def test_unpack_fixed_fields_matches_host(decoded_span):
         host = getattr(batch, name)
         got = np.asarray(cols[name])[:n]
         np.testing.assert_array_equal(got.astype(np.int64), host,
-                                      err_msg=f"column {name}")
-
-
-def test_unpack_pallas_matches_jnp(decoded_span):
-    header, records, data, offs, batch = decoded_span
-    dev_data = pad_data(data, 1 << 20)
-    dev_offs, n = pad_offsets(offs.astype(np.int32), 1024)
-    a = unpack_fixed_fields(dev_data, dev_offs)
-    b = unpack_fixed_fields_pallas(dev_data, dev_offs, block_n=256)
-    for name in FIXED_FIELDS:
-        np.testing.assert_array_equal(np.asarray(a[name]), np.asarray(b[name]),
                                       err_msg=f"column {name}")
 
 
