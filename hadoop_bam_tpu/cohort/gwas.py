@@ -32,6 +32,7 @@ import numpy as np
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.utils.errors import PlanError
 from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.utils.stepcache import named_step
 
 # columns of the per-variant stats tensor the step returns, in order
 GWAS_COLUMNS = ("af", "call_rate", "hwe_chi2", "score_chi2")
@@ -43,7 +44,6 @@ def make_cohort_gwas_step(mesh, geometry, with_pheno: bool,
     ``[n_dev, cap, 4]`` float32 (NaN where a stat is undefined).  The
     phenotype rides as a replicated runtime argument, so one compiled
     step serves every batch and every phenotype."""
-    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -122,7 +122,7 @@ def make_cohort_gwas_step(mesh, geometry, with_pheno: bool,
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis), P(axis), P()),
                    out_specs=P(axis))
-    step = jax.jit(fn)
+    step = named_step("gwas_step", fn)
     _STEP_CACHE[key] = step
     return step
 

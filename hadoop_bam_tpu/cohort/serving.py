@@ -31,7 +31,7 @@ from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.cohort.manifest import CohortManifest, load_manifest
 from hadoop_bam_tpu.utils.errors import PlanError
 from hadoop_bam_tpu.utils.metrics import METRICS
-from hadoop_bam_tpu.utils.stepcache import BoundedStepCache
+from hadoop_bam_tpu.utils.stepcache import BoundedStepCache, named_step
 
 COHORT_PROJECTION = "cohort_dosage"
 
@@ -83,7 +83,6 @@ def make_cohort_slice_step(mesh, axis: str = "data", *,
     (replicated int32[3]).  Returns ``(keep, hits, af, af_sum, af_n)``
     — count-only serving reads just the per-device scalars; ``af`` is
     the per-row diploid ALT allele frequency (records mode)."""
-    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -117,7 +116,7 @@ def make_cohort_slice_step(mesh, axis: str = "data", *,
         fn = shard_map(per_device, mesh=mesh,
                        in_specs=(P(axis),) * 4 + (P(),),
                        out_specs=(P(axis),) * 5)
-        return jax.jit(fn)
+        return named_step("cohort_slice_step", fn)
 
     return _cache.get_or_build(key, build)
 

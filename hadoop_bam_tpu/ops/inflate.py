@@ -25,6 +25,7 @@ import numpy as np
 
 from hadoop_bam_tpu.formats import bgzf
 from hadoop_bam_tpu.utils import native
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 
 def block_table(raw: bytes, offset: int = 0) -> dict:
@@ -298,6 +299,10 @@ class FusedSpanDecode:
             rc = self._job.finish()
             self.n_rows, self.tail = self._job.n_rows, self._job.tail
             idx = self._job.err_index
+            # the host feed's "time busy", measured where the work
+            # happens: core-nanoseconds of inflate + walk + pack
+            METRICS.count("decode.native_busy_ns", self._job.busy_ns)
+            METRICS.count("decode.native_jobs")
             self._job = None
             if check and rc < 0:
                 _raise_fused_error(rc, idx)

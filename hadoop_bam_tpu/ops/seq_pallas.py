@@ -170,6 +170,7 @@ def seq_qual_stats(seq_tile: jnp.ndarray, qual_tile: jnp.ndarray,
             jax.ShapeDtypeStruct((1, N_CODES), jnp.int32),
         ),
         interpret=interpret,
+        name="hbam_seq_stats_kernel",
     )(seq_tile, qual_tile, lengths[:, None])
     return {"gc": gc[:, 0], "mean_qual": mq[:, 0], "base_hist": hist[0]}
 

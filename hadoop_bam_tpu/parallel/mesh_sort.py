@@ -62,6 +62,7 @@ from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.formats.bam import SAMHeader
 from hadoop_bam_tpu.utils.errors import PlanError
 from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.utils.stepcache import named_step
 
 _I32_SENTINEL = np.int32(2**31 - 1)
 GLOBAL_INDEX_CEILING = 2**31 - 2     # int32 global record indices
@@ -195,26 +196,29 @@ def _make_sort_step(mesh, records_cap: int):
     def per_device(data, offsets, count, base, bhi, blo):
         data, offsets = data[0], offsets[0]
         count, base = count[0], base[0]
-        cols = unpack_fixed_fields(data, offsets)
-        valid = jnp.arange(R, dtype=jnp.int32) < count
-        hi, lo, gidx = _device_keys(cols["refid"], cols["pos"], valid,
-                                    base, R)
-        perm, sb, rank = _bucket_pack(hi, lo, bhi, blo, R)
-        send_hi, send_lo, send_ix = _send_matrices(hi, lo, gidx, perm,
-                                                   sb, rank, n_dev, R)
+        with jax.named_scope("local_sort"):
+            cols = unpack_fixed_fields(data, offsets)
+            valid = jnp.arange(R, dtype=jnp.int32) < count
+            hi, lo, gidx = _device_keys(cols["refid"], cols["pos"], valid,
+                                        base, R)
+            perm, sb, rank = _bucket_pack(hi, lo, bhi, blo, R)
+            send_hi, send_lo, send_ix = _send_matrices(
+                hi, lo, gidx, perm, sb, rank, n_dev, R)
 
         # the shuffle: row b of each device goes to device b
-        recv_hi = jax.lax.all_to_all(send_hi, "data", 0, 0, tiled=True)
-        recv_lo = jax.lax.all_to_all(send_lo, "data", 0, 0, tiled=True)
-        recv_ix = jax.lax.all_to_all(send_ix, "data", 0, 0, tiled=True)
+        with jax.named_scope("exchange"):
+            recv_hi = jax.lax.all_to_all(send_hi, "data", 0, 0, tiled=True)
+            recv_lo = jax.lax.all_to_all(send_lo, "data", 0, 0, tiled=True)
+            recv_ix = jax.lax.all_to_all(send_ix, "data", 0, 0, tiled=True)
 
         # bucket-local sort; the global-index key makes ties deterministic
-        _, _, six = jax.lax.sort(
-            (recv_hi.ravel(), recv_lo.ravel(), recv_ix.ravel()),
-            num_keys=3)
+        with jax.named_scope("merge"):
+            _, _, six = jax.lax.sort(
+                (recv_hi.ravel(), recv_lo.ravel(), recv_ix.ravel()),
+                num_keys=3)
         return six[None]
 
-    return jax.jit(shard_map(
+    return named_step("sort_step", shard_map(
         per_device, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data"), P(), P()),
         out_specs=P("data"), check_vma=False))
@@ -273,39 +277,42 @@ def _make_bytes_sort_step(mesh, records_cap: int, stride: int):
     def per_device(rows, lens, count, base, bhi, blo):
         rows, lens = rows[0], lens[0]
         count, base = count[0], base[0]
-        refid = le_i32(rows, 4)          # BAM fixed fields live at the
-        pos = le_i32(rows, 8)            # row head: refID @4, pos @8
-        valid = jnp.arange(R, dtype=jnp.int32) < count
-        hi, lo, gidx = _device_keys(refid, pos, valid, base, R)
-        # capacity is structural (a source holds at most R records, so
-        # no (src, dst) send cell can overflow)
-        perm, sb, rank = _bucket_pack(hi, lo, bhi, blo, R)
-        send_hi, send_lo, send_ix = _send_matrices(hi, lo, gidx, perm,
-                                                   sb, rank, n_dev, R)
-        send_ln = jnp.zeros((n_dev, R), jnp.int32
-                            ).at[sb, rank].set(lens[perm])
-        send_rows = jnp.zeros((n_dev, R, stride), jnp.uint8
-                              ).at[sb, rank].set(rows[perm])
+        with jax.named_scope("local_sort"):
+            refid = le_i32(rows, 4)      # BAM fixed fields live at the
+            pos = le_i32(rows, 8)        # row head: refID @4, pos @8
+            valid = jnp.arange(R, dtype=jnp.int32) < count
+            hi, lo, gidx = _device_keys(refid, pos, valid, base, R)
+            # capacity is structural (a source holds at most R records,
+            # so no (src, dst) send cell can overflow)
+            perm, sb, rank = _bucket_pack(hi, lo, bhi, blo, R)
+            send_hi, send_lo, send_ix = _send_matrices(
+                hi, lo, gidx, perm, sb, rank, n_dev, R)
+            send_ln = jnp.zeros((n_dev, R), jnp.int32
+                                ).at[sb, rank].set(lens[perm])
+            send_rows = jnp.zeros((n_dev, R, stride), jnp.uint8
+                                  ).at[sb, rank].set(rows[perm])
 
-        recv_hi = jax.lax.all_to_all(send_hi, "data", 0, 0,
-                                     tiled=True).ravel()
-        recv_lo = jax.lax.all_to_all(send_lo, "data", 0, 0,
-                                     tiled=True).ravel()
-        recv_ix = jax.lax.all_to_all(send_ix, "data", 0, 0,
-                                     tiled=True).ravel()
-        recv_ln = jax.lax.all_to_all(send_ln, "data", 0, 0,
-                                     tiled=True).ravel()
-        recv_rows = jax.lax.all_to_all(send_rows, "data", 0, 0,
-                                       tiled=True).reshape(N, stride)
+        with jax.named_scope("exchange"):
+            recv_hi = jax.lax.all_to_all(send_hi, "data", 0, 0,
+                                         tiled=True).ravel()
+            recv_lo = jax.lax.all_to_all(send_lo, "data", 0, 0,
+                                         tiled=True).ravel()
+            recv_ix = jax.lax.all_to_all(send_ix, "data", 0, 0,
+                                         tiled=True).ravel()
+            recv_ln = jax.lax.all_to_all(send_ln, "data", 0, 0,
+                                         tiled=True).ravel()
+            recv_rows = jax.lax.all_to_all(send_rows, "data", 0, 0,
+                                           tiled=True).reshape(N, stride)
 
-        iota = jnp.arange(N, dtype=jnp.int32)
-        _, _, six, order = jax.lax.sort(
-            (recv_hi, recv_lo, recv_ix, iota), num_keys=3)
-        sorted_rows = jnp.take(recv_rows, order, axis=0)
-        sorted_ln = jnp.take(recv_ln, order)
+        with jax.named_scope("merge"):
+            iota = jnp.arange(N, dtype=jnp.int32)
+            _, _, six, order = jax.lax.sort(
+                (recv_hi, recv_lo, recv_ix, iota), num_keys=3)
+            sorted_rows = jnp.take(recv_rows, order, axis=0)
+            sorted_ln = jnp.take(recv_ln, order)
         return sorted_rows[None], sorted_ln[None], six[None]
 
-    return jax.jit(shard_map(
+    return named_step("bytes_sort_step", shard_map(
         per_device, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data"), P(), P()),
         out_specs=(P("data"), P("data"), P("data")), check_vma=False))
@@ -1269,50 +1276,57 @@ def _sort_bam_mesh_index(input_path: str, output_path: str, *, mesh,
     if header is None:
         header, _ = read_bam_header(input_path)
 
-    spans = plan_bam_spans_balanced(input_path, n_dev, header=header)
+    # one round; each phase a span on the calling thread (PERF.md section 3)
     raw: List[Tuple[np.ndarray, np.ndarray]] = []   # (data, offsets)
     his: List[np.ndarray] = []
     los: List[np.ndarray] = []
-    for s in spans:
-        data, offs, _voffs, _ = _decode_span_core(input_path, s, False,
-                                                  "auto")
-        if data.size > 2**31 - 64:
-            raise ValueError(
-                f"span inflates to {data.size} bytes — offsets exceed "
-                f"the device int32 tile layout; use utils.sort.sort_bam "
-                f"for inputs this large")
-        raw.append((data, offs.astype(np.int32)))
-        h, l = _keys_of(data, offs)
-        his.append(h)
-        los.append(l)
-    counts = [o.size for _, o in raw]
+    with METRICS.span("sort.read_wall", round=0) as read_args:
+        spans = plan_bam_spans_balanced(input_path, n_dev, header=header)
+        for s in spans:
+            data, offs, _voffs, _ = _decode_span_core(input_path, s, False,
+                                                      "auto")
+            if data.size > 2**31 - 64:
+                raise ValueError(
+                    f"span inflates to {data.size} bytes — offsets exceed "
+                    f"the device int32 tile layout; use "
+                    f"utils.sort.sort_bam for inputs this large")
+            raw.append((data, offs.astype(np.int32)))
+            h, l = _keys_of(data, offs)
+            his.append(h)
+            los.append(l)
+        counts = [o.size for _, o in raw]
+        total = int(sum(counts))
+        read_args["records"] = total
     METRICS.count("mesh_sort.rounds")
     METRICS.count_per_device("mesh_sort.device_rows", counts)
-    total = int(sum(counts))
     base = np.zeros(n_dev, dtype=np.int32)
     if counts:
         base[1:len(counts)] = np.cumsum(counts[:-1])
 
-    bytes_cap = _round_up(max((d.size for d, _ in raw), default=1), 256)
-    records_cap = _round_up(max(counts, default=1), 8)
-    datas = np.zeros((n_dev, bytes_cap), np.uint8)
-    offsets = np.zeros((n_dev, records_cap), np.int32)
-    cvec = np.zeros(n_dev, np.int32)
-    for d, (dat, off) in enumerate(raw):
-        datas[d, :dat.size] = dat
-        offsets[d, :off.size] = off
-        cvec[d] = off.size
-    bhi, blo = _sample_bounds(his, los, n_dev)
+    with METRICS.span("sort.pack_wall", round=0, records=total):
+        bytes_cap = _round_up(max((d.size for d, _ in raw), default=1), 256)
+        records_cap = _round_up(max(counts, default=1), 8)
+        datas = np.zeros((n_dev, bytes_cap), np.uint8)
+        offsets = np.zeros((n_dev, records_cap), np.int32)
+        cvec = np.zeros(n_dev, np.int32)
+        for d, (dat, off) in enumerate(raw):
+            datas[d, :dat.size] = dat
+            offsets[d, :off.size] = off
+            cvec[d] = off.size
+        bhi, blo = _sample_bounds(his, los, n_dev)
 
-    step = _make_sort_step(mesh, records_cap)
-    sharding = NamedSharding(mesh, P("data"))
-    rep = NamedSharding(mesh, P())
-    six = step(jax.device_put(datas, sharding),
-               jax.device_put(offsets, sharding),
-               jax.device_put(cvec, sharding),
-               jax.device_put(base, sharding),
-               jax.device_put(bhi, rep), jax.device_put(blo, rep))
-    six = np.asarray(six)                     # [n_dev, n_dev * records_cap]
+    # launch to ready: step build (a trace + cache load every job, see
+    # steps.built.hbam_sort_step), transfers, the exchange, the readback
+    with METRICS.span("sort.exchange_wall", round=0, records=total):
+        step = _make_sort_step(mesh, records_cap)
+        sharding = NamedSharding(mesh, P("data"))
+        rep = NamedSharding(mesh, P())
+        six = step(jax.device_put(datas, sharding),
+                   jax.device_put(offsets, sharding),
+                   jax.device_put(cvec, sharding),
+                   jax.device_put(base, sharding),
+                   jax.device_put(bhi, rep), jax.device_put(blo, rep))
+        six = np.asarray(six)                 # [n_dev, n_dev * records_cap]
     del datas, offsets                        # padded copies; raw suffices
 
     # apply the permutation: buckets in device order ARE the global order.
@@ -1325,37 +1339,47 @@ def _sort_bam_mesh_index(input_path: str, output_path: str, *, mesh,
     out_header = _sorted_header(header, by_name=False)
     from hadoop_bam_tpu.write import write_bam_records
 
+    def permute_bucket(idxs):
+        """(record bytes, record starts) of one bucket in sorted order."""
+        s_arr = span_of[idxs]
+        o_arr = np.empty(idxs.size, np.int64)
+        ln_arr = np.empty(idxs.size, np.int64)
+        for sp in np.unique(s_arr):
+            m = s_arr == sp
+            data, offs = raw[sp]
+            o = offs[idxs[m] - int(base[sp])].astype(np.int64)
+            bs = (data[o[:, None] + np.arange(4)]
+                  .view("<i4").ravel().astype(np.int64))
+            o_arr[m] = o
+            ln_arr[m] = bs + 4
+        dst0 = np.cumsum(ln_arr) - ln_arr
+        out = np.empty(int(ln_arr.sum()), np.uint8)
+        for sp in np.unique(s_arr):
+            m = s_arr == sp
+            data, _ = raw[sp]
+            nb = ln_arr[m]
+            f = (np.arange(int(nb.sum()), dtype=np.int64)
+                 - np.repeat(np.cumsum(nb) - nb, nb))
+            out[np.repeat(dst0[m], nb) + f] = \
+                data[np.repeat(o_arr[m], nb) + f]
+        return out, dst0
+
     def bucket_chunks():
         for d in range(n_dev):
             idxs = six[d]
             idxs = idxs[idxs != _I32_SENTINEL].astype(np.int64)
             if not idxs.size:
                 continue
-            s_arr = span_of[idxs]
-            o_arr = np.empty(idxs.size, np.int64)
-            ln_arr = np.empty(idxs.size, np.int64)
-            for sp in np.unique(s_arr):
-                m = s_arr == sp
-                data, offs = raw[sp]
-                o = offs[idxs[m] - int(base[sp])].astype(np.int64)
-                bs = (data[o[:, None] + np.arange(4)]
-                      .view("<i4").ravel().astype(np.int64))
-                o_arr[m] = o
-                ln_arr[m] = bs + 4
-            dst0 = np.cumsum(ln_arr) - ln_arr
-            out = np.empty(int(ln_arr.sum()), np.uint8)
-            for sp in np.unique(s_arr):
-                m = s_arr == sp
-                data, _ = raw[sp]
-                nb = ln_arr[m]
-                f = (np.arange(int(nb.sum()), dtype=np.int64)
-                     - np.repeat(np.cumsum(nb) - nb, nb))
-                out[np.repeat(dst0[m], nb) + f] = \
-                    data[np.repeat(o_arr[m], nb) + f]
-            yield out, dst0
+            # a child of sort.write_wall where the writer pulls chunks on
+            # the calling thread; closed before the yield, never across it
+            with METRICS.span("sort.permute_wall", round=0,
+                              records=int(idxs.size)):
+                chunk = permute_bucket(idxs)
+            yield chunk
 
-    written = write_bam_records(output_path, out_header, bucket_chunks(),
-                                config=config).records
+    with METRICS.span("sort.write_wall", round=0, records=total):
+        written = write_bam_records(output_path, out_header,
+                                    bucket_chunks(), config=config).records
     if written != total:
         raise RuntimeError(
             f"mesh sort wrote {written} of {total} records — bucket "

@@ -72,6 +72,7 @@ from hadoop_bam_tpu.utils.resilient import (
     QuarantineManifest, RetryPolicy, RetryingByteSource,
 )
 from hadoop_bam_tpu.utils.seekable import as_byte_source, scoped_byte_source
+from hadoop_bam_tpu.utils.stepcache import named_step
 
 logger = logging.getLogger(__name__)
 
@@ -809,7 +810,7 @@ def make_flagstat_step(mesh: Mesh, axis: str = "data") -> Callable:
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis), P(axis), P(axis)),
                    out_specs=P())
-    step = jax.jit(fn)
+    step = named_step("flagstat_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -839,7 +840,7 @@ def make_flagstat_tile_step(mesh: Mesh, axis: str = "data",
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis), P(axis)),
                    out_specs=P())
-    step = jax.jit(fn)
+    step = named_step("flagstat_tile_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -862,7 +863,7 @@ def make_unpack_step(mesh: Mesh, axis: str = "data") -> Callable:
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis), P(axis), P(axis)),
                    out_specs=P(axis))
-    step = jax.jit(fn)
+    step = named_step("unpack_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -878,7 +879,11 @@ def iter_span_groups(spans: Sequence[FileVirtualSpan], n_dev: int
         yield spans[i:i + n_dev]
 
 
-_ADD = jax.jit(jnp.add)
+def _totals_add(a, b):
+    return jnp.add(a, b)
+
+
+_ADD = named_step("totals_add", _totals_add)
 
 
 def parse_config_intervals(config: HBamConfig, header):
@@ -1531,21 +1536,24 @@ def make_seq_stats_step(mesh: Mesh, geometry: PayloadGeometry,
 
     def per_device(prefix, seq, qual, count):
         prefix, seq, qual, count = prefix[0], seq[0], qual[0], count[0]
-        cols = unpack_projected_tile(prefix, ALL_FIELDS)
-        valid = jnp.arange(prefix.shape[0], dtype=jnp.int32) < count
-        lengths = jnp.where(valid,
-                            jnp.minimum(cols["l_seq"], geometry.max_len), 0)
-        stats = seq_qual_stats(seq, qual, lengths,
-                               block_n=geometry.block_n,
-                               interpret=interpret)
-        return _payload_stats_tail(stats, valid, axis)
+        with jax.named_scope("unpack"):
+            cols = unpack_projected_tile(prefix, ALL_FIELDS)
+            valid = jnp.arange(prefix.shape[0], dtype=jnp.int32) < count
+            lengths = jnp.where(
+                valid, jnp.minimum(cols["l_seq"], geometry.max_len), 0)
+        with jax.named_scope("kernel"):
+            stats = seq_qual_stats(seq, qual, lengths,
+                                   block_n=geometry.block_n,
+                                   interpret=interpret)
+        with jax.named_scope("psum"):
+            return _payload_stats_tail(stats, valid, axis)
 
     # check_vma=False: pallas_call's out_shape has no varying-mesh-axes
     # annotation, which the default shard_map VMA check rejects
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis), P(axis), P(axis), P(axis)),
                    out_specs=(P(), P()), check_vma=False)
-    step = jax.jit(fn)
+    step = named_step("seq_stats_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -1636,14 +1644,16 @@ def make_read_stats_step(mesh: Mesh, geometry: PayloadGeometry,
         seq, qual, lengths, count = seq[0], qual[0], lengths[0], count[0]
         valid = jnp.arange(seq.shape[0], dtype=jnp.int32) < count
         lengths = jnp.where(valid, lengths, 0)
-        stats = seq_qual_stats(seq, qual, lengths,
-                               block_n=geometry.block_n,
-                               interpret=interpret)
-        return _payload_stats_tail(stats, valid, axis)
+        with jax.named_scope("kernel"):
+            stats = seq_qual_stats(seq, qual, lengths,
+                                   block_n=geometry.block_n,
+                                   interpret=interpret)
+        with jax.named_scope("psum"):
+            return _payload_stats_tail(stats, valid, axis)
 
     fn = shard_map(per_device, mesh=mesh, in_specs=(P(axis),) * 4,
                    out_specs=(P(), P()), check_vma=False)
-    step = jax.jit(fn)
+    step = named_step("read_stats_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -2078,7 +2088,7 @@ def make_device_flagstat_step(mesh: Mesh, axis: str = "data") -> Callable:
     fn = shard_map(per_device, mesh=mesh, in_specs=(P(axis),) * 4,
                    out_specs=(P(), P(axis), P(axis), P(axis)),
                    check_vma=False)
-    step = jax.jit(fn)
+    step = named_step("device_flagstat_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -2361,7 +2371,7 @@ def make_device_seq_stats_step(mesh: Mesh, geometry: PayloadGeometry,
     fn = shard_map(per_device, mesh=mesh, in_specs=(P(axis),) * 4,
                    out_specs=(P(), P(), P(axis), P(axis), P(axis)),
                    check_vma=False)
-    step = jax.jit(fn)
+    step = named_step("device_seq_stats_step", fn)
     _STEP_CACHE[key] = step
     return step
 
@@ -2768,9 +2778,8 @@ def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
 
     def dispatch(arrays, counts):
         nonlocal totals_vec
-        with METRICS.timer("pipeline.device_put"):
-            t = jax.device_put(arrays[0], sharding)
-            c = jax.device_put(counts, sharding)
+        t = jax.device_put(arrays[0], sharding)
+        c = jax.device_put(counts, sharding)
         with METRICS.span("bam.kernel_wall"):
             vec = step(t, c)
             totals_vec = vec if totals_vec is None \
@@ -2895,7 +2904,7 @@ def make_coverage_step(mesh: Mesh, window: int, max_cigar: int,
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis), P(axis), P(), P()),
                    out_specs=P(axis))
-    step = jax.jit(fn)
+    step = named_step("coverage_step", fn)
     _STEP_CACHE[key] = step
     return step
 

@@ -32,14 +32,19 @@ Two mechanisms replace it:
   ``stream()`` powers the generator-shaped ``tensor_batches`` APIs.
 
 Wall-clock accounting rides along: ``pipeline.feed_wall`` (whole feed),
-``pipeline.dispatch_wall`` (device-busy wall inside dispatch calls) and
+``pipeline.dispatch_wall`` (host wall inside dispatch calls) and
 the ``pipeline.dispatch_bytes`` counter feed the bench's
 ``overlap_efficiency`` ratio — the thread-summed ``METRICS.timer``
-values cannot show overlap, the wall spans can.
+values cannot show overlap, the wall spans can.  Each thread's time is
+partitioned by spans (all carry a profiler annotation while a recorder
+is active): the dispatch thread's by ``feed.wait_group`` +
+``pipeline.dispatch_wall``; the packer's by ``feed.wait_rows`` +
+``feed.wait_slot`` + ``staging.transfer_wait`` + ``staging.pack``.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import contextvars
 import dataclasses
 import queue
@@ -50,6 +55,7 @@ from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
+from hadoop_bam_tpu.obs.trace import active_recorder
 from hadoop_bam_tpu.utils.metrics import METRICS
 
 
@@ -334,14 +340,29 @@ class FeedPipeline:
             collections.deque()
         have = 0
         exhausted = False
+        # feed.wait_rows: the packer starved by decode (pool fetch, job
+        # start and the native chunk poll all sit under next(it)).  With
+        # a recorder active each pull is a span, so it carries a profiler
+        # annotation; otherwise two clock reads a pull, summed into one
+        # add_wall a group
+        traced = active_recorder() is not None
+        waited = 0.0
 
         def pull_until(need: int) -> None:
-            nonlocal exhausted, have
+            nonlocal exhausted, have, waited
             while not exhausted and have < need:
                 if cancel.is_set():
                     raise _Cancelled()
                 try:
-                    arrays = next(it)
+                    if traced:
+                        with METRICS.span("feed.wait_rows"):
+                            arrays = next(it)
+                    else:
+                        t_wait = time.perf_counter()
+                        try:
+                            arrays = next(it)
+                        finally:
+                            waited += time.perf_counter() - t_wait
                 except StopIteration:
                     exhausted = True
                     return
@@ -355,11 +376,19 @@ class FeedPipeline:
             # balance needs one group's worth buffered up front (the
             # tail split depends on the total); serial mode pulls
             # lazily so tensor_batches never holds an extra group of
-            # decoded spans in memory
+            # decoded spans in memory (its later pulls then sit inside
+            # staging.pack, as feed.wait_rows children)
             pull_until(self.n_dev * self.cap if self.balance else 1)
+            if waited:
+                METRICS.add_wall("feed.wait_rows", waited)
+                waited = 0.0
             if not have:
                 break
-            slot = ring.lease(cancel)
+            # feed.wait_slot: the packer held by back-pressure — every
+            # ring slot still leased to the dispatch side here, the
+            # hand-off queue full below (only with more slots than depth)
+            with METRICS.span("feed.wait_slot"):
+                slot = ring.lease(cancel)
             if slot.in_flight is not None:
                 # the slot's previous dispatch may still be transferring
                 # from these buffers: wait HERE, on the packer thread,
@@ -367,67 +396,65 @@ class FeedPipeline:
                 with METRICS.span("staging.transfer_wait"):
                     _block_in_flight(slot.in_flight)
                 slot.in_flight = None
-            t_pack = time.perf_counter()
-            counts = slot.counts
-            counts[:] = 0
-            target = self.cap
-            if self.balance and exhausted and have < self.n_dev * self.cap:
-                # balanced tail (stats drivers): the serial fill order
-                # would park the whole remainder on the first devices
-                # and leave the rest idle — a small file on an 8-wide
-                # mesh then pays one device's wall time AND a full-cap
-                # padded transfer.  Spreading the tail evenly keeps
-                # every shard busy and lets the bucket ladder shrink
-                # the dispatch.  psum-invariant, so results are
-                # unchanged; tensor_batches keeps the serial order
-                # (balance=False) for byte-stable public batches.
-                target = max(1, -(-have // self.n_dev))
-            for dev in range(self.n_dev):
-                filled = 0
-                while filled < target:
-                    if not parts:
-                        pull_until(1)
-                        if not parts:
-                            break
-                    head = parts[0]
-                    k = min(target - filled, head[0].shape[0])
-                    for dst, src in zip(slot.arrays, head):
-                        dst[dev, filled:filled + k] = src[:k]
-                    if k == head[0].shape[0]:
-                        parts.popleft()
-                    else:
-                        parts[0] = tuple(h[k:] for h in head)
-                    filled += k
-                    have -= k
-                counts[dev] = filled
-                if not parts and exhausted:
-                    break
-            bucket = self.cap
-            if not self.fixed_shape:
-                # per-device bucket caps: the dispatch height is shared
-                # (one shard_map step) but sized by the LARGEST shard,
-                # so the final partial group shrinks to the smallest
-                # bucket holding it (bucket_cap is monotonic in count,
-                # so the max over devices equals bucket_cap(max count))
-                bucket = max(bucket_cap(int(c), self.cap, self.block_n)
-                             for c in counts)
-            # zero ONLY the written tail: rows [count, bucket) per
-            # device.  Rows past the bucket are never dispatched, and
-            # rows under the count are fully overwritten — a full group
-            # therefore pays no memset at all.
-            for spec, dst in zip(self.specs, slot.arrays):
-                for dev in range(self.n_dev):
-                    c = int(counts[dev])
-                    if c < bucket:
-                        dst[dev, c:bucket] = spec.pad
             # pack span (packer thread): group assembly occupancy sits
             # next to the consumer thread's dispatch spans in the trace
             # — the double-buffer overlap made visible
-            METRICS.add_wall("staging.pack", time.perf_counter() - t_pack,
-                             t0=t_pack,
-                             args={"rows": int(counts.sum()),
-                                   "bucket": bucket})
-            _put(q, (slot, bucket), cancel)
+            with METRICS.span("staging.pack") as pack_args:
+                counts = slot.counts
+                counts[:] = 0
+                target = self.cap
+                if self.balance and exhausted and have < self.n_dev * self.cap:
+                    # balanced tail (stats drivers): the serial fill order
+                    # would park the whole remainder on the first devices
+                    # and leave the rest idle — a small file on an 8-wide
+                    # mesh then pays one device's wall time AND a full-cap
+                    # padded transfer.  Spreading the tail evenly keeps
+                    # every shard busy and lets the bucket ladder shrink
+                    # the dispatch.  psum-invariant, so results are
+                    # unchanged; tensor_batches keeps the serial order
+                    # (balance=False) for byte-stable public batches.
+                    target = max(1, -(-have // self.n_dev))
+                for dev in range(self.n_dev):
+                    filled = 0
+                    while filled < target:
+                        if not parts:
+                            pull_until(1)
+                            if not parts:
+                                break
+                        head = parts[0]
+                        k = min(target - filled, head[0].shape[0])
+                        for dst, src in zip(slot.arrays, head):
+                            dst[dev, filled:filled + k] = src[:k]
+                        if k == head[0].shape[0]:
+                            parts.popleft()
+                        else:
+                            parts[0] = tuple(h[k:] for h in head)
+                        filled += k
+                        have -= k
+                    counts[dev] = filled
+                    if not parts and exhausted:
+                        break
+                bucket = self.cap
+                if not self.fixed_shape:
+                    # per-device bucket caps: the dispatch height is shared
+                    # (one shard_map step) but sized by the LARGEST shard,
+                    # so the final partial group shrinks to the smallest
+                    # bucket holding it (bucket_cap is monotonic in count,
+                    # so the max over devices equals bucket_cap(max count))
+                    bucket = max(bucket_cap(int(c), self.cap, self.block_n)
+                                 for c in counts)
+                # zero ONLY the written tail: rows [count, bucket) per
+                # device.  Rows past the bucket are never dispatched, and
+                # rows under the count are fully overwritten — a full group
+                # therefore pays no memset at all.
+                for spec, dst in zip(self.specs, slot.arrays):
+                    for dev in range(self.n_dev):
+                        c = int(counts[dev])
+                        if c < bucket:
+                            dst[dev, c:bucket] = spec.pad
+                pack_args.update(rows=int(counts.sum()), bucket=bucket)
+            with METRICS.span("feed.wait_slot"):
+                _put(q, (slot, bucket), cancel)
 
     # -- consumer side (the caller's thread) --------------------------------
 
@@ -477,7 +504,10 @@ class FeedPipeline:
         packer.start()
         try:
             while True:
-                item = q.get()
+                # feed.wait_group: the dispatch thread starved by the
+                # packer (and, through it, by decode)
+                with METRICS.span("feed.wait_group"):
+                    item = q.get()
                 if item is _SENTINEL:
                     break
                 slot, bucket = item
@@ -509,18 +539,26 @@ class FeedPipeline:
         for slot, arrays in self._slots(stream):
             yield arrays, slot.counts
 
-    def _account(self, arrays: Tuple[np.ndarray, ...], counts: np.ndarray,
-                 dt: float, t0: Optional[float] = None) -> None:
-        self._device_wall += dt
-        self.dispatches += 1
-        METRICS.count_per_device(f"{self.name}.device_rows", counts)
+    @contextlib.contextmanager
+    def _account(self, arrays: Tuple[np.ndarray, ...],
+                 counts: np.ndarray) -> Iterator[None]:
+        """The ``<name>.dispatch_wall`` span around one group's dispatch
+        call, and that group's counters once the call returned."""
         n = None
         if self.count_bytes:
             n = sum(int(a.nbytes) for a in arrays) + int(counts.nbytes)
+        t0 = time.perf_counter()
+        with METRICS.span(f"{self.name}.dispatch_wall") as span_args:
+            if n is not None:
+                span_args["bytes"] = n
+            yield
+        dt = time.perf_counter() - t0
+        self._device_wall += dt
+        self.dispatches += 1
+        METRICS.count_per_device(f"{self.name}.device_rows", counts)
+        if n is not None:
             self.dispatch_bytes += n
             METRICS.count("pipeline.dispatch_bytes", n)
-        METRICS.add_wall(f"{self.name}.dispatch_wall", dt, t0=t0,
-                         args=None if n is None else {"bytes": n})
         if self.fmt:
             METRICS.add_wall(f"{self.fmt}.dispatch_wall", dt)
         # per-group dispatch latency distribution: the p99 here is the
@@ -540,10 +578,8 @@ class FeedPipeline:
         buffers — asynchronous transfers stay safe without the dispatch
         thread ever blocking."""
         for slot, arrays in self._slots(span_stream):
-            t0 = time.perf_counter()
-            out = emit_fn(arrays, slot.counts)
-            self._account(arrays, slot.counts, time.perf_counter() - t0,
-                          t0=t0)
+            with self._account(arrays, slot.counts):
+                out = emit_fn(arrays, slot.counts)
             slot.in_flight = out
             yield out
 

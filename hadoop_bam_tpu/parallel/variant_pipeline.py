@@ -39,6 +39,7 @@ from hadoop_bam_tpu.parallel.pipeline import (
 from hadoop_bam_tpu.resilience import chaos
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import decode_pool, decode_pool_size
+from hadoop_bam_tpu.utils.stepcache import named_step
 
 logger = logging.getLogger(__name__)
 
@@ -531,7 +532,7 @@ def make_variant_stats_step(mesh: Mesh, geometry: VariantGeometry,
 
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis),) * 5, out_specs=(P(), P()))
-    step = jax.jit(fn)
+    step = named_step("variant_step", fn)
     _STEP_CACHE[key] = step
     return step
 

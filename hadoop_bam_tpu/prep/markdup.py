@@ -33,6 +33,7 @@ import numpy as np
 from hadoop_bam_tpu.parallel.mesh_sort import (
     _I32_SENTINEL, _bucket_pack, _device_keys, _send_matrices,
 )
+from hadoop_bam_tpu.utils.stepcache import named_step
 
 _U32 = 0xFFFFFFFF
 # ineligible flags: unmapped 0x4, secondary 0x100, supplementary 0x800
@@ -205,7 +206,7 @@ def _make_fused_sort_markdup_step(mesh, records_cap: int, stride: int,
                 k0[None], k1[None], k2[None], k3[None], k4[None],
                 score[None], elig.astype(jnp.uint8)[None])
 
-    return jax.jit(shard_map(
+    return named_step("fused_sort_markdup_step", shard_map(
         per_device, mesh=mesh,
         in_specs=(P("data"),) * 5 + (P(), P()),
         out_specs=(P("data"),) * 10, check_vma=False))
@@ -276,7 +277,7 @@ def _make_markdup_exchange_step(mesh, cap: int):
         dup = (ok & prev_same).astype(jnp.uint8)
         return six[None], dup[None]
 
-    return jax.jit(shard_map(
+    return named_step("markdup_exchange_step", shard_map(
         per_device, mesh=mesh,
         in_specs=(P("data"),) * 8,
         out_specs=(P("data"), P("data")), check_vma=False))

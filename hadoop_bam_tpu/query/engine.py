@@ -43,7 +43,7 @@ from hadoop_bam_tpu.split.intervals import Interval, resolve_interval
 from hadoop_bam_tpu.split.spans import FileVirtualSpan
 from hadoop_bam_tpu.utils.errors import PlanError
 from hadoop_bam_tpu.utils.metrics import METRICS
-from hadoop_bam_tpu.utils.stepcache import BoundedStepCache
+from hadoop_bam_tpu.utils.stepcache import BoundedStepCache, named_step
 
 _I32_MAX = np.int32(np.iinfo(np.int32).max)
 # compressed gap below which neighbouring index ranges coalesce into one
@@ -87,7 +87,6 @@ def make_overlap_step(mesh, axis: str = "data"):
     boolean keep mask.  The interval bounds ride the tile as per-row
     columns, so one step serves rows belonging to DIFFERENT requests in
     the same dispatch (the whole point of batching the queries)."""
-    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -109,7 +108,7 @@ def make_overlap_step(mesh, axis: str = "data"):
 
         fn = shard_map(per_device, mesh=mesh, in_specs=(P(axis),) * 8,
                        out_specs=P(axis))
-        return jax.jit(fn)
+        return named_step("query_filter_step", fn)
 
     return _STEP_CACHE.get_or_build(key, build)
 

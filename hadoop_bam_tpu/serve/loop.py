@@ -26,6 +26,7 @@ Span/metric taxonomy (PR-6 obs layer; all Prometheus-exportable):
 ``serve.request_wall`` / ``serve.tile_build_wall`` /
 ``serve.filter_wall`` spans, ``serve.latency_s`` end-to-end histogram
 (enqueue -> result, admission wait included), ``serve.queue_wait_s``,
+``serve.filter_launches`` (filter-step launches, one per tile group),
 ``serve.tile_hits/misses/evictions``, ``serve.prefetch_issued/useful``,
 and ``query.deadline_misses`` for jobs that finish past their budget.
 """
@@ -569,6 +570,7 @@ class ServeLoop:
                     count += int(np.asarray(hits).sum())
                     if job.want_records:
                         masks.append(np.asarray(keep))
+            METRICS.count("serve.filter_launches", len(tiles.groups))
             if job.want_records and masks:
                 rows_per_chunk.append((
                     (s, e), self._flat_rows(masks, builder), tiles.n))

@@ -35,7 +35,7 @@ import numpy as np
 
 from hadoop_bam_tpu.resilience import chaos
 from hadoop_bam_tpu.utils.metrics import METRICS
-from hadoop_bam_tpu.utils.stepcache import BoundedStepCache
+from hadoop_bam_tpu.utils.stepcache import BoundedStepCache, named_step
 
 # the one projection served today: interval-overlap columns.  Payload
 # projections (seq/qual tiles for query-then-analyze fusion) slot in as
@@ -219,7 +219,6 @@ def make_tile_filter_step(mesh, axis: str = "data"):
     into per-row columns at pack time — the interval here is a runtime
     argument, so one resident tile serves every query that lands on its
     chunk without repacking or retransferring anything."""
-    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -240,7 +239,7 @@ def make_tile_filter_step(mesh, axis: str = "data"):
         fn = shard_map(per_device, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
                        out_specs=(P(axis), P(axis)))
-        return jax.jit(fn)
+        return named_step("tile_filter_step", fn)
 
     return _STEP_CACHE.get_or_build(key, build)
 

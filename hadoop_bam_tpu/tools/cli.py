@@ -1746,12 +1746,21 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    args = build_parser().parse_args(argv)
+    argv = sys.argv[1:] if argv is None else list(argv)
+    # the verb is the first positional (the top-level parser takes no
+    # option but -h); named before parsing so the trace and the span
+    # below cover the parser build too
+    verb = next((a for a in argv if not a.startswith("-")), "?")
     # one TraceContext per CLI invocation: the verb is an entry point,
     # and every span / journal line / flight-ring entry the verb
     # produces carries this trace id (obs/context.py)
     from hadoop_bam_tpu.obs.context import trace_context
-    with trace_context(op=f"cli.{getattr(args, 'verb', '?')}"):
+    from hadoop_bam_tpu.utils.metrics import METRICS
+    # cli.main_wall: the whole invocation; less plan.execute_wall it is
+    # the verb's time outside the plan (parser, header, plan, print)
+    with trace_context(op=f"cli.{verb}"), \
+            METRICS.span("cli.main_wall", verb=verb):
+        args = build_parser().parse_args(argv)
         try:
             # device verbs only: pure-IO verbs must not pay jax
             # import/backend init (or grab the accelerator) at startup.
@@ -1768,8 +1777,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             # ValueError, TransientIOError (shed load / blown deadline)
             # an OSError
             from hadoop_bam_tpu.obs import flight
-            flight.recorder().dump(
-                f"cli_error:{getattr(args, 'verb', '?')}", error=str(e))
+            flight.recorder().dump(f"cli_error:{verb}", error=str(e))
             print(f"error: {e}", file=sys.stderr)
             return 1
 

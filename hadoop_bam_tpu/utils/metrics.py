@@ -15,8 +15,6 @@ latency/size distributions, and emits structured spans:
                               tracing is enabled (``obs/trace.py``) +
                               a ``jax.profiler`` annotation when jax is
                               active — Chrome-trace exportable
-- ``trace``                   timer + jax.profiler annotation (degrades
-                              to a plain timer on minimal installs)
 
 **Context scoping.**  ``METRICS`` is a PROXY: attribute access resolves
 to the contextvar-scoped current ``Metrics`` instance, falling back to
@@ -228,7 +226,7 @@ class Metrics:
         _flight.recorder().record_span(name, seconds, args or None)
 
     @contextlib.contextmanager
-    def span(self, name: str, **args) -> Iterator[None]:
+    def span(self, name: str, **args) -> Iterator[Dict[str, object]]:
         """A STAGE SPAN: ``wall_timer`` aggregation plus, when tracing is
         enabled (``obs.trace.enable_tracing``), one trace-ring event per
         occurrence — name, thread, duration, the keyword ``args``
@@ -238,10 +236,12 @@ class Metrics:
         Every completion ALSO lands in the always-on flight recorder
         ring (one deque append).  Tracing disabled, this is
         ``wall_timer`` plus the flight append (the bench's
-        ``obs_overhead_pct`` row pins the whole cost <2%)."""
+        ``obs_overhead_pct`` row pins the whole cost <2%).
+
+        Yields a dict for args known only when the span ends (the rows
+        a pack wrote): what the body puts there joins ``args``."""
         rec = active_recorder()
-        if args:
-            args = trim_span_args(args)
+        late: Dict[str, object] = {}
         # child-span bookkeeping only while tracing (the causal ids are
         # for the exported tree; the flight ring needs just the trace id,
         # which it reads from the contextvar itself)
@@ -251,12 +251,14 @@ class Metrics:
         try:
             if ann is not None:
                 with ann, self.wall_timer(name):
-                    yield
+                    yield late
             else:
                 with self.wall_timer(name):
-                    yield
+                    yield late
         finally:
             dur = time.perf_counter() - t0
+            if args or late:
+                args = trim_span_args({**args, **late})
             if rec is not None:
                 ev_args = dict(args) if args else {}
                 if ids is not None:
@@ -275,26 +277,6 @@ class Metrics:
                     ev_args["replica"] = rid
                 rec.complete(name, t0, dur, ev_args or None)
             _flight.recorder().record_span(name, dur, args or None)
-
-    @contextlib.contextmanager
-    def trace(self, name: str) -> Iterator[None]:
-        """Timer + jax.profiler annotation (shows up in TPU traces).
-
-        The profiler import is guarded: on a minimal install without
-        jax (or with a jax lacking the profiler module) this degrades
-        to the plain ``timer`` instead of raising ImportError from a
-        hot loop."""
-        try:
-            from jax.profiler import TraceAnnotation
-            ann = TraceAnnotation(name)
-        except Exception:  # noqa: BLE001 — profiling is optional
-            ann = None
-        if ann is None:
-            with self.timer(name):
-                yield
-        else:
-            with ann, self.timer(name):
-                yield
 
     # -- mesh-wide merge (parallel/distributed.merge_metrics) ----------------
 
@@ -398,12 +380,8 @@ class NullMetrics(Metrics):
         yield
 
     @contextlib.contextmanager
-    def span(self, name: str, **args) -> Iterator[None]:
-        yield
-
-    @contextlib.contextmanager
-    def trace(self, name: str) -> Iterator[None]:
-        yield
+    def span(self, name: str, **args) -> Iterator[Dict[str, object]]:
+        yield {}
 
 
 # ---------------------------------------------------------------------------

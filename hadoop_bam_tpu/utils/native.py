@@ -217,7 +217,7 @@ def load() -> Optional[ctypes.CDLL]:
             lib.hbam_fused_next.argtypes = [ctypes.c_void_p, i64p, i64p]
             lib.hbam_fused_finish.restype = ctypes.c_int
             lib.hbam_fused_finish.argtypes = [
-                ctypes.c_void_p, i64p, i64p, i64p]
+                ctypes.c_void_p, i64p, i64p, i64p, i64p]
         _lib = lib
         return _lib
 
@@ -499,6 +499,9 @@ class FusedJob:
         self.tail = int(start)
         self.n_rows = 0
         self.err_index = -1
+        # core-nanoseconds the workers spent in inflate + walk + pack;
+        # known once they are joined (``finish``)
+        self.busy_ns = 0
 
     def next_chunk(self) -> "Optional[tuple[int, int]]":
         """Block until the next walked row range lands; (row_lo, row_hi),
@@ -518,20 +521,16 @@ class FusedJob:
 
     def finish(self) -> int:
         """Join + free; idempotent.  Returns the final rc (0 or -kind) and
-        populates ``tail``/``n_rows``/``err_index``."""
+        populates ``tail``/``n_rows``/``err_index``/``busy_ns``."""
         if self._h is None:
             return self.rc
-        tail = np.zeros(1, dtype=np.int64)
-        n_rows = np.zeros(1, dtype=np.int64)
-        err_index = np.zeros(1, dtype=np.int64)
+        out = np.zeros(4, dtype=np.int64)   # tail, n_rows, err_index, busy
         rc = self._lib.hbam_fused_finish(
-            self._h, _ptr(tail, ctypes.c_int64),
-            _ptr(n_rows, ctypes.c_int64), _ptr(err_index, ctypes.c_int64))
+            self._h, *(_ptr(out[i:], ctypes.c_int64) for i in range(4)))
         self._h = None
         self.rc = int(rc)
-        self.tail = int(tail[0])
-        self.n_rows = int(n_rows[0])
-        self.err_index = int(err_index[0])
+        self.tail, self.n_rows, self.err_index, self.busy_ns = \
+            (int(v) for v in out)
         return self.rc
 
     close = finish

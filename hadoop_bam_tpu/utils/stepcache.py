@@ -1,4 +1,8 @@
-"""A tiny locked, bounded build-once cache for jitted step functions.
+"""How every device step is built: named, jitted, counted — and, where a
+process may cycle through meshes, cached with a bound.
+
+``named_step`` is the one ``jax.jit`` call of the step builders: it gives
+the program a stable name on the device plane and counts the build.
 
 Both the query engine's overlap predicate and the serve tier's tile
 filter key one compiled step per (mesh, axis) — a process cycling
@@ -10,6 +14,25 @@ from __future__ import annotations
 
 import threading
 from typing import Callable, Dict, Hashable
+
+from hadoop_bam_tpu.utils.metrics import METRICS
+
+
+def named_step(name: str, fn: Callable) -> Callable:
+    """``jax.jit(fn)`` as the program ``hbam_<name>``: the XLA module is
+    then ``jit_hbam_<name>``, which the profiler's device plane carries,
+    so a trace reduction finds the step after a refactor renumbers XLA's
+    fusions.  The identity is the function's NAME, not only a scope
+    inside it: the persistent compile cache's key leaves debug metadata
+    out but not the module name.  ``fn`` is renamed in place, so pass a
+    function built for this step (a ``shard_map`` result, a local def),
+    never a shared one.  Counts ``steps.built.hbam_<name>``: a count that
+    grows with the jobs run is a step re-traced every job."""
+    import jax
+
+    fn.__name__ = fn.__qualname__ = f"hbam_{name}"
+    METRICS.count(f"steps.built.{fn.__name__}")
+    return jax.jit(fn)
 
 
 class BoundedStepCache:

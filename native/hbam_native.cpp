@@ -9,6 +9,7 @@
 //
 // Build: g++ -O3 -march=native -shared -fPIC -pthread hbam_native.cpp -lz
 #include <atomic>
+#include <chrono>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
@@ -780,16 +781,26 @@ struct HbamFusedJob {
   int64_t err_index = -1;        // failing block (1-3) or offset (4-5)
   std::atomic<bool> cancel{false};
   std::atomic<int32_t> next{0};
+  // core-nanoseconds the workers spent in inflate (+ CRC) and in the
+  // walk + pack, lock waits left out: the job's "time busy"
+  std::atomic<int64_t> busy_ns{0};
   std::mutex mu;
   std::condition_variable cv;
   std::deque<HbamFusedChunk> ready;
   std::vector<std::thread> pool;
 };
 
+inline int64_t hbam_now_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+      std::chrono::steady_clock::now().time_since_epoch()).count();
+}
+
 // Walk newly contiguous bytes and pack rows.  Called with ``lk`` held;
 // the walk body runs unlocked (walk_active excludes other walkers while
-// inflation of later chunks proceeds in parallel).
-void hbam_fused_drain(HbamFusedJob* j, std::unique_lock<std::mutex>& lk) {
+// inflation of later chunks proceeds in parallel).  ``walk_ns`` gains the
+// nanoseconds spent in the unlocked walk.
+void hbam_fused_drain(HbamFusedJob* j, std::unique_lock<std::mutex>& lk,
+                      int64_t& walk_ns) {
   if (j->walk_active || j->err_kind) return;
   for (;;) {
     const bool final_pass = j->frontier >= j->n_chunks;
@@ -802,6 +813,7 @@ void hbam_fused_drain(HbamFusedJob* j, std::unique_lock<std::mutex>& lk) {
     int64_t p = j->walk_pos;
     int64_t r = j->rows;
     lk.unlock();
+    const int64_t t_walk = hbam_now_ns();
     int ekind = 0;
     while (p + 4 <= limit && p < j->stop) {
       int32_t bs;
@@ -841,6 +853,7 @@ void hbam_fused_drain(HbamFusedJob* j, std::unique_lock<std::mutex>& lk) {
       ++r;
       p += 4 + static_cast<int64_t>(bs);
     }
+    walk_ns += hbam_now_ns() - t_walk;
     lk.lock();
     const int64_t lo = j->rows;
     j->rows = r;
@@ -889,6 +902,7 @@ void hbam_fused_worker(HbamFusedJob* j) {
                            ? b0 + j->chunk_blocks : j->n_blocks;
     int ekind = 0;
     int64_t eidx = -1;
+    const int64_t t_chunk = hbam_now_ns();
     for (int32_t b = b0; b < b1 && !ekind; ++b) {
 #if defined(HBAM_USE_LIBDEFLATE)
       size_t out_n = 0;
@@ -930,17 +944,20 @@ void hbam_fused_worker(HbamFusedJob* j) {
         if (got != j->expect_crc[b]) { ekind = 3; eidx = b; }
       }
     }
+    int64_t chunk_ns = hbam_now_ns() - t_chunk;
     std::unique_lock<std::mutex> lk(j->mu);
     if (ekind) {
       if (!j->err_kind) { j->err_kind = ekind; j->err_index = eidx; }
       j->cancel.store(true);
       j->cv.notify_all();
+      j->busy_ns.fetch_add(chunk_ns, std::memory_order_relaxed);
       break;
     }
     j->chunk_done[c] = 1;
     while (j->frontier < j->n_chunks && j->chunk_done[j->frontier])
       ++j->frontier;
-    hbam_fused_drain(j, lk);
+    hbam_fused_drain(j, lk, chunk_ns);
+    j->busy_ns.fetch_add(chunk_ns, std::memory_order_relaxed);
   }
 #if defined(HBAM_USE_LIBDEFLATE)
   libdeflate_free_decompressor(d);
@@ -1030,11 +1047,12 @@ int hbam_fused_next(void* h, int64_t* row_lo, int64_t* row_hi) {
 
 // Join workers and free the job.  Returns 0 or -kind; *tail receives the
 // first incomplete record's offset (== stop-trimmed walk end), *n_rows
-// the packed row count, *err_index the failing block/offset on error.
+// the packed row count, *err_index the failing block/offset on error,
+// *busy_ns the core-nanoseconds the workers spent in inflate + walk + pack.
 // Safe to call while workers are still running (cancels outstanding
 // chunks) — but then dst/out arrays are only partially written.
 int hbam_fused_finish(void* h, int64_t* tail, int64_t* n_rows,
-                      int64_t* err_index) {
+                      int64_t* err_index, int64_t* busy_ns) {
   HbamFusedJob* j = static_cast<HbamFusedJob*>(h);
   {
     std::lock_guard<std::mutex> lk(j->mu);
@@ -1046,6 +1064,7 @@ int hbam_fused_finish(void* h, int64_t* tail, int64_t* n_rows,
   if (tail) *tail = j->walk_pos;
   if (n_rows) *n_rows = j->rows;
   if (err_index) *err_index = j->err_index;
+  if (busy_ns) *busy_ns = j->busy_ns.load();
   delete j;
   return rc;
 }

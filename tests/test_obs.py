@@ -166,24 +166,35 @@ def test_trace_save_is_loadable(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Metrics.trace degradation (satellite: no bare import error in hot loops)
+# Metrics.span's profiler annotation (satellite: no bare import error in
+# hot loops; the annotation is the recorder's, resolved once)
 # ---------------------------------------------------------------------------
 
-def test_trace_degrades_without_jax_profiler(monkeypatch):
+def test_span_degrades_without_jax_profiler(monkeypatch):
     import sys
     monkeypatch.setitem(sys.modules, "jax", None)
     monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    rec = enable_tracing()
+    assert rec.annotation("stage.t") is None
     m = Metrics()
-    with m.trace("stage.t"):      # must not raise ImportError
+    with m.span("stage.t"):       # must not raise ImportError
         pass
-    assert m.timer_calls["stage.t"] == 1
+    assert m.wall_calls["stage.t"] == 1
+    assert [e[0] for e in rec.events()] == ["stage.t"]
 
 
-def test_trace_with_profiler_still_times():
+def test_span_with_profiler_annotation_still_times_and_takes_late_args():
+    import jax  # noqa: F401 — the recorder annotates only once jax is in
+
+    rec = enable_tracing()
+    assert rec.annotation("stage.t2") is not None
     m = Metrics()
-    with m.trace("stage.t2"):
-        pass
-    assert m.timer_calls["stage.t2"] == 1
+    with m.span("stage.t2", rows=1) as late:
+        late["bucket"] = 8        # known only when the span ends
+    assert m.wall_calls["stage.t2"] == 1
+    (ev,) = rec.events()
+    assert ev[0] == "stage.t2"
+    assert ev[5]["rows"] == 1 and ev[5]["bucket"] == 8
 
 
 # ---------------------------------------------------------------------------
@@ -428,6 +439,9 @@ def indexed_bam(tmp_path_factory):
 def test_cli_query_trace_and_metrics_json(indexed_bam, tmp_path, capsys):
     from hadoop_bam_tpu.tools import cli
 
+    # --metrics-json snapshots the process-global metrics: start them
+    # from zero, whatever this worker ran before
+    base_metrics().reset()
     trace_path = str(tmp_path / "trace.json")
     snap_path = str(tmp_path / "snap.json")
     rc = cli.main(["query", indexed_bam, "chr1:1-5000", "chr2:1-2000",
