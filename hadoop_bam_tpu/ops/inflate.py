@@ -26,26 +26,35 @@ import numpy as np
 from hadoop_bam_tpu.formats import bgzf
 from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.utils.pools import NO_LEASE, SpanBuffer, SpanBufferPool
 
 
-def block_table(raw: bytes, offset: int = 0) -> dict:
-    """Parse consecutive BGZF block headers into a columnar table."""
-    coffs, cdata_off, cdata_len, isize = [], [], [], []
+def block_table(raw, offset: int = 0) -> dict:
+    """Parse consecutive BGZF block headers into a columnar table.
+
+    The native library walks the header chain in one call; the Python
+    parser below is the whole walk without it, and otherwise starts at
+    the first header the native walk did not accept — so a malformed
+    chain raises ``parse_block_header``'s own ``BGZFError``."""
+    cols = [[], [], [], []]     # coffset, cdata_off, cdata_len, isize
     p = offset
     n = len(raw)
+    head = None
+    if p < n and native.available():
+        head, p = native.block_table(np.frombuffer(raw, dtype=np.uint8), p)
     while p < n:
         info = bgzf.parse_block_header(raw, p)
-        coffs.append(info.coffset)
-        cdata_off.append(info.cdata_offset)
-        cdata_len.append(info.cdata_size)
-        isize.append(info.isize)
+        cols[0].append(info.coffset)
+        cols[1].append(info.cdata_offset)
+        cols[2].append(info.cdata_size)
+        cols[3].append(info.isize)
         p = info.next_coffset
-    return {
-        "coffset": np.asarray(coffs, dtype=np.int64),
-        "cdata_off": np.asarray(cdata_off, dtype=np.int64),
-        "cdata_len": np.asarray(cdata_len, dtype=np.int32),
-        "isize": np.asarray(isize, dtype=np.int32),
-    }
+    dtypes = (np.int64, np.int64, np.int32, np.int32)
+    cols = [np.asarray(c, dtype=dt) for c, dt in zip(cols, dtypes)]
+    if head is not None:
+        cols = [np.concatenate([h, c]) if c.size else h
+                for h, c in zip(head, cols)]
+    return dict(zip(("coffset", "cdata_off", "cdata_len", "isize"), cols))
 
 
 def inflate_span(raw: bytes, table: Optional[dict] = None,
@@ -216,6 +225,18 @@ class FusedSpanDecode:
     ``BGZFError``/``ValueError`` the two-pass path raises; closing the
     stream early (generator abandoned) joins the native workers.
 
+    Who owns the memory.  The packed outputs are always fresh arrays and
+    the caller's to keep: the feed's packer holds views of ``rows``
+    across spans.  ``data`` and ``offsets`` are fresh too unless the
+    caller passes ``buffers`` (a ``SpanBufferPool``), which says neither
+    escapes the span — the streamed drivers, whose consumers see packed
+    rows only.  Then both live in one leased buffer, belong to this
+    decode until ``finish()`` (every way out ends there: a normal end, an
+    error, an early close, ``__del__``) and read ``None`` afterwards.
+    ``raw_lease`` is the lease under ``raw`` when the compressed bytes sit
+    in a pooled buffer; the native job reads them until it is joined, so
+    it goes back at the same point.
+
     Modes: ``"offsets"`` (walk only — callers packing variable-length
     series themselves), ``"rows"`` (fixed-prefix ``sel`` ranges packed
     into ``row_stride``-byte rows), ``"payload"`` (prefix/seq/qual tiles,
@@ -227,21 +248,37 @@ class FusedSpanDecode:
                  sel: Optional[Sequence[Tuple[int, int]]] = None,
                  row_stride: int = 0, max_len: int = 0, seq_stride: int = 0,
                  qual_stride: int = 0, check_crc: bool = False,
-                 chunk_blocks: int = 32, n_threads: int = 0):
+                 chunk_blocks: int = 32, n_threads: int = 0,
+                 buffers: Optional[SpanBufferPool] = None,
+                 raw_lease: SpanBuffer = NO_LEASE):
+        self._buffers = buffers
+        self._leases = [raw_lease]
+        self._job = None
         if table is None:
             table = block_table(raw)
         isize = table["isize"]
         ubase = np.zeros(isize.size + 1, dtype=np.int64)
         np.cumsum(isize, out=ubase[1:])
         total = int(ubase[-1])
-        self.data = np.empty(total, dtype=np.uint8)
+        self.inflated_bytes = total
         self.ubase = ubase[:-1]
         self.stop = total if stop is None else min(int(stop), total)
         self.rows = self.prefix = self.seq = self.qual = None
         src = np.frombuffer(raw, dtype=np.uint8)
         expect = footer_crcs(src, table) if check_crc else None
+        # min on-wire record = 4-byte block_size + 32-byte fixed core
         cap = max(16, (self.stop - start) // 36 + 1)
-        self.offsets = np.empty(cap, dtype=np.int64)
+        if buffers is None:
+            self.data = np.empty(total, dtype=np.uint8)
+            self.offsets = np.empty(cap, dtype=np.int64)
+        else:
+            # ONE buffer for both: two leases a span out of one size
+            # class would ask the class for twice what it keeps
+            lease = buffers.lease(total + 8 + 8 * cap)
+            self._leases.append(lease)
+            self.data = lease.array[:total]
+            off0 = (total + 7) & ~7
+            self.offsets = lease.array[off0:off0 + 8 * cap].view(np.int64)
         mode_id = {"offsets": native.FUSED_OFFSETS,
                    "rows": native.FUSED_ROWS,
                    "payload": native.FUSED_PAYLOAD}[mode]
@@ -260,8 +297,8 @@ class FusedSpanDecode:
                                             dtype=np.uint8)
         self.n_blocks = int(isize.size)
         if self.n_blocks == 0:
-            self._job = None
             self.n_rows, self.tail = 0, int(start)
+            self._release()
             return
         self._job = native.FusedJob(
             src, table["cdata_off"], table["cdata_len"], isize, expect,
@@ -270,6 +307,15 @@ class FusedSpanDecode:
             seq_stride, qual_stride, self.offsets, chunk_blocks, n_threads)
         self.n_rows: Optional[int] = None
         self.tail: Optional[int] = None
+
+    def _release(self) -> None:
+        """Hand every leased buffer back (idempotent).  Only once no
+        native worker runs: they read ``raw`` and write ``data``."""
+        if self._buffers is not None:
+            self.data = self.offsets = None
+        leases, self._leases = self._leases, []
+        for lease in leases:
+            lease.release()
 
     def chunks(self) -> "Iterator[Tuple[int, int]]":
         """Yield ``(row_lo, row_hi)`` as the native walk completes them;
@@ -293,20 +339,35 @@ class FusedSpanDecode:
                 self.finish(check=False)
 
     def finish(self, check: bool = True) -> Tuple[int, int]:
-        """Join the job; returns (n_rows, tail).  ``check=False`` skips
-        raising (the cancellation path)."""
-        if self._job is not None:
-            rc = self._job.finish()
-            self.n_rows, self.tail = self._job.n_rows, self._job.tail
-            idx = self._job.err_index
+        """Join the job and hand the leased buffers back; returns
+        (n_rows, tail).  ``check=False`` skips raising (the cancellation
+        path)."""
+        job, self._job = self._job, None
+        rc, idx = 0, -1
+        if job is not None:
+            rc = job.finish()
+            self.n_rows, self.tail = job.n_rows, job.tail
+            idx = job.err_index
             # the host feed's "time busy", measured where the work
             # happens: core-nanoseconds of inflate + walk + pack
-            METRICS.count("decode.native_busy_ns", self._job.busy_ns)
+            METRICS.count("decode.native_busy_ns", job.busy_ns)
             METRICS.count("decode.native_jobs")
-            self._job = None
-            if check and rc < 0:
-                _raise_fused_error(rc, idx)
+        self._release()
+        if check and rc < 0:
+            _raise_fused_error(rc, idx)
         return self.n_rows, self.tail
+
+    def __del__(self):
+        # Dropped unfinished.  The collector may run this on a thread
+        # that holds any lock, the metrics' among them: join natively,
+        # hand the buffers back (the pool takes no lock), count nothing.
+        try:
+            job, self._job = self._job, None
+            if job is not None:
+                job.finish()
+            self._release()
+        except Exception:  # noqa: BLE001 — interpreter teardown
+            pass
 
     @property
     def err_index(self) -> int:

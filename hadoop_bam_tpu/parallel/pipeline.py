@@ -66,7 +66,8 @@ from hadoop_bam_tpu.utils import errors as hberrors
 from hadoop_bam_tpu.utils.errors import PlanError, classify_error
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import (
-    decode_pool, decode_pool_size, submit as pool_submit,
+    NO_LEASE, SPAN_BUFFERS, SpanBuffer, decode_pool, decode_pool_size,
+    stream_window_cap, submit as pool_submit,
 )
 from hadoop_bam_tpu.utils.resilient import (
     QuarantineManifest, RetryPolicy, RetryingByteSource,
@@ -137,27 +138,57 @@ class HostSpanBatch:
     voffsets: List[np.ndarray]  # per-device per-record virtual offsets
 
 
-def _fetch_span_raw(src, span: FileVirtualSpan) -> Tuple[bytes, int, int]:
+def _fetch_span_raw(src, span: FileVirtualSpan
+                    ) -> Tuple[memoryview, int, int, SpanBuffer]:
     """Fetch one span's compressed bytes: the whole blocks in
     [start_c, end_c) plus the block AT end_c when the span ends inside it
-    (end_u > 0) — reading it up front folds it into one batched-inflate
-    call instead of a per-block Python zlib + whole-buffer concatenate
-    afterwards.  Returns (raw, end_block_size, next_c) where ``next_c`` is
-    the compressed offset of the first block past the fetched bytes."""
+    (end_u > 0), so one block table and one native job cover the span.
+
+    A source that can fill a buffer in place (a local file, or the retry
+    wrapper around one) is read ONCE, [start_c, end_c + MAX_BLOCK_SIZE)
+    clipped to the file, into a buffer leased from the span-buffer pool;
+    the end block's size comes from its header where it lies.  Any other
+    source (in-memory bytes, the chaos wrapper, a remote source) keeps
+    two ``pread``s and a concatenate.  Returns (raw, end_block_size,
+    next_c, lease): ``next_c`` is the compressed offset of the first
+    block past ``raw``; ``lease`` (``NO_LEASE`` on the ``pread`` path) is
+    what ``raw`` lives in — whoever takes ``raw`` releases it once
+    nothing reads ``raw`` any more."""
     from hadoop_bam_tpu.formats import bgzf
 
     start_c, start_u = span.start
     end_c, end_u = span.end
-    with METRICS.span("bam.fetch_wall", nbytes=max(end_c - start_c, 0)):
-        raw = src.pread(start_c, max(end_c - start_c, 0))
-        end_block_size = 0
-        if end_u > 0 and end_c < src.size:
-            head = src.pread(end_c, bgzf.MAX_BLOCK_SIZE)
-            info = bgzf.parse_block_header(head, 0)
-            end_block_size = info.block_size
-            raw = raw + head[:end_block_size]
+    want = max(end_c - start_c, 0)
+    end_block = end_u > 0 and end_c < src.size
+    pread_into = getattr(src, "pread_into", None)
+    lease = NO_LEASE
+    with METRICS.span("bam.fetch_wall", nbytes=want):
+        if pread_into is None:
+            raw = src.pread(start_c, want)
+            end_block_size = 0
+            if end_block:
+                head = src.pread(end_c, bgzf.MAX_BLOCK_SIZE)
+                end_block_size = bgzf.parse_block_header(head, 0).block_size
+                raw = raw + head[:end_block_size]
+            raw = memoryview(raw)
+        else:
+            ask = min(want + (bgzf.MAX_BLOCK_SIZE if end_block else 0),
+                      src.size - start_c)
+            raw, end_block_size = memoryview(b""), 0
+            if ask > 0:
+                lease = SPAN_BUFFERS.lease(ask)
+                try:
+                    buf = memoryview(lease.array)[:ask]
+                    buf = buf[:pread_into(start_c, buf)]
+                    if end_block:
+                        end_block_size = bgzf.parse_block_header(
+                            buf, want).block_size
+                except BaseException:
+                    lease.release()
+                    raise
+                raw = buf[:want + end_block_size]
     next_c = (end_c + end_block_size) if raw else start_c
-    return raw, end_block_size, next_c
+    return raw, end_block_size, next_c, lease
 
 
 def _decode_span_core(source, span: FileVirtualSpan,
@@ -187,20 +218,23 @@ def _decode_span_core(source, span: FileVirtualSpan,
     end_c, end_u = span.end
     METRICS.count("pipeline.spans")
 
-    raw, end_block_size, next_c = _fetch_span_raw(src, span)
+    raw, end_block_size, next_c, lease = _fetch_span_raw(src, span)
     if raw:
-        table = inflate_ops.block_table(raw)
-        with METRICS.timer("pipeline.inflate"), \
-                METRICS.span("bam.inflate_wall", nbytes=len(raw)):
-            data, ubase = inflate_ops.inflate_span(raw, table,
-                                                   backend=inflate_backend)
-        METRICS.count("pipeline.blocks", int(table["isize"].size))
-        METRICS.count("pipeline.inflated_bytes", int(data.size))
-        if check_crc:
-            # a separate third sweep over the inflated bytes — the fused
-            # path folds this into its single visit for ~free
-            with METRICS.timer("pipeline.crc"):
-                inflate_ops.verify_crcs(raw, table, data, ubase)
+        try:
+            table = inflate_ops.block_table(raw)
+            with METRICS.timer("pipeline.inflate"), \
+                    METRICS.span("bam.inflate_wall", nbytes=len(raw)):
+                data, ubase = inflate_ops.inflate_span(
+                    raw, table, backend=inflate_backend)
+            METRICS.count("pipeline.blocks", int(table["isize"].size))
+            METRICS.count("pipeline.inflated_bytes", int(data.size))
+            if check_crc:
+                # a separate third sweep over the inflated bytes — the
+                # fused path folds this into its single visit for ~free
+                with METRICS.timer("pipeline.crc"):
+                    inflate_ops.verify_crcs(raw, table, data, ubase)
+        finally:
+            lease.release()     # nothing below reads the compressed bytes
         abs_coffs = table["coffset"] + start_c
     else:
         data = np.empty(0, dtype=np.uint8)
@@ -338,7 +372,7 @@ def _stream_window(window: int) -> int:
     span is a live multi-threaded native job (the pool task only fetches
     and starts it), so the pool-sized window that bounds buffered decodes
     would oversubscribe the host several-fold here."""
-    return min(window, max(2, 2 * (os.cpu_count() or 1)))
+    return min(window, stream_window_cap())
 
 
 def _fused_off(config: Optional[HBamConfig]) -> HBamConfig:
@@ -353,34 +387,44 @@ def _start_fused_span(src, span: FileVirtualSpan, mode: str, *,
                       sel=None, row_bytes: int = 0,
                       geometry: "Optional[PayloadGeometry]" = None,
                       check_crc: bool = False,
-                      config: Optional[HBamConfig] = None):
+                      config: Optional[HBamConfig] = None,
+                      data_escapes: bool = True):
     """Fetch one span and start its fused native decode job.
 
     The fetch runs HERE, on the caller's thread — transient I/O faults
     surface inside the decode_with_retry boundary even when the chunk
-    stream is consumed later.  Returns (dec, end_inflated, next_c, table)
-    or None for an empty span (the two-pass path disposes of those)."""
-    raw, end_block_size, next_c = _fetch_span_raw(src, span)
+    stream is consumed later.  ``data_escapes=False`` (the streamed
+    callers: their consumers see packed rows only) lets the decode lease
+    its inflated bytes and offsets from the span-buffer pool until its
+    ``finish()``.  Returns (dec, end_inflated, next_c, table) or None for
+    an empty span (the two-pass path disposes of those)."""
+    raw, end_block_size, next_c, lease = _fetch_span_raw(src, span)
     if not raw:
         return None
-    table = inflate_ops.block_table(raw)
-    isize = table["isize"]
-    total = int(isize.sum())
-    end_inflated = (total - int(isize[-1]) + span.end[1]) if end_block_size \
-        else total
-    cfg = config if config is not None else DEFAULT_CONFIG
-    kwargs = {}
-    if mode == "rows":
-        kwargs = dict(sel=sel, row_stride=row_bytes)
-    elif mode == "payload":
-        kwargs = dict(max_len=geometry.max_len,
-                      seq_stride=geometry.seq_stride,
-                      qual_stride=geometry.qual_stride)
-    dec = inflate_ops.FusedSpanDecode(
-        raw, table, start=span.start[1], stop=end_inflated, mode=mode,
-        check_crc=check_crc,
-        chunk_blocks=max(1, int(cfg.decode_chunk_blocks)),
-        **kwargs)
+    try:
+        table = inflate_ops.block_table(raw)
+        isize = table["isize"]
+        total = int(isize.sum())
+        end_inflated = (total - int(isize[-1]) + span.end[1]) \
+            if end_block_size else total
+        cfg = config if config is not None else DEFAULT_CONFIG
+        kwargs = {}
+        if mode == "rows":
+            kwargs = dict(sel=sel, row_stride=row_bytes)
+        elif mode == "payload":
+            kwargs = dict(max_len=geometry.max_len,
+                          seq_stride=geometry.seq_stride,
+                          qual_stride=geometry.qual_stride)
+        # the decode owns ``lease`` from here: its finish() releases it
+        dec = inflate_ops.FusedSpanDecode(
+            raw, table, start=span.start[1], stop=end_inflated, mode=mode,
+            check_crc=check_crc,
+            chunk_blocks=max(1, int(cfg.decode_chunk_blocks)),
+            buffers=None if data_escapes else SPAN_BUFFERS,
+            raw_lease=lease, **kwargs)
+    except BaseException:
+        lease.release()
+        raise
     return dec, end_inflated, next_c, table
 
 
@@ -389,7 +433,7 @@ def _fused_span_counts(dec, table, n: int) -> None:
     these itself; a fused span that falls back must not double-count)."""
     METRICS.count("pipeline.spans")
     METRICS.count("pipeline.blocks", int(table["isize"].size))
-    METRICS.count("pipeline.inflated_bytes", int(dec.data.size))
+    METRICS.count("pipeline.inflated_bytes", dec.inflated_bytes)
     METRICS.count("pipeline.records", n)
 
 
@@ -488,7 +532,8 @@ def _iter_fused_span_chunks(src, span: FileVirtualSpan, mode: str, *,
     src = as_byte_source(src)
     started = _start_fused_span(src, span, mode, sel=sel,
                                 row_bytes=row_bytes, geometry=geometry,
-                                check_crc=check_crc, config=config)
+                                check_crc=check_crc, config=config,
+                                data_escapes=False)
 
     def slices(lo: int, hi: int) -> Tuple[np.ndarray, ...]:
         if mode == "rows":
@@ -513,7 +558,7 @@ def _iter_fused_span_chunks(src, span: FileVirtualSpan, mode: str, *,
                     METRICS.wall_timer("pipeline.host_decode_wall"), \
                     METRICS.timer("pipeline.fused_decode"), \
                     METRICS.span("bam.fused_decode_wall",
-                                 nbytes=int(dec.data.size)):
+                                 nbytes=dec.inflated_bytes):
                 for lo, hi in dec.chunks():
                     now = time.perf_counter()
                     # per-chunk handoff latency: the stall a staging
@@ -901,13 +946,19 @@ def _span_retry_policy(config: HBamConfig) -> RetryPolicy:
 
 
 def _resilient_source(path, config: HBamConfig):
-    """What the decode stages should read through: the plain path, or a
-    RetryingByteSource wrap when ``config.io_read_retries`` asks for
-    read-level retries (backoff + per-read deadline under the span grain)."""
+    """What a driver's decode stages read through, opened ONCE a scan and
+    shared by its pool tasks (positioned reads keep no seek state): the
+    file's byte source — through the chaos registry's wrapper when one is
+    installed for the path — inside a RetryingByteSource when
+    ``config.io_read_retries`` asks for read-level retries (backoff +
+    per-read deadline under the span grain).  Nobody closes it: a pool
+    task abandoned by an early close may still read, so the descriptor
+    goes when the last task drops the source."""
+    src = as_byte_source(path)
     r = int(config.io_read_retries or 0)
     if r <= 0 or not isinstance(path, (str, os.PathLike)):
-        return path
-    return RetryingByteSource(path, RetryPolicy(
+        return src
+    return RetryingByteSource(src, RetryPolicy(
         retries=r,
         backoff_base_s=float(config.retry_backoff_base_s),
         backoff_max_s=float(config.retry_backoff_max_s),
@@ -2002,14 +2053,23 @@ def _tokenize_span_tokens(src, span: FileVirtualSpan,
     BGZF-level faults (DEFLATE corruption, ISIZE, CRC) raise BGZFError
     HERE, inside the retry boundary — exactly where the host planes
     raise them.  Returns None for an empty span."""
-    from hadoop_bam_tpu.ops.inflate_device import ladder_pow2
-    from hadoop_bam_tpu.utils import native
-
     src = as_byte_source(src)
-    raw, end_block_size, _next_c = _fetch_span_raw(src, span)
+    raw, end_block_size, _next_c, lease = _fetch_span_raw(src, span)
     METRICS.count("pipeline.spans")
     if not raw:
         return None
+    try:
+        return _tokenize_fetched(raw, end_block_size, span, check_crc)
+    finally:
+        lease.release()     # the token arrays are copies of what they need
+
+
+def _tokenize_fetched(raw, end_block_size: int, span: FileVirtualSpan,
+                      check_crc: bool) -> _TokenChunk:
+    """``_tokenize_span_tokens`` past the fetch: ``raw`` is read, not kept."""
+    from hadoop_bam_tpu.ops.inflate_device import ladder_pow2
+    from hadoop_bam_tpu.utils import native
+
     table = inflate_ops.block_table(raw)
     isize = table["isize"]
     n = int(isize.size)
@@ -2678,10 +2738,12 @@ def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
     host_backend = decision.host_backend
 
     if spans is None:
-        # Span size trades host-decode parallelism (smaller = more threads
-        # busy) against per-span Python overhead; tiles repack across span
-        # boundaries, so this does NOT couple to the device geometry.
-        # 4 MiB measured best on a 1-CPU host (sweep in commit history).
+        # Span size trades host-decode parallelism (smaller = more spans
+        # in flight) against the per-span start (one read, one header
+        # walk, one native job: PERF.md section 5); tiles repack across
+        # span boundaries, so this does NOT couple to the device
+        # geometry.  4 MiB is the size every chip number in PERF.md was
+        # taken at; it has not been swept on that host.
         span_bytes = 4 << 20
         src = as_byte_source(path)
         n_spans = max(n_dev, int(np.ceil(src.size / span_bytes)))

@@ -193,6 +193,10 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_crc32_batch.restype = ctypes.c_int
         lib.hbam_crc32_batch.argtypes = [
             i8p, i64p, i32p, ctypes.c_int32, u32p, ctypes.c_int32]
+        lib.hbam_block_table.restype = ctypes.c_int64
+        lib.hbam_block_table.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, i64p, i64p, i32p, i32p,
+            ctypes.c_int64, i64p]
         lib.hbam_deflate_batch.restype = ctypes.c_int
         lib.hbam_deflate_batch.argtypes = [
             i8p, i64p, i32p, ctypes.c_int32, i8p, i64p, i32p, i32p,
@@ -242,6 +246,38 @@ def inflate_batch(src: np.ndarray, cdata_off: np.ndarray,
         _ptr(isize, ctypes.c_int32), n_threads)
     if rc:
         raise ValueError(f"native inflate failed at block {rc - 1000}")
+
+
+def block_table(src: np.ndarray, offset: int = 0
+                ) -> "tuple[tuple[np.ndarray, ...], int]":
+    """Native walk of the BGZF header chain in ``src[offset:]`` (the
+    interpreter lock is released for the call).  Returns ((coffset i64,
+    cdata_off i64, cdata_len i32, isize i32), stop): ``stop`` is
+    ``src.size`` after a clean walk, else the offset of the first header
+    the walk did not accept — the caller's Python parser names the fault
+    from there."""
+    lib = load()
+    assert lib is not None
+    n = int(src.size)
+    # a BGZF block is seldom under 4 KiB; a table that fills is doubled
+    cap = max(64, (n - offset) >> 12)
+    parts = []
+    stop = np.full(1, offset, dtype=np.int64)
+    while True:
+        cols = (np.empty(cap, np.int64), np.empty(cap, np.int64),
+                np.empty(cap, np.int32), np.empty(cap, np.int32))
+        k = int(lib.hbam_block_table(
+            _ptr(src, ctypes.c_uint8), n, int(stop[0]),
+            _ptr(cols[0], ctypes.c_int64), _ptr(cols[1], ctypes.c_int64),
+            _ptr(cols[2], ctypes.c_int32), _ptr(cols[3], ctypes.c_int32),
+            cap, _ptr(stop, ctypes.c_int64)))
+        parts.append(tuple(c[:k] for c in cols))
+        if k < cap or int(stop[0]) >= n:
+            break
+        cap *= 2
+    if len(parts) > 1:
+        parts = [tuple(np.concatenate(c) for c in zip(*parts))]
+    return parts[0], int(stop[0])
 
 
 def walk_bam_records(buf: np.ndarray, start: int, cap: int

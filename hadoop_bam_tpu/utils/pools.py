@@ -20,9 +20,12 @@ import contextvars
 import os
 import threading
 import time
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
+
+import numpy as np
 
 from hadoop_bam_tpu.resilience import chaos
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 _LOCK = threading.Lock()
 _POOL: Optional[cf.ThreadPoolExecutor] = None
@@ -100,7 +103,6 @@ def result_with_timeout(fut: cf.Future, timeout_s: Optional[float],
         return fut.result(timeout=timeout_s)
     except cf.TimeoutError:
         from hadoop_bam_tpu.utils.errors import TransientIOError
-        from hadoop_bam_tpu.utils.metrics import METRICS
         METRICS.count("pool.task_timeouts")
         fut.cancel()
         raise TransientIOError(
@@ -140,7 +142,6 @@ def submit(pool: cf.ThreadPoolExecutor, fn, *args,
     if priority == "fg":
         return pool.submit(ctx.run, _timed_task, fn, t_submit, args, kwargs)
     fut: cf.Future = cf.Future()
-    from hadoop_bam_tpu.utils.metrics import METRICS
     METRICS.count("pool.bg_submitted")
     with _BG_LOCK:
         _BG_QUEUE.append((pool, fut, ctx, fn, t_submit, args, kwargs))
@@ -223,7 +224,6 @@ def cancel_background() -> int:
             _p, fut, *_rest = _BG_QUEUE.popleft()
             if fut.cancel():
                 cancelled += 1
-    from hadoop_bam_tpu.utils.metrics import METRICS
     if cancelled:
         METRICS.count("pool.bg_cancelled", cancelled)
     return cancelled
@@ -263,3 +263,94 @@ def set_decode_pool(pool: Optional[cf.ThreadPoolExecutor],
         _POOL_SIZE = 0 if pool is None else int(
             size if size is not None else getattr(pool, "_max_workers", 1))
         return prev, prev_size
+
+
+# ---------------------------------------------------------------------------
+# span buffers (the host feed's recycled span-start memory)
+# ---------------------------------------------------------------------------
+
+def stream_window_cap() -> int:
+    """Most spans a STREAMED fused decode keeps in flight (each is a live
+    multi-threaded native job): twice the host's CPUs, at least 2."""
+    return max(2, 2 * (os.cpu_count() or 1))
+
+
+class SpanBuffer:
+    """One leased buffer: ``array`` (uint8, its class's full size) is the
+    holder's alone until ``release()``, which clears it.  A second
+    release is a no-op, so every exit path may call it."""
+
+    __slots__ = ("array", "_pool")
+
+    def __init__(self, array: Optional[np.ndarray],
+                 pool: Optional["SpanBufferPool"]):
+        self.array = array
+        self._pool = pool
+
+    def release(self) -> None:
+        buf, self.array = self.array, None
+        if buf is not None:
+            self._pool._give_back(buf)
+
+
+class SpanBufferPool:
+    """Recycled ``uint8`` buffers for what a span start used to allocate
+    fresh: the compressed bytes, the inflated bytes and the record-offset
+    scratch of a span (parallel/pipeline.py ``_fetch_span_raw``,
+    ops/inflate.py ``FusedSpanDecode``).  Each of those sits above the
+    allocator's mmap threshold, so a fresh one is faulted in page by page
+    on first touch and unmapped on free — ~2 GB a 4.19 M-record scan.
+
+    One rule: a request rounds up to a power of two, 1 MiB at least, and
+    that size is its class.  A class keeps at most ``max_free`` returned
+    buffers — what the streamed feed holds in flight, plus two — and
+    drops the oldest beyond that; a class above 64 MiB is never kept.  A
+    buffer comes back dirty: holders write before they read.
+
+    No lock: a class's free list is a bounded deque, whose append and pop
+    are atomic, because a decode dropped unfinished returns its buffers
+    from ``__del__``, which the collector may run on a thread that is
+    inside this pool.  Counters (on the leasing thread):
+    ``feed.span_buffers_reused``, ``feed.span_buffers_minted``,
+    ``feed.span_fresh_bytes`` (bytes of every buffer minted)."""
+
+    MIN_CLASS = 1 << 20
+    MAX_KEPT_CLASS = 1 << 26
+
+    def __init__(self, max_free: Optional[int] = None):
+        self.max_free = stream_window_cap() + 2 if max_free is None \
+            else int(max_free)
+        self._free: Dict[int, "collections.deque[np.ndarray]"] = {}
+
+    @classmethod
+    def size_class(cls, nbytes: int) -> int:
+        return max(cls.MIN_CLASS, 1 << max(0, int(nbytes) - 1).bit_length())
+
+    def lease(self, nbytes: int) -> SpanBuffer:
+        size = self.size_class(nbytes)
+        try:
+            buf = self._free[size].pop()
+            METRICS.count("feed.span_buffers_reused")
+        except (KeyError, IndexError):
+            buf = np.empty(size, dtype=np.uint8)
+            METRICS.count("feed.span_buffers_minted")
+            METRICS.count("feed.span_fresh_bytes", size)
+        return SpanBuffer(buf, self)
+
+    def _give_back(self, buf: np.ndarray) -> None:
+        if buf.size <= self.MAX_KEPT_CLASS:
+            free = self._free.get(buf.size)
+            if free is None:
+                free = self._free.setdefault(
+                    buf.size, collections.deque(maxlen=self.max_free))
+            free.append(buf)
+
+    def free_counts(self) -> Dict[int, int]:
+        """{class size: buffers held free}."""
+        return {k: len(v) for k, v in list(self._free.items()) if v}
+
+
+# the process-wide pool, like the decode pool above: one feed, one pool
+SPAN_BUFFERS = SpanBufferPool()
+# the lease of bytes that live in no pooled buffer: releasing it does nothing
+NO_LEASE = SpanBuffer(None, None)

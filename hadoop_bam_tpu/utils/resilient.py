@@ -118,11 +118,20 @@ class RetryingByteSource(ByteSource):
         self.policy = policy or RetryPolicy()
         self.size = self.inner.size
         self.path = getattr(self.inner, "path", None)
+        if self.inner.pread_into is not None:
+            self.pread_into = self._pread_into
 
     def pread(self, offset: int, size: int) -> bytes:
         return call_with_retry(
             lambda: self.inner.pread(offset, size), self.policy,
             what=f"pread({offset}, {size}) on {self.path or self.inner!r}",
+            counter="io.read_retries")
+
+    def _pread_into(self, offset: int, buf) -> int:
+        return call_with_retry(
+            lambda: self.inner.pread_into(offset, buf), self.policy,
+            what=f"preadv({offset}, {len(buf)}) on "
+                 f"{self.path or self.inner!r}",
             counter="io.read_retries")
 
     def close(self) -> None:

@@ -30,6 +30,11 @@ class ByteSource:
     def pread(self, offset: int, size: int) -> bytes:
         raise NotImplementedError
 
+    # ``pread_into(offset, buf) -> int``: fill a caller's writable buffer
+    # in place and return the bytes read (short only at the end of the
+    # source).  None where a source cannot: its callers use ``pread``.
+    pread_into = None
+
     def close(self) -> None:
         pass
 
@@ -63,6 +68,22 @@ class FileByteSource(ByteSource):
             raise TransientIOError(
                 f"pread({offset}, {size}) failed on {self.path}: {e}"
             ) from e
+
+    def pread_into(self, offset: int, buf) -> int:
+        mv = memoryview(buf)
+        got = 0
+        try:
+            while got < len(mv) and offset + got < self.size:
+                n = os.preadv(self._fd, [mv[got:]], offset + got)
+                if n <= 0:
+                    break
+                got += n
+        except OSError as e:
+            from hadoop_bam_tpu.utils.errors import TransientIOError
+            raise TransientIOError(
+                f"preadv({offset}, {len(mv)}) failed on {self.path}: {e}"
+            ) from e
+        return got
 
     def close(self) -> None:
         if self._fd >= 0:

@@ -207,6 +207,59 @@ int64_t hbam_walk_bam_payload(const uint8_t* buf, int64_t n, int64_t start,
   return count;
 }
 
+// Walk the chain of BGZF block headers in src[offset, n): the columnar
+// block table of a compressed span in one call (ops/inflate.py
+// block_table).  Accepts exactly the headers formats/bgzf.py
+// parse_block_header accepts — gzip magic with FEXTRA, every FEXTRA
+// subfield walked for the BC subfield, BSIZE covering header + footer, the
+// whole block inside the buffer, ISIZE <= 64 KiB — and writes one row per
+// block.  Stops at the first header it does not accept, or when ``cap`` rows
+// are written; *stop receives that header's offset (n after a clean walk).
+// It names no fault: the caller re-parses from *stop in Python, which
+// raises the error the Python walk always raised.  Returns the row count.
+int64_t hbam_block_table(const uint8_t* src, int64_t n, int64_t offset,
+                         int64_t* coffset, int64_t* cdata_off,
+                         int32_t* cdata_len, int32_t* isize, int64_t cap,
+                         int64_t* stop) {
+  auto u16 = [&](int64_t p) {
+    return static_cast<int64_t>(src[p]) | (static_cast<int64_t>(src[p + 1]) << 8);
+  };
+  int64_t count = 0;
+  int64_t p = offset;
+  while (p < n && count < cap) {
+    if (n - p < 18) break;                             // truncated header
+    if (src[p] != 0x1f || src[p + 1] != 0x8b || src[p + 2] != 0x08 ||
+        src[p + 3] != 0x04) break;                     // bad magic / flags
+    const int64_t xtra_end = p + 12 + u16(p + 10);
+    if (n < xtra_end) break;                           // truncated FEXTRA
+    int64_t bsize = -1;
+    for (int64_t q = p + 12; q + 4 <= xtra_end; q += 4 + u16(q + 2)) {
+      if (src[q] == 66 && src[q + 1] == 67 && u16(q + 2) == 2) {
+        if (q + 6 <= n) bsize = u16(q + 4);
+        break;
+      }
+    }
+    if (bsize < 0) break;                              // no BC subfield
+    const int64_t block_size = bsize + 1;
+    if (block_size < xtra_end - p + 8) break;          // BSIZE too small
+    if (n - p < block_size) break;                     // truncated body
+    const int64_t end = p + block_size;
+    const uint32_t isz = static_cast<uint32_t>(src[end - 4]) |
+                         (static_cast<uint32_t>(src[end - 3]) << 8) |
+                         (static_cast<uint32_t>(src[end - 2]) << 16) |
+                         (static_cast<uint32_t>(src[end - 1]) << 24);
+    if (isz > 0x10000u) break;                         // ISIZE > 64 KiB
+    coffset[count] = p;
+    cdata_off[count] = xtra_end;
+    cdata_len[count] = static_cast<int32_t>(block_size - (xtra_end - p) - 8);
+    isize[count] = static_cast<int32_t>(isz);
+    ++count;
+    p = end;
+  }
+  if (stop) *stop = p;
+  return count;
+}
+
 // CRC32 of a batch of byte ranges (BGZF block payload validation), threaded.
 // Returns 0; crcs[i] receives the zlib CRC32 of data[off[i] .. off[i]+len[i]).
 int hbam_crc32_batch(const uint8_t* data, const int64_t* off,

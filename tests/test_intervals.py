@@ -278,8 +278,9 @@ def test_bai_chunk_ends_are_block_aligned(tmp_path):
     spans = plan_interval_spans(path, [iv], header, bai=idx)
     assert spans
     for span in spans:
-        raw, _end_block, _next_c = _fetch_span_raw(src, span)
+        raw, _end_block, _next_c, lease = _fetch_span_raw(src, span)
         table = inflate_ops.block_table(raw)   # raises on mid-block ends
+        lease.release()
         assert int(table["isize"].sum()) > 0
     src.close()
 

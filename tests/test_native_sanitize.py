@@ -19,9 +19,9 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The subprocess body: build fixtures in memory and push them through every
-# native entry point (inflate, CRC, record walks, packed/payload walks,
-# deflate, rANS 4x8 + Nx16, DEFLATE tokenize).  Multi-threaded calls are
-# explicit so ASan sees the pthread paths.  It then drives the two
+# native entry point (BGZF header walk, inflate, CRC, record walks,
+# packed/payload walks, deflate, rANS 4x8 + Nx16, DEFLATE tokenize).
+# Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
 # reused ring slots) and a two-replica serving fleet over real TCP
@@ -52,6 +52,20 @@ raw = sink.getvalue()
 
 from hadoop_bam_tpu.ops import inflate as inflate_ops
 table = inflate_ops.block_table(raw)
+# the native BGZF header walk: the clean chain, a table that fills and is
+# resumed, and the refusals — a buffer cut anywhere inside the first two
+# blocks must stop the walk at a block start without reading past the cut
+cols, stop = native.block_table(np.frombuffer(raw, np.uint8), 0)
+assert stop == len(raw) and (cols[0] == table["coffset"]).all()
+assert (cols[3] == table["isize"]).all()
+many, stop = native.block_table(np.frombuffer(raw[-28:] * 200, np.uint8), 0)
+assert many[0].size == 200 and stop == 28 * 200
+starts = (0, int(table["coffset"][1]), int(table["coffset"][2]))
+for cut in list(range(40)) + list(range(40, starts[2], 97)):
+    part, stop = native.block_table(
+        np.frombuffer(bytes(raw[:cut]), np.uint8), 0)
+    assert stop == max(s for s in starts if s <= cut) or stop == cut == 0
+    assert part[0].size == starts.index(stop)
 data, ubase = inflate_ops.inflate_span(raw, table, backend="native",
                                        n_threads=4)
 inflate_ops.verify_crcs(raw, table, data, ubase, n_threads=4)
