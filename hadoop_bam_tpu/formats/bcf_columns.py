@@ -54,6 +54,7 @@ from hadoop_bam_tpu.formats.bcf import (
     T_INT32, T_MISSING, _INT_EOV, _INT_MISSING,
 )
 from hadoop_bam_tpu.formats.vcf import VCFHeader
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 # FLAG bits shared with parallel/variant_pipeline.py
 FLAG_PASS = 1
@@ -398,6 +399,47 @@ def decode_bcf_cursor_meta(buf: bytes, header: VCFHeader,
     return {"n": n, "starts": starts, "flags": flags, "gt_groups": groups}
 
 
+# GT values gathered at a time: the gather's index matrix and the widened
+# genotypes are int64, so a whole span of a cohort-wide file at once was
+# gigabytes of temporaries a pool thread (2,504 samples x 26,000 records:
+# 1 GB each); a slab keeps them near the cache whatever the span holds
+_GT_SLAB_VALUES = 1 << 20
+
+
+def _gt_group_dosage(b: np.ndarray, rows: np.ndarray, offs: np.ndarray,
+                     typ_g: int, cnt: int, ns: int,
+                     dosage: np.ndarray) -> None:
+    """ALT dosage of the records ``rows`` (one GT layout: type ``typ_g``,
+    ploidy ``cnt``, ``ns`` samples; payloads at ``offs``) written into
+    their ``dosage`` rows."""
+    dt = _GT_DTYPES[typ_g]
+    span = np.arange(dt.itemsize * cnt * ns)
+    slab = max(1, _GT_SLAB_VALUES // max(1, cnt * ns))
+    for lo in range(0, rows.size, slab):
+        part = rows[lo:lo + slab]
+        raw = b[offs[lo:lo + slab, None] + span]
+        # the genotypes stay in their own width (int8 for every cohort
+        # with under 64 alleles a site; nothing below leaves its range),
+        # and the ploidy axis is walked, not reduced: NumPy's reductions
+        # over an axis of 2 were four fifths of a slab's time
+        g = raw.view(dt).reshape(part.size, ns, cnt)
+        any_present = np.zeros((part.size, ns), bool)
+        any_missing = np.zeros((part.size, ns), bool)
+        n_alt = np.zeros((part.size, ns), np.int16)
+        for k in range(cnt):
+            gk = g[:, :, k]
+            present = gk != _INT_EOV[typ_g]         # pre-EOV entries
+            # allele index = (g >> 1) - 1; masking the phase bit is
+            # required: a phased missing allele ('0|.') encodes as 1
+            allele = gk >> 1
+            any_present |= present
+            any_missing |= present & ((allele == 0)
+                                      | (gk == _INT_MISSING[typ_g]))
+            n_alt += present & (allele > 1)
+        d = np.where(any_present & ~any_missing, n_alt, -1)
+        dosage[part, :ns] = np.minimum(d, 127).astype(np.int8)
+
+
 def _decode_columns(buf: bytes, header: VCFHeader, samples_pad: int,
                     starts: Optional[np.ndarray]) -> Dict[str, np.ndarray]:
     b = np.frombuffer(buf, np.uint8)
@@ -424,27 +466,14 @@ def _decode_columns(buf: bytes, header: VCFHeader, samples_pad: int,
     if bool((have & (n_sample > samples_pad)).any()):
         raise _Ineligible("record carries more samples than the tile")
     if bool(have.any()):
-        combo = (gt_typ << 48) | (gt_count << 24) | n_sample
-        for c in np.unique(combo[have]):
-            sel = have & (combo == c)
-            rows = np.flatnonzero(sel)
-            typ_g = int(gt_typ[rows[0]])
-            cnt = int(gt_count[rows[0]])
-            ns = int(n_sample[rows[0]])
-            dt = _GT_DTYPES[typ_g]
-            w = dt.itemsize
-            raw = b[gt_off[rows, None] + np.arange(w * cnt * ns)]
-            g = raw.view(dt).reshape(rows.size, ns, cnt).astype(np.int64)
-            present = g != _INT_EOV[typ_g]          # pre-EOV entries
-            # allele index = (g >> 1) - 1; masking the phase bit is
-            # required: a phased missing allele ('0|.') encodes as 1
-            missing = present & (((g >> 1) == 0)
-                                 | (g == _INT_MISSING[typ_g]))
-            alt = present & (((g >> 1) - 1) > 0)
-            d = np.where(present.any(axis=2) & ~missing.any(axis=2),
-                         alt.sum(axis=2), -1)
-            dosage[rows[:, None], np.arange(ns)] = \
-                np.minimum(d, 127).astype(np.int8)
+        with METRICS.span("vcf.gt_dosage_wall"):
+            combo = (gt_typ << 48) | (gt_count << 24) | n_sample
+            for c in np.unique(combo[have]):
+                rows = np.flatnonzero(have & (combo == c))
+                r0 = rows[0]
+                _gt_group_dosage(b, rows, gt_off[rows], int(gt_typ[r0]),
+                                 int(gt_count[r0]), int(n_sample[r0]),
+                                 dosage)
 
     return {
         "chrom": chrom.astype(np.int32),

@@ -576,9 +576,8 @@ def distributed_variant_stats(path: str, config=None, header=None):
     weighted by n_af; per-sample call rates by n_variants."""
     from hadoop_bam_tpu.api.vcf_dataset import open_vcf
     from hadoop_bam_tpu.config import DEFAULT_CONFIG
-    from hadoop_bam_tpu.parallel.pipeline import pipeline_span_count
     from hadoop_bam_tpu.parallel.variant_pipeline import (
-        variant_stats_file,
+        variant_span_count, variant_stats_file,
     )
 
     config = DEFAULT_CONFIG if config is None else config
@@ -590,7 +589,7 @@ def distributed_variant_stats(path: str, config=None, header=None):
     n_samples = header.n_samples
 
     def plan():
-        n = pipeline_span_count(path, jax.device_count(), config)
+        n = variant_span_count(ds, jax.device_count(), config)
         return ds.spans(num_spans=n)
 
     def local(mine):

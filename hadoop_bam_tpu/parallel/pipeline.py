@@ -1712,7 +1712,8 @@ def make_read_stats_step(mesh: Mesh, geometry: PayloadGeometry,
 # text read-format extensions recognized by the payload stats dispatch
 # (single source of truth — the CLI imports these)
 def pipeline_span_count(path, n_dev: int,
-                        config: HBamConfig = DEFAULT_CONFIG) -> int:
+                        config: HBamConfig = DEFAULT_CONFIG,
+                        size_scale: float = 1.0) -> int:
     """Span count at the PIPELINE grain for a whole-file stats driver.
 
     config.split_size is the HDFS-style job grain (128 MiB default); a
@@ -1722,7 +1723,9 @@ def pipeline_span_count(path, n_dev: int,
     size configured SMALLER than the pipeline default (a memory bound)
     while still slicing big-grain configs fine enough to overlap.
     Sized via as_byte_source so non-local byte sources keep pipelining;
-    unsizable sources fall back to one span per device.
+    unsizable sources fall back to one span per device.  ``size_scale``
+    weighs the file's bytes (the variant driver's: a file that deflates
+    far better than the grain assumes, variant_span_count).
     """
     grain = float(max(1, min(int(config.split_size), 4 << 20)))
     try:
@@ -1730,7 +1733,7 @@ def pipeline_span_count(path, n_dev: int,
             size = src.size
     except Exception:  # noqa: BLE001 — planning must not fail the driver
         return n_dev
-    return max(n_dev, int(np.ceil(size / grain)))
+    return max(n_dev, int(np.ceil(size * size_scale / grain)))
 
 
 FASTQ_EXTS = (".fastq", ".fq", ".fastq.gz", ".fq.gz")

@@ -459,9 +459,10 @@ class FeedPipeline:
     # -- consumer side (the caller's thread) --------------------------------
 
     def _slots(self, stream: Iterable[Tuple[np.ndarray, ...]]
-               ) -> Iterator[Tuple[RingSlot, Tuple[np.ndarray, ...]]]:
-        """Yield leased ``(slot, bucket_views)`` pairs; the slot is
-        released when the generator is advanced (or closed) — the
+               ) -> Iterator[Tuple[RingSlot, Tuple[np.ndarray, ...],
+                                   np.ndarray]]:
+        """Yield leased ``(slot, bucket_views, counts)`` triples; the
+        slot is released when the generator is advanced (or closed) — the
         depth-2 contract lives here."""
         import jax
 
@@ -471,7 +472,10 @@ class FeedPipeline:
         # instead zero-copy alias a suitably aligned host buffer: the
         # "transfer" is ready at once while the step launched after it
         # still reads the slot — so there each group is dispatched from
-        # a private copy that nothing overwrites.
+        # a private copy that nothing overwrites.  The slot's COUNTS are
+        # ring memory too (a step that ran late read the count the packer
+        # had written for a later group and dropped the difference in
+        # rows), so they are copied with the rows.
         cpu_backend = jax.default_backend() == "cpu"
         ring = StagingRing(self.n_dev, self.cap, self.specs,
                            self.ring_slots)
@@ -512,10 +516,12 @@ class FeedPipeline:
                     break
                 slot, bucket = item
                 arrays = tuple(a[:, :bucket] for a in slot.arrays)
+                counts = slot.counts
                 if cpu_backend:
                     arrays = tuple(np.array(a) for a in arrays)
+                    counts = np.array(counts)
                 try:
-                    yield slot, arrays
+                    yield slot, arrays, counts
                 finally:
                     slot.release()
         finally:
@@ -536,8 +542,8 @@ class FeedPipeline:
         in-flight transfer tracking — a consumer that hands these views
         to jax itself must use ``committed_device_put`` (or copy first);
         ``stream``/``feed`` consumers get the tracking for free."""
-        for slot, arrays in self._slots(stream):
-            yield arrays, slot.counts
+        for _slot, arrays, counts in self._slots(stream):
+            yield arrays, counts
 
     @contextlib.contextmanager
     def _account(self, arrays: Tuple[np.ndarray, ...],
@@ -577,9 +583,9 @@ class FeedPipeline:
         transfer handle, and the packer waits on it before reusing the
         buffers — asynchronous transfers stay safe without the dispatch
         thread ever blocking."""
-        for slot, arrays in self._slots(span_stream):
-            with self._account(arrays, slot.counts):
-                out = emit_fn(arrays, slot.counts)
+        for slot, arrays, counts in self._slots(span_stream):
+            with self._account(arrays, counts):
+                out = emit_fn(arrays, counts)
             slot.in_flight = out
             yield out
 

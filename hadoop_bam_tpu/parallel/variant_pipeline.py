@@ -19,6 +19,7 @@ import dataclasses
 import itertools
 import logging
 import struct
+import time
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -59,7 +60,9 @@ class VariantGeometry:
     ``tile_records=None`` (the default) sizes the tile from the sample
     count: as many variants per step as keep the dosage tile within
     ~8 MB, clamped to [64, 65536].  Fewer, larger dispatches amortize
-    the per-step issue cost (not re-measured on the current machine),
+    the per-step issue cost (at 2,504 samples a tile is 3,352 records:
+    on the v5e a 262,144-record scan was 79 groups, 8.35 MB over the
+    link and 24 us of device time a group — PERF.md, PR 28),
     but a fixed 64k tile would be gigabytes for cohort-scale VCFs —
     the device step materializes int32 casts of the whole dosage tile.
     The floor is records-small on purpose: a 100k-sample cohort at the
@@ -205,15 +208,25 @@ def bcf_span_stat_columns(path: str, span, header: VCFHeader,
     )
     from hadoop_bam_tpu.split.vcf_planners import read_bcf_span_frames
 
-    with METRICS.span("vcf.inflate_wall"):
-        raw, starts = read_bcf_span_frames(path, span, is_bgzf)
-    with METRICS.span("vcf.tokenize_wall"):
-        cols = decode_bcf_columns(raw, header, geometry.samples_pad,
-                                  starts=starts)
-        if cols is not None:
-            return stat_columns(cols)
-        from hadoop_bam_tpu.formats.bcf import scan_variant_columns
-        return scan_variant_columns(raw, header, geometry.samples_pad)
+    # vcf.decode_busy_ns: this thread's CPU time in the span's read +
+    # decode (waits for the interpreter lock left out) — the host variant
+    # plane's twin of decode.native_busy_ns
+    t_cpu = time.thread_time_ns()
+    try:
+        with METRICS.span("vcf.inflate_wall"):
+            raw, starts = read_bcf_span_frames(path, span, is_bgzf)
+        METRICS.count("vcf.inflated_bytes", len(raw))
+        with METRICS.span("vcf.tokenize_wall"):
+            cols = decode_bcf_columns(raw, header, geometry.samples_pad,
+                                      starts=starts)
+            if cols is not None:
+                return stat_columns(cols)
+            METRICS.count("vcf.columnar_declined_spans")
+            from hadoop_bam_tpu.formats.bcf import scan_variant_columns
+            return scan_variant_columns(raw, header, geometry.samples_pad)
+    finally:
+        METRICS.count("vcf.decode_busy_ns",
+                      time.thread_time_ns() - t_cpu)
 
 
 _ALT_W = 16            # widest ALT the vectorized SNP test gathers
@@ -510,25 +523,28 @@ def make_variant_stats_step(mesh: Mesh, geometry: VariantGeometry,
         n_variants = vi.sum()
         n_snp = (valid & ((flags & FLAG_SNP) != 0)).sum().astype(jnp.int32)
         n_pass = (valid & ((flags & FLAG_PASS) != 0)).sum().astype(jnp.int32)
-        d = dosage.astype(jnp.int32)
-        called = (d >= 0) & valid[:, None]
-        n_called = called.sum(axis=1)                           # [cap] i32
-        alt_sum = jnp.where(called, d, 0).sum(axis=1
-                                              ).astype(jnp.float32)
-        has_calls = n_called > 0
-        af = jnp.where(has_calls,
-                       alt_sum / (2.0 * jnp.maximum(n_called, 1)
-                                  .astype(jnp.float32)),
-                       0.0)
-        sum_af = (af * valid.astype(jnp.float32)).sum()
-        n_af = (has_calls & valid).sum().astype(jnp.int32)
-        per_sample_called = called.astype(jnp.int32).sum(axis=0)  # [S]
-        ivec = jnp.concatenate([
-            jnp.stack([n_variants, n_snp, n_pass, n_af]),
-            per_sample_called,
-        ])
-        return (jax.lax.psum(sum_af[None], axis),
-                jax.lax.psum(ivec, axis))
+        with jax.named_scope("unpack"):
+            d = dosage.astype(jnp.int32)
+            called = (d >= 0) & valid[:, None]
+        with jax.named_scope("reduce"):
+            n_called = called.sum(axis=1)                       # [cap] i32
+            alt_sum = jnp.where(called, d, 0).sum(axis=1
+                                                  ).astype(jnp.float32)
+            has_calls = n_called > 0
+            af = jnp.where(has_calls,
+                           alt_sum / (2.0 * jnp.maximum(n_called, 1)
+                                      .astype(jnp.float32)),
+                           0.0)
+            sum_af = (af * valid.astype(jnp.float32)).sum()
+            n_af = (has_calls & valid).sum().astype(jnp.int32)
+            per_sample_called = called.astype(jnp.int32).sum(axis=0)  # [S]
+            ivec = jnp.concatenate([
+                jnp.stack([n_variants, n_snp, n_pass, n_af]),
+                per_sample_called,
+            ])
+        with jax.named_scope("psum"):
+            return (jax.lax.psum(sum_af[None], axis),
+                    jax.lax.psum(ivec, axis))
 
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(P(axis),) * 5, out_specs=(P(), P()))
@@ -808,6 +824,47 @@ def _variant_stats_device_plane(ds, mesh: Mesh, config: HBamConfig,
     return _variant_stats_result(totals, header)
 
 
+def _bgzf_inflate_ratio(path: str, window: int = 256 << 10) -> float:
+    """Inflated / compressed bytes over the BGZF blocks that lie whole in
+    the file's first ``window`` bytes (read off their headers and
+    footers; nothing is inflated).  1.0 when it cannot be told."""
+    from hadoop_bam_tpu.formats import bgzf
+    from hadoop_bam_tpu.parallel.pipeline import scoped_byte_source
+
+    try:
+        with scoped_byte_source(path) as src:
+            head = src.pread(0, window)
+    except Exception:  # noqa: BLE001 — planning must not fail the driver
+        return 1.0
+    packed = inflated = off = 0
+    while True:
+        try:
+            info = bgzf.parse_block_header(head, off)
+        except bgzf.BGZFError:
+            break
+        packed += info.block_size
+        inflated += info.isize
+        off += info.block_size
+    return max(1.0, inflated / packed) if packed else 1.0
+
+
+def variant_span_count(ds, n_dev: int,
+                       config: HBamConfig = DEFAULT_CONFIG) -> int:
+    """Span count of a whole-file variant scan.  ``pipeline_span_count``
+    bounds a span's COMPRESSED bytes (4 MiB), which fits files that
+    deflate about 4x.  A cohort-wide BCF deflates 30x and more (5 KB
+    records of mostly 0|0): 4 MiB of it are 26,000 records of 2,504
+    samples, ten spans a 262,144-record scan, all decoded before the
+    first tile reaches the device.  So a BGZF BCF's bytes are weighed by
+    the ratio its first blocks show, which keeps a span's INFLATED bytes
+    near four pipeline grains."""
+    if not ds._is_bgzf_bcf:
+        return pipeline_span_count(ds.path, n_dev, config)
+    return pipeline_span_count(
+        ds.path, n_dev, config,
+        size_scale=max(1.0, _bgzf_inflate_ratio(ds.path) / 4.0))
+
+
 def variant_stats_file(path: str, mesh: Optional[Mesh] = None,
                        config: HBamConfig = DEFAULT_CONFIG,
                        geometry: Optional[VariantGeometry] = None,
@@ -891,7 +948,7 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
     if spans is None:
         with METRICS.span("vcf.plan_wall"):
             spans = ds.spans(
-                num_spans=pipeline_span_count(path, n_dev, config))
+                num_spans=variant_span_count(ds, n_dev, config))
     step = make_variant_stats_step(mesh, geometry)
     sharding = NamedSharding(mesh, P("data"))
     pool = decode_pool(config)
@@ -935,6 +992,7 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
                         for k in ("chrom", "pos", "flags", "dosage")]
                 c = jax.device_put(counts, sharding)
                 totals.add(*step(*args, c))  # async; drained at the end
+            METRICS.count("pipeline.records", int(counts.sum()))
             return (*args, c)  # in-flight handles: the ring waits on them
 
         fp.feed(tuples, dispatch)

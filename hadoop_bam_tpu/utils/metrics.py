@@ -72,7 +72,11 @@ def trim_span_args(args: Dict[str, object]) -> Dict[str, object]:
 
 class Metrics:
     def __init__(self) -> None:
-        self._lock = threading.Lock()
+        # re-entrant: an allocation made under the lock can start a
+        # garbage collection, and a collected generator's ``finally``
+        # (an abandoned _iter_windowed joining its native jobs) counts
+        # into this object on the same thread
+        self._lock = threading.RLock()
         self.counters: Dict[str, int] = defaultdict(int)
         self.timers: Dict[str, float] = defaultdict(float)
         self.timer_calls: Dict[str, int] = defaultdict(int)
