@@ -26,7 +26,60 @@ import numpy as np
 from hadoop_bam_tpu.formats import bgzf
 from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.metrics import METRICS
-from hadoop_bam_tpu.utils.pools import NO_LEASE, SpanBuffer, SpanBufferPool
+from hadoop_bam_tpu.utils.pools import (
+    NO_LEASE, SPAN_BUFFERS, SpanBuffer, SpanBufferPool,
+)
+
+
+def fetch_span_raw(src, span
+                   ) -> Tuple[memoryview, int, int, SpanBuffer]:
+    """Fetch one span's compressed bytes: the whole blocks in
+    [start_c, end_c) plus the block AT end_c when the span ends inside it
+    (end_u > 0), so one block table and one native job cover the span.
+
+    A source that can fill a buffer in place (a local file, or the retry
+    wrapper around one) is read ONCE, [start_c, end_c + MAX_BLOCK_SIZE)
+    clipped to the file, into a buffer leased from the span-buffer pool;
+    the end block's size comes from its header where it lies.  Any other
+    source (in-memory bytes, the chaos wrapper, a remote source) keeps
+    two ``pread``s and a concatenate.  Returns (raw, end_block_size,
+    next_c, lease): ``next_c`` is the compressed offset of the first
+    block past ``raw``; ``lease`` (``NO_LEASE`` on the ``pread`` path) is
+    what ``raw`` lives in — whoever takes ``raw`` releases it once
+    nothing reads ``raw`` any more."""
+    start_c, start_u = span.start
+    end_c, end_u = span.end
+    want = max(end_c - start_c, 0)
+    end_block = end_u > 0 and end_c < src.size
+    pread_into = getattr(src, "pread_into", None)
+    lease = NO_LEASE
+    with METRICS.span("bam.fetch_wall", nbytes=want):
+        if pread_into is None:
+            raw = src.pread(start_c, want)
+            end_block_size = 0
+            if end_block:
+                head = src.pread(end_c, bgzf.MAX_BLOCK_SIZE)
+                end_block_size = bgzf.parse_block_header(head, 0).block_size
+                raw = raw + head[:end_block_size]
+            raw = memoryview(raw)
+        else:
+            ask = min(want + (bgzf.MAX_BLOCK_SIZE if end_block else 0),
+                      src.size - start_c)
+            raw, end_block_size = memoryview(b""), 0
+            if ask > 0:
+                lease = SPAN_BUFFERS.lease(ask)
+                try:
+                    buf = memoryview(lease.array)[:ask]
+                    buf = buf[:pread_into(start_c, buf)]
+                    if end_block:
+                        end_block_size = bgzf.parse_block_header(
+                            buf, want).block_size
+                except BaseException:
+                    lease.release()
+                    raise
+                raw = buf[:want + end_block_size]
+    next_c = (end_c + end_block_size) if raw else start_c
+    return raw, end_block_size, next_c, lease
 
 
 def block_table(raw, offset: int = 0) -> dict:
@@ -58,14 +111,17 @@ def block_table(raw, offset: int = 0) -> dict:
 
 
 def inflate_span(raw: bytes, table: Optional[dict] = None,
-                 backend: str = "auto", n_threads: int = 0
+                 backend: str = "auto", n_threads: int = 0,
+                 out: Optional[np.ndarray] = None
                  ) -> Tuple[np.ndarray, np.ndarray]:
     """Inflate all blocks of a compressed span.
 
     Returns (data, ubase): ``data`` is the contiguous inflated bytes of the
     span; ``ubase[i]`` is each block's starting offset within ``data`` (the
     map from (block, in-block offset) to buffer offset — i.e. from virtual
-    offsets to positions).
+    offsets to positions).  ``data`` is fresh memory, or — host backends —
+    the head of ``out`` (uint8, at least the blocks' ISIZE sum) when the
+    caller brings the buffer.
     """
     if table is None:
         table = block_table(raw)
@@ -76,7 +132,7 @@ def inflate_span(raw: bytes, table: Optional[dict] = None,
     ubase = np.zeros(isize.size + 1, dtype=np.int64)
     np.cumsum(isize, out=ubase[1:])
     total = int(ubase[-1])
-    dst = np.empty(total, dtype=np.uint8)
+    dst = np.empty(total, dtype=np.uint8) if out is None else out[:total]
     src = np.frombuffer(raw, dtype=np.uint8)
 
     if backend == "auto":

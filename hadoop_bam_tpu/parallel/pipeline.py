@@ -66,8 +66,8 @@ from hadoop_bam_tpu.utils import errors as hberrors
 from hadoop_bam_tpu.utils.errors import PlanError, classify_error
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import (
-    NO_LEASE, SPAN_BUFFERS, SpanBuffer, decode_pool, decode_pool_size,
-    stream_window_cap, submit as pool_submit,
+    SPAN_BUFFERS, decode_pool, decode_pool_size, stream_window_cap,
+    submit as pool_submit,
 )
 from hadoop_bam_tpu.utils.resilient import (
     QuarantineManifest, RetryPolicy, RetryingByteSource,
@@ -138,59 +138,6 @@ class HostSpanBatch:
     voffsets: List[np.ndarray]  # per-device per-record virtual offsets
 
 
-def _fetch_span_raw(src, span: FileVirtualSpan
-                    ) -> Tuple[memoryview, int, int, SpanBuffer]:
-    """Fetch one span's compressed bytes: the whole blocks in
-    [start_c, end_c) plus the block AT end_c when the span ends inside it
-    (end_u > 0), so one block table and one native job cover the span.
-
-    A source that can fill a buffer in place (a local file, or the retry
-    wrapper around one) is read ONCE, [start_c, end_c + MAX_BLOCK_SIZE)
-    clipped to the file, into a buffer leased from the span-buffer pool;
-    the end block's size comes from its header where it lies.  Any other
-    source (in-memory bytes, the chaos wrapper, a remote source) keeps
-    two ``pread``s and a concatenate.  Returns (raw, end_block_size,
-    next_c, lease): ``next_c`` is the compressed offset of the first
-    block past ``raw``; ``lease`` (``NO_LEASE`` on the ``pread`` path) is
-    what ``raw`` lives in — whoever takes ``raw`` releases it once
-    nothing reads ``raw`` any more."""
-    from hadoop_bam_tpu.formats import bgzf
-
-    start_c, start_u = span.start
-    end_c, end_u = span.end
-    want = max(end_c - start_c, 0)
-    end_block = end_u > 0 and end_c < src.size
-    pread_into = getattr(src, "pread_into", None)
-    lease = NO_LEASE
-    with METRICS.span("bam.fetch_wall", nbytes=want):
-        if pread_into is None:
-            raw = src.pread(start_c, want)
-            end_block_size = 0
-            if end_block:
-                head = src.pread(end_c, bgzf.MAX_BLOCK_SIZE)
-                end_block_size = bgzf.parse_block_header(head, 0).block_size
-                raw = raw + head[:end_block_size]
-            raw = memoryview(raw)
-        else:
-            ask = min(want + (bgzf.MAX_BLOCK_SIZE if end_block else 0),
-                      src.size - start_c)
-            raw, end_block_size = memoryview(b""), 0
-            if ask > 0:
-                lease = SPAN_BUFFERS.lease(ask)
-                try:
-                    buf = memoryview(lease.array)[:ask]
-                    buf = buf[:pread_into(start_c, buf)]
-                    if end_block:
-                        end_block_size = bgzf.parse_block_header(
-                            buf, want).block_size
-                except BaseException:
-                    lease.release()
-                    raise
-                raw = buf[:want + end_block_size]
-    next_c = (end_c + end_block_size) if raw else start_c
-    return raw, end_block_size, next_c, lease
-
-
 def _decode_span_core(source, span: FileVirtualSpan,
                       check_crc: bool = False,
                       inflate_backend: str = "auto",
@@ -218,7 +165,7 @@ def _decode_span_core(source, span: FileVirtualSpan,
     end_c, end_u = span.end
     METRICS.count("pipeline.spans")
 
-    raw, end_block_size, next_c, lease = _fetch_span_raw(src, span)
+    raw, end_block_size, next_c, lease = inflate_ops.fetch_span_raw(src, span)
     if raw:
         try:
             table = inflate_ops.block_table(raw)
@@ -398,7 +345,7 @@ def _start_fused_span(src, span: FileVirtualSpan, mode: str, *,
     its inflated bytes and offsets from the span-buffer pool until its
     ``finish()``.  Returns (dec, end_inflated, next_c, table) or None for
     an empty span (the two-pass path disposes of those)."""
-    raw, end_block_size, next_c, lease = _fetch_span_raw(src, span)
+    raw, end_block_size, next_c, lease = inflate_ops.fetch_span_raw(src, span)
     if not raw:
         return None
     try:
@@ -2057,7 +2004,7 @@ def _tokenize_span_tokens(src, span: FileVirtualSpan,
     HERE, inside the retry boundary — exactly where the host planes
     raise them.  Returns None for an empty span."""
     src = as_byte_source(src)
-    raw, end_block_size, _next_c, lease = _fetch_span_raw(src, span)
+    raw, end_block_size, _next_c, lease = inflate_ops.fetch_span_raw(src, span)
     METRICS.count("pipeline.spans")
     if not raw:
         return None

@@ -15,6 +15,7 @@ allele frequency, and per-sample call rates in a single pass.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import logging
@@ -206,24 +207,30 @@ def bcf_span_stat_columns(path: str, span, header: VCFHeader,
     from hadoop_bam_tpu.formats.bcf_columns import (
         decode_bcf_columns, stat_columns,
     )
-    from hadoop_bam_tpu.split.vcf_planners import read_bcf_span_frames
+    from hadoop_bam_tpu.split.vcf_planners import bcf_span_frames
 
     # vcf.decode_busy_ns: this thread's CPU time in the span's read +
     # decode (waits for the interpreter lock left out) — the host variant
     # plane's twin of decode.native_busy_ns
     t_cpu = time.thread_time_ns()
     try:
-        with METRICS.span("vcf.inflate_wall"):
-            raw, starts = read_bcf_span_frames(path, span, is_bgzf)
-        METRICS.count("vcf.inflated_bytes", len(raw))
-        with METRICS.span("vcf.tokenize_wall"):
-            cols = decode_bcf_columns(raw, header, geometry.samples_pad,
-                                      starts=starts)
-            if cols is not None:
-                return stat_columns(cols)
-            METRICS.count("vcf.columnar_declined_spans")
-            from hadoop_bam_tpu.formats.bcf import scan_variant_columns
-            return scan_variant_columns(raw, header, geometry.samples_pad)
+        # ``raw`` may be a view of a leased span buffer, handed back when
+        # the stack unwinds: every column below is memory of its own (the
+        # packer holds columns across spans)
+        with contextlib.ExitStack() as leased:
+            with METRICS.span("vcf.inflate_wall"):
+                raw, starts = leased.enter_context(
+                    bcf_span_frames(path, span, is_bgzf))
+            METRICS.count("vcf.inflated_bytes", len(raw))
+            with METRICS.span("vcf.tokenize_wall"):
+                cols = decode_bcf_columns(raw, header, geometry.samples_pad,
+                                          starts=starts)
+                if cols is not None:
+                    return stat_columns(cols)
+                METRICS.count("vcf.columnar_declined_spans")
+                from hadoop_bam_tpu.formats.bcf import scan_variant_columns
+                return scan_variant_columns(raw, header,
+                                            geometry.samples_pad)
     finally:
         METRICS.count("vcf.decode_busy_ns",
                       time.thread_time_ns() - t_cpu)
@@ -729,10 +736,12 @@ def _variant_stats_device_plane(ds, mesh: Mesh, config: HBamConfig,
         with METRICS.span("vcf.plan_wall", spans=n_spans):
             spans = ds.spans(num_spans=n_spans)
     spans = list(spans)
-    # the host oracle (read_bcf_span_frames -> BGZFReader) folds CRCs
-    # unconditionally, so the device route must keep the same error
-    # contract on CRC-only damage: the tokenize-time fold is always on
-    # for the variant family, config.check_crc notwithstanding
+    # the host plane's span read (split/vcf_planners.bcf_span_frames,
+    # either path) verifies each block's ISIZE and not its CRC32
+    # (``BGZFReader(src)`` defaults to check_crc=False): CRC-only damage
+    # surfaces there only in a block the BGZF split guesser confirms.
+    # This route folds the CRC at tokenize time for the variant family,
+    # config.check_crc notwithstanding
     check_crc = True
     samples_pad = geometry.samples_pad
     src = _resilient_source(ds.path, config)

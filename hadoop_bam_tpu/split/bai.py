@@ -296,7 +296,7 @@ class IncrementalBinningCore:
     The old fallback packed (coffset+1, 0), one BYTE past the block
     start: BGZFReader-based chunk reads tolerated that by accident, but
     block-table consumers (plan_interval_spans -> coverage's
-    _fetch_span_raw) need end coffsets on real block boundaries and
+    fetch_span_raw) need end coffsets on real block boundaries and
     died mid-block with "truncated BGZF header".
     """
 

@@ -15,8 +15,9 @@ Rebuild of hb/VCFInputFormat.java's split behavior (SURVEY.md section 3.4):
 """
 from __future__ import annotations
 
+import contextlib
 import struct
-from typing import List, Optional, Tuple
+from typing import Iterator, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -25,11 +26,15 @@ from hadoop_bam_tpu.formats import bgzf
 from hadoop_bam_tpu.formats.bcf import BCFRecordCodec
 from hadoop_bam_tpu.formats.bcfio import read_bcf_header
 from hadoop_bam_tpu.formats.vcf import VCFHeader, VcfRecord
+from hadoop_bam_tpu.ops import inflate as inflate_ops
 from hadoop_bam_tpu.split.bcf_guesser import BCFSplitGuesser
 from hadoop_bam_tpu.split.bgzf_guesser import BGZFSplitGuesser
 from hadoop_bam_tpu.split.planners import plan_byte_ranges
 from hadoop_bam_tpu.split.spans import FileByteSpan, FileVirtualSpan
-from hadoop_bam_tpu.utils.seekable import as_byte_source
+from hadoop_bam_tpu.utils import native
+from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.utils.pools import NO_LEASE, SPAN_BUFFERS, SpanBuffer
+from hadoop_bam_tpu.utils.seekable import as_byte_source, scoped_byte_source
 
 
 # ---------------------------------------------------------------------------
@@ -226,23 +231,84 @@ def read_bcf_span_frames(source, span: FileVirtualSpan,
                          is_bgzf: Optional[bool] = None
                          ) -> Tuple[bytes, np.ndarray]:
     """(concatenated record bytes, per-record start offsets) of a BCF
-    span — the input of the columnar decoder
-    (formats/bcf_columns.decode_bcf_columns).
+    span, as memory of the caller's own — ``bcf_span_frames`` without
+    the lease."""
+    with bcf_span_frames(source, span, is_bgzf) as (raw, starts):
+        return bytes(raw), starts
 
-    The span's whole inflated range is read in BULK (block-granular,
-    not per-record — two tiny ``BGZFReader.read`` calls per record were
-    2.5x the columnar decode itself), then the record framing the
-    decoder needs comes from one cursor chase over the ``l_shared``/
-    ``l_indiv`` prefixes, which also extends the tail record past the
-    span end exactly like the per-record reader did: a record belongs
-    to the span iff its first byte does.  A record cut off by EOF is
-    kept (the decoder raises ``BCFError`` on it, matching the record
-    path); a bare header stub at EOF is dropped (the record path never
-    emitted it either)."""
-    src = as_byte_source(source)
-    if is_bgzf is None:
-        _, _, is_bgzf = read_bcf_header(src)
-    unpack = struct.Struct("<II").unpack_from
+
+@contextlib.contextmanager
+def bcf_span_frames(source, span: FileVirtualSpan,
+                    is_bgzf: Optional[bool] = None
+                    ) -> Iterator[Tuple[Union[bytes, memoryview],
+                                        np.ndarray]]:
+    """``with bcf_span_frames(...) as (raw, starts)``: the concatenated
+    record bytes of a BCF span and the per-record start offsets — the
+    input of the columnar decoder (formats/bcf_columns.decode_bcf_columns).
+
+    A record belongs to the span iff its first byte does; the tail record
+    runs past the span end exactly as the per-record reader reads it.  A
+    record cut off by EOF is kept (the decoder raises ``BCFError`` on it,
+    matching the record path); a bare header stub at EOF is dropped (the
+    record path never emitted it either).
+
+    The input decides the path.  A BGZF source with the native library is
+    read as the BAM feed reads a span (``_lease_bgzf_span_frames``): then
+    ``raw`` is a view of a buffer leased from the span-buffer pool, good
+    until the ``with`` ends — whatever outlives it must be a copy.  A raw
+    BCF, no native library, or a span that read declines goes block by
+    block through ``bgzf.BGZFReader`` (``_read_bcf_span_frames``: the
+    byte-identity oracle), and ``raw`` is ``bytes``.  Counters:
+    ``vcf.native_read_spans`` / ``vcf.python_read_spans``."""
+    with scoped_byte_source(source) as src:
+        if is_bgzf is None:
+            _, _, is_bgzf = read_bcf_header(src)
+        leased = _lease_bgzf_span_frames(src, span) if is_bgzf else None
+        if leased is None:
+            METRICS.count("vcf.python_read_spans")
+            yield _read_bcf_span_frames(src, span, is_bgzf)
+        else:
+            METRICS.count("vcf.native_read_spans")
+            lease, raw, starts = leased
+            try:
+                yield raw, starts
+            finally:
+                lease.release()
+
+
+_FRAME_LENGTHS = struct.Struct("<II").unpack_from
+
+
+def _chase_frames(buf, n0: int, grow) -> Tuple[object, int, np.ndarray]:
+    """The cursor chase over the ``l_shared``/``l_indiv`` prefixes of the
+    records that start in ``buf[:n0]``.  ``grow(need)`` returns the buffer
+    made ``need`` bytes long, or as long as the file allows.  Returns
+    (the buffer, the length of the span's records in it, their starts)."""
+    starts: List[int] = []
+    p = 0
+    while p < n0:
+        if p + 8 > len(buf):
+            buf = grow(p + 8)
+            if p + 8 > len(buf):                # EOF mid-header stub
+                break
+        l_shared, l_indiv = _FRAME_LENGTHS(buf, p)
+        end = p + 8 + l_shared + l_indiv
+        if end > len(buf):
+            buf = grow(end)
+            if end > len(buf):                  # EOF mid-body: keep the
+                starts.append(p)                # partial; decode raises
+                p = len(buf)
+                break
+        starts.append(p)
+        p = end
+    return buf, p, np.asarray(starts, np.int64)
+
+
+def _read_bcf_span_frames(src, span: FileVirtualSpan, is_bgzf: bool
+                          ) -> Tuple[bytes, np.ndarray]:
+    """The span's whole inflated range read in BULK (block-granular, not
+    per-record — two tiny ``BGZFReader.read`` calls per record were 2.5x
+    the columnar decode itself), then framed by one cursor chase."""
     if is_bgzf:
         r = bgzf.BGZFReader(src)
         r.seek_voffset(span.start_voffset)
@@ -258,22 +324,84 @@ def read_bcf_span_frames(source, span: FileVirtualSpan,
         def read_more(k: int) -> bytes:
             return src.pread(pos0 + len(buf), k)
 
-    n0 = len(buf)
-    starts: List[int] = []
-    p = 0
-    while p < n0:
-        if p + 8 > len(buf):
-            buf += read_more(p + 8 - len(buf))
-            if p + 8 > len(buf):
-                del buf[p:]                     # EOF mid-header stub
-                break
-        l_shared, l_indiv = unpack(buf, p)
-        end = p + 8 + l_shared + l_indiv
-        if end > len(buf):
-            buf += read_more(end - len(buf))
-            if end > len(buf):                  # EOF mid-body: keep the
-                starts.append(p)                # partial; decode raises
-                break
-        starts.append(p)
-        p = end
-    return bytes(buf), np.asarray(starts, np.int64)
+    def grow(need: int) -> bytearray:
+        buf.extend(read_more(need - len(buf)))
+        return buf
+
+    buf, length, starts = _chase_frames(buf, len(buf), grow)
+    del buf[length:]
+    return bytes(buf), starts
+
+
+def _lease_bgzf_span_frames(src, span: FileVirtualSpan
+                            ) -> Optional[Tuple[SpanBuffer, memoryview,
+                                                np.ndarray]]:
+    """A BGZF BCF span read the way the BAM feed reads one: ONE positioned
+    read of the compressed range (``ops.inflate.fetch_span_raw``), the
+    native header walk, ONE native inflate of all its blocks into a
+    buffer leased from the span-buffer pool — one release of the
+    interpreter lock a span where the ``BGZFReader`` takes two a block —
+    and the chase over a view of that buffer, no copy.  One native thread:
+    the pool's threads are the parallelism.  ISIZE is verified and CRC32
+    is not, as ``BGZFReader(src)`` does.
+
+    Returns (lease, record bytes, starts); the caller releases the lease
+    when nothing reads the bytes any more.  Returns None — nothing leased,
+    the ``BGZFReader`` path decides — without the native library, for a
+    span that is empty or does not start and end inside its blocks, and
+    when the block chain does not parse or inflate (that path then raises
+    its own ``BGZFError``)."""
+    start_u, end_u = span.start[1], span.end[1]
+    if not native.available() or span.start_voffset >= span.end_voffset:
+        return None
+    lease = raw_lease = NO_LEASE
+    try:
+        raw, end_block_size, next_c, raw_lease = \
+            inflate_ops.fetch_span_raw(src, span)
+        if not raw:
+            return None
+        table = inflate_ops.block_table(raw)
+        isize = table["isize"]
+        total = int(isize.sum())
+        n0 = (total - int(isize[-1]) + end_u if end_block_size else total) \
+            - start_u
+        if start_u > int(isize[0]) or end_u > int(isize[-1]) or n0 <= 0:
+            return None
+        lease = SPAN_BUFFERS.lease(total)
+        inflate_ops.inflate_span(raw, table, backend="native", n_threads=1,
+                                 out=lease.array)
+    except BaseException as e:
+        lease.release()
+        if isinstance(e, bgzf.BGZFError):
+            return None
+        raise
+    finally:
+        raw_lease.release()     # nothing below reads the compressed bytes
+
+    tail = None
+
+    def grow(need: int) -> memoryview:
+        """The tail record runs past the last fetched block: the
+        following block(s) through the block reader, never a speculative
+        over-read."""
+        nonlocal lease, total, tail
+        if tail is None:
+            tail = bgzf.BGZFReader(src)
+            tail.seek_voffset(next_c << 16)
+        more = tail.read(need - (total - start_u))
+        if total + len(more) > lease.array.size:
+            bigger = SPAN_BUFFERS.lease(total + len(more))
+            bigger.array[:total] = lease.array[:total]
+            lease.release()
+            lease = bigger
+        lease.array[total:total + len(more)] = np.frombuffer(more, np.uint8)
+        total += len(more)
+        return memoryview(lease.array)[start_u:total]
+
+    try:
+        buf, length, starts = _chase_frames(
+            memoryview(lease.array)[start_u:total], n0, grow)
+    except BaseException:
+        lease.release()
+        raise
+    return lease, buf[:length], starts

@@ -296,7 +296,7 @@ class SpanBuffer:
 class SpanBufferPool:
     """Recycled ``uint8`` buffers for what a span start used to allocate
     fresh: the compressed bytes, the inflated bytes and the record-offset
-    scratch of a span (parallel/pipeline.py ``_fetch_span_raw``,
+    scratch of a span (ops/inflate.py ``fetch_span_raw``,
     ops/inflate.py ``FusedSpanDecode``).  Each of those sits above the
     allocator's mmap threshold, so a fresh one is faulted in page by page
     on first touch and unmapped on free — ~2 GB a 4.19 M-record scan.

@@ -271,14 +271,14 @@ def test_bai_chunk_ends_are_block_aligned(tmp_path):
     # the exact failing composition: interval spans from the BAI feed
     # the raw-fetch + block-table path (what coverage_file does)
     from hadoop_bam_tpu.ops import inflate as inflate_ops
-    from hadoop_bam_tpu.parallel.pipeline import _fetch_span_raw
 
     iv = resolve_interval(f"{header.ref_names[0]}:1-100000000",
                           header.ref_names)
     spans = plan_interval_spans(path, [iv], header, bai=idx)
     assert spans
     for span in spans:
-        raw, _end_block, _next_c, lease = _fetch_span_raw(src, span)
+        raw, _end_block, _next_c, lease = inflate_ops.fetch_span_raw(
+            src, span)
         table = inflate_ops.block_table(raw)   # raises on mid-block ends
         lease.release()
         assert int(table["isize"].sum()) > 0

@@ -1,6 +1,6 @@
 """The span start without fresh memory: the span-buffer pool
 (``utils/pools.SpanBufferPool``), the single read into a leased buffer
-(``parallel/pipeline._fetch_span_raw``), the leased inflated bytes of the
+(``ops/inflate.fetch_span_raw``), the leased inflated bytes of the
 streamed fused decode (``ops/inflate.FusedSpanDecode``) and the native
 BGZF header walk (``hbam_block_table``).
 
@@ -73,6 +73,7 @@ def pool(monkeypatch):
     """A private pool in place of the process-wide one."""
     p = SpanBufferPool()
     monkeypatch.setattr(pl, "SPAN_BUFFERS", p)
+    monkeypatch.setattr(inflate_ops, "SPAN_BUFFERS", p)
     METRICS.reset()
     return p
 
@@ -322,17 +323,19 @@ def test_one_read_fetches_what_two_reads_and_a_copy_fetched(bam3, pool):
     inside = FileVirtualSpan(path, spans[1].start_voffset,
                              spans[1].start_voffset + 7)   # one block
     for span in list(spans) + [inside]:
-        want = pl._fetch_span_raw(BytesByteSource(whole), span)
+        want = inflate_ops.fetch_span_raw(BytesByteSource(whole), span)
         assert want[3].array is None        # the pread path leases nothing
         for src in (file_src, retrying):
-            raw, end_size, next_c, lease = pl._fetch_span_raw(src, span)
+            raw, end_size, next_c, lease = inflate_ops.fetch_span_raw(
+                src, span)
             assert bytes(raw) == bytes(want[0])
             assert (end_size, next_c) == want[1:3]
             assert raw.obj is lease.array
             lease.release()
     assert _all_back(pool)
     past_end = FileVirtualSpan(path, len(whole) << 16, len(whole) << 16)
-    raw, end_size, next_c, lease = pl._fetch_span_raw(file_src, past_end)
+    raw, end_size, next_c, lease = inflate_ops.fetch_span_raw(
+        file_src, past_end)
     assert (len(raw), end_size, next_c) == (0, 0, len(whole))
     assert lease.array is None
 
