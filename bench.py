@@ -1619,303 +1619,6 @@ def bench_fused_decode(path: str):
                      "decode_share arms run check_crc=True")}
 
 
-def bench_device_inflate(path: str):
-    """The round-11 contract row: the token-feed device decode plane
-    (host Huffman tokenize overlapped with on-mesh LZ77 resolve + record
-    walk + fixed-field unpack; ops/inflate_device.py) vs the fused-native
-    host plane, flagstat over the same pinned span subset of the scaling
-    fixture.  Reports the tokenize / device-resolve wall-share breakdown
-    and the overlap between them — the structural claim this row pins is
-    that the host half of inflate (Huffman tokenize, ~1/3 of inflate
-    cost) OVERLAPS the device half, so the non-overlapped inflate share
-    of flagstat wall drops vs the fused-native arm where the whole
-    inflate is host wall.  CAVEAT (recorded in the note): this 1-core
-    host runs the "device" stage on XLA:CPU, so the row measures overlap
-    STRUCTURE and plane correctness, not TPU speedup — tokenize and
-    resolve time-slice one core here, and resolve is far slower than
-    native inflate."""
-    import dataclasses as _dc
-
-    import jax
-
-    from hadoop_bam_tpu.config import DEFAULT_CONFIG
-    from hadoop_bam_tpu.formats.bamio import read_bam_header
-    from hadoop_bam_tpu.parallel.pipeline import (
-        DEVICE_PLANE_SPAN_BYTES, flagstat_file,
-    )
-    from hadoop_bam_tpu.split.planners import plan_spans_cached
-    from hadoop_bam_tpu.utils import native as nat
-    from hadoop_bam_tpu.utils.metrics import METRICS
-
-    if not nat.available():
-        return {"metric": "device_inflate_records_per_sec",
-                "error": "native tokenizer unavailable"}
-    bam = _scaling_fixture(path)
-    header, _ = read_bam_header(bam)
-    src_size = os.path.getsize(bam)
-    n_spans = max(len(jax.devices()),
-                  int(np.ceil(src_size / DEVICE_PLANE_SPAN_BYTES)))
-    spans = list(plan_spans_cached(bam, header, DEFAULT_CONFIG,
-                                   num_spans=n_spans))
-    # a ~6 MiB compressed prefix bounds the XLA:CPU walk cost per run on
-    # this host; both arms run the SAME pinned subset so rates compare
-    budget = 6 << 20
-    take, acc = [], 0
-    for s in spans:
-        take.append(s)
-        acc += s.compressed_size
-        if acc >= budget:
-            break
-    cfg_dev = _dc.replace(DEFAULT_CONFIG, inflate_backend="device")
-    cfg_fused = _dc.replace(DEFAULT_CONFIG, inflate_backend="native")
-
-    def run(cfg):
-        return flagstat_file(bam, header=header, spans=take, config=cfg)
-
-    n_records = run(cfg_dev)["total"]    # warmup: resolve/walk jit
-    fused_total = run(cfg_fused)["total"]
-    if fused_total != n_records:
-        # a silent device-walk counting bug must fail the row, not
-        # produce plausible rates from the wrong denominator
-        return {"metric": "device_inflate_records_per_sec",
-                "error": f"plane parity break: device total {n_records} "
-                         f"!= fused total {fused_total}"}
-    best = {"device": float("inf"), "fused": float("inf")}
-    walls = {}
-    for _ in range(2):                   # interleaved best-of-2
-        for arm, cfg in (("device", cfg_dev), ("fused", cfg_fused)):
-            METRICS.reset()
-            t0 = time.perf_counter()
-            run(cfg)
-            dt = time.perf_counter() - t0
-            if dt < best[arm]:
-                best[arm] = dt
-                w = dict(METRICS.snapshot()["wall_timers"])
-                w["_total"] = dt
-                walls[arm] = w
-
-    def share(arm, host_key, dev_key):
-        w = walls[arm]
-        total = max(w["_total"], 1e-9)
-        host = float(w.get(host_key, 0.0))
-        devw = float(w.get(dev_key, 0.0))
-        overlap = max(0.0, host + devw - total)
-        return {
-            f"{host_key.split('.')[1]}_s": round(host, 4),
-            f"{dev_key.split('.')[1]}_s": round(devw, 4),
-            "overlap_s": round(overlap, 4),
-            "overlap_efficiency": round(
-                overlap / max(min(host, devw), 1e-9), 3),
-            # the host inflate work NOT hidden behind the other stage,
-            # as a share of the arm's flagstat wall
-            "nonoverlap_inflate_share": round(
-                max(0.0, host - overlap) / total, 3),
-        }
-
-    breakdown = {
-        "device": share("device", "bam.tokenize_wall",
-                        "bam.device_resolve_wall"),
-        "fused": share("fused", "bam.fused_decode_wall",
-                       "bam.dispatch_wall"),
-    }
-    dev_rate = n_records / best["device"]
-    fused_rate = n_records / best["fused"]
-    return {"metric": "device_inflate_records_per_sec",
-            "value": round(dev_rate, 1), "unit": "records/s",
-            "vs_baseline": round(dev_rate / fused_rate, 3),
-            "fused_records_per_sec": round(fused_rate, 1),
-            "records": int(n_records),
-            "spans": len(take),
-            "decode_plane_walls": breakdown,
-            "note": ("flagstat on a pinned ~6 MiB span subset of the "
-                     "scaling fixture, interleaved best-of-2; "
-                     "vs_baseline = device-plane/fused-native rate; "
-                     "device arm = host tokenize overlapped with "
-                     "on-mesh resolve+walk+unpack.  1-core XLA:CPU "
-                     "caveat: measures overlap structure, not TPU "
-                     "speedup — the 'device' here IS the host CPU")}
-
-
-# ---------------------------------------------------------------------------
-# 4b. device decode plane families (round 21): payload / variant / cold serve
-# ---------------------------------------------------------------------------
-
-def bench_device_planes(path: str):
-    """The round-21 contract row: the token-feed device plane extended
-    past flagstat to three more families, one arm each —
-
-    - ``seq_stats``: segmented seq/qual payload projections unpacked
-      on-mesh vs the host driver, same pinned span subset both arms;
-    - ``variant``: BCF variant stats (device fixed-prefix unpack +
-      grouped GT dosage gathers over the resolved mesh buffer) vs the
-      host columnar decoder;
-    - ``serve_cold``: a cold region_serve pass whose tiles are built
-      entirely on-device (serve/tiles.device_build_chunk) vs a cold
-      host-built pass over the same distinct windows.
-
-    Every arm asserts value identity against its host oracle IN-RUN (a
-    parity break fails the row instead of reporting plausible rates)
-    and reports the device arm's pipeline.host_decode_wall share — the
-    structural claim is that the new routes keep host record decode off
-    the critical path (~0; payload span fixups may contribute epsilon).
-    Same 1-core XLA:CPU caveat as the device_inflate row: this pins
-    overlap structure and plane correctness, not TPU speedup."""
-    import dataclasses as _dc
-
-    import jax
-
-    from hadoop_bam_tpu.config import DEFAULT_CONFIG
-    from hadoop_bam_tpu.formats.bamio import read_bam_header
-    from hadoop_bam_tpu.parallel.pipeline import (
-        DEVICE_PLANE_SPAN_BYTES, seq_stats_file,
-    )
-    from hadoop_bam_tpu.parallel.variant_pipeline import variant_stats_file
-    from hadoop_bam_tpu.split.planners import plan_spans_cached
-    from hadoop_bam_tpu.utils import native as nat
-    from hadoop_bam_tpu.utils.metrics import METRICS
-
-    metric = "device_plane_families_records_per_sec"
-    if not nat.available():
-        return {"metric": metric, "error": "native tokenizer unavailable"}
-    cfg_dev = _dc.replace(DEFAULT_CONFIG, inflate_backend="device")
-
-    def match(a, b):
-        """Counts exact, float reductions within device/host
-        reduce-order jitter (f32 tile partials vs f64 host sums)."""
-        if set(a) != set(b):
-            return False
-        for k in a:
-            va, vb = a[k], b[k]
-            if isinstance(va, (int, np.integer)):
-                if int(va) != int(vb):
-                    return False
-            elif not np.allclose(np.asarray(va, np.float64),
-                                 np.asarray(vb, np.float64),
-                                 rtol=1e-5, atol=1e-8):
-                return False
-        return True
-
-    def race(run_dev, run_host):
-        """Interleaved best-of-2 of both arms; returns (best walls,
-        device-arm host_decode_wall share at its best run)."""
-        best = {"device": float("inf"), "host": float("inf")}
-        share = {}
-        for _ in range(2):
-            for arm, run in (("device", run_dev), ("host", run_host)):
-                METRICS.reset()
-                t0 = time.perf_counter()
-                run()
-                dt = time.perf_counter() - t0
-                if dt < best[arm]:
-                    best[arm] = dt
-                    w = METRICS.snapshot()["wall_timers"]
-                    share[arm] = (float(w.get("pipeline.host_decode_wall",
-                                              0.0)) / max(dt, 1e-9))
-        return best, share
-
-    # --- payload arm: seq_stats over the pinned ~6 MiB span subset ---
-    bam = _scaling_fixture(path)
-    header, _ = read_bam_header(bam)
-    n_spans = max(len(jax.devices()),
-                  int(np.ceil(os.path.getsize(bam)
-                              / DEVICE_PLANE_SPAN_BYTES)))
-    spans = list(plan_spans_cached(bam, header, DEFAULT_CONFIG,
-                                   num_spans=n_spans))
-    budget = 6 << 20
-    take, acc = [], 0
-    for s in spans:
-        take.append(s)
-        acc += s.compressed_size
-        if acc >= budget:
-            break
-
-    def seq_dev():
-        return seq_stats_file(bam, header=header, spans=take,
-                              config=cfg_dev)
-
-    def seq_host():
-        return seq_stats_file(bam, header=header, spans=take)
-
-    dev_stats = seq_dev()                    # warmup: resolve/unpack jit
-    host_stats = seq_host()
-    if not match(dev_stats, host_stats):
-        return {"metric": metric,
-                "error": "seq_stats device plane parity break vs host"}
-    n_records = int(host_stats["n_reads"])
-    sbest, sshare = race(seq_dev, seq_host)
-    seq_arm = {
-        "device_records_per_sec": round(n_records / sbest["device"], 1),
-        "host_records_per_sec": round(n_records / sbest["host"], 1),
-        "host_decode_share": round(sshare["device"], 4),
-        "identical_to_host": True,
-        "records": n_records, "spans": len(take)}
-
-    # --- variant arm: BCF stats, whole-file both planes ---
-    bcfp = build_bcf_fixture()
-
-    def var_dev():
-        return variant_stats_file(bcfp, config=cfg_dev)
-
-    def var_host():
-        return variant_stats_file(bcfp)
-
-    vd, vh = var_dev(), var_host()           # warmup + parity
-    if not match(vd, vh):
-        return {"metric": metric,
-                "error": "variant device plane parity break vs host"}
-    n_variants = int(vh["n_variants"])
-    vbest, vshare = race(var_dev, var_host)
-    var_arm = {
-        "device_variants_per_sec": round(n_variants / vbest["device"], 1),
-        "host_variants_per_sec": round(n_variants / vbest["host"], 1),
-        "host_decode_share": round(vshare["device"], 4),
-        "identical_to_host": True, "variants": n_variants}
-
-    # --- serve arm: one cold pass per plane over the distinct windows ---
-    from hadoop_bam_tpu.serve import ServeLoop
-
-    bam_q, regions = _region_query_fixture(path)
-    # 16 distinct windows bound the XLA:CPU device-walk cost of the cold
-    # pass on this 1-core host; identity and metering pin the same way
-    windows = sorted(set(regions))[:16]
-    counts, serve_arm = {}, {}
-    for arm, cfg in (("device", _dc.replace(cfg_dev,
-                                            serve_prefetch=False)),
-                     ("host", _dc.replace(DEFAULT_CONFIG,
-                                          serve_prefetch=False))):
-        with ServeLoop(config=cfg) as loop:
-            METRICS.reset()
-            t0 = time.perf_counter()
-            res = loop.query(bam_q, windows)
-            dt = time.perf_counter() - t0
-            snap = METRICS.snapshot()
-        counts[arm] = [r.count for r in res]
-        serve_arm[f"{arm}_queries_per_sec"] = round(len(windows) / dt, 1)
-        if arm == "device":
-            serve_arm["host_decode_share"] = round(
-                float(snap["wall_timers"].get(
-                    "pipeline.host_decode_wall", 0.0)) / max(dt, 1e-9), 4)
-            serve_arm["device_tile_builds"] = int(
-                snap["counters"].get("serve.device_tile_builds", 0))
-    if counts["device"] != counts["host"]:
-        return {"metric": metric,
-                "error": "cold serve device tiles parity break vs host"}
-    serve_arm["identical_counts"] = True
-    serve_arm["regions"] = len(windows)
-
-    rate = seq_arm["device_records_per_sec"]
-    return {"metric": metric, "value": rate, "unit": "records/s",
-            "vs_baseline": round(
-                rate / max(seq_arm["host_records_per_sec"], 1e-9), 3),
-            "seq_stats": seq_arm, "variant": var_arm,
-            "serve_cold": serve_arm,
-            "note": ("round-21 device plane families: per-arm host-oracle "
-                     "identity asserted in-run; host_decode_share is the "
-                     "device arm's pipeline.host_decode_wall / wall.  "
-                     "1-core XLA:CPU caveat: overlap structure, not TPU "
-                     "speedup — the 'device' here IS the host CPU")}
-
-
 # ---------------------------------------------------------------------------
 # 5. FASTQ reads/s (device payload stats driver)
 # ---------------------------------------------------------------------------
@@ -2430,42 +2133,6 @@ def bench_coverage(path: str):
             "note": "device pileup vs single-thread NumPy pileup"}
 
 
-def bench_deflate_tokenize(path: str):
-    """Host half of the device-DEFLATE experiment (BASELINE.md r3 "Device
-    DEFLATE"): Huffman tokenize GB/s, with vs_baseline = tokenize/full-
-    native-inflate speed ratio.  vs_baseline < 1 records that the
-    two-stage device split cannot beat host inflate even granting a free
-    device stage — the measured negative result."""
-    import numpy as np
-
-    from hadoop_bam_tpu.ops import inflate as inflate_ops
-    from hadoop_bam_tpu.utils import native as nat
-
-    if not nat.available():
-        return {"metric": "deflate_tokenize_gbps", "value": 0.0,
-                "unit": "GB/s", "note": "native tokenizer unavailable"}
-    raw_b = open(path, "rb").read()
-    table = inflate_ops.block_table(raw_b)
-    src = np.frombuffer(raw_b, np.uint8)
-    total = int(table["isize"].sum())
-    stride = max(16, int(table["isize"].max()))
-
-    def run():
-        return nat.deflate_tokenize_batch(
-            src, table["cdata_off"], table["cdata_len"], stride, 1)
-
-    _, dt = _median_time(run)
-
-    def base_run():
-        return inflate_ops.inflate_span(raw_b, table, backend="native",
-                                        n_threads=1)
-
-    _, bdt = _median_time(base_run)
-    return {"metric": "deflate_tokenize_gbps",
-            "value": round(total / dt / 1e9, 3), "unit": "GB/s",
-            "vs_baseline": round(bdt / dt, 3)}
-
-
 # ---------------------------------------------------------------------------
 # single-kernel rows: what the device itself contributes per stage.  Each
 # measurement is serialized chained execution with a SCALAR readback per
@@ -2933,10 +2600,6 @@ def main() -> int:
                    est_s=15)
     _run_component(lambda: bench_split_guess(path),
                    "split_guess_p50_ms_per_boundary", est_s=10)
-    _run_component(lambda: bench_device_inflate(path),
-                   "device_inflate_records_per_sec", est_s=150.0)
-    _run_component(lambda: bench_device_planes(path),
-                   "device_plane_families_records_per_sec", est_s=150.0)
     _run_component(lambda: bench_fused_decode(path),
                    "fused_decode_records_per_sec", est_s=30)
     _run_component(lambda: bench_fault_resilience(path),
@@ -2963,8 +2626,6 @@ def main() -> int:
                    "fastq_reads_per_sec", est_s=25)
     _run_component(lambda: bench_bam_write(path),
                    "bam_write_records_per_sec", est_s=25)
-    _run_component(lambda: bench_deflate_tokenize(path),
-                   "deflate_tokenize_gbps", est_s=15)
     _run_component(lambda: bench_coverage(path),
                    "coverage_records_per_sec", est_s=35)
     _run_component(lambda: bench_sort(path), "sort_records_per_sec_mesh",

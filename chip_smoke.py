@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import hashlib
 import io
 import json
@@ -53,8 +52,7 @@ FULL_RECORDS = 1 << 22             # the cut this smoke runs at
 CHUNK_RECORDS = 1 << 18            # generator grain == DecodeGeometry tile
 # Sort / mkdup / serve subset.  ISSUE 21 asks for >= 2^20; cut to 2^19
 # because the exchange and markdup steps alone take ~440 s to compile on
-# a cold one-chip machine (size-independent), which with the device
-# plane's ~250 s leaves the 1200 s limit no room for the 2^20 run parts.
+# a cold one-chip machine (size-independent).
 # `--sort-records 1048576` restores it (PERF.md: passed on 1 and 4 chips).
 SORT_RECORDS = 1 << 19
 HEADER_TEXT = (
@@ -407,7 +405,6 @@ class Smoke:
                                      else SORT_RECORDS)
         self.subset_chunks = min(self.n_chunks, max(1, want // self.chunk))
         self.subset = self.subset_chunks * self.chunk
-        self.n_variants = 2_000 if tiny else 50_000
         self.n_regions = 12 if tiny else 48
         win = 200_000 if tiny else 4_000_000
         self.cov_lo = 1_000_001
@@ -512,7 +509,6 @@ class Smoke:
         if self.phases[-1]["ok"]:
             self.start_host_oracles()
         self.scan()
-        self.device_planes()
         self.sort_mkdup()
         self.serve()
         self.compile_cache()
@@ -629,20 +625,7 @@ class Smoke:
 
         with self.phase("3-scan") as rec:
             self.need("0-fixture")
-            from hadoop_bam_tpu.config import (
-                DEFAULT_CONFIG, plane_probe_report,
-                resolve_inflate_backend,
-            )
             with MetricsContext() as m:
-                plane = resolve_inflate_backend(DEFAULT_CONFIG)
-                probe = plane_probe_report()
-                rec["decode_plane"] = plane
-                rec["plane_probe"] = probe
-                self.say("3-scan", f"inflate_backend 'auto' resolved to "
-                                   f"{plane!r}; probe {probe}")
-                check(not (probe or {}).get("error"),
-                      f"plane probe raised: {probe}")
-
                 # summarize (flagstat over the projected prefix tiles)
                 t0 = time.perf_counter()
                 out = run_cli(["summarize", self.bam])
@@ -741,170 +724,6 @@ class Smoke:
                           "Mosaic kernel (tpu_custom_call)")
         return "mosaic" if mosaic else "xla-twin"
 
-    def device_planes(self) -> None:
-        from hadoop_bam_tpu.utils.metrics import MetricsContext
-
-        with self.phase("4-device-plane") as rec:
-            self.need("0-fixture")
-            from hadoop_bam_tpu.config import DEFAULT_CONFIG
-            from hadoop_bam_tpu.formats.bamio import read_bam_header
-            from hadoop_bam_tpu.parallel.pipeline import (
-                DEVICE_PLANE_SPAN_BYTES, flagstat_file, seq_stats_file,
-            )
-            from hadoop_bam_tpu.parallel.variant_pipeline import (
-                variant_stats_file,
-            )
-            from hadoop_bam_tpu.split.planners import plan_spans_cached
-
-            # ladder off: a device-plane failure must fail, not demote
-            dev = dataclasses.replace(DEFAULT_CONFIG,
-                                      inflate_backend="device",
-                                      adaptive_planes=False)
-            host = dataclasses.replace(DEFAULT_CONFIG,
-                                       inflate_backend="native")
-            header, _ = read_bam_header(self.bam)
-            size = os.path.getsize(self.bam)
-            n_spans = max(self.n_dev, -(-size // DEVICE_PLANE_SPAN_BYTES))
-            spans = plan_spans_cached(self.bam, header, DEFAULT_CONFIG,
-                                      num_spans=n_spans)
-            spans = list(spans)[:4 * self.n_dev]
-            rec["slice_spans"] = len(spans)
-            fams = rec.setdefault("families", {})
-
-            def family(name, run, n_of, same):
-                want = run(host)
-                with MetricsContext() as m:
-                    c0 = self._compile_state()[0]
-                    t0 = time.perf_counter()
-                    cold = run(dev)
-                    t_cold = time.perf_counter() - t0
-                    comp = self._compile_state()[0] - c0
-                    t0 = time.perf_counter()
-                    warm = run(dev)
-                    t_warm = time.perf_counter() - t0
-                snap = m.snapshot()
-                check(same(cold, want) and same(warm, want),
-                      f"{name}: device plane {cold} != host plane {want}")
-                n = n_of(want)
-                fams[name] = {
-                    "records": n, "cold_seconds": round(t_cold, 3),
-                    "compile_seconds": round(comp, 3),
-                    "warm_seconds": round(t_warm, 3),
-                    "warm_records_per_sec": round(n / t_warm, 1)}
-                self.say("4-device-plane",
-                         f"{name}: device == host on {n} records; cold "
-                         f"{t_cold:.2f}s (compile {comp:.2f}s), warm "
-                         f"{t_warm:.2f}s = {n / t_warm:.0f} records/s "
-                         f"(smoke observation, one run)")
-                return snap
-
-            def close(a, b):
-                for k in a:
-                    va, vb = a[k], b[k]
-                    if isinstance(va, (int, np.integer)):
-                        if int(va) != int(vb):
-                            return False
-                    elif not np.allclose(np.asarray(va, np.float64),
-                                         np.asarray(vb, np.float64),
-                                         rtol=1e-5, atol=1e-8):
-                        return False
-                return set(a) == set(b)
-
-            snap = family(
-                "flagstat",
-                lambda cfg: flagstat_file(self.bam, config=cfg,
-                                          header=header, spans=spans),
-                lambda w: int(w["total"]), lambda a, b: a == b)
-            check("bam.device_resolve_wall" in snap.get("wall_timers", {}),
-                  "flagstat did not run on the device plane")
-            self.check_all_devices_fed(
-                "4-device-plane",
-                self.device_rows(snap["counters"],
-                                 "pipeline.device_plane_blocks"))
-            family(
-                "payload-seq-stats",
-                lambda cfg: seq_stats_file(self.bam, config=cfg,
-                                           header=header, spans=spans),
-                lambda w: int(w["n_reads"]), close)
-
-            bcf, want_bcf = self.make_bcf()
-            snap = family(
-                "bcf-variant-stats",
-                lambda cfg: variant_stats_file(bcf, config=cfg),
-                lambda w: int(w["n_variants"]), close)
-            check("vcf.device_resolve_wall" in snap.get("wall_timers", {}),
-                  "variant stats did not run on the device plane")
-            got = variant_stats_file(bcf, config=dev)
-            check(close({k: got[k] for k in want_bcf}, want_bcf),
-                  f"variant stats {got} != NumPy reference {want_bcf}")
-
-            # cold serve tiles built on the device plane
-            from hadoop_bam_tpu.serve import ServeLoop
-            regions = self.regions(8, seed_tag=4, widths=(300, 2_000))
-            want_counts = [self.ref.region_count(lo, hi)
-                           for _r, lo, hi in regions]
-            names = [r for r, _lo, _hi in regions]
-            with MetricsContext() as m, ServeLoop(
-                    config=dataclasses.replace(dev, serve_prefetch=False)
-                    ) as loop:
-                t0 = time.perf_counter()
-                cold = loop.query(self.bam, names)
-                t_cold = time.perf_counter() - t0
-            builds = int(m.snapshot()["counters"].get(
-                "serve.device_tile_builds", 0))
-            check([r.count for r in cold] == want_counts,
-                  f"device-built tiles answered {[r.count for r in cold]}, "
-                  f"reference {want_counts}")
-            check(builds > 0, "no serve tile was built on the device plane")
-            fams["serve-cold-tiles"] = {
-                "regions": len(names), "device_tile_builds": builds,
-                "cold_seconds": round(t_cold, 3)}
-            self.say("4-device-plane",
-                     f"serve-cold-tiles: {len(names)} regions == NumPy "
-                     f"reference, {builds} tiles built on the device in "
-                     f"{t_cold:.2f}s (smoke observation, one run)")
-
-    def make_bcf(self):
-        """A seeded 3-sample call set (the shape bench.py's BCF row uses:
-        biallelic SNPs, PASS, GT in {0/0, 0/1, 1/1, ./.}) written
-        through the repo's writer, plus its NumPy stats."""
-        from hadoop_bam_tpu.api.writers import open_vcf_writer
-        from hadoop_bam_tpu.formats.vcf import VCFHeader, VcfRecord
-
-        n = self.n_variants
-        rng = np.random.default_rng([self.args.seed, 7])
-        hdr = VCFHeader.from_text(
-            "##fileformat=VCFv4.2\n"
-            f"##contig=<ID={CONTIG},length={CONTIG_LEN}>\n"
-            '##INFO=<ID=DP,Number=1,Type=Integer,Description="Depth">\n'
-            '##FORMAT=<ID=GT,Number=1,Type=String,Description="Genotype">\n'
-            "#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO\tFORMAT\t"
-            "s0\ts1\ts2\n")
-        pos = np.cumsum(rng.integers(1, 50, n))
-        ref = rng.integers(0, 4, n)
-        alt = (ref + rng.integers(1, 4, n)) % 4
-        gt = rng.integers(0, 4, (n, 3))
-        text = ("0/0", "0/1", "1/1", "./.")
-        path = os.path.join(self.scratch, "calls.bcf")
-        with open_vcf_writer(path, hdr) as w:
-            for i in range(n):
-                g = "\t".join(text[k] for k in gt[i])
-                w.write_record(VcfRecord.from_line(
-                    f"{CONTIG}\t{pos[i]}\t.\t{'ACGT'[ref[i]]}\t"
-                    f"{'ACGT'[alt[i]]}\t{30 + i % 40}\tPASS\t"
-                    f"DP={i % 100}\tGT\t{g}"))
-        dosage = np.where(gt == 3, -1, gt)
-        called = dosage >= 0
-        n_called = called.sum(1)
-        af = np.where(called, dosage, 0).sum(1) / (2.0 * np.maximum(n_called,
-                                                                    1))
-        has = n_called > 0
-        want = {"n_variants": n, "n_snp": n, "n_pass": n,
-                "n_af": int(has.sum()),
-                "mean_af": float(af[has].mean()),
-                "sample_callrate": called.sum(0) / n}
-        return path, want
-
     def regions(self, n: int, seed_tag: int,
                 widths=(300, 2_000, 20_000)):
         """Seeded region windows inside the subset's slice of the contig
@@ -919,7 +738,7 @@ class Smoke:
         return out
 
     def start_host_oracles(self) -> None:
-        """The serial host references phase 5 compares against
+        """The serial host references phase 4 compares against
         (utils/sort.sort_bam, prep/oracle.py) are pure host Python; they
         run on a thread of their own from here on, beside the device
         phases, instead of adding their wall to the run's."""
@@ -939,7 +758,7 @@ class Smoke:
                 markdup_bam_oracle(self.shuffled, self.oracle["mkdup"])
                 self.oracle["seconds"] = (round(t1 - t0, 2), round(
                     time.perf_counter() - t1, 2))
-            except BaseException as e:  # noqa: BLE001 — joined in phase 5
+            except BaseException as e:  # noqa: BLE001 — joined in phase 4
                 self.oracle["error"] = e
 
         self.oracle_thread = threading.Thread(target=work, daemon=True)
@@ -965,18 +784,18 @@ class Smoke:
         doc = {"seconds": round(dt, 2),
                "compile_seconds": round(self._compile_state()[0] - c0, 2),
                "rounds": rounds, "device_rows": rows, "sha256": got}
-        self.say("5-sort-mkdup",
+        self.say("4-sort-mkdup",
                  f"hbam {' '.join(argv[:1] + argv[3:])}: {self.subset} "
                  f"records, {rounds} exchange round(s), byte-identical to "
                  f"the host oracle (sha256 {got[:16]}) in {dt:.1f}s "
                  f"(compile {doc['compile_seconds']}s)")
         check(rounds >= min_rounds, f"{key}: {rounds} exchange rounds, "
                                     f"wanted >= {min_rounds}")
-        self.check_all_devices_fed(f"5-sort-mkdup/{key}", rows)
+        self.check_all_devices_fed(f"4-sort-mkdup/{key}", rows)
         return doc, snap
 
     def sort_mkdup(self) -> None:
-        with self.phase("5-sort-mkdup") as rec:
+        with self.phase("4-sort-mkdup") as rec:
             self.need("0-fixture")
             self.oracle_thread.join()
             if "error" in self.oracle:
@@ -1010,11 +829,11 @@ class Smoke:
             rec["mkdup"]["duplicates_marked"] = dups
             check(dups > 0, "mkdup marked no duplicates on a fixture "
                             "seeded with them")
-            self.say("5-sort-mkdup", f"mkdup marked {dups} duplicates")
+            self.say("4-sort-mkdup", f"mkdup marked {dups} duplicates")
 
     def serve(self) -> None:
-        with self.phase("6-serve") as rec:
-            self.need("5-sort-mkdup")
+        with self.phase("5-serve") as rec:
+            self.need("4-sort-mkdup")
             from hadoop_bam_tpu.query.engine import QueryEngine, QueryRequest
             from hadoop_bam_tpu.serve import ServeLoop
             from hadoop_bam_tpu.serve.transport import make_tcp_server
@@ -1071,7 +890,7 @@ class Smoke:
                        open_breakers=health["open_breakers"])
             check(not health["open_breakers"],
                   f"open breakers after serving: {health['open_breakers']}")
-            self.say("6-serve",
+            self.say("5-serve",
                      f"{len(names)} regions over a socket, cold then warm, "
                      f"== QueryEngine.query_records == NumPy reference; "
                      f"median latency cold {lat['cold']:.1f} ms / warm "
@@ -1079,7 +898,7 @@ class Smoke:
                      f"ok; tile cache {tiles}")
 
     def compile_cache(self) -> None:
-        with self.phase("7-compile-cache") as rec:
+        with self.phase("6-compile-cache") as rec:
             total_c = sum(p.get("compile_seconds", 0) for p in self.phases)
             hits = sum(p.get("cache_hits", 0) for p in self.phases)
             wrote = sum(p.get("cache_entries_written", 0)
@@ -1087,7 +906,7 @@ class Smoke:
             rec.update(total_compile_seconds=round(total_c, 2),
                        total_cache_hits=hits, total_entries_written=wrote,
                        dir=self.cache_dir)
-            self.say("7-compile-cache",
+            self.say("6-compile-cache",
                      f"compile {total_c:.1f}s over the run; persistent "
                      f"cache at {self.cache_dir}: {hits} hits, {wrote} "
                      f"entries written (a second run in this checkout "

@@ -14,9 +14,6 @@ AST analyzers over correctness regimes generic linters cannot see:
   ``np.frombuffer(...).copy()`` materializations of inflated spans on
   the decode hot path (every extra sweep is a DRAM pass the fused
   decode exists to remove)
-- ``devicesync``   (DV9xx) — per-iteration host syncs (``np.asarray``,
-  ``jax.device_get``, ``.item()``) in loops inside the device decode
-  plane (each one stalls the token-feed pipeline behind the link)
 - ``jobsafety``    (JS1xx) — crash-safe job discipline in ``write/`` +
   the mesh sort: publication renames outside the blessed/journaled
   commit helpers, non-idempotent (random/pid/time-derived) temp names

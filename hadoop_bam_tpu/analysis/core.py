@@ -158,9 +158,9 @@ def analyzers() -> Dict[str, Analyzer]:
     """Name -> analyzer map (importing the analyzer modules on demand)."""
     # import for registration side effects
     from hadoop_bam_tpu.analysis import (  # noqa: F401
-        decodepath, devicesync, feedpath, jobsafety, layout, lockstep,
-        obsrules, planroute, querycache, servebounds, taxonomy,
-        threadsafety, trace_safety, writepath,
+        decodepath, feedpath, jobsafety, layout, lockstep, obsrules,
+        planroute, querycache, servebounds, taxonomy, threadsafety,
+        trace_safety, writepath,
     )
     return dict(_REGISTRY)
 

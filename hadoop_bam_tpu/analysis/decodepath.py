@@ -31,11 +31,10 @@ from hadoop_bam_tpu.analysis.astutil import last_segment
 from hadoop_bam_tpu.analysis.core import Finding, Module, Project, register
 
 # the modules every inflated byte flows through on the BAM-family hot
-# path: inflate dispatch + fused decode, the device-DEFLATE experiment,
-# the tile unpack layer, and the span pipeline + staging feed
+# path: inflate dispatch + fused decode, the tile unpack layer, and the
+# span pipeline + staging feed
 SCOPE = (
     "hadoop_bam_tpu/ops/inflate.py",
-    "hadoop_bam_tpu/ops/inflate_device.py",
     "hadoop_bam_tpu/ops/unpack_bam.py",
     "hadoop_bam_tpu/parallel/pipeline.py",
     "hadoop_bam_tpu/parallel/staging.py",

@@ -41,7 +41,6 @@ SCOPE = (
     "hadoop_bam_tpu/formats/bgzf.py",
     "hadoop_bam_tpu/formats/bamio.py",
     "hadoop_bam_tpu/ops/inflate.py",
-    "hadoop_bam_tpu/ops/inflate_device.py",
     "hadoop_bam_tpu/split/planners.py",
     "hadoop_bam_tpu/split/vcf_planners.py",
     "hadoop_bam_tpu/split/read_planners.py",

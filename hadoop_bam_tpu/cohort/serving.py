@@ -306,7 +306,7 @@ class CohortServer:
         with METRICS.span("cohort.slice_wall", region=region):
             # dispatch EVERY group first, drain once: per-group host
             # syncs inside the loop would serialize a device round-trip
-            # every n_dev*cap rows (the DV901 discipline, applied here)
+            # every n_dev*cap rows
             pending = []
             for t in sets:
                 if deadline is not None:

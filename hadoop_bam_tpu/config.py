@@ -86,9 +86,8 @@ _HADOOP_KEY_MAP = {
     # reference's analog was per-block zlib-over-JNI with no fusion)
     "hbam.use-fused-decode": "use_fused_decode",
     "hbam.decode-chunk-blocks": "decode_chunk_blocks",
-    # decode-plane selection (ops/inflate_device.py + the pipeline
-    # token-feed path; no reference analog — the JNI inflate had exactly
-    # one implementation)
+    # decode-plane selection (no reference analog — the JNI inflate had
+    # exactly one implementation)
     "hbam.inflate-backend": "inflate_backend",
     # region-query serving knobs (query/; no reference analog — Hadoop-BAM
     # only ever trimmed scan plans with intervals, it never served them)
@@ -376,19 +375,12 @@ class HBamConfig:
     #                                  stream tiles before the span tail
     #                                  inflates)
     inflate_backend: str = "auto"    # decode-plane selection:
-    #                                  "auto"   = probe once per process
-    #                                             and pick fused-native
-    #                                             vs the device plane
+    #                                  "auto"   = "native"
     #                                             (resolve_inflate_backend)
     #                                  "native" = host C++ inflate
     #                                             (+ fused single-pass)
     #                                  "zlib"   = Python zlib (portable;
     #                                             disables the fused path)
-    #                                  "device" = token-feed device decode
-    #                                             plane (host Huffman
-    #                                             tokenize + on-mesh LZ77
-    #                                             resolve/walk/unpack) on
-    #                                             drivers that support it
 
     # --- region-query serving (query/) ---
     query_cache_bytes: int = 256 << 20  # decoded-chunk LRU byte budget
@@ -525,43 +517,36 @@ DEFAULT_CONFIG = HBamConfig()
 
 
 # ---------------------------------------------------------------------------
-# Decode-plane selection.  ``inflate_backend="auto"`` resolves ONCE per
-# process: the probe (ops/inflate_device.probe_device_plane) times the
-# host Huffman tokenize stage against the device LZ77 resolve and picks
-# the device plane only when its pipelined wall (max of the two
-# overlapped stages) beats host inflate — which can never happen when
-# the "device" is the host CPU running XLA, so the CPU backend resolves
-# straight to "native" without paying the probe's jit compile.  Drivers
-# without a device plane treat "device" as "native" (each driver
-# documents its planes; flagstat is the token-feed pilot).
+# Decode-plane selection.  Inflate is host work by measurement: the one
+# chip run of an on-mesh DEFLATE plane (PR 21) scanned three orders of
+# magnitude under the native host feed and PR 30 deleted it (PERF.md
+# section 6).  What is left to choose is which host code inflates.
 # ---------------------------------------------------------------------------
 
-INFLATE_BACKENDS = ("auto", "native", "zlib", "device")
+INFLATE_BACKENDS = ("auto", "native", "zlib")
 
 # Decode planes, fastest first — the vocabulary plan/executor.select_plane
 # (the ONE plane-gating predicate; planroute lint PL101 keeps gates out of
 # every other package) decides over, and the rung order the resilience
 # DemotionLadder demotes along.  "fused" is a MODE of the native plane
 # (the single-pass sweep), not a plane of its own.
-DECODE_PLANES = ("device", "native", "zlib")
-
-_PLANE_CACHE: dict = {}
+DECODE_PLANES = ("native", "zlib")
 
 
 def resolve_inflate_backend(config: "HBamConfig | None") -> str:
     """Resolve a config's ``inflate_backend`` to a concrete plane name
-    ("native" | "zlib" | "device").  "auto" probes once per process.
+    ("native" | "zlib"): a pure function of the config.  "auto" is
+    "native" — the span decoders fall to zlib themselves when the native
+    library is absent.
 
     This is only the STARTING rung, and only one input of the decision:
-    per-plan routing (which plane a given op DAG actually runs on, given
-    intervals / skip_bad_spans / fused availability) is decided in
-    ``plan.executor.select_plane``, the single predicate table every
-    driver consults.  With ``config.adaptive_planes`` the
-    drivers run the resolved plane through a ``resilience.DemotionLadder``
-    — oracle-confirmed plane-local faults demote it mid-run and a
-    half-open probe revisits the faster plane after the breaker
-    cooldown, so the once-per-process probe is no longer the last word
-    on plane selection."""
+    per-plan routing (fused / fused-stream eligibility given intervals /
+    skip_bad_spans) is decided in ``plan.executor.select_plane``, the
+    single predicate table every driver consults.  With
+    ``config.adaptive_planes`` the drivers run the resolved plane
+    through a ``resilience.DemotionLadder`` — oracle-confirmed
+    plane-local faults demote it mid-run and a half-open probe revisits
+    the faster plane after the breaker cooldown."""
     backend = getattr(config, "inflate_backend", "auto") \
         if config is not None else "auto"
     if backend not in INFLATE_BACKENDS:
@@ -572,36 +557,4 @@ def resolve_inflate_backend(config: "HBamConfig | None") -> str:
         raise PlanError(
             f"unknown inflate backend {backend!r}; "
             f"expected one of {INFLATE_BACKENDS}")
-    if backend != "auto":
-        return backend
-    if "auto" not in _PLANE_CACHE:
-        _PLANE_CACHE["auto"] = _probe_auto_plane()
-    return _PLANE_CACHE["auto"]
-
-
-def _probe_auto_plane() -> str:
-    import logging
-
-    from hadoop_bam_tpu.utils.metrics import METRICS
-    try:
-        from hadoop_bam_tpu.ops.inflate_device import probe_device_plane
-        probe = probe_device_plane()
-        _PLANE_CACHE["probe"] = probe
-        return "device" if probe.get("device_wins") else "native"
-    except Exception as e:  # noqa: BLE001 — selection must never fail a run
-        # ...but a probe that raised (a device-plane compile failure on
-        # the chip, say) must not vanish: logged, counted, and kept for
-        # `hbam explain` / chip_smoke.py to show
-        logging.getLogger(__name__).exception(
-            "device decode plane probe failed; resolving 'auto' to the "
-            "native plane")
-        METRICS.count("pipeline.plane_probe_failed")
-        _PLANE_CACHE["probe"] = {"error": f"{type(e).__name__}: {e}"}
-        return "native"
-
-
-def plane_probe_report() -> "dict | None":
-    """What the once-per-process "auto" probe measured (its timings and
-    decision, or {"error": ...} when it raised); None before any "auto"
-    resolution."""
-    return _PLANE_CACHE.get("probe")
+    return "native" if backend == "auto" else backend

@@ -232,12 +232,8 @@ def _cursor_walk(b: np.ndarray, header: VCFHeader,
     checks, the fixed 24-byte prefix views, and the lockstep typed-value
     walk (alleles -> SNP test, FILTER -> PASS, FORMAT -> GT layout).
 
-    Shared verbatim by the host columnar decode (``_decode_columns``,
-    which adds the GT->dosage gather) and the device unpack route
-    (``decode_bcf_cursor_meta``, which ships the GT layout to the mesh
-    and gathers there).  Raises ``BCFError`` on corruption and
-    ``_Ineligible`` on pathological geometry — identically for both
-    consumers, so the planes agree on every input's outcome class."""
+    ``_decode_columns`` adds the GT->dosage gather.  Raises ``BCFError``
+    on corruption and ``_Ineligible`` on pathological geometry."""
     n = starts.size
     if bool((starts < 0).any()) or int(starts.max()) + 32 > b.size:
         raise BCFError("BCF record start out of range")
@@ -341,62 +337,6 @@ def _cursor_walk(b: np.ndarray, header: VCFHeader,
         "snp": snp, "is_pass": is_pass,
         "gt_typ": gt_typ, "gt_count": gt_count, "gt_off": gt_off,
     }
-
-
-def decode_bcf_cursor_meta(buf: bytes, header: VCFHeader,
-                           samples_pad: int,
-                           starts: Optional[np.ndarray] = None
-                           ) -> Optional[Dict[str, object]]:
-    """Host-side record metadata for the DEVICE variant unpack: the
-    cursor walk runs here (it is serially dependent and branch-heavy —
-    the half that does NOT vectorize), but the bulk byte work (the
-    24-byte prefix assembly and the GT payload gathers) is left to the
-    mesh, which reads them straight out of the resolved-bytes buffer via
-    ``ops/inflate_device.variant_prefix_device`` /
-    ``variant_gt_dosage_device``.
-
-    Returns None when the span is ineligible for the columnar layout
-    (same geometry guards as ``decode_bcf_columns``); raises the same
-    ``BCFError`` taxonomy on corruption.  Dict:
-
-    - ``n``: record count; ``starts`` i64 [n] record start offsets;
-    - ``flags``: u8 [n] — the PASS|SNP byte, fully host-derived;
-    - ``gt_groups``: list of (rows i64[], offs i64[], width, ploidy,
-      n_sample) — one entry per distinct GT layout, the grouping the
-      device gather is keyed by (rows not covered by any group keep the
-      all-missing dosage row).
-    """
-    b = np.frombuffer(buf, np.uint8)
-    if starts is None:
-        starts = frame_record_starts(buf)
-    starts = np.asarray(starts, np.int64)
-    n = starts.size
-    if n == 0:
-        return {"n": 0, "starts": starts, "flags": np.zeros(0, np.uint8),
-                "gt_groups": []}
-    try:
-        wk = _cursor_walk(b, header, starts)
-        gt_typ, gt_count = wk["gt_typ"], wk["gt_count"]
-        n_sample = wk["n_sample"]
-        have = gt_typ > 0
-        if bool((have & (gt_count > _MAX_GT_PLOIDY)).any()):
-            raise _Ineligible("GT ploidy too large")
-        if bool((have & (n_sample > samples_pad)).any()):
-            raise _Ineligible("record carries more samples than the tile")
-    except _Ineligible:
-        return None
-    groups = []
-    if bool(have.any()):
-        combo = (gt_typ << 48) | (gt_count << 24) | n_sample
-        for c in np.unique(combo[have]):
-            rows = np.flatnonzero(have & (combo == c))
-            groups.append((rows, wk["gt_off"][rows],
-                           _GT_DTYPES[int(gt_typ[rows[0]])].itemsize,
-                           int(gt_count[rows[0]]),
-                           int(n_sample[rows[0]])))
-    flags = (wk["is_pass"].astype(np.uint8) * FLAG_PASS
-             | wk["snp"].astype(np.uint8) * FLAG_SNP)
-    return {"n": n, "starts": starts, "flags": flags, "gt_groups": groups}
 
 
 # GT values gathered at a time: the gather's index matrix and the widened

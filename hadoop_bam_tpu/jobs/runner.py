@@ -13,6 +13,7 @@ import glob
 import os
 from typing import Callable, Dict, List, Optional, Sequence
 
+from hadoop_bam_tpu.config import resolve_inflate_backend
 from hadoop_bam_tpu.jobs import journal as jj
 from hadoop_bam_tpu.obs.context import ensure_trace
 from hadoop_bam_tpu.utils.errors import PlanError
@@ -141,6 +142,10 @@ def _resume_replayed(journal_path: str, config, state, kind: str) -> Dict:
                 if hasattr(config, k)}
     if recorded:
         config = dataclasses.replace(config, **recorded)
+    # a header naming a decode plane this build does not have (one
+    # written before PR 30 under inflate_backend="device") refuses with
+    # PlanError here instead of resuming on another plane
+    resolve_inflate_backend(config)
     if kind in ("mesh_sort_spill", "mesh_sort"):
         from hadoop_bam_tpu.parallel.mesh_sort import sort_bam_mesh
 

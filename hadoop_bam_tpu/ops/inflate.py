@@ -7,12 +7,8 @@ Paths, in preference order:
   (native/hbam_native.cpp) — the production host path feeding device batches.
 - ``zlib``: Python zlib per block (portable fallback, still batched at the
   span level).
-- ``device``: two-stage device DEFLATE (ops/inflate_device.py) — host
-  Huffman tokenize (native, threaded) + on-device LZ77 copy resolution by
-  pointer doubling.  Not the default: the Huffman stage is bit-serial, so
-  the host stage bounds throughput; PERF.md holds the measured numbers.
 
-All paths share one contract: given the raw compressed span bytes and the
+Both paths share one contract: given the raw compressed span bytes and the
 parsed block table, produce a contiguous inflated buffer + per-block inflated
 offsets.
 """
@@ -119,15 +115,12 @@ def inflate_span(raw: bytes, table: Optional[dict] = None,
     Returns (data, ubase): ``data`` is the contiguous inflated bytes of the
     span; ``ubase[i]`` is each block's starting offset within ``data`` (the
     map from (block, in-block offset) to buffer offset — i.e. from virtual
-    offsets to positions).  ``data`` is fresh memory, or — host backends —
-    the head of ``out`` (uint8, at least the blocks' ISIZE sum) when the
+    offsets to positions).  ``data`` is fresh memory, or the head of
+    ``out`` (uint8, at least the blocks' ISIZE sum) when the
     caller brings the buffer.
     """
     if table is None:
         table = block_table(raw)
-    if backend == "device":
-        from hadoop_bam_tpu.ops.inflate_device import inflate_span_device
-        return inflate_span_device(raw, table, n_threads=n_threads)
     isize = table["isize"]
     ubase = np.zeros(isize.size + 1, dtype=np.int64)
     np.cumsum(isize, out=ubase[1:])

@@ -21,7 +21,6 @@ import dataclasses
 import functools
 import logging
 import os
-import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -32,7 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hadoop_bam_tpu.parallel.mesh import shard_map
 from hadoop_bam_tpu.parallel.staging import (
-    FeedPipeline, StagingRing, TileSpec, _block_in_flight, bucket_cap,
+    FeedPipeline, TileSpec, bucket_cap,
 )
 
 from hadoop_bam_tpu.config import (
@@ -44,7 +43,7 @@ from hadoop_bam_tpu.config import (
 # span-level decoders and the existing import surface.
 from hadoop_bam_tpu.plan.executor import (  # noqa: F401 — re-exports
     FLAGSTAT_DAG, PAYLOAD_DAG, _fused_stream_gate, _use_fused,
-    host_backend_for, select_plane,
+    select_plane,
 )
 from hadoop_bam_tpu.plan.ir import SourceIR
 from hadoop_bam_tpu.formats.bam import SAMHeader
@@ -958,7 +957,7 @@ def decode_with_retry(fn: Callable, span: FileVirtualSpan,
     kind = hberrors.CORRUPT
     attempts = 0
     transient_tries = 0
-    plane = ladder.host_plane() if ladder is not None else None
+    plane = ladder.plane() if ladder is not None else None
     blamed: List[Tuple[str, BaseException]] = []
     while attempts <= policy.retries + len(blamed):
         attempts += 1
@@ -1374,20 +1373,15 @@ def iter_payload_tile_groups(path: str, spans: Sequence[FileVirtualSpan],
     pool = decode_pool(config)
     window = max(1, prefetch) * decode_pool_size(config)
 
-    # the ONE routing decision (plan/executor.py), consumed here only
-    # for host_backend and fused streaming: the payload family's DEVICE
-    # route lives in _seq_stats_impl (which never reaches this
-    # generator on the device plane) — tensor_batches consumers always
-    # materialize host row tiles, so "device" rides the host planes in
-    # this generator, "zlib"/"native" are honored as asked, and chunk
-    # streaming follows the shared fused-stream gate
+    # the ONE routing decision (plan/executor.py): the inflate backend
+    # as asked, and chunk streaming behind the shared fused-stream gate
     decision = select_plane(SourceIR(path, "bam"), PAYLOAD_DAG, config,
                             intervals=intervals)
-    host_backend = decision.host_backend
+    host_backend = decision.plane
     # same demotion ladder as flagstat's host path: corrupt failures on
     # the native rung re-decode on zlib (byte-identical) and oracle-
     # confirmed blame opens the native domain's breaker
-    ladder = decode_ladder(path, decision.backend, config) \
+    ladder = decode_ladder(path, decision.plane, config) \
         if config.adaptive_planes else None
 
     # same chunk-streaming shape as flagstat_file: fused spans hand their
@@ -1468,7 +1462,7 @@ class _StatTotals:
         ti = np.zeros(np.shape(i0), np.int64)
         with METRICS.span("pipeline.combine_wall", groups=len(self._pairs)):
             # ONE bulk device_get for every queued group (a per-group
-            # fetch in the loop is a sync per group — DV901's territory)
+            # fetch in the loop is a sync per group)
             for f, i in jax.device_get(self._pairs):
                 tf += f
                 ti += i
@@ -1869,35 +1863,6 @@ def _seq_stats_impl(path: str, mesh: Optional[Mesh] = None,
     if header is None:
         header, _ = read_bam_header(path)
 
-    # the same plane wrapper as _flagstat_impl: THE routing decision
-    # (plan/executor.select_plane) with the ladder consulted last, the
-    # device plane tried first when selected, and demotable device
-    # faults falling through to the host path below with oracle-
-    # confirmed blame recorded only after the host run completes
-    intervals = parse_config_intervals(config, header)
-    ladder = decode_ladder(path, resolve_inflate_backend(config), config) \
-        if config.adaptive_planes else None
-    device_blame: Optional[BaseException] = None
-    decision = select_plane(SourceIR(path, "bam"), PAYLOAD_DAG, config,
-                            intervals=intervals, ladder=ladder)
-    if decision.plane == "device":
-        check_quarantine_gate(path, config)
-        try:
-            out = _seq_stats_device_plane(path, mesh, config, header,
-                                          geometry, spans, quarantine,
-                                          prefetch=prefetch)
-            if ladder is not None:
-                ladder.record_success("device")
-            quarantine_run_ok(path, config)
-            return out
-        except Exception as e:  # noqa: BLE001 — plane policy boundary
-            if ladder is None or not ladder.demotable("device", e):
-                raise
-            logger.warning("device decode plane failed (%s: %s); "
-                           "demoting to the host planes for %s",
-                           type(e).__name__, e, path)
-            device_blame = e
-
     if spans is None:
         span_bytes = 8 << 20
         src = as_byte_source(path)
@@ -1927,659 +1892,6 @@ def _seq_stats_impl(path: str, mesh: Optional[Mesh] = None,
             path, spans, geometry, n_dev, config, prefetch, header=header,
             quarantine=quarantine, balance=True, emit_fn=emit):
         pass
-    result = _attach_quarantine(_payload_stats_result(totals), quarantine)
-    if ladder is not None and device_blame is not None:
-        # oracle confirmation: the host planes completed where the
-        # device plane failed — blame the device domain (opens its
-        # breaker after repeated confirmations)
-        ladder.confirm_failure("device", device_blame)
-    return result
-
-
-# ---------------------------------------------------------------------------
-# Device decode plane: the token-feed path (ops/inflate_device.py).
-#
-# Where the host planes inflate spans on CPU and ship packed ROW tiles, the
-# device plane ships LZ77 TOKEN chunks: pool workers run the bit-serial
-# native Huffman tokenize (the only unvectorizable half of inflate, CRC
-# folded in when asked) and the mesh step does everything else — LZ77
-# resolve, contiguous pack, the record walk (pointer doubling over the
-# block_size chain) and the FIXED_FIELDS unpack — so the inflated bytes
-# NEVER exist on the host on this path.  Chunks ride the existing
-# StagingRing with per-slot in-flight handles: host tokenize of group k+1
-# overlaps device resolve+unpack of group k.
-#
-# Spans whose final record is cut at the buffer end (and the remainder of
-# spans wider than the block ladder) complete through a host FIXUP decode
-# at drain time — the device reports each chunk's walk tail, and records
-# starting in [tail, span end) go through the ordinary projected-row host
-# path.  flagstat is the pilot driver; selection is config.inflate_backend
-# ("auto" probes once per process — see config.resolve_inflate_backend).
-# ---------------------------------------------------------------------------
-
-# widest token chunk one device step takes: 64 BGZF blocks (~4 MiB
-# inflated at the 64 KiB ladder rung).  Spans wider than this stream
-# their first 64 blocks through the device and the rest through the
-# host fixup, so the plane degrades gracefully instead of erroring.
-DEVICE_PLANE_MAX_BLOCKS = 64
-# compressed span grain the plane plans at when the caller didn't pin a
-# plan: small enough that a span's token chunk fits the ladder with room
-# to spare, big enough to amortize per-span Python overhead
-DEVICE_PLANE_SPAN_BYTES = 512 << 10
-
-
-@dataclasses.dataclass
-class _TokenChunk:
-    """One span's host-tokenized device-plane unit (<= MAX_BLOCKS blocks)."""
-    tokens: np.ndarray     # [used, P] u32 LZ77 tokens
-    n_tokens: np.ndarray   # [used] i32
-    isize: np.ndarray      # [used] i32
-    start: int             # record-walk start (inflated chunk coords)
-    stop: int              # ownership limit (records starting < stop)
-    used: int              # blocks tokenized for the device
-    P: int                 # ladder rung (token pad == per-block bytes)
-    n_blocks: int          # blocks in the WHOLE span (> used: host fixup)
-    span: FileVirtualSpan
-    ubase: np.ndarray      # [n_blocks+1] i64 inflated block starts
-    abs_coffs: np.ndarray  # [n_blocks] i64 absolute compressed offsets
-
-    def fixup_span(self, tail: int) -> FileVirtualSpan:
-        """The host-decoded remainder: records starting in
-        [tail, span end) — the cut final record, plus every block past
-        the device chunk for over-wide spans."""
-        blk = int(np.searchsorted(self.ubase[1:], tail, side="right"))
-        blk = min(blk, self.n_blocks - 1)
-        u = int(tail - self.ubase[blk])
-        start_v = (int(self.abs_coffs[blk]) << 16) | u
-        return FileVirtualSpan(self.span.path, start_v,
-                               self.span.end_voffset)
-
-
-def _tokenize_span_tokens(src, span: FileVirtualSpan,
-                          check_crc: bool = False
-                          ) -> Optional[_TokenChunk]:
-    """Host half of the device plane for one span: fetch + block table +
-    threaded native Huffman tokenize (CRC folded in when ``check_crc``).
-    BGZF-level faults (DEFLATE corruption, ISIZE, CRC) raise BGZFError
-    HERE, inside the retry boundary — exactly where the host planes
-    raise them.  Returns None for an empty span."""
-    src = as_byte_source(src)
-    raw, end_block_size, _next_c, lease = inflate_ops.fetch_span_raw(src, span)
-    METRICS.count("pipeline.spans")
-    if not raw:
-        return None
-    try:
-        return _tokenize_fetched(raw, end_block_size, span, check_crc)
-    finally:
-        lease.release()     # the token arrays are copies of what they need
-
-
-def _tokenize_fetched(raw, end_block_size: int, span: FileVirtualSpan,
-                      check_crc: bool) -> _TokenChunk:
-    """``_tokenize_span_tokens`` past the fetch: ``raw`` is read, not kept."""
-    from hadoop_bam_tpu.ops.inflate_device import ladder_pow2
-    from hadoop_bam_tpu.utils import native
-
-    table = inflate_ops.block_table(raw)
-    isize = table["isize"]
-    n = int(isize.size)
-    used = min(n, DEVICE_PLANE_MAX_BLOCKS)
-    src_arr = np.frombuffer(raw, dtype=np.uint8)
-    sub = isize[:used]
-    P = ladder_pow2(max(16, int(sub.max())))
-    with METRICS.span("bam.tokenize_wall", nbytes=len(raw), blocks=used):
-        try:
-            out = native.deflate_tokenize_batch(
-                src_arr, table["cdata_off"][:used],
-                table["cdata_len"][:used], P, 0, with_crc=check_crc)
-        except ValueError as e:
-            # same class as the host inflate backends: bad DEFLATE bytes
-            # are BGZF-level corruption whichever plane finds them
-            from hadoop_bam_tpu.formats import bgzf
-            raise bgzf.BGZFError(str(e)) from e
-    tokens, n_tokens, out_lens = out[:3]
-    if not np.array_equal(out_lens, sub):
-        from hadoop_bam_tpu.formats import bgzf
-        bad = int(np.nonzero(out_lens != sub)[0][0])
-        raise bgzf.BGZFError(
-            f"ISIZE mismatch in block {bad}: tokenized "
-            f"{int(out_lens[bad])}, footer says {int(sub[bad])}")
-    if check_crc:
-        expect = inflate_ops.footer_crcs(src_arr, table)[:used]
-        mism = np.nonzero(out[3] != expect)[0]
-        if mism.size:
-            from hadoop_bam_tpu.formats import bgzf
-            raise bgzf.BGZFError(
-                f"CRC32 mismatch in block(s) {mism[:8].tolist()}")
-    ub = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(isize, out=ub[1:])
-    if used == n and end_block_size:
-        stop = int(ub[n]) - int(isize[-1]) + span.end[1]
-    elif used == n:
-        stop = int(ub[n])
-    else:
-        stop = int(ub[used])
-    METRICS.count("pipeline.blocks", used)
-    METRICS.count("pipeline.inflated_bytes", int(ub[used]))
-    return _TokenChunk(tokens=tokens, n_tokens=n_tokens, isize=sub,
-                       start=span.start[1], stop=stop, used=used, P=P,
-                       n_blocks=n, span=span, ubase=ub,
-                       abs_coffs=table["coffset"] + span.start[0])
-
-
-def make_device_flagstat_step(mesh: Mesh, axis: str = "data") -> Callable:
-    """Jitted sharded step over token chunks: (tokens [n, B, P] u32,
-    n_tokens [n, B], isize [n, B], meta [n, 1, 2] (start, stop)) ->
-    (psum'd flagstat vector, per-device n_all / tail / bad).  The whole
-    decode — LZ77 resolve, contiguous pack, record walk, fixed-field
-    unpack, flagstat reduce — happens in the one jitted call; only the
-    16 counters and three walk scalars per device ever come back."""
-    key = ("device_flagstat", tuple(mesh.devices.flat), mesh.axis_names,
-           axis)
-    if key in _STEP_CACHE:
-        return _STEP_CACHE[key]
-
-    from hadoop_bam_tpu.ops.flagstat import FLAGSTAT_FIELDS
-    from hadoop_bam_tpu.ops.inflate_device import resolve_walk_fields
-
-    def per_device(tokens, n_tokens, isize, meta):
-        tokens, n_tokens = tokens[0], n_tokens[0]
-        isize, meta = isize[0], meta[0]
-        cols, valid, n_all, tail, bad = resolve_walk_fields(
-            tokens, n_tokens, isize, meta[0, 0], meta[0, 1])
-        stats = flagstat_from_columns(cols, valid)
-        vec = jnp.stack([stats[k] for k in FLAGSTAT_FIELDS])
-        return (jax.lax.psum(vec, axis),
-                n_all[None], tail[None], bad[None])
-
-    # check_vma=False: the while_loops inside the resolve and the walk
-    # have no varying-mesh-axes replication rule (same reason the Pallas
-    # seq-stats step opts out)
-    fn = shard_map(per_device, mesh=mesh, in_specs=(P(axis),) * 4,
-                   out_specs=(P(), P(axis), P(axis), P(axis)),
-                   check_vma=False)
-    step = named_step("device_flagstat_step", fn)
-    _STEP_CACHE[key] = step
-    return step
-
-
-def _flagstat_device_plane(path: str, mesh: Mesh, config: HBamConfig,
-                           header: SAMHeader,
-                           spans: Optional[Sequence[FileVirtualSpan]],
-                           quarantine: Optional[QuarantineManifest],
-                           prefetch: int = 2) -> Dict[str, int]:
-    """flagstat through the token-feed device decode plane.
-
-    Pool workers tokenize spans (bam.tokenize_wall) while this thread
-    packs token chunks into StagingRing slots and dispatches the fused
-    resolve+walk+unpack step (bam.device_resolve_wall, stage timer
-    pipeline.device_inflate) — tokenize of group k+1 overlaps device
-    decode of group k, and the ring's per-slot in-flight handles keep a
-    buffer from being overwritten while its transfer is still reading.
-    Walk tails drain once at the end; cut final records and over-wide
-    spans complete through the host projected-row fixup path."""
-    from hadoop_bam_tpu.ops.flagstat import FLAGSTAT_FIELDS
-    from hadoop_bam_tpu.ops.inflate_device import records_cap
-    from hadoop_bam_tpu.ops.rans import _round_pow2
-    from hadoop_bam_tpu.utils import native
-    from hadoop_bam_tpu.utils.errors import CorruptDataError
-
-    if not native.available():
-        raise PlanError(
-            "inflate_backend='device' needs the native tokenizer "
-            "(hbam_deflate_tokenize_batch); native library unavailable")
-    n_dev = int(np.prod(mesh.devices.shape))
-    if spans is None:
-        src0 = as_byte_source(path)
-        n_spans = max(n_dev, int(np.ceil(src0.size
-                                         / DEVICE_PLANE_SPAN_BYTES)))
-        src0.close()
-        from hadoop_bam_tpu.split.planners import plan_spans_cached
-        with METRICS.span("bam.plan_wall", spans=n_spans):
-            spans = plan_spans_cached(path, header, config,
-                                      num_spans=n_spans)
-    spans = list(spans)
-    if quarantine is not None and quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    check_crc = bool(config.check_crc)
-    step = make_device_flagstat_step(mesh)
-    sharding = NamedSharding(mesh, P("data"))
-    src = _resilient_source(path, config)
-    pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
-    ring_slots = int(config.feed_ring_slots)
-    # the ring is sized LAZILY to the ladder shapes the plan actually
-    # produces (worst case [n_dev, 64, 65536] u32 is a quarter GB of
-    # token staging on a wide mesh; a small-block plan needs a tiny
-    # fraction of that).  Growing mints a fresh ring after draining the
-    # old slots' in-flight handles — shapes only cross a ladder rung a
-    # bounded number of times per run.
-    ring_state: Dict[str, object] = {"ring": None, "B": 0, "P": 0}
-    cancel = threading.Event()
-    totals_vec = None
-    pending: List[Tuple] = []          # (handles, chunks, records cap)
-
-    def get_ring(B: int, Pg: int) -> StagingRing:
-        ring = ring_state["ring"]
-        if ring is not None and B <= ring_state["B"] \
-                and Pg <= ring_state["P"]:
-            return ring
-        if ring is not None:
-            for slot in ring.slots:
-                if slot.in_flight is not None:
-                    _block_in_flight(slot.in_flight)
-                    slot.in_flight = None
-        ring_state["B"] = max(B, int(ring_state["B"]))
-        ring_state["P"] = max(Pg, int(ring_state["P"]))
-        ring_state["ring"] = StagingRing(
-            n_dev, int(ring_state["B"]),
-            [TileSpec((int(ring_state["P"]),), np.uint32),  # tokens
-             TileSpec((), np.int32),                        # n_tokens
-             TileSpec((), np.int32),                        # isize
-             TileSpec((2,), np.int32)],          # row 0: (start, stop)
-            slots=ring_slots)
-        return ring_state["ring"]
-
-    def decode(span):
-        def inner(s):
-            return _tokenize_span_tokens(src, s, check_crc)
-        with METRICS.timer("pipeline.host_decode"), \
-                METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("bam.host_decode_wall"):
-            return decode_with_retry(inner, span, config,
-                                     quarantine=quarantine)
-
-    def dispatch_group(group: List[_TokenChunk]) -> None:
-        nonlocal totals_vec
-        B = max(_round_pow2(c.used, 8) for c in group)
-        Pg = max(c.P for c in group)
-        slot = get_ring(B, Pg).lease(cancel)
-        if slot.in_flight is not None:
-            # the slot's previous dispatch may still be transferring from
-            # — or, on the CPU backend, COMPUTING OVER an alias of —
-            # these buffers; the wait is time spent on device resolve of
-            # an earlier group, so it accrues to the resolve wall
-            with METRICS.timer("pipeline.device_inflate"), \
-                    METRICS.span("bam.device_resolve_wall", wait=True), \
-                    METRICS.span("staging.transfer_wait"):
-                _block_in_flight(slot.in_flight)
-            slot.in_flight = None
-        tok, nt, isz, meta = slot.arrays
-        for dev in range(n_dev):
-            if dev < len(group):
-                c = group[dev]
-                tok[dev, :c.used, :c.P] = c.tokens
-                nt[dev, :c.used] = c.n_tokens
-                isz[dev, :c.used] = c.isize
-                if c.used < B:
-                    # stale token rows are inert under n_tokens == 0 and
-                    # isize == 0; only the masks need zeroing
-                    nt[dev, c.used:B] = 0
-                    isz[dev, c.used:B] = 0
-                meta[dev, 0, 0] = c.start
-                meta[dev, 0, 1] = c.stop
-            else:
-                nt[dev, :B] = 0
-                isz[dev, :B] = 0
-                meta[dev, 0] = 0
-        views = (tok[:, :B, :Pg], nt[:, :B], isz[:, :B], meta[:, :1])
-        # chaos point at the shard_map step boundary: an injected fault
-        # here models a device/runtime step failure — it unwinds the
-        # whole device-plane run, which is exactly what the flagstat
-        # ladder wrapper demotes on
-        chaos.fire("device.step", blocks=int(sum(c.used for c in group)))
-        with METRICS.timer("pipeline.device_inflate"), \
-                METRICS.span("bam.device_resolve_wall",
-                             blocks=int(sum(c.used for c in group))):
-            args = [jax.device_put(v, sharding) for v in views]
-            vec, n_all, tails, bad = step(*args)
-            totals_vec = vec if totals_vec is None \
-                else _ADD(totals_vec, vec)
-        METRICS.count("pipeline.dispatch_bytes",
-                      sum(int(v.nbytes) for v in views))
-        METRICS.count_per_device("pipeline.device_plane_blocks",
-                                 [c.used for c in group])
-        # the slot's in-flight handle carries the step OUTPUTS, not just
-        # the transferred inputs: a [:, :B, :P] view of a ring slot is a
-        # CONTIGUOUS prefix, which CPU jax.device_put may zero-copy
-        # alias — the resolve step would then still be reading the
-        # buffer when the next group's pack overwrites it.  Waiting on
-        # the outputs means the compute (hence every read of the
-        # aliased memory) has finished before the slot is reused.
-        slot.in_flight = (tuple(args), (vec, n_all, tails, bad))
-        slot.release()
-        pending.append(((n_all, tails, bad), list(group),
-                        records_cap(B, Pg)))
-
-    group: List[_TokenChunk] = []
-    try:
-        for chunk in _iter_windowed(pool, spans, decode, window,
-                                    config=config):
-            if chunk is None:
-                continue
-            group.append(chunk)
-            if len(group) == n_dev:
-                dispatch_group(group)
-                group = []
-        if group:
-            dispatch_group(group)
-    finally:
-        cancel.set()
-
-    # one bulk device_get drains every group's walk scalars (a per-group
-    # fetch in the loop would sync the pipeline it exists to overlap);
-    # the block accrues to the resolve wall — it IS waiting for the
-    # device to finish the outstanding groups
-    with METRICS.timer("pipeline.device_inflate"), \
-            METRICS.span("bam.device_resolve_wall", drain=True):
-        fetched = jax.device_get([p[0] for p in pending]) if pending \
-            else []
-    fix_spans: List[FileVirtualSpan] = []
-    n_records = 0
-    for (n_all, tails, bad), chunks, rec_cap in (
-            (f, p[1], p[2]) for f, p in zip(fetched, pending)):
-        for dev, c in enumerate(chunks):
-            if int(bad[dev]):
-                raise CorruptDataError(
-                    f"malformed BAM record chain in span {c.span}")
-            if int(n_all[dev]) > rec_cap:
-                raise CorruptDataError(
-                    f"record count {int(n_all[dev])} exceeds capacity "
-                    f"{rec_cap} in span {c.span}")
-            n_records += int(n_all[dev])
-            tail = int(tails[dev])
-            if tail < c.stop or c.used < c.n_blocks:
-                fix_spans.append(c.fixup_span(tail))
-    METRICS.count("pipeline.records", n_records)
-
-    if fix_spans:
-        # host fixup: the cut/remainder records go through the ordinary
-        # projected-row plane and the cached flagstat tile step
-        projection = FLAGSTAT_PROJECTION
-        row_bytes = projection_row_bytes(projection)
-        tile_step = make_flagstat_tile_step(mesh, projection=projection)
-
-        def fix_rows():
-            for fs in fix_spans:
-                def inner(s):
-                    return decode_span_prefix_host(
-                        src, s, check_crc, "auto", projection,
-                        want_voffs=False, header=header, config=config)[0]
-                with METRICS.timer("pipeline.host_decode"), \
-                        METRICS.wall_timer("pipeline.host_decode_wall"), \
-                        METRICS.span("bam.host_decode_wall"):
-                    rows = decode_with_retry(inner, fs, config,
-                                             quarantine=quarantine)
-                yield ((rows if rows is not None
-                        else np.empty((0, row_bytes), np.uint8)),)
-
-        fp = FeedPipeline(n_dev, 4096, (TileSpec((row_bytes,), np.uint8),),
-                          balance=True, config=config, fmt="bam")
-
-        def fix_dispatch(arrays, counts):
-            nonlocal totals_vec
-            t = jax.device_put(arrays[0], sharding)
-            cc = jax.device_put(counts, sharding)
-            with METRICS.span("bam.kernel_wall"):
-                v = tile_step(t, cc)
-                totals_vec = v if totals_vec is None \
-                    else _ADD(totals_vec, v)
-            return t, cc
-
-        fp.feed(fix_rows(), fix_dispatch)
-
-    if totals_vec is None:
-        host = np.zeros(len(FLAGSTAT_FIELDS), dtype=np.int64)
-    else:
-        with METRICS.timer("pipeline.device_drain"), \
-                METRICS.span("bam.combine_wall"):
-            host = np.asarray(jax.device_get(totals_vec), dtype=np.int64)
-    return _attach_quarantine(
-        {k: int(host[i]) for i, k in enumerate(FLAGSTAT_FIELDS)},
-        quarantine)
-
-
-def make_device_seq_stats_step(mesh: Mesh, geometry: PayloadGeometry,
-                               axis: str = "data") -> Callable:
-    """Jitted sharded step over token chunks for the payload family:
-    (tokens [n, B, P] u32, n_tokens [n, B], isize [n, B], meta [n, 1, 2])
-    -> (psum'd f32 [2] / i32 [1+16] payload stat sums, per-device
-    n_all / tail / bad).  Resolve + pack + record walk + segmented
-    seq/qual gather + the fused Pallas payload kernel, all in one jitted
-    call — the inflated bytes and the payload tiles never exist on the
-    host."""
-    key = ("device_seq_stats", tuple(mesh.devices.flat), mesh.axis_names,
-           axis, geometry)
-    if key in _STEP_CACHE:
-        return _STEP_CACHE[key]
-
-    from hadoop_bam_tpu.ops.inflate_device import resolve_walk_payload
-    from hadoop_bam_tpu.ops.seq_pallas import seq_qual_stats
-
-    interpret = mesh.devices.flat[0].platform != "tpu"
-
-    def per_device(tokens, n_tokens, isize, meta):
-        tokens, n_tokens = tokens[0], n_tokens[0]
-        isize, meta = isize[0], meta[0]
-        cols, seq, qual, valid, n_all, tail, bad = resolve_walk_payload(
-            tokens, n_tokens, isize, meta[0, 0], meta[0, 1],
-            max_len=geometry.max_len, seq_stride=geometry.seq_stride,
-            qual_stride=geometry.qual_stride)
-        # same length rule as make_seq_stats_step (clipped low too: a
-        # corrupt negative l_seq must not reach the Pallas grid — the
-        # drain raises on the walk's bad flag before stats are used)
-        lengths = jnp.where(
-            valid, jnp.clip(cols["l_seq"], 0, geometry.max_len), 0)
-        # records_cap is a pow2 >= 16, block_n a pow2, so min divides
-        stats = seq_qual_stats(
-            seq, qual, lengths,
-            block_n=min(geometry.block_n, seq.shape[0]),
-            interpret=interpret)
-        fvec, ivec = _payload_stats_tail(stats, valid, axis)
-        return fvec, ivec, n_all[None], tail[None], bad[None]
-
-    fn = shard_map(per_device, mesh=mesh, in_specs=(P(axis),) * 4,
-                   out_specs=(P(), P(), P(axis), P(axis), P(axis)),
-                   check_vma=False)
-    step = named_step("device_seq_stats_step", fn)
-    _STEP_CACHE[key] = step
-    return step
-
-
-def _seq_stats_device_plane(path: str, mesh: Mesh, config: HBamConfig,
-                            header: SAMHeader,
-                            geometry: PayloadGeometry,
-                            spans: Optional[Sequence[FileVirtualSpan]],
-                            quarantine: Optional[QuarantineManifest],
-                            prefetch: int = 2) -> Dict[str, object]:
-    """seq_stats through the token-feed device decode plane — the same
-    overlap structure as ``_flagstat_device_plane`` (pool tokenize of
-    group k+1 under device resolve of group k, StagingRing in-flight
-    handles, one bulk scalar drain, host fixups for cut tails and
-    over-wide spans), with the payload step in place of the flagstat
-    reduce."""
-    from hadoop_bam_tpu.ops.inflate_device import records_cap
-    from hadoop_bam_tpu.ops.rans import _round_pow2
-    from hadoop_bam_tpu.utils import native
-    from hadoop_bam_tpu.utils.errors import CorruptDataError
-
-    if not native.available():
-        raise PlanError(
-            "inflate_backend='device' needs the native tokenizer "
-            "(hbam_deflate_tokenize_batch); native library unavailable")
-    n_dev = int(np.prod(mesh.devices.shape))
-    if spans is None:
-        src0 = as_byte_source(path)
-        n_spans = max(n_dev, int(np.ceil(src0.size
-                                         / DEVICE_PLANE_SPAN_BYTES)))
-        src0.close()
-        from hadoop_bam_tpu.split.planners import plan_spans_cached
-        with METRICS.span("bam.plan_wall", spans=n_spans):
-            spans = plan_spans_cached(path, header, config,
-                                      num_spans=n_spans)
-    spans = list(spans)
-    if quarantine is not None and quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    check_crc = bool(config.check_crc)
-    step = make_device_seq_stats_step(mesh, geometry)
-    sharding = NamedSharding(mesh, P("data"))
-    src = _resilient_source(path, config)
-    pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
-    ring_slots = int(config.feed_ring_slots)
-    ring_state: Dict[str, object] = {"ring": None, "B": 0, "P": 0}
-    cancel = threading.Event()
-    totals = _StatTotals()
-    pending: List[Tuple] = []          # (handles, chunks, records cap)
-
-    def get_ring(B: int, Pg: int) -> StagingRing:
-        ring = ring_state["ring"]
-        if ring is not None and B <= ring_state["B"] \
-                and Pg <= ring_state["P"]:
-            return ring
-        if ring is not None:
-            for slot in ring.slots:
-                if slot.in_flight is not None:
-                    _block_in_flight(slot.in_flight)
-                    slot.in_flight = None
-        ring_state["B"] = max(B, int(ring_state["B"]))
-        ring_state["P"] = max(Pg, int(ring_state["P"]))
-        ring_state["ring"] = StagingRing(
-            n_dev, int(ring_state["B"]),
-            [TileSpec((int(ring_state["P"]),), np.uint32),  # tokens
-             TileSpec((), np.int32),                        # n_tokens
-             TileSpec((), np.int32),                        # isize
-             TileSpec((2,), np.int32)],          # row 0: (start, stop)
-            slots=ring_slots)
-        return ring_state["ring"]
-
-    def decode(span):
-        def inner(s):
-            return _tokenize_span_tokens(src, s, check_crc)
-        with METRICS.timer("pipeline.host_decode"), \
-                METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("bam.host_decode_wall"):
-            return decode_with_retry(inner, span, config,
-                                     quarantine=quarantine)
-
-    def dispatch_group(group: List[_TokenChunk]) -> None:
-        B = max(_round_pow2(c.used, 8) for c in group)
-        Pg = max(c.P for c in group)
-        slot = get_ring(B, Pg).lease(cancel)
-        if slot.in_flight is not None:
-            with METRICS.timer("pipeline.device_inflate"), \
-                    METRICS.span("bam.device_resolve_wall", wait=True), \
-                    METRICS.span("staging.transfer_wait"):
-                _block_in_flight(slot.in_flight)
-            slot.in_flight = None
-        tok, nt, isz, meta = slot.arrays
-        for dev in range(n_dev):
-            if dev < len(group):
-                c = group[dev]
-                tok[dev, :c.used, :c.P] = c.tokens
-                nt[dev, :c.used] = c.n_tokens
-                isz[dev, :c.used] = c.isize
-                if c.used < B:
-                    nt[dev, c.used:B] = 0
-                    isz[dev, c.used:B] = 0
-                meta[dev, 0, 0] = c.start
-                meta[dev, 0, 1] = c.stop
-            else:
-                nt[dev, :B] = 0
-                isz[dev, :B] = 0
-                meta[dev, 0] = 0
-        views = (tok[:, :B, :Pg], nt[:, :B], isz[:, :B], meta[:, :1])
-        chaos.fire("device.step", blocks=int(sum(c.used for c in group)))
-        with METRICS.timer("pipeline.device_inflate"), \
-                METRICS.span("bam.device_resolve_wall",
-                             blocks=int(sum(c.used for c in group))):
-            args = [jax.device_put(v, sharding) for v in views]
-            fvec, ivec, n_all, tails, bad = step(*args)
-            totals.add(fvec, ivec)
-        METRICS.count("pipeline.dispatch_bytes",
-                      sum(int(v.nbytes) for v in views))
-        METRICS.count_per_device("pipeline.device_plane_blocks",
-                                 [c.used for c in group])
-        # in-flight carries the step OUTPUTS: CPU device_put may
-        # zero-copy alias the contiguous ring-prefix views (see
-        # _flagstat_device_plane's dispatch for the full story)
-        slot.in_flight = (tuple(args), (fvec, ivec, n_all, tails, bad))
-        slot.release()
-        pending.append(((n_all, tails, bad), list(group),
-                        records_cap(B, Pg)))
-
-    group: List[_TokenChunk] = []
-    try:
-        for chunk in _iter_windowed(pool, spans, decode, window,
-                                    config=config):
-            if chunk is None:
-                continue
-            group.append(chunk)
-            if len(group) == n_dev:
-                dispatch_group(group)
-                group = []
-        if group:
-            dispatch_group(group)
-    finally:
-        cancel.set()
-
-    with METRICS.timer("pipeline.device_inflate"), \
-            METRICS.span("bam.device_resolve_wall", drain=True):
-        fetched = jax.device_get([p[0] for p in pending]) if pending \
-            else []
-    fix_spans: List[FileVirtualSpan] = []
-    n_records = 0
-    for (n_all, tails, bad), chunks, rec_cap in (
-            (f, p[1], p[2]) for f, p in zip(fetched, pending)):
-        for dev, c in enumerate(chunks):
-            if int(bad[dev]):
-                raise CorruptDataError(
-                    f"malformed BAM record chain in span {c.span}")
-            if int(n_all[dev]) > rec_cap:
-                raise CorruptDataError(
-                    f"record count {int(n_all[dev])} exceeds capacity "
-                    f"{rec_cap} in span {c.span}")
-            n_records += int(n_all[dev])
-            tail = int(tails[dev])
-            if tail < c.stop or c.used < c.n_blocks:
-                fix_spans.append(c.fixup_span(tail))
-    METRICS.count("pipeline.records", n_records)
-
-    if fix_spans:
-        # host fixup: cut/remainder records go through the ordinary
-        # payload host packer and the cached host payload step — the
-        # same stats semantics, so totals merge exactly
-        widths = (PREFIX, geometry.seq_stride, geometry.qual_stride)
-        host_step = make_seq_stats_step(mesh, geometry)
-
-        def fix_rows():
-            for fs in fix_spans:
-                def inner(s):
-                    return decode_span_payload_host(
-                        src, s, geometry, check_crc, "auto",
-                        header=header, config=config)[:3]
-                with METRICS.timer("pipeline.host_decode"), \
-                        METRICS.wall_timer("pipeline.host_decode_wall"), \
-                        METRICS.span("bam.host_decode_wall"):
-                    out = decode_with_retry(inner, fs, config,
-                                            quarantine=quarantine)
-                yield out if out is not None else tuple(
-                    np.empty((0, w), np.uint8) for w in widths)
-
-        fp = FeedPipeline(n_dev, geometry.tile_records,
-                          [TileSpec((w,), np.uint8) for w in widths],
-                          block_n=geometry.block_n, balance=True,
-                          config=config, fmt="bam")
-
-        def fix_dispatch(arrays, counts):
-            args = [jax.device_put(a, sharding) for a in arrays]
-            cc = jax.device_put(counts, sharding)
-            with METRICS.span("bam.kernel_wall"):
-                totals.add(*host_step(*args, cc))
-            return (*args, cc)
-
-        fp.feed(fix_rows(), fix_dispatch)
-
     return _attach_quarantine(_payload_stats_result(totals), quarantine)
 
 
@@ -2647,45 +1959,15 @@ def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
     # this run through as the probe and a clean finish heals it
     check_quarantine_gate(path, config)
     intervals = parse_config_intervals(config, header)
-    # the demotion ladder: plane-local faults demote device -> native ->
-    # zlib mid-run with byte-identical results and heal back through
-    # half-open probes (resilience/domains.py)
-    ladder = decode_ladder(path, resolve_inflate_backend(config), config) \
-        if config.adaptive_planes else None
-    device_blame: Optional[BaseException] = None
-    # THE routing decision (plan/executor.select_plane): device plane
-    # when the token-feed DAG applies and every gate passes (the breaker
-    # gate consumes a half-open probe slot, so select_plane consults it
-    # last, only when the device path would actually run)
+    # THE routing decision (plan/executor.select_plane)
     decision = select_plane(SourceIR(path, "bam"), FLAGSTAT_DAG, config,
-                            intervals=intervals, ladder=ladder)
-    if decision.plane == "device":
-        # the token-feed device decode plane (resolve+walk+unpack on the
-        # mesh).  Interval filtering needs whole-span offsets and
-        # skip_bad_spans needs span-granular quarantine — both fall back
-        # to the host planes, same gating as fused chunk streaming.
-        try:
-            out = _flagstat_device_plane(path, mesh, config, header,
-                                         spans, quarantine,
-                                         prefetch=prefetch)
-            if ladder is not None:
-                ladder.record_success("device")
-            quarantine_run_ok(path, config)
-            return out
-        except Exception as e:  # noqa: BLE001 — plane policy boundary
-            if ladder is None or not ladder.demotable("device", e):
-                raise
-            # mid-run demotion: the device totals died with the
-            # exception, so the host planes recompute from scratch —
-            # byte-identical results, slower plane.  Blame lands on the
-            # device domain only if the host run COMPLETES (oracle
-            # confirmation, below); its breaker opening keeps later
-            # runs on the host planes until a half-open probe heals.
-            logger.warning("device decode plane failed (%s: %s); "
-                           "demoting to the host planes for %s",
-                           type(e).__name__, e, path)
-            device_blame = e
-    host_backend = decision.host_backend
+                            intervals=intervals)
+    host_backend = decision.plane
+    # the demotion ladder: plane-local faults demote native -> zlib
+    # mid-run with byte-identical results and heal back through
+    # half-open probes (resilience/domains.py)
+    ladder = decode_ladder(path, host_backend, config) \
+        if config.adaptive_planes else None
 
     if spans is None:
         # Span size trades host-decode parallelism (smaller = more spans
@@ -2805,11 +2087,6 @@ def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
         with METRICS.timer("pipeline.device_drain"), \
                 METRICS.span("bam.combine_wall"):
             host = np.asarray(jax.device_get(totals_vec), dtype=np.int64)
-    if ladder is not None and device_blame is not None:
-        # the host planes completed the run the device plane could not:
-        # oracle-confirmed plane-local fault — charge the device domain
-        # (enough of these open its breaker; a half-open probe heals it)
-        ladder.confirm_failure("device", device_blame)
     quarantine_run_ok(path, config)
     return _attach_quarantine(
         {k: int(host[i]) for i, k in enumerate(FLAGSTAT_FIELDS)}, quarantine)
@@ -2841,10 +2118,7 @@ def decode_span_cigar_rows(source, span: FileVirtualSpan, max_cigar: int,
     span-retry boundary (a user-parameter error must not be retried or
     skip_bad_spans-eaten as corruption).
     """
-    # coverage has no device plane (the cigar series is variable-length);
-    # "device" rides the host planes, "zlib"/"native" are honored
-    # (plan/executor owns the mapping)
-    host_backend = host_backend_for(config)
+    host_backend = resolve_inflate_backend(config)
     got = _decode_span_fused(source, span, "offsets", check_crc=check_crc,
                              want_voffs=False, config=config) \
         if _use_fused(config, host_backend) else None

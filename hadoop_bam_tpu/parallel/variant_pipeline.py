@@ -18,10 +18,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import itertools
-import logging
-import struct
 import time
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,19 +29,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from hadoop_bam_tpu.parallel.mesh import shard_map
 from hadoop_bam_tpu.parallel.staging import FeedPipeline
 
-from hadoop_bam_tpu.config import (
-    DEFAULT_CONFIG, HBamConfig, resolve_inflate_backend,
-)
+from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.formats.vcf import VariantBatch, VCFHeader
 from hadoop_bam_tpu.parallel.pipeline import (
     _STEP_CACHE, _StatTotals, _iter_windowed, pipeline_span_count,
 )
-from hadoop_bam_tpu.resilience import chaos
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import decode_pool, decode_pool_size
 from hadoop_bam_tpu.utils.stepcache import named_step
-
-logger = logging.getLogger(__name__)
 
 # dispatch-bucket granularity for variant tiles (no Pallas block
 # constraint on this path; 64 keeps the jit shape ladder tiny)
@@ -560,109 +553,9 @@ def make_variant_stats_step(mesh: Mesh, geometry: VariantGeometry,
     return step
 
 
-# ---------------------------------------------------------------------------
-# The variant device decode plane (ops/inflate_device.py token feed).
-#
-# Pool workers tokenize BGZF BCF spans (the bit-serial Huffman half);
-# the mesh resolves + packs the span's bytes (LZ77 on device — no host
-# inflate call anywhere on this route).  The serially dependent cursor
-# walk over typed-value descriptors runs on the HOST against one bulk
-# copy of the resolved buffer (formats/bcf_columns.decode_bcf_cursor_meta
-# — lengths chase and flag derivation, a few bytes per record), while
-# the BULK byte work rides the device-resident buffer: the [n, 24]
-# fixed-prefix assembly (variant_prefix_device) and the grouped GT
-# gathers -> dosage (variant_gt_dosage_device).  Cut tail records and
-# over-wide spans complete through the host BCF oracle, exactly like
-# the BAM device plane's fixups.
-# ---------------------------------------------------------------------------
-
-
-@jax.jit
-def _variant_tile_stats(chrom, pos, flags, dosage, count):
-    """Single-tile twin of make_variant_stats_step's per-device math
-    (no psum — the device plane accumulates via _StatTotals): the SAME
-    stat semantics, so device-plane and host-plane totals merge and
-    compare exactly."""
-    cap = flags.shape[0]
-    valid = jnp.arange(cap, dtype=jnp.int32) < count
-    vi = valid.astype(jnp.int32)
-    n_variants = vi.sum()
-    n_snp = (valid & ((flags & FLAG_SNP) != 0)).sum().astype(jnp.int32)
-    n_pass = (valid & ((flags & FLAG_PASS) != 0)).sum().astype(jnp.int32)
-    d = dosage.astype(jnp.int32)
-    called = (d >= 0) & valid[:, None]
-    n_called = called.sum(axis=1)
-    alt_sum = jnp.where(called, d, 0).sum(axis=1).astype(jnp.float32)
-    has_calls = n_called > 0
-    af = jnp.where(has_calls,
-                   alt_sum / (2.0 * jnp.maximum(n_called, 1)
-                              .astype(jnp.float32)),
-                   0.0)
-    sum_af = (af * valid.astype(jnp.float32)).sum()
-    n_af = (has_calls & valid).sum().astype(jnp.int32)
-    per_sample_called = called.astype(jnp.int32).sum(axis=0)
-    ivec = jnp.concatenate([
-        jnp.stack([n_variants, n_snp, n_pass, n_af]), per_sample_called])
-    return sum_af[None], ivec
-
-
-def _round_pow2_min8(x: int) -> int:
-    from hadoop_bam_tpu.ops.rans import _round_pow2
-    return _round_pow2(max(int(x), 8), 8)
-
-
-def _resolved_span_bytes(chunk) -> np.ndarray:
-    """Resolve one token chunk on device and return (device buffer,
-    host view of its first ``total`` bytes).  The ONE host sync per
-    span on the variant device route — the cursor walk is serially
-    dependent and must read real bytes; everything bulk (prefix tile,
-    GT gathers) stays on the device buffer this function also returns.
-    Module-level on purpose: the per-span loop calls it, and the single
-    bulk copy is the approved sync shape (DV901)."""
-    from hadoop_bam_tpu.ops.inflate_device import resolve_tokens_packed
-
-    B = _round_pow2_min8(chunk.used)
-    tokens, nt, isz = chunk.tokens, chunk.n_tokens, chunk.isize
-    if B != chunk.used:
-        tokens = np.vstack(
-            [tokens, np.zeros((B - chunk.used, chunk.P), np.uint32)])
-        nt = np.concatenate([nt, np.zeros(B - chunk.used, np.int32)])
-        isz = np.concatenate([isz, np.zeros(B - chunk.used, np.int32)])
-    buf_dev = resolve_tokens_packed(jnp.asarray(tokens), jnp.asarray(nt),
-                                    jnp.asarray(isz))
-    total = int(chunk.ubase[chunk.used])
-    return buf_dev, np.asarray(buf_dev)[:total]
-
-
-def _frame_span_records(hbuf: np.ndarray, start: int, stop: int
-                        ) -> Tuple[np.ndarray, int]:
-    """Record framing over a resolved span buffer with span ownership:
-    the l_shared/l_indiv cursor chase from ``start``, keeping records
-    whose FIRST byte is < ``stop`` (the same ownership rule the host
-    span reader applies) and which complete within the buffer.  Returns
-    (starts i64, tail) — ``tail`` is the first incomplete owned
-    record's offset (== the walked end when every owned record
-    completed), the host-fixup handoff point."""
-    total = hbuf.shape[0]
-    unpack = struct.Struct("<II").unpack_from
-    starts: List[int] = []
-    p = int(start)
-    view = memoryview(hbuf)
-    while p < stop:
-        if p + 8 > total:
-            break
-        l_shared, l_indiv = unpack(view, p)
-        end = p + 8 + l_shared + l_indiv
-        if end > total:
-            break
-        starts.append(p)
-        p = end
-    return np.asarray(starts, np.int64), p
-
-
 def _variant_stats_result(totals: _StatTotals,
                           header: VCFHeader) -> Dict[str, object]:
-    """Shared result assembly for the host and device variant routes."""
+    """Result assembly of a variant-stats scan from its drained totals."""
     if not totals:
         return {"n_variants": 0, "n_snp": 0, "n_pass": 0, "mean_af": 0.0,
                 "n_af": 0, "sample_callrate": np.zeros(header.n_samples)}
@@ -682,155 +575,6 @@ def _variant_stats_result(totals: _StatTotals,
         "n_af": int(ints[3]),
         "sample_callrate": callrate,
     }
-
-
-def _pad_cols_device(cols: Dict[str, np.ndarray], samples_pad: int):
-    """Host column dict -> padded device tile tuple for
-    _variant_tile_stats (the host-oracle fallback/fixup feed)."""
-    n = int(cols["chrom"].shape[0])
-    R = _round_pow2_min8(n)
-
-    def pad(a, fill):
-        out = np.full((R,) + a.shape[1:], fill, a.dtype)
-        out[:n] = a
-        return jnp.asarray(out)
-
-    dosage = cols["dosage"]
-    if dosage.shape[1] != samples_pad:
-        wide = np.full((dosage.shape[0], samples_pad), -1, np.int8)
-        wide[:, :dosage.shape[1]] = dosage[:, :samples_pad]
-        dosage = wide
-    return (pad(cols["chrom"], 0), pad(cols["pos"], 0),
-            pad(cols["flags"], 0), pad(dosage, -1), jnp.int32(n))
-
-
-def _variant_stats_device_plane(ds, mesh: Mesh, config: HBamConfig,
-                                header: VCFHeader,
-                                geometry: VariantGeometry,
-                                spans, prefetch: int = 2
-                                ) -> Dict[str, object]:
-    """Variant stats through the token-feed device decode plane (module
-    section comment above; BGZF BCF only — the caller gates)."""
-    from hadoop_bam_tpu.formats.bcf_columns import decode_bcf_cursor_meta
-    from hadoop_bam_tpu.ops.inflate_device import (
-        variant_gt_dosage_device, variant_prefix_device,
-    )
-    from hadoop_bam_tpu.parallel.pipeline import (
-        DEVICE_PLANE_SPAN_BYTES, _resilient_source, _tokenize_span_tokens,
-        decode_with_retry,
-    )
-    from hadoop_bam_tpu.utils import native
-    from hadoop_bam_tpu.utils.errors import PlanError
-    from hadoop_bam_tpu.utils.seekable import as_byte_source
-
-    if not native.available():
-        raise PlanError(
-            "inflate_backend='device' needs the native tokenizer "
-            "(hbam_deflate_tokenize_batch); native library unavailable")
-    n_dev = int(np.prod(mesh.devices.shape))
-    if spans is None:
-        src0 = as_byte_source(ds.path)
-        n_spans = max(n_dev, int(np.ceil(src0.size
-                                         / DEVICE_PLANE_SPAN_BYTES)))
-        src0.close()
-        with METRICS.span("vcf.plan_wall", spans=n_spans):
-            spans = ds.spans(num_spans=n_spans)
-    spans = list(spans)
-    # the host plane's span read (split/vcf_planners.bcf_span_frames,
-    # either path) verifies each block's ISIZE and not its CRC32
-    # (``BGZFReader(src)`` defaults to check_crc=False): CRC-only damage
-    # surfaces there only in a block the BGZF split guesser confirms.
-    # This route folds the CRC at tokenize time for the variant family,
-    # config.check_crc notwithstanding
-    check_crc = True
-    samples_pad = geometry.samples_pad
-    src = _resilient_source(ds.path, config)
-    pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
-    totals = _StatTotals()
-    fix_spans = []
-    n_records = 0
-
-    def decode(span):
-        # tokenize is metered inside _tokenize_span_tokens
-        # (bam.tokenize_wall — the BGZF token stage, format-agnostic);
-        # deliberately NOT under pipeline.host_decode_wall: no host
-        # inflate happens on this route
-        def inner(s):
-            return _tokenize_span_tokens(src, s, check_crc)
-        return decode_with_retry(inner, span, config)
-
-    def host_cols(span):
-        """The host-oracle decode of one (fixup) span, reduced with the
-        same tile math — byte/value-identical merge."""
-        def inner(s):
-            return bcf_span_stat_columns(ds.path, s, header, geometry,
-                                         True)
-        with METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("vcf.host_decode_wall"):
-            return decode_with_retry(inner, span, config)
-
-    for chunk in _iter_windowed(pool, spans, decode, window,
-                                config=config):
-        if chunk is None:
-            continue
-        # chaos point at the plane's dispatch boundary — the ladder
-        # wrapper in _variant_stats_impl demotes on injected faults
-        chaos.fire("device.step", blocks=int(chunk.used))
-        with METRICS.timer("pipeline.device_inflate"), \
-                METRICS.span("vcf.device_resolve_wall",
-                             blocks=int(chunk.used)):
-            buf_dev, hbuf = _resolved_span_bytes(chunk)
-        starts, tail = _frame_span_records(hbuf, chunk.start,
-                                           chunk.stop)
-        meta = decode_bcf_cursor_meta(hbuf, header, samples_pad,
-                                      starts=starts)
-        if meta is None:
-            # pathological geometry: the WHOLE span takes the host
-            # oracle (same fallback the columnar host path has) — so
-            # no device-tail fixup for this chunk, or its cut records
-            # would count twice
-            cols = host_cols(chunk.span)
-            if cols is not None:
-                totals.add(*_variant_tile_stats(
-                    *_pad_cols_device(cols, samples_pad)))
-            continue
-        if tail < chunk.stop or chunk.used < chunk.n_blocks:
-            fix_spans.append(chunk.fixup_span(tail))
-        n = int(meta["n"])
-        n_records += n
-        if n == 0:
-            continue
-        R = _round_pow2_min8(n)
-        s32 = np.zeros(R, np.int32)
-        s32[:n] = meta["starts"]
-        with METRICS.span("vcf.device_unpack_wall", rows=n):
-            chrom_d, pos_d = variant_prefix_device(
-                buf_dev, jnp.asarray(s32))
-            flags = np.zeros(R, np.uint8)
-            flags[:n] = meta["flags"]
-            dosage_d = jnp.full((R, samples_pad), -1, jnp.int8)
-            for rows, offs, width, cnt, ns in meta["gt_groups"]:
-                R2 = _round_pow2_min8(rows.size)
-                offs_p = np.zeros(R2, np.int32)
-                offs_p[:rows.size] = offs
-                d = variant_gt_dosage_device(
-                    buf_dev, jnp.asarray(offs_p), width, cnt,
-                    ns)[:rows.size]
-                dosage_d = dosage_d.at[
-                    jnp.asarray(rows.astype(np.int32))[:, None],
-                    jnp.arange(ns)].set(d)
-            totals.add(*_variant_tile_stats(
-                chrom_d, pos_d, jnp.asarray(flags), dosage_d,
-                jnp.int32(n)))
-    METRICS.count("pipeline.records", n_records)
-
-    for fs in fix_spans:
-        cols = host_cols(fs)
-        if cols is not None:
-            totals.add(*_variant_tile_stats(
-                *_pad_cols_device(cols, samples_pad)))
-    return _variant_stats_result(totals, header)
 
 
 def _bgzf_inflate_ratio(path: str, window: int = 256 << 10) -> float:
@@ -900,22 +644,9 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
                         header: Optional[VCFHeader] = None,
                         spans=None,
                         prefetch: int = 2) -> Dict[str, object]:
-    """The variant-stats mesh-feed implementation (executor runner).
-
-    Plane routing mirrors the BAM drivers: ``select_plane`` over the
-    VARIANT_DAG picks the token-feed device route for a BGZF BCF source
-    under the device backend; any device-route failure the PR-11 ladder
-    calls demotable falls through to the host mesh feed below, and the
-    device plane's blame is confirmed only after the host plane proves
-    the bytes were fine."""
+    """The variant-stats mesh-feed implementation (executor runner)."""
     from hadoop_bam_tpu.api.vcf_dataset import open_vcf
     from hadoop_bam_tpu.parallel.mesh import make_mesh
-    from hadoop_bam_tpu.plan.executor import (
-        SourceIR, VARIANT_DAG, select_plane,
-    )
-    from hadoop_bam_tpu.resilience.domains import (
-        decode_ladder,
-    )
 
     ds = open_vcf(path, config)
     if header is None:
@@ -926,33 +657,6 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
     if geometry is None:
         geometry = VariantGeometry(n_samples=header.n_samples)
     cap = geometry.tile_records
-
-    fmt = "bcf" if path.lower().endswith(".bcf") else "vcf"
-    ladder = None
-    if config.adaptive_planes:
-        ladder = decode_ladder(path, resolve_inflate_backend(config),
-                               config)
-    device_blame: Optional[BaseException] = None
-    # a non-BGZF source can never take the device route: don't let the
-    # decision consume the breaker's half-open probe for it
-    decision = select_plane(
-        SourceIR(path, fmt), VARIANT_DAG, config,
-        ladder=ladder if ds._is_bgzf_bcf else None)
-    if decision.plane == "device" and ds._is_bgzf_bcf:
-        try:
-            result = _variant_stats_device_plane(
-                ds, mesh, config, header, geometry, spans,
-                prefetch=prefetch)
-            if ladder is not None:
-                ladder.record_success("device")
-            return result
-        except Exception as e:  # noqa: BLE001 — demotion boundary
-            if ladder is None or not ladder.demotable("device", e):
-                raise
-            logger.warning(
-                "variant device plane failed (%s: %s); demoting to the "
-                "host plane for this run", type(e).__name__, e)
-            device_blame = e
 
     if spans is None:
         with METRICS.span("vcf.plan_wall"):
@@ -1005,10 +709,4 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
             return (*args, c)  # in-flight handles: the ring waits on them
 
         fp.feed(tuples, dispatch)
-    result = _variant_stats_result(totals, header)
-    if ladder is not None and device_blame is not None:
-        # the host plane decoded the same file fine: the device failure
-        # was plane-local — charge its fault domain (repeated charges
-        # open the breaker and demote future runs up front)
-        ladder.confirm_failure("device", device_blame)
-    return result
+    return _variant_stats_result(totals, header)

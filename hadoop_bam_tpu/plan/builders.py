@@ -32,8 +32,7 @@ PAYLOAD_SPAN_BYTES = 8 << 20
 def flagstat_plan(path: str,
                   config: Optional[HBamConfig] = None) -> PlanIR:
     """BAM flagstat: project the flagstat columns, reduce with one psum
-    per tile group.  The only DAG the token-feed device plane currently
-    implements (``executor._device_capable``)."""
+    per tile group."""
     from hadoop_bam_tpu.ops.unpack_bam import FLAGSTAT_PROJECTION
 
     cfg = config if config is not None else DEFAULT_CONFIG
@@ -69,31 +68,17 @@ def seq_stats_plan(path: str, config: Optional[HBamConfig] = None,
 def variant_stats_plan(path: str, config: Optional[HBamConfig] = None,
                        geometry=None) -> PlanIR:
     """VCF/BCF variant stats: pack (chrom, pos, flags, dosage) tiles,
-    reduce counts + allele frequency + per-sample call rates.
-
-    A BCF source compiled under the device backend routes its unpack
-    through the mesh (``variant_unpack_device``) — and that op is part
-    of the plan IDENTITY: a journaled job compiled for the device route
-    refuses to resume against a host-plane journal and vice versa
-    (``jobs.runner.plan_journal_params``), because the two routes
-    partition work differently (device-plane span grain vs the host
-    span plan)."""
-    from hadoop_bam_tpu.config import resolve_inflate_backend
-
-    cfg = config if config is not None else DEFAULT_CONFIG
+    reduce counts + allele frequency + per-sample call rates."""
     fmt = "bcf" if path.lower().endswith(".bcf") else "vcf"
     params = {}
     if geometry is not None:
         params = dict(n_samples=geometry.n_samples,
                       tile_records=geometry.tile_records)
-    ops = [op_node("variant_pack", **params)]
-    if fmt == "bcf" and resolve_inflate_backend(cfg) == "device":
-        ops.append(op_node("variant_unpack_device"))
-    ops.append(op_node("variant_stats_reduce"))
     return PlanIR(
         source=SourceIR(path, fmt),
         spans=SpansIR.auto(),
-        ops=tuple(ops),
+        ops=(op_node("variant_pack", **params),
+             op_node("variant_stats_reduce")),
         sink=SinkIR.of("variant_stats"))
 
 

@@ -22,12 +22,13 @@ Two jobs live here, and ONLY here:
    its ``_*_impl`` functions, which consume the decision this module
    computed instead of re-deriving gates.
 
-Decode planes (``config.DECODE_PLANES``): "device" (token-feed on-mesh
-inflate; flagstat is the pilot DAG), "native" (host C++ inflate, with
-the fused single-pass sweep as a MODE when eligible), "zlib" (portable
-Python).  ``resolve_inflate_backend`` (config.py) turns "auto" into a
-concrete starting rung once per process; the ``DemotionLadder``
-(resilience/domains.py) may still demote mid-run.
+Decode planes (``config.DECODE_PLANES``): "native" (host C++ inflate,
+with the fused single-pass sweep as a MODE when eligible) and "zlib"
+(portable Python).  Inflate, record walk and row pack are host work by
+measurement (PERF.md section 6, PR 30); the mesh runs unpack + reduce /
+sort / filter.  ``resolve_inflate_backend`` (config.py) turns "auto"
+into the starting rung; the ``DemotionLadder`` (resilience/domains.py)
+may still demote mid-run.
 """
 from __future__ import annotations
 
@@ -48,21 +49,17 @@ from hadoop_bam_tpu.utils.metrics import METRICS
 
 @dataclasses.dataclass(frozen=True)
 class PlaneDecision:
-    """One plan's resolved routing: the selected plane, the backend
-    strings the span-level decoders consume, fused-mode eligibility,
+    """One plan's resolved routing: the selected plane (the backend
+    string the span-level decoders consume), fused-mode eligibility,
     and — for ``hbam explain`` — why each rejected plane/mode failed
     its gate."""
-    plane: str            # selected decode plane for this op DAG
-    backend: str          # resolve_inflate_backend(config) result
-    host_backend: str     # what host span decoders pass as backend
+    plane: str            # resolve_inflate_backend(config) result
     use_fused: bool       # fused single-pass native sweep eligible
     stream_fused: bool    # chunk-streamed fused decode eligible
     rejected: Tuple[Tuple[str, str], ...]   # (plane_or_mode, reason)
 
     def to_doc(self) -> Dict:
-        return {"plane": self.plane, "backend": self.backend,
-                "host_backend": self.host_backend,
-                "use_fused": self.use_fused,
+        return {"plane": self.plane, "use_fused": self.use_fused,
                 "stream_fused": self.stream_fused,
                 "rejected": {p: r for p, r in self.rejected}}
 
@@ -94,36 +91,6 @@ def _fused_stream_gate(config: Optional[HBamConfig], intervals) -> bool:
             and not cfg.skip_bad_spans)
 
 
-def host_backend_for(config: Optional[HBamConfig]) -> str:
-    """The backend string host span decoders take: the resolved plane,
-    with "device" mapped to "auto" (families ride the host planes
-    wherever the token-feed plane does not apply)."""
-    backend = resolve_inflate_backend(config)
-    return "auto" if backend == "device" else backend
-
-
-# which op DAGs the token-feed device plane implements, per source
-# format — THE capability table (ROADMAP item 1).  An op anywhere in the
-# DAG from the format's set marks the whole DAG device-capable; the
-# reduce/sink op is the stable discriminator across the parameterized
-# builder DAGs and the minimal twins below.  Text VCF deliberately has
-# no row: the device plane rides the BGZF token feed, and text variant
-# lines have no gather-shaped record layout to unpack on-mesh.
-_DEVICE_DAGS = {
-    "bam": frozenset({"flagstat_reduce", "seq_stats_reduce",
-                      "tile_build"}),
-    "bcf": frozenset({"variant_unpack_device", "variant_stats_reduce"}),
-}
-
-
-def _device_capable(source: SourceIR, ops: Tuple[TensorOpIR, ...]) -> bool:
-    """Does the token-feed device plane implement this op DAG?"""
-    fam = _DEVICE_DAGS.get(getattr(source, "fmt", None))
-    if not fam:
-        return False
-    return any(getattr(o, "op", None) in fam for o in ops)
-
-
 # canonical op DAGs of the in-repo scan/serve families (plan/builders.py
 # carries the fully-parameterized versions; these minimal twins are what
 # the mesh-feed impls pass to select_plane when invoked directly)
@@ -135,72 +102,40 @@ SERVE_TILE_DAG = (op_node("chunk_decode"), op_node("tile_build"))
 
 def select_plane(source: SourceIR, ops: Tuple[TensorOpIR, ...],
                  config: Optional[HBamConfig], *,
-                 intervals=None, ladder=None) -> PlaneDecision:
+                 intervals=None) -> PlaneDecision:
     """THE plane-selection predicate table (module docstring).
 
     ``intervals`` is the parsed interval filter (None = no filtering —
     the gates test identity, matching the drivers' historical
-    ``intervals is None``).  ``ladder`` is the file's ``DemotionLadder``
-    when adaptive planes are on; its device breaker is consulted LAST,
-    only when every other device gate passed, because ``allow_plane``
-    consumes a half-open probe slot.
-
-    Native-library absence deliberately does NOT gate the device plane
-    here: an explicit ``inflate_backend="device"`` without the native
-    tokenizer is a configuration fault and must surface as PlanError
-    from the device runner, not silently reroute.  It DOES gate the
-    fused mode (``fused_available`` implies native)."""
+    ``intervals is None``).  Native-library absence gates the fused
+    mode (``fused_available`` implies native); the span decoders fall
+    to zlib themselves when the library is absent.  ``source`` and
+    ``ops`` name the plan being routed; no gate left reads them
+    (ROADMAP D4)."""
     from hadoop_bam_tpu.ops import inflate as inflate_ops
 
     cfg = config if config is not None else DEFAULT_CONFIG
-    backend = resolve_inflate_backend(cfg)
-    host_backend = "auto" if backend == "device" else backend
+    plane = resolve_inflate_backend(cfg)
     rejected = []
 
     fused = True
     if not cfg.use_fused_decode:
         fused = False
         rejected.append(("fused", "config.use_fused_decode is off"))
-    elif host_backend not in ("auto", "native"):
+    elif plane != "native":
         fused = False
         rejected.append(
-            ("fused", f"backend {host_backend!r} disables the native "
+            ("fused", f"backend {plane!r} disables the native "
                       f"fused sweep"))
     elif not inflate_ops.fused_available():
         fused = False
         rejected.append(
             ("fused", "native fused entry points unavailable"))
 
-    plane = None
-    if backend != "device":
+    if plane == "zlib":
         rejected.append(
-            ("device", f"inflate_backend resolved to {backend!r}"))
-    elif not _device_capable(source, ops):
-        rejected.append(
-            ("device", "no device decode plane for this op DAG "
-                       "(token-feed families: BAM flagstat/payload/"
-                       "serve-tile, BCF variant)"))
-    elif intervals is not None:
-        rejected.append(
-            ("device", "interval filtering needs whole-span offsets "
-                       "on the host"))
-    elif cfg.skip_bad_spans:
-        rejected.append(
-            ("device", "skip_bad_spans needs span-granular quarantine"))
-    elif ladder is not None and not ladder.allow_plane("device"):
-        rejected.append(
-            ("device", "device fault-domain breaker is OPEN"))
-    else:
-        plane = "device"
-
-    if plane is None:
-        if backend == "zlib":
-            rejected.append(
-                ("native", "inflate_backend='zlib' pins the portable "
-                           "plane"))
-            plane = "zlib"
-        else:
-            plane = "native"
+            ("native", "inflate_backend='zlib' pins the portable "
+                       "plane"))
 
     stream = fused and intervals is None and not cfg.skip_bad_spans
     if fused and not stream:
@@ -210,8 +145,7 @@ def select_plane(source: SourceIR, ops: Tuple[TensorOpIR, ...],
              if intervals is not None
              else "skip_bad_spans needs span-granular quarantine"))
     assert plane in DECODE_PLANES
-    return PlaneDecision(plane=plane, backend=backend,
-                         host_backend=host_backend, use_fused=fused,
+    return PlaneDecision(plane=plane, use_fused=fused,
                          stream_fused=stream, rejected=tuple(rejected))
 
 
@@ -247,9 +181,9 @@ def select_chunk_source(*, tile_cached: bool, fleet_owned: bool,
 
 def plane_report(config: Optional[HBamConfig] = None) -> Dict[str, Dict]:
     """Display-only decision table per driver family for this process +
-    config — the ``hbam serve`` health surface.  Never consumes breaker
-    probes (ladder=None) and never touches files; the interval gate is
-    approximated by whether ``config.bam_intervals`` is set."""
+    config — the ``hbam serve`` health surface.  Never touches files;
+    the interval gate is approximated by whether ``config.bam_intervals``
+    is set."""
     cfg = config if config is not None else DEFAULT_CONFIG
     intervals = () if getattr(cfg, "bam_intervals", None) else None
     # the SAME DAG constants the drivers route with — rebuilding them
@@ -279,6 +213,9 @@ def execute(plan: PlanIR, *, config: Optional[HBamConfig] = None,
     Returns whatever the sink promises: a stats dict, a lazy tensor
     batch iterator, or the query tier's (columns, cache-cost) pair."""
     cfg = config if config is not None else DEFAULT_CONFIG
+    # a backend name the config cannot resolve is a PlanError before any
+    # runner starts (not every family consults select_plane)
+    resolve_inflate_backend(cfg)
     runner = _runner_for(plan)
     METRICS.count("plan.executions")
     if getattr(runner, "lazy_sink", False):

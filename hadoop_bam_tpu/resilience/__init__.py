@@ -6,11 +6,10 @@ Turns the PR-1 static fault policies into an adaptive loop:
   machine with decayed failure windows (injectable clock);
 - ``domains``: ``FaultDomain`` tracking keyed (subsystem, backend,
   file identity), the ``DemotionLadder`` that demotes decode planes
-  device -> native -> zlib mid-run (byte-identical results) and heals
+  native -> zlib mid-run (byte-identical results) and heals
   back via half-open probes, and the upgraded quarantine circuit;
 - ``chaos``: named fault points past the byte-source layer (pool
-  submission, the device shard_map step, deflate workers, transport
-  disconnects) with seed-derived deterministic schedules.
+  submission, native decode, deflate workers, transport disconnects) with seed-derived deterministic schedules.
 
 Everything here is host-local policy — no jax, no collectives — so it
 is safe to consult from pool workers, the serve dispatcher, and client

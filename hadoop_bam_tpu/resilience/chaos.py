@@ -1,8 +1,8 @@
 """Chaos fault points past the byte-source layer + seeded schedules.
 
 PR 1's ``install_chaos`` covers exactly one seam: path-opened byte
-sources.  The device plane, the serve tier's transports, the shared
-pool, and the parallel writer all fault in production for reasons a
+sources.  The serve tier's transports, the shared pool, and the
+parallel writer all fault in production for reasons a
 ``pread`` wrapper can never exercise.  This module adds *named fault
 points* — instrumented call sites that consult a registry and raise /
 delay deterministically when a schedule is installed, and cost one
@@ -18,8 +18,6 @@ point                     instrumented at
                           fault here wedges a worker mid-task)
 ``decode.native``         the ladder-aware span decode closures
                           (``parallel/pipeline.py``), native rung only
-``device.step``           ``_flagstat_device_plane`` dispatch (the
-                          shard_map step boundary)
 ``write.deflate``         ``ParallelBGZFWriter._deflate`` pool workers
 ``serve.transport``       ``serve/transport.handle_stream`` per line
                           (an injected disconnect)
@@ -52,8 +50,7 @@ from hadoop_bam_tpu.utils.errors import CorruptDataError, TransientIOError
 from hadoop_bam_tpu.utils.metrics import METRICS
 
 KNOWN_POINTS = ("pool.submit", "pool.task", "decode.native",
-                "device.step", "write.deflate", "serve.transport",
-                "serve.peer")
+                "write.deflate", "serve.transport", "serve.peer")
 
 FAULT_KINDS = ("transient", "corrupt", "disconnect", "delay")
 

@@ -9,7 +9,7 @@ the window instead of counting forever.
 
 ``DemotionLadder`` layers the multi-backend decode lineage (Rapidgzip /
 Compressed-Resident Genomics, PAPERS.md) on top: every decode plane in
-``device -> native -> zlib`` produces byte-identical results, so when
+``native -> zlib`` produces byte-identical results, so when
 one plane's domain breaker opens, the run *demotes* to the next plane
 mid-flight and keeps producing correct answers — and after the
 breaker's cooldown a half-open probe re-tries the faster plane and
@@ -40,7 +40,7 @@ from hadoop_bam_tpu.utils.metrics import METRICS
 
 # fast -> safe; every rung is byte-identical, each one slower and more
 # battle-tested than the one above it
-PLANES = ("device", "native", "zlib")
+PLANES = ("native", "zlib")
 
 _MAX_DOMAINS = 256          # LRU bound on tracked domains
 
@@ -207,24 +207,6 @@ class DemotionLadder:
             if self._domain(p).breaker.allow():
                 return p
         return self.planes[-1]
-
-    def host_plane(self) -> str:
-        """Like ``plane()`` but never 'device' — what the span-level
-        host decode closures consult."""
-        for p in self.planes[:-1]:
-            if p == "device":
-                continue
-            if self._domain(p).breaker.allow():
-                return p
-        return self.planes[-1]
-
-    def allow_plane(self, plane: str) -> bool:
-        """Gate ONE plane's breaker (consumes a half-open probe slot —
-        call only when the caller will actually attempt the plane and
-        report the outcome; use ``states()`` for display)."""
-        if plane not in self.planes:
-            return False
-        return self._domain(plane).breaker.allow()
 
     def next_lower(self, plane: str) -> Optional[str]:
         try:

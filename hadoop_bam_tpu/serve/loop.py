@@ -51,12 +51,10 @@ from hadoop_bam_tpu.obs.slo import SloEngine
 from hadoop_bam_tpu.query.engine import QueryEngine, _I32_MAX
 from hadoop_bam_tpu.serve.prefetch import Prefetcher
 from hadoop_bam_tpu.serve.tenancy import TenantQuotas, priority_rank
-from hadoop_bam_tpu.plan.executor import (
-    SERVE_TILE_DAG, SourceIR, select_chunk_source, select_plane,
-)
+from hadoop_bam_tpu.plan.executor import select_chunk_source
 from hadoop_bam_tpu.serve.tiles import (
     INTERVAL_PROJECTION, DeviceTileCache, TileBuilder,
-    device_build_chunk, make_tile_filter_step, tile_key,
+    make_tile_filter_step, tile_key,
 )
 from hadoop_bam_tpu.utils.errors import (
     PLAN, CorruptDataError, PlanError, TransientIOError, classify_error,
@@ -445,24 +443,6 @@ class ServeLoop:
         degraded = fleet.degraded() if fleet is not None else False
         if degraded:
             fleet.note_degraded()
-        # cold-tile plane routing, decided ONCE per request: the same
-        # select_plane discipline the batch drivers use, over the
-        # serve-tile DAG.  Records mode always builds from the host
-        # chunk (the materializer needs its columns anyway — a device
-        # build would just decode the chunk twice).
-        ladder = None
-        device_plane = False
-        if not job.want_records:
-            if self.config.adaptive_planes:
-                from hadoop_bam_tpu.config import resolve_inflate_backend
-                from hadoop_bam_tpu.resilience.domains import decode_ladder
-                ladder = decode_ladder(
-                    meta.path, resolve_inflate_backend(self.config),
-                    self.config)
-            decision = select_plane(SourceIR(meta.path, meta.kind),
-                                    SERVE_TILE_DAG, self.config,
-                                    ladder=ladder)
-            device_plane = decision.plane == "device"
         count = 0
         n_candidates = 0
         tile_hits = 0
@@ -506,48 +486,17 @@ class ServeLoop:
                             # deadline still binds the fallback)
                             METRICS.count("fleet.peer_fallback_local")
                             value = None
-                device_blame = None
-                if value is None and device_plane:
-                    # cold miss on the device plane: tokens resolve and
-                    # the (rid, pos1, end1) columns unpack entirely
-                    # on-mesh — no host inflate, no host record decode.
-                    # None = the chunk declined (over-wide/over-cap/
-                    # cut record) and takes the host oracle, which is
-                    # not a device fault; an EXCEPTION is, and demotes
-                    # through the ladder to the host build below
-                    try:
-                        tiles = device_build_chunk(
-                            builder, meta.ident, meta.path, s, e,
-                            self.config)
-                    except Exception as exc:  # noqa: BLE001 — demotion
-                        if ladder is None or not ladder.demotable(
-                                "device", exc):
-                            raise
-                        device_blame = exc
-                        tiles = None
-                    if tiles is not None and ladder is not None:
-                        ladder.record_success("device")
-                if tiles is None:
-                    if value is None:
-                        value = engine._chunk(meta, s, e)
-                        # ticks serve.prefetch_useful when the host
-                        # chunk was decoded ahead of need
-                        self.prefetcher.was_prefetched(
-                            engine.chunk_key(meta, s, e))
-                        if fleet is not None:
-                            fleet.note_local_decode()
-                    tiles = builder.build(meta.ident, value)
-                    if ladder is not None and device_blame is not None:
-                        # host plane decoded the same chunk fine: the
-                        # device failure was plane-local — charge it
-                        ladder.confirm_failure("device", device_blame)
-                    quarantined = (int(value["n"]) == 0
-                                   and int(value["nbytes"]) == 0)
-                else:
-                    # device builds can't be quarantined spans: the
-                    # skip_bad_spans knob gates the device plane off
-                    # entirely (select_plane), and bad bytes raise
-                    quarantined = False
+                if value is None:
+                    value = engine._chunk(meta, s, e)
+                    # ticks serve.prefetch_useful when the host
+                    # chunk was decoded ahead of need
+                    self.prefetcher.was_prefetched(
+                        engine.chunk_key(meta, s, e))
+                    if fleet is not None:
+                        fleet.note_local_decode()
+                tiles = builder.build(meta.ident, value)
+                quarantined = (int(value["n"]) == 0
+                               and int(value["nbytes"]) == 0)
                 if not quarantined:
                     self.tiles.put(key, tiles)
                 else:

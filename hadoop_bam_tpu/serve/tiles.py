@@ -33,7 +33,6 @@ from typing import Dict, Hashable, List, Optional, Tuple
 
 import numpy as np
 
-from hadoop_bam_tpu.resilience import chaos
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.stepcache import BoundedStepCache, named_step
 
@@ -356,96 +355,3 @@ class TileBuilder:
 
     def close(self) -> None:
         self._cancel.set()
-
-
-def device_build_chunk(builder: TileBuilder, ident: Tuple, path: str,
-                       s: int, e: int, config) -> Optional[TileSet]:
-    """Cold serve-tile build through the token-feed device decode plane:
-    host tokenize (native Huffman) -> on-mesh LZ77 resolve + record walk
-    + interval unpack (``ops/inflate_device.resolve_walk_intervals``) ->
-    sharded device tiles.  The (rid, pos1, end1) columns never exist as
-    host arrays — a cold miss on this route does no host inflate and no
-    host record decode at all (``pipeline.host_decode_wall`` stays 0).
-
-    Returns None whenever the chunk needs the host oracle instead: an
-    over-wide span (> DEVICE_PLANE_MAX_BLOCKS), a CIGAR past the
-    device-walk cap, a record-capacity overflow, a record cut at the
-    buffer edge, or a malformed record chain — the host path then
-    decodes it (and raises the canonical error class if the bytes
-    really are bad).  Declining is not a device FAULT, so the caller
-    charges no ladder blame for it; BGZF-level corruption raises here
-    (inside ``_tokenize_span_tokens``), which IS ladder-demotable."""
-    import jax
-    import jax.numpy as jnp
-
-    from hadoop_bam_tpu.ops.inflate_device import resolve_walk_intervals
-    from hadoop_bam_tpu.ops.rans import _round_pow2
-    from hadoop_bam_tpu.parallel.pipeline import _tokenize_span_tokens
-    from hadoop_bam_tpu.split.spans import FileVirtualSpan
-    from hadoop_bam_tpu.utils import native
-    from hadoop_bam_tpu.utils.errors import PlanError
-
-    if not native.available():
-        raise PlanError(
-            "inflate_backend='device' needs the native tokenizer "
-            "(hbam_deflate_tokenize_batch); native library unavailable")
-    chunk = _tokenize_span_tokens(path, FileVirtualSpan(path, s, e),
-                                  bool(config.check_crc))
-    if chunk is None:
-        return TileSet(groups=[], n=0, nbytes=64, ident=ident)
-    if chunk.used < chunk.n_blocks:
-        return None
-    # chaos point at the plane's dispatch boundary — the serve loop's
-    # ladder demotes an injected fault here to the host tile build
-    chaos.fire("device.step", blocks=int(chunk.used))
-    B = _round_pow2(max(chunk.used, 8), 8)
-    tokens, nt, isz = chunk.tokens, chunk.n_tokens, chunk.isize
-    if B != chunk.used:
-        tokens = np.vstack(
-            [tokens, np.zeros((B - chunk.used, chunk.P), np.uint32)])
-        nt = np.concatenate([nt, np.zeros(B - chunk.used, np.int32)])
-        isz = np.concatenate([isz, np.zeros(B - chunk.used, np.int32)])
-    with METRICS.span("serve.device_resolve_wall", blocks=chunk.used):
-        rid, pos1, end1, n_all, tail, bad, over = resolve_walk_intervals(
-            jnp.asarray(tokens), jnp.asarray(nt), jnp.asarray(isz),
-            jnp.int32(chunk.start), jnp.int32(chunk.stop))
-        # ONE bulk fetch of the four verdict scalars per chunk
-        n_i, tail_i, bad_i, over_i = [
-            int(v) for v in jax.device_get((n_all, tail, bad, over))]
-    R = int(rid.shape[0])
-    if bad_i or over_i or n_i > R or tail_i < chunk.stop:
-        return None
-    if n_i == 0:
-        return TileSet(groups=[], n=0, nbytes=64, ident=ident)
-    per_group = builder.n_dev * builder.cap
-    n_groups = -(-n_i // per_group)
-    padded = n_groups * per_group
-    with METRICS.span("serve.tile_build_wall", rows=n_i):
-        def shard(col, fill):
-            # kernel outputs already pad (rid=-1, pos1=end1=0) past the
-            # walked records; extend with the same fills to the group
-            # grid — identical to the TileSpec pads of the host builder
-            colp = jnp.pad(col, (0, max(0, padded - R)),
-                           constant_values=fill)[:padded]
-            return colp.reshape(n_groups, builder.n_dev, builder.cap)
-
-        rid_g = shard(rid, -1)
-        pos_g = shard(pos1, 0)
-        end_g = shard(end1, 0)
-        counts = np.zeros((n_groups, builder.n_dev), np.int32)
-        for g in range(n_groups):
-            for dev in range(builder.n_dev):
-                lo = g * per_group + dev * builder.cap
-                counts[g, dev] = max(0, min(builder.cap, n_i - lo))
-        groups: List[TileGroup] = []
-        nbytes = 0
-        for g in range(n_groups):
-            dev_arrays = jax.device_put(
-                (rid_g[g], pos_g[g], end_g[g], counts[g]),
-                builder.sharding)
-            g_rows = int(min(n_i - g * per_group, per_group))
-            groups.append(TileGroup(cols=dev_arrays[:3],
-                                    counts=dev_arrays[3], n=g_rows))
-            nbytes += sum(int(a.nbytes) for a in dev_arrays)
-    METRICS.count("serve.device_tile_builds")
-    return TileSet(groups=groups, n=n_i, nbytes=nbytes + 64, ident=ident)

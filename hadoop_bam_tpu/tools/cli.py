@@ -443,8 +443,6 @@ def cmd_explain(args) -> int:
     elif args.op == "seq-stats":
         plan = builders.seq_stats_plan(args.path, cfg)
     elif args.op == "vcf-stats":
-        # cfg matters here: the backend decides whether the BCF device
-        # unpack op joins the DAG (and therefore the digest)
         plan = builders.variant_stats_plan(args.path, cfg)
     elif args.op == "cohort":
         plan = builders.cohort_plan(args.path, cfg)
@@ -479,30 +477,21 @@ def cmd_explain(args) -> int:
     intervals = () if cfg.bam_intervals else None
     decision = select_plane(plan.source, plan.ops, cfg,
                             intervals=intervals)
-    # what the once-per-process "auto" probe measured — or the error it
-    # raised (counted as pipeline.plane_probe_failed); None when the
-    # backend was pinned and no probe ran
-    from hadoop_bam_tpu.config import plane_probe_report
-    probe = plane_probe_report()
     if args.json:
         print(_json.dumps({"plan": plan.to_doc(),
                            "digest": plan.digest(),
-                           "decision": decision.to_doc(),
-                           "plane_probe": probe},
+                           "decision": decision.to_doc()},
                           indent=1, sort_keys=True))
         return 0
     for line in plan.render():
         print(line)
-    print(f"plane   {decision.plane} (backend={decision.backend}, "
-          f"host_backend={decision.host_backend}, "
+    print(f"plane   {decision.plane} ("
           f"fused={'on' if decision.use_fused else 'off'}, "
           f"stream_fused={'on' if decision.stream_fused else 'off'})")
     if decision.rejected:
         print("rejected:")
         for p, reason in decision.rejected:
             print(f"  {p:13s} {reason}")
-    if probe is not None:
-        print("probe   " + " ".join(f"{k}={v}" for k, v in probe.items()))
     return 0
 
 
@@ -1599,9 +1588,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "genomic index into pinned chunks)")
     ex.add_argument("--intervals", default=None,
                     help="explain with hadoopbam.bam.intervals set "
-                         "(gates the device plane and fused streaming)")
+                         "(gates fused streaming)")
     ex.add_argument("--inflate-backend", default=None,
-                    choices=["auto", "native", "zlib", "device"],
+                    choices=["auto", "native", "zlib"],
                     help="explain under this backend instead of the "
                          "config default")
     ex.add_argument("--skip-bad-spans", action="store_true",

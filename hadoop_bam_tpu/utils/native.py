@@ -201,13 +201,6 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_deflate_batch.argtypes = [
             i8p, i64p, i32p, ctypes.c_int32, i8p, i64p, i32p, i32p,
             ctypes.c_int32, ctypes.c_int32]
-        lib.hbam_deflate_tokenize.restype = ctypes.c_int
-        lib.hbam_deflate_tokenize.argtypes = [
-            i8p, ctypes.c_int64, u32p, ctypes.c_int64, i64p, i64p]
-        lib.hbam_deflate_tokenize_batch.restype = ctypes.c_int
-        lib.hbam_deflate_tokenize_batch.argtypes = [
-            i8p, i64p, i32p, ctypes.c_int32, u32p, ctypes.c_int64,
-            i32p, i32p, u32p, ctypes.c_int32]
         if hasattr(lib, "hbam_fused_start"):
             lib.hbam_fused_start.restype = ctypes.c_void_p
             lib.hbam_fused_start.argtypes = [
@@ -406,48 +399,6 @@ def rans_decode(order: int, buf: np.ndarray, ptr: int, freqs: np.ndarray,
             "corrupt rANS stream (ran out of bytes)" if rc == -1 else
             "corrupt rANS stream (final-state integrity check failed)")
     return out
-
-
-def deflate_tokenize_batch(src: np.ndarray, cdata_off: np.ndarray,
-                           cdata_len: np.ndarray, tok_stride: int,
-                           n_threads: int = 0, with_crc: bool = False
-                           ) -> tuple:
-    """Huffman-decode many raw DEFLATE streams into LZ77 token arrays
-    (copies unresolved) — the host half of the two-stage device inflate
-    (ops/inflate_device.py).  Returns (tokens [B, tok_stride] u32,
-    n_tokens [B] i32, out_lens [B] i32); with ``with_crc`` a fourth
-    ``crcs [B] u32`` array rides along — the CRC32 of each block's
-    inflated bytes, folded in at tokenize time (thread-local resolve
-    scratch), so check_crc on the device plane needs no separate host
-    inflate sweep."""
-    lib = load()
-    assert lib is not None
-    n = len(cdata_off)
-    if n_threads <= 0:
-        n_threads = min(n, os.cpu_count() or 1)
-    tokens = np.empty((n, tok_stride), dtype=np.uint32)
-    n_tokens = np.zeros(n, dtype=np.int32)
-    out_lens = np.zeros(n, dtype=np.int32)
-    crcs = np.zeros(n, dtype=np.uint32) if with_crc else None
-    rc = lib.hbam_deflate_tokenize_batch(
-        _ptr(src, ctypes.c_uint8), _ptr(cdata_off, ctypes.c_int64),
-        _ptr(cdata_len, ctypes.c_int32), n,
-        _ptr(tokens, ctypes.c_uint32), tok_stride,
-        _ptr(n_tokens, ctypes.c_int32), _ptr(out_lens, ctypes.c_int32),
-        None if crcs is None else _ptr(crcs, ctypes.c_uint32),
-        n_threads)
-    if rc:
-        kinds = {1: "truncated stream", 2: "malformed stream",
-                 3: "token capacity exceeded (caller's tok_stride too "
-                    "small)", 4: "back-reference before stream start"}
-        kind = (rc - 1000) // 1000000
-        block = (rc - 1000) % 1000000
-        raise ValueError(
-            f"deflate tokenize failed at block {block}: "
-            f"{kinds.get(kind, f'error {kind}')}")
-    if with_crc:
-        return tokens, n_tokens, out_lens, crcs
-    return tokens, n_tokens, out_lens
 
 
 def itf8_decode_batch(buf: np.ndarray, count: int

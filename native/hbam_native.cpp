@@ -490,286 +490,6 @@ long long hbam_itf8_decode_batch(const unsigned char* buf,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// DEFLATE tokenizer: Huffman-decode a raw DEFLATE stream into LZ77 tokens
-// WITHOUT resolving back-references — the host half of the two-stage device
-// inflate experiment (ops/inflate_device.py).  The bit-serial, branchy
-// Huffman stage is unvectorizable and stays on the host (threaded across
-// blocks); the embarrassingly parallel copy resolution runs on the device.
-//
-// Token u32 layout:
-//   bit 31 set   -> copy: bits 16-24 = length (3..258), bits 0-15 = dist-1
-//   bit 31 clear -> literal: bits 0-7 = byte value
-// [SPEC] RFC 1951 (DEFLATE): block types, code-length code order, canonical
-// Huffman construction, length/distance base+extra-bit tables.
-
-namespace {
-
-// 64-bit bit reservoir, LSB-first; refilled with zero padding past EOF
-// (consumption past the real end is caught by the ``consumed`` counter).
-struct HbamBits64 {
-  const uint8_t* p;
-  int64_t n;
-  int64_t pos;       // next unread byte
-  uint64_t acc;
-  int cnt;           // bits in acc (may include zero padding)
-  int64_t consumed;  // bits taken so far (pad bits included)
-};
-
-inline void hbam_refill(HbamBits64* b) {
-  while (b->cnt <= 56) {
-    const uint64_t byte = b->pos < b->n ? b->p[b->pos++] : 0;
-    b->acc |= byte << b->cnt;
-    b->cnt += 8;
-  }
-}
-
-inline uint32_t hbam_take(HbamBits64* b, int k) {
-  const uint32_t v = static_cast<uint32_t>(b->acc) & ((1u << k) - 1u);
-  b->acc >>= k;
-  b->cnt -= k;
-  b->consumed += k;
-  return v;
-}
-
-inline uint32_t hbam_getbits(HbamBits64* b, int k) {
-  hbam_refill(b);
-  return hbam_take(b, k);
-}
-
-struct HbamHuff {
-  uint16_t count[16];   // codes per bit length
-  uint16_t sym[288];    // symbols ordered by (length, symbol)
-  bool empty;
-};
-
-int hbam_build_huff(const uint8_t* lens, int n, HbamHuff* h) {
-  for (int i = 0; i < 16; ++i) h->count[i] = 0;
-  for (int i = 0; i < n; ++i) h->count[lens[i]]++;
-  h->empty = (h->count[0] == n);
-  h->count[0] = 0;
-  if (h->empty) return 0;   // legal: e.g. HDIST table with no codes
-  int left = 1;             // over-subscription check
-  for (int l = 1; l < 16; ++l) {
-    left <<= 1;
-    left -= h->count[l];
-    if (left < 0) return -1;
-  }
-  uint16_t offs[16];
-  offs[1] = 0;
-  for (int l = 1; l < 15; ++l)
-    offs[l + 1] = static_cast<uint16_t>(offs[l] + h->count[l]);
-  for (int i = 0; i < n; ++i)
-    if (lens[i]) h->sym[offs[lens[i]]++] = static_cast<uint16_t>(i);
-  return 0;
-}
-
-// canonical code decode, one bit at a time (fallback for codes > 10 bits
-// and for the tiny code-length table); caller must hbam_refill first
-inline int hbam_decode_slow(HbamBits64* b, const HbamHuff* h) {
-  int code = 0, first = 0, index = 0;
-  for (int l = 1; l < 16; ++l) {
-    code |= static_cast<int>(hbam_take(b, 1));
-    const int cnt = h->count[l];
-    if (code - first < cnt) return h->sym[index + (code - first)];
-    index += cnt;
-    first = (first + cnt) << 1;
-    code <<= 1;
-  }
-  return -1;
-}
-
-// one-level lookup table over the low ROOT_BITS reservoir bits (DEFLATE
-// packs codes MSB-first, so table indices are bit-reversed codes); codes
-// longer than ROOT_BITS leave zero entries and fall back to slow decode.
-constexpr int kRootBits = 10;
-
-struct HbamFastTable {
-  uint16_t root[1 << kRootBits];  // bit15 valid, bits 9-12 len, 0-8 sym
-  HbamHuff slow;
-};
-
-int hbam_build_fast(const uint8_t* lens, int n, HbamFastTable* t) {
-  if (hbam_build_huff(lens, n, &t->slow)) return -1;
-  std::memset(t->root, 0, sizeof(t->root));
-  if (t->slow.empty) return 0;
-  uint32_t next_code[16];
-  uint32_t code = 0;
-  for (int l = 1; l < 16; ++l) {
-    code = (code + t->slow.count[l - 1]) << 1;
-    next_code[l] = code;
-  }
-  for (int i = 0; i < n; ++i) {
-    const int l = lens[i];
-    if (!l) continue;
-    const uint32_t c = next_code[l]++;
-    if (l > kRootBits) continue;
-    uint32_t r = 0;                 // reverse the l code bits
-    for (int bb = 0; bb < l; ++bb) r |= ((c >> bb) & 1u) << (l - 1 - bb);
-    const uint16_t e = static_cast<uint16_t>(
-        0x8000u | (static_cast<uint32_t>(l) << 9) | i);
-    for (uint32_t j = r; j < (1u << kRootBits); j += (1u << l))
-      t->root[j] = e;
-  }
-  return 0;
-}
-
-inline int hbam_fast_sym(HbamBits64* b, const HbamFastTable* t) {
-  const uint16_t e = t->root[b->acc & ((1u << kRootBits) - 1u)];
-  if (e & 0x8000) {
-    const int l = (e >> 9) & 0xF;
-    b->acc >>= l;
-    b->cnt -= l;
-    b->consumed += l;
-    return e & 0x1FF;
-  }
-  return hbam_decode_slow(b, &t->slow);
-}
-
-const uint16_t kLenBase[29] = {
-    3,  4,  5,  6,  7,  8,  9,  10, 11,  13,  15,  17,  19,  23, 27,
-    31, 35, 43, 51, 59, 67, 83, 99, 115, 131, 163, 195, 227, 258};
-const uint8_t kLenExtra[29] = {0, 0, 0, 0, 0, 0, 0, 0, 1, 1, 1, 1, 2, 2, 2,
-                               2, 3, 3, 3, 3, 4, 4, 4, 4, 5, 5, 5, 5, 0};
-const uint16_t kDistBase[30] = {
-    1,    2,    3,    4,    5,    7,    9,    13,   17,   25,
-    33,   49,   65,   97,   129,  193,  257,  385,  513,  769,
-    1025, 1537, 2049, 3073, 4097, 6145, 8193, 12289, 16385, 24577};
-const uint8_t kDistExtra[30] = {0, 0, 0,  0,  1,  1,  2,  2,  3,  3,
-                                4, 4, 5,  5,  6,  6,  7,  7,  8,  8,
-                                9, 9, 10, 10, 11, 11, 12, 12, 13, 13};
-const uint8_t kClPerm[19] = {16, 17, 18, 0, 8,  7, 9,  6, 10, 5,
-                             11, 4,  12, 3, 13, 2, 14, 1, 15};
-
-}  // namespace
-
-extern "C" {
-
-// Tokenize one raw DEFLATE stream.  tokens/cap: output token array and its
-// capacity; n_tokens/out_len: tokens written and total inflated length.
-// Returns 0, or <0: -1 truncated input, -2 malformed stream, -3 token
-// capacity exceeded, -4 distance reaches before stream start.
-int hbam_deflate_tokenize(const uint8_t* comp, int64_t comp_len,
-                          uint32_t* tokens, int64_t cap,
-                          int64_t* n_tokens, int64_t* out_len) {
-  HbamBits64 b{comp, comp_len, 0, 0, 0, 0};
-  const int64_t limit = comp_len * 8;
-  int64_t nt = 0, opos = 0;
-  uint32_t bfinal = 0;
-  do {
-    hbam_refill(&b);
-    bfinal = hbam_take(&b, 1);
-    const uint32_t btype = hbam_take(&b, 2);
-    if (btype == 0) {             // stored: byte-align, LEN/NLEN, raw copy
-      hbam_take(&b, b.cnt & 7);
-      const uint32_t len = hbam_getbits(&b, 16);
-      const uint32_t nlen = hbam_getbits(&b, 16);
-      if (b.consumed > limit) return -1;
-      if ((len ^ 0xFFFFu) != nlen) return -2;
-      if (nt + len > cap) return -3;
-      uint32_t remaining = len;
-      while (remaining && b.cnt >= 8) {   // drain reservoir bytes first
-        tokens[nt++] = hbam_take(&b, 8);
-        --remaining;
-      }
-      if (b.consumed > limit) return -1;
-      if (b.pos + remaining > b.n) return -1;
-      for (uint32_t i = 0; i < remaining; ++i)
-        tokens[nt++] = comp[b.pos + i];
-      b.pos += remaining;
-      b.consumed += 8 * static_cast<int64_t>(remaining);
-      opos += len;
-      continue;
-    }
-    static thread_local HbamFastTable lit_t, dist_t;
-    if (btype == 1) {             // fixed tables [SPEC RFC1951 3.2.6]
-      uint8_t lens[288];
-      for (int i = 0; i < 144; ++i) lens[i] = 8;
-      for (int i = 144; i < 256; ++i) lens[i] = 9;
-      for (int i = 256; i < 280; ++i) lens[i] = 7;
-      for (int i = 280; i < 288; ++i) lens[i] = 8;
-      hbam_build_fast(lens, 288, &lit_t);
-      uint8_t dlens[30];
-      for (int i = 0; i < 30; ++i) dlens[i] = 5;
-      hbam_build_fast(dlens, 30, &dist_t);
-    } else if (btype == 2) {      // dynamic tables [SPEC RFC1951 3.2.7]
-      uint32_t hlit = hbam_getbits(&b, 5) + 257;
-      uint32_t hdist = hbam_getbits(&b, 5) + 1;
-      uint32_t hclen = hbam_getbits(&b, 4) + 4;
-      if (hlit > 286 || hdist > 30) return -2;
-      uint8_t cl[19] = {0};
-      for (uint32_t i = 0; i < hclen; ++i)
-        cl[kClPerm[i]] = static_cast<uint8_t>(hbam_getbits(&b, 3));
-      if (b.consumed > limit) return -1;
-      HbamHuff clh;
-      if (hbam_build_huff(cl, 19, &clh) || clh.empty) return -2;
-      uint8_t lens[288 + 30] = {0};
-      uint32_t idx = 0;
-      while (idx < hlit + hdist) {
-        hbam_refill(&b);
-        if (b.consumed > limit) return -1;
-        const int s = hbam_decode_slow(&b, &clh);
-        if (s < 0) return -2;
-        if (s < 16) {
-          lens[idx++] = static_cast<uint8_t>(s);
-        } else {
-          uint32_t rep;
-          uint8_t val = 0;
-          if (s == 16) {
-            if (idx == 0) return -2;
-            val = lens[idx - 1];
-            rep = hbam_take(&b, 2) + 3;
-          } else if (s == 17) {
-            rep = hbam_take(&b, 3) + 3;
-          } else {
-            rep = hbam_take(&b, 7) + 11;
-          }
-          if (idx + rep > hlit + hdist) return -2;
-          while (rep--) lens[idx++] = val;
-        }
-      }
-      if (lens[256] == 0) return -2;   // end-of-block code must exist
-      if (hbam_build_fast(lens, static_cast<int>(hlit), &lit_t) ||
-          lit_t.slow.empty)
-        return -2;
-      if (hbam_build_fast(lens + hlit, static_cast<int>(hdist), &dist_t))
-        return -2;
-    } else {
-      return -2;                  // btype 3 is reserved
-    }
-    for (;;) {                    // symbol loop: one refill covers the
-      hbam_refill(&b);            // worst case 15+5+15+13 = 48 bits
-      if (b.consumed > limit) return -1;
-      int s = hbam_fast_sym(&b, &lit_t);
-      if (s < 0) return -2;
-      if (s < 256) {
-        if (nt >= cap) return -3;
-        tokens[nt++] = static_cast<uint32_t>(s);
-        ++opos;
-      } else if (s == 256) {
-        break;
-      } else {
-        s -= 257;
-        if (s >= 29 || dist_t.slow.empty) return -2;
-        const uint32_t length = kLenBase[s] + hbam_take(&b, kLenExtra[s]);
-        const int ds = hbam_fast_sym(&b, &dist_t);
-        if (ds < 0 || ds >= 30) return -2;
-        const uint32_t d = kDistBase[ds] + hbam_take(&b, kDistExtra[ds]);
-        if (static_cast<int64_t>(d) > opos) return -4;
-        if (nt >= cap) return -3;
-        tokens[nt++] = 0x80000000u | (length << 16) | (d - 1);
-        opos += length;
-      }
-    }
-  } while (!bfinal);
-  if (b.consumed > limit) return -1;
-  *n_tokens = nt;
-  *out_len = opos;
-  return 0;
-}
-
-}  // extern "C"
-
-// ---------------------------------------------------------------------------
 // Fused single-pass span decode: inflate + record walk + projection pack +
 // CRC fold in ONE streamed pass over the span, chunk-granular.
 //
@@ -1120,87 +840,6 @@ int hbam_fused_finish(void* h, int64_t* tail, int64_t* n_rows,
   if (busy_ns) *busy_ns = j->busy_ns.load();
   delete j;
   return rc;
-}
-
-// Resolve a block's LZ77 tokens into ``scratch`` (grown as needed) and
-// return the CRC32 of the inflated bytes — the tokenize-time CRC fold for
-// the device decode plane.  The resolved bytes are a thread-local
-// throwaway: the device resolves its own copy, this exists only so
-// check_crc can be verified against the BGZF footer WITHOUT a host
-// inflate pass materializing in the pipeline (the resolve here is
-// cache-resident and far cheaper than the Huffman stage just paid).
-static uint32_t hbam_tokens_crc32(const uint32_t* toks, int64_t nt,
-                                  int64_t out_len,
-                                  std::vector<uint8_t>* scratch) {
-  if (static_cast<int64_t>(scratch->size()) < out_len)
-    scratch->resize(static_cast<size_t>(out_len));
-  uint8_t* out = scratch->data();
-  int64_t p = 0;
-  for (int64_t t = 0; t < nt; ++t) {
-    const uint32_t tok = toks[t];
-    if (tok & 0x80000000u) {
-      const int64_t length = (tok >> 16) & 0x1FF;
-      const int64_t dist = (tok & 0xFFFFu) + 1;
-      // overlapping copies (dist < length) must run byte-serial
-      const uint8_t* s = out + p - dist;
-      for (int64_t k = 0; k < length; ++k) out[p + k] = s[k];
-      p += length;
-    } else {
-      out[p++] = static_cast<uint8_t>(tok & 0xFF);
-    }
-  }
-  return static_cast<uint32_t>(
-      crc32(0L, out, static_cast<uInt>(out_len)));
-}
-
-// Threaded batch tokenize over independent blocks (same pool shape as
-// hbam_inflate_batch).  tokens is [n_blocks, tok_stride] row-major.
-// out_crcs (nullable): per-block CRC32 of the inflated bytes, folded in
-// at tokenize time from a thread-local resolve scratch.
-// Returns 0, or (1000 + first failing block index + 1000000 * -rc) so the
-// caller can recover both which block failed and why (rc per
-// hbam_deflate_tokenize: -1 truncated, -2 malformed, -3 token capacity,
-// -4 bad distance).
-int hbam_deflate_tokenize_batch(const uint8_t* src, const int64_t* off,
-                                const int32_t* len, int32_t n_blocks,
-                                uint32_t* tokens, int64_t tok_stride,
-                                int32_t* n_tokens, int32_t* out_lens,
-                                uint32_t* out_crcs, int32_t n_threads) {
-  if (n_threads < 1) n_threads = 1;
-  std::atomic<int32_t> next(0);
-  std::atomic<int32_t> fail(-1);
-  auto worker = [&]() {
-    std::vector<uint8_t> scratch;
-    for (;;) {
-      const int32_t i = next.fetch_add(1);
-      if (i >= n_blocks || fail.load(std::memory_order_relaxed) >= 0) break;
-      int64_t nt = 0, ol = 0;
-      const int rc = hbam_deflate_tokenize(
-          src + off[i], len[i],
-          tokens + static_cast<int64_t>(i) * tok_stride, tok_stride, &nt,
-          &ol);
-      if (rc) {
-        int32_t e = -1;
-        fail.compare_exchange_strong(e, i + 1000000 * -rc);
-        break;
-      }
-      n_tokens[i] = static_cast<int32_t>(nt);
-      out_lens[i] = static_cast<int32_t>(ol);
-      if (out_crcs)
-        out_crcs[i] = hbam_tokens_crc32(
-            tokens + static_cast<int64_t>(i) * tok_stride, nt, ol, &scratch);
-    }
-  };
-  if (n_threads == 1) {
-    worker();
-  } else {
-    std::vector<std::thread> pool;
-    pool.reserve(n_threads);
-    for (int t = 0; t < n_threads; ++t) pool.emplace_back(worker);
-    for (auto& th : pool) th.join();
-  }
-  const int32_t f = fail.load();
-  return f >= 0 ? 1000 + f : 0;
 }
 
 }  // extern "C"

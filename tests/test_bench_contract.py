@@ -34,12 +34,8 @@ def _fill_state(bench, n_notes=6):
         ("obs_overhead_pct", 1.3, "%", None),
         ("plan_overhead_pct", 0.6, "%", None),
         ("cohort_join_variants_per_sec", 48211.5, "variants/s", None),
-        ("device_inflate_records_per_sec", 93211.4, "records/s", 0.42),
-        ("device_plane_families_records_per_sec", 141002.3, "records/s",
-         0.48),
         ("fastq_reads_per_sec", 188001.0, "reads/s", 2.37),
         ("bam_write_records_per_sec", 301222.5, "records/s", 2.1),
-        ("deflate_tokenize_gbps", 0.41, "GB/s", 0.8),
         ("coverage_records_per_sec", 375000.2, "records/s", 1.25),
         ("sort_records_per_sec_mesh", 47368.1, "records/s", 6.6),
         ("resume_overhead_pct", 1.4, "%", None),
@@ -139,42 +135,6 @@ def _fill_state(bench, n_notes=6):
                                           "dispatch": 0.09},
                        cold_slice_p50_ms=310.2, warm_slice_p50_ms=3.1,
                        warm_host_decode_share=0.0)
-        if m == "device_plane_families_records_per_sec":
-            # r21: the three new device-plane families (payload seq_stats,
-            # BCF variant, cold serve tiles) — per-arm host-oracle
-            # identity and the ~0 host-decode share ride the FULL row
-            # only; the compact line keeps the payload-arm rate
-            row.update(
-                seq_stats={"device_records_per_sec": 141002.3,
-                           "host_records_per_sec": 293755.1,
-                           "host_decode_share": 0.021,
-                           "identical_to_host": True,
-                           "records": 24000, "spans": 12},
-                variant={"device_variants_per_sec": 88211.0,
-                         "host_variants_per_sec": 152003.4,
-                         "host_decode_share": 0.0,
-                         "identical_to_host": True, "variants": 50000},
-                serve_cold={"device_queries_per_sec": 21.4,
-                            "host_queries_per_sec": 23.8,
-                            "host_decode_share": 0.0,
-                            "device_tile_builds": 51,
-                            "identical_counts": True, "regions": 51})
-        if m == "device_inflate_records_per_sec":
-            # r11: the decode-plane wall breakdown (tokenize vs on-mesh
-            # resolve and their overlap) rides the FULL row only
-            row.update(
-                fused_records_per_sec=221931.0, records=24000, spans=12,
-                decode_plane_walls={
-                    "device": {"tokenize_wall_s": 0.083,
-                               "device_resolve_wall_s": 0.211,
-                               "overlap_s": 0.064,
-                               "overlap_efficiency": 0.77,
-                               "nonoverlap_inflate_share": 0.071},
-                    "fused": {"fused_decode_wall_s": 0.0718,
-                              "dispatch_wall_s": 0.0441,
-                              "overlap_s": 0.011,
-                              "overlap_efficiency": 0.25,
-                              "nonoverlap_inflate_share": 0.56}})
         comps.append(row)
     comps.append({"metric": "broken_row", "error": "RuntimeError: boom"})
     comps.append({"metric": "late_row", "skipped": "deadline"})
@@ -276,9 +236,6 @@ def test_full_snapshot_keeps_detail_on_progress_lines(bench):
     assert rs["fleet_failed_requests"] == 0
     ov = by_metric["obs_overhead_pct"]
     assert ov["instrumented_s"] > 0 and ov["null_s"] > 0
-    # r12: the device decode plane row pins the tokenize / device-resolve
-    # wall breakdown and overlap accounting — full row only, the compact
-    # line keeps just the rate
     # the write-path row pins the arm comparison fields and byte
     # identity — shape only, no ratio (host-dependent on 1 core)
     # r14: the degrade-and-heal serving row pins shed accounting (rate
@@ -319,35 +276,10 @@ def test_full_snapshot_keeps_detail_on_progress_lines(bench):
     assert mk["byte_identical_to_oracle"] is True
     assert mk["records"] > 0 and mk["output_bytes"] > 0
     assert mk["duplicates_marked"] >= 0
-    # r21: the device-plane families row pins per-arm host-oracle
-    # identity and the ~0 host-decode wall share on every device arm —
-    # full row only, the compact line keeps the payload-arm rate
-    dp = by_metric["device_plane_families_records_per_sec"]
-    for arm in ("seq_stats", "variant", "serve_cold"):
-        assert 0.0 <= dp[arm]["host_decode_share"] < 0.1
-    assert dp["seq_stats"]["identical_to_host"] is True
-    assert dp["seq_stats"]["records"] > 0 and dp["seq_stats"]["spans"] > 0
-    assert dp["variant"]["identical_to_host"] is True
-    assert dp["variant"]["variants"] > 0
-    assert dp["serve_cold"]["identical_counts"] is True
-    assert dp["serve_cold"]["device_tile_builds"] > 0
-    assert dp["serve_cold"]["regions"] > 0
-    di = by_metric["device_inflate_records_per_sec"]
-    planes = di["decode_plane_walls"]
-    assert set(planes) == {"device", "fused"}
-    dv = planes["device"]
-    assert dv["tokenize_wall_s"] > 0 and dv["device_resolve_wall_s"] > 0
-    assert 0.0 <= dv["overlap_efficiency"] <= 1.0
-    assert 0.0 <= dv["nonoverlap_inflate_share"] <= 1.0
-    assert 0.0 <= planes["fused"]["nonoverlap_inflate_share"] <= 1.0
-    assert di["fused_records_per_sec"] > 0 and di["spans"] > 0
     line = json.dumps(bench._compact_snapshot(full))
     assert len(line) <= bench.FINAL_LINE_BUDGET
     out = json.loads(line)
     assert out["components"]["region_query_queries_per_sec"] == 41.7
-    assert out["components"]["device_inflate_records_per_sec"] == 93211.4
-    assert out["components"][
-        "device_plane_families_records_per_sec"] == 141002.3
 
 
 def test_latency_component_dropped_before_components(bench):

@@ -9,8 +9,7 @@
 - the compile-cache placement rule (``utils/backend.enable_compile_cache``);
 - the native artifact's digest name: a foreign ``.so`` at the old fixed name
   is never loaded, the name follows source / flags / CPU, a failed build
-  keeps the compiler's message;
-- a raising decode-plane probe is logged and counted, not swallowed.
+  keeps the compiler's message.
 """
 import json
 import logging
@@ -21,9 +20,7 @@ import sys
 
 import pytest
 
-from hadoop_bam_tpu import config as hconfig
 from hadoop_bam_tpu.utils import backend, native
-from hadoop_bam_tpu.utils.metrics import MetricsContext
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
@@ -65,31 +62,28 @@ def test_chip_smoke_tiny_cpu_passes_and_second_run_hits_cache(tmp_path):
     report = json.loads((out / "chip_smoke_report.json").read_text())
     assert [p["phase"] for p in report["phases"]] == [
         "1-environment", "2-host-feed", "0-fixture", "3-scan",
-        "4-device-plane", "5-sort-mkdup", "6-serve", "7-compile-cache"]
+        "4-sort-mkdup", "5-serve", "6-compile-cache"]
     assert all(p["ok"] for p in report["phases"]), report["phases"]
     by = {p["phase"]: p for p in report["phases"]}
     assert by["1-environment"]["compile_cache_from_env"] is True
     assert by["3-scan"]["seq_stats_kernel"] == "xla-twin"   # cpu: no Mosaic
     assert by["3-scan"]["demotions"] == 0
-    assert set(by["4-device-plane"]["families"]) == {
-        "flagstat", "payload-seq-stats", "bcf-variant-stats",
-        "serve-cold-tiles"}
-    assert by["5-sort-mkdup"]["sort_bytes_spill"]["rounds"] >= 3
-    assert by["5-sort-mkdup"]["mkdup"]["duplicates_marked"] > 0
-    assert by["7-compile-cache"]["total_entries_written"] > 0
+    assert by["4-sort-mkdup"]["sort_bytes_spill"]["rounds"] >= 3
+    assert by["4-sort-mkdup"]["mkdup"]["duplicates_marked"] > 0
+    assert by["6-compile-cache"]["total_entries_written"] > 0
     # nothing of the run is left in the scratch parent but out/ + cache/
     assert sorted(p.name for p in tmp_path.iterdir()) == ["cache", "out"]
 
     second = _run(argv, env)
     assert second.returncode == 0, second.stdout[-4000:]
     report2 = json.loads((out / "chip_smoke_report.json").read_text())
-    cache2 = {p["phase"]: p for p in report2["phases"]}["7-compile-cache"]
+    cache2 = {p["phase"]: p for p in report2["phases"]}["6-compile-cache"]
     assert cache2["total_cache_hits"] > 0
     # same seed, same answers: the sorted / duplicate-marked bytes match
     by2 = {p["phase"]: p for p in report2["phases"]}
     for job in ("sort_index", "sort_bytes", "sort_bytes_spill", "mkdup"):
-        assert by2["5-sort-mkdup"][job]["sha256"] \
-            == by["5-sort-mkdup"][job]["sha256"]
+        assert by2["4-sort-mkdup"][job]["sha256"] \
+            == by["4-sort-mkdup"][job]["sha256"]
 
 
 # ---------------------------------------------------------------------------
@@ -234,26 +228,3 @@ def test_failed_build_keeps_the_compilers_message(fresh_native, monkeypatch,
     assert "error" in err and "broken.cpp" in err
     assert "native library build failed" in caplog.text
     assert not native.available()
-
-
-# ---------------------------------------------------------------------------
-# a raising plane probe is logged and counted
-# ---------------------------------------------------------------------------
-
-def test_raising_plane_probe_is_logged_and_counted(monkeypatch, caplog):
-    from hadoop_bam_tpu.ops import inflate_device
-
-    def boom():
-        raise RuntimeError("Mosaic refused the resolve step")
-
-    monkeypatch.setattr(inflate_device, "probe_device_plane", boom)
-    monkeypatch.setattr(hconfig, "_PLANE_CACHE", {})
-    with MetricsContext() as m, \
-            caplog.at_level(logging.ERROR, logger=hconfig.__name__):
-        plane = hconfig.resolve_inflate_backend(hconfig.DEFAULT_CONFIG)
-    assert plane == "native"
-    assert m.snapshot()["counters"]["pipeline.plane_probe_failed"] == 1
-    assert "device decode plane probe failed" in caplog.text
-    assert "Mosaic refused the resolve step" in caplog.text
-    assert hconfig.plane_probe_report() == {
-        "error": "RuntimeError: Mosaic refused the resolve step"}

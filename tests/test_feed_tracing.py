@@ -274,10 +274,6 @@ def _step_table():
     def spans(n):
         return [S((n, D), u8), S((n, R), i32), S((n,), i32)]
 
-    def tokens(n):
-        return [S((n, 2, T), u32), S((n, 2), i32), S((n, 2), i32),
-                S((n, 2, 2), i32)]
-
     def bounds(n):
         return [S((n - 1,), u32), S((n - 1,), u32)]
 
@@ -300,10 +296,6 @@ def _step_table():
             lambda m: pl.make_coverage_step(m, 128, 4),
             lambda n: [S((n, R, pl._CIGAR_ROW_HDR + 16), u8), S((n,), i32),
                        S((), i32), S((), i32)], True),
-        "device_flagstat_step": (pl.make_device_flagstat_step, tokens,
-                                 True),
-        "device_seq_stats_step": (
-            lambda m: pl.make_device_seq_stats_step(m, g), tokens, True),
         "tile_filter_step": (
             make_tile_filter_step,
             lambda n: [S((n, R), i32)] * 3 + [S((n,), i32), S((3,), i32)],
@@ -348,8 +340,7 @@ def _step_table():
 
 STEP_NAMES = [
     "flagstat_step", "flagstat_tile_step", "unpack_step", "seq_stats_step",
-    "read_stats_step", "coverage_step", "device_flagstat_step",
-    "device_seq_stats_step", "tile_filter_step", "sort_step",
+    "read_stats_step", "coverage_step", "tile_filter_step", "sort_step",
     "bytes_sort_step", "fused_sort_markdup_step", "markdup_exchange_step",
     "variant_step", "query_filter_step", "gwas_step", "cohort_slice_step",
     "totals_add",
@@ -358,7 +349,7 @@ STEP_NAMES = [
 
 def test_the_step_table_names_every_builder_once():
     assert sorted(_step_table()) == sorted(STEP_NAMES)
-    assert len(set(STEP_NAMES)) == len(STEP_NAMES) == 18
+    assert len(set(STEP_NAMES)) == len(STEP_NAMES) == 16
 
 
 @pytest.mark.parametrize("name", STEP_NAMES)

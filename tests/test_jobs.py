@@ -172,53 +172,32 @@ def test_resume_refuses_changed_input_identity(tmp_path):
                           **hdr)
 
 
-def test_plan_digest_refuses_device_host_route_swap(tmp_path):
-    """The decode ROUTE is plan identity: a journaled job compiled for
-    the BCF device variant route (round 21: ``variant_unpack_device`` in
-    the op DAG) refuses to resume against a host-plane journal, and vice
-    versa — the two routes partition work differently (device-plane span
-    grain vs the host span plan), so silently mixing them would
-    mis-stitch units."""
-    from hadoop_bam_tpu.jobs.runner import plan_journal_params
-    from hadoop_bam_tpu.plan import builders
+def test_journal_naming_device_backend_refuses_resume(tmp_path):
+    """A journal whose header was written under ``inflate_backend=
+    "device"`` (the on-mesh decode plane PR 30 deleted) names a plane
+    this build does not have: ``hbam resume`` refuses with a PlanError
+    listing the valid backends instead of resuming the job on another
+    plane — before the job's pipeline is even entered."""
+    from hadoop_bam_tpu.jobs.runner import resume_job
 
-    bcf = str(tmp_path / "x.bcf")       # builders never open the file
-    host_plan = builders.variant_stats_plan(
-        bcf, dataclasses.replace(DEFAULT_CONFIG,
-                                 inflate_backend="native"))
-    dev_plan = builders.variant_stats_plan(
-        bcf, dataclasses.replace(DEFAULT_CONFIG,
-                                 inflate_backend="device"))
-    assert [o["op"] for o in dev_plan.to_doc()["ops"]] == [
-        "variant_pack", "variant_unpack_device", "variant_stats_reduce"]
-    assert "variant_unpack_device" not in [
-        o["op"] for o in host_plan.to_doc()["ops"]]
-    assert host_plan.digest() != dev_plan.digest()
-
-    jp, inputs, hdr = _mini_job(tmp_path)
-    host_hdr = {**hdr, "params": plan_journal_params(host_plan)}
-    JobJournal.resume(jp, inputs=inputs, **host_hdr)[0].close()
-    with pytest.raises(PlanError, match="refusing to resume"):
-        JobJournal.resume(
-            jp, inputs=inputs,
-            **{**hdr, "params": plan_journal_params(dev_plan)})
-    # and the mirror image: device journal, host resume
-    jp2 = jp + ".dev"
-    dev_hdr = {**hdr, "params": plan_journal_params(dev_plan)}
-    JobJournal.resume(jp2, inputs=inputs, **dev_hdr)[0].close()
-    with pytest.raises(PlanError, match="refusing to resume"):
-        JobJournal.resume(
-            jp2, inputs=inputs,
-            **{**hdr, "params": plan_journal_params(host_plan)})
-    # a text VCF compiles the SAME plan under either backend (no device
-    # row exists for it) — no spurious refusal on a config-only change
-    vcf = str(tmp_path / "x.vcf")
-    assert builders.variant_stats_plan(
-        vcf, dataclasses.replace(DEFAULT_CONFIG,
-                                 inflate_backend="native")).digest() == \
-        builders.variant_stats_plan(
-            vcf, dataclasses.replace(DEFAULT_CONFIG,
-                                     inflate_backend="device")).digest()
+    jp, inputs, hdr = _mini_job(
+        tmp_path, kind="mesh_sort",
+        params={"input": str(tmp_path / "in.dat"),
+                "output": str(tmp_path / "out.dat"),
+                "exchange": None, "round_records": None})
+    j, _ = JobJournal.resume(
+        jp, inputs=inputs, **hdr,
+        config_values={"write_compress_level": 6,
+                       "inflate_backend": "device"})
+    j.close()
+    before = open(jp, "rb").read()
+    with pytest.raises(PlanError, match="unknown inflate backend 'device'"
+                       ) as ei:
+        resume_job(jp)
+    for name in ("auto", "native", "zlib"):
+        assert repr(name) in str(ei.value)
+    assert open(jp, "rb").read() == before      # nothing appended
+    assert not os.path.exists(tmp_path / "out.dat")
 
 
 def test_artifact_verification_and_sweep(tmp_path):

@@ -385,8 +385,8 @@ def test_the_step_names_its_phases():
 
 
 def test_a_finalizer_that_counts_cannot_deadlock_the_metrics():
-    """Found by this file's tests running before tests/test_device_planes.py
-    in one process: ``wall_timer`` allocates under the metrics lock, the
+    """Found by this file's tests running before another file's in one
+    process (PR 28): ``wall_timer`` allocates under the metrics lock, the
     allocation started a garbage collection, the collector closed an
     abandoned ``_iter_windowed`` generator, whose ``finally`` joined a
     native job and counted ``decode.native_busy_ns`` — into the lock its

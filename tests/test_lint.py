@@ -1198,124 +1198,6 @@ def test_missing_baseline_file_is_empty(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# device-plane sync discipline (DV9xx)
-# ---------------------------------------------------------------------------
-
-_DV_BAD = '''
-import numpy as np
-import jax
-
-def _device_plane_drain(chunks, handles):
-    out = []
-    for h in handles:
-        out.append(np.asarray(h))          # DV901: sync per iteration
-    total = 0
-    i = 0
-    while i < len(handles):
-        total += handles[i].item()         # DV901: sync per iteration
-        i += 1
-    for h in handles:
-        vals = jax.device_get(h)           # DV901: sync per iteration
-        total += int(vals[0])
-    return out, total
-'''
-
-_DV_CLEAN = '''
-import numpy as np
-import jax
-
-def _device_plane_drain(chunks, handles):
-    # the approved idiom: ONE bulk fetch, loops over host data
-    fetched = jax.device_get(handles)
-    total = 0
-    for vals in fetched:
-        total += int(vals[0])
-    return total
-
-def inflate_span_device(raw, table, chunk=64):
-    # host-boundary library function: its contract IS host bytes, the
-    # chunk-granular sync is the API, exempt by name
-    dst = []
-    for lo in range(0, 8, chunk):
-        dst.append(np.asarray(_resolve(raw, lo)))
-    return dst
-
-def _resolve(raw, lo):
-    return raw
-
-def _summary(handle):
-    return np.asarray(handle)              # not in a loop: one sync
-'''
-
-
-def test_dv_seeded_violations_fire():
-    findings = lint_sources(
-        {"hadoop_bam_tpu/ops/inflate_device.py": _DV_BAD},
-        only=["devicesync"])
-    assert rules_of(findings) == {"DV901"}
-    assert len(findings) == 3
-    assert all(f.severity == "error" for f in findings)
-    assert all("_device_plane_drain" in f.message for f in findings)
-
-
-def test_dv_clean_idioms_pass():
-    findings = lint_sources(
-        {"hadoop_bam_tpu/parallel/pipeline.py": _DV_CLEAN},
-        only=["devicesync"])
-    assert findings == []
-
-
-def test_dv_for_iter_expression_is_once_not_per_iteration():
-    # device_get in the for statement's ITERATOR evaluates once — the
-    # exact bulk-drain idiom the rule's message recommends
-    findings = lint_sources({"hadoop_bam_tpu/parallel/pipeline.py": '''
-import jax
-
-def _device_plane_totals(pairs):
-    tf = 0
-    for f, i in jax.device_get(pairs):
-        tf += f + i
-    return tf
-'''}, only=["devicesync"])
-    assert findings == []
-
-
-def test_dv_outside_plane_not_scoped():
-    # same bad source off the device decode plane: silent (serve/loop.py
-    # stays unscoped — its record-filter loop reads per-chunk hit counts
-    # by design; the PLANE files are tiles.py and the pipelines)
-    findings = lint_sources(
-        {"hadoop_bam_tpu/ops/inflate.py": _DV_BAD,
-         "hadoop_bam_tpu/serve/loop.py": _DV_BAD},
-        only=["devicesync"])
-    assert findings == []
-
-
-@pytest.mark.parametrize("path", [
-    "hadoop_bam_tpu/parallel/variant_pipeline.py",
-    "hadoop_bam_tpu/serve/tiles.py",
-])
-def test_dv_round21_families_are_scoped(path):
-    # the variant and cold-serve-tile device drivers joined the plane in
-    # round 21: the same seeded violations fire there...
-    findings = lint_sources({path: _DV_BAD}, only=["devicesync"])
-    assert rules_of(findings) == {"DV901"}
-    assert len(findings) == 3
-    # ...and the approved idioms stay silent
-    assert lint_sources({path: _DV_CLEAN}, only=["devicesync"]) == []
-
-
-def test_dv_live_plane_files_are_clean():
-    # the REAL driver sources hold the discipline they are linted for —
-    # baseline stays empty
-    import pathlib
-    root = pathlib.Path(__file__).resolve().parents[1]
-    from hadoop_bam_tpu.analysis.devicesync import SCOPE
-    srcs = {rel: (root / rel).read_text() for rel in SCOPE}
-    assert lint_sources(srcs, only=["devicesync"]) == []
-
-
-# ---------------------------------------------------------------------------
 # plane-routing discipline (PL101)
 # ---------------------------------------------------------------------------
 

@@ -20,7 +20,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The subprocess body: build fixtures in memory and push them through every
 # native entry point (BGZF header walk, inflate, CRC, record walks,
-# packed/payload walks, deflate, rANS 4x8 + Nx16, DEFLATE tokenize).
+# packed/payload walks, deflate, rANS 4x8 + Nx16).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -115,21 +115,6 @@ g = cancelled.chunks()
 next(g)
 g.close()          # join while workers may still be inflating
 assert cancelled.n_rows is not None
-
-# DEFLATE tokenize (host half of the device inflate), threaded
-src = np.frombuffer(raw, dtype=np.uint8)
-tokens, n_tokens, out_lens = native.deflate_tokenize_batch(
-    src, table["cdata_off"], table["cdata_len"],
-    int(table["isize"].max()) + 16, n_threads=4)
-assert (out_lens == table["isize"]).all()
-
-# tokenize with the CRC fold (thread-local resolve scratch under ASan/
-# TSan: each worker resolves its blocks into its own growable buffer)
-toks_c, nt_c, ol_c, crcs = native.deflate_tokenize_batch(
-    src, table["cdata_off"], table["cdata_len"],
-    int(table["isize"].max()) + 16, n_threads=4, with_crc=True)
-assert (ol_c == table["isize"]).all()
-assert (crcs == inflate_ops.footer_crcs(src, table)).all()
 
 # batch ITF8 (CRAM fixed-series predecode), incl. the truncation path
 from hadoop_bam_tpu.formats.cram import write_itf8

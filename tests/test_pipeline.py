@@ -441,3 +441,74 @@ def test_assign_spans_empty_plan():
     assert assign_spans([], index=0, count=2) == []
     assert assign_spans([], index=1, count=2) == []
     assert assign_spans([], index=0, count=1) == []
+
+
+# ---------------------------------------------------------------------------
+# which code inflates: config.resolve_inflate_backend, the one place
+# ---------------------------------------------------------------------------
+
+def test_inflate_backend_knob_and_selector():
+    from hadoop_bam_tpu.config import (
+        INFLATE_BACKENDS, HBamConfig, resolve_inflate_backend,
+    )
+    from hadoop_bam_tpu.utils.errors import PlanError
+
+    assert INFLATE_BACKENDS == ("auto", "native", "zlib")
+    cfg = HBamConfig.from_dict({"hbam.inflate-backend": "zlib"})
+    assert cfg.inflate_backend == "zlib"
+    assert resolve_inflate_backend(cfg) == "zlib"
+    assert resolve_inflate_backend(
+        HBamConfig(inflate_backend="native")) == "native"
+    assert resolve_inflate_backend(HBamConfig()) == "native"     # "auto"
+    assert resolve_inflate_backend(None) == "native"
+    for name in ("warp", "device"):
+        with pytest.raises(PlanError, match="expected one of") as ei:
+            resolve_inflate_backend(HBamConfig(inflate_backend=name))
+        assert all(repr(b) in str(ei.value) for b in INFLATE_BACKENDS)
+
+
+def test_auto_backend_resolves_without_jax(monkeypatch):
+    """"auto" is a pure function of the config: no probe, no compile, no
+    JAX call — whatever the backend calls itself.  (Every process on the
+    chip used to jit-compile and time a device resolve step here.)"""
+    import jax
+
+    from hadoop_bam_tpu import config as hconfig
+
+    touched = []
+
+    def refuse(*a, **kw):
+        touched.append(a)
+        raise RuntimeError("resolve_inflate_backend touched JAX")
+
+    # a backend that does not call itself "cpu", were anything to ask
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: touched.append("backend") or "tpu")
+    for name in ("jit", "devices", "device_put"):
+        monkeypatch.setattr(jax, name, refuse)
+    monkeypatch.setattr(jax.numpy, "asarray", refuse)
+    assert hconfig.resolve_inflate_backend(hconfig.HBamConfig()) == "native"
+    assert touched == []
+    assert not hasattr(hconfig, "plane_probe_report")
+    assert not hasattr(hconfig, "_PLANE_CACHE")
+
+
+def test_flagstat_zlib_backend_honored(tmp_path):
+    """inflate_backend='zlib' rides the host path with the fused native
+    plane disabled — same totals, portable plane."""
+    import dataclasses
+
+    from hadoop_bam_tpu.config import DEFAULT_CONFIG
+    from hadoop_bam_tpu.formats.bamio import write_bam
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
+    header = make_header()
+    path = str(tmp_path / "z.bam")
+    write_bam(path, header, make_records(header, 500, seed=2))
+    with MetricsContext() as m:
+        host = flagstat_file(path)
+    assert m.get("decode.native_jobs") > 0        # the fused sweep ran
+    cfg = dataclasses.replace(DEFAULT_CONFIG, inflate_backend="zlib")
+    with MetricsContext() as m:
+        assert flagstat_file(path, config=cfg) == host
+    assert m.get("decode.native_jobs") == 0

@@ -8,8 +8,8 @@ The load-bearing pins:
   pre-refactor direct path — the inline mesh-feed impls it now wraps.
 - **Plane selection in ONE function**: ``select_plane`` is the single
   predicate table; the gate matrix (intervals x skip_bad_spans x
-  backend x native-missing x op DAG x breaker) is pinned combination
-  by combination, including the rejection reasons ``hbam explain``
+  backend x native-missing x family) is pinned combination by
+  combination, including the rejection reasons ``hbam explain``
   prints.
 - **Digest stability**: the IR serialization is canonical — same plan,
   same digest across processes; any field change moves it; the format
@@ -71,6 +71,42 @@ def test_digest_stable_and_plan_digest_compatible(bam):
     assert [o["op"] for o in doc["ops"]] == ["project", "flagstat_reduce"]
 
 
+# Digests of the scan and query plans as the parent of PR 30 computed
+# them (commit a0c6db5, default config): journals written before the
+# on-mesh decode plane was deleted must keep resuming.
+_PARENT_DIGESTS = {
+    "flagstat": "49b6e793209ed79f83785074",
+    "seq_stats": "20ec2ff440d832936a30b064",
+    "variant_stats_bcf": "97f470ead550d1b20e311557",
+    "variant_stats_vcf": "8b8f7023e089e56eef57d23f",
+    "query_chunk": "bff6ff5d4b22c71639959132",
+    "query_region": "baad55ad16475495a68f77c0",
+    "serve_tile": "5e0a3d81d12536fb73c91066",
+}
+
+
+@pytest.mark.parametrize("backend", ["auto", "native", "zlib"])
+def test_plan_digests_unchanged(backend):
+    cfg = dataclasses.replace(DEFAULT_CONFIG, inflate_backend=backend)
+    bam_path = "/data/na12878.bam"
+    got = {
+        "flagstat": builders.flagstat_plan(bam_path, cfg),
+        "seq_stats": builders.seq_stats_plan(bam_path, cfg),
+        "variant_stats_bcf": builders.variant_stats_plan(
+            "/data/kgp3.bcf", cfg),
+        "variant_stats_vcf": builders.variant_stats_plan(
+            "/data/kgp3.vcf.gz", cfg),
+        "query_chunk": builders.query_chunk_plan(
+            bam_path, "bam", 65536, 1 << 24),
+        "query_region": builders.query_region_plan(
+            bam_path, "bam", "chr20:1000-2000",
+            [(65536, 1 << 20), (1 << 21, 1 << 22)]),
+        "serve_tile": builders.serve_tile_plan(
+            bam_path, "bam", 65536, 1 << 24),
+    }
+    assert {k: p.digest() for k, p in got.items()} == _PARENT_DIGESTS
+
+
 def test_pinned_spans_and_param_normalization():
     s = SpansIR.pin([("f.bam", 7, 99)])
     assert s.mode == "pinned" and s.pinned == (("f.bam", 7, 99),)
@@ -105,11 +141,10 @@ def test_select_native_clean_path():
     from hadoop_bam_tpu.ops.inflate import fused_available
     d = select_plane(_FLAG_SRC, _FLAG_OPS,
                      _cfg(inflate_backend="native"))
-    assert d.plane == "native" and d.backend == "native"
-    assert d.host_backend == "native"
+    assert d.plane == "native"
     assert d.use_fused == fused_available()
     assert d.stream_fused == fused_available()
-    assert "device" in _rejected(d)
+    assert set(_rejected(d)) == (set() if fused_available() else {"fused"})
 
 
 def test_select_zlib_pins_portable_plane():
@@ -120,66 +155,25 @@ def test_select_zlib_pins_portable_plane():
     assert "native" in rej and "fused" in rej
 
 
-def test_select_device_full_gate_pass():
+def test_select_fused_stream_rejected_by_intervals():
     d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="device"))
-    assert d.plane == "device"
-    assert d.host_backend == "auto"      # host fallback rides auto
-
-
-def test_select_device_rejected_by_intervals():
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="device"), intervals=[()])
+                     _cfg(inflate_backend="native"), intervals=[()])
     assert d.plane == "native"
-    assert "whole-span offsets" in _rejected(d)["device"]
-    # fused streaming is gated by the same condition
-    assert not d.stream_fused
-
-
-def test_select_device_rejected_by_skip_bad_spans():
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="device", skip_bad_spans=True))
-    assert d.plane == "native"
-    assert "quarantine" in _rejected(d)["device"]
-    assert not d.stream_fused
-
-
-def test_select_device_rejected_for_non_device_dag():
-    # the query chunk-columns DAG (chunk_decode alone, host predicate
-    # columns) has no device route
-    d = select_plane(SourceIR("x.bam", "bam", role="chunk"),
-                     (op_node("chunk_decode"),),
-                     _cfg(inflate_backend="device"))
-    assert d.plane == "native"
-    assert "op DAG" in _rejected(d)["device"]
-    # but the non-device planes keep fused streaming when eligible
+    # the sweep itself stays eligible; only chunk streaming is gated
     from hadoop_bam_tpu.ops.inflate import fused_available
-    assert d.stream_fused == fused_available()
+    assert d.use_fused == fused_available() and not d.stream_fused
+    if fused_available():
+        assert "whole span's offsets" in _rejected(d)["fused-stream"]
 
 
-def test_select_device_families_round21():
-    """The round-21 families pass the device gate: BAM payload, BCF
-    variant, BAM serve-tile — and their near-misses reject with the
-    capability reason."""
-    cfg = _cfg(inflate_backend="device")
-    # payload (seq_stats) on BAM
-    assert select_plane(_FLAG_SRC, _PAYLOAD_OPS, cfg).plane == "device"
-    # variant on BCF
-    vops = (op_node("variant_pack"), op_node("variant_stats_reduce"))
-    assert select_plane(SourceIR("x.bcf", "bcf"), vops,
-                        cfg).plane == "device"
-    # serve-tile on BAM (chunk role)
-    sops = (op_node("chunk_decode"), op_node("tile_build"))
-    assert select_plane(SourceIR("x.bam", "bam", role="chunk"), sops,
-                        cfg).plane == "device"
-    # text VCF deliberately has NO device row: the token feed needs the
-    # BGZF container and the BCF binary layout
-    d = select_plane(SourceIR("x.vcf", "vcf"), vops, cfg)
+def test_select_fused_stream_rejected_by_skip_bad_spans():
+    d = select_plane(_FLAG_SRC, _FLAG_OPS,
+                     _cfg(inflate_backend="native", skip_bad_spans=True))
     assert d.plane == "native"
-    assert "op DAG" in _rejected(d)["device"]
-    # a CRAM source can never ride the BGZF token feed either
-    d2 = select_plane(SourceIR("x.cram", "cram"), _PAYLOAD_OPS, cfg)
-    assert "op DAG" in _rejected(d2)["device"]
+    from hadoop_bam_tpu.ops.inflate import fused_available
+    assert d.use_fused == fused_available() and not d.stream_fused
+    if fused_available():
+        assert "quarantine" in _rejected(d)["fused-stream"]
 
 
 @pytest.mark.parametrize("src,ops", [
@@ -190,55 +184,59 @@ def test_select_device_families_round21():
     (SourceIR("x.bam", "bam", role="chunk"),
      (op_node("chunk_decode"), op_node("tile_build"))),
 ])
-def test_select_round21_families_share_the_gate_matrix(src, ops):
-    """Every new family rejects through the SAME gates as flagstat:
-    intervals, skip_bad_spans, open breaker — reason strings included
-    (the `hbam explain` surface)."""
-    d = select_plane(src, ops, _cfg(inflate_backend="device"),
+def test_select_families_share_the_gate_matrix(src, ops, monkeypatch):
+    """Every family decides through the SAME gates as flagstat:
+    intervals and skip_bad_spans gate chunk streaming, zlib pins the
+    portable plane with the sweep off — reason strings included (the
+    `hbam explain` surface)."""
+    from hadoop_bam_tpu.ops import inflate as inflate_ops
+    monkeypatch.setattr(inflate_ops, "fused_available", lambda: True)
+
+    d = select_plane(src, ops, _cfg(), intervals=[()])
+    assert d == select_plane(_FLAG_SRC, _FLAG_OPS, _cfg(), intervals=[()])
+    assert d.plane == "native" and d.use_fused and not d.stream_fused
+    assert "whole span's offsets" in _rejected(d)["fused-stream"]
+
+    d = select_plane(src, ops, _cfg(skip_bad_spans=True))
+    assert d.plane == "native" and d.use_fused and not d.stream_fused
+    assert "quarantine" in _rejected(d)["fused-stream"]
+
+    d = select_plane(src, ops, _cfg(inflate_backend="zlib"),
                      intervals=[()])
-    assert d.plane != "device"
-    assert "whole-span offsets" in _rejected(d)["device"]
+    assert d.plane == "zlib" and not d.use_fused and not d.stream_fused
+    assert set(_rejected(d)) == {"fused", "native"}
 
-    d = select_plane(src, ops, _cfg(inflate_backend="device",
-                                    skip_bad_spans=True))
-    assert d.plane != "device"
-    assert "quarantine" in _rejected(d)["device"]
-
-    class OpenLadder:
-        def allow_plane(self, plane):
-            return False
-
-    d = select_plane(src, ops, _cfg(inflate_backend="device"),
-                     ladder=OpenLadder())
-    assert d.plane != "device"
-    assert "breaker" in _rejected(d)["device"]
-
-    d = select_plane(src, ops, _cfg(inflate_backend="native"))
-    assert d.plane == "native"
-    assert "inflate_backend" in _rejected(d)["device"]
+    d = select_plane(src, ops, _cfg())
+    assert d.plane == "native" and d.use_fused and d.stream_fused
+    assert _rejected(d) == {}
 
 
-def test_select_device_rejected_by_open_breaker():
-    class OpenLadder:
-        probes = 0
+def test_inflate_backend_device_is_refused(bam, capsys):
+    """The deleted on-mesh decode plane left no name behind: a config
+    that asks for it is a PlanError listing the three valid values, at
+    selection and at execution, and the CLI's parser rejects the flag
+    value."""
+    from hadoop_bam_tpu.config import INFLATE_BACKENDS
+    from hadoop_bam_tpu.parallel.pipeline import flagstat_file
+    from hadoop_bam_tpu.parallel.variant_pipeline import variant_stats_file
+    from hadoop_bam_tpu.tools.cli import main
+    from hadoop_bam_tpu.utils.errors import PLAN, PlanError, classify_error
 
-        def allow_plane(self, plane):
-            self.probes += 1
-            return False
-
-    lad = OpenLadder()
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="device"), ladder=lad)
-    assert d.plane == "native"
-    assert "breaker" in _rejected(d)["device"]
-    assert lad.probes == 1
-
-    # the probe slot is consumed ONLY when every other gate passed
-    lad2 = OpenLadder()
-    select_plane(_FLAG_SRC, _FLAG_OPS,
-                 _cfg(inflate_backend="device", skip_bad_spans=True),
-                 ladder=lad2)
-    assert lad2.probes == 0
+    assert INFLATE_BACKENDS == ("auto", "native", "zlib")
+    path, header, _ = bam
+    cfg = _cfg(inflate_backend="device")
+    for run in (lambda: select_plane(_FLAG_SRC, _FLAG_OPS, cfg),
+                lambda: flagstat_file(path, config=cfg, header=header),
+                lambda: variant_stats_file(path + ".bcf", config=cfg)):
+        with pytest.raises(PlanError) as ei:
+            run()
+        assert classify_error(ei.value) == PLAN
+        for name in INFLATE_BACKENDS:
+            assert repr(name) in str(ei.value)
+    with pytest.raises(SystemExit) as se:
+        main(["explain", "flagstat", path, "--inflate-backend", "device"])
+    assert se.value.code == 2
+    assert "invalid choice: 'device'" in capsys.readouterr().err
 
 
 def test_select_native_missing_disables_fused(monkeypatch):
@@ -249,12 +247,6 @@ def test_select_native_missing_disables_fused(monkeypatch):
     assert d.plane == "native"
     assert not d.use_fused and not d.stream_fused
     assert "unavailable" in _rejected(d)["fused"]
-    # explicit device WITHOUT the native tokenizer still selects device:
-    # the runner raises PlanError (configuration fault), selection must
-    # not silently reroute a user's explicit plane choice
-    d2 = select_plane(_FLAG_SRC, _FLAG_OPS,
-                      _cfg(inflate_backend="device"))
-    assert d2.plane == "device"
 
 
 def test_select_fused_off_by_config():
@@ -270,11 +262,14 @@ def test_plane_report_families():
     rep = plane_report(_cfg(inflate_backend="native"))
     assert set(rep) == {"flagstat", "payload", "variant", "serve"}
     for fam in rep.values():
-        assert fam["plane"] in ("device", "native", "zlib")
+        assert fam["plane"] == "native"
+        assert set(fam) == {"plane", "use_fused", "stream_fused",
+                            "rejected"}
         assert isinstance(fam["rejected"], dict)
-    # under the device backend every family routes device
-    dev = plane_report(_cfg(inflate_backend="device"))
-    assert all(f["plane"] == "device" for f in dev.values())
+    # under the zlib backend every family routes zlib, the sweep off
+    z = plane_report(_cfg(inflate_backend="zlib"))
+    assert all(f["plane"] == "zlib" and not f["use_fused"]
+               for f in z.values())
 
 
 # ---------------------------------------------------------------------------
@@ -466,13 +461,17 @@ def test_explain_cli_text_and_json(bam, capsys):
     out = capsys.readouterr().out
     assert "plane   " in out and "sink    flagstat" in out
 
+    assert "probe" not in out
+
     assert main(["explain", "flagstat", path, "--json",
-                 "--inflate-backend", "device",
+                 "--inflate-backend", "zlib",
                  "--skip-bad-spans"]) == 0
     doc = json.loads(capsys.readouterr().out)
+    assert set(doc) == {"plan", "digest", "decision"}
     assert doc["digest"] == builders.flagstat_plan(path).digest()
-    assert doc["decision"]["plane"] == "native"
-    assert "quarantine" in doc["decision"]["rejected"]["device"]
+    assert doc["decision"]["plane"] == "zlib"
+    assert "portable" in doc["decision"]["rejected"]["native"]
+    assert "zlib" in doc["decision"]["rejected"]["fused"]
 
 
 def test_explain_cli_query_pins_chunks(bam, capsys):

@@ -2,9 +2,9 @@
 
 - the closed/open/half-open ``CircuitBreaker`` state machine on an
   injected clock (decayed windows, probe budgets, retry-after hints);
-- the decode-plane demotion ladder: flagstat under injected device /
-  native plane faults completes byte-identical to the zlib oracle,
-  demotes mid-run, and heals back through a half-open probe;
+- the decode-plane demotion ladder: flagstat and seq-stats under
+  injected native plane faults complete byte-identical to the zlib
+  oracle, demote mid-run, and heal back through a half-open probe;
 - the upgraded quarantine circuit (fast-fail gate + heal on a clean
   probe run);
 - serve-tier degradation: per-tenant breakers, shed taxonomy with
@@ -293,55 +293,72 @@ def test_adaptive_planes_off_keeps_static_selection(bam):
     assert resilience.registry().states() == {}
 
 
-@pytest.mark.skipif(
-    not __import__("hadoop_bam_tpu.utils.native",
-                   fromlist=["available"]).available(),
-    reason="device plane needs the native tokenizer")
-def test_device_step_faults_demote_to_host_then_heal(bam):
-    """Device rung of the ladder: an injected shard_map-step fault
-    unwinds the device-plane run; flagstat demotes to the host planes
-    mid-call (identical result), charges the device domain only after
-    the host run completes, and a half-open probe heals it."""
+def test_payload_native_faults_demote_to_zlib_then_heal(bam):
+    """The payload family's rung of the same ladder (the flagstat twin
+    is above): seq-stats under injected native-plane faults equals the
+    zlib oracle, opens the native domain, stays on zlib while it is
+    OPEN, and a half-open probe heals it."""
+    from hadoop_bam_tpu.parallel.pipeline import seq_stats_file
+
     path, header, records = bam
     spans = _spans(path, header, n=3)
     clk = FakeClock()
     resilience.reset(clock=clk)
-    oracle = _flagstat(path, header, spans, _cfg(
-        inflate_backend="zlib", adaptive_planes=False))
 
-    cfg = _cfg(inflate_backend="device", breaker_failure_threshold=1.0)
-    with fault_points_on("device.step",
-                         [PointFault("transient", count=1)]):
-        faulted = _flagstat(path, header, spans, cfg)
+    def run(config):
+        out = seq_stats_file(path, header=header, spans=spans,
+                             config=config)
+        return {k: np.asarray(v).tolist() for k, v in out.items()}
+
+    oracle = run(_cfg(inflate_backend="zlib", adaptive_planes=False))
+    assert oracle["n_reads"] == len(records)
+
+    cfg = _cfg(inflate_backend="native", breaker_failure_threshold=1.0)
+    with fault_points_on("decode.native",
+                         [PointFault("corrupt", count=1000)]):
+        faulted = run(cfg)
     assert faulted == oracle
-    key = f"decode/device/{os.path.abspath(path)}"
-    states = resilience.registry().states()
-    assert states[key]["state"] == OPEN          # threshold 1: open now
+    key = f"decode/native/{os.path.abspath(path)}"
+    assert resilience.registry().states()[key]["state"] == OPEN
 
-    # OPEN device circuit: the run starts straight on the host planes
-    demoted = _flagstat(path, header, spans, cfg)
-    assert demoted == oracle
-    # cooled down: half-open probe goes back through the device plane
+    assert run(cfg) == oracle                    # OPEN: straight on zlib
+    assert resilience.registry().states()[key]["state"] == OPEN
     clk.advance(float(cfg.breaker_cooldown_s) + 0.1)
-    healed = _flagstat(path, header, spans, cfg)
-    assert healed == oracle
+    assert run(cfg) == oracle                    # the half-open probe
     states = resilience.registry().states()
     assert states[key]["state"] == CLOSED
     assert states[key]["healed_total"] == 1
 
 
-def test_device_plan_error_never_demotes(bam, monkeypatch):
-    """PLAN-class failures (native library missing under a forced
-    device backend) raise through the ladder untouched — a
-    misconfigured run must not silently degrade (pinned since PR 9)."""
-    from hadoop_bam_tpu.utils import native as native_mod
+def test_plan_error_never_demotes(bam, monkeypatch):
+    """A PLAN-class failure on the native rung raises through the ladder
+    untouched — no retry, no demotion to a zlib rung that would have
+    decoded the span, no domain charged: a misconfigured run must not
+    silently degrade (pinned since PR 9)."""
+    from hadoop_bam_tpu.parallel import pipeline
 
     path, header, _ = bam
-    monkeypatch.setattr(native_mod, "available", lambda: False)
-    with pytest.raises(PlanError):
-        _flagstat(path, header, _spans(path, header, n=2),
-                  _cfg(inflate_backend="device"))
+    real = pipeline.decode_span_prefix_host
+    calls = []
+
+    def misconfigured(src, span, check_crc, backend, *a, **kw):
+        calls.append(backend)
+        if backend == "native":
+            raise PlanError("the native rung is misconfigured")
+        return real(src, span, check_crc, backend, *a, **kw)
+
+    monkeypatch.setattr(pipeline, "decode_span_prefix_host", misconfigured)
+    spans = _spans(path, header, n=2)
+    demotions = METRICS.get("pipeline.span_demotions")
+    with pytest.raises(PlanError, match="misconfigured"):
+        _flagstat(path, header, spans,
+                  _cfg(inflate_backend="native", use_fused_decode=False))
+    assert calls and set(calls) == {"native"}
     assert resilience.registry().states() == {}
+    assert METRICS.get("pipeline.span_demotions") == demotions
+    # the zlib rung would have decoded it: the same run, started there
+    assert _flagstat(path, header, spans, _cfg(
+        inflate_backend="zlib"))["total"] == 3000
 
 
 # ---------------------------------------------------------------------------
