@@ -201,6 +201,12 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_deflate_batch.argtypes = [
             i8p, i64p, i32p, ctypes.c_int32, i8p, i64p, i32p, i32p,
             ctypes.c_int32, ctypes.c_int32]
+        i8sp = ctypes.POINTER(ctypes.c_int8)
+        lib.hbam_bcf_gt_dosage.restype = ctypes.c_int64
+        lib.hbam_bcf_gt_dosage.argtypes = [
+            i8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int64, i8sp, ctypes.c_int64,
+            ctypes.c_int64]
         if hasattr(lib, "hbam_fused_start"):
             lib.hbam_fused_start.restype = ctypes.c_void_p
             lib.hbam_fused_start.argtypes = [
@@ -419,6 +425,42 @@ def itf8_decode_batch(buf: np.ndarray, count: int
     if consumed < 0:
         raise ValueError("ITF8 stream truncated")
     return out, int(consumed)
+
+
+def bcf_gt_dosage(buf: np.ndarray, rows: np.ndarray, offs: np.ndarray,
+                  typ: int, ploidy: int, n_sample: int,
+                  dosage: np.ndarray) -> None:
+    """Native GT -> ALT dosage of one layout group of a BCF span (the
+    interpreter lock is released for the call): record ``i`` keeps
+    ``ploidy x n_sample`` genotypes of BCF int type ``typ`` at
+    ``buf[offs[i]]`` and gets its ``n_sample`` int8 dosages in row
+    ``rows[i]`` of ``dosage``; columns past ``n_sample`` are left alone.
+    The kernel re-checks every extent: a payload or row outside its
+    buffer, or a layout it cannot take, raises ``BCFError`` and writes
+    nothing."""
+    from hadoop_bam_tpu.formats.bcf import BCFError
+    lib = load()
+    assert lib is not None
+    if (buf.dtype != np.uint8 or dosage.dtype != np.int8 or dosage.ndim != 2
+            or not buf.flags.c_contiguous or not dosage.flags.c_contiguous):
+        raise ValueError("bcf_gt_dosage wants contiguous u8 bytes and an "
+                         "i8 [records, samples] matrix")
+    offs = np.ascontiguousarray(offs, np.int64)
+    rows = np.ascontiguousarray(rows, np.int64)
+    if offs.shape != rows.shape or offs.ndim != 1:
+        raise ValueError("bcf_gt_dosage wants one offset a row")
+    rc = int(lib.hbam_bcf_gt_dosage(
+        _ptr(buf, ctypes.c_uint8), int(buf.size),
+        _ptr(offs, ctypes.c_int64), _ptr(rows, ctypes.c_int64),
+        int(offs.size), int(typ), int(ploidy), int(n_sample),
+        _ptr(dosage, ctypes.c_int8), int(dosage.shape[0]),
+        int(dosage.shape[1])))
+    if rc < 0:
+        raise BCFError(f"GT layout (type {typ}, ploidy {ploidy}, "
+                       f"{n_sample} samples) cannot be gathered")
+    if rc:
+        raise BCFError(f"GT vector of record {rc - 1} of its layout group "
+                       "overruns the span")
 
 
 def fused_available() -> bool:

@@ -14,8 +14,10 @@
 #include <cstdint>
 #include <cstring>
 #include <deque>
+#include <limits>
 #include <mutex>
 #include <thread>
+#include <type_traits>
 #include <vector>
 
 #include <zlib.h>
@@ -485,6 +487,106 @@ long long hbam_itf8_decode_batch(const unsigned char* buf,
     p += 1 + extra;
   }
   return p;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// BCF GT -> ALT dosage (formats/bcf_columns.py): the per-sample reduction of
+// one record's genotype vector, the semantics of _gt_group_dosage and
+// formats/bcf.scan_variant_columns.  Per entry g of a width whose minimum is
+// MISS and whose end-of-vector value is EOV = MISS + 1:
+//   present = g != EOV
+//   missing = present and (g >> 1 == 0 or g == MISS)   -- g in {0, 1, MISS}:
+//             allele index (g >> 1) - 1 < 0, phase bit masked ('0|.' is 1)
+//   alt     = present and (g >> 1) > 1                 -- g >= 4
+// and per sample: -1 with no present entry or any missing one, else the
+// count of alt entries, clamped to 127.
+// ---------------------------------------------------------------------------
+namespace {
+
+template <typename T>
+inline T gt_load(const uint8_t* p) {       // GT vectors sit at any offset
+  T v;
+  std::memcpy(&v, p, sizeof(T));
+  return v;
+}
+
+// One record's row.  P > 0 is a ploidy known at compile time (1 and 2: what
+// call sets use): the entry loop unrolls and the sample loop is branch-free
+// in the genotype's own width, so the compiler vectorises it over samples.
+// P == 0 takes any ``ploidy`` (EOV-padded mixed, polyploid) a sample at a
+// time, the ALT count held wider than the int8 it is clamped into.
+template <typename T, int P>
+void gt_dosage_row(const uint8_t* g, int64_t ns, int32_t ploidy,
+                   int8_t* out) {
+  using Acc = typename std::conditional<P != 0, T, int32_t>::type;
+  constexpr T kMiss = std::numeric_limits<T>::min();
+  constexpr T kEov = kMiss + 1;
+  const int32_t np = P ? P : ploidy;
+  for (int64_t s = 0; s < ns; ++s) {
+    Acc present = 0, missing = 0, n_alt = 0;
+    for (int32_t k = 0; k < np; ++k) {
+      const T v = gt_load<T>(g + (s * np + k) * sizeof(T));
+      present |= static_cast<Acc>(v != kEov);
+      missing |= static_cast<Acc>((v == kMiss) | (v == 0) | (v == 1));
+      n_alt += static_cast<Acc>(v >= 4);
+    }
+    out[s] = static_cast<int8_t>(
+        (present & ~missing & 1) ? (n_alt > 127 ? 127 : n_alt) : -1);
+  }
+}
+
+template <typename T>
+void gt_dosage_rows(const uint8_t* buf, const int64_t* offs,
+                    const int64_t* rows, int64_t n, int32_t ploidy,
+                    int64_t ns, int8_t* out, int64_t out_stride) {
+  auto* row = ploidy == 2 ? gt_dosage_row<T, 2>
+            : ploidy == 1 ? gt_dosage_row<T, 1> : gt_dosage_row<T, 0>;
+  for (int64_t i = 0; i < n; ++i)
+    row(buf + offs[i], ns, ploidy, out + rows[i] * out_stride);
+}
+
+}  // namespace
+
+extern "C" {
+
+// ALT dosage of one GT layout group of a BCF span: record i keeps ploidy x
+// n_sample little-endian genotypes of BCF type ``typ`` (1 int8, 2 int16,
+// 3 int32) at buf[offs[i]] and gets n_sample int8 dosages in row rows[i]
+// of the [out_rows, out_stride] matrix; columns past n_sample are left as
+// they are.  The inner loop is chosen from (typ, ploidy), which the
+// records state.  No threads: the callers' pool threads run it with the
+// interpreter lock released.  Every extent is checked before anything is
+// written: returns 0, -1 for a layout it cannot take, or 1 + the index of
+// the first record whose payload or row lies outside its buffer.
+int64_t hbam_bcf_gt_dosage(const uint8_t* buf, int64_t buf_len,
+                           const int64_t* offs, const int64_t* rows,
+                           int64_t n, int32_t typ, int32_t ploidy,
+                           int64_t n_sample, int8_t* out, int64_t out_rows,
+                           int64_t out_stride) {
+  // a ploidy past 2^24 or 2^32 samples is no BCF record's (and would
+  // overflow the extent below)
+  if (typ < 1 || typ > 3 || ploidy < 0 || ploidy > (1 << 24) ||
+      n_sample < 0 || n_sample > out_stride ||
+      n_sample > (int64_t{1} << 32) || n < 0 || buf_len < 0 || out_rows < 0)
+    return -1;
+  const int64_t extent = (typ == 3 ? 4 : typ) * ploidy * n_sample;
+  for (int64_t i = 0; i < n; ++i) {
+    if (offs[i] < 0 || offs[i] > buf_len || extent > buf_len - offs[i] ||
+        rows[i] < 0 || rows[i] >= out_rows)
+      return 1 + i;
+  }
+  if (typ == 1)
+    gt_dosage_rows<int8_t>(buf, offs, rows, n, ploidy, n_sample, out,
+                           out_stride);
+  else if (typ == 2)
+    gt_dosage_rows<int16_t>(buf, offs, rows, n, ploidy, n_sample, out,
+                            out_stride);
+  else
+    gt_dosage_rows<int32_t>(buf, offs, rows, n, ploidy, n_sample, out,
+                            out_stride);
+  return 0;
 }
 
 }  // extern "C"

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from hadoop_bam_tpu.formats.bcf import (
-    BCFError, BCFRecordCodec, scan_variant_columns,
+    BCFError, BCFRecordCodec, T_INT8, T_INT16, T_INT32, scan_variant_columns,
 )
 from hadoop_bam_tpu.formats.bcf_columns import (
     STAT_KEYS, decode_bcf_columns, frame_record_starts, stat_columns,
@@ -170,10 +170,246 @@ def test_empty_buffer():
 
 
 # ---------------------------------------------------------------------------
+# the native GT -> dosage kernel against its two oracles
+# ---------------------------------------------------------------------------
+
+_GT_MISS = {T_INT8: -128, T_INT16: -32768, T_INT32: -2147483648}
+_GT_FMT = {T_INT8: "<b", T_INT16: "<h", T_INT32: "<i"}
+# the widest allele index a width holds: ((a + 1) << 1) | 1 under its
+# sentinels (int8 stops at 62, so a site with 64 alleles widens to int16)
+_GT_AMAX = {T_INT8: 62, T_INT16: 16000, T_INT32: 1 << 20}
+GT_PAD = 8                      # samples_pad of these spans; n_sample 5
+
+
+def _raw_record(pos, typ=None, ploidy=0, gt=None, n_sample=5):
+    """One BCF record built byte by byte so that the GT width is the
+    test's choice (the codec always takes the narrowest): biallelic SNP,
+    PASS, no INFO, FORMAT GT of ``typ`` x ``ploidy`` holding the
+    [n_sample, ploidy] values ``gt`` — or no FORMAT at all."""
+    from hadoop_bam_tpu.formats.bcf import (
+        _descriptor, encode_typed_ints, encode_typed_string,
+    )
+    n_fmt = 0 if gt is None else 1
+    shared = struct.pack("<iiifHHI", 0, pos, 1, 30.0, 0, 2,
+                         (n_fmt << 24) | (n_sample if n_fmt else 0))
+    shared += _descriptor(0, 7)                     # ID: empty string
+    shared += encode_typed_string("A") + encode_typed_string("C")
+    shared += encode_typed_ints([0])                # FILTER PASS
+    indiv = b""
+    if gt is not None:
+        gt_key = _header().string_dictionary().index("GT")
+        indiv = encode_typed_ints([gt_key]) + _descriptor(ploidy, typ)
+        indiv += b"".join(struct.pack(_GT_FMT[typ], int(v))
+                          for v in np.asarray(gt).reshape(-1))
+    return struct.pack("<II", len(shared), len(indiv)) + shared + indiv
+
+
+def _called(rng, typ, ploidy, ns, amax=3):
+    """[ns, ploidy] genotype values, every allele called (0..amax),
+    phased or not at random."""
+    a = rng.integers(0, amax + 1, (ns, ploidy))
+    return ((a + 1) << 1) | rng.integers(0, 2, (ns, ploidy))
+
+
+def _gt_case(case, typ, ploidy, rng, ns=5):
+    """The records of one case: a list of ``_raw_record`` keywords."""
+    miss, eov = _GT_MISS[typ], _GT_MISS[typ] + 1
+    recs = []
+    for _ in range(6):
+        g = _called(rng, typ, ploidy, ns)
+        if case == "nocall":
+            g[0, :] = 0                             # './.'
+            g[1, :] = miss                          # typed MISSING
+            g[2, rng.integers(ploidy)] = 0          # '0/.'
+        elif case == "half_missing":
+            g[0, -1] = 1                            # '0|.'
+            g[1, 0] = 1                             # '.|0' (phase bit set)
+            g[3, :] = 1
+        elif case == "eov_padded":
+            for s in range(ns):                     # s present, rest padded
+                g[s, min(s, ploidy):] = eov         # sample 0: none present
+        elif case == "big_allele":
+            g = _called(rng, typ, ploidy, ns, _GT_AMAX[typ])
+            g[0, :] = ((_GT_AMAX[typ] + 1) << 1) | 1
+        recs.append(dict(typ=typ, ploidy=ploidy, gt=g, n_sample=ns))
+        if case == "no_gt_rows":
+            recs.append(dict())
+        elif case == "two_groups":
+            typ2 = T_INT16 if typ != T_INT16 else T_INT32
+            recs.append(dict(typ=typ2, ploidy=ploidy + 1, n_sample=3,
+                             gt=_called(rng, typ2, ploidy + 1, 3)))
+    return recs
+
+
+def _three_ways(recs, monkeypatch):
+    """A span through the native kernel, the NumPy twin and the
+    record-serial scanner; returns the native columns after pinning all
+    three to each other, and checks which path counted the records."""
+    from hadoop_bam_tpu.utils import native
+    from hadoop_bam_tpu.utils.metrics import base_metrics
+
+    assert native.available(), native.build_info()["error"]
+    header = _header()
+    buf = b"".join(_raw_record(100 + i, **kw) for i, kw in enumerate(recs))
+    n_gt = sum(1 for kw in recs if kw)
+    base_metrics().reset()
+    fast = decode_bcf_columns(buf, header, GT_PAD)
+    c = base_metrics().snapshot()["counters"]
+    assert c.get("vcf.gt_native_records", 0) == n_gt
+    assert "vcf.gt_numpy_records" not in c
+    scan = scan_variant_columns(buf, header, GT_PAD)
+    with monkeypatch.context() as m:
+        m.setattr(native, "load", lambda: None)
+        base_metrics().reset()
+        slow = decode_bcf_columns(buf, header, GT_PAD)
+        c = base_metrics().snapshot()["counters"]
+    assert c.get("vcf.gt_numpy_records", 0) == n_gt
+    assert "vcf.gt_native_records" not in c
+    assert fast is not None and slow is not None
+    for k in fast:
+        np.testing.assert_array_equal(fast[k], slow[k], err_msg=k)
+        assert fast[k].dtype == slow[k].dtype, k
+    for k in STAT_KEYS:
+        np.testing.assert_array_equal(fast[k], scan[k], err_msg=k)
+    assert fast["dosage"].tobytes() == scan["dosage"].tobytes()
+    return fast
+
+
+GT_WIDTHS = pytest.mark.parametrize("typ", [T_INT8, T_INT16, T_INT32],
+                                    ids=["int8", "int16", "int32"])
+GT_CASES = ("called", "nocall", "half_missing", "eov_padded", "big_allele",
+            "no_gt_rows", "two_groups")
+
+
+@pytest.mark.parametrize("case", GT_CASES)
+@pytest.mark.parametrize("ploidy", [1, 2, 3, 8])
+@GT_WIDTHS
+def test_native_gt_dosage_equals_both_oracles(typ, ploidy, case,
+                                              monkeypatch):
+    """Width x ploidy x genotype shape: the native kernel's dosage is
+    the NumPy twin's and the record scanner's, byte for byte."""
+    rng = np.random.default_rng(1000 * typ + 10 * ploidy
+                                + GT_CASES.index(case))
+    recs = _gt_case(case, typ, ploidy, rng)
+    fast = _three_ways(recs, monkeypatch)
+    d = fast["dosage"]
+    assert (d[:, 5:] == -1).all()                   # n_sample < samples_pad
+    if case == "called":
+        assert (d[:, :5] >= 0).all()
+    elif case == "nocall":
+        assert (d[:, :3] == -1).all() and (d[:, 3:5] >= 0).all()
+    elif case == "half_missing":
+        assert (d[:, [0, 1, 3]] == -1).all()
+    elif case == "eov_padded":
+        assert (d[:, 0] == -1).all() and (d[:, 1:5] >= 0).all()
+    elif case == "big_allele":
+        assert (d[:, 0] == ploidy).all()
+    elif case == "no_gt_rows":
+        assert (d[1::2] == -1).all() and (d[0::2, :5] >= 0).all()
+    else:
+        assert (d[1::2, 3:] == -1).all() and (d[1::2, :3] >= 0).all()
+
+
+@GT_WIDTHS
+def test_native_gt_dosage_clamps_a_256_ploid_at_127(typ, monkeypatch):
+    """Ploidy 256 (the guard's edge, an extended-count descriptor), every
+    allele ALT: the count is held wider than int8 and clamped to 127; a
+    sample with 127 ALTs and one with a missing entry sit beside it."""
+    g = np.full((5, 256), (2 << 1) | 1)
+    g[1, 127:] = 2                                  # 127 ALT, 129 REF
+    g[2, 255] = _GT_MISS[typ]
+    g[3, 100:] = _GT_MISS[typ] + 1                  # 100 ALT then EOV
+    fast = _three_ways([dict(typ=typ, ploidy=256, gt=g)] * 3, monkeypatch)
+    np.testing.assert_array_equal(
+        fast["dosage"][0], [127, 127, -1, 100, 127, -1, -1, -1])
+
+
+@GT_WIDTHS
+@pytest.mark.parametrize("ploidy", [1, 2, 5])
+def test_native_gt_kernel_over_every_value_near_the_sentinels(typ, ploidy):
+    """The kernel alone against the NumPy twin on vectors longer than a
+    SIMD register, at odd offsets: every int8 value (int16 / int32: every
+    value beside the sentinels, zero and the extremes) in every ploidy
+    slot."""
+    from hadoop_bam_tpu.formats.bcf_columns import _GT_DTYPES, _gt_group_dosage
+    from hadoop_bam_tpu.utils import native
+
+    dt = _GT_DTYPES[typ]
+    info = np.iinfo(dt)
+    vals = np.unique(np.concatenate([
+        np.arange(info.min, info.min + 130), np.arange(-3, 130),
+        np.arange(info.max - 3, info.max + 1)]).clip(info.min, info.max))
+    rng = np.random.default_rng(typ * 7 + ploidy)
+    ns = 2504
+    g = rng.choice(vals, (3, ns, ploidy))
+    g[0, :vals.size, 0] = vals                      # each value, slot 0
+    g[1, :vals.size, -1] = vals                     # and the last slot
+    rows = np.array([2, 0, 4], np.int64)
+    parts, offs, at = [], [], 0
+    for r in range(3):                              # odd, unaligned offsets
+        pad = b"\x5a" * (2 * r + 1)
+        offs.append(at + len(pad))
+        parts.append(pad + g[r].astype(dt).tobytes())
+        at += len(parts[-1])
+    b = np.frombuffer(b"".join(parts), np.uint8)
+    offs = np.asarray(offs, np.int64)
+    want = np.full((5, ns + 7), -1, np.int8)
+    got = want.copy()
+    _gt_group_dosage(b, rows, offs, typ, ploidy, ns, want)
+    native.bcf_gt_dosage(b, rows, offs, typ, ploidy, ns, got)
+    assert got.tobytes() == want.tobytes()
+    assert (got[[1, 3]] == -1).all() and (got[:, ns:] == -1).all()
+
+
+def test_native_gt_wrapper_refuses_what_lies_outside_its_buffers():
+    """An offset, an extent or a row outside the span / the matrix is a
+    BCFError from the kernel's own check, and nothing is written."""
+    from hadoop_bam_tpu.utils import native
+
+    b = np.zeros(100, np.uint8)
+    rows = np.arange(2, dtype=np.int64)
+    for offs, ns, typ, ploidy, rws in (
+            ([0, 96], 5, T_INT8, 1, rows),          # 96 + 5 > 100
+            ([0, -1], 5, T_INT8, 1, rows),
+            ([0, 101], 0, T_INT8, 1, rows),
+            ([0, 1 << 62], 5, T_INT32, 2, rows),
+            ([0, 0], 5, T_INT8, 1, [0, 2]),         # row past the matrix
+            ([0, 0], 5, T_INT8, 1, [-1, 0]),
+            ([0, 0], 9, T_INT8, 1, rows),           # wider than the matrix
+            ([0, 0], 5, 5, 1, rows),                # a float is no GT type
+            ([0, 0], 5, T_INT8, -1, rows)):
+        out = np.full((2, 8), 7, np.int8)
+        with pytest.raises(BCFError):
+            native.bcf_gt_dosage(b, np.asarray(rws, np.int64),
+                                 np.asarray(offs, np.int64), typ, ploidy,
+                                 ns, out)
+        assert (out == 7).all()
+    out = np.full((2, 8), 7, np.int8)               # the edge itself fits
+    native.bcf_gt_dosage(b, rows, np.array([0, 95], np.int64), T_INT8, 1, 5,
+                         out)
+    assert (out[:, :5] == -1).all() and (out[:, 5:] == 7).all()
+    native.bcf_gt_dosage(b, rows, np.array([0, 100], np.int64), T_INT8, 0, 5,
+                         out)                       # ploidy 0: none present
+    assert (out[:, :5] == -1).all()
+
+
+# ---------------------------------------------------------------------------
 # corruption fuzz: raise, never mis-decode
 # ---------------------------------------------------------------------------
 
-def test_truncation_always_raises():
+@pytest.fixture(params=["native", "numpy"])
+def gt_path(request, monkeypatch):
+    """Both GT -> dosage paths: the native kernel, and the NumPy twin a
+    host without the library runs."""
+    from hadoop_bam_tpu.utils import native
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "load", lambda: None)
+    else:
+        assert native.available(), native.build_info()["error"]
+    return request.param
+
+
+def test_truncation_always_raises(gt_path):
     """Every cut that is not a record boundary must raise BCFError."""
     header, _, _, buf = _encode(LINES)
     bounds = set(frame_record_starts(buf).tolist()) | {len(buf)}
@@ -185,7 +421,7 @@ def test_truncation_always_raises():
             decode_bcf_columns(buf[:cut], header, 8)
 
 
-def test_corrupt_lengths_and_type_codes_raise():
+def test_corrupt_lengths_and_type_codes_raise(gt_path):
     header, codec, recs, buf = _encode(LINES)
     starts = frame_record_starts(buf)
 
@@ -209,7 +445,7 @@ def test_corrupt_lengths_and_type_codes_raise():
         decode_bcf_columns(bytes(bad), header, 8, starts=starts)
 
 
-def test_random_byte_flips_never_decode_loosely():
+def test_random_byte_flips_never_decode_loosely(gt_path):
     """Flipping one byte either still yields records framed exactly as
     claimed (decode succeeds or falls back) or raises BCFError — no
     crash, no out-of-range read."""

@@ -261,20 +261,26 @@ def test_columnar_and_record_scan_agree_on_a_2504_wide_span():
 
 
 def test_the_gt_gather_is_slabbed_not_span_wide(monkeypatch):
-    """A span's GT values are reduced a bounded slab at a time: the same
-    columns whatever the slab, down to one record."""
+    """The NumPy twin of the native GT kernel (what a host without the
+    library runs) reduces a span's GT values a bounded slab at a time:
+    the same columns whatever the slab, down to one record — and the
+    kernel's."""
     from hadoop_bam_tpu.formats import bcf_columns
     from hadoop_bam_tpu.formats.bcf import decode_header
+    from hadoop_bam_tpu.utils import native
 
     shape = SMALL["missing-haploid-unphased"]
     f = K.gen_fields(4, 0, 1, 120, shape)
     buf = K.assemble(f, shape)[0].tobytes()
     header, _ = decode_header(K.header_bytes(shape))
+    kernel = bcf_columns.decode_bcf_columns(buf, header, 24)
+    monkeypatch.setattr(native, "load", lambda: None)
     whole = bcf_columns.decode_bcf_columns(buf, header, 24)
     monkeypatch.setattr(bcf_columns, "_GT_SLAB_VALUES", 1)
     one = bcf_columns.decode_bcf_columns(buf, header, 24)
     for k in whole:
         assert np.array_equal(whole[k], one[k], equal_nan=True), k
+        assert np.array_equal(whole[k], kernel[k], equal_nan=True), k
 
 
 # -- (e) the generator is a function of the seed; the benchmark's copy -------
@@ -333,6 +339,9 @@ def test_the_scan_counts_its_records_and_bytes(tiny):
         < 0.02 * CONFIG["shape"]["mean_record_bytes"]
     assert c["vcf.decode_busy_ns"] > 0
     assert "vcf.columnar_declined_spans" not in c
+    # every record's dosage row came from the native GT kernel
+    assert c["vcf.gt_native_records"] == ref.n
+    assert "vcf.gt_numpy_records" not in c
     walls = snap["wall_timers"]
     assert 0 < walls["vcf.gt_dosage_wall"] <= walls["vcf.tokenize_wall"]
     # a group ships 2,504 dosages + chrom + pos + flags a record

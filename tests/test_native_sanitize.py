@@ -20,7 +20,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The subprocess body: build fixtures in memory and push them through every
 # native entry point (BGZF header walk, inflate, CRC, record walks,
-# packed/payload walks, deflate, rANS 4x8 + Nx16).
+# packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
+# kernel).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -127,6 +128,49 @@ try:
     raise AssertionError("truncated ITF8 did not raise")
 except ValueError:
     pass
+
+# BCF GT -> dosage kernel: every (width, ploidy) inner loop, payloads
+# ending on the buffer's last byte (an over-read is ASan's to see), the
+# refusals, and four Python threads writing disjoint rows of one matrix
+# with the interpreter lock released (TSan)
+import threading
+from hadoop_bam_tpu.formats.bcf import BCFError
+from hadoop_bam_tpu.formats.bcf_columns import _GT_DTYPES, _gt_group_dosage
+nrng = np.random.default_rng(5)
+for typ, dt in _GT_DTYPES.items():
+    info = np.iinfo(dt)
+    pool = np.array([info.min, info.min + 1, 0, 1, 2, 3, 4, 5, 9, info.max])
+    for ploidy in (0, 1, 2, 3, 7):
+        ns = 333
+        g = nrng.choice(pool, (8, ns, ploidy)).astype(dt)
+        step = g[0].nbytes + 3
+        raw_gt = bytearray()
+        for r in range(8):
+            raw_gt += b"\x00" * 3 + g[r].tobytes()      # ends on the last byte
+        bgt = np.frombuffer(bytes(raw_gt), np.uint8)
+        offs_gt = np.arange(8, dtype=np.int64) * step + 3
+        rows_gt = np.arange(8, dtype=np.int64)[::-1].copy()
+        want = np.full((8, ns + 5), -1, np.int8)
+        got = want.copy()
+        _gt_group_dosage(bgt, rows_gt, offs_gt, typ, ploidy, ns, want)
+        ts = [threading.Thread(target=native.bcf_gt_dosage, args=(
+                  bgt, rows_gt[k::4], offs_gt[k::4], typ, ploidy, ns, got))
+              for k in range(4)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+        assert got.tobytes() == want.tobytes(), (typ, ploidy)
+        if ploidy:
+            for bad_offs, bad_rows in ((offs_gt + 1, rows_gt),
+                                       (offs_gt, rows_gt + 1)):
+                try:
+                    native.bcf_gt_dosage(bgt, bad_rows, bad_offs, typ,
+                                         ploidy, ns, got)
+                    raise AssertionError("GT overrun did not raise")
+                except BCFError:
+                    pass
+            assert got.tobytes() == want.tobytes()
 
 # staging packer: the FeedPipeline's background pack thread races the
 # dispatching consumer over reused ring slots — drive it with a host
