@@ -202,3 +202,221 @@ def cohort_gwas(source, phenotype=None, mesh=None,
     for j, name in enumerate(GWAS_COLUMNS):
         out[name] = stats_all[:, j]
     return out
+
+
+# ---------------------------------------------------------------------------
+# the structure-adjusted, many-trait form over a device-resident matrix
+# (``hbam vcf-gwas``; the driver is parallel/variant_pipeline.py's)
+# ---------------------------------------------------------------------------
+#
+# Pass 1 keeps every tile group of the file in one int8 matrix on the
+# chip and accumulates GCTA's genetic relationship matrix from it (Yang
+# et al., AJHG 88:76, 2011):  A = Z^T Z / |C|,  z_js = (g_js - 2 p_j) /
+# sqrt(2 p_j (1 - p_j)) over the sites C = {SNP, no missing call, MAF >=
+# GWAS_MAF_PERCENT %}.  The GWAS_AXES leading eigenvectors of A are the
+# covariates (EIGENSTRAT, Price et al., Nat Genet 38:904, 2006):
+# X = [1, v_1..v_k], Q = qr(X).Q.  Pass 2 reads the matrix, not the file:
+# Y~ = Y - Q Q^T Y, sigma2_p = |y~_p|^2 / S, and for every site and trait
+#
+#     u = g_j . y~_p     v_j = |g_j|^2 - |Q^T g_j|^2
+#     chi2 = u^2 / (v_j sigma2_p)      (the score test above, adjusted)
+#
+# NaN where v_j <= 1e-6 |g_j|^2 (the dosage lies in the covariates' span)
+# or a call is missing.  Traits share their missingness (none), which is
+# what lets one matrix product serve them all (as PLINK 2's --glm batches
+# quantitative traits).
+
+GWAS_AXES = 4              # covariate axes beside the intercept
+GWAS_MAF_PERCENT = 1       # the GRM's site filter, as a whole percentage
+
+
+def read_traits_tsv(path: str, samples):
+    """(trait names, Y [S, P] float64 in the header's sample order) of a
+    trait file: header ``sample`` + P names, one row a sample, matched by
+    name.  A missing or non-finite value, an unknown, repeated or absent
+    sample is a ``PlanError``: equal missingness is the verb's contract."""
+    with open(path, encoding="utf-8") as fh:
+        lines = fh.read().splitlines()
+    head = lines[0].split("\t") if lines else []
+    if len(head) < 2 or head[0] != "sample":
+        raise PlanError(f"{path}: a trait file starts with a header line "
+                        f"'sample<TAB>name...'")
+    names = head[1:]
+    index = {s: i for i, s in enumerate(samples)}
+    y = np.full((len(samples), len(names)), np.nan)
+    seen = np.zeros(len(samples), bool)
+    for n, line in enumerate(lines[1:], 2):
+        if not line:
+            continue
+        parts = line.split("\t")
+        i = index.get(parts[0])
+        if i is None:
+            raise PlanError(f"{path}:{n}: sample {parts[0]!r} is not in "
+                            f"the call set")
+        if seen[i]:
+            raise PlanError(f"{path}:{n}: sample {parts[0]!r} twice")
+        if len(parts) != len(head):
+            raise PlanError(f"{path}:{n}: {len(parts) - 1} values for "
+                            f"{len(names)} traits")
+        try:
+            y[i] = np.array(parts[1:], dtype=np.float64)
+        except ValueError:
+            raise PlanError(f"{path}:{n}: a value of sample {parts[0]!r} "
+                            f"is missing or not a number") from None
+        seen[i] = True
+    if not seen.all():
+        absent = [s for s, ok in zip(samples, seen) if not ok]
+        raise PlanError(f"{path}: no row for {len(absent)} of the call "
+                        f"set's samples (first: {absent[0]!r})")
+    if not np.isfinite(y).all():
+        i, p = np.argwhere(~np.isfinite(y))[0]
+        raise PlanError(f"{path}: trait {names[p]!r} of sample "
+                        f"{samples[i]!r} is not finite")
+    return names, y
+
+
+def make_gwas_load_step(n_samples: int):
+    """Jitted pass-1 step, built once a process for a sample count: one
+    tile group -> written into the donated resident matrix at ``row0``
+    (``resident_update``), reduced to allele frequencies (``af``) and
+    added into the donated GRM accumulators (``grm``:
+    ops/gwas_pallas.py::grm_accumulate says what they hold).
+
+    ``step(resident [cap, Sp] i8, sites [2, cap] i32, acc [Sp, Sp] f32,
+    r [Sp] f32, c [] f32, n_grm [] i32, chrom, pos [1, bucket] i32, flags
+    [1, bucket] u8, dosage [1, bucket, S_pad] i8, count [1] i32, row0 []
+    i32)`` returns the first six, updated."""
+    import jax
+    import jax.numpy as jnp
+
+    from hadoop_bam_tpu.ops.gwas_pallas import (
+        GRM_BLOCK, grm_accumulate, round_up,
+    )
+    from hadoop_bam_tpu.parallel.pipeline import _STEP_CACHE
+    from hadoop_bam_tpu.parallel.variant_pipeline import FLAG_SNP
+
+    key = ("gwas_load", int(n_samples))
+    if key in _STEP_CACHE:
+        return _STEP_CACHE[key]
+
+    def load(resident, sites, acc, r, c, n_grm, chrom, pos, flags, dosage,
+             count, row0):
+        d, fl, cnt = dosage[0], flags[0], count[0]
+        bucket, s_pad = d.shape
+        kp, sp = round_up(bucket, GRM_BLOCK), resident.shape[1]
+        with jax.named_scope("resident_update"):
+            dp = jnp.pad(d, ((0, kp - bucket), (0, sp - s_pad)),
+                         constant_values=-1)
+            resident = jax.lax.dynamic_update_slice(
+                resident, dp[:bucket], (row0, 0))
+            sites = jax.lax.dynamic_update_slice(
+                sites, jnp.concatenate([chrom, pos]), (0, row0))
+        with jax.named_scope("af"):
+            gi = dp.astype(jnp.int32)
+            called = gi >= 0
+            n_called = called.sum(axis=1)
+            alt = jnp.where(called, gi, 0).sum(axis=1)
+            snp = (jnp.pad(fl, (0, kp - bucket)) & FLAG_SNP) != 0
+            # 0.01 <= alt / (2 n) <= 0.99, in integers: exact at the edge
+            in_c = ((jnp.arange(kp, dtype=jnp.int32) < cnt) & snp
+                    & (n_called == n_samples)
+                    & (100 * alt >= 2 * GWAS_MAF_PERCENT * n_called)
+                    & (100 * alt <= 2 * (100 - GWAS_MAF_PERCENT)
+                       * n_called))
+            p = alt.astype(jnp.float32) \
+                / (2 * jnp.maximum(n_called, 1)).astype(jnp.float32)
+            m = jnp.where(in_c, 2.0 * p, 0.0)
+            w = jnp.where(in_c, 1.0 / (2.0 * p * (1.0 - p)), 0.0)
+        with jax.named_scope("grm"):
+            acc = grm_accumulate(acc, dp, m, w)
+            wm = w * m
+            r = r + jnp.dot(wm, dp.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+            c = c + (wm * m).sum()
+            n_grm = n_grm + in_c.astype(jnp.int32).sum()
+        return resident, sites, acc, r, c, n_grm
+
+    step = named_step("gwas_load_step", load,
+                      donate_argnums=(0, 1, 2, 3, 4, 5))
+    _STEP_CACHE[key] = step
+    return step
+
+
+def make_gwas_assoc_step(n_samples: int, n_traits: int,
+                         with_table: bool = False):
+    """Jitted pass-2 step over the resident matrix
+    (ops/gwas_pallas.py::assoc_scan): ``step(resident, w3 [3, Sp, Np]
+    bf16, isig [Np] f32, n_sites [1] i32)`` -> the per-trait summaries and, when
+    ``with_table``, the ``[cap, P]`` chi2 table."""
+    from hadoop_bam_tpu.ops.gwas_pallas import assoc_scan
+    from hadoop_bam_tpu.parallel.pipeline import _STEP_CACHE
+
+    key = ("gwas_assoc", int(n_samples), int(n_traits), bool(with_table))
+    if key in _STEP_CACHE:
+        return _STEP_CACHE[key]
+
+    def assoc(resident, w3, isig, n_sites):
+        return assoc_scan(resident, w3, isig, n_sites, n_traits=n_traits,
+                          n_cov=1 + GWAS_AXES, n_samples=n_samples,
+                          with_table=with_table)
+
+    step = named_step("gwas_assoc_step", assoc)
+    _STEP_CACHE[key] = step
+    return step
+
+
+def grm_from_accumulators(acc, r, c: float, n_grm: int,
+                         n_samples: int) -> np.ndarray:
+    """A [S, S] float64 from what pass 1 accumulated: ``(T^T G - (r - c)
+    1^T) / |C|`` read from the upper triangle and mirrored."""
+    s = int(n_samples)
+    upper = np.triu(np.asarray(acc, np.float64)[:s, :s]
+                    - (np.asarray(r, np.float64)[:s] - float(c))[:, None])
+    return (upper + np.triu(upper, 1).T) / max(int(n_grm), 1)
+
+
+def covariates(a: np.ndarray):
+    """(eigenvalues descending, Q [S, 1 + GWAS_AXES]) of the GRM: the
+    intercept and the leading eigenvectors, orthonormalised."""
+    w, v = np.linalg.eigh(a)
+    x = np.concatenate([np.ones((a.shape[0], 1)),
+                        v[:, ::-1][:, :GWAS_AXES]], axis=1)
+    return w[::-1], np.linalg.qr(x)[0]
+
+
+def variant_gwas_file(path: str, traits: str, return_table: bool = False,
+                      mesh=None, config: HBamConfig = DEFAULT_CONFIG,
+                      geometry=None, spans=None) -> Dict[str, object]:
+    """Structure-adjusted association of every site of a cohort VCF/BCF
+    against every trait of the TSV ``traits``: one read of the file into a
+    device-resident int8 matrix, the GRM and its leading eigenvectors as
+    covariates, then the score test from the matrix (the comment above
+    has the formulas).  Returns ``n_sites``, ``n_grm_sites``,
+    ``eigenvalues`` (all, descending), ``q`` [S, 5], ``traits`` (names)
+    and per trait ``tested`` (one count: traits share their missingness),
+    ``mean_chi2``, ``max_chi2``, ``max_pos``, ``genome_wide`` (chi2 over
+    29.72, p < 5e-8); with ``return_table`` also ``pos`` [M] and ``chi2``
+    [M, P] float32 in file order.  A thin plan builder over the one
+    executor."""
+    from hadoop_bam_tpu.plan import builders
+    from hadoop_bam_tpu.plan import executor as plan_executor
+
+    plan = builders.variant_gwas_plan(path, traits, config)
+    return plan_executor.execute(plan, config=config, mesh=mesh,
+                                 geometry=geometry, spans=spans,
+                                 return_table=return_table)
+
+
+def format_gwas(res: Dict[str, object]):
+    """The lines ``hbam vcf-gwas`` prints."""
+    lines = [f"sites\t{res['n_sites']}",
+             f"grm_sites\t{res['n_grm_sites']}",
+             f"traits\t{len(res['traits'])}"]
+    lines += [f"eigenvalue_{k + 1}\t{res['eigenvalues'][k]:.9g}"
+              for k in range(GWAS_AXES)]
+    lines.append("trait\ttested\tmean_chi2\tmax_chi2\tmax_pos\tgenome_wide")
+    for t, name in enumerate(res["traits"]):
+        lines.append(f"{name}\t{res['tested']}\t{res['mean_chi2'][t]:.9g}\t"
+                     f"{res['max_chi2'][t]:.9g}\t{res['max_pos'][t]}\t"
+                     f"{res['genome_wide'][t]}")
+    return lines

@@ -638,13 +638,19 @@ def variant_stats_file(path: str, mesh: Optional[Mesh] = None,
                                  spans=spans, prefetch=prefetch)
 
 
-def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
-                        config: HBamConfig = DEFAULT_CONFIG,
-                        geometry: Optional[VariantGeometry] = None,
-                        header: Optional[VCFHeader] = None,
-                        spans=None,
-                        prefetch: int = 2) -> Dict[str, object]:
-    """The variant-stats mesh-feed implementation (executor runner)."""
+def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
+                       geometry: Optional[VariantGeometry],
+                       header: Optional[VCFHeader], spans, prefetch: int,
+                       make_dispatch) -> VCFHeader:
+    """What every whole-file variant driver runs: open the file, plan its
+    spans, decode them in the pool (retried), repack the rows into tile
+    groups and hand each group to the driver's own dispatch.
+
+    ``make_dispatch(ds, header, mesh, geometry)`` is called once the spans
+    are planned and returns ``dispatch(named, counts)``: ``named`` maps the
+    tile schema's keys to the group's borrowed ``[n_dev, bucket, ...]``
+    views, and what it returns (the device arrays made from them) is the
+    ring slot's in-flight handle.  Returns the header."""
     from hadoop_bam_tpu.api.vcf_dataset import open_vcf
     from hadoop_bam_tpu.parallel.mesh import make_mesh
 
@@ -662,11 +668,9 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
         with METRICS.span("vcf.plan_wall"):
             spans = ds.spans(
                 num_spans=variant_span_count(ds, n_dev, config))
-    step = make_variant_stats_step(mesh, geometry)
-    sharding = NamedSharding(mesh, P("data"))
+    dispatch_group = make_dispatch(ds, header, mesh, geometry)
     pool = decode_pool(config)
     window = max(1, prefetch) * decode_pool_size(config)
-    totals = _StatTotals()
     from hadoop_bam_tpu.parallel.pipeline import decode_with_retry
 
     def decode(span):
@@ -700,13 +704,262 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
     if fp is not None:
         def dispatch(arrays, counts):
             with METRICS.span("vcf.dispatch_wall"):
-                named = dict(zip(keys, arrays))
-                args = [jax.device_put(named[k], sharding)
-                        for k in ("chrom", "pos", "flags", "dosage")]
-                c = jax.device_put(counts, sharding)
-                totals.add(*step(*args, c))  # async; drained at the end
+                handles = dispatch_group(dict(zip(keys, arrays)), counts)
             METRICS.count("pipeline.records", int(counts.sum()))
-            return (*args, c)  # in-flight handles: the ring waits on them
+            return handles  # in-flight: the ring waits on them
 
         fp.feed(tuples, dispatch)
+    return header
+
+
+def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
+                        config: HBamConfig = DEFAULT_CONFIG,
+                        geometry: Optional[VariantGeometry] = None,
+                        header: Optional[VCFHeader] = None,
+                        spans=None,
+                        prefetch: int = 2) -> Dict[str, object]:
+    """The variant-stats mesh-feed implementation (executor runner)."""
+    totals = _StatTotals()
+
+    def make_dispatch(ds, header, mesh, geometry):
+        step = make_variant_stats_step(mesh, geometry)
+        sharding = NamedSharding(mesh, P("data"))
+
+        def dispatch(named, counts):
+            args = [jax.device_put(named[k], sharding)
+                    for k in ("chrom", "pos", "flags", "dosage")]
+            c = jax.device_put(counts, sharding)
+            totals.add(*step(*args, c))  # async; drained at the end
+            return (*args, c)
+
+        return dispatch
+
+    header = _scan_variant_file(path, mesh, config, geometry, header, spans,
+                                prefetch, make_dispatch)
     return _variant_stats_result(totals, header)
+
+
+# ---------------------------------------------------------------------------
+# hbam vcf-gwas: the same feed, kept — a device-resident dosage matrix
+# ---------------------------------------------------------------------------
+
+# room over the estimated site count: the estimate is the file's inflated
+# size over its first records' size, and records differ in width
+_GWAS_HEADROOM = 1.0 / 64
+_PROBE_BYTES = 256 << 10
+
+
+def _estimate_variant_sites(ds) -> int:
+    """Sites of a VCF/BCF from what a plan can observe without decoding
+    it: the inflated size of the file after its header over the mean size
+    of the first records there.  A BGZF file's inflated size is the sum
+    of its blocks' ISIZE, read off the block chain (the compressed bytes
+    are read once more for it, nothing is inflated: a call set's chunks
+    deflate 3 % apart and single blocks 30 %, so no sample of blocks is a
+    guide)."""
+    from hadoop_bam_tpu.api.dispatch import VCFContainer
+    from hadoop_bam_tpu.formats import bgzf
+    from hadoop_bam_tpu.formats.bcfio import read_bcf_header
+    from hadoop_bam_tpu.ops import inflate as inflate_ops
+    from hadoop_bam_tpu.parallel.pipeline import scoped_byte_source
+    from hadoop_bam_tpu.utils.errors import PlanError
+
+    if ds.container is VCFContainer.VCF_GZIP:
+        raise PlanError(f"{ds.path}: a plain-gzip VCF cannot be sized "
+                        f"without inflating it; recompress it with bgzip")
+    is_bcf = ds.container is VCFContainer.BCF
+    blocked = ds._is_bgzf_bcf or ds.container is VCFContainer.VCF_BGZF
+    with scoped_byte_source(ds.path) as src:
+        first = read_bcf_header(src)[1] if is_bcf else 0
+        inflated = float(src.size - (first >> 16))
+        if blocked:
+            chain = inflate_ops.block_table(
+                src.pread(first >> 16, src.size - (first >> 16)))
+            inflated = float(chain["isize"].sum() - (first & 0xFFFF))
+            reader = bgzf.BGZFReader(src)
+            reader.seek_voffset(first)
+            probe = reader.read(_PROBE_BYTES)
+        else:
+            probe = src.pread(first >> 16, _PROBE_BYTES)
+    if is_bcf:
+        sizes, p = [], 0
+        while p + 8 <= len(probe):
+            l_shared, l_indiv = np.frombuffer(probe, "<u4", 2, p)
+            sizes.append(8 + int(l_shared) + int(l_indiv))
+            p += sizes[-1]
+        mean = float(np.mean(sizes)) if sizes else 0.0
+    else:
+        lines = [ln for ln in probe.split(b"\n")[:-1]
+                 if ln and not ln.startswith(b"#")]
+        mean = float(np.mean([len(ln) + 1 for ln in lines])) if lines \
+            else 0.0
+    if mean <= 0:
+        raise PlanError(f"{ds.path}: no record found after the header")
+    return max(1, int(np.ceil(inflated / mean)))
+
+
+@dataclasses.dataclass
+class GwasResident:
+    """What pass 1 of ``hbam vcf-gwas`` leaves on the device: the int8
+    dosage matrix ``[capacity, Sp]`` (file order, pad rows and columns
+    -1), the sites' ``(chrom, pos)`` ``[2, capacity]``, and the GRM
+    accumulators (ops/gwas_pallas.py says what they hold)."""
+    resident: object = None
+    sites: object = None
+    acc: object = None
+    r: object = None
+    c: object = None
+    n_grm: object = None
+    rows: int = 0
+
+
+def _variant_gwas_load(path: str, mesh: Optional[Mesh], config: HBamConfig,
+                       geometry: Optional[VariantGeometry],
+                       header: Optional[VCFHeader], spans,
+                       prefetch: int) -> Tuple[VCFHeader, GwasResident]:
+    """Pass 1: the whole file through the variant feed, every tile group
+    written into the resident matrix and added to the GRM on its way."""
+    from hadoop_bam_tpu.cohort.gwas import make_gwas_load_step
+    from hadoop_bam_tpu.ops.gwas_pallas import (
+        ASSOC_ROWS, GRM_BLOCK, round_up,
+    )
+    from hadoop_bam_tpu.parallel.mesh import make_mesh
+    from hadoop_bam_tpu.utils.errors import PlanError
+
+    if mesh is None:
+        mesh = make_mesh(devices=jax.devices()[:1])
+    if mesh.devices.size != 1:
+        raise PlanError("vcf-gwas keeps its matrix on one device; it was "
+                        f"given a mesh of {mesh.devices.size}")
+    dev = mesh.devices.flat[0]
+    st = GwasResident()
+
+    def make_dispatch(ds, header, mesh, geometry):
+        tile = geometry.tile_records
+        want = int(np.ceil(_estimate_variant_sites(ds)
+                           * (1.0 + _GWAS_HEADROOM)))
+        capacity = round_up(round_up(want, tile), ASSOC_ROWS)
+        sp = round_up(geometry.samples_pad, GRM_BLOCK)
+        need = capacity * (sp + 8) + 4 * sp * (sp + 1)
+        stats = dev.memory_stats() or {}
+        free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+        if stats and need > free:
+            raise PlanError(
+                f"{ds.path}: the resident matrix of ~{want} sites x "
+                f"{header.n_samples} samples needs {need / 1e9:.2f} GB on "
+                f"the device and {free / 1e9:.2f} GB are free")
+        with jax.default_device(dev):
+            st.resident = jnp.full((capacity, sp), -1, jnp.int8)
+            st.sites = jnp.zeros((2, capacity), jnp.int32)
+            st.acc = jnp.zeros((sp, sp), jnp.float32)
+            st.r = jnp.zeros((sp,), jnp.float32)
+            st.c = jnp.zeros((), jnp.float32)
+            st.n_grm = jnp.zeros((), jnp.int32)
+        METRICS.count("gwas.resident_bytes", int(st.resident.nbytes))
+        step = make_gwas_load_step(header.n_samples)
+
+        def dispatch(named, counts):
+            bucket = named["dosage"].shape[1]
+            if st.rows + bucket > capacity:
+                raise PlanError(
+                    f"{ds.path}: more sites than its first records' size "
+                    f"foretold ({capacity} rows were reserved)")
+            args = [jax.device_put(named[k], dev)
+                    for k in ("chrom", "pos", "flags", "dosage")]
+            c = jax.device_put(counts, dev)
+            (st.resident, st.sites, st.acc, st.r, st.c,
+             st.n_grm) = step(st.resident, st.sites, st.acc, st.r, st.c,
+                              st.n_grm, *args, c, np.int32(st.rows))
+            st.rows += int(counts[0])
+            return (*args, c)
+
+        return dispatch
+
+    header = _scan_variant_file(path, mesh, config, geometry, header, spans,
+                                prefetch, make_dispatch)
+    return header, st
+
+
+def _variant_gwas_impl(path: str, traits: str, mesh: Optional[Mesh] = None,
+                       config: HBamConfig = DEFAULT_CONFIG,
+                       geometry: Optional[VariantGeometry] = None,
+                       header: Optional[VCFHeader] = None, spans=None,
+                       prefetch: int = 2,
+                       return_table: bool = False) -> Dict[str, object]:
+    """The ``hbam vcf-gwas`` job (executor runner; cohort/gwas.py has the
+    formulas): traits read, pass 1 over the file, the covariates, pass 2
+    over the resident matrix."""
+    from hadoop_bam_tpu.api.vcf_dataset import open_vcf
+    from hadoop_bam_tpu.cohort import gwas
+    from hadoop_bam_tpu.ops.gwas_pallas import LANE, round_up, split_bf16
+    from hadoop_bam_tpu.utils.errors import PlanError
+
+    if header is None:
+        header = open_vcf(path, config).header
+    n_s = header.n_samples
+    if n_s <= 1 + gwas.GWAS_AXES:
+        raise PlanError(f"{path}: {n_s} samples cannot carry "
+                        f"{gwas.GWAS_AXES} covariate axes")
+    with METRICS.span("gwas.pheno_wall"):
+        names, y = gwas.read_traits_tsv(traits, header.samples)
+    with METRICS.span("gwas.load_wall"):
+        header, st = _variant_gwas_load(path, mesh, config, geometry,
+                                        header, spans, prefetch)
+    n_sites = st.rows
+    with METRICS.span("gwas.grm_wall"):
+        n_grm = int(st.n_grm)
+        a = gwas.grm_from_accumulators(st.acc, st.r, float(st.c), n_grm,
+                                       n_s)
+        st.acc = st.r = None
+    if n_grm == 0:
+        raise PlanError(f"{path}: no site passes the GRM's filter (SNP, "
+                        f"no missing call, MAF >= "
+                        f"{gwas.GWAS_MAF_PERCENT} %)")
+    with METRICS.span("gwas.eigh_wall"):
+        eigenvalues, q = gwas.covariates(a)
+    with METRICS.span("gwas.pheno_wall"):
+        yt = y - q @ (q.T @ y)
+        sigma2 = (yt * yt).sum(axis=0) / n_s
+        if not (sigma2 > 0).all():
+            raise PlanError(f"{traits}: trait "
+                            f"{names[int(np.argmin(sigma2))]!r} has no "
+                            f"variance left beside the covariates")
+    n_t = len(names)
+    with METRICS.span("gwas.assoc_wall"):
+        sp = st.resident.shape[1]
+        np_ = round_up(n_t + q.shape[1], LANE)
+        w = np.zeros((sp, np_), np.float32)
+        w[:n_s, :n_t] = yt
+        w[:n_s, n_t:n_t + q.shape[1]] = q
+        isig = np.zeros(np_, np.float32)
+        isig[:n_t] = 1.0 / sigma2
+        dev = st.resident.devices().pop()
+        step = gwas.make_gwas_assoc_step(n_s, n_t, return_table)
+        # split on the host: under jit a TPU may drop the round trip
+        out = step(st.resident,
+                   jax.device_put(np.stack(split_bf16(w)), dev),
+                   jax.device_put(isig, dev),
+                   jax.device_put(np.array([n_sites], np.int32), dev))
+        sites = np.asarray(st.sites)[:, :n_sites]
+        max_site = sites[:, np.asarray(out["max_row"])]
+        tested = int(out["tested"])
+        res = {
+            "n_sites": n_sites, "n_grm_sites": n_grm,
+            "eigenvalues": eigenvalues, "q": q, "traits": names,
+            "tested": tested,
+            "mean_chi2": np.asarray(out["sum"], np.float64)
+            / max(tested, 1),
+            "max_chi2": np.asarray(out["max"]),
+            "max_chrom": max_site[0], "max_pos": max_site[1],
+            "genome_wide": np.asarray(out["hits"]),
+        }
+        if return_table:
+            res.update(chrom=sites[0], pos=sites[1],
+                       chi2=np.asarray(out["chi2"])[:n_sites])
+    METRICS.count("gwas.sites", n_sites)
+    METRICS.count("gwas.grm_sites", n_grm)
+    METRICS.count("gwas.assoc_sites", n_sites)
+    METRICS.count("gwas.assoc_sites_resident", st.rows)
+    METRICS.count("gwas.traits", n_t)
+    METRICS.count("gwas.jobs")
+    return res

@@ -82,6 +82,28 @@ def variant_stats_plan(path: str, config: Optional[HBamConfig] = None,
         sink=SinkIR.of("variant_stats"))
 
 
+def variant_gwas_plan(path: str, traits: str,
+                      config: Optional[HBamConfig] = None) -> PlanIR:
+    """VCF/BCF structure-adjusted association (``hbam vcf-gwas``): the
+    variant scan's tiles kept in a device-resident int8 matrix while the
+    GRM accumulates, its leading eigenvectors as covariates, then the
+    score test of every site against every trait of the TSV ``traits``
+    from the matrix (cohort/gwas.py has the formulas).  The numbers are
+    the verb's constants; the trait file is part of the job's identity."""
+    from hadoop_bam_tpu.cohort.gwas import GWAS_AXES, GWAS_MAF_PERCENT
+
+    fmt = "bcf" if path.lower().endswith(".bcf") else "vcf"
+    return PlanIR(
+        source=SourceIR(path, fmt),
+        spans=SpansIR.auto(),
+        ops=(op_node("variant_pack"),
+             op_node("resident_load"),
+             op_node("grm_accumulate", maf_percent=GWAS_MAF_PERCENT),
+             op_node("covariates", axes=GWAS_AXES),
+             op_node("assoc_scan", traits=os.path.abspath(traits))),
+        sink=SinkIR.of("variant_gwas"))
+
+
 def serve_tile_plan(path: str, kind: str = "bam",
                     start_voffset: int = 0,
                     end_voffset: int = 0) -> PlanIR:

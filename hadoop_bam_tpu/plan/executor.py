@@ -237,6 +237,8 @@ def _runner_for(plan: PlanIR):
         return _run_seq_stats
     if kind == "variant_stats":
         return _run_variant_stats
+    if kind == "variant_gwas":
+        return _run_variant_gwas
     if kind == "chunk_columns":
         return _run_chunk_columns
     if kind == "tensor_batches" and plan.source.role == "join":
@@ -246,7 +248,8 @@ def _runner_for(plan: PlanIR):
     raise PlanError(
         f"no executor runner for sink {kind!r} "
         f"(source role {plan.source.role!r}) — known sinks: flagstat, "
-        f"seq_stats, variant_stats, chunk_columns, join/tensor_batches, "
+        f"seq_stats, variant_stats, variant_gwas, chunk_columns, "
+        f"join/tensor_batches, "
         f"bam_file")
 
 
@@ -277,6 +280,20 @@ def _run_variant_stats(plan: PlanIR, cfg: HBamConfig, kw: Dict):
         plan.source.path, mesh=kw.get("mesh"), config=cfg,
         geometry=kw.get("geometry"), header=kw.get("header"),
         spans=kw.get("spans"), prefetch=kw.get("prefetch", 2))
+
+
+def _run_variant_gwas(plan: PlanIR, cfg: HBamConfig, kw: Dict):
+    """``hbam vcf-gwas``: the trait file rides the ``assoc_scan`` op node
+    (part of the plan's identity)."""
+    from hadoop_bam_tpu.parallel import variant_pipeline
+
+    traits = dict(next(op for op in plan.ops
+                       if op.op == "assoc_scan").params)["traits"]
+    return variant_pipeline._variant_gwas_impl(
+        plan.source.path, traits, mesh=kw.get("mesh"), config=cfg,
+        geometry=kw.get("geometry"), header=kw.get("header"),
+        spans=kw.get("spans"), prefetch=kw.get("prefetch", 2),
+        return_table=bool(kw.get("return_table", False)))
 
 
 def _run_mkdup(plan: PlanIR, cfg: HBamConfig, kw: Dict):

@@ -444,6 +444,9 @@ def cmd_explain(args) -> int:
         plan = builders.seq_stats_plan(args.path, cfg)
     elif args.op == "vcf-stats":
         plan = builders.variant_stats_plan(args.path, cfg)
+    elif args.op == "vcf-gwas":
+        plan = builders.variant_gwas_plan(
+            args.path, args.path + ".traits.tsv", cfg)
     elif args.op == "cohort":
         plan = builders.cohort_plan(args.path, cfg)
     elif args.op == "mkdup":
@@ -506,6 +509,14 @@ def cmd_vcf_stats(args) -> int:
     print(f"mean_af\t{stats['mean_af']:.6f}")
     for i, cr in enumerate(stats["sample_callrate"]):
         print(f"callrate_{i}\t{cr:.4f}")
+    return 0
+
+
+def cmd_vcf_gwas(args) -> int:
+    from hadoop_bam_tpu.cohort.gwas import format_gwas, variant_gwas_file
+
+    for line in format_gwas(variant_gwas_file(args.path, args.pheno)):
+        print(line)
     return 0
 
 
@@ -1387,6 +1398,17 @@ def build_parser() -> argparse.ArgumentParser:
     vst.add_argument("path")
     vst.set_defaults(fn=cmd_vcf_stats, uses_device=True)
 
+    vg = sub.add_parser("vcf-gwas",
+                        help="structure-adjusted association of every "
+                             "site against every trait: a device-resident "
+                             "dosage matrix, GRM eigenvectors as "
+                             "covariates, a score test from the matrix")
+    vg.add_argument("path")
+    vg.add_argument("--pheno", required=True, metavar="TRAITS.TSV",
+                    help="header 'sample' + trait names, one row a "
+                         "sample, no missing values")
+    vg.set_defaults(fn=cmd_vcf_gwas, uses_device=True)
+
     so = sub.add_parser("sort", help="sort a BAM (external spill-merge)")
     so.add_argument("input")
     so.add_argument("output")
@@ -1578,8 +1600,8 @@ def build_parser() -> argparse.ArgumentParser:
              "plane decision (which plane, and why each rejected "
              "plane failed its gate)")
     ex.add_argument("op", choices=["flagstat", "seq-stats", "vcf-stats",
-                                   "query", "cohort", "serve-tile",
-                                   "mkdup"])
+                                   "vcf-gwas", "query", "cohort",
+                                   "serve-tile", "mkdup"])
     ex.add_argument("path", help="input file (BAM/VCF/BCF) or cohort "
                                  "manifest JSON")
     ex.add_argument("--region", default=None,

@@ -18,7 +18,7 @@ from typing import Callable, Dict, Hashable
 from hadoop_bam_tpu.utils.metrics import METRICS
 
 
-def named_step(name: str, fn: Callable) -> Callable:
+def named_step(name: str, fn: Callable, donate_argnums=()) -> Callable:
     """``jax.jit(fn)`` as the program ``hbam_<name>``: the XLA module is
     then ``jit_hbam_<name>``, which the profiler's device plane carries,
     so a trace reduction finds the step after a refactor renumbers XLA's
@@ -27,12 +27,14 @@ def named_step(name: str, fn: Callable) -> Callable:
     out but not the module name.  ``fn`` is renamed in place, so pass a
     function built for this step (a ``shard_map`` result, a local def),
     never a shared one.  Counts ``steps.built.hbam_<name>``: a count that
-    grows with the jobs run is a step re-traced every job."""
+    grows with the jobs run is a step re-traced every job.
+    ``donate_argnums`` names the arguments the step updates in place
+    (device-resident state a driver threads through its dispatches)."""
     import jax
 
     fn.__name__ = fn.__qualname__ = f"hbam_{name}"
     METRICS.count(f"steps.built.{fn.__name__}")
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=donate_argnums)
 
 
 class BoundedStepCache:

@@ -447,6 +447,91 @@ def test_gwas_matches_numpy_reference(tmp_path):
                                    err_msg=col)
 
 
+# -- the structure-adjusted, many-trait form (hbam vcf-gwas): its host half;
+#    the job itself is tests/test_kgp3_gwas.py's ------------------------------
+
+def _grm_inputs(seed, n_sites, n_samples):
+    rng = np.random.default_rng(seed)
+    p = rng.uniform(0.05, 0.95, n_sites)
+    g = rng.binomial(2, p[:, None], (n_sites, n_samples)).astype(np.int8)
+    g[:, 0] = 0
+    g[:, 1] = 2                         # no monomorphic site
+    return g
+
+
+@pytest.mark.parametrize("n_sites,n_samples,pad", [
+    (40, 12, 4), (300, 31, 1), (64, 50, 14)])
+def test_grm_from_accumulators_is_gctas_a(n_sites, n_samples, pad):
+    """What pass 1 accumulates — T^T G on and above the diagonal, the
+    vector r, the scalar c — mirrors back into A = Z^T Z / |C|."""
+    from hadoop_bam_tpu.cohort.gwas import grm_from_accumulators
+
+    g = _grm_inputs(n_sites, n_sites, n_samples).astype(np.float64)
+    p = g.sum(axis=1) / (2 * n_samples)
+    z = (g - 2 * p[:, None]) / np.sqrt(2 * p * (1 - p))[:, None]
+    want = z.T @ z / n_sites
+    m, w = 2 * p, 1 / (2 * p * (1 - p))
+    sp = n_samples + pad
+    gp = np.full((n_sites, sp), -1.0)
+    gp[:, :n_samples] = g
+    t = (gp - m[:, None]) * w[:, None]
+    acc = np.triu(t.T @ gp) + np.tril(np.full((sp, sp), 7.0), -1)
+    r, c = (w * m) @ gp, float((w * m * m).sum())
+    got = grm_from_accumulators(acc.astype(np.float32),
+                                r.astype(np.float32), c, n_sites,
+                                n_samples)
+    assert got.shape == (n_samples, n_samples)
+    assert np.array_equal(got, got.T)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_covariates_are_the_intercept_and_the_leading_axes(seed):
+    from hadoop_bam_tpu.cohort.gwas import GWAS_AXES, covariates
+
+    rng = np.random.default_rng(seed)
+    s = 60
+    pop = rng.integers(0, 5, s)
+    x = np.eye(5)[pop] * 3 + rng.standard_normal((s, 5)) * 0.1
+    x -= x.mean(axis=0)
+    a = x @ x.T / 5 + np.eye(s) * 0.01
+    w, q = covariates(a)
+    assert q.shape == (s, 1 + GWAS_AXES) and (np.diff(w) <= 0).all()
+    np.testing.assert_allclose(q.T @ q, np.eye(1 + GWAS_AXES), atol=1e-12)
+    # the intercept and the top eigenvectors lie in Q's span
+    wv, v = np.linalg.eigh(a)
+    for col in [np.ones(s) / np.sqrt(s)] + [v[:, -k]
+                                            for k in range(1, GWAS_AXES + 1)]:
+        np.testing.assert_allclose(q @ (q.T @ col), col, atol=1e-9)
+    np.testing.assert_allclose(w[:GWAS_AXES], wv[::-1][:GWAS_AXES])
+
+
+@pytest.mark.parametrize("body,message", [
+    ("sample\tA\tB\ns1\t1\t2\ns2\t3\t4\n", None),
+    ("sample\tA\tB\ns2\t3\t4\n\ns1\t1\t2\n", None),
+    ("sample\tA\tB\ns1\t1\t\ns2\t3\t4\n", "missing or not a number"),
+    ("sample\tA\tB\ns1\t1\tnan\ns2\t3\t4\n", "not finite"),
+    ("sample\tA\tB\ns1\t1\t2\ns3\t3\t4\n", "not in the call set"),
+    ("sample\tA\tB\ns1\t1\t2\n", "no row for 1"),
+    ("sample\tA\tB\ns1\t1\t2\ns1\t3\t4\n", "twice"),
+    ("sample\tA\tB\ns1\t1\ns2\t3\t4\n", "1 values for 2"),
+    ("id\tA\tB\ns1\t1\t2\ns2\t3\t4\n", "header line"),
+    ("", "header line"),
+])
+def test_read_traits_tsv(tmp_path, body, message):
+    from hadoop_bam_tpu.cohort.gwas import read_traits_tsv
+
+    path = tmp_path / "traits.tsv"
+    path.write_text(body)
+    if message is None:
+        names, y = read_traits_tsv(str(path), ["s1", "s2"])
+        assert names == ["A", "B"]
+        assert np.array_equal(y, [[1.0, 2.0], [3.0, 4.0]])
+    else:
+        with pytest.raises(PlanError, match=message):
+            read_traits_tsv(str(path), ["s1", "s2"])
+
+
 def test_gwas_without_phenotype_and_bad_phenotype(tmp_path):
     p = _write_sample(str(tmp_path / "p.vcf"), "p", [
         "chr20\t100\t.\tA\tG\t30\tPASS\t.\tGT\t0/1"])

@@ -254,7 +254,9 @@ def _step_table():
     import jax
     import jax.numpy as jnp
 
-    from hadoop_bam_tpu.cohort.gwas import make_cohort_gwas_step
+    from hadoop_bam_tpu.cohort.gwas import (
+        make_cohort_gwas_step, make_gwas_assoc_step, make_gwas_load_step,
+    )
     from hadoop_bam_tpu.cohort.serving import make_cohort_slice_step
     from hadoop_bam_tpu.parallel import mesh_sort
     from hadoop_bam_tpu.parallel import pipeline as pl
@@ -332,6 +334,19 @@ def _step_table():
             make_cohort_slice_step,
             lambda n: [S((n, R), i32), S((n, R), i32), S((n, R, 8), i8),
                        S((n,), i32), S((3,), i32)], True),
+        # the two programs of `hbam vcf-gwas` hold their matrix on one
+        # device: no mesh, no leading device axis but the feed's 1
+        "gwas_load_step": (
+            lambda m: make_gwas_load_step(8),
+            lambda n: [S((1024, 512), i8), S((2, 1024), i32),
+                       S((512, 512), f32), S((512,), f32), S((), f32),
+                       S((), i32), S((1, R), i32), S((1, R), i32),
+                       S((1, R), u8), S((1, R, 8), i8), S((1,), i32),
+                       S((), i32)], True),
+        "gwas_assoc_step": (
+            lambda m: make_gwas_assoc_step(8, 3),
+            lambda n: [S((1024, 512), i8), S((3, 512, 128), jnp.bfloat16),
+                       S((128,), f32), S((1,), i32)], True),
         "totals_add": (
             lambda m: pl._ADD,
             lambda n: [S((16,), i32), S((16,), i32)], True),
@@ -343,13 +358,13 @@ STEP_NAMES = [
     "read_stats_step", "coverage_step", "tile_filter_step", "sort_step",
     "bytes_sort_step", "fused_sort_markdup_step", "markdup_exchange_step",
     "variant_step", "query_filter_step", "gwas_step", "cohort_slice_step",
-    "totals_add",
+    "gwas_load_step", "gwas_assoc_step", "totals_add",
 ]
 
 
 def test_the_step_table_names_every_builder_once():
     assert sorted(_step_table()) == sorted(STEP_NAMES)
-    assert len(set(STEP_NAMES)) == len(STEP_NAMES) == 16
+    assert len(set(STEP_NAMES)) == len(STEP_NAMES) == 18
 
 
 @pytest.mark.parametrize("name", STEP_NAMES)

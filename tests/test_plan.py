@@ -83,6 +83,7 @@ _PARENT_DIGESTS = {
     "query_region": "baad55ad16475495a68f77c0",
     "serve_tile": "5e0a3d81d12536fb73c91066",
 }
+_GWAS_DIGEST = "84825dee93d292ee5b065ae0"
 
 
 @pytest.mark.parametrize("backend", ["auto", "native", "zlib"])
@@ -105,6 +106,17 @@ def test_plan_digests_unchanged(backend):
             bam_path, "bam", 65536, 1 << 24),
     }
     assert {k: p.digest() for k, p in got.items()} == _PARENT_DIGESTS
+    # the plan PR 32 added: pinned from its first commit on, and moved by
+    # the trait file (part of the job's identity)
+    gwas = builders.variant_gwas_plan("/data/kgp3.bcf", "/data/traits.tsv",
+                                      cfg)
+    assert gwas.digest() == _GWAS_DIGEST
+    assert gwas.sink.kind == "variant_gwas"
+    assert [o.op for o in gwas.ops] == [
+        "variant_pack", "resident_load", "grm_accumulate", "covariates",
+        "assoc_scan"]
+    assert builders.variant_gwas_plan(
+        "/data/kgp3.bcf", "/data/other.tsv", cfg).digest() != _GWAS_DIGEST
 
 
 def test_pinned_spans_and_param_normalization():
@@ -506,3 +518,17 @@ def test_explain_cli_cohort(tmp_path, capsys):
     assert doc["plan"]["sink"]["kind"] == "tensor_batches"
     assert doc["plan"]["ops"][0]["op"] == "kway_join"
     assert doc["plan"]["ops"][0]["params"]["samples"] == 1
+
+
+def test_explain_cli_vcf_gwas(capsys):
+    from hadoop_bam_tpu.tools.cli import main
+    assert main(["explain", "vcf-gwas", "/data/kgp3.bcf", "--json"]) == 0
+    doc = json.loads(capsys.readouterr().out)
+    assert doc["plan"]["sink"]["kind"] == "variant_gwas"
+    assert doc["plan"]["source"]["fmt"] == "bcf"
+    ops = {o["op"]: o.get("params", {}) for o in doc["plan"]["ops"]}
+    assert ops["covariates"] == {"axes": 4}
+    assert ops["grm_accumulate"] == {"maf_percent": 1}
+    assert ops["assoc_scan"]["traits"] == "/data/kgp3.bcf.traits.tsv"
+    assert main(["explain", "vcf-gwas", "/data/kgp3.bcf"]) == 0
+    assert "sink    variant_gwas" in capsys.readouterr().out
