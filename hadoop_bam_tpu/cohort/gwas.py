@@ -228,6 +228,12 @@ def cohort_gwas(source, phenotype=None, mesh=None,
 
 GWAS_AXES = 4              # covariate axes beside the intercept
 GWAS_MAF_PERCENT = 1       # the GRM's site filter, as a whole percentage
+# covariates(): the subspace iteration's block, the residual it stops on
+# (relative to the leading eigenvalue) and the fewest block products worth
+# starting it for (``_leading_eigenpairs`` says where each comes from)
+EIGH_BLOCK = 16
+EIGH_RESIDUAL = 1e-13
+EIGH_MIN_PRODUCTS = 12
 
 
 def read_traits_tsv(path: str, samples):
@@ -375,13 +381,75 @@ def grm_from_accumulators(acc, r, c: float, n_grm: int,
     return (upper + np.triu(upper, 1).T) / max(int(n_grm), 1)
 
 
+def _leading_eigenpairs(a: np.ndarray):
+    """``(theta [GWAS_AXES] descending, V [S, GWAS_AXES], block products
+    spent)``: the leading eigenpairs of the symmetric float64 ``a`` by
+    block subspace iteration with a Rayleigh-Ritz step; ``theta`` and
+    ``V`` are None where this method is not the cheaper one for this
+    matrix.
+
+    ``X`` is ``[S, EIGH_BLOCK]``, orthonormal, from a fixed-seed generator
+    (two jobs on one file return the same bits).  A round is one product
+    ``Y = A X``, from which come the Ritz pairs of ``H = X^T Y``, their
+    residuals ``Y z_i - theta_i X z_i`` and the next block ``qr(Y)``; it
+    stops when ``max_i |A v_i - theta_i v_i|_2 <= EIGH_RESIDUAL theta_1``
+    over the leading GWAS_AXES pairs, never on a count.  1e-13 is 10-30
+    times the floor float64 leaves such a residual at (3e-15 to 1e-14 at
+    S = 2,504) and puts the span within ``1e-13 theta_1 / (theta_4 -
+    theta_5)`` of the full solve's: 1.5e-13 on a kgp3 cohort, whose
+    readings against the reference (1e-8: the float32 accumulation of A)
+    it cannot move.
+
+    A round contracts the error by ``lambda_(EIGH_BLOCK + 1) / lambda_4``:
+    0.022 on a kgp3 chromosome (10 products, the last residual 5e-15),
+    0.21 on a 4,096-site file (20-21 products).  On that chromosome's A
+    every block from 4 to 32 took 10 or 11 products, 10-23 ms on the chip
+    host up to 16 columns and 60-72 ms from 24 (PERF.md, PR 33); 16 is the
+    widest under that step, and the wider block contracts faster where
+    the tail decays.  The products are capped by the shape at ``S // (2
+    EIGH_BLOCK)``, which costs a fifth to two fifths of LAPACK's full
+    ``eigh`` of the same matrix (two hosts, S = 128 to 2,504; 78 products
+    at 2,504).  Past the cap the gap after the fourth eigenvalue is too
+    small for this method (a ratio over 0.68) and the caller pays the full
+    solve; where the cap is under EIGH_MIN_PRODUCTS (S < 384: not even the
+    easiest spectrum would pass it, and the full solve is under 20 ms)
+    nothing is tried."""
+    s = a.shape[0]
+    cap = s // (2 * EIGH_BLOCK)
+    if cap < EIGH_MIN_PRODUCTS:
+        return None, None, 0
+    x = np.linalg.qr(np.random.default_rng(0)
+                     .standard_normal((s, EIGH_BLOCK)))[0]
+    for products in range(1, cap + 1):
+        y = a @ x
+        h = x.T @ y
+        theta, z = np.linalg.eigh((h + h.T) / 2)
+        theta, z = theta[::-1][:GWAS_AXES], z[:, ::-1][:, :GWAS_AXES]
+        v = x @ z
+        r = y @ z - v * theta
+        if np.linalg.norm(r, axis=0).max() <= EIGH_RESIDUAL * abs(theta[0]):
+            return theta, v, products
+        x = np.linalg.qr(y)[0]
+    return None, None, cap
+
+
 def covariates(a: np.ndarray):
-    """(eigenvalues descending, Q [S, 1 + GWAS_AXES]) of the GRM: the
-    intercept and the leading eigenvectors, orthonormalised."""
-    w, v = np.linalg.eigh(a)
-    x = np.concatenate([np.ones((a.shape[0], 1)),
-                        v[:, ::-1][:, :GWAS_AXES]], axis=1)
-    return w[::-1], np.linalg.qr(x)[0]
+    """(the GWAS_AXES leading eigenvalues descending, Q [S, 1 +
+    GWAS_AXES]) of the GRM: the intercept and the leading eigenvectors,
+    orthonormalised.  Only those pairs are computed where the iteration
+    converges (``_leading_eigenpairs``); a small cohort, or one whose
+    fourth axis does not stand clear of the rest, gets them from LAPACK's
+    full float64 ``eigh`` of the same matrix."""
+    w, v, products = _leading_eigenpairs(a)
+    METRICS.count("gwas.eigh_products", products)
+    if w is None:
+        w, v = np.linalg.eigh(a)
+        w, v = w[::-1][:GWAS_AXES], v[:, ::-1][:, :GWAS_AXES]
+        METRICS.count("gwas.eigh_full_jobs")
+    else:
+        METRICS.count("gwas.eigh_topk_jobs")
+    x = np.concatenate([np.ones((a.shape[0], 1)), v], axis=1)
+    return w, np.linalg.qr(x)[0]
 
 
 def variant_gwas_file(path: str, traits: str, return_table: bool = False,
@@ -392,12 +460,12 @@ def variant_gwas_file(path: str, traits: str, return_table: bool = False,
     device-resident int8 matrix, the GRM and its leading eigenvectors as
     covariates, then the score test from the matrix (the comment above
     has the formulas).  Returns ``n_sites``, ``n_grm_sites``,
-    ``eigenvalues`` (all, descending), ``q`` [S, 5], ``traits`` (names)
-    and per trait ``tested`` (one count: traits share their missingness),
-    ``mean_chi2``, ``max_chi2``, ``max_pos``, ``genome_wide`` (chi2 over
-    29.72, p < 5e-8); with ``return_table`` also ``pos`` [M] and ``chi2``
-    [M, P] float32 in file order.  A thin plan builder over the one
-    executor."""
+    ``eigenvalues`` (the GWAS_AXES leading, descending), ``q`` [S, 5],
+    ``traits`` (names) and per trait ``tested`` (one count: traits share
+    their missingness), ``mean_chi2``, ``max_chi2``, ``max_pos``,
+    ``genome_wide`` (chi2 over 29.72, p < 5e-8); with ``return_table``
+    also ``pos`` [M] and ``chi2`` [M, P] float32 in file order.  A thin
+    plan builder over the one executor."""
     from hadoop_bam_tpu.plan import builders
     from hadoop_bam_tpu.plan import executor as plan_executor
 

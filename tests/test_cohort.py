@@ -506,6 +506,89 @@ def test_covariates_are_the_intercept_and_the_leading_axes(seed):
     np.testing.assert_allclose(w[:GWAS_AXES], wv[::-1][:GWAS_AXES])
 
 
+def _grm_like(s, lead, seed):
+    """A symmetric [s, s] float64 shaped like a cohort's GRM: five unequal
+    groups make four axes with the eigenvalues ``lead``, over a Wishart
+    tail topping at 1.9; rounded through float32, as the accumulators
+    are."""
+    rng = np.random.default_rng(seed)
+    pop = np.searchsorted(np.cumsum([0.26, 0.14, 0.20, 0.19]),
+                          np.arange(s) / s, side="right")
+    u = np.eye(5)[pop][:, :4] + 0.01 * rng.standard_normal((s, 4))
+    u = np.linalg.qr(u - u.mean(axis=0))[0]
+    z = rng.standard_normal((s, s // 2))
+    tail = z @ z.T
+    a = (u * np.asarray(lead, np.float64)) @ u.T \
+        + tail * (1.9 / np.linalg.eigvalsh(tail)[-1])
+    a = a.astype(np.float32).astype(np.float64)
+    return (a + a.T) / 2
+
+
+def _with_spectrum(s, spectrum, seed):
+    """A symmetric [s, s] float64 with exactly this spectrum, in a seeded
+    orthogonal basis."""
+    v = np.linalg.qr(np.random.default_rng(seed)
+                     .standard_normal((s, s)))[0]
+    a = (v * np.asarray(spectrum, np.float64)) @ v.T
+    return (a + a.T) / 2
+
+
+SOLVER_CASES = {
+    # name: (matrix, the path it takes)
+    "a GRM-shaped matrix": (
+        lambda: _grm_like(640, (60, 45, 30, 20), 11), "topk"),
+    "the cell's near-degenerate pair": (
+        lambda: _grm_like(512, (103, 89, 88, 72), 12), "topk"),
+    "an exactly repeated leading eigenvalue": (
+        lambda: _with_spectrum(448, [100, 100, 80, 60]
+                               + list(np.linspace(2, 0.1, 444)), 13),
+        "topk"),
+    "no gap after the fourth": (
+        lambda: _with_spectrum(416, np.linspace(2, 1, 416), 14), "full"),
+    "a small cohort": (
+        lambda: _grm_like(60, (60, 45, 30, 20), 15), "full"),
+}
+
+
+@pytest.mark.parametrize("case", SOLVER_CASES)
+def test_covariates_solver_equals_the_full_solve(case):
+    """Every case against ``np.linalg.eigh`` of the same matrix; which
+    path delivered is what the counters say; two calls give the same
+    bits."""
+    from hadoop_bam_tpu.cohort.gwas import (
+        EIGH_BLOCK, EIGH_MIN_PRODUCTS, GWAS_AXES, covariates,
+    )
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
+    build, path = SOLVER_CASES[case]
+    a = build()
+    s = a.shape[0]
+    with MetricsContext() as m:
+        w, q = covariates(a)
+    w2, q2 = covariates(a)
+    assert np.array_equal(q, q2) and np.array_equal(w, w2)
+    assert w.shape == (GWAS_AXES,) and q.shape == (s, 1 + GWAS_AXES)
+    wf, vf = np.linalg.eigh(a)
+    np.testing.assert_allclose(w, wf[::-1][:GWAS_AXES], rtol=1e-12)
+    assert np.abs(q.T @ q - np.eye(1 + GWAS_AXES)).max() < 1e-12
+    qf = np.linalg.qr(np.concatenate(
+        [np.ones((s, 1)), vf[:, ::-1][:, :GWAS_AXES]], axis=1))[0]
+    assert np.abs(q @ q.T - qf @ qf.T).max() < 1e-12
+    cap = s // (2 * EIGH_BLOCK)
+    products = m.get("gwas.eigh_products")
+    if path == "topk":
+        assert (m.get("gwas.eigh_topk_jobs"),
+                m.get("gwas.eigh_full_jobs")) == (1, 0)
+        assert EIGH_MIN_PRODUCTS <= cap and 0 < products < cap
+    else:
+        assert (m.get("gwas.eigh_topk_jobs"),
+                m.get("gwas.eigh_full_jobs")) == (0, 1)
+        # the shape rule tries nothing; the fallback paid the cap once
+        assert products == (cap if cap >= EIGH_MIN_PRODUCTS else 0)
+        assert np.array_equal(q, qf) and np.array_equal(
+            w, wf[::-1][:GWAS_AXES])
+
+
 @pytest.mark.parametrize("body,message", [
     ("sample\tA\tB\ns1\t1\t2\ns2\t3\t4\n", None),
     ("sample\tA\tB\ns2\t3\t4\n\ns1\t1\t2\n", None),

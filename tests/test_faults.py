@@ -23,7 +23,7 @@ from hadoop_bam_tpu.utils.errors import (
     CircuitBreakerError, CorruptDataError, PlanError, TransientIOError,
     classify_error,
 )
-from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.utils.metrics import METRICS, MetricsContext
 from hadoop_bam_tpu.utils.resilient import (
     FaultInjectingByteSource, FaultSpec, QuarantineManifest, RetryPolicy,
     RetryingByteSource, chaos_on,
@@ -278,15 +278,18 @@ def test_transient_retry_uses_injected_clock(bam):
 
     cfg = dataclasses.replace(DEFAULT_CONFIG, span_retries=3)
     q = QuarantineManifest(total_spans=1)
-    METRICS.reset()
-    rows, _ = decode_with_retry(inner, spans[0], cfg, quarantine=q,
-                                policy=policy)
+    # counted in a context of the test's own: the aborted run of the test
+    # before may still have a corrupt span in flight on the pool, which
+    # lands in the process's metrics whenever it finishes
+    with MetricsContext() as m:
+        rows, _ = decode_with_retry(inner, spans[0], cfg, quarantine=q,
+                                    policy=policy)
     assert rows.shape[0] == len(records)
     assert clock.sleeps == [0.25, 0.5]     # exponential, no real sleeps
     assert dict(src.injected) == {"transient": 2}
     assert len(q) == 0
-    assert METRICS.get("pipeline.transient_retries") == 2
-    assert METRICS.get("pipeline.bad_spans") == 0
+    assert m.get("pipeline.transient_retries") == 2
+    assert m.get("pipeline.bad_spans") == 0
 
 
 def test_corrupt_fails_fast_without_retries():
@@ -516,7 +519,7 @@ def test_serde_variant_round_trip():
 
 def test_metrics_counters_tick(bam):
     path, header, records = bam
-    from hadoop_bam_tpu.utils.metrics import METRICS
+    from hadoop_bam_tpu.utils.metrics import METRICS, MetricsContext
     METRICS.reset()
     for s in _spans(path, header):
         decode_span_prefix_host(path, s)

@@ -141,9 +141,18 @@ def test_tiny_job_equals_the_reference(tiny):
 
 
 def test_tiny_file_through_the_verb_equals_the_reference(tiny):
+    from hadoop_bam_tpu.cohort.gwas import EIGH_BLOCK
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
     bcf, tsv, ref, _res = tiny
-    rc, out, _err = run_cli(["vcf-gwas", bcf, "--pheno", tsv])
+    with MetricsContext() as m:
+        rc, out, _err = run_cli(["vcf-gwas", bcf, "--pheno", tsv])
     assert rc == 0 and ref.wrong(out, TOL) is None, out[:300]
+    # 2,504 samples: the four pairs came from the subspace iteration,
+    # inside its cap
+    assert (m.get("gwas.eigh_topk_jobs"), m.get("gwas.eigh_full_jobs")) \
+        == (1, 0)
+    assert 0 < m.get("gwas.eigh_products") < 2504 // (2 * EIGH_BLOCK)
     lines = out.strip().splitlines()
     assert lines[0] == "sites\t4096" and len(lines) == 3 + R.AXES + 1 + 8
     # the comparison refuses what it should
@@ -261,6 +270,21 @@ def test_a_text_vcf_is_loaded_like_the_bcf(small, tmp_path):
         variant_gwas_file(vcf + ".gz", tsv)
 
 
+def test_a_small_cohort_gets_its_covariates_from_the_full_solve(small):
+    """40 samples: LAPACK's ``eigh`` is cheaper than any block product;
+    the shape decides, and the counters say so."""
+    from hadoop_bam_tpu.cohort.gwas import variant_gwas_file
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
+    bcf, tsv, ref = small
+    with MetricsContext() as m:
+        res = variant_gwas_file(bcf, tsv, return_table=True)
+    assert (m.get("gwas.eigh_topk_jobs"), m.get("gwas.eigh_full_jobs"),
+            m.get("gwas.eigh_products")) == (0, 1, 0)
+    assert res["eigenvalues"].shape == (R.AXES,)
+    assert ref.outside(readings(ref, res), SMALL_TOL) == []
+
+
 # -- (c) the trait file --------------------------------------------------------
 
 def _edit(tsv, tmp_path, fn):
@@ -338,6 +362,7 @@ def test_two_jobs_count_their_work_and_build_their_steps_once(tmp_path):
     assert c["gwas.assoc_sites_resident"] == c["gwas.assoc_sites"] \
         == 2 * ref.n
     assert c["gwas.grm_sites"] == 2 * ref.n_grm and c["gwas.traits"] == 4
+    assert c["gwas.eigh_full_jobs"] == 2 and "gwas.eigh_topk_jobs" not in c
     # the file crosses once a job: pass 2 decodes nothing
     assert c["vcf.inflated_bytes"] == 2 * ref.scan.record_bytes
     assert c["steps.built.hbam_gwas_load_step"] == 1
