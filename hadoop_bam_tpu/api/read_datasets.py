@@ -6,7 +6,8 @@ padded-array bridge that feeds device pipelines the same way BamBatch does.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterator, List, Optional, Tuple
+import threading
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -16,14 +17,61 @@ from hadoop_bam_tpu.formats.fastq import SequencedFragment, parse_fastq
 from hadoop_bam_tpu.formats.qseq import parse_qseq
 from hadoop_bam_tpu.split.planners import plan_text_spans, read_text_span
 from hadoop_bam_tpu.split.read_planners import (
-    plan_fasta_spans, read_fasta_span, read_fastq_span,
+    GZIP_MAGIC, iter_gzip_text_chunks, iter_on_thread, plan_fasta_spans,
+    read_fasta_span, read_fastq_span,
 )
 from hadoop_bam_tpu.split.spans import FileByteSpan
+from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.seekable import scoped_byte_source
+
+
+class TextChunk:
+    """One record-aligned piece of a span's text, as a whole-file driver
+    tokenises it: a plain-text span is one chunk whose ``text()`` reads
+    the span (re-readable, so it may be retried); a compressed span is a
+    stream of chunks that carry their inflated text (``streamed``: read
+    once, in order, never retried).  ``done()`` says the chunk's text is
+    no longer needed — what the stream's count of text alive goes by."""
+
+    __slots__ = ("span", "streamed", "_text", "_read", "_alive")
+
+    def __init__(self, span: FileByteSpan, *,
+                 read: Optional[Callable[[], bytes]] = None,
+                 text: Optional[bytes] = None,
+                 alive: "Optional[_TextAlive]" = None):
+        self.span = span
+        self.streamed = read is None
+        self._text, self._read, self._alive = text, read, alive
+
+    def text(self) -> bytes:
+        return self._text if self.streamed else self._read()
+
+    def done(self) -> None:
+        text, self._text = self._text, None
+        if text is not None and self._alive is not None:
+            self._alive.add(-len(text))
+
+
+class _TextAlive:
+    """Bytes of a stream's inflated text alive between the inflater and
+    the end of their tokenise, and their high-water mark."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._now = 0
+        self.peak = 0
+
+    def add(self, n: int) -> None:
+        with self._lock:
+            self._now += n
+            self.peak = max(self.peak, self._now)
 
 
 class _SpannedDataset:
     """Shared span bookkeeping + checkpoint/resume."""
+
+    fmt = "read"                # the dataset's prefix of spans and counters
+    lines_per_record = 1        # how a stream of its text is cut
 
     def __init__(self, path: str, config: HBamConfig):
         self.path = path
@@ -32,8 +80,12 @@ class _SpannedDataset:
         self._plan_num_spans: Optional[int] = None
         self._next_span = 0
 
-    def read_span(self, span: FileByteSpan) -> List:
+    def parse_text(self, text: bytes) -> List:
+        """The records of a record-aligned piece of text."""
         raise NotImplementedError
+
+    def read_span(self, span: FileByteSpan) -> List:
+        return self.parse_text(self.read_span_text(span))
 
     def _iter_spans(self, num_spans: Optional[int]) -> Iterator:
         """Span-granular resumable iteration (state = spans delivered).
@@ -47,33 +99,70 @@ class _SpannedDataset:
             self._next_span += 1
             yield from recs
 
-    def _is_compressed(self) -> bool:
-        """gzip/BGZF input?  Compressed text reads as ONE span over the
-        inflated stream — the reference's behavior for non-splittable
-        Hadoop codecs."""
+    def is_compressed(self) -> bool:
+        """gzip/BGZF input?  Compressed text is ONE span in the plan —
+        the reference's behavior for non-splittable Hadoop codecs, and
+        the unit a journal records — and a stream of record-aligned
+        chunks at read time (``iter_span_chunks``)."""
         cached = getattr(self, "_compressed", None)
         if cached is None:
             with scoped_byte_source(self.path) as src:
-                cached = src.pread(0, 2) == b"\x1f\x8b"
+                cached = src.pread(0, 2) == GZIP_MAGIC
             self._compressed = cached
         return cached
 
     def _plan_spans(self, num_spans: Optional[int]) -> List[FileByteSpan]:
-        if self._is_compressed():
+        if self.is_compressed():
             with scoped_byte_source(self.path) as src:
                 return [FileByteSpan(self.path, 0, src.size)]
         return plan_text_spans(self.path, num_spans=num_spans,
                                span_bytes=None if num_spans
                                else self.config.split_size)
 
-    def _span_text(self, span: FileByteSpan, reader) -> bytes:
-        """Span text via ``reader(path, span)``, decompressing the whole
-        file for the single compressed-input span."""
-        if span.start == 0 and self._is_compressed():
-            import gzip
-            with open(self.path, "rb") as f:
-                return gzip.decompress(f.read())
-        return reader(self.path, span)
+    def _read_plain_span(self, span: FileByteSpan) -> bytes:
+        """Record-aligned text of a plain-text span."""
+        raise NotImplementedError
+
+    def _streamed(self, span: FileByteSpan) -> bool:
+        return span.start == 0 and self.is_compressed()
+
+    def _stream_text(self, grain: int) -> Iterator[bytes]:
+        return iter_gzip_text_chunks(self.path, grain,
+                                     self.lines_per_record, fmt=self.fmt)
+
+    def read_span_text(self, span: FileByteSpan) -> bytes:
+        """Raw record-aligned text of a span (the whole inflated file for
+        a compressed input's one span) — what the object parse reads."""
+        if self._streamed(span):
+            return b"".join(self._stream_text(self.config.split_size))
+        return self._read_plain_span(span)
+
+    def iter_span_chunks(self, span: FileByteSpan, grain: int
+                         ) -> Iterator[TextChunk]:
+        """The span's text as the ``TextChunk``s a whole-file driver
+        tokenises.  A plain-text span is one chunk, read when its
+        ``text()`` is asked for (on the driver's pool, under its retry).
+        A compressed span is a stream: one thread a file,
+        ``hbam-inflate-stream``, inflates it into record-aligned chunks
+        of at most ``grain`` bytes, one chunk ahead of the consumer; an
+        error in it (a corrupt or truncated member) is raised here.  When
+        the stream ends, its high-water mark of text alive is added to
+        ``<fmt>.stream_peak_text_bytes`` (a chunk's text is alive until
+        its ``done()``)."""
+        if not self._streamed(span):
+            yield TextChunk(span, read=lambda: self._read_plain_span(span))
+            return
+        alive = _TextAlive()
+
+        def chunks() -> Iterator[TextChunk]:
+            for text in self._stream_text(grain):
+                alive.add(len(text))
+                yield TextChunk(span, text=text, alive=alive)
+
+        try:
+            yield from iter_on_thread(chunks, "hbam-inflate-stream")
+        finally:
+            METRICS.count(f"{self.fmt}.stream_peak_text_bytes", alive.peak)
 
     def spans(self, num_spans: Optional[int] = None) -> List[FileByteSpan]:
         if self._plan is not None and num_spans is not None \
@@ -99,15 +188,17 @@ class _SpannedDataset:
 
 class FastqDataset(_SpannedDataset):
     """Splittable FASTQ: record-quadruple alignment at every span
-    boundary; compressed inputs read as one span (base class)."""
+    boundary; a compressed input is one span in the plan and a stream of
+    4-line-aligned chunks at read time (base class)."""
 
-    def read_span_text(self, span: FileByteSpan) -> bytes:
-        """Raw record-aligned text of a span (whole file when gzipped) —
-        the input to both the object parse and the vectorized tile path."""
-        return self._span_text(span, read_fastq_span)
+    fmt = "fastq"
+    lines_per_record = 4
 
-    def read_span(self, span: FileByteSpan) -> List[SequencedFragment]:
-        return parse_fastq(self.read_span_text(span),
+    def _read_plain_span(self, span: FileByteSpan) -> bytes:
+        return read_fastq_span(self.path, span)
+
+    def parse_text(self, text: bytes) -> List[SequencedFragment]:
+        return parse_fastq(text,
                            encoding=self.config.fastq_base_quality_encoding,
                            filter_failed_qc=self.config.fastq_filter_failed_qc)
 
@@ -135,11 +226,13 @@ class FastqDataset(_SpannedDataset):
 class QseqDataset(_SpannedDataset):
     """Illumina qseq: one record per line."""
 
-    def read_span_text(self, span: FileByteSpan) -> bytes:
-        return self._span_text(span, read_text_span)
+    fmt = "qseq"
 
-    def read_span(self, span: FileByteSpan) -> List[SequencedFragment]:
-        return parse_qseq(self.read_span_text(span),
+    def _read_plain_span(self, span: FileByteSpan) -> bytes:
+        return read_text_span(self.path, span)
+
+    def parse_text(self, text: bytes) -> List[SequencedFragment]:
+        return parse_qseq(text,
                           encoding=self.config.qseq_base_quality_encoding,
                           filter_failed_qc=self.config.qseq_filter_failed_qc)
 

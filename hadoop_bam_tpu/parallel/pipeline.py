@@ -66,7 +66,7 @@ from hadoop_bam_tpu.utils.errors import PlanError, classify_error
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import (
     SPAN_BUFFERS, decode_pool, decode_pool_size, stream_window_cap,
-    submit as pool_submit,
+    submit as pool_submit, text_stream_window,
 )
 from hadoop_bam_tpu.utils.resilient import (
     QuarantineManifest, RetryPolicy, RetryingByteSource,
@@ -1650,8 +1650,12 @@ def make_read_stats_step(mesh: Mesh, geometry: PayloadGeometry,
     return step
 
 
-# text read-format extensions recognized by the payload stats dispatch
-# (single source of truth — the CLI imports these)
+def pipeline_grain(config: HBamConfig = DEFAULT_CONFIG) -> int:
+    """The pipeline grain in bytes (``pipeline_span_count``'s): what a
+    whole-file driver cuts a file — or a stream's inflated text — by."""
+    return max(1, min(int(config.split_size), 4 << 20))
+
+
 def pipeline_span_count(path, n_dev: int,
                         config: HBamConfig = DEFAULT_CONFIG,
                         size_scale: float = 1.0) -> int:
@@ -1668,7 +1672,7 @@ def pipeline_span_count(path, n_dev: int,
     weighs the file's bytes (the variant driver's: a file that deflates
     far better than the grain assumes, variant_span_count).
     """
-    grain = float(max(1, min(int(config.split_size), 4 << 20)))
+    grain = float(pipeline_grain(config))
     try:
         with scoped_byte_source(path) as src:
             size = src.size
@@ -1677,6 +1681,8 @@ def pipeline_span_count(path, n_dev: int,
     return max(n_dev, int(np.ceil(size * size_scale / grain)))
 
 
+# text read-format extensions recognized by the payload stats dispatch
+# (single source of truth — the CLI imports these)
 FASTQ_EXTS = (".fastq", ".fq", ".fastq.gz", ".fq.gz")
 QSEQ_EXTS = (".qseq", ".qseq.gz")
 TEXT_READ_EXTS = FASTQ_EXTS + QSEQ_EXTS
@@ -1727,9 +1733,37 @@ def fastq_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
                          prefetch: int = 2,
                          quarantine: Optional[QuarantineManifest] = None,
                          ) -> Dict[str, object]:
-    """Distributed GC / quality / base stats over a FASTQ (or QSEQ) file —
-    the text-format twin of seq_stats_file, through the same fused Pallas
-    payload kernel."""
+    """Distributed GC / quality / base stats over a FASTQ (or QSEQ) file,
+    plain or gzip'd — the text-format twin of seq_stats_file, through the
+    same fused Pallas payload kernel, and like it a thin plan builder
+    over the one executor."""
+    from hadoop_bam_tpu.plan import builders
+    from hadoop_bam_tpu.plan import executor as plan_executor
+
+    plan = builders.read_stats_plan(path, config, geometry=geometry)
+    return plan_executor.execute(plan, config=config, mesh=mesh,
+                                 geometry=geometry, spans=spans,
+                                 prefetch=prefetch, quarantine=quarantine)
+
+
+def _read_stats_impl(path: str, fmt: str, mesh: Optional[Mesh] = None,
+                     config: HBamConfig = DEFAULT_CONFIG,
+                     geometry: Optional[PayloadGeometry] = None,
+                     spans=None,
+                     prefetch: int = 2,
+                     quarantine: Optional[QuarantineManifest] = None,
+                     ) -> Dict[str, object]:
+    """The text-read payload-stats implementation (executor runner; ``fmt``
+    is the plan's source format, "fastq" | "qseq").
+
+    The unit of work is a ``TextChunk``: a plain file is many spans of one
+    chunk each, read and tokenised on the pool under the span retry
+    policy; a gzip'd file is one span whose chunks one thread inflates in
+    order (``iter_span_chunks``) while the pool tokenises the ones before.
+    From the chunk onward both run the same lines into the same feed.  A
+    streamed chunk is not re-readable — its tiles may be on the device
+    before a later chunk fails — so nothing of a stream is retried or
+    quarantined: an error ends the scan."""
     from hadoop_bam_tpu.api.read_datasets import (
         fastq_text_to_payload_tiles, fragments_to_payload_tiles,
         open_fastq, open_qseq, qseq_text_to_payload_tiles,
@@ -1742,9 +1776,7 @@ def fastq_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
     if geometry is None:
         geometry = PayloadGeometry()
     cap = geometry.tile_records
-    lower = path.lower()
-    is_qseq = lower.endswith(QSEQ_EXTS)
-    fmt = "qseq" if is_qseq else "fastq"
+    is_qseq = fmt == "qseq"
     ds = open_qseq(path, config) if is_qseq else open_fastq(path, config)
     # Vectorized tokenize (no per-read Python objects) whenever the config
     # doesn't force the object path: failed-QC filtering needs parsed
@@ -1769,32 +1801,53 @@ def fastq_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
     step = make_read_stats_step(mesh, geometry)
     sharding = NamedSharding(mesh, P("data"))
     pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
+    # plain spans wait for their reads, so more of them than cores are in
+    # flight; a stream's chunks are pure compute behind one inflater, and
+    # their decode is not the idempotent unit the straggler defence may
+    # run twice (it gives the chunk's text up)
+    streamed = ds.is_compressed()
+    window = text_stream_window() if streamed \
+        else max(1, prefetch) * decode_pool_size(config)
+    grain = pipeline_grain(config)
     totals = _StatTotals()
 
-    def decode(span):
-        def inner(s):
+    def chunks():
+        for span in spans:
+            yield from ds.iter_span_chunks(span, grain)
+
+    def decode(chunk):
+        def inner(_span):
             with METRICS.span(f"{fmt}.fetch_wall"):
-                raw = ds.read_span_text(s) if fast_tiles \
-                    else ds.read_span(s)
-            with METRICS.span(f"{fmt}.tokenize_wall"):
-                if fast_tiles:
-                    return text_to_tiles(
-                        raw, geometry.seq_stride, geometry.qual_stride,
-                        geometry.max_len, qual_offset)
-                return fragments_to_payload_tiles(
-                    raw, geometry.seq_stride, geometry.qual_stride,
-                    geometry.max_len)
+                text = chunk.text()
+            t_cpu = time.thread_time_ns()
+            try:
+                with METRICS.span(f"{fmt}.tokenize_wall"):
+                    if fast_tiles:
+                        return text_to_tiles(
+                            text, geometry.seq_stride, geometry.qual_stride,
+                            geometry.max_len, qual_offset)
+                    return fragments_to_payload_tiles(
+                        ds.parse_text(text), geometry.seq_stride,
+                        geometry.qual_stride, geometry.max_len)
+            finally:
+                METRICS.count(f"{fmt}.tokenize_busy_ns",
+                              time.thread_time_ns() - t_cpu)
         with METRICS.wall_timer("pipeline.host_decode_wall"), \
                 METRICS.span(f"{fmt}.host_decode_wall"):
-            out = decode_with_retry(inner, span, config,
+            if chunk.streamed:
+                try:
+                    return inner(chunk.span)
+                finally:
+                    chunk.done()
+            out = decode_with_retry(inner, chunk.span, config,
                                     quarantine=quarantine)
         return out if out is not None else (
             np.empty((0, geometry.seq_stride), np.uint8),
             np.empty((0, geometry.qual_stride), np.uint8),
             np.empty((0,), np.int32))
 
-    stream = _iter_windowed(pool, spans, decode, window, config=config)
+    stream = _iter_windowed(pool, chunks(), decode, window,
+                            config=None if streamed else config)
     # the shared feed: in-place ring packing replaces the old per-group
     # np.stack of freshly zero-padded shards, and each device only pays
     # copy work for its own rows (the per-device bucket-cap behavior the
@@ -1810,6 +1863,7 @@ def fastq_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
         c = jax.device_put(counts, sharding)
         with METRICS.span(f"{fmt}.kernel_wall"):
             totals.add(*step(*args, c))  # async; drained once at the end
+        METRICS.count("pipeline.records", int(counts.sum()))
         return (*args, c)  # in-flight handles: the ring waits before reuse
 
     fp.feed(stream, dispatch)

@@ -65,6 +65,36 @@ def seq_stats_plan(path: str, config: Optional[HBamConfig] = None,
         sink=SinkIR.of("seq_stats"))
 
 
+def read_stats_plan(path: str, config: Optional[HBamConfig] = None,
+                    geometry=None) -> PlanIR:
+    """FASTQ / QSEQ payload stats (``hbam seq-stats reads.fastq[.gz]``):
+    the file's text — plain spans at the pipeline grain, or a gzip'd
+    file's one span streamed in chunks of it — tokenised into the same
+    4-bit seq + qual row tiles as the BAM plan, through the same
+    reduction.  Same sink as ``seq_stats_plan``: the executor picks the
+    runner by the source's format."""
+    from hadoop_bam_tpu.parallel.pipeline import (
+        QSEQ_EXTS, PayloadGeometry, pipeline_grain,
+    )
+
+    cfg = config if config is not None else DEFAULT_CONFIG
+    g = geometry if geometry is not None else PayloadGeometry()
+    fmt = "qseq" if path.lower().endswith(QSEQ_EXTS) else "fastq"
+    filt = getattr(cfg, f"{fmt}_filter_failed_qc")
+    enc = getattr(cfg, f"{fmt}_base_quality_encoding")
+    return PlanIR(
+        source=SourceIR(path, fmt),
+        spans=SpansIR.auto(span_bytes=pipeline_grain(cfg)),
+        ops=(op_node("text_tokenize", quality_offset=int(enc.value),
+                     filter_failed_qc=bool(filt)),
+             op_node("payload_pack", max_len=g.max_len,
+                     seq_stride=g.seq_stride, qual_stride=g.qual_stride,
+                     tile_records=g.tile_records,
+                     fixed_shape=g.fixed_shape),
+             op_node("seq_stats_reduce")),
+        sink=SinkIR.of("seq_stats"))
+
+
 def variant_stats_plan(path: str, config: Optional[HBamConfig] = None,
                        geometry=None) -> PlanIR:
     """VCF/BCF variant stats: pack (chrom, pos, flags, dosage) tiles,

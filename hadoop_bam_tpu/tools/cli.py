@@ -441,7 +441,11 @@ def cmd_explain(args) -> int:
     if args.op == "flagstat":
         plan = builders.flagstat_plan(args.path, cfg)
     elif args.op == "seq-stats":
-        plan = builders.seq_stats_plan(args.path, cfg)
+        from hadoop_bam_tpu.parallel.pipeline import TEXT_READ_EXTS
+        build = builders.read_stats_plan \
+            if args.path.lower().endswith(TEXT_READ_EXTS) \
+            else builders.seq_stats_plan
+        plan = build(args.path, cfg)
     elif args.op == "vcf-stats":
         plan = builders.variant_stats_plan(args.path, cfg)
     elif args.op == "vcf-gwas":
@@ -1383,7 +1387,9 @@ def build_parser() -> argparse.ArgumentParser:
 
     sq = sub.add_parser("seq-stats",
                         help="GC/quality/base stats via the Pallas "
-                             "payload kernel")
+                             "payload kernel: a BAM, a CRAM, or a FASTQ "
+                             "/ QSEQ read file, plain or gzip'd "
+                             "(.fastq .fq .qseq, each also .gz)")
     sq.add_argument("path")
     sq.add_argument("--max-len", type=int, default=160)
     sq.add_argument("--reference",

@@ -275,6 +275,16 @@ def stream_window_cap() -> int:
     return max(2, 2 * (os.cpu_count() or 1))
 
 
+def text_stream_window() -> int:
+    """Most chunks of a compressed text STREAM tokenised at once: half the
+    host's CPUs, at least 2.  A chunk's text is in memory already, so its
+    tokenise never waits for a read and more of them in flight than cores
+    only queue; the stream's one inflater — which sets the pace — the
+    packer and the dispatch thread need the other cores.  The window is
+    also what bounds a streamed scan's memory (window + 2 chunks)."""
+    return max(2, (os.cpu_count() or 1) // 2)
+
+
 class SpanBuffer:
     """One leased buffer: ``array`` (uint8, its class's full size) is the
     holder's alone until ``release()``, which clears it.  A second

@@ -58,24 +58,34 @@ N_DEV, CAP, GROUPS = 2, 16, 30
 SLOW_S = 0.01       # long against the per-group Python between spans
 
 
-def _chunks(delay_s: float):
+def _nap(delay_s: float, slept=None) -> None:
+    """``time.sleep`` that notes what a real sleep asked for and got."""
+    t0 = time.perf_counter()
+    time.sleep(delay_s)
+    if slept is not None and delay_s:
+        slept.append((delay_s, time.perf_counter() - t0))
+
+
+def _chunks(delay_s: float, slept=None):
     """GROUPS groups' worth of rows, one group a chunk."""
     for _ in range(GROUPS):
         if delay_s:
-            time.sleep(delay_s)
+            _nap(delay_s, slept)
         yield (np.ones((N_DEV * CAP, 4), np.uint8),)
 
 
-def _feed(stream_delay_s: float, dispatch_delay_s: float, traced: bool):
+def _feed(stream_delay_s: float, dispatch_delay_s: float, traced: bool,
+          slept=None):
     """One balanced feed (the stats drivers' mode) under its own
-    MetricsContext; returns that context's wall timers."""
+    MetricsContext; returns that context's wall timers.  ``slept``
+    collects (asked, took) of every sleep of the run."""
     if traced:
         enable_tracing()
     fp = FeedPipeline(N_DEV, CAP, (TileSpec((4,), np.uint8),), block_n=4,
                       balance=True)
     with MetricsContext() as m:
-        n = fp.feed(_chunks(stream_delay_s),
-                    lambda arrays, counts: time.sleep(dispatch_delay_s))
+        n = fp.feed(_chunks(stream_delay_s, slept),
+                    lambda arrays, counts: _nap(dispatch_delay_s, slept))
     assert n == GROUPS
     return m.snapshot()["wall_timers"], m
 
@@ -103,17 +113,29 @@ def test_slow_dispatch_holds_the_packer_on_a_slot(traced):
                          [(SLOW_S, 0.0), (0.0, SLOW_S)],
                          ids=["slow_stream", "slow_dispatch"])
 def test_each_feed_thread_is_partitioned(stream_s, dispatch_s):
-    w, _ = _feed(stream_s, dispatch_s, traced=False)
+    slept = []
+    w, _ = _feed(stream_s, dispatch_s, traced=False, slept=slept)
     feed = w["pipeline.feed_wall"]
     dispatch_thread = w["feed.wait_group"] + w["pipeline.dispatch_wall"]
     packer = (w.get("feed.wait_rows", 0.0) + w.get("feed.wait_slot", 0.0)
               + w.get("staging.transfer_wait", 0.0) + w["staging.pack"])
-    assert dispatch_thread >= 0.95 * feed
+    # What a thread's spans leave uncovered is the Python between them
+    # and the hand-offs: a group each way through the queue and the ring,
+    # each woken by the scheduler that wakes this run's own sleeps.  How
+    # late those woke, summed, is the run's measured scheduling error: on
+    # a machine that runs six test workers it is what grows, and it is no
+    # share of the feed — so it is the slack, in seconds, beside the 5 %.
+    late = sum(took - asked for asked, took in slept)
+    assert len(slept) == GROUPS and late >= 0.0
+    assert dispatch_thread >= 0.95 * feed - late
     # the packer leaves once the last group is handed over, up to two
     # dispatches (the queued group and the one in hand) before the feed
-    # ends: that tail is all a slow dispatch side may leave uncovered
-    tail = 2 * dispatch_s
-    assert packer + tail >= 0.95 * feed
+    # ends: that tail is all a slow dispatch side may leave uncovered —
+    # as long as those dispatches took, not as long as they asked for
+    tail = sum(sorted(took for _asked, took in slept)[-2:]) \
+        if dispatch_s else 0.0
+    assert packer + tail >= 0.95 * feed - late
+    # the spans of one thread lie inside the feed's wall and never overlap
     assert dispatch_thread <= 1.02 * feed and packer <= 1.02 * feed
 
 
