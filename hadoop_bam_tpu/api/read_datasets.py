@@ -53,8 +53,9 @@ class TextChunk:
 
 
 class _TextAlive:
-    """Bytes of a stream's inflated text alive between the inflater and
-    the end of their tokenise, and their high-water mark."""
+    """Bytes of a stream's inflated text alive between the inflater —
+    its workers' buffers too — and the end of their tokenise, and their
+    high-water mark."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -126,9 +127,11 @@ class _SpannedDataset:
     def _streamed(self, span: FileByteSpan) -> bool:
         return span.start == 0 and self.is_compressed()
 
-    def _stream_text(self, grain: int) -> Iterator[bytes]:
+    def _stream_text(self, grain: int,
+                     alive: "Optional[_TextAlive]" = None) -> Iterator[bytes]:
         return iter_gzip_text_chunks(self.path, grain,
-                                     self.lines_per_record, fmt=self.fmt)
+                                     self.lines_per_record, fmt=self.fmt,
+                                     alive=alive)
 
     def read_span_text(self, span: FileByteSpan) -> bytes:
         """Raw record-aligned text of a span (the whole inflated file for
@@ -143,19 +146,23 @@ class _SpannedDataset:
         tokenises.  A plain-text span is one chunk, read when its
         ``text()`` is asked for (on the driver's pool, under its retry).
         A compressed span is a stream: one thread a file,
-        ``hbam-inflate-stream``, inflates it into record-aligned chunks
-        of at most ``grain`` bytes, one chunk ahead of the consumer; an
-        error in it (a corrupt or truncated member) is raised here.  When
-        the stream ends, its high-water mark of text alive is added to
-        ``<fmt>.stream_peak_text_bytes`` (a chunk's text is alive until
-        its ``done()``)."""
+        ``hbam-inflate-stream``, hands on its record-aligned chunks of at
+        most ``grain`` bytes, one chunk ahead of the consumer — inflating
+        them itself or walking the pieces of the ``hbam-inflate_<i>``
+        workers (``iter_gzip_text_chunks``); an error in it (a corrupt or
+        truncated member) is raised here.  When the stream ends, its
+        high-water mark of text alive is added to
+        ``<fmt>.stream_peak_text_bytes``: a chunk's text until its
+        ``done()``, and what the workers hold before it — 2 B a symbol
+        decoded ahead, then the bytes until they are copied into a
+        chunk."""
         if not self._streamed(span):
             yield TextChunk(span, read=lambda: self._read_plain_span(span))
             return
         alive = _TextAlive()
 
         def chunks() -> Iterator[TextChunk]:
-            for text in self._stream_text(grain):
+            for text in self._stream_text(grain, alive):
                 alive.add(len(text))
                 yield TextChunk(span, text=text, alive=alive)
 

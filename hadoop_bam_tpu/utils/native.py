@@ -207,6 +207,20 @@ def load() -> Optional[ctypes.CDLL]:
             i8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64, ctypes.c_int32,
             ctypes.c_int32, ctypes.c_int64, i8sp, ctypes.c_int64,
             ctypes.c_int64]
+        u16p = ctypes.POINTER(ctypes.c_uint16)
+        lib.hbam_deflate_find_block.restype = ctypes.c_int64
+        lib.hbam_deflate_find_block.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64]
+        lib.hbam_deflate_decode_symbols.restype = ctypes.c_int32
+        lib.hbam_deflate_decode_symbols.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int32, u16p, ctypes.c_int64, i64p, i64p]
+        lib.hbam_deflate_resolve.restype = ctypes.c_uint32
+        lib.hbam_deflate_resolve.argtypes = [
+            u16p, ctypes.c_int64, i8p, i8p, ctypes.c_uint8, i64p]
+        lib.hbam_crc32_combine.restype = ctypes.c_uint32
+        lib.hbam_crc32_combine.argtypes = [
+            ctypes.c_uint32, ctypes.c_uint32, ctypes.c_int64]
         if hasattr(lib, "hbam_fused_start"):
             lib.hbam_fused_start.restype = ctypes.c_void_p
             lib.hbam_fused_start.argtypes = [
@@ -461,6 +475,116 @@ def bcf_gt_dosage(buf: np.ndarray, rows: np.ndarray, offs: np.ndarray,
     if rc:
         raise BCFError(f"GT vector of record {rc - 1} of its layout group "
                        "overruns the span")
+
+
+# A DEFLATE window, and the symbols ``deflate_decode_symbols`` writes where
+# the window is unknown: 256 + k for its byte k (hbam_native.cpp kGzWindow).
+DEFLATE_WINDOW = 32768
+DEFLATE_SLACK = 16
+_UNKNOWN_WINDOW = np.arange(256, 256 + DEFLATE_WINDOW, dtype=np.uint16)
+
+
+def _src_u8(src) -> np.ndarray:
+    return src if isinstance(src, np.ndarray) \
+        else np.frombuffer(src, dtype=np.uint8)
+
+
+def deflate_find_block(src, from_bit: int, until_bit: int) -> int:
+    """The first bit offset in ``[from_bit, until_bit)`` of ``src`` at
+    which a non-final dynamic-Huffman DEFLATE block header parses whole
+    to two prefix codes inflate would accept (the interpreter lock is
+    released for the scan); -1 where there is none.  Stored and fixed
+    blocks are not found."""
+    lib = load()
+    assert lib is not None
+    a = _src_u8(src)
+    return int(lib.hbam_deflate_find_block(
+        _ptr(a, ctypes.c_uint8), int(a.size), int(from_bit),
+        int(until_bit)))
+
+
+def deflate_symbol_buffer(cap: int) -> np.ndarray:
+    """Room for ``cap`` symbols of ``deflate_decode_symbols`` behind the
+    window it starts from (and the decoder's slack): allocated, not
+    touched, so what stays unused costs no memory."""
+    return np.empty(DEFLATE_WINDOW + int(cap) + DEFLATE_SLACK, np.uint16)
+
+
+def deflate_decode_symbols(src, start_bit: int, stop_bit: int,
+                           soft_cap: int, window: Optional[bytes],
+                           buf: np.ndarray) -> "tuple[int, int, np.ndarray]":
+    """Decode DEFLATE blocks of ``src`` from ``start_bit`` (a block's
+    first bit) into 16-bit symbols in ``buf`` (``deflate_symbol_buffer``),
+    the interpreter lock released.  ``window`` is the text before the
+    block, at most ``DEFLATE_WINDOW`` bytes of it (a match may not reach
+    further back than it has), or ``None``: unknown — a symbol is then a
+    byte, or 256 + k where a match reaches byte k of the unknown window.
+    It stops at the first block boundary at or past ``stop_bit`` or with
+    ``soft_cap`` symbols written, at the final block's end, or with the
+    last whole block where ``src`` or ``buf`` ends inside one.  Returns
+    (status, end_bit, symbols): status 1 at the final block's end, 0 at
+    another boundary, -1 for data that is no DEFLATE, -2 / -3 where not
+    one block fits the room / the input; ``symbols`` is a view of
+    ``buf``."""
+    lib = load()
+    assert lib is not None
+    a = _src_u8(src)
+    if buf.dtype != np.uint16 or not buf.flags.c_contiguous \
+            or buf.size < DEFLATE_WINDOW + DEFLATE_SLACK:
+        raise ValueError("deflate_decode_symbols wants a buffer of "
+                         "deflate_symbol_buffer")
+    if window is None:
+        buf[:DEFLATE_WINDOW] = _UNKNOWN_WINDOW
+        known = DEFLATE_WINDOW
+    else:
+        tail = np.frombuffer(window, np.uint8)[-DEFLATE_WINDOW:]
+        known = int(tail.size)
+        buf[DEFLATE_WINDOW - known:DEFLATE_WINDOW] = tail
+    out = np.zeros(2, dtype=np.int64)
+    rc = int(lib.hbam_deflate_decode_symbols(
+        _ptr(a, ctypes.c_uint8), int(a.size), int(start_bit), int(stop_bit),
+        int(soft_cap), known, _ptr(buf, ctypes.c_uint16),
+        int(buf.size) - DEFLATE_WINDOW - DEFLATE_SLACK,
+        _ptr(out, ctypes.c_int64), _ptr(out[1:], ctypes.c_int64)))
+    return rc, int(out[0]), buf[DEFLATE_WINDOW:DEFLATE_WINDOW + int(out[1])]
+
+
+def deflate_resolve(symbols: np.ndarray, window: Optional[bytes],
+                    out: Optional[np.ndarray] = None, eol: int = 0x0A
+                    ) -> "tuple[np.ndarray, int, int]":
+    """Symbols -> (bytes, their CRC32, how many of them are ``eol``), the
+    interpreter lock released: a symbol under 256 is its byte, 256 + k
+    is byte k of the ``DEFLATE_WINDOW`` bytes before the symbols' first
+    — ``window``, its last bytes where it is shorter (what lies before
+    them reads 0), or ``None`` where no symbol is a mark.  The bytes are
+    written into ``out`` where given (a view of it is returned)."""
+    lib = load()
+    assert lib is not None
+    if symbols.dtype != np.uint16 or symbols.ndim != 1 \
+            or not symbols.flags.c_contiguous:
+        raise ValueError("deflate_resolve wants contiguous u16 symbols")
+    win = None
+    if window:
+        win = np.frombuffer(window[-DEFLATE_WINDOW:].rjust(
+            DEFLATE_WINDOW, b"\0"), np.uint8)
+    if out is None:
+        out = np.empty(symbols.size, dtype=np.uint8)
+    elif out.dtype != np.uint8 or not out.flags.c_contiguous \
+            or out.size < symbols.size:
+        raise ValueError("deflate_resolve wants room for a byte a symbol")
+    eols = np.zeros(1, dtype=np.int64)
+    crc = int(lib.hbam_deflate_resolve(
+        _ptr(symbols, ctypes.c_uint16), int(symbols.size),
+        None if win is None else _ptr(win, ctypes.c_uint8),
+        _ptr(out, ctypes.c_uint8), int(eol), _ptr(eols, ctypes.c_int64)))
+    return out[:symbols.size], crc, int(eols[0])
+
+
+def crc32_combine(crc_a: int, crc_b: int, len_b: int) -> int:
+    """CRC32 of A ++ B from CRC32(A), CRC32(B) and len(B)."""
+    lib = load()
+    assert lib is not None
+    return int(lib.hbam_crc32_combine(int(crc_a), int(crc_b), int(len_b)))
 
 
 def fused_available() -> bool:

@@ -275,14 +275,29 @@ def stream_window_cap() -> int:
     return max(2, 2 * (os.cpu_count() or 1))
 
 
+def text_inflate_workers() -> int:
+    """Threads that inflate a compressed text STREAM from inside its
+    members (``split/read_planners.py::_SpeculativeMembers``): a quarter
+    of the host's CPUs, at least 2 — one worker's two stages are no faster
+    than the one ``zlib`` inflate they replace.  A worker feeds ~0.65 M
+    FASTQ records a second and the tokenisers take under 1 M from all of
+    them (PERF.md section 6, PR 35), so more workers only crowd the
+    tokenisers."""
+    return max(2, (os.cpu_count() or 1) // 4)
+
+
 def text_stream_window() -> int:
-    """Most chunks of a compressed text STREAM tokenised at once: half the
-    host's CPUs, at least 2.  A chunk's text is in memory already, so its
+    """Most chunks of a compressed text STREAM tokenised at once: the
+    host's CPUs less the stream's inflate workers and two more (the
+    stream's own thread; the packer and the dispatch thread, which mostly
+    wait), at least 2.  A chunk's text is in memory already, so its
     tokenise never waits for a read and more of them in flight than cores
-    only queue; the stream's one inflater — which sets the pace — the
-    packer and the dispatch thread need the other cores.  The window is
-    also what bounds a streamed scan's memory (window + 2 chunks)."""
-    return max(2, (os.cpu_count() or 1) // 2)
+    only queue.  A tokenise costs four to five times the CPU of the
+    inflate that feeds it, so with the inflate on several threads the
+    tokenisers set the pace and get the larger share.  The window is also
+    what bounds a streamed scan's memory: window + 2 chunks, and what the
+    inflate workers hold ahead of them."""
+    return max(2, (os.cpu_count() or 1) - text_inflate_workers() - 2)
 
 
 class SpanBuffer:

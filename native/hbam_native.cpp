@@ -592,6 +592,511 @@ int64_t hbam_bcf_gt_dosage(const uint8_t* buf, int64_t buf_len,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
+// DEFLATE decoded INSIDE one gzip member, on many cores (the stream of
+// split/read_planners.py; Rapidgzip, arXiv 2308.08955; pugz): a sequencer's
+// .fastq.gz is one member with no index, so a worker that starts mid-member
+// knows neither where a block begins nor the 32 KiB of text before it.
+//   hbam_deflate_find_block   the next bit at which a dynamic-Huffman block
+//                             header parses to two valid prefix codes;
+//   hbam_deflate_decode_symbols  a decoder of this repo's own that writes
+//                             16-bit symbols: a byte, or 256 + k where a match
+//                             reaches position k of the window it was given
+//                             as unknown;
+//   hbam_deflate_resolve      symbols -> bytes through the true window, one
+//                             table look-up a symbol, and the bytes' CRC32.
+// No threads of their own: the callers' threads run them with the
+// interpreter lock released.
+// ---------------------------------------------------------------------------
+namespace {
+
+constexpr int kGzWindow = 32768;
+constexpr int kGzLitRoot = 10, kGzDistRoot = 8, kGzPreRoot = 7;
+constexpr int kGzLitCap = 1024 + 1024, kGzDistCap = 256 + 1024;
+
+// A table entry: bits 0-4 the code's length, 5-7 its kind, 8-12 the extra
+// bits that follow (a subtable's index bits for kGzSub), 16-31 the payload:
+// a literal, a length's or distance's base, a subtable's offset.
+enum { kGzLit = 0, kGzLen = 1, kGzEob = 2, kGzSub = 3, kGzBad = 4 };
+
+constexpr uint32_t gz_entry(int kind, int extra, int payload) {
+  return static_cast<uint32_t>(kind) << 5 | static_cast<uint32_t>(extra) << 8 |
+         static_cast<uint32_t>(payload) << 16;
+}
+
+struct GzSymbols {
+  uint32_t litlen[288], dist[32], pre[19];
+  GzSymbols() {
+    static const uint16_t lbase[29] = {3, 4, 5, 6, 7, 8, 9, 10, 11, 13, 15, 17,
+        19, 23, 27, 31, 35, 43, 51, 59, 67, 83, 99, 115, 131, 163, 195, 227,
+        258};
+    static const uint8_t lext[29] = {0, 0, 0, 0, 0, 0, 0, 0, 1, 1, 1, 1, 2, 2,
+        2, 2, 3, 3, 3, 3, 4, 4, 4, 4, 5, 5, 5, 5, 0};
+    static const uint16_t dbase[30] = {1, 2, 3, 4, 5, 7, 9, 13, 17, 25, 33, 49,
+        65, 97, 129, 193, 257, 385, 513, 769, 1025, 1537, 2049, 3073, 4097,
+        6145, 8193, 12289, 16385, 24577};
+    static const uint8_t dext[30] = {0, 0, 0, 0, 1, 1, 2, 2, 3, 3, 4, 4, 5, 5,
+        6, 6, 7, 7, 8, 8, 9, 9, 10, 10, 11, 11, 12, 12, 13, 13};
+    for (int s = 0; s < 256; ++s) litlen[s] = gz_entry(kGzLit, 0, s);
+    litlen[256] = gz_entry(kGzEob, 0, 0);
+    for (int s = 257; s < 286; ++s)
+      litlen[s] = gz_entry(kGzLen, lext[s - 257], lbase[s - 257]);
+    litlen[286] = litlen[287] = gz_entry(kGzBad, 0, 0);
+    for (int s = 0; s < 30; ++s) dist[s] = gz_entry(kGzLen, dext[s], dbase[s]);
+    dist[30] = dist[31] = gz_entry(kGzBad, 0, 0);
+    for (int s = 0; s < 19; ++s) pre[s] = gz_entry(kGzLit, 0, s);
+  }
+};
+
+const GzSymbols& gz_symbols() {
+  static const GzSymbols s;
+  return s;
+}
+
+// What zlib's inflate_table accepts of a set of code lengths: never an
+// over-subscribed one; an incomplete one only as a single code of length 1
+// (or, ``may_be_empty``, no code at all).  Fills count[1..15].
+bool gz_code_ok(const uint8_t* lens, int n, int* count, bool may_be_empty) {
+  for (int l = 0; l <= 15; ++l) count[l] = 0;
+  for (int s = 0; s < n; ++s) ++count[lens[s]];
+  if (count[0] == n) return may_be_empty;
+  int left = 1, used = 0, max_len = 0;
+  for (int l = 1; l <= 15; ++l) {
+    left = (left << 1) - count[l];
+    if (left < 0) return false;
+    if (count[l]) { max_len = l; used += count[l]; }
+  }
+  return left == 0 || (max_len == 1 && used == 1);
+}
+
+inline uint32_t gz_reverse(uint32_t code, int len) {
+  uint32_t r = 0;
+  for (int i = 0; i < len; ++i) { r = r << 1 | (code & 1); code >>= 1; }
+  return r;
+}
+
+// The decode table of one canonical prefix code: ``root`` bits index the
+// primary table, longer codes go through a subtable a prefix.  ``entries``
+// gives each symbol's kind, extra bits and payload.  False for a set of
+// lengths gz_code_ok refuses (or a table that would not fit ``cap``).
+bool gz_build(const uint8_t* lens, int n, int root, const uint32_t* entries,
+              bool may_be_empty, uint32_t* table, int cap) {
+  int count[16];
+  if (!gz_code_ok(lens, n, count, may_be_empty)) return false;
+  const uint32_t bad = gz_entry(kGzBad, 0, 0) | 1;
+  for (int i = 0; i < (1 << root); ++i) table[i] = bad;
+  int offs[17];
+  offs[1] = 0;
+  for (int l = 1; l <= 15; ++l) offs[l + 1] = offs[l] + count[l];
+  const int m = offs[16];
+  uint16_t sym[288];
+  uint8_t slen[288];
+  uint16_t code[288];
+  {
+    int at[16];
+    for (int l = 1; l <= 15; ++l) at[l] = offs[l];
+    for (int s = 0; s < n; ++s)
+      if (lens[s]) { sym[at[lens[s]]] = static_cast<uint16_t>(s);
+                     slen[at[lens[s]]++] = lens[s]; }
+    uint32_t c = 0;
+    int prev = m ? slen[0] : 0;
+    for (int i = 0; i < m; ++i) {
+      c <<= (slen[i] - prev);
+      prev = slen[i];
+      code[i] = static_cast<uint16_t>(c++);
+    }
+  }
+  int i = 0;
+  for (; i < m && slen[i] <= root; ++i) {
+    const uint32_t e = entries[sym[i]] | slen[i];
+    for (uint32_t x = gz_reverse(code[i], slen[i]); x < (1u << root);
+         x += 1u << slen[i])
+      table[x] = e;
+  }
+  int used = 1 << root;
+  while (i < m) {
+    const int prefix = code[i] >> (slen[i] - root);
+    int j = i;
+    while (j < m && (code[j] >> (slen[j] - root)) == prefix) ++j;
+    const int sub_bits = slen[j - 1] - root;
+    if (used + (1 << sub_bits) > cap) return false;
+    uint32_t* sub = table + used;
+    for (int x = 0; x < (1 << sub_bits); ++x) sub[x] = bad;
+    table[gz_reverse(prefix, root)] =
+        gz_entry(kGzSub, sub_bits, used) | static_cast<uint32_t>(root);
+    for (; i < j; ++i) {
+      const int rem = slen[i] - root;
+      const uint32_t e = entries[sym[i]] | slen[i];
+      for (uint32_t x = gz_reverse(code[i] & ((1u << rem) - 1), rem);
+           x < (1u << sub_bits); x += 1u << rem)
+        sub[x] = e;
+    }
+    used += 1 << sub_bits;
+  }
+  return true;
+}
+
+// The next symbol of a table built by gz_build, its bits dropped.
+#define GZ_LOOKUP(e, table, root, b)                                        \
+  do {                                                                      \
+    (e) = (table)[(b).buf & ((1u << (root)) - 1)];                          \
+    if ((((e) >> 5) & 7) == kGzSub)                                         \
+      (e) = (table)[((e) >> 16) +                                           \
+                    (((b).buf >> (root)) & ((1u << (((e) >> 8) & 31)) - 1))]; \
+    (b).drop((e) & 31);                                                     \
+  } while (0)
+
+// Bits of src[0, n) from any bit offset, least significant first.  Past the
+// end it reads zeros and counts them (``over`` bytes): ``exhausted()`` says
+// a bit that was consumed was one of those.
+struct GzBits {
+  const uint8_t* base;
+  const uint8_t* next;
+  const uint8_t* end;
+  uint64_t buf;
+  int cnt;
+  int64_t over;
+
+  void seek(const uint8_t* src, int64_t n, int64_t bit) {
+    base = src;
+    end = src + n;
+    next = src + ((bit >> 3) < n ? (bit >> 3) : n);
+    buf = 0;
+    cnt = 0;
+    over = (bit >> 3) < n ? 0 : (bit >> 3) - n;
+    refill();
+    drop(static_cast<int>(bit & 7));
+  }
+  inline void refill() {
+    if (end - next >= 8) {
+      uint64_t w;
+      std::memcpy(&w, next, 8);      // little-endian hosts, as the BAM walk
+      buf |= w << cnt;
+      const int nb = (63 - cnt) >> 3;
+      next += nb;
+      cnt += nb << 3;
+    } else {
+      while (cnt <= 56) {
+        if (next < end) buf |= static_cast<uint64_t>(*next++) << cnt;
+        else ++over;
+        cnt += 8;
+      }
+    }
+  }
+  inline uint32_t peek(int k) const {
+    return static_cast<uint32_t>(buf) & ((1u << k) - 1);
+  }
+  inline void drop(int k) { buf >>= k; cnt -= k; }
+  inline int64_t bitpos() const { return ((next - base) + over) * 8 - cnt; }
+  inline bool exhausted() const { return over * 8 > cnt; }
+};
+
+// The body of a dynamic block's header after its three first bits: HLIT,
+// HDIST, HCLEN, the code-length code and the two sets of lengths it spells
+// (lens[0, nlen) and lens[nlen, nlen + ndist)), refused wherever zlib's
+// inflate refuses them.  The caller checks ``b.exhausted()``.
+bool gz_read_dynamic(GzBits& b, uint8_t* lens, int* nlen, int* ndist) {
+  static const uint8_t order[19] = {16, 17, 18, 0, 8, 7, 9, 6, 10, 5, 11, 4,
+                                    12, 3, 13, 2, 14, 1, 15};
+  b.refill();
+  *nlen = static_cast<int>(b.peek(5)) + 257;
+  b.drop(5);
+  *ndist = static_cast<int>(b.peek(5)) + 1;
+  b.drop(5);
+  const int ncode = static_cast<int>(b.peek(4)) + 4;
+  b.drop(4);
+  if (*nlen > 286 || *ndist > 30) return false;
+  uint8_t pre[19] = {0};
+  for (int i = 0; i < ncode; ++i) {
+    if (b.cnt < 3) b.refill();
+    pre[order[i]] = static_cast<uint8_t>(b.peek(3));
+    b.drop(3);
+  }
+  uint32_t table[1 << kGzPreRoot];
+  if (!gz_build(pre, 19, kGzPreRoot, gz_symbols().pre, false, table,
+                1 << kGzPreRoot))
+    return false;
+  const int total = *nlen + *ndist;
+  int have = 0;
+  while (have < total) {
+    if (b.cnt < 16) b.refill();
+    uint32_t e;
+    GZ_LOOKUP(e, table, kGzPreRoot, b);
+    if (((e >> 5) & 7) != kGzLit) return false;
+    const int s = static_cast<int>(e >> 16);
+    if (s < 16) { lens[have++] = static_cast<uint8_t>(s); continue; }
+    int rep;
+    uint8_t fill = 0;
+    if (s == 16) {
+      if (!have) return false;
+      fill = lens[have - 1];
+      rep = 3 + static_cast<int>(b.peek(2));
+      b.drop(2);
+    } else if (s == 17) {
+      rep = 3 + static_cast<int>(b.peek(3));
+      b.drop(3);
+    } else {
+      rep = 11 + static_cast<int>(b.peek(7));
+      b.drop(7);
+    }
+    if (have + rep > total) return false;
+    while (rep--) lens[have++] = fill;
+  }
+  return lens[256] != 0;             // a block has to be able to end
+}
+
+struct GzTables {
+  uint32_t lit[kGzLitCap];
+  uint32_t dist[kGzDistCap];
+};
+
+bool gz_build_block(const uint8_t* lens, int nlen, int ndist, GzTables* t) {
+  return gz_build(lens, nlen, kGzLitRoot, gz_symbols().litlen, false, t->lit,
+                  kGzLitCap) &&
+         gz_build(lens + nlen, ndist, kGzDistRoot, gz_symbols().dist, true,
+                  t->dist, kGzDistCap);
+}
+
+const GzTables& gz_fixed_tables() {
+  static const GzTables* fixed = [] {
+    uint8_t lens[320];
+    for (int s = 0; s < 288; ++s)
+      lens[s] = s < 144 ? 8 : s < 256 ? 9 : s < 280 ? 7 : 8;
+    for (int s = 0; s < 32; ++s) lens[288 + s] = 5;
+    GzTables* t = new GzTables();
+    gz_build_block(lens, 288, 32, t);
+    return t;
+  }();
+  return *fixed;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The first bit offset in [from_bit, until_bit) of src[0, n) at which a
+// non-final dynamic-Huffman block header parses whole: BFINAL 0, BTYPE 2,
+// HLIT and HDIST in range, a complete code-length code, two sets of lengths
+// inflate would accept, an end-of-block code.  Stored and fixed blocks are
+// not found; a header cut by the end of src is none.  -1: none.
+int64_t hbam_deflate_find_block(const uint8_t* src, int64_t n,
+                                int64_t from_bit, int64_t until_bit) {
+  // which of a byte's eight bit offsets open with BFINAL 0, BTYPE 2 (the
+  // bits 0, 0, 1), by the ten bits from the byte's first
+  static const uint8_t* const opens = [] {
+    uint8_t* t = new uint8_t[1024];
+    for (int w = 0; w < 1024; ++w) {
+      t[w] = 0;
+      for (int k = 0; k < 8; ++k)
+        if (((w >> k) & 7) == 4) t[w] |= static_cast<uint8_t>(1 << k);
+    }
+    return t;
+  }();
+  // the code space three code lengths of 3 bits each take, of 128
+  static const uint16_t* const space3 = [] {
+    uint16_t* t = new uint16_t[512];
+    for (int w = 0; w < 512; ++w)
+      t[w] = static_cast<uint16_t>(((128 >> (w & 7)) & 127) +
+                                   ((128 >> ((w >> 3) & 7)) & 127) +
+                                   ((128 >> (w >> 6)) & 127));
+    return t;
+  }();
+  if (from_bit < 0) from_bit = 0;
+  if (until_bit > n * 8) until_bit = n * 8;
+  GzBits b;
+  uint8_t lens[320];
+  int count[16];
+  for (int64_t byte = from_bit >> 3; byte * 8 < until_bit; ++byte) {
+    uint32_t word = 0;
+    if (byte + 4 <= n) std::memcpy(&word, src + byte, 4);
+    else std::memcpy(&word, src + byte, static_cast<size_t>(n - byte));
+    for (uint32_t at = opens[word & 1023]; at; at &= at - 1) {
+      const int k = __builtin_ctz(at);
+      const int64_t p = byte * 8 + k;
+      if (p < from_bit || p >= until_bit) continue;
+      const uint32_t w = word >> k;   // 25 bits or more of the header
+      if (((w >> 3) & 31) > 29 || ((w >> 8) & 31) > 29) continue;
+      // the code-length code's lengths, 3 bits each, have to fill the
+      // code space exactly: nearly every false start ends here
+      const int64_t q = p + 17;
+      if ((q >> 3) + 8 <= n) {
+        uint64_t x;
+        std::memcpy(&x, src + (q >> 3), 8);
+        x >>= (q & 7);                // 57 bits: 19 lengths at most
+        x &= (uint64_t{1} << (3 * (((w >> 13) & 15) + 4))) - 1;
+        int space = 0;
+        for (; x; x >>= 9) space += space3[x & 511];
+        if (space != 128) continue;
+      }
+      b.seek(src, n, p + 3);
+      int nlen, ndist;
+      if (!gz_read_dynamic(b, lens, &nlen, &ndist) || b.exhausted())
+        continue;
+      if (gz_code_ok(lens, nlen, count, false) &&
+          gz_code_ok(lens + nlen, ndist, count, true))
+        return p;
+    }
+  }
+  return -1;
+}
+
+// Decode DEFLATE blocks of src[0, n) from ``start_bit`` (a block's first
+// bit) into 16-bit symbols.  ``out`` holds kGzWindow symbols of window —
+// the caller writes them: 256 + k at position k where the text before the
+// block is unknown, the bytes themselves where it is known (``window_len``
+// of them are real: a match may not reach further back) — then room for
+// ``cap`` symbols and 16 of slack.  A match copies symbols, so what it
+// takes from an unknown window stays marked.  Block after block, until the
+// first block boundary at or past ``stop_bit`` or with ``soft_cap``
+// symbols written, or the final block's end.
+//
+// Returns 1 at the final block's end, 0 at another boundary (*end_bit the
+// boundary, *n_out the symbols up to it).  Where src or the room ends
+// inside a block, the blocks decoded whole are the result (0); with none:
+// -2 out of room, -3 out of input.  -1: the data is no DEFLATE.
+int32_t hbam_deflate_decode_symbols(const uint8_t* src, int64_t n,
+                                    int64_t start_bit, int64_t stop_bit,
+                                    int64_t soft_cap, int32_t window_len,
+                                    uint16_t* out, int64_t cap,
+                                    int64_t* end_bit, int64_t* n_out) {
+  if (start_bit < 0 || cap < 0 || window_len < 0 || window_len > kGzWindow)
+    return -1;
+  if (start_bit >= n * 8) return -3;
+  uint16_t* o = out + kGzWindow;
+  int64_t pos = 0;
+  int64_t good_bit = start_bit, good_pos = 0;
+  int32_t short_of = 0;
+  GzTables dyn;
+  uint8_t lens[320];
+  GzBits b;
+  b.seek(src, n, start_bit);
+  for (;;) {
+    b.refill();
+    const uint32_t head = b.peek(3);
+    b.drop(3);
+    if (b.exhausted()) { short_of = -3; break; }
+    const bool final_block = head & 1;
+    const int type = static_cast<int>(head >> 1);
+    if (type == 0) {
+      const int64_t at = (b.bitpos() + 7) >> 3;
+      if (at + 4 > n) { short_of = -3; break; }
+      const uint32_t len = src[at] | static_cast<uint32_t>(src[at + 1]) << 8;
+      const uint32_t nlen = src[at + 2] |
+                            static_cast<uint32_t>(src[at + 3]) << 8;
+      if ((len ^ nlen) != 0xffffu) return -1;
+      if (at + 4 + len > n) { short_of = -3; break; }
+      if (pos + len > cap) { short_of = -2; break; }
+      const uint8_t* s = src + at + 4;
+      for (uint32_t i = 0; i < len; ++i) o[pos + i] = s[i];
+      pos += len;
+      b.seek(src, n, (at + 4 + len) * 8);
+    } else if (type == 3) {
+      return -1;
+    } else {
+      const GzTables* t = &dyn;
+      if (type == 1) {
+        t = &gz_fixed_tables();
+      } else {
+        int nlen, ndist;
+        const bool ok = gz_read_dynamic(b, lens, &nlen, &ndist);
+        if (b.exhausted()) { short_of = -3; break; }
+        if (!ok || !gz_build_block(lens, nlen, ndist, &dyn)) return -1;
+      }
+      const uint32_t* lt = t->lit;
+      const uint32_t* dt = t->dist;
+      for (;;) {
+        if (b.cnt < 48) {
+          b.refill();
+          if (b.over && b.exhausted()) { short_of = -3; break; }
+        }
+        uint32_t e;
+        GZ_LOOKUP(e, lt, kGzLitRoot, b);
+        const int kind = (e >> 5) & 7;
+        if (kind == kGzLit) {
+          if (pos >= cap) { short_of = -2; break; }
+          o[pos++] = static_cast<uint16_t>(e >> 16);
+          continue;
+        }
+        if (kind == kGzEob) break;
+        if (kind != kGzLen) return -1;
+        int xb = (e >> 8) & 31;
+        const int64_t len = (e >> 16) + b.peek(xb);
+        b.drop(xb);
+        uint32_t d;
+        GZ_LOOKUP(d, dt, kGzDistRoot, b);
+        if (((d >> 5) & 7) != kGzLen) return -1;
+        xb = (d >> 8) & 31;
+        const int64_t dist = (d >> 16) + b.peek(xb);
+        b.drop(xb);
+        if (dist > pos + window_len) return -1;
+        if (pos + len > cap) { short_of = -2; break; }
+        uint16_t* dst = o + pos;
+        const uint16_t* s = dst - dist;
+        if (dist >= 8) {
+          for (int64_t i = 0; i < len; i += 8) std::memcpy(dst + i, s + i, 16);
+        } else {
+          for (int64_t i = 0; i < len; ++i) dst[i] = s[i];
+        }
+        pos += len;
+      }
+      if (short_of) break;
+      if (b.exhausted()) { short_of = -3; break; }
+    }
+    good_bit = b.bitpos();
+    good_pos = pos;
+    if (final_block || good_bit >= stop_bit || pos >= soft_cap) {
+      *end_bit = good_bit;
+      *n_out = pos;
+      return final_block ? 1 : 0;
+    }
+  }
+  *end_bit = good_bit;
+  *n_out = good_pos;
+  return good_bit > start_bit ? 0 : short_of;
+}
+
+// Symbols -> bytes: a symbol under 256 is its byte, 256 + k is byte k of
+// ``window`` (kGzWindow bytes: the text before the symbols' first; null
+// where there is none, and a mark then reads 0).  *n_eol receives how many
+// of the bytes are ``eol`` (a text's line count, for whoever cuts it by
+// records).  Returns the CRC32 of the n bytes written.
+uint32_t hbam_deflate_resolve(const uint16_t* syms, int64_t n,
+                              const uint8_t* window, uint8_t* out,
+                              uint8_t eol, int64_t* n_eol) {
+  uint8_t lut[256 + kGzWindow];
+  for (int s = 0; s < 256; ++s) lut[s] = static_cast<uint8_t>(s);
+  if (window) std::memcpy(lut + 256, window, kGzWindow);
+  else std::memset(lut + 256, 0, kGzWindow);
+  int64_t eols = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint32_t s = syms[i];
+    const uint8_t b = lut[s < 256 + kGzWindow ? s : 0];
+    out[i] = b;
+    eols += b == eol;
+  }
+  if (n_eol) *n_eol = eols;
+  uint32_t crc = 0;
+  for (int64_t at = 0; at < n; at += int64_t{1} << 30) {
+    const size_t piece = static_cast<size_t>(
+        n - at < (int64_t{1} << 30) ? n - at : int64_t{1} << 30);
+#if defined(HBAM_USE_LIBDEFLATE)
+    crc = libdeflate_crc32(crc, out + at, piece);
+#else
+    crc = static_cast<uint32_t>(crc32(crc, out + at,
+                                      static_cast<uInt>(piece)));
+#endif
+  }
+  return crc;
+}
+
+// The CRC32 of A ++ B from crc(A), crc(B) and len(B) (zlib's).
+uint32_t hbam_crc32_combine(uint32_t crc_a, uint32_t crc_b, int64_t len_b) {
+  return static_cast<uint32_t>(
+      crc32_combine(crc_a, crc_b, static_cast<z_off_t>(len_b)));
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
 // Fused single-pass span decode: inflate + record walk + projection pack +
 // CRC fold in ONE streamed pass over the span, chunk-granular.
 //

@@ -10,13 +10,17 @@ while the file inflates (parallel/pipeline.py::_read_stats_impl).
 - a truncated member, a flipped CRC32, a wrong ISIZE and bytes that start no
   member each fail ``hbam seq-stats`` non-zero with no totals printed;
 - the text alive at once is bounded by the chunks in flight, not the file;
-- the verb runs ``plan.execute`` and builds its device step once.
+- the verb runs ``plan.execute`` and builds its device step once;
+- a file of two speculative chunks or more is inflated by several threads
+  (``_SpeculativeMembers``): the same bytes, the same refusals, whatever
+  blocks, members and headers the file is made of.
 """
 import contextlib
 import dataclasses
 import gzip
 import io
 import random
+import re
 import struct
 import zlib
 
@@ -25,12 +29,18 @@ import pytest
 from hadoop_bam_tpu.config import DEFAULT_CONFIG
 from hadoop_bam_tpu.formats.fastq import FastqError
 from hadoop_bam_tpu.obs import disable_tracing, enable_tracing
+from hadoop_bam_tpu.split import read_planners
 from hadoop_bam_tpu.split.read_planners import (
-    iter_gzip_text_chunks, iter_on_thread,
+    _SpeculativeMembers, gzip_speculation, iter_gzip_text_chunks,
+    iter_on_thread,
 )
+from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.metrics import MetricsContext
 from hadoop_bam_tpu.utils.pools import text_stream_window
-from hadoop_bam_tpu.utils.seekable import ByteSource
+from hadoop_bam_tpu.utils.seekable import ByteSource, BytesByteSource
+
+import hiseq_fastq_reference as H
+from test_deflate_native import reads_text
 
 import kgp3_reference as K
 
@@ -240,6 +250,22 @@ def test_closing_the_stream_early_stops_its_thread():
 N_LONG = 4400
 
 
+def speculation_budget(path: str, text_bytes: int) -> int:
+    """What the inflate workers of ``path``'s stream may hold besides the
+    chunks being tokenised (0 where its inflate stays serial): a chunk's
+    text is ``chunk`` compressed bytes at the file's ratio and up to a
+    block more, ``workers + 1`` of them decoded ahead at 2 B a symbol,
+    three resolved behind at 1 B."""
+    with open(path, "rb") as fh:
+        blob = fh.read()
+    how = gzip_speculation(BytesByteSource(blob))
+    if how is None:
+        return 0
+    chunk, workers = how
+    piece = 2 * chunk * text_bytes // len(blob) + (1 << 17)
+    return (2 * (workers + 1) + 3) * piece
+
+
 @pytest.fixture(scope="module")
 def long_file(tmp_path_factory):
     """50 grains of 16 KiB of text (and the same reads as a plain file)."""
@@ -269,7 +295,9 @@ def test_text_alive_is_bounded_by_the_chunks_in_flight(long_file):
     assert m.get("fastq.stream_chunks") >= 50
     assert m.get("fastq.inflated_bytes") == len(text)
     peak = m.get("fastq.stream_peak_text_bytes")
-    assert 0 < peak <= (text_stream_window() + 2) * grain < len(text) / 4
+    assert (text_stream_window() + 2) * grain < len(text) / 4
+    assert 0 < peak <= (text_stream_window() + 2) * grain \
+        + speculation_budget(gz, len(text))
     assert m.get("pipeline.records") == N_LONG
     assert m.get("fastq.inflate_busy_ns") > 0
     assert m.get("fastq.tokenize_busy_ns") > 0
@@ -299,10 +327,19 @@ def test_the_inflate_runs_on_its_own_thread_a_span_a_chunk(long_file):
         disable_tracing()
     spans = [(thread, args) for name, _ts, _dur, _tid, thread, args
              in rec.events() if name == "fastq.inflate_wall"]
-    assert {t for t, _a in spans} == {"hbam-inflate-stream"}
+    # a span a chunk on the stream's thread; where the file is inflated on
+    # several threads, a span a task on each of those too
+    workers = [(t, a) for t, a in spans if t != "hbam-inflate-stream"]
+    spans = [(t, a) for t, a in spans if t == "hbam-inflate-stream"]
     assert sum(a["bytes"] for _t, a in spans) == len(text)
     assert [a["chunk"] for _t, a in spans if a["bytes"]] \
         == list(range(m.get("fastq.stream_chunks")))
+    if gzip_speculation(BytesByteSource(open(gz, "rb").read())) is None:
+        assert not workers
+    else:
+        assert {t.rsplit("_", 1)[0] for t, _a in workers} == {"hbam-inflate"}
+        assert {a["stage"] for _t, a in workers} == {"speculate", "resolve"}
+        assert m.get("fastq.inflated_bytes_parallel") > 0
     tok = {thread for name, _ts, _dur, _tid, thread, _a in rec.events()
            if name == "fastq.tokenize_wall"}
     assert tok and all(t.startswith("hbam-decode") for t in tok)
@@ -406,7 +443,8 @@ def test_a_qseq_gz_streams_a_line_a_record(tmp_path):
     assert m.get("qseq.stream_members") == 2
     assert m.get("qseq.inflated_bytes") == len(text)
     assert 0 < m.get("qseq.stream_peak_text_bytes") \
-        <= (text_stream_window() + 2) * 32768
+        <= (text_stream_window() + 2) * 32768 \
+        + speculation_budget(gz, len(text))
     assert m.get("fastq.stream_chunks") == 0
     chunks = list(iter_gzip_text_chunks(gz, 1000, 1, fmt="qseq"))
     assert b"".join(chunks) == text
@@ -438,3 +476,426 @@ def test_the_object_api_keeps_its_results(long_file):
     (one,) = list(open_fastq(plain).iter_span_chunks(
         open_fastq(plain).spans(num_spans=1)[0], 65536))
     assert not one.streamed and one.text() == text
+
+
+# ---------------------------------------------------------------------------
+# several threads inside one member: the same bytes, the same refusals
+# ---------------------------------------------------------------------------
+
+needs_native = pytest.mark.skipif(not native.available(),
+                                  reason="no native library")
+
+
+def spec_chunks(blob: bytes, grain: int, lines: int, chunk: int = 4096,
+                workers: int = 3, fmt: str = "fastq", alive=None):
+    """The speculative producer's chunks at a chunk size of the test's
+    choosing (``iter_gzip_text_chunks`` picks it from the file's size)."""
+    gz = _SpeculativeMembers(BytesByteSource(blob), "mem.gz", fmt, chunk,
+                             workers, alive=alive)
+    try:
+        yield from gz.chunks(grain, lines)
+    finally:
+        gz.close()
+
+
+def hiseq_text(pairs: int, seed: int = 7, read: int = 0) -> bytes:
+    return b"".join(t for t, _a, _p in H.iter_chunks(seed, read, pairs))
+
+
+def far_text(n: int) -> bytes:
+    """Every record is the one 97 records (~26 KB) earlier with three bases
+    changed: matches reach across every chunk edge, nearly a window back."""
+    return reads_text(n, seed=17, back=97)
+
+
+def gzip_member(text: bytes, level: int = 4, strategy: int = 0,
+                flags: int = 0) -> bytes:
+    """One gzip member built by hand: FEXTRA (4), FNAME (8), FCOMMENT (16)
+    and FHCRC (2) as ``flags`` says."""
+    head = bytes([0x1f, 0x8b, 8, flags, 0, 0, 0, 0, 0, 3])
+    if flags & 4:
+        extra = b"AP\x05\x00hello" + b"ZZ\x00\x00"
+        head += struct.pack("<H", len(extra)) + extra
+    if flags & 8:
+        head += b"lane_R1_001.fastq\x00"
+    if flags & 16:
+        head += b"a comment\x00"
+    if flags & 2:
+        head += struct.pack("<H", zlib.crc32(head) & 0xffff)
+    c = zlib.compressobj(level, zlib.DEFLATED, -15, 8, strategy)
+    return head + c.compress(text) + c.flush() \
+        + struct.pack("<II", zlib.crc32(text), len(text) & 0xffffffff)
+
+
+HISEQ = hiseq_text(1200)
+
+
+def _planted(n: int) -> bytes:
+    """Incompressible bytes (level 0: stored blocks, the bytes verbatim)
+    laced with a real dynamic block's first bytes: a header parses there,
+    and what follows it is noise."""
+    c = zlib.compressobj(6, zlib.DEFLATED, -15)
+    head = (c.compress(HISEQ[:40_000]) + c.flush(zlib.Z_SYNC_FLUSH))[:200]
+    rng = random.Random(23)
+    out = bytearray(rng.getrandbits(8) for _ in range(n))
+    for at in range(3000, n - 300, 7000):
+        out[at:at + len(head)] = head
+    return bytes(out)
+
+
+def _parallel_case(kind: str):
+    """(gzip bytes, lines a record) of one parallel case."""
+    if kind.startswith("level"):
+        return gzip.compress(HISEQ, int(kind[5:])), 4
+    if kind == "stored":                # no dynamic block anywhere
+        return gzip_member(HISEQ, 0), 4
+    if kind == "fixed":
+        return gzip_member(HISEQ, 6, zlib.Z_FIXED), 4
+    if kind == "far_matches":
+        return gzip.compress(far_text(2500), 9), 4
+    if kind == "false_starts":
+        return gzip_member(_planted(150_000), 0), 1
+    if kind == "two_members":           # cut inside a record
+        return (gzip.compress(HISEQ[:100_001], 4)
+                + gzip.compress(HISEQ[100_001:], 6)), 4
+    if kind == "three_members":         # dynamic, stored, fixed blocks
+        return (gzip.compress(HISEQ[:180_001], 1)
+                + gzip_member(HISEQ[180_001:240_000], 0)
+                + gzip_member(HISEQ[240_000:], 9, zlib.Z_FIXED)), 4
+    if kind == "empty_members":
+        return (gzip.compress(b"") + gzip.compress(HISEQ[:5000], 4)
+                + gzip.compress(b"") + gzip.compress(HISEQ[5000:], 4)
+                + gzip.compress(b"")), 4
+    if kind == "header_fields":         # FEXTRA + FNAME + FCOMMENT + FHCRC
+        return (gzip_member(HISEQ[:90_000], 4, flags=4 | 8 | 16 | 2)
+                + gzip_member(HISEQ[90_000:], 4, flags=8)), 4
+    if kind == "bgzip":
+        return _members("bgzip", HISEQ), 4
+    assert kind == "qseq"
+    return gzip.compress(_qseq_text(3000), 4), 1
+
+
+PARALLEL_CASES = ["level1", "level4", "level6", "level9", "stored", "fixed",
+                  "far_matches", "false_starts", "two_members",
+                  "three_members", "empty_members", "header_fields", "bgzip",
+                  "qseq"]
+
+
+@needs_native
+@pytest.mark.parametrize("chunk", [1024, 4096, 30_000])
+@pytest.mark.parametrize("kind", PARALLEL_CASES)
+def test_several_threads_give_zlibs_bytes(kind, chunk):
+    blob, lines = _parallel_case(kind)
+    want = gzip.decompress(blob)
+    grain = 20_000
+    with MetricsContext() as m:
+        chunks = list(spec_chunks(blob, grain, lines, chunk,
+                                  fmt="qseq" if kind == "qseq" else "fastq"))
+        serial = list(read_planners._record_chunks(
+            read_planners._GzipMembers(BytesByteSource(blob), "mem.gz"),
+            grain, lines, "serial"))
+    assert b"".join(chunks) == want == b"".join(serial)
+    longest = max(len(ln) + 1 for ln in want.split(b"\n"))
+    # (the end of the file ends the last chunk wherever it is)
+    for c in chunks[:None if want.endswith(b"\n") else -1]:
+        assert c.endswith(b"\n") and c.count(b"\n") % lines == 0
+        assert len(c) <= max(grain, lines * longest)
+    fmt = "qseq" if kind == "qseq" else "fastq"
+    for name in ("stream_members", "inflated_bytes", "compressed_bytes"):
+        assert m.get(f"{fmt}.{name}") == m.get(f"serial.{name}"), name
+    assert m.get(f"{fmt}.compressed_bytes") == len(blob)
+    assert m.get(f"{fmt}.stream_chunks") == len(chunks)
+    assert m.get(f"{fmt}.inflate_busy_ns") > 0
+    par = m.get(f"{fmt}.inflated_bytes_parallel")
+    if kind in ("stored", "fixed", "false_starts"):
+        # no dynamic block exists: every stretch is decoded in order
+        assert par == 0
+        assert m.get(f"{fmt}.inflate_respeculated_chunks") \
+            == -(-len(blob) // chunk) - 1
+    elif kind == "bgzip":
+        assert 0 <= par < len(want)
+    elif kind == "three_members":
+        assert chunk > 4096 or 0 < par <= 180_001
+    elif kind != "empty_members" and chunk <= 4096:
+        assert par > len(want) // 2, par
+    if kind == "false_starts":
+        # the planted headers were found and decoded from, for nothing
+        found = [native.deflate_find_block(blob, 8 * at, 8 * (at + chunk))
+                 for at in range(chunk, len(blob), chunk)]
+        assert sum(f >= 0 for f in found) >= 3
+
+
+@needs_native
+@pytest.mark.parametrize("grain", [1, 64, RECORD_MAX, 1000, 65536, 1 << 22],
+                         ids=lambda g: f"grain{g}")
+def test_several_threads_cut_at_records_at_any_grain(grain):
+    """A record longer than the grain comes whole; otherwise no chunk
+    passes the grain, and the chunks are the one inflate's."""
+    blob = gzip.compress(TEXT, 4)
+    chunks = list(spec_chunks(blob, grain, 4, chunk=2048))
+    assert b"".join(chunks) == TEXT
+    assert chunks == list(read_planners._record_chunks(
+        read_planners._GzipMembers(BytesByteSource(blob), "mem.gz"),
+        grain, 4, "serial"))
+    for c in chunks:
+        assert c.startswith(b"@") and c.count(b"\n") % 4 == 0
+        assert len(c) <= grain or grain < RECORD_MAX
+        assert len(c) <= max(grain, RECORD_MAX + grain)
+    if grain == 1:
+        assert len(chunks) == 600       # a record a chunk
+    if grain >= len(TEXT):
+        assert len(chunks) == 1
+    # one line a record: the same text cut at any line end
+    lines = list(spec_chunks(blob, 64, 1, chunk=2048))
+    assert b"".join(lines) == TEXT and all(c.endswith(b"\n") for c in lines)
+
+
+def _damage_blob(blob: bytes, kind: str) -> bytes:
+    crc, isize = struct.unpack("<II", blob[-8:])
+    if kind == "truncated":
+        return blob[:len(blob) * 2 // 3]
+    if kind == "truncated_trailer":
+        return blob[:-3]
+    if kind == "flipped_crc":
+        return blob[:-8] + struct.pack("<II", crc ^ 1, isize)
+    if kind == "wrong_isize":
+        return blob[:-8] + struct.pack("<II", crc, isize + 1)
+    if kind == "flipped_byte":          # in the deflate stream
+        i = len(blob) // 2
+        return blob[:i] + bytes([blob[i] ^ 0x55]) + blob[i + 1:]
+    if kind == "second_member_truncated":
+        return blob + blob[:len(blob) // 2]
+    if kind == "second_member_flipped_crc":
+        return blob + blob[:-8] + struct.pack("<II", crc ^ 1, isize)
+    if kind == "bad_method":
+        return blob + blob[:2] + b"\x07" + blob[3:]
+    if kind == "header_crc":
+        good = gzip_member(HISEQ[:3000], 4, flags=2 | 8)
+        return blob + good[:12] + b"X" + good[13:]
+    assert kind == "trailing_garbage"
+    return blob + b"\x00" * 16
+
+
+DAMAGE = ["truncated", "truncated_trailer", "flipped_crc", "wrong_isize",
+          "flipped_byte", "second_member_truncated",
+          "second_member_flipped_crc", "bad_method", "header_crc",
+          "trailing_garbage"]
+
+
+@needs_native
+@pytest.mark.parametrize("chunk", [4096, 65536])
+@pytest.mark.parametrize("kind", DAMAGE)
+def test_several_threads_refuse_what_zlib_refuses(kind, chunk):
+    blob = _damage_blob(gzip.compress(HISEQ, 4), kind)
+    second = kind.startswith("second") or kind in (
+        "bad_method", "header_crc", "trailing_garbage")
+    got = []
+    with pytest.raises(FastqError, match="offset") as bad:
+        for c in spec_chunks(blob, 20_000, 4, chunk):
+            got.append(c)
+    # it names the member and its offset, as the one inflate does
+    with pytest.raises(FastqError) as serial:
+        for _ in read_planners._record_chunks(
+                read_planners._GzipMembers(BytesByteSource(blob), "mem.gz"),
+                20_000, 4, "serial"):
+            pass
+    member = re.search(r"gzip member -?\d+(?: \(offset \d+\))?|offset \d+, "
+                       r"after gzip member \d+", str(bad.value))
+    assert member and member.group(0) in str(serial.value)
+    # what was handed on before the fault is the text before it, in order
+    # (past a flipped byte it is what the bytes decode to, as zlib's is,
+    # until the decoder or the trailer refuses it)
+    text = b"".join(got)
+    if kind != "flipped_byte":
+        assert HISEQ.startswith(text[:len(HISEQ)])
+    if second:
+        assert len(text) > len(HISEQ) - 2 * 20_000
+    if kind in ("flipped_crc", "wrong_isize"):
+        assert "incorrect" in str(bad.value)
+    if kind.endswith("truncated") or kind == "truncated_trailer":
+        assert "truncated" in str(bad.value)
+
+
+@pytest.fixture(scope="module")
+def lane_file(tmp_path_factory):
+    """A file of several speculative chunks as ``iter_gzip_text_chunks``
+    sizes them (4,096 reads, ~340 KB of gzip: the benchmark's tiny file)."""
+    text = hiseq_text(4096, seed=11)
+    path = str(tmp_path_factory.mktemp("lane") / "lane_R1_001.fastq.gz")
+    with open(path, "wb") as fh:
+        fh.write(gzip.compress(text, 4))
+    return path, text
+
+
+@pytest.fixture
+def eight_cpus(monkeypatch):
+    """The path is chosen by the host's CPUs: make the test's host one
+    with CPUs to spare, whatever it runs on."""
+    monkeypatch.setattr(read_planners.os, "cpu_count", lambda: 8)
+
+
+@needs_native
+def test_the_file_decides_who_inflates(lane_file, eight_cpus, monkeypatch):
+    path, text = lane_file
+    size = len(open(path, "rb").read())
+    chunk, workers = gzip_speculation(BytesByteSource(open(path, "rb").read()))
+    assert (chunk, workers) == (65536, 2) and size >= 2 * chunk
+    with MetricsContext() as m:
+        chunks = list(iter_gzip_text_chunks(path, 1 << 22, 4))
+    assert b"".join(chunks) == text
+    assert m.get("fastq.inflated_bytes_parallel") > len(text) // 2
+    assert m.get("fastq.inflated_bytes") == len(text)
+    # a large file: chunks of the largest size, a quarter of the CPUs
+
+    big = BytesByteSource(open(path, "rb").read())
+    big.size = 1 << 30
+    assert gzip_speculation(big) == (read_planners._SPEC_CHUNK_MAX, 2)
+    monkeypatch.setattr(read_planners.os, "cpu_count", lambda: 13)
+    assert gzip_speculation(big)[1] == 3 and text_stream_window() == 8
+    monkeypatch.setattr(read_planners.os, "cpu_count", lambda: 8)
+    # a short file, a BGZF file, one CPU, no native library: one inflate
+    assert gzip_speculation(BytesByteSource(gzip.compress(TEXT, 4))) is None
+    bg = _members("bgzip", text)
+    assert len(bg) > 4 * chunk
+    assert gzip_speculation(BytesByteSource(bg)) is None
+    monkeypatch.setattr(read_planners.os, "cpu_count", lambda: 1)
+    assert gzip_speculation(BytesByteSource(open(path, "rb").read())) is None
+    monkeypatch.setattr(read_planners.os, "cpu_count", lambda: 8)
+    monkeypatch.setattr(native, "load", lambda: None)
+    assert gzip_speculation(BytesByteSource(open(path, "rb").read())) is None
+    with MetricsContext() as m:
+        serial = list(iter_gzip_text_chunks(path, 1 << 22, 4))
+    assert b"".join(serial) == text
+    assert m.get("fastq.inflated_bytes_parallel") == 0
+
+
+@needs_native
+@pytest.mark.parametrize("kind", ["flipped_byte", "flipped_crc",
+                                  "wrong_isize", "truncated",
+                                  "trailing_garbage"])
+def test_a_damaged_member_fails_the_scan_on_several_threads(
+        lane_file, eight_cpus, tmp_path, kind):
+    path, text = lane_file
+    bad = str(tmp_path / "bad_R1_001.fastq.gz")
+    with open(bad, "wb") as fh:
+        fh.write(_damage_blob(open(path, "rb").read(), kind))
+    assert gzip_speculation(BytesByteSource(open(bad, "rb").read()))
+    with MetricsContext() as m:
+        with pytest.raises(FastqError, match="offset"):
+            for _ in iter_gzip_text_chunks(bad, 65536, 4):
+                pass
+        assert m.get("fastq.inflated_bytes_parallel") > 0
+    rc, out, err = run_cli(["seq-stats", bad])
+    assert rc != 0
+    assert out == ""                    # no totals, not even `reads`
+    assert "error:" in err and "gzip member" in err or "start no" in err
+    # and the sound file's answer is the plain file's
+    rc, out, _ = run_cli(["seq-stats", path])
+    plain = str(tmp_path / "lane.fastq")
+    with open(plain, "wb") as fh:
+        fh.write(text)
+    assert rc == 0 and out == run_cli(["seq-stats", plain])[1]
+    assert out.startswith("reads\t4096\n")
+
+
+@needs_native
+def test_closing_the_stream_early_stops_its_workers():
+    import threading
+
+    before = {t.name for t in threading.enumerate()}
+    it = spec_chunks(gzip.compress(HISEQ, 4), 4096, 4, chunk=2048)
+    assert next(it).startswith(b"@")
+    assert any(t.name.startswith("hbam-inflate_")
+               for t in threading.enumerate())
+    it.close()
+    assert {t.name for t in threading.enumerate()
+            if t.name.startswith("hbam-inflate")} <= before
+
+
+class _Alive:
+    def __init__(self):
+        self.now = self.peak = 0
+        self._lock = __import__("threading").Lock()
+
+    def add(self, n):
+        with self._lock:
+            self.now += n
+            self.peak = max(self.peak, self.now)
+
+
+@needs_native
+def test_the_workers_buffers_are_counted_and_given_back():
+    blob = gzip.compress(HISEQ, 4)
+    alive = _Alive()
+    sizes = [len(c) for c in spec_chunks(blob, 20_000, 4, chunk=8192,
+                                         workers=3, alive=alive)]
+    assert sum(sizes) == len(HISEQ)
+    assert alive.now == 0               # everything handed on or freed
+    # at most workers + 1 chunks of symbols (2 B each) ahead, two pieces
+    # and a chunk's worth of text behind
+    piece = 8192 * len(HISEQ) // len(blob) + 40_000
+    assert 2 * piece < alive.peak <= (2 * 4 + 3) * piece + 20_000
+
+
+@needs_native
+def test_text_alive_does_not_grow_with_the_file(tmp_path, eight_cpus):
+    """Two files at the largest chunk size, one twice the other: the same
+    high-water mark of text and symbols alive."""
+    from hadoop_bam_tpu.parallel.pipeline import fastq_seq_stats_file
+
+    peaks = []
+    for pairs in (49_152, 98_304):
+        path = str(tmp_path / f"lane{pairs}_R1_001.fastq.gz")
+        c = zlib.compressobj(4, zlib.DEFLATED, 31)
+        n_text = 0
+        with open(path, "wb") as fh:
+            for t, _a, _p in H.iter_chunks(29, 0, pairs):
+                fh.write(c.compress(t))
+                n_text += len(t)
+            fh.write(c.flush())
+        how = gzip_speculation(BytesByteSource(open(path, "rb").read()))
+        assert how == (read_planners._SPEC_CHUNK_MAX, 2)
+        cfg = dataclasses.replace(DEFAULT_CONFIG, split_size=1 << 20)
+        with MetricsContext() as m:
+            got = fastq_seq_stats_file(path, config=cfg)
+        assert got["n_reads"] == pairs
+        assert m.get("fastq.inflated_bytes") == n_text
+        assert m.get("fastq.inflated_bytes_parallel") > 0.9 * n_text
+        peak = m.get("fastq.stream_peak_text_bytes")
+        assert 0 < peak <= (text_stream_window() + 2) * (1 << 20) \
+            + speculation_budget(path, n_text)
+        peaks.append(peak)
+    assert peaks[1] <= 1.25 * peaks[0]
+    assert peaks[1] < 0.6 * n_text
+
+
+@needs_native
+def test_many_workers_and_streams_at_once_lose_nothing():
+    """More workers than cores, three streams at once, the interpreter
+    switching threads every 10 us: every stream is the text, every buffer
+    counted is counted back (a lost update would leave a remainder)."""
+    import sys
+    import threading
+
+    blob = gzip.compress(HISEQ, 4) + gzip.compress(HISEQ[:50_000], 9)
+    want = HISEQ + HISEQ[:50_000]
+    results, alives = {}, [_Alive() for _ in range(3)]
+
+    def stream(i):
+        results[i] = b"".join(spec_chunks(blob, 30_000, 4, chunk=1024,
+                                          workers=16, alive=alives[i]))
+
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(target=stream, args=(i,)) for i in range(3)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(before)
+    assert [results.get(i) == want for i in range(3)] == [True] * 3
+    assert [a.now for a in alives] == [0, 0, 0]
+    assert all(a.peak > 0 for a in alives)

@@ -205,6 +205,46 @@ def test_seq_stats_on_the_gzip_the_plain_file_and_the_reference_agree(
     assert other is not None and "base histogram" in other
 
 
+@pytest.mark.parametrize("split_size", [None, 1 << 16])
+def test_the_answer_does_not_depend_on_who_inflates(tiny, monkeypatch,
+                                                    split_size):
+    """The tiny lane through the inflate workers (a host with CPUs to
+    spare, the native library) and through the one ``zlib`` inflate (the
+    library masked out): the same counts and, to the bit, the same two
+    means — the tiles the chip sees are the same, however the text is
+    cut."""
+    from hadoop_bam_tpu.config import DEFAULT_CONFIG
+    from hadoop_bam_tpu.parallel.pipeline import fastq_seq_stats_file
+    from hadoop_bam_tpu.split import read_planners
+    from hadoop_bam_tpu.utils import native
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
+    if not native.available():
+        pytest.skip("no native library")
+    paths, ref, _texts = tiny
+    cfg = DEFAULT_CONFIG if split_size is None \
+        else dataclasses.replace(DEFAULT_CONFIG, split_size=split_size)
+    monkeypatch.setattr(read_planners.os, "cpu_count", lambda: 8)
+    answers = {}
+    for who in ("workers", "one"):
+        if who == "one":
+            monkeypatch.setattr(native, "load", lambda: None)
+        for r, path in enumerate(paths):
+            with MetricsContext() as m:
+                got = fastq_seq_stats_file(path, config=cfg)
+            par = m.get("fastq.inflated_bytes_parallel")
+            assert par > 0 if who == "workers" else par == 0
+            assert m.get("fastq.inflated_bytes") == ref.text_bytes[r]
+            answers[who, r] = (int(got["n_reads"]),
+                               [int(c) for c in got["base_hist"]],
+                               float(got["mean_gc"]),
+                               float(got["mean_qual"]))
+    for r in range(2):
+        assert answers["workers", r] == answers["one", r]
+        assert answers["one", r][:2] == (ref.all[r].n,
+                                         ref.all[r].hist.tolist())
+
+
 def test_the_filter_leaves_the_reads_that_passed(tiny):
     from hadoop_bam_tpu.config import DEFAULT_CONFIG
     from hadoop_bam_tpu.parallel.pipeline import fastq_seq_stats_file

@@ -21,7 +21,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The subprocess body: build fixtures in memory and push them through every
 # native entry point (BGZF header walk, inflate, CRC, record walks,
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
-# kernel).
+# kernel, the DEFLATE block finder / symbol decoder / resolve).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -171,6 +171,77 @@ for typ, dt in _GT_DTYPES.items():
                 except BCFError:
                     pass
             assert got.tobytes() == want.tobytes()
+
+# DEFLATE inside a member (a gzip'd FASTQ's inflate workers): the block
+# finder over a buffer that ends anywhere (a header read past the end is
+# ASan's to see), the symbol decoder from every block start with its input
+# and its room cut short, the resolve, four threads decoding at once (the
+# fixed tables and the symbol tables are built on first use: TSan), and
+# the speculative stream itself at a chunk of 2 KiB
+import gzip, zlib
+from hadoop_bam_tpu.split import read_planners
+from hadoop_bam_tpu.utils.seekable import BytesByteSource
+ftext = b"".join(
+    b"@r%d\n%s\n+\n%s\n" % (i, bytes(rng.choice(b"ACGT") for _ in range(90)),
+                           bytes(rng.randint(35, 74) for _ in range(90)))
+    for i in range(1500))
+zc = zlib.compressobj(6, zlib.DEFLATED, -15)
+zraw = b"".join(zc.compress(ftext[a:a + 30000]) + zc.flush(zlib.Z_BLOCK)
+                for a in range(0, len(ftext), 30000)) + zc.flush()
+zfix = zlib.compressobj(6, zlib.DEFLATED, -15, 8, zlib.Z_FIXED)
+zfixed = zfix.compress(ftext[:20000]) + zfix.flush()
+W = native.DEFLATE_WINDOW
+sbuf = native.deflate_symbol_buffer(len(ftext))
+starts, at = [], 0
+while True:
+    at = native.deflate_find_block(np.frombuffer(zraw, np.uint8).copy(),
+                                   at, 8 * len(zraw))
+    if at < 0:
+        break
+    starts.append(at)
+    at += 1
+assert len(starts) >= 5, starts
+for cut in list(range(1, 300, 7)) + [len(zraw) - k for k in range(1, 40)]:
+    part = np.frombuffer(zraw[:cut], np.uint8).copy()   # ends on its last byte
+    native.deflate_find_block(part, 0, 8 * cut)
+    native.deflate_decode_symbols(part, 0, 1 << 40, 1 << 40, b"", sbuf)
+def decode_all(k):
+    for b0 in starts[k::4]:
+        rc, end, syms = native.deflate_decode_symbols(
+            zraw, b0, 1 << 40, 1 << 40, None,
+            native.deflate_symbol_buffer(len(ftext)))
+        assert rc == 1, rc
+        for room in (0, 1, 257, 5000):
+            rc2, _e, s2 = native.deflate_decode_symbols(
+                zraw, b0, 1 << 40, 1 << 40, None,
+                native.deflate_symbol_buffer(room))
+            assert rc2 in (0, -2) and s2.size <= room
+        out, crc, eols = native.deflate_resolve(
+            syms, ftext[:len(ftext) - syms.size])
+        assert out.tobytes() == ftext[len(ftext) - syms.size:]
+        assert crc == zlib.crc32(out.tobytes())
+    rc, _e, syms = native.deflate_decode_symbols(
+        zfixed, 0, 1 << 40, 1 << 40, b"", native.deflate_symbol_buffer(20000))
+    assert rc == 1 and syms.astype(np.uint8).tobytes() == ftext[:20000]
+ts = [threading.Thread(target=decode_all, args=(k,)) for k in range(4)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(120)
+    assert not t.is_alive()
+assert native.crc32_combine(zlib.crc32(ftext[:777]), zlib.crc32(ftext[777:]),
+                            len(ftext) - 777) == zlib.crc32(ftext)
+for blob in (gzip.compress(ftext, 4) + gzip.compress(ftext[:5000], 1),
+             gzip.compress(ftext, 4)[:-9]):
+    gzs = read_planners._SpeculativeMembers(
+        BytesByteSource(blob), "mem.gz", "fastq", 2048, 4)
+    try:
+        got_text = b"".join(gzs.chunks(10000, 4))
+        assert got_text == ftext + ftext[:5000]
+    except read_planners.FastqError as e:
+        assert "truncated" in str(e) and blob[-1:] != b"\0", e
+    finally:
+        gzs.close()
 
 # staging packer: the FeedPipeline's background pack thread races the
 # dispatching consumer over reused ring slots — drive it with a host
