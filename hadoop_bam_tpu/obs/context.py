@@ -144,3 +144,35 @@ def begin_span() -> Optional[Tuple["contextvars.Token", str, int, int]]:
 
 def end_span(token: "contextvars.Token") -> None:
     _CURRENT.reset(token)
+
+
+# ---------------------------------------------------------------------------
+# the execute clock: when the plan a feed belongs to began
+# ---------------------------------------------------------------------------
+
+# [perf_counter instant] of the innermost eager plan.execute of this
+# context, emptied by the first feed dispatch after it — what
+# feed.first_dispatch_wait (parallel/staging.FeedPipeline._account) is
+# measured from.  None outside an execute: a bare feed records nothing.
+_EXECUTE_STARTED: "contextvars.ContextVar[Optional[list]]" = \
+    contextvars.ContextVar("hbam_execute_started", default=None)
+
+
+@contextlib.contextmanager
+def execute_clock(t0: float) -> Iterator[None]:
+    """Stamp the block as one plan execution begun at ``t0``."""
+    tok = _EXECUTE_STARTED.set([t0])
+    try:
+        yield
+    finally:
+        _EXECUTE_STARTED.reset(tok)
+
+
+def take_execute_start() -> Optional[float]:
+    """The running execute's start, ONCE: the first caller after the
+    stamp gets it, later ones (and callers outside an execute) None."""
+    cell = _EXECUTE_STARTED.get()
+    if cell is None:
+        return None
+    t0, cell[0] = cell[0], None
+    return t0

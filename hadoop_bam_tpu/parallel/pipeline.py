@@ -47,6 +47,7 @@ from hadoop_bam_tpu.plan.executor import (  # noqa: F401 — re-exports
 )
 from hadoop_bam_tpu.plan.ir import SourceIR
 from hadoop_bam_tpu.formats.bam import SAMHeader
+from hadoop_bam_tpu.obs.trace import active_recorder
 from hadoop_bam_tpu.ops import inflate as inflate_ops
 from hadoop_bam_tpu.ops.flagstat import flagstat_from_columns
 from hadoop_bam_tpu.ops.unpack_bam import (
@@ -1060,13 +1061,35 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
 
     Without a config (or with both knobs off before any soft deadline
     exists) the await path is the plain blocking ``Future.result()``.
+
+    Every unit carries its own clock (``utils/pools.TaskStamps``, written
+    by the worker) and the consumer says what it waited for:
+
+    - a head that is NOT done when the consumer arrives is
+      ``feed.head_wait`` — a span on the pulling thread while a recorder
+      is active (the packer's, or the dispatch thread's under
+      ``variant_feed``'s peek), two clock reads otherwise — and at its
+      end, from the stamps alone, three walls: ``feed.head_queued`` (the
+      part before the head's ``started``: no pool thread was free),
+      ``feed.head_running`` (the rest: it was on a thread and not done)
+      and ``feed.ready_behind_head`` (the part during which a LATER unit
+      of the window had already finished: what a hand-off out of order
+      would not have waited).  A head that is done costs one ``done()``;
+    - every unit taken adds ``feed.units`` and, from its stamps, the
+      sums ``feed.unit_queued_ns`` / ``unit_run_ns`` / ``unit_held_ns``
+      (finished -> taken) and — of units run while a recorder was active,
+      whose thread usage the worker took — ``unit_cpu_ns`` /
+      ``unit_sys_ns``.  Of a speculated or re-submitted unit the
+      winner's stamps count.
     """
     from collections import deque
 
     from hadoop_bam_tpu.utils.resilient import call_with_retry
 
     it = iter(items)
-    dq: "deque[list]" = deque()        # entries: [item, fut, t0, spec'd]
+    # entries: [item, future, speculated?, index]; the future's stamps
+    # (submitted / started / finished, the run's rusage) are the unit's
+    dq: "deque[list]" = deque()
     # transient SUBMISSION failures (a saturated executor, an injected
     # pool.submit chaos fault) retry briefly instead of killing the
     # whole driver run — the task itself has its own failure policy
@@ -1106,11 +1129,11 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
             f.add_done_callback(_reap)
 
     def _await(entry) -> object:
-        """Resolve one entry under the defense policy (docstring)."""
+        """Resolve one entry under the defense policy (docstring); the
+        future that won is left in ``entry[1]``."""
         if timeout_s is None and latency is None:
             return entry[1].result()           # undefended fast path
-        # candidates: [future, deadline anchor, is_speculative, submit
-        # stamp]; the primary plus at most one speculative twin plus
+        # candidates: the primary plus at most one speculative twin plus
         # timeout re-submissions.  Two clocks on purpose:
         # - the DEADLINE anchor starts when this await begins (a decode
         #   that ran overlapped while earlier entries were consumed is
@@ -1120,13 +1143,13 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
         #   at the back of the same queue) and the soft deadline would
         #   speculate on tasks that never started (a twin queued behind
         #   the original can only lose);
-        # - the SUBMIT stamp feeds the latency histogram: turnaround,
-        #   which can only over-estimate, keeps the p95-derived soft
-        #   deadline conservative.
+        # - the future's SUBMIT stamp feeds the latency histogram:
+        #   turnaround, which can only over-estimate, keeps the
+        #   p95-derived soft deadline conservative.
         now = time.perf_counter()
-        # fields: [future, deadline anchor, is_spec, submit stamp,
+        # fields: [future, deadline anchor, is_spec,
         # first-observed-queued stamp (None until seen pending)]
-        cands = [[entry[1], now, False, entry[2], None]]
+        cands = [[entry[1], now, False, None]]
         resubmits = 0
         while True:
             for c in list(cands):
@@ -1147,18 +1170,20 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
                         raise
                     continue
                 if latency is not None:
-                    latency.observe(time.perf_counter() - c[3])
+                    latency.observe(time.perf_counter()
+                                    - c[0].stamps.submitted)
                 if c[2]:
                     METRICS.count("jobs.speculative_won")
                 for o in cands:
                     if o is not c:
                         _abandon(o[0])
+                entry[1] = c[0]
                 return out
             now = time.perf_counter()
             for c in cands:
                 if not c[0].running() and not c[0].done():
-                    if c[4] is None:
-                        c[4] = now
+                    if c[3] is None:
+                        c[3] = now
                     # still queued: hold the deadline anchor — but only
                     # within a bounded grace.  Unbounded holding would
                     # make a FULLY-wedged pool (every worker stuck, so
@@ -1166,7 +1191,7 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
                     # exact forever-hang this knob exists to end; a
                     # merely-backlogged pool drains within the grace
                     if timeout_s is None or \
-                            now - c[4] <= timeout_s * _QUEUED_GRACE:
+                            now - c[3] <= timeout_s * _QUEUED_GRACE:
                         c[1] = now
             if timeout_s is not None:
                 for c in list(cands):
@@ -1186,24 +1211,24 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
                         f"wedged") from None
                 resubmits += 1
                 METRICS.count("jobs.timeout_resubmits")
-                t = time.perf_counter()
-                cands.append([_submit(entry[0]), t, False, t, None])
+                cands.append([_submit(entry[0]), time.perf_counter(),
+                              False, None])
                 now = time.perf_counter()
             soft = latency.soft_deadline_s() if latency is not None \
                 else None
-            if soft is not None and not entry[3] and len(cands) == 1 \
+            if soft is not None and not entry[2] and len(cands) == 1 \
                     and now - cands[0][1] > soft:
-                entry[3] = True
+                entry[2] = True
                 METRICS.count("jobs.speculative_launched")
-                t = time.perf_counter()
-                cands.append([_submit(entry[0]), t, True, t, None])
+                cands.append([_submit(entry[0]), time.perf_counter(),
+                              True, None])
             # sleep until the nearest deadline (or a coarse slice that
             # keeps the undeadlined wait cheap), woken early by any
             # candidate completing
             waits = [0.25]
             if timeout_s is not None:
                 waits += [c[1] + timeout_s - now for c in cands]
-            if soft is not None and not entry[3]:
+            if soft is not None and not entry[2]:
                 waits += [cands[0][1] + soft - now]
             elif latency is not None and soft is None:
                 waits += [float(latency.min_s)]
@@ -1211,20 +1236,67 @@ def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
                     timeout=max(0.005, min(waits)),
                     return_when=cf.FIRST_COMPLETED)
 
+    def _head_walls(entry, t_arrive: float, traced: bool) -> dict:
+        """The wait that just ended, split by the stamps alone (nothing
+        polled while it ran): before the head's ``started`` it was queued,
+        after it running; from the earliest ``finished`` among the units
+        still in the window, a finished unit sat behind it."""
+        t_end = time.perf_counter()
+        waited = t_end - t_arrive
+        queued = min(max(entry[1].stamps.started - t_arrive, 0.0), waited)
+        done = [f for f in (e[1].stamps.finished for e in dq)
+                if f is not None and f < t_end]
+        behind = t_end - max(t_arrive, min(done)) if done else 0.0
+        if not traced:
+            METRICS.add_wall("feed.head_wait", waited)
+        for name, sec, t0 in (
+                ("feed.head_queued", queued, t_arrive),
+                ("feed.head_running", waited - queued, t_arrive + queued),
+                ("feed.ready_behind_head", behind, t_end - behind)):
+            if sec > 0.0:
+                METRICS.add_wall(name, sec, t0=t0)
+        return {"queued_s": queued, "running_s": waited - queued,
+                "ready_behind_s": behind, "behind_done": len(done)}
+
+    def _take(entry) -> object:
+        """The consumer takes the head unit (docstring: the head wait and
+        the unit's counters)."""
+        if entry[1].done():
+            out = _await(entry)
+        else:
+            t_arrive = time.perf_counter()
+            if active_recorder() is not None:
+                with METRICS.span("feed.head_wait", unit=entry[3]) as late:
+                    out = _await(entry)
+                    late.update(_head_walls(entry, t_arrive, True))
+            else:
+                out = _await(entry)
+                _head_walls(entry, t_arrive, False)
+        st = entry[1].stamps
+        sums = [("units", 1),
+                ("unit_queued_ns", (st.started - st.submitted) * 1e9),
+                ("unit_run_ns", (st.finished - st.started) * 1e9),
+                ("unit_held_ns", (time.perf_counter() - st.finished) * 1e9)]
+        if st.usage is not None:     # taken while a recorder was active
+            user, sys_ns = st.usage
+            sums += [("unit_cpu_ns", user + sys_ns), ("unit_sys_ns", sys_ns)]
+        for name, n in sums:
+            METRICS.count(f"feed.{name}", int(n))
+        return out
+
     try:
-        # entries: [item, future, submit stamp, speculated?] — the stamp
-        # feeds latency.observe in _await (the straggler histogram)
         for item in it:
-            dq.append([item, _submit(item), time.perf_counter(), False])
+            dq.append([item, _submit(item), False, len(dq)])
             if len(dq) >= window:
                 break
+        n_units = len(dq)
         while dq:
             entry = dq.popleft()
             for item in it:
-                dq.append([item, _submit(item), time.perf_counter(),
-                           False])
+                dq.append([item, _submit(item), False, n_units])
+                n_units += 1
                 break
-            yield _await(entry)
+            yield _take(entry)
     finally:
         for entry in dq:
             _abandon(entry[1])

@@ -40,6 +40,8 @@ partitioned by spans (all carry a profiler annotation while a recorder
 is active): the dispatch thread's by ``feed.wait_group`` +
 ``pipeline.dispatch_wall``; the packer's by ``feed.wait_rows`` +
 ``feed.wait_slot`` + ``staging.transfer_wait`` + ``staging.pack``.
+``feed.first_dispatch_wait`` is the one wall that starts outside the
+feed: from the ``plan.execute`` around it to its first dispatch.
 """
 from __future__ import annotations
 
@@ -55,6 +57,7 @@ from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
+from hadoop_bam_tpu.obs.context import take_execute_start
 from hadoop_bam_tpu.obs.trace import active_recorder
 from hadoop_bam_tpu.utils.metrics import METRICS
 
@@ -344,7 +347,9 @@ class FeedPipeline:
         # start and the native chunk poll all sit under next(it)).  With
         # a recorder active each pull is a span, so it carries a profiler
         # annotation; otherwise two clock reads a pull, summed into one
-        # add_wall a group
+        # add_wall a group.  Its child feed.head_wait (parallel/pipeline.
+        # _iter_windowed) says why: the next unit of the decode window
+        # was queued, was running, or was stuck in front of finished ones
         traced = active_recorder() is not None
         waited = 0.0
 
@@ -553,12 +558,20 @@ class FeedPipeline:
         n = None
         if self.count_bytes:
             n = sum(int(a.nbytes) for a in arrays) + int(counts.nbytes)
+        t_exec = take_execute_start()
         t0 = time.perf_counter()
         with METRICS.span(f"{self.name}.dispatch_wall") as span_args:
             if n is not None:
                 span_args["bytes"] = n
             yield
         dt = time.perf_counter() - t0
+        if t_exec is not None:
+            # plan.execute's start to the first group's dispatch: the
+            # plan, a variant feed's peek, the first units' decode and
+            # the first pack.  Once an execute (take_execute_start), and
+            # never for a feed that no plan.execute stands around
+            METRICS.add_wall("feed.first_dispatch_wait", t0 - t_exec,
+                             t0=t_exec)
         self._device_wall += dt
         self.dispatches += 1
         METRICS.count_per_device(f"{self.name}.device_rows", counts)

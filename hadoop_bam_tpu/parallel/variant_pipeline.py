@@ -486,7 +486,12 @@ def variant_feed(cols_stream, n_dev: int, cap: int,
     the dict stream re-threaded as key-ordered array tuples for
     ``fp.feed``/``fp.stream``.  The one place the
     stats driver and VcfDataset.tensor_batches share their wiring, so
-    schema handling cannot drift between them."""
+    schema handling cannot drift between them.
+
+    The peek runs on the CALLER's thread (the dispatch thread of a scan)
+    before the feed exists, so none of the feed's own waits holds it: a
+    windowed stream's ``feed.head_wait`` (``_iter_windowed``) times it on
+    that thread, and ``feed.first_dispatch_wait`` holds it whole."""
     stream = iter(cols_stream)
     first = next(stream, None)
     if first is None:
@@ -703,8 +708,8 @@ def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
                                     balance=True, fmt="vcf")
     if fp is not None:
         def dispatch(arrays, counts):
-            with METRICS.span("vcf.dispatch_wall"):
-                handles = dispatch_group(dict(zip(keys, arrays)), counts)
+            # timed by FeedPipeline._account (pipeline. / vcf.dispatch_wall)
+            handles = dispatch_group(dict(zip(keys, arrays)), counts)
             METRICS.count("pipeline.records", int(counts.sum()))
             return handles  # in-flight: the ring waits on them
 

@@ -33,6 +33,7 @@ may still demote mid-run.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, Iterator, Optional, Tuple
 
 from hadoop_bam_tpu.config import (
@@ -40,7 +41,9 @@ from hadoop_bam_tpu.config import (
 )
 from hadoop_bam_tpu.plan.ir import PlanIR, SourceIR, TensorOpIR, op_node
 from hadoop_bam_tpu.utils.errors import PlanError
-from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.obs import context as trace_ctx
+from hadoop_bam_tpu.obs.trace import active_recorder
+from hadoop_bam_tpu.utils.metrics import METRICS, process_usage
 
 
 # ---------------------------------------------------------------------------
@@ -224,9 +227,25 @@ def execute(plan: PlanIR, *, config: Optional[HBamConfig] = None,
         # sinks' full-run walls.  The iteration's own stage spans
         # (cohort.*) already cover the work.
         return runner(plan, cfg, kw)
+    # the execute clock (what feed.first_dispatch_wait is measured from)
+    # and, while a recorder is active, what the whole process — every
+    # thread, the native workers and the text inflaters included — spent
+    # while the runner ran (not always: under the chip host's sandboxed
+    # kernel the two getrusage calls cost a 0.27 s scan ~1 %, and a serve
+    # chunk would pay them too)
+    t0 = time.perf_counter()
     with METRICS.span("plan.execute_wall", sink=plan.sink.kind,
-                      fmt=plan.source.fmt):
-        return runner(plan, cfg, kw)
+                      fmt=plan.source.fmt), trace_ctx.execute_clock(t0):
+        u0 = process_usage() if active_recorder() is not None else None
+        try:
+            return runner(plan, cfg, kw)
+        finally:
+            if u0 is not None:
+                METRICS.count("exec.wall_ns",
+                              int((time.perf_counter() - t0) * 1e9))
+                for name, a, b in zip(("cpu_user_ns", "cpu_sys_ns"),
+                                      u0, process_usage()):
+                    METRICS.count(f"exec.{name}", b - a)
 
 
 def _runner_for(plan: PlanIR):
@@ -327,8 +346,6 @@ def _run_chunk_columns(plan: PlanIR, cfg: HBamConfig, kw: Dict):
     the ``(columns, cache_cost)`` pair ``ChunkCache.get_or_compute``
     stores — cost None on a quarantined chunk, so a healed transient
     fault re-decodes on the next query instead of caching emptiness."""
-    import time
-
     import numpy as np
 
     from hadoop_bam_tpu.parallel.pipeline import decode_with_retry

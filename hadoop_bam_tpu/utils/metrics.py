@@ -32,12 +32,43 @@ import contextvars
 import threading
 import time
 from collections import defaultdict
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, Optional, Tuple
 
 from hadoop_bam_tpu.obs import context as trace_ctx
 from hadoop_bam_tpu.obs import flight as _flight
 from hadoop_bam_tpu.obs.hist import Histogram
 from hadoop_bam_tpu.obs.trace import active_recorder
+
+try:
+    import resource
+    _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+except ImportError:                         # no rusage on this platform
+    resource = None
+    _RUSAGE_THREAD = None
+
+
+def _usage(who) -> Tuple[int, int]:
+    ru = resource.getrusage(who)
+    return int(ru.ru_utime * 1e9), int(ru.ru_stime * 1e9)
+
+
+def thread_usage() -> Tuple[int, int]:
+    """(user ns, system ns) of the calling thread so far:
+    ``getrusage(RUSAGE_THREAD)``, and where the platform has none,
+    ``time.thread_time_ns`` as user time.  The kernel's user + system
+    total is exact; how it splits the two is sampled by its tick."""
+    if _RUSAGE_THREAD is None:
+        return time.thread_time_ns(), 0
+    return _usage(_RUSAGE_THREAD)
+
+
+def process_usage() -> Tuple[int, int]:
+    """The same two of the whole process, every thread that ever ran in
+    it — native workers included (``getrusage(RUSAGE_SELF)``)."""
+    if resource is None:
+        return time.process_time_ns(), 0
+    return _usage(resource.RUSAGE_SELF)
+
 
 # span-args size guard: a pathological path/region/repr string passed as
 # a span attr must not bloat the trace ring or the flight recorder —

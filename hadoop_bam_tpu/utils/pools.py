@@ -24,8 +24,11 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from hadoop_bam_tpu.obs.trace import active_recorder
 from hadoop_bam_tpu.resilience import chaos
-from hadoop_bam_tpu.utils.metrics import METRICS
+from hadoop_bam_tpu.utils.metrics import (
+    METRICS, current_metrics, thread_usage,
+)
 
 _LOCK = threading.Lock()
 _POOL: Optional[cf.ThreadPoolExecutor] = None
@@ -62,24 +65,48 @@ def decode_pool_size(config=None) -> int:
     return _POOL_SIZE
 
 
-def _timed_task(fn, t_submit: float, args, kwargs):
-    from hadoop_bam_tpu.utils.metrics import current_metrics
+class TaskStamps:
+    """One pool task's own clock, written by the worker and read by
+    whoever holds the future (``fut.stamps``): ``submitted``, ``started``
+    and ``finished`` are ``time.perf_counter`` instants (None until
+    reached); ``usage`` is the run's ``thread_usage`` difference (user
+    ns, system ns), taken only while a trace recorder is active."""
+    __slots__ = ("submitted", "started", "finished", "usage")
 
+    def __init__(self, submitted: float):
+        self.submitted = submitted
+        self.started: Optional[float] = None
+        self.finished: Optional[float] = None
+        self.usage: Optional[Tuple[int, ...]] = None
+
+
+def _timed_task(fn, stamps: TaskStamps, args, kwargs):
     m = current_metrics()
-    t0 = time.perf_counter()
+    t0 = stamps.started = time.perf_counter()
     # queue wait + run durations as log-bucketed histograms: the pool is
     # SHARED across drivers, so p95 task_wait is the direct saturation
     # signal (a deep wait distribution means the pool, not the device,
-    # is the bottleneck) — a flat timer cannot show that
-    m.observe("pool.task_wait_s", t0 - t_submit)
+    # is the bottleneck) — a flat timer cannot show that.  The consumer's
+    # side of it is feed.head_queued (parallel/pipeline._iter_windowed):
+    # how long the unit it needed NEXT sat in that queue.  The same two
+    # clock reads are the task's stamps
+    m.observe("pool.task_wait_s", t0 - stamps.submitted)
     # chaos point ON THE WORKER thread (pool.submit fires on the
     # submitter's): a "delay" fault here wedges a worker mid-task —
     # the exact hang shape the per-future timeout exists to surface
     chaos.fire("pool.task")
+    # the thread's rusage only while a recorder is active: under the chip
+    # host's sandboxed kernel the two calls a task — 6 us each on an idle
+    # process — cost a 0.27 s flagstat scan of 77 units 3-5 % (they are
+    # made with the interpreter lock held; PERF.md section 6, PR 36)
+    u0 = thread_usage() if active_recorder() is not None else None
     try:
         return fn(*args, **kwargs)
     finally:
-        m.observe("pool.task_run_s", time.perf_counter() - t0)
+        if u0 is not None:
+            stamps.usage = tuple(b - a for a, b in zip(u0, thread_usage()))
+        t1 = stamps.finished = time.perf_counter()
+        m.observe("pool.task_run_s", t1 - t0)
 
 
 def result_with_timeout(fut: cf.Future, timeout_s: Optional[float],
@@ -138,14 +165,16 @@ def submit(pool: cf.ThreadPoolExecutor, fn, *args,
     # saturated/failing executor would (no-op unless armed)
     chaos.fire("pool.submit", priority=priority)
     ctx = contextvars.copy_context()
-    t_submit = time.perf_counter()
+    stamps = TaskStamps(time.perf_counter())
     if priority == "fg":
-        return pool.submit(ctx.run, _timed_task, fn, t_submit, args, kwargs)
-    fut: cf.Future = cf.Future()
-    METRICS.count("pool.bg_submitted")
-    with _BG_LOCK:
-        _BG_QUEUE.append((pool, fut, ctx, fn, t_submit, args, kwargs))
-    _pump_background()
+        fut = pool.submit(ctx.run, _timed_task, fn, stamps, args, kwargs)
+    else:
+        fut = cf.Future()
+        METRICS.count("pool.bg_submitted")
+        with _BG_LOCK:
+            _BG_QUEUE.append((pool, fut, ctx, fn, stamps, args, kwargs))
+        _pump_background()
+    fut.stamps = stamps
     return fut
 
 
@@ -166,11 +195,11 @@ def background_limit(pool: cf.ThreadPoolExecutor) -> int:
     return max(1, size // 4)
 
 
-def _run_background(fut: cf.Future, ctx, fn, t_submit, args, kwargs) -> None:
+def _run_background(fut: cf.Future, ctx, fn, stamps, args, kwargs) -> None:
     if not fut.set_running_or_notify_cancel():
         return
     try:
-        fut.set_result(ctx.run(_timed_task, fn, t_submit, args, kwargs))
+        fut.set_result(ctx.run(_timed_task, fn, stamps, args, kwargs))
     except BaseException as e:  # noqa: BLE001 — crosses the thread
         fut.set_exception(e)
 
@@ -185,12 +214,12 @@ def _pump_background() -> None:
                 return
             item = _BG_QUEUE.popleft()
             _BG_RUNNING[0] += 1
-        _pool, fut, ctx, fn, t_submit, args, kwargs = item
+        _pool, fut, ctx, fn, stamps, args, kwargs = item
 
-        def task(fut=fut, ctx=ctx, fn=fn, t_submit=t_submit, args=args,
+        def task(fut=fut, ctx=ctx, fn=fn, stamps=stamps, args=args,
                  kwargs=kwargs):
             try:
-                _run_background(fut, ctx, fn, t_submit, args, kwargs)
+                _run_background(fut, ctx, fn, stamps, args, kwargs)
             finally:
                 with _BG_LOCK:
                     _BG_RUNNING[0] -= 1

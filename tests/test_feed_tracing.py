@@ -9,7 +9,12 @@ named step programs and the sort's phase spans.
   the benchmark's trace reduction finds them on the profiler's clock;
 - ``decode.native_busy_ns`` is bounded by workers x wall;
 - every step builder jits a program named ``hbam_<step>`` and counts its
-  builds under ``steps.built.hbam_<step>``.
+  builds under ``steps.built.hbam_<step>``;
+- a unit of the decode window carries its own clock: the wait for a head
+  that is not done is ``feed.head_wait`` = ``feed.head_queued`` +
+  ``feed.head_running``, ``feed.ready_behind_head`` the part a finished
+  follower sat through; ``feed.unit_*`` sum the units' stamps;
+  ``feed.first_dispatch_wait`` and ``exec.*`` are once a ``plan.execute``.
 """
 import os
 import sys
@@ -23,7 +28,7 @@ from hadoop_bam_tpu.formats.bamio import BamWriter
 from hadoop_bam_tpu.obs import disable_tracing, enable_tracing
 from hadoop_bam_tpu.ops import inflate as inflate_ops
 from hadoop_bam_tpu.parallel.staging import FeedPipeline, TileSpec
-from hadoop_bam_tpu.utils.metrics import MetricsContext
+from hadoop_bam_tpu.utils.metrics import MetricsContext, thread_usage
 
 from fixtures import make_header, make_records
 
@@ -175,7 +180,9 @@ def test_feed_and_cli_spans_land_in_the_profiler_trace(bam, tmp_path,
                                                        capsys):
     import jax
 
+    from hadoop_bam_tpu.parallel.pipeline import _iter_windowed
     from hadoop_bam_tpu.tools.cli import main
+    from hadoop_bam_tpu.utils.pools import decode_pool
 
     sys.path.insert(0, ROOT)
     try:
@@ -192,6 +199,11 @@ def test_feed_and_cli_spans_land_in_the_profiler_trace(bam, tmp_path,
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
         assert main(["summarize", bam]) == 0
+        # a head the consumer certainly waits for (the scan's own span
+        # starts may all be done when the packer comes for them)
+        assert list(_iter_windowed(decode_pool(), range(2),
+                                   lambda i: time.sleep(0.02) or i, 2)) \
+            == [0, 1]
     finally:
         jax.profiler.stop_trace()
     capsys.readouterr()
@@ -199,7 +211,7 @@ def test_feed_and_cli_spans_land_in_the_profiler_trace(bam, tmp_path,
              trace_reduce.host_spans(trace_reduce.load(trace_dir))}
     assert {"feed.wait_group", "feed.wait_rows", "staging.pack",
             "pipeline.dispatch_wall", "cli.main_wall",
-            "plan.execute_wall"} <= names
+            "plan.execute_wall", "feed.head_wait"} <= names
 
 
 def test_cli_main_wall_covers_the_parser_and_the_plan(bam, capsys):
@@ -480,3 +492,339 @@ def test_serve_counts_its_filter_launches(bam):
     assert launches >= 3
     # one launch a tile group, counted beside the span that times them
     assert m.wall_calls["serve.filter_wall"] <= launches
+
+
+# ---------------------------------------------------------------------------
+# D. a unit's life through the decode window
+# ---------------------------------------------------------------------------
+
+TRACED = pytest.mark.parametrize("traced", [False, True],
+                                 ids=["untraced", "traced"])
+HEAD_S, FOLLOWER_S = 0.30, 0.02
+LATE_S = 0.06       # what a sleep may overrun on a machine of six workers
+
+
+@pytest.fixture()
+def wide_pool():
+    import concurrent.futures as cf
+
+    pool = cf.ThreadPoolExecutor(max_workers=8)
+    yield pool
+    pool.shutdown(wait=False, cancel_futures=True)
+
+
+def _windowed(pool, items, fn, window, traced, **kw):
+    """Drain one ``_iter_windowed`` under its own MetricsContext; returns
+    (results, wall timers, the context, the head waits' ring events)."""
+    from hadoop_bam_tpu.parallel.pipeline import _iter_windowed
+
+    rec = enable_tracing() if traced else None
+    with MetricsContext() as m:
+        out = list(_iter_windowed(pool, items, fn, window, **kw))
+    waits = [e for e in rec.events() if e[0] == "feed.head_wait"] \
+        if rec is not None else []
+    return out, m.snapshot()["wall_timers"], m, waits
+
+
+def _slow_head(i, ends=None):
+    time.sleep(HEAD_S if i == 0 else FOLLOWER_S)
+    if ends is not None:
+        ends[i] = time.perf_counter()
+    return i
+
+
+@TRACED
+def test_a_slow_head_with_fast_followers_books_their_lead(wide_pool, traced):
+    ends = {}
+    out, w, m, waits = _windowed(wide_pool, range(4),
+                                 lambda i: _slow_head(i, ends), 4, traced)
+    assert out == [0, 1, 2, 3]
+    # from the first follower's end to the head's, as the sleeps really
+    # ended; the consumer wakes a little after the head does
+    lead = ends[0] - min(ends[1], ends[2], ends[3])
+    assert lead > 0.5 * (HEAD_S - FOLLOWER_S)
+    assert lead - 0.005 <= w["feed.ready_behind_head"] <= lead + LATE_S
+    assert w["feed.ready_behind_head"] <= w["feed.head_wait"]
+    # the followers were done when they were taken: one wait, the head's
+    assert m.wall_calls["feed.head_wait"] == 1
+    if traced:
+        (ev,) = waits
+        args = ev[5]
+        assert args["unit"] == 0 and args["behind_done"] == 3
+        assert args["ready_behind_s"] == pytest.approx(
+            w["feed.ready_behind_head"])
+        assert args["queued_s"] + args["running_s"] == pytest.approx(ev[2],
+                                                                     rel=0.05)
+
+
+@TRACED
+def test_head_queued_plus_running_is_the_head_wait(wide_pool, traced):
+    _, w, _, _ = _windowed(wide_pool, range(8), _slow_head, 4, traced)
+    parts = w.get("feed.head_queued", 0.0) + w["feed.head_running"]
+    assert parts == pytest.approx(w["feed.head_wait"], rel=0.05)
+    assert w["feed.head_running"] >= HEAD_S - LATE_S
+
+
+@pytest.mark.parametrize("workers", [1, 8], ids=["one_thread", "wide"])
+def test_head_queued_is_a_head_no_thread_was_free_for(workers):
+    """A pool hands its tasks out in order, so the head — the oldest of
+    the window — is queued only behind work that is not the window's: here
+    another job's task, on the pool when the scan begins."""
+    import concurrent.futures as cf
+
+    slept = []
+    pool = cf.ThreadPoolExecutor(max_workers=workers)
+    try:
+        pool.submit(_nap, 0.15, slept)
+        out, w, _, _ = _windowed(pool, range(4),
+                                 lambda i: time.sleep(FOLLOWER_S) or i, 4,
+                                 traced=False)
+    finally:
+        pool.shutdown(wait=False, cancel_futures=True)
+    assert out == [0, 1, 2, 3]
+    if workers == 1:
+        (_asked, busy_s), = slept    # as long as the other job really took
+        assert busy_s - LATE_S <= w["feed.head_queued"] <= busy_s + LATE_S
+    else:
+        # a free thread picks the head up as fast as it wakes
+        assert w.get("feed.head_queued", 0.0) < 0.02
+        assert w["feed.head_running"] > 0.0
+
+
+@TRACED
+def test_a_head_that_is_done_on_arrival_records_nothing(wide_pool, traced):
+    def items():
+        # every later item is late, and so is the stream's end: each
+        # head was done long before the consumer came for it
+        for i in range(8):
+            if i:
+                time.sleep(0.02)
+            yield i
+        time.sleep(0.02)
+
+    out, w, m, waits = _windowed(wide_pool, items(), lambda i: i, 4, traced)
+    assert out == list(range(8)) and not waits
+    assert not {"feed.head_wait", "feed.head_queued", "feed.head_running",
+                "feed.ready_behind_head"} & set(w)
+    assert m.get("feed.units") == 8
+    # ... and the units were held, finished, while the consumer was away
+    assert m.get("feed.unit_held_ns") > 7 * 0.02e9
+
+
+@TRACED
+def test_under_a_feed_the_head_walls_lie_inside_wait_rows(wide_pool, traced):
+    from hadoop_bam_tpu.parallel.pipeline import _iter_windowed
+
+    def decode(_i):
+        time.sleep(SLOW_S)
+        return (np.ones((N_DEV * CAP, 4), np.uint8),)
+
+    rec = enable_tracing() if traced else None
+    fp = FeedPipeline(N_DEV, CAP, (TileSpec((4,), np.uint8),), block_n=4,
+                      balance=True)
+    with MetricsContext() as m:
+        # a window of one (the head and the unit submitted as it is
+        # taken): nearly every head is waited for
+        n = fp.feed(_iter_windowed(wide_pool, range(GROUPS), decode, 1),
+                    lambda arrays, counts: None)
+    w = m.snapshot()["wall_timers"]
+    assert n == GROUPS and m.get("feed.units") == GROUPS
+    head = w.get("feed.head_queued", 0.0) + w["feed.head_running"]
+    # a head has run since the unit before it was taken, so a wait is a
+    # part of SLOW_S: a quarter of the sleeps is a floor, not the figure
+    assert 0.25 * GROUPS * SLOW_S < head <= 1.02 * w["feed.wait_rows"]
+    assert "feed.first_dispatch_wait" not in w   # no plan.execute around it
+    if traced:
+        threads = {e[4] for e in rec.events() if e[0] == "feed.head_wait"}
+        assert threads == {"hbam-feed-pack"}
+
+
+def _cpu_time_is_fine_grained():
+    """Whether this kernel charges a thread its CPU time as it runs: spin
+    20 ms and look.  Linux does; a sandboxed kernel (the chip host's
+    ``runsc``) reports it in 10 ms steps, and there only presence and
+    order can be asserted of the rusage counters, not amounts."""
+    u0, t0 = sum(thread_usage()), time.perf_counter()
+    while time.perf_counter() - t0 < 0.02:
+        pass
+    return 0.012e9 <= sum(thread_usage()) - u0 <= 0.03e9
+
+
+@TRACED
+def test_unit_counters_sum_what_the_units_stamps_say(wide_pool, traced):
+    def fn(i):
+        c_end, t_end = time.thread_time() + 0.01, time.perf_counter() + 0.01
+        # 10 ms on the CPU, and of the wall where CPU time comes in steps
+        while time.thread_time() < c_end or time.perf_counter() < t_end:
+            pass
+        time.sleep(0.01)                        # 10 ms off it
+        return i
+
+    out, _, m, _ = _windowed(wide_pool, range(12), fn, 4, traced)
+    assert out == list(range(12)) and m.get("feed.units") == 12
+    run = m.get("feed.unit_run_ns")
+    assert run >= 12 * 0.018e9
+    assert m.get("feed.unit_queued_ns") > 0
+    assert m.get("feed.unit_held_ns") >= 0
+    counters = m.snapshot()["counters"]
+    if not traced:
+        # the threads' rusage is taken only while a recorder is active
+        assert not {"feed.unit_cpu_ns", "feed.unit_sys_ns"} & set(counters)
+        return
+    cpu = counters["feed.unit_cpu_ns"]
+    assert 0 <= counters["feed.unit_sys_ns"] <= cpu
+    if _cpu_time_is_fine_grained():
+        assert cpu >= 12 * 0.008e9 and run >= cpu * 0.95
+
+
+@pytest.mark.parametrize("defence", ["speculated", "resubmitted"])
+def test_a_twin_or_a_resubmit_is_one_unit_with_the_winners_stamps(
+        wide_pool, defence):
+    import dataclasses
+    import threading
+
+    from hadoop_bam_tpu.config import DEFAULT_CONFIG
+
+    release = threading.Event()
+    lock = threading.Lock()
+    seen = set()
+    n, straggler = 32, 30
+
+    def fn(i):
+        with lock:
+            first = i not in seen
+            seen.add(i)
+        if i == straggler and first:
+            release.wait(20)        # the first copy never ends in time
+            return i
+        time.sleep(0.005)
+        return i
+
+    if defence == "speculated":
+        cfg, wait_s = dataclasses.replace(
+            DEFAULT_CONFIG, straggler_min_s=0.05,
+            straggler_multiplier=2.0), 0.05
+        counter = "jobs.speculative_won"
+    else:
+        cfg, wait_s = dataclasses.replace(
+            DEFAULT_CONFIG, pool_task_timeout_s=0.25,
+            speculative_decode=False), 0.25
+        counter = "jobs.timeout_resubmits"
+    try:
+        out, w, m, _ = _windowed(wide_pool, range(n), fn, 4, traced=False,
+                                 config=cfg)
+    finally:
+        release.set()
+    assert out == list(range(n)) and m.get(counter) >= 1
+    assert m.get("feed.units") == n
+    # the loser's stamps have no end yet; the winner ran 5 ms, and began
+    # only once the defence had waited: that wait is the head's queue
+    assert m.get("feed.unit_run_ns") < n * 0.05e9
+    assert w["feed.head_queued"] >= wait_s * 0.9
+
+
+@pytest.fixture(scope="module")
+def small_vcf(tmp_path_factory):
+    import test_variant_pipeline as tv
+
+    path = str(tmp_path_factory.mktemp("feedtrace_vcf") / "v.vcf")
+    with open(path, "w") as f:
+        f.write(tv.HEADER_TEXT)
+        for r in tv._make_records(600):
+            f.write(r.to_line() + "\n")
+    return path
+
+
+@pytest.fixture(scope="module")
+def small_fastqs(tmp_path_factory):
+    d = tmp_path_factory.mktemp("feedtrace_fq")
+    rng = np.random.default_rng(11)
+    paths = []
+    for mate in (1, 2):
+        path = str(d / f"r{mate}.fastq")
+        with open(path, "w") as f:
+            for i in range(3000):
+                seq = "".join(rng.choice(list("ACGT"), 40))
+                f.write(f"@r{i}/{mate}\n{seq}\n+\n{'I' * 40}\n")
+        paths.append(path)
+    return paths
+
+
+def _execute(verb, bam, small_vcf, small_fastqs):
+    """One ``plan.execute`` a file behind the verb, with tiles small
+    enough that a scan dispatches several groups."""
+    from hadoop_bam_tpu.parallel.pipeline import (
+        DecodeGeometry, PayloadGeometry, fastq_seq_stats_file, flagstat_file,
+    )
+    from hadoop_bam_tpu.parallel.variant_pipeline import (
+        VariantGeometry, variant_stats_file,
+    )
+
+    if verb == "summarize":
+        assert flagstat_file(
+            bam, geometry=DecodeGeometry(tile_records=256))["total"] == 3000
+        return 1
+    if verb == "vcf-stats":
+        out = variant_stats_file(small_vcf, geometry=VariantGeometry(
+            tile_records=32, n_samples=5))
+        assert out["n_variants"] == 600
+        return 1
+    for path in small_fastqs:
+        out = fastq_seq_stats_file(path, geometry=PayloadGeometry(
+            max_len=64, tile_records=256, block_n=256))
+        assert out["n_reads"] == 3000
+    return len(small_fastqs)
+
+
+@pytest.mark.parametrize("verb", ["summarize", "vcf-stats", "seq-stats"])
+def test_first_dispatch_wait_is_once_an_execute(verb, bam, small_vcf,
+                                                small_fastqs):
+    _execute(verb, bam, small_vcf, small_fastqs)        # compile outside
+    with MetricsContext() as m:
+        executes = _execute(verb, bam, small_vcf, small_fastqs)
+    w = m.snapshot()["wall_timers"]
+    assert m.get("plan.executions") == executes
+    assert m.wall_calls["pipeline.dispatch_wall"] > executes
+    assert m.wall_calls["feed.first_dispatch_wait"] == executes
+    assert 0.0 < w["feed.first_dispatch_wait"] < w["plan.execute_wall"]
+    if verb == "vcf-stats":
+        # the peek's wait is on the calling thread, outside the feed
+        assert w["feed.first_dispatch_wait"] >= w.get("vcf.plan_wall", 0.0)
+
+
+def test_exec_counters_rise_by_one_executes_worth(bam, small_vcf,
+                                                  small_fastqs):
+    _execute("summarize", bam, small_vcf, small_fastqs)
+    with MetricsContext() as m:
+        _execute("summarize", bam, small_vcf, small_fastqs)
+    # the process's rusage is taken only while a recorder is active
+    assert not [k for k in m.snapshot()["counters"] if k.startswith("exec.")]
+    enable_tracing()
+    with MetricsContext() as m:
+        _execute("summarize", bam, small_vcf, small_fastqs)
+        once = dict(m.snapshot()["counters"])
+        wall = m.snapshot()["wall_timers"]["plan.execute_wall"]
+        _execute("summarize", bam, small_vcf, small_fastqs)
+        twice = m.snapshot()["counters"]
+    assert once["exec.wall_ns"] == pytest.approx(wall * 1e9, rel=0.02)
+    cpu = once["exec.cpu_user_ns"] + once["exec.cpu_sys_ns"]
+    assert 0 <= cpu <= (os.cpu_count() or 1) * once["exec.wall_ns"] * 1.5
+    assert twice["exec.wall_ns"] > once["exec.wall_ns"]
+    for name in ("exec.cpu_user_ns", "exec.cpu_sys_ns"):
+        assert twice[name] >= once[name] >= 0, name
+    if _cpu_time_is_fine_grained():
+        assert cpu > 0
+        assert twice["exec.cpu_user_ns"] > once["exec.cpu_user_ns"]
+
+
+def test_vcf_dispatch_wall_counts_a_dispatch_once(bam, small_vcf,
+                                                  small_fastqs):
+    _execute("vcf-stats", bam, small_vcf, small_fastqs)
+    with MetricsContext() as m:
+        _execute("vcf-stats", bam, small_vcf, small_fastqs)
+    w = m.snapshot()["wall_timers"]
+    groups = m.wall_calls["pipeline.dispatch_wall"]
+    assert m.wall_calls["vcf.dispatch_wall"] == groups > 1
+    # one is the span's own wall, the other the clock reads around it
+    assert w["vcf.dispatch_wall"] == pytest.approx(
+        w["pipeline.dispatch_wall"], rel=0.05, abs=groups * 1e-4)
