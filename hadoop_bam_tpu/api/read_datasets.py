@@ -21,6 +21,7 @@ from hadoop_bam_tpu.split.read_planners import (
     read_fasta_span, read_fastq_span,
 )
 from hadoop_bam_tpu.split.spans import FileByteSpan
+from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.seekable import scoped_byte_source
 
@@ -432,17 +433,43 @@ def fastq_text_to_payload_tiles(text: bytes, seq_stride: int,
                                 qual_offset: int = 33
                                 ) -> Tuple[np.ndarray, np.ndarray,
                                            np.ndarray]:
-    """Vectorized FASTQ span -> payload tiles, no per-read Python objects.
+    """FASTQ span -> payload tiles, no per-read Python objects.
 
     The stats drivers only need (packed bases, qualities, lengths); going
     through parse_fastq costs a SequencedFragment (with run-metadata name
-    parsing) per read and dominates the FASTQ pipeline wall clock.  This
-    path tokenizes the whole span with NumPy: newline scan -> line table ->
-    4-line record grid -> one clamped gather per payload matrix.
+    parsing) per read and dominates the FASTQ pipeline wall clock.  With
+    the native library the span is one pass over its bytes with the
+    interpreter lock released (``utils/native.py::fastq_tokenize``: find a
+    record's four lines, check them, code and pack, pad the row).  Without
+    it, and for any text the pass refuses, the NumPy twin below runs: the
+    same tiles byte for byte, and the one place the refusals are worded.
+    ``fastq.tokenize_native_records`` / ``fastq.tokenize_numpy_records``
+    count whose rows a span's were.
 
     Validation matches parse_fastq's strictness where cheap (4n lines,
     '@'/'+' leads, SEQ/QUAL length equality); it raises the same FastqError.
     """
+    if native.load() is not None:
+        tiles = native.fastq_tokenize(
+            text, _NIBBLE_CODE, seq_stride, qual_stride, max_len,
+            qual_offset)
+        if tiles is not None:
+            METRICS.count("fastq.tokenize_native_records", tiles[2].size)
+            return tiles
+    tiles = _fastq_text_to_payload_tiles_numpy(
+        text, seq_stride, qual_stride, max_len, qual_offset)
+    METRICS.count("fastq.tokenize_numpy_records", tiles[2].size)
+    return tiles
+
+
+def _fastq_text_to_payload_tiles_numpy(text: bytes, seq_stride: int,
+                                       qual_stride: int, max_len: int,
+                                       qual_offset: int
+                                       ) -> Tuple[np.ndarray, np.ndarray,
+                                                  np.ndarray]:
+    """``fastq_text_to_payload_tiles`` in NumPy: newline scan -> line
+    table -> 4-line record grid -> one clamped gather per payload
+    matrix."""
     from hadoop_bam_tpu.formats.fastq import FastqError
 
     buf = np.frombuffer(text, dtype=np.uint8)

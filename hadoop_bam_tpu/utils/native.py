@@ -207,6 +207,13 @@ def load() -> Optional[ctypes.CDLL]:
             i8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64, ctypes.c_int32,
             ctypes.c_int32, ctypes.c_int64, i8sp, ctypes.c_int64,
             ctypes.c_int64]
+        lib.hbam_fastq_count_lines.restype = ctypes.c_int64
+        lib.hbam_fastq_count_lines.argtypes = [i8p, ctypes.c_int64]
+        lib.hbam_fastq_tokenize.restype = ctypes.c_int32
+        lib.hbam_fastq_tokenize.argtypes = [
+            i8p, ctypes.c_int64, i8p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int32, i8p, ctypes.c_int64, i8p, ctypes.c_int64, i32p,
+            ctypes.c_int64]
         u16p = ctypes.POINTER(ctypes.c_uint16)
         lib.hbam_deflate_find_block.restype = ctypes.c_int64
         lib.hbam_deflate_find_block.argtypes = [
@@ -475,6 +482,45 @@ def bcf_gt_dosage(buf: np.ndarray, rows: np.ndarray, offs: np.ndarray,
     if rc:
         raise BCFError(f"GT vector of record {rc - 1} of its layout group "
                        "overruns the span")
+
+
+def fastq_tokenize(text, nibble: np.ndarray, seq_stride: int,
+                   qual_stride: int, max_len: int, qual_offset: int
+                   ) -> "Optional[tuple[np.ndarray, np.ndarray, np.ndarray]]":
+    """A FASTQ chunk's ``text`` -> its (seq [n, seq_stride] u8 of
+    ``nibble[base]`` codes two a byte, qual [n, qual_stride] u8 re-based
+    by ``qual_offset``, lengths [n] i32 = min(read length, max_len)) in
+    one native pass over the bytes, the interpreter lock released: the
+    tiles of ``api/read_datasets.py::fastq_text_to_payload_tiles``, byte
+    for byte.  The pass writes every byte of every row, so the tiles are
+    minted uninitialised and nothing else is allocated.  ``None`` where it
+    refuses the text — lines that are not 4n, a record without its ``@``
+    or ``+``, SEQ and QUAL of unequal length, under ``qual_offset != 33``
+    a quality outside Phred 0..93 anywhere in a field — or the arguments
+    (the ranges are the pass's to check): the caller's NumPy twin then
+    raises what it always raised."""
+    lib = load()
+    assert lib is not None
+    buf = _src_u8(text)
+    if nibble.dtype != np.uint8 or nibble.size != 256 \
+            or not nibble.flags.c_contiguous or buf.dtype != np.uint8 \
+            or buf.ndim != 1 or not buf.flags.c_contiguous:
+        raise ValueError("fastq_tokenize wants contiguous u8 text and a "
+                         "256-entry u8 code table")
+    p_text = _ptr(buf, ctypes.c_uint8)
+    lines = int(lib.hbam_fastq_count_lines(p_text, int(buf.size)))
+    if lines % 4:
+        return None
+    n = lines // 4
+    seq = np.empty((n, seq_stride), dtype=np.uint8)
+    qual = np.empty((n, qual_stride), dtype=np.uint8)
+    lengths = np.empty(n, dtype=np.int32)
+    rc = int(lib.hbam_fastq_tokenize(
+        p_text, int(buf.size), _ptr(nibble, ctypes.c_uint8), int(max_len),
+        int(qual_offset), int(qual_offset != 33), _ptr(seq, ctypes.c_uint8),
+        int(seq_stride), _ptr(qual, ctypes.c_uint8), int(qual_stride),
+        _ptr(lengths, ctypes.c_int32), n))
+    return (seq, qual, lengths) if rc == 0 else None
 
 
 # A DEFLATE window, and the symbols ``deflate_decode_symbols`` writes where

@@ -306,27 +306,45 @@ def stream_window_cap() -> int:
 
 def text_inflate_workers() -> int:
     """Threads that inflate a compressed text STREAM from inside its
-    members (``split/read_planners.py::_SpeculativeMembers``): a quarter
-    of the host's CPUs, at least 2 — one worker's two stages are no faster
-    than the one ``zlib`` inflate they replace.  A worker feeds ~0.65 M
-    FASTQ records a second and the tokenisers take under 1 M from all of
-    them (PERF.md section 6, PR 35), so more workers only crowd the
-    tokenisers."""
-    return max(2, (os.cpu_count() or 1) // 4)
+    members (``split/read_planners.py::_SpeculativeMembers``): a third of
+    the host's CPUs, at least 2 — one worker's two stages are no faster
+    than the one ``zlib`` inflate they replace — and at most 4.  Since
+    FASTQ's tokenise is a native pass (PR 37) the inflate is that
+    stream's whole cost a record (~1,400 ns of a core against the
+    tokenise's ~130), so the workers set the pace up to what the stream's
+    ONE in-order thread hands on: ~2.0 M FASTQ records/s, reached with
+    four workers.  That plateau is the stream thread's, not a share of
+    the CPUs, so the cap is a count.  The probes this rests on (PERF.md
+    section 6, PR 37; 13 CPUs, records/s of whole FASTQ scans): 3 workers
+    1.75 M, 4 1.98 M, 6 2.00 M, 8 1.99 M, 10 1.96 M; the stream alone,
+    nothing downstream: 1.64 / 1.91 / 2.06 / 2.03 M with 3 / 4 / 6 / 8 —
+    flat from four on, each worker past them only 0.3 points of
+    ``fastq.resident_text_share``."""
+    return min(4, max(2, (os.cpu_count() or 1) // 3))
 
 
 def text_stream_window() -> int:
     """Most chunks of a compressed text STREAM tokenised at once: the
-    host's CPUs less the stream's inflate workers and two more (the
-    stream's own thread; the packer and the dispatch thread, which mostly
-    wait), at least 2.  A chunk's text is in memory already, so its
+    host's CPUs less a quarter of them (at least 2) and two more, at
+    least 2 — on every host what it was before FASTQ's tokenise became a
+    native pass (PR 37).  A chunk's text is in memory already, so its
     tokenise never waits for a read and more of them in flight than cores
-    only queue.  A tokenise costs four to five times the CPU of the
-    inflate that feeds it, so with the inflate on several threads the
-    tokenisers set the pace and get the larger share.  The window is also
-    what bounds a streamed scan's memory: window + 2 chunks, and what the
+    only queue.  The native pass never fills the window (a chunk is
+    tokenised in ~2 ms and the next arrives ~8 ms later: 2, 3, 4, 6, 7 or
+    8 slots read the same rate and the same text alive), so for it the
+    slots cost nothing.  The window is for the streams that still
+    tokenise in NumPy — QSEQ, FASTQ on a host without the native library
+    or on the object path — where a tokenise costs four to five times the
+    CPU of the inflate that feeds it, the tokenisers set the pace and the
+    inflate workers mostly wait.  Their probe (PERF.md section 6, PR 37;
+    13 CPUs, window x workers, M records/s): FASTQ's NumPy twin 8 x 3
+    0.81, 7 x 4 0.83, 8 x 4 0.93, 2 x 4 0.54; ``.qseq.gz`` 0.63, 0.60,
+    0.71, 0.42; the twin behind one serial inflate 8: 0.67, 7: 0.68, 2:
+    0.53 (PR 35's: 12 in flight no more than 8).  The window is also what
+    bounds a streamed scan's memory: window + 2 chunks, and what the
     inflate workers hold ahead of them."""
-    return max(2, (os.cpu_count() or 1) - text_inflate_workers() - 2)
+    cpus = os.cpu_count() or 1
+    return max(2, cpus - max(2, cpus // 4) - 2)
 
 
 class SpanBuffer:

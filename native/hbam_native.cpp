@@ -592,6 +592,113 @@ int64_t hbam_bcf_gt_dosage(const uint8_t* buf, int64_t buf_len,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
+// FASTQ text -> payload tiles in one pass (the text reads' tokenise + 4-bit
+// pack; api/read_datasets.py::fastq_text_to_payload_tiles is the NumPy twin
+// and the oracle).  The line rules are the twin's ``_scan_lines``: a line
+// ends at '\n', one '\r' before it is not part of it, a last line with no
+// '\n' counts unless it is empty once its '\r' is gone.  No threads: the
+// callers' pool threads run it with the interpreter lock released.
+// ---------------------------------------------------------------------------
+namespace {
+
+// The line that starts at ``pos`` of text[0, n): [pos, *end) without its
+// line end; returns where the next line starts, or -1 where there is no
+// line at ``pos``.
+inline int64_t fastq_line(const uint8_t* text, int64_t n, int64_t pos,
+                          int64_t* end) {
+  if (pos >= n) return -1;
+  const void* nl = std::memchr(text + pos, '\n', static_cast<size_t>(n - pos));
+  const int64_t e = nl ? static_cast<const uint8_t*>(nl) - text : n;
+  *end = e - (e > pos && text[e - 1] == '\r');
+  if (!nl && *end == pos) return -1;     // a last line of "\r" alone
+  return nl ? e + 1 : n;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Lines of text[0, n) by the rules above (a FASTQ chunk holds lines / 4
+// records: the caller sizes the tiles from it).
+int64_t hbam_fastq_count_lines(const uint8_t* text, int64_t n) {
+  if (n <= 0) return 0;
+  int64_t lines = 0;
+  for (int64_t i = 0; i < n;) {         // 32-bit lanes a block: it vectorises
+    const int64_t m = n - i < 4096 ? n - i : 4096;
+    uint32_t c = 0;
+    for (int64_t j = 0; j < m; ++j) c += text[i + j] == '\n';
+    lines += c;
+    i += m;
+  }
+  if (text[n - 1] == '\n') return lines;
+  const bool lone_cr = text[n - 1] == '\r' && (n == 1 || text[n - 2] == '\n');
+  return lines + !lone_cr;
+}
+
+// Every record of the FASTQ text[0, n) into row i of three tiles: seq
+// [rows, seq_stride] (``nibble[base]`` codes, two a byte, the first in the
+// high nibble), qual [rows, qual_stride] (the byte less ``qual_offset``,
+// not below 0), lengths [rows] = min(read length, max_len); what a row
+// keeps is cut at max_len and at its stride, and every byte of every row is
+// written.  Returns 0 when text[0, n) was exactly ``rows`` records, else
+// what it refuses, rows written before it left as they are:
+//   -1 arguments it cannot take          -2 lines that are not 4 x rows
+//   -3 a record without its '@' or '+'   -4 SEQ and QUAL of unequal length
+//   -5 (``guard`` set) a quality outside Phred 0..93 anywhere in a field.
+// Nothing is read outside text[0, n) or written outside the three tiles.
+int32_t hbam_fastq_tokenize(const uint8_t* text, int64_t n,
+                            const uint8_t* nibble, int64_t max_len,
+                            int64_t qual_offset, int32_t guard,
+                            uint8_t* seq, int64_t seq_stride, uint8_t* qual,
+                            int64_t qual_stride, int32_t* lengths,
+                            int64_t rows) {
+  if (n < 0 || rows < 0 || max_len < 0 || max_len > INT32_MAX ||
+      seq_stride < 0 || qual_stride < 0 || qual_offset < 0 ||
+      qual_offset > 255)
+    return -1;
+  const uint8_t off = static_cast<uint8_t>(qual_offset);
+  int64_t pos = 0;
+  for (int64_t i = 0;; ++i) {
+    int64_t s[4], e[4];
+    for (int k = 0; k < 4; ++k) {
+      s[k] = pos;
+      pos = fastq_line(text, n, pos, &e[k]);
+      if (pos < 0) return k == 0 && i == rows ? 0 : -2;
+    }
+    if (i >= rows) return -2;
+    if (text[s[0]] != '@' || text[s[2]] != '+') return -3;
+    const int64_t len = e[1] - s[1];
+    if (len != e[3] - s[3]) return -4;
+    const uint8_t* b = text + s[1];
+    const uint8_t* q = text + s[3];
+    if (guard) {
+      uint8_t bad = 0;
+      for (int64_t j = 0; j < len; ++j)
+        bad |= static_cast<uint8_t>(q[j] < off) |
+               static_cast<uint8_t>(q[j] - off > 93);
+      if (bad) return -5;
+    }
+    const int64_t keep = len < max_len ? len : max_len;
+    lengths[i] = static_cast<int32_t>(keep);
+    uint8_t* srow = seq + i * seq_stride;
+    const int64_t pairs = keep / 2 < seq_stride ? keep / 2 : seq_stride;
+    for (int64_t j = 0; j < pairs; ++j)
+      srow[j] = static_cast<uint8_t>(nibble[b[2 * j]] << 4 | nibble[b[2 * j + 1]]);
+    int64_t sk = pairs;
+    if ((keep & 1) && sk < seq_stride)
+      srow[sk++] = static_cast<uint8_t>(nibble[b[keep - 1]] << 4);
+    std::memset(srow + sk, 0, static_cast<size_t>(seq_stride - sk));
+    uint8_t* qrow = qual + i * qual_stride;
+    const int64_t qk = keep < qual_stride ? keep : qual_stride;
+    for (int64_t j = 0; j < qk; ++j)
+      qrow[j] = q[j] > off ? static_cast<uint8_t>(q[j] - off) : 0;
+    std::memset(qrow + qk, 0, static_cast<size_t>(qual_stride - qk));
+  }
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
 // DEFLATE decoded INSIDE one gzip member, on many cores (the stream of
 // split/read_planners.py; Rapidgzip, arXiv 2308.08955; pugz): a sequencer's
 // .fastq.gz is one member with no index, so a worker that starts mid-member

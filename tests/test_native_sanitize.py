@@ -21,7 +21,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The subprocess body: build fixtures in memory and push them through every
 # native entry point (BGZF header walk, inflate, CRC, record walks,
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
-# kernel, the DEFLATE block finder / symbol decoder / resolve).
+# kernel, the FASTQ tokenise + pack, the DEFLATE block finder / symbol
+# decoder / resolve).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -171,6 +172,56 @@ for typ, dt in _GT_DTYPES.items():
                 except BCFError:
                     pass
             assert got.tobytes() == want.tobytes()
+
+# FASTQ text -> payload tiles in one pass: the tiles of the NumPy twin on a
+# text that ends on its buffer's last byte; the same text cut at every byte
+# of its first records and its last (mid-name, mid-read, inside a CRLF: a
+# line end looked for past the cut is ASan's to see) — the pass takes the
+# cut text or refuses it, the twin agrees; no newline at all, only
+# newlines, n = 0, rows whose strides cut them; four threads at once
+from hadoop_bam_tpu.api import read_datasets as rds
+from hadoop_bam_tpu.formats.fastq import FastqError
+def fq_rec(i, eol, qlo=33):
+    ln = rng.randint(0, 40)
+    return (b"@r%d" % i + eol
+            + bytes(rng.choice(b"ACGTNacgt") for _ in range(ln)) + eol + b"+"
+            + eol + bytes(rng.randint(qlo, qlo + 41) for _ in range(ln)) + eol)
+def fq_check(text, seq_stride=12, qual_stride=24, max_len=23, off=33):
+    own = np.frombuffer(bytes(text), np.uint8).copy()    # ends on its last byte
+    got = native.fastq_tokenize(own, rds._NIBBLE_CODE, seq_stride,
+                                qual_stride, max_len, off)
+    try:
+        want = rds._fastq_text_to_payload_tiles_numpy(
+            bytes(text), seq_stride, qual_stride, max_len, off)
+    except FastqError:
+        want = None
+    assert (got is None) == (want is None), bytes(text[-40:])
+    if got is not None:
+        for g, w in zip(got, want):
+            assert g.tobytes() == w.tobytes()
+for eol in (b"\n", b"\r\n"):
+    fq = b"".join(fq_rec(i, eol) for i in range(60))
+    fq_check(fq)
+    for cut in list(range(0, 200)) + list(range(len(fq) - 120, len(fq))):
+        fq_check(fq[:cut])
+    fq_check(fq, 3, 5, 40)
+    fq_check(fq, 0, 0, 0)
+    fq_check(fq, off=64)                                 # the guard refuses
+    fq_check(b"".join(fq_rec(i, eol, 64) for i in range(60)), off=64)
+for odd in (b"", b"\n", b"\r", b"\n" * 64, b"\r\n" * 8, b"@" * 300,
+            b"@a\nACGT\n+\nIIII", b"@a\nACGT\n+\n", b"@\n\n+\n\n"):
+    fq_check(odd)
+fq_ok = []
+def fq_many():
+    for _ in range(20):
+        fq_check(fq)
+    fq_ok.append(1)
+ts = [threading.Thread(target=fq_many) for _ in range(4)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(60)
+assert len(fq_ok) == 4
 
 # DEFLATE inside a member (a gzip'd FASTQ's inflate workers): the block
 # finder over a buffer that ends anywhere (a header read past the end is
