@@ -708,8 +708,9 @@ def bench_vcf(path: str):
             "value": round(meas, 1), "unit": "variants/s",
             "vs_baseline": round(meas / base, 3),
             # wall-clock union spans per stage (Metrics.wall_timer):
-            # inflate = BGZF span read, tokenize = grid tokenizer,
-            # dosage_pack = GT columns, dispatch = device_put + step
+            # inflate = BGZF span read, tokenize = the text tokeniser,
+            # gt_dosage = its one pass over the bytes (line bounds + GT
+            # columns), dispatch = device_put + step
             "vcf_stage_seconds": vcf_stages}
 
 

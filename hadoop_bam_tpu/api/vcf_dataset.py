@@ -8,6 +8,7 @@ spans, and yields records or SoA ``VariantBatch``es per span.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Iterator, List, Optional, Union
 
 import numpy as np
@@ -17,12 +18,14 @@ from hadoop_bam_tpu.api.dispatch import VCFContainer, sniff_vcf_container
 from hadoop_bam_tpu.formats import bgzf
 from hadoop_bam_tpu.formats.bcfio import read_bcf_header
 from hadoop_bam_tpu.formats.vcf import (
-    VCFHeader, VariantBatch, VcfRecord, read_vcf_header_text,
+    VCFHeader, VariantBatch, VcfRecord, read_vcf_header_bgzf,
+    read_vcf_header_text,
 )
 from hadoop_bam_tpu.split.planners import plan_text_spans, read_text_span
 from hadoop_bam_tpu.split.spans import FileByteSpan, FileVirtualSpan
 from hadoop_bam_tpu.split.vcf_planners import (
-    plan_bcf_spans, plan_bgzf_text_spans, read_bcf_span, read_bgzf_text_span,
+    bgzf_text_span, plan_bcf_spans, plan_bgzf_text_spans, read_bcf_span,
+    read_bgzf_text_span,
 )
 from hadoop_bam_tpu.utils.seekable import as_byte_source
 
@@ -50,14 +53,7 @@ class VcfDataset:
                 header, _ = read_vcf_header_text(src.pread)
                 return header
             if self.container is VCFContainer.VCF_BGZF:
-                r = bgzf.BGZFReader(src)
-
-                def read_chunk(off: int, size: int) -> bytes:
-                    r.seek_voffset(0)
-                    r.read(off)  # positions are tiny (header-sized)
-                    return r.read(size)
-                header, _ = read_vcf_header_text(read_chunk)
-                return header
+                return read_vcf_header_bgzf(src)
             if self.container is VCFContainer.VCF_GZIP:
                 import gzip
                 text = gzip.decompress(src.pread(0, src.size))
@@ -111,6 +107,18 @@ class VcfDataset:
             with open(self.path, "rb") as f:
                 return gzip.decompress(f.read())
         return read_text_span(self.path, span)
+
+    @contextlib.contextmanager
+    def span_text(self, span: Span):
+        """``with ds.span_text(span) as text``: the span's text as
+        ``read_span_text`` gives it, without the copy where the reader
+        can lend its buffer (a BGZF VCF: a view of a leased span buffer,
+        good until the ``with`` ends)."""
+        if self.container is VCFContainer.VCF_BGZF:
+            with bgzf_text_span(self.path, span) as text:
+                yield text
+        else:
+            yield self.read_span_text(span)
 
     # -- span read (hb/VCFRecordReader / hb/BCFRecordReader) -----------------
     def read_span(self, span: Span) -> List[VcfRecord]:

@@ -301,38 +301,53 @@ def read_vcf_header_text(read_chunk) -> Tuple[VCFHeader, int]:
     hb/util/VCFHeaderReader.java, which every task re-reads from file start.
     """
     buf = bytearray()
-    off = 0
+    off = pos = 0
     while True:
         got = read_chunk(off, 1 << 16)
         if not got:
             break
         buf += got
         off += len(got)
-        # stop once a complete non-# line exists
-        end = _header_end(buf)
+        # stop once a complete non-# line exists; the scan resumes at the
+        # first line it has not seen whole (a call set's header is
+        # megabytes of ##contig lines)
+        end, pos = _header_end(buf, pos)
         if end is not None:
             return VCFHeader.from_text(buf[:end].decode()), end
-    end = _header_end(buf, at_eof=True)
+    end, _ = _header_end(buf, pos, at_eof=True)
     if end is None:
         raise VCFError("no #CHROM line found")
     return VCFHeader.from_text(buf[:end].decode()), end
 
 
-def _header_end(buf: bytes, at_eof: bool = False) -> Optional[int]:
-    pos = 0
+def read_vcf_header_bgzf(src) -> VCFHeader:
+    """The header of a BGZF-compressed VCF: its blocks read once, in
+    order (the header reader asks for consecutive chunks; 2,504 sample
+    names are ~30 KB of header, a call set's ##contig lines megabytes)."""
+    from hadoop_bam_tpu.formats import bgzf
+
+    r = bgzf.BGZFReader(src)
+    r.seek_voffset(0)
+    # the header reader asks for consecutive chunks: the reader's own
+    # position is the offset
+    return read_vcf_header_text(lambda _off, size: r.read(size))[0]
+
+
+def _header_end(buf: bytes, pos: int = 0, at_eof: bool = False
+                ) -> Tuple[Optional[int], int]:
+    """(offset of the first data line, or None while it is not known;
+    where the next call's scan starts: the line at ``pos`` on)."""
     n = len(buf)
     while pos < n:
         nl = buf.find(b"\n", pos)
         if nl < 0:
             if at_eof and buf[pos:pos + 1] != b"#":
-                return pos
-            if at_eof:
-                return n
-            return None
+                return pos, pos
+            return (n if at_eof else None), pos
         if buf[pos:pos + 1] != b"#":
-            return pos
+            return pos, pos
         pos = nl + 1
-    return n if at_eof else None
+    return (n if at_eof else None), pos
 
 
 # ---------------------------------------------------------------------------

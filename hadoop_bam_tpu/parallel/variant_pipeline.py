@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import itertools
+import threading
 import time
 from typing import Dict, Iterator, Optional, Tuple
 
@@ -113,27 +114,52 @@ _GT_DOSE = {b"0/0": 0, b"0|0": 0, b"0/1": 1, b"1/0": 1, b"0|1": 1,
 _SNP_ALTS = frozenset(b"ACGTN")
 
 
-def pack_variant_tiles_from_text(text: bytes, header: VCFHeader,
+def pack_variant_tiles_from_text(text, header: VCFHeader,
                                  geometry: VariantGeometry
                                  ) -> Dict[str, np.ndarray]:
     """Text-VCF tokenizer for the stats/tensor path — the host-side 'VCF
-    line tokenizer' kernel of SURVEY.md section 7.3(e).
+    line tokenizer' kernel of SURVEY.md section 7.3(e).  ``text`` is any
+    contiguous bytes-like (a view of a leased span buffer included);
+    every column returned is memory of its own.
 
-    Dispatches to the NumPy grid tokenizer (newline/tab scans -> field
-    boundary matrix -> one clamped gather per column; no per-line Python)
-    and falls back to this scalar parse ONLY for rows the vectorized path
-    flags as irregular (ALT wider than its gather, multi-digit or
-    polyploid genotypes, non-digit POS).  Semantics match
-    pack_variant_tiles (asserted by tests)."""
-    cols, odd = _pack_variant_text_vectorized(text, header, geometry)
-    if odd:
-        # odd: (kept-row index, line start, line end) for irregular rows
-        rows = np.asarray([r for r, _, _ in odd])
+    One tokeniser for narrow and wide files, its work in proportion to
+    the bytes and never to lines x samples.  One pass finds each record
+    line, its first nine tabs and — where FORMAT is exactly ``GT`` and
+    the sample block is the regular ``digit sep digit`` cells of a call
+    set — its dosage row: ``utils/native.py::vcf_tokenize`` with the
+    interpreter lock released, ``_vcf_tokenize_numpy`` on a host without
+    the library (counted: ``vcf.text_native_records`` /
+    ``vcf.text_numpy_records``).  CHROM, POS and the flags come from the
+    nine tabs by a few NumPy gathers (``_fixed_field_columns``).  A line
+    that is not regular (a multi-digit allele, a haploid or missing call,
+    ``GT:...`` subfields, a short line, an ALT wider than its gather, a
+    non-digit POS) is read by the scalar parse below, which stays the
+    statement of the semantics (``vcf.text_bulk_records`` /
+    ``vcf.text_scalar_records``; asserted equal by tests)."""
+    from hadoop_bam_tpu.utils import native
+
+    buf = np.frombuffer(text, dtype=np.uint8)
+    S, pad = geometry.n_samples, geometry.samples_pad
+    with METRICS.span("vcf.gt_dosage_wall"):
+        if native.load() is not None:
+            bounds, ntab, bulk, dosage = native.vcf_tokenize(buf, S, pad)
+            METRICS.count("vcf.text_native_records", len(ntab))
+        else:
+            bounds, ntab, bulk, dosage = _vcf_tokenize_numpy(buf, S, pad)
+            METRICS.count("vcf.text_numpy_records", len(ntab))
+    cols, odd = _fixed_field_columns(buf, bounds, header)
+    cols["dosage"] = dosage
+    rows = np.flatnonzero(odd | ~bulk)
+    if rows.size:
+        mv = memoryview(buf)
         patch = _pack_variant_tiles_from_text_scalar(
-            b"\n".join(text[s:e] for _, s, e in odd) + b"\n",
+            b"\n".join(mv[s:e] for s, e in
+                       bounds[rows][:, (0, 10)].tolist()) + b"\n",
             header, geometry)
         for k in cols:
             cols[k][rows] = patch[k]
+    METRICS.count("vcf.text_bulk_records", len(ntab) - rows.size)
+    METRICS.count("vcf.text_scalar_records", int(rows.size))
     return cols
 
 
@@ -229,63 +255,157 @@ def bcf_span_stat_columns(path: str, span, header: VCFHeader,
                       time.thread_time_ns() - t_cpu)
 
 
+class _TextAlive:
+    """A scan's inflated text alive at once: from a span's read until the
+    end of its tokenise.  Its high-water mark is ``vcf.text_peak_bytes``,
+    added once a scan: the window's spans x a span's text bound it,
+    whatever the file's size."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._now = self.peak = 0
+
+    def add(self, n: int) -> None:
+        with self._lock:
+            self._now += n
+            self.peak = max(self.peak, self._now)
+
+
+def text_span_stat_columns(ds, span, header: VCFHeader,
+                           geometry: VariantGeometry, alive: _TextAlive
+                           ) -> Dict[str, np.ndarray]:
+    """One text-VCF span -> stats tile columns: the span's lines read
+    (``VcfDataset.span_text``: a BGZF file's through one positioned read
+    and one native inflate into a leased buffer) and tokenised
+    (``pack_variant_tiles_from_text``) — the text twin of
+    ``bcf_span_stat_columns``, with its spans and counters; ``alive``
+    is the scan's account of text in flight."""
+    t_cpu = time.thread_time_ns()
+    held = 0
+    try:
+        # ``text`` may be a view of a leased span buffer, handed back when
+        # the stack unwinds: every column below is memory of its own
+        with contextlib.ExitStack() as leased:
+            with METRICS.span("vcf.inflate_wall"):
+                text = leased.enter_context(ds.span_text(span))
+            held = len(text)
+            METRICS.count("vcf.inflated_bytes", held)
+            alive.add(held)
+            with METRICS.span("vcf.tokenize_wall"):
+                return pack_variant_tiles_from_text(text, header, geometry)
+    finally:
+        alive.add(-held)
+        METRICS.count("vcf.decode_busy_ns",
+                      time.thread_time_ns() - t_cpu)
+
+
 _ALT_W = 16            # widest ALT the vectorized SNP test gathers
-_GT_W = 4              # widest genotype prefix gathered (covers "0/1:")
 _POS_W = 10            # max decimal digits in a 31-bit position
+# the NumPy twin works a slab of lines at a time: the bytes it gathers at
+# once (a window of each line's head; the lines' sample blocks)
+_TWIN_SLAB_BYTES = 2 << 20
+_TWIN_HEAD_W = 256     # a line's first nine tabs lie within this, mostly
 
 
-def _pack_variant_text_vectorized(text: bytes, header: VCFHeader,
-                                  geometry: VariantGeometry):
-    """NumPy grid tokenizer: newline/tab scans -> per-line field-boundary
-    matrix -> one clamped gather per column.  Returns (cols, odd) where
-    ``odd`` lists (row, line_start, line_end) for rows needing the scalar
-    fallback (wide ALT, unusual GT shapes, non-digit POS)."""
-    S = geometry.n_samples
-    buf = np.frombuffer(text, dtype=np.uint8)
-    if buf.size == 0:
-        return {"chrom": np.empty(0, np.int32),
-                "pos": np.empty(0, np.int32),
-                "flags": np.empty(0, np.uint8),
-                "dosage": np.full((0, geometry.samples_pad), -1, np.int8),
-                }, []
-    nl = np.flatnonzero(buf == 0x0A)
-    if nl.size == 0 or nl[-1] != buf.size - 1:
+def _vcf_tokenize_numpy(buf: np.ndarray, S: int, pad: int):
+    """NumPy twin of ``native/hbam_native.cpp::hbam_vcf_tokenize`` (what a
+    host without the library runs; array for array what
+    ``utils/native.py::vcf_tokenize`` returns, a row with ``bulk`` unset
+    aside, which neither promises): one newline scan, the first nine tabs
+    of a line from a window of its head (widened for the lines whose ALT
+    or INFO is long), and the regular sample blocks copied a line at a
+    time into one ``[lines, S, 4]`` byte matrix and checked there — a slab
+    of lines at once, no index of lines x samples and no loop over
+    samples."""
+    nl = np.concatenate([np.flatnonzero(buf[lo:lo + _TWIN_SLAB_BYTES] == 0x0A)
+                         + lo for lo in range(0, buf.size, _TWIN_SLAB_BYTES)]
+                        or [np.empty(0, np.int64)])
+    if buf.size and (nl.size == 0 or nl[-1] != buf.size - 1):
         nl = np.append(nl, buf.size)
-    starts = np.empty(nl.size, dtype=np.int64)
-    starts[0] = 0
+    starts = np.zeros(nl.size, dtype=np.int64)
     starts[1:] = nl[:-1] + 1
     ends = nl
-    first = buf[np.minimum(starts, buf.size - 1)]
-    keep = (ends > starts) & (first != ord("#"))
+    keep = ends > starts
+    keep[keep] = buf[starts[keep]] != ord("#")
+    starts, ends = starts[keep], ends[keep]
 
-    tabs = np.flatnonzero(buf == 0x09)
-    t0 = np.searchsorted(tabs, starts)
-    t1 = np.searchsorted(tabs, ends)
-    ntab = t1 - t0
-    keep &= ntab >= 7                       # >= 8 fields, scalar parity
-    starts, ends, t0, ntab = (a[keep] for a in (starts, ends, t0, ntab))
+    # the first nine tabs, the line's end where it has fewer
+    tabm = np.repeat(ends[:, None], 9, axis=1)
+    ntab = np.zeros(starts.size, np.int32)
+    todo, w = np.arange(starts.size), _TWIN_HEAD_W
+    while todo.size:
+        step = max(1, _TWIN_SLAB_BYTES // w)
+        later = []
+        for lo in range(0, todo.size, step):
+            rows = todo[lo:lo + step]
+            ln = ends[rows] - starts[rows]
+            j = np.arange(min(w, int(ln.max())), dtype=np.int64)[None, :]
+            win = buf[np.minimum(starts[rows, None] + j, buf.size - 1)]
+            r, c = np.nonzero((win == 0x09) & (j < ln[:, None]))
+            k = np.arange(r.size) - np.searchsorted(r, np.arange(rows.size))[r]
+            cnt = np.bincount(r, minlength=rows.size)
+            done = (cnt >= 9) | (ln <= j.size)
+            on = done[r] & (k < 9)
+            tabm[rows[r[on]], k[on]] = starts[rows[r[on]]] + c[on]
+            ntab[rows[done]] = np.minimum(cnt[done], 9)
+            later.append(rows[~done])
+        todo, w = np.concatenate(later), w * 8
+    keep = ntab >= 7                        # >= 8 fields, scalar parity
+    starts, ends, tabm, ntab = (a[keep] for a in (starts, ends, tabm, ntab))
     n = starts.size
+    bounds = np.concatenate([starts[:, None], tabm, ends[:, None]], axis=1)
+    dosage = np.full((n, pad), -1, np.int8)
+    bulk = np.ones(n, bool)
+    if not S or not n:
+        return bounds, ntab, bulk, dosage
+
+    # FORMAT is field 8; the sample fields need the ninth tab
+    f0, f1 = tabm[:, 7] + 1, tabm[:, 8]
+    at = np.minimum(f0, buf.size - 2)
+    has_gt = (ntab == 9) & (f1 - f0 >= 2) & (buf[at] == ord("G")) \
+        & (buf[at + 1] == ord("T"))
+    cand = has_gt & (f1 - f0 == 2) & (ends - f1 - 1 == 4 * S - 1)
+    bulk[has_gt & ~cand] = False
+    rows = np.flatnonzero(cand)
+    step = max(1, _TWIN_SLAB_BYTES // (4 * S))
+    for lo in range(0, rows.size, step):
+        slab = rows[lo:lo + step]
+        cells = np.empty((slab.size, 4 * S), np.uint8)
+        cells[:, -1] = 0x09
+        for i, o in enumerate((f1[slab] + 1).tolist()):
+            cells[i, :4 * S - 1] = buf[o:o + 4 * S - 1]
+        cells = cells.reshape(slab.size, S, 4)
+        c0, c1, c2 = cells[:, :, 0], cells[:, :, 1], cells[:, :, 2]
+        ok = ((c0 - 0x30 <= 9) & (c2 - 0x30 <= 9)       # uint8 wraps
+              & ((c1 == ord("/")) | (c1 == ord("|")))
+              & (cells[:, :, 3] == 0x09)).all(axis=1)
+        bulk[slab] = ok
+        dosage[slab[ok], :S] = ((c0 > 0x30).view(np.int8)
+                                + (c2 > 0x30).view(np.int8))[ok]
+    return bounds, ntab, bulk, dosage
+
+
+def _fixed_field_columns(buf: np.ndarray, bounds: np.ndarray,
+                         header: VCFHeader):
+    """``chrom`` / ``pos`` / ``flags`` of the record lines whose ``bounds``
+    (start, first nine tabs, end) the tokenise pass found: one clamped
+    gather a field.  Returns (cols, odd): ``odd`` marks the rows the scalar
+    parse has to read (an ALT wider than its gather, a POS that is not a
+    31-bit decimal)."""
+    n = bounds.shape[0]
     cols = {"chrom": np.full(n, -1, np.int32),
             "pos": np.zeros(n, np.int32),
-            "flags": np.zeros(n, np.uint8),
-            "dosage": np.full((n, geometry.samples_pad), -1, np.int8)}
-    if n == 0:
-        return cols, []
-    nf = 10 + S                             # fields we may need bounds for
-    k = np.arange(nf - 1, dtype=np.int64)[None, :]
-    tabm = tabs[np.minimum(t0[:, None] + k, tabs.size - 1)]
-    tabm = np.where(k < ntab[:, None], tabm, ends[:, None])
-    # field f occupies [fs[f], fe[f])
-    fs = np.concatenate([starts[:, None], tabm + 1], axis=1)
-    fe = np.concatenate([tabm, ends[:, None]], axis=1)
-    fe = np.maximum(fe, fs)                 # past-the-last fields: empty
+            "flags": np.zeros(n, np.uint8)}
     odd = np.zeros(n, bool)
+    if n == 0:
+        return cols, odd
 
     def gather(f, width):
         """[n, width] bytes of field f, zero past its length, + lengths."""
-        ln = fe[:, f] - fs[:, f]
+        fs = bounds[:, f] + (1 if f else 0)
+        ln = np.maximum(bounds[:, f + 1] - fs, 0)   # past the last: empty
         j = np.arange(width, dtype=np.int64)[None, :]
-        g = buf[np.minimum(fs[:, f, None] + j, buf.size - 1)]
+        g = buf[np.minimum(fs[:, None] + j, buf.size - 1)]
         return np.where(j < ln[:, None], g, 0), ln
 
     # CHROM: a span holds 1-2 distinct names, but a real header can carry
@@ -299,10 +419,9 @@ def _pack_variant_text_vectorized(text: bytes, header: VCFHeader,
     keyed = np.concatenate(
         [cbytes, np.minimum(clen, cw + 1)[:, None].astype(np.uint8)],
         axis=1)
-    # hash-group the rows (a span holds ~1-2 distinct names; a real
-    # header can carry thousands of contigs, so neither a per-contig
-    # scan nor a lexicographic row-unique is acceptable): u64 scalar
-    # unique + one vectorized verify against each group's representative
+    # hash-group the rows (neither a per-contig scan nor a lexicographic
+    # row-unique is acceptable): u64 scalar unique + one vectorized
+    # verify against each group's representative
     weights = ((2 * np.arange(cw + 1, dtype=np.uint64) + 1)
                * np.uint64(0x9E3779B97F4A7C15))
     with np.errstate(over="ignore"):
@@ -356,48 +475,7 @@ def _pack_variant_text_vectorized(text: bytes, header: VCFHeader,
     is_snp = (rlen == 1) & (alen % 2 == 1) & ok_even & ok_odd
     cols["flags"] = (is_pass.astype(np.uint8) * FLAG_PASS
                      | is_snp.astype(np.uint8) * FLAG_SNP)
-
-    # genotypes: FORMAT (field 8) must start "GT"; per sample, dosage
-    # from the first 1 or 3 characters of the GT subfield.  Wall-spanned
-    # separately (vcf.dosage_pack_wall): the GT columns are the dominant
-    # tokenizer cost on wide cohorts and the bench's vcf_stage_seconds
-    # row wants them attributable
-    if S:
-        with METRICS.span("vcf.dosage_pack_wall"):
-            gb8, glen8 = gather(8, 2)
-            has_gt = (glen8 >= 2) & (gb8[:, 0] == ord("G")) \
-                & (gb8[:, 1] == ord("T")) & (ntab >= 9)
-            for s in range(S):
-                f = 9 + s
-                present = has_gt & (ntab >= f)  # field exists on the line
-                sb, sln = gather(f, _GT_W)
-                colon = np.where((sb == ord(":")) & (np.arange(_GT_W) <
-                                                     sln[:, None]),
-                                 np.arange(_GT_W), _GT_W).min(axis=1)
-                gtlen = np.minimum(sln, colon)
-                c0, c1, c2 = sb[:, 0], sb[:, 1], sb[:, 2]
-                d0 = (c0 >= 0x30) & (c0 <= 0x39)
-                d2 = (c2 >= 0x30) & (c2 <= 0x39)
-                sep = (c1 == ord("/")) | (c1 == ord("|"))
-                one = gtlen == 1
-                tri = (gtlen == 3) & sep
-                dot0, dot2 = c0 == ord("."), c2 == ord(".")
-                val1 = np.where(d0, (c0 > 0x30).astype(np.int8),
-                                np.int8(-1))
-                val3 = np.where(d0 & d2,
-                                ((c0 > 0x30).astype(np.int8)
-                                 + (c2 > 0x30).astype(np.int8)),
-                                np.int8(-1))
-                # '.' anywhere -> missing (scalar: first non-digit allele
-                # aborts to -1); handled by d0/d2 being False for '.'
-                val = np.where(one, val1, np.where(tri, val3, np.int8(0)))
-                regular = one | tri
-                odd |= present & ~regular & (gtlen > 0)
-                row_ok = present & regular
-                cols["dosage"][row_ok, s] = val[row_ok]
-    odd_rows = np.flatnonzero(odd)
-    return cols, [(int(r), int(starts[r]), int(ends[r]))
-                  for r in odd_rows]
+    return cols, odd
 
 
 def _iter_variant_tiles(cols_stream, cap: int, geometry: VariantGeometry
@@ -582,41 +660,85 @@ def _variant_stats_result(totals: _StatTotals,
     }
 
 
-def _bgzf_inflate_ratio(path: str, window: int = 256 << 10) -> float:
-    """Inflated / compressed bytes over the BGZF blocks that lie whole in
-    the file's first ``window`` bytes (read off their headers and
-    footers; nothing is inflated).  1.0 when it cannot be told."""
+# the ratio's sample: whole blocks in this many windows of this size,
+# spread evenly over the file (a BGZF block is 64 KiB at most, so every
+# window holds one).  Single blocks of a call set deflate 30 % apart:
+# 8 windows read its ratio 5 % off, 32 within 1 % (4 MiB read, ~12 ms)
+_RATIO_WINDOWS = 32
+_RATIO_WINDOW_BYTES = 128 << 10
+
+
+def _bgzf_inflate_ratio(path: str) -> float:
+    """Inflated / compressed bytes over the whole BGZF blocks that lie in
+    32 windows of 128 KiB spread evenly over the file (read off their
+    headers and footers; nothing is inflated).  1.0 when it cannot be
+    told.  Spread over the file because its head is no guide: the first
+    256 KiB of a 48 MB call set's text read 51.5x, 58.0x and 61.0x on
+    three seeds whose files all deflate 55.8-56.1x (the header's block,
+    then whichever sites a file happens to start with), and the span
+    count followed it, 147 to 174 spans of one amount of text."""
     from hadoop_bam_tpu.formats import bgzf
     from hadoop_bam_tpu.parallel.pipeline import scoped_byte_source
 
+    packed = inflated = 0
     try:
         with scoped_byte_source(path) as src:
-            head = src.pread(0, window)
+            last = max(0, src.size - _RATIO_WINDOW_BYTES)
+            starts = sorted({last * i // (_RATIO_WINDOWS - 1)
+                             for i in range(_RATIO_WINDOWS)})
+            for at in starts:
+                head = src.pread(at, _RATIO_WINDOW_BYTES)
+                p, n = _whole_blocks(head, from_start=at == 0)
+                packed += p
+                inflated += n
     except Exception:  # noqa: BLE001 — planning must not fail the driver
         return 1.0
-    packed = inflated = off = 0
-    while True:
-        try:
-            info = bgzf.parse_block_header(head, off)
-        except bgzf.BGZFError:
-            break
-        packed += info.block_size
-        inflated += info.isize
-        off += info.block_size
     return max(1.0, inflated / packed) if packed else 1.0
+
+
+def _whole_blocks(head: bytes, from_start: bool) -> Tuple[int, int]:
+    """(compressed, inflated) bytes of the chain of whole BGZF blocks in
+    ``head``: from its first byte, or from the first block start found in
+    it that a second block follows."""
+    from hadoop_bam_tpu.formats import bgzf
+
+    def chain(off: int) -> Tuple[int, int, int]:
+        packed = inflated = blocks = 0
+        while True:
+            try:
+                info = bgzf.parse_block_header(head, off)
+            except bgzf.BGZFError:
+                return packed, inflated, blocks
+            packed += info.block_size
+            inflated += info.isize
+            blocks += 1
+            off += info.block_size
+
+    if from_start:
+        return chain(0)[:2]
+    for cand in bgzf.find_block_starts_numpy(
+            np.frombuffer(head, dtype=np.uint8)):
+        packed, inflated, blocks = chain(int(cand))
+        if blocks >= 2:
+            return packed, inflated
+    return 0, 0
 
 
 def variant_span_count(ds, n_dev: int,
                        config: HBamConfig = DEFAULT_CONFIG) -> int:
     """Span count of a whole-file variant scan.  ``pipeline_span_count``
     bounds a span's COMPRESSED bytes (4 MiB), which fits files that
-    deflate about 4x.  A cohort-wide BCF deflates 30x and more (5 KB
-    records of mostly 0|0): 4 MiB of it are 26,000 records of 2,504
-    samples, ten spans a 262,144-record scan, all decoded before the
-    first tile reaches the device.  So a BGZF BCF's bytes are weighed by
-    the ratio its first blocks show, which keeps a span's INFLATED bytes
-    near four pipeline grains."""
-    if not ds._is_bgzf_bcf:
+    deflate about 4x.  A cohort-wide call set deflates 30x and more — BCF
+    (5 KB records of mostly 0|0) and bgzip'd VCF text (10 KB lines of
+    mostly ``0|0``, ~50x) alike: 4 MiB of the BCF are 26,000 records of
+    2,504 samples, of the text ~200 MB and 20,000 lines, ten spans a
+    262,144-record scan, all decoded before the first tile reaches the
+    device.  So any BGZF variant file's bytes are weighed by the ratio a
+    sample of its blocks shows (``_bgzf_inflate_ratio``), which keeps a
+    span's INFLATED bytes near four pipeline grains."""
+    from hadoop_bam_tpu.api.dispatch import VCFContainer
+
+    if not (ds._is_bgzf_bcf or ds.container is VCFContainer.VCF_BGZF):
         return pipeline_span_count(ds.path, n_dev, config)
     return pipeline_span_count(
         ds.path, n_dev, config,
@@ -656,6 +778,7 @@ def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
     tile schema's keys to the group's borrowed ``[n_dev, bucket, ...]``
     views, and what it returns (the device arrays made from them) is the
     ring slot's in-flight handle.  Returns the header."""
+    from hadoop_bam_tpu.api.dispatch import VCFContainer
     from hadoop_bam_tpu.api.vcf_dataset import open_vcf
     from hadoop_bam_tpu.parallel.mesh import make_mesh
 
@@ -678,17 +801,17 @@ def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
     window = max(1, prefetch) * decode_pool_size(config)
     from hadoop_bam_tpu.parallel.pipeline import decode_with_retry
 
+    is_text = ds.container is not VCFContainer.BCF
+    alive = _TextAlive()
+
     def decode(span):
         def inner(s):
             # per-stage wall spans (Metrics.wall_timer: overlapping pool
             # threads union, so values are wall seconds, not thread-sums)
             # feed the bench's vcf_stage_seconds row
-            with METRICS.span("vcf.inflate_wall"):
-                text = ds.read_span_text(s)
-            if text is not None:  # fast tokenizer, no record objects
-                with METRICS.span("vcf.tokenize_wall"):
-                    return pack_variant_tiles_from_text(text, header,
-                                                        geometry)
+            if is_text:     # fast tokenizer, no record objects
+                return text_span_stat_columns(ds, s, header, geometry,
+                                              alive)
             return bcf_span_stat_columns(ds.path, s, header, geometry,
                                          ds._is_bgzf_bcf)
         with METRICS.wall_timer("pipeline.host_decode_wall"), \
@@ -714,6 +837,8 @@ def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
             return handles  # in-flight: the ring waits on them
 
         fp.feed(tuples, dispatch)
+    if is_text:
+        METRICS.count("vcf.text_peak_bytes", alive.peak)
     return header
 
 

@@ -241,7 +241,6 @@ class QueryEngine:
         return meta
 
     def _variant_header(self, path: str, kind: str):
-        from hadoop_bam_tpu.formats import bgzf
         from hadoop_bam_tpu.utils.seekable import scoped_byte_source
         with scoped_byte_source(path) as src:
             if kind == "bcf":
@@ -252,15 +251,8 @@ class QueryEngine:
                         f"{path} is a raw (non-BGZF) BCF — virtual-offset "
                         f"random access needs the BGZF container")
                 return header
-            from hadoop_bam_tpu.formats.vcf import read_vcf_header_text
-            r = bgzf.BGZFReader(src)
-
-            def read_chunk(off: int, size: int) -> bytes:
-                r.seek_voffset(0)
-                r.read(off)           # header-sized positions only
-                return r.read(size)
-            header, _ = read_vcf_header_text(read_chunk)
-            return header
+            from hadoop_bam_tpu.formats.vcf import read_vcf_header_bgzf
+            return read_vcf_header_bgzf(src)
 
     def _cram_container_table(self, path: str, ident):
         """[(offset, end, ref_seq_id, start, span)] for every data

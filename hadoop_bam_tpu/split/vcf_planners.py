@@ -93,54 +93,185 @@ def _prev_block_last_byte(src, coffset: int) -> Optional[int]:
 
 
 def read_bgzf_text_span(source, span: FileByteSpan) -> bytes:
-    """All text lines *starting* within the span's compressed block range.
+    """All text lines *starting* within the span's compressed block range,
+    as memory of the caller's own — ``bgzf_text_span`` without the
+    lease."""
+    with bgzf_text_span(source, span) as text:
+        return bytes(text)
 
-    A line starts in the span iff its first inflated byte lies in a block
-    whose compressed offset is in [span.start, span.end) — with the partial
-    line carried over a boundary owned by the previous span."""
-    src = as_byte_source(source)
-    start, end = span.start, span.end
 
+@contextlib.contextmanager
+def bgzf_text_span(source, span: FileByteSpan
+                   ) -> Iterator[memoryview]:
+    """``with bgzf_text_span(...) as text``: all text lines *starting*
+    within the span's compressed block range — the input of the text
+    tokeniser (parallel/variant_pipeline.pack_variant_tiles_from_text).
+
+    A line belongs to the span in which its first byte lies: the span
+    whose blocks [span.start, span.end) hold that byte.  So the partial
+    line at the span's head is the previous span's (told by the last byte
+    of the block before ``span.start``, ``_prev_block_last_byte``), the
+    span's last line is read to its end in the blocks that follow, the
+    first span owns the header lines, and the union of a plan's spans is
+    every line of the file exactly once, whatever the span count.
+
+    The host decides the path.  With the native library the span is read
+    as the BCF read reads one (``_lease_bgzf_text``): then ``text`` is a
+    view of a buffer leased from the span-buffer pool, good until the
+    ``with`` ends — whatever outlives it must be a copy.  Without it the
+    blocks are inflated one by one in Python (``_inflate_text_python``)
+    and ``text`` is a view of their ``bytes``.  Counters:
+    ``vcf.native_read_spans`` / ``vcf.python_read_spans``."""
+    with scoped_byte_source(source) as src:
+        leased = _lease_bgzf_text(src, span)
+        if leased is None:
+            METRICS.count("vcf.python_read_spans")
+            lease = NO_LEASE
+            buf, base_len = _inflate_text_python(src, span)
+        else:
+            METRICS.count("vcf.native_read_spans")
+            lease, buf, base_len = leased
+        try:
+            skip_first = False
+            if span.start > 0 and base_len:
+                prev = _prev_block_last_byte(src, span.start)
+                skip_first = prev is not None and prev != 0x0A
+            lo, hi = _owned_text(buf, base_len, skip_first)
+            yield memoryview(buf)[lo:hi]
+        finally:
+            lease.release()
+
+
+def _owned_text(buf, base_len: int, skip_first: bool) -> Tuple[int, int]:
+    """[lo, hi) of ``buf`` that is the lines whose first byte lies in
+    ``buf[:base_len]`` (the span's own blocks; what follows is the tail
+    read to finish its last line).  ``skip_first``: the first line began
+    in a block before the span."""
+    view = memoryview(buf)
+    n = len(view)
+    lo = 0
+    if skip_first:
+        lo = _find_newline(view, 0, n) + 1
+        if lo == 0 or lo >= base_len:       # one line covers the span
+            return 0, 0
+    if base_len == 0:
+        return 0, 0
+    if view[base_len - 1] == 0x0A:
+        return lo, base_len
+    nl = _find_newline(view, base_len, n)
+    return lo, n if nl < 0 else nl + 1
+
+
+def _find_newline(view: memoryview, start: int, stop: int) -> int:
+    """Index of the first newline in ``view[start:stop]``, or -1."""
+    step = 1 << 16                  # a line's end is near: look a bit at a time
+    while start < stop:
+        end = min(stop, start + step)
+        at = bytes(view[start:end]).find(b"\n")
+        if at >= 0:
+            return start + at
+        start, step = end, step * 4
+    return -1
+
+
+def _tail_blocks(src, coffset: int) -> Iterator[bytes]:
+    """The inflated blocks from ``coffset`` on, one at a time (the tail of
+    a span's last line: nearly always the one block after the span)."""
+    while coffset < src.size:
+        head = src.pread(coffset, bgzf.MAX_BLOCK_SIZE)
+        info = bgzf.parse_block_header(head, 0)
+        yield bgzf.inflate_block(head, info, check_crc=False)
+        coffset += info.block_size
+
+
+def _inflate_text_python(src, span: FileByteSpan) -> Tuple[bytes, int]:
+    """The span's blocks inflated one by one with ``zlib`` (the host has
+    no native library, or the native read declined): (their text + the
+    blocks that finish the last line, the length of the span's own text).
+    One ``pread`` of the span's compressed range (and a block's worth
+    more: a span that does not end on a block start still gets its last
+    block whole), one a tail block."""
+    want = max(0, min(span.end, src.size) - span.start)
+    raw = src.pread(span.start, want + bgzf.MAX_BLOCK_SIZE)
     chunks: List[bytes] = []
-    base_len = 0          # inflated bytes belonging to in-span blocks
-    coffset = start
-    while coffset < min(end, src.size):
-        head = src.pread(coffset, bgzf.MAX_BLOCK_SIZE)
-        info = bgzf.parse_block_header(head, 0)
-        chunks.append(bgzf.inflate_block(head, info, check_crc=False))
-        base_len += len(chunks[-1])
-        coffset += info.block_size
-    buf = b"".join(chunks)
-    # extend past the end until the final in-span line is complete
-    while (len(buf) == 0 or not buf.endswith(b"\n")) and coffset < src.size:
-        head = src.pread(coffset, bgzf.MAX_BLOCK_SIZE)
-        info = bgzf.parse_block_header(head, 0)
-        ext = bgzf.inflate_block(head, info, check_crc=False)
-        coffset += info.block_size
-        if not ext:
-            continue
-        nl = ext.find(b"\n")
-        if nl >= 0:
-            buf += ext[:nl + 1]
+    off = 0
+    while off < want:
+        info = bgzf.parse_block_header(raw, off)
+        chunks.append(bgzf.inflate_block(raw, info, check_crc=False))
+        off += info.block_size
+    base = b"".join(chunks)
+    if not base or base.endswith(b"\n"):
+        return base, len(base)
+    chunks = [base]
+    for ext in _tail_blocks(src, span.start + off):
+        chunks.append(ext)
+        if b"\n" in ext:
             break
-        buf += ext
+    return b"".join(chunks), len(base)
 
-    skip_first = False
-    if start > 0:
-        prev = _prev_block_last_byte(src, start)
-        skip_first = prev is not None and prev != 0x0A
-    out = bytearray()
-    pos = 0
-    n = len(buf)
-    first = True
-    while pos < base_len and pos < n:
-        nl = buf.find(b"\n", pos)
-        line_end = n if nl < 0 else nl + 1
-        if not (first and skip_first):
-            out += buf[pos:line_end]
-        first = False
-        pos = line_end
-    return bytes(out)
+
+def _lease_bgzf_text(src, span: FileByteSpan
+                     ) -> Optional[Tuple[SpanBuffer, memoryview, int]]:
+    """A BGZF text span read the way the BCF read reads one: ONE
+    positioned read of the span's compressed range and the block after it
+    (``ops.inflate.fetch_span_raw``: where the span's last line nearly
+    always ends), the native header walk, ONE native inflate of all those
+    blocks into a buffer leased from the span-buffer pool, the
+    interpreter lock released.  One native thread: the pool's threads are
+    the parallelism.  ISIZE is verified and CRC32 is not, as
+    ``bgzf.inflate_block(check_crc=False)`` does.  A last line that runs
+    past the block after the span (a line longer than a block) is
+    finished block by block.
+
+    Returns (lease, text of the span's blocks + the tail, the length of
+    the span's own text); the caller releases the lease.  Returns None —
+    nothing leased, the Python path decides and raises its own
+    ``BGZFError`` — without the native library, for an empty span and
+    when the block chain does not parse or inflate."""
+    end = min(span.end, src.size)
+    if not native.available() or span.start >= end:
+        return None
+    lease = raw_lease = NO_LEASE
+    try:
+        # end_u = 1: the read takes the block AT ``end`` along
+        raw, end_block_size, next_c, raw_lease = inflate_ops.fetch_span_raw(
+            src, FileVirtualSpan(span.path, span.start << 16,
+                                 (end << 16) | 1))
+        if not raw:
+            return None
+        table = inflate_ops.block_table(raw)
+        isize = table["isize"]
+        total = int(isize.sum())
+        base_len = total - (int(isize[-1]) if end_block_size else 0)
+        lease = SPAN_BUFFERS.lease(total)
+        inflate_ops.inflate_span(raw, table, backend="native", n_threads=1,
+                                 out=lease.array)
+    except BaseException as e:
+        lease.release()
+        if isinstance(e, bgzf.BGZFError):
+            return None
+        raise
+    finally:
+        raw_lease.release()     # nothing below reads the compressed bytes
+    try:
+        view = memoryview(lease.array)
+        if base_len and view[base_len - 1] != 0x0A \
+                and _find_newline(view, base_len, total) < 0:
+            for ext in _tail_blocks(src, next_c):
+                if total + len(ext) > lease.array.size:
+                    bigger = SPAN_BUFFERS.lease(2 * (total + len(ext)))
+                    bigger.array[:total] = lease.array[:total]
+                    lease.release()
+                    lease = bigger
+                lease.array[total:total + len(ext)] = \
+                    np.frombuffer(ext, np.uint8)
+                total += len(ext)
+                if b"\n" in ext:
+                    break
+        return lease, memoryview(lease.array)[:total], base_len
+    except BaseException:
+        lease.release()
+        raise
 
 
 # ---------------------------------------------------------------------------

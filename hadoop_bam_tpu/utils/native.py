@@ -214,6 +214,10 @@ def load() -> Optional[ctypes.CDLL]:
             i8p, ctypes.c_int64, i8p, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_int32, i8p, ctypes.c_int64, i8p, ctypes.c_int64, i32p,
             ctypes.c_int64]
+        lib.hbam_vcf_tokenize.restype = ctypes.c_int64
+        lib.hbam_vcf_tokenize.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, i64p, i32p, i8p, i8sp,
+            ctypes.c_int64, ctypes.c_int64]
         u16p = ctypes.POINTER(ctypes.c_uint16)
         lib.hbam_deflate_find_block.restype = ctypes.c_int64
         lib.hbam_deflate_find_block.argtypes = [
@@ -521,6 +525,43 @@ def fastq_tokenize(text, nibble: np.ndarray, seq_stride: int,
         int(seq_stride), _ptr(qual, ctypes.c_uint8), int(qual_stride),
         _ptr(lengths, ctypes.c_int32), n))
     return (seq, qual, lengths) if rc == 0 else None
+
+
+def vcf_tokenize(text, n_sample: int, samples_pad: int
+                 ) -> "tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]":
+    """A VCF text span's record lines in one native pass over the bytes,
+    the interpreter lock released: (bounds [n, 11] i64 — a line's start,
+    its first nine tabs (its end where it has fewer), its end; ntab [n]
+    i32, nine at most; bulk [n] bool; dosage [n, samples_pad] i8).  Where
+    ``bulk`` is set the dosage row is final: -1 throughout for a line with
+    no ``GT`` FORMAT, the ALT dosages of a line whose FORMAT is exactly
+    ``GT`` and whose ``n_sample`` cells are all ``digit sep digit``.  The
+    other rows are not written: the line goes to the caller's scalar
+    parse.  What
+    ``parallel/variant_pipeline.py::_vcf_tokenize_numpy`` returns, array
+    for array."""
+    lib = load()
+    assert lib is not None
+    buf = _src_u8(text)
+    if buf.dtype != np.uint8 or buf.ndim != 1 or not buf.flags.c_contiguous:
+        raise ValueError("vcf_tokenize wants contiguous u8 text")
+    p_text = _ptr(buf, ctypes.c_uint8)
+    cap = int(lib.hbam_vcf_tokenize(p_text, int(buf.size), int(n_sample),
+                                    None, None, None, None,
+                                    int(samples_pad), 0))
+    if cap < 0:
+        raise ValueError(f"vcf_tokenize refused its arguments ({cap})")
+    bounds = np.empty((cap, 11), dtype=np.int64)
+    ntab = np.empty(cap, dtype=np.int32)
+    bulk = np.empty(cap, dtype=np.uint8)
+    dosage = np.empty((cap, samples_pad), dtype=np.int8)
+    n = int(lib.hbam_vcf_tokenize(
+        p_text, int(buf.size), int(n_sample), _ptr(bounds, ctypes.c_int64),
+        _ptr(ntab, ctypes.c_int32), _ptr(bulk, ctypes.c_uint8),
+        _ptr(dosage, ctypes.c_int8), int(samples_pad), cap))
+    if n != cap:
+        raise ValueError(f"vcf_tokenize counted {cap} records and wrote {n}")
+    return bounds, ntab, bulk.view(bool), dosage
 
 
 # A DEFLATE window, and the symbols ``deflate_decode_symbols`` writes where

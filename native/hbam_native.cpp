@@ -592,6 +592,114 @@ int64_t hbam_bcf_gt_dosage(const uint8_t* buf, int64_t buf_len,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
+// VCF text -> line bounds + ALT dosage in one pass over the bytes (the text
+// variant feed's tokenise; parallel/variant_pipeline.py::_vcf_tokenize_numpy
+// is the NumPy twin, ``_pack_variant_tiles_from_text_scalar`` the statement
+// of the semantics).  A line ends at '\n' (a '\r' before it is part of its
+// last field, as ``bytes.split`` has it); an empty line, a '#' line and a
+// line of fewer than eight fields are no record.  No threads: the callers'
+// pool threads run it with the interpreter lock released.
+// ---------------------------------------------------------------------------
+extern "C" {
+
+// Every record line of text[0, n), in order, into row i of
+//   bounds [cap, 11] : the line's start, its first nine tabs (the line's end
+//                      where it has fewer), its end (the '\n', or n);
+//   ntab   [cap]     : tabs found, nine at most;
+//   bulk   [cap]     : 1 where the row of ``dosage`` is final, 0 where the
+//                      line's sample fields are not the regular shape and
+//                      the caller's scalar parse has to read the line;
+//   dosage [cap, stride] int8 : -1 in every column of a line with no FORMAT
+//                      that starts ``GT``; of a line whose FORMAT is exactly
+//                      ``GT`` and whose sample block is n_sample cells
+//                      ``digit sep digit`` (sep '/' or '|') joined by tabs —
+//                      4 n_sample - 1 bytes, the shape of nearly every line
+//                      of a call set — (a > 0) + (b > 0) a sample, -1 in the
+//                      columns past n_sample.  A row with bulk 0 is not
+//                      written.
+// Work follows the bytes: nine ``memchr`` for tabs, one for the line end and
+// one pass over the sample block a line, whatever n_sample is.  Returns the
+// number of records, -1 for arguments it cannot take, -2 when ``cap`` rows
+// do not hold them (nothing outside the arrays is written either way).
+// With ``bounds`` null it only counts the records: what sizes the arrays.
+int64_t hbam_vcf_tokenize(const uint8_t* text, int64_t n, int64_t n_sample,
+                          int64_t* bounds, int32_t* ntab, uint8_t* bulk,
+                          int8_t* dosage, int64_t stride, int64_t cap) {
+  if (n < 0 || cap < 0 || n_sample < 0 || n_sample > stride ||
+      n_sample > (int64_t{1} << 40))
+    return -1;
+  const int64_t block = 4 * n_sample - 1;
+  int64_t rows = 0;
+  for (int64_t pos = 0; pos < n;) {
+    const int64_t s = pos;
+    // the first nine tabs, then the line end from the last of them on: a
+    // '\n' met first ends the line and the hunt
+    int64_t t[9];
+    int32_t nt = 0;
+    int64_t at = s, e = -1;
+    while (nt < 9) {
+      const uint8_t* p = text + at;
+      const uint8_t* stop = text + n;
+      while (p < stop && *p != '\t' && *p != '\n') ++p;   // ~150 bytes a line
+      if (p == stop) { e = n; break; }
+      if (*p == '\n') { e = p - text; break; }
+      t[nt++] = p - text;
+      at = p - text + 1;
+    }
+    if (e < 0) {
+      const void* nl = std::memchr(text + at, '\n', static_cast<size_t>(n - at));
+      e = nl ? static_cast<const uint8_t*>(nl) - text : n;
+    }
+    pos = e + 1;
+    if (e == s || text[s] == '#' || nt < 7) continue;
+    if (!bounds) { ++rows; continue; }
+    if (rows >= cap) return -2;
+    int64_t* b = bounds + rows * 11;
+    b[0] = s;
+    for (int k = 0; k < 9; ++k) b[1 + k] = k < nt ? t[k] : e;
+    b[10] = e;
+    ntab[rows] = nt;
+    int8_t* out = dosage + rows * stride;
+    // FORMAT is field 8: [t[7] + 1, t[8]); sample fields need the ninth tab
+    const bool has_gt = n_sample > 0 && nt == 9 && t[8] - t[7] - 1 >= 2 &&
+                        text[t[7] + 1] == 'G' && text[t[7] + 2] == 'T';
+    uint8_t ok = 1;
+    if (!has_gt) {
+      std::memset(out, 0xFF, static_cast<size_t>(stride));
+    } else if (t[8] - t[7] - 1 != 2 || e - t[8] - 1 != block) {
+      ok = 0;
+    } else {
+      const uint8_t* g = text + t[8] + 1;
+      uint32_t bad = 0;
+      for (int64_t i = 0; i + 1 < n_sample; ++i) {   // it vectorises
+        uint32_t v;
+        std::memcpy(&v, g + 4 * i, 4);
+        const uint32_t c0 = v & 0xFF, c1 = (v >> 8) & 0xFF,
+                       c2 = (v >> 16) & 0xFF, c3 = v >> 24;
+        bad |= static_cast<uint32_t>(c0 - '0' > 9u) |
+               static_cast<uint32_t>(c2 - '0' > 9u) |
+               static_cast<uint32_t>((c1 != '/') & (c1 != '|')) |
+               static_cast<uint32_t>(c3 != '\t');
+        out[i] = static_cast<int8_t>((c0 > '0') + (c2 > '0'));
+      }
+      const uint8_t* last = g + 4 * (n_sample - 1);
+      const uint32_t c0 = last[0], c1 = last[1], c2 = last[2];
+      bad |= static_cast<uint32_t>(c0 - '0' > 9u) |
+             static_cast<uint32_t>(c2 - '0' > 9u) |
+             static_cast<uint32_t>((c1 != '/') & (c1 != '|'));
+      out[n_sample - 1] = static_cast<int8_t>((c0 > '0') + (c2 > '0'));
+      std::memset(out + n_sample, 0xFF, static_cast<size_t>(stride - n_sample));
+      ok = !bad;
+    }
+    bulk[rows] = ok;
+    ++rows;
+  }
+  return rows;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
 // FASTQ text -> payload tiles in one pass (the text reads' tokenise + 4-bit
 // pack; api/read_datasets.py::fastq_text_to_payload_tiles is the NumPy twin
 // and the oracle).  The line rules are the twin's ``_scan_lines``: a line

@@ -56,7 +56,7 @@ def _fill_state(bench, n_notes=6):
             # line keeps just the numeric value
             row["vcf_stage_seconds"] = {
                 "inflate_wall": 0.21, "tokenize_wall": 0.33,
-                "dosage_pack_wall": 0.12, "dispatch_wall": 0.18}
+                "gt_dosage_wall": 0.12, "dispatch_wall": 0.18}
         if m == "region_query_queries_per_sec":
             row.update(cold_queries_per_sec=17.1, cache_hit_rate=0.93,
                        regions=250, records_matched=2_551_000,
@@ -206,7 +206,7 @@ def test_full_snapshot_keeps_detail_on_progress_lines(bench):
     # r9: VCF per-stage walls + region-query cache detail stay on the
     # progress lines (the compact line keeps only the numeric values)
     assert set(by_metric["vcf_variants_per_sec"]["vcf_stage_seconds"]) \
-        == {"inflate_wall", "tokenize_wall", "dosage_pack_wall",
+        == {"inflate_wall", "tokenize_wall", "gt_dosage_wall",
             "dispatch_wall"}
     rq = by_metric["region_query_queries_per_sec"]
     assert 0.0 <= rq["cache_hit_rate"] <= 1.0

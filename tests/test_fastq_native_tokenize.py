@@ -347,7 +347,7 @@ def test_tokenize_native_share_metric(bench_run, counters, want):
         bench = json.load(fh)
     entry = [m for m in bench["per_layer"]
              if m["name"] == "fastq.tokenize_native_share"]
-    assert len(entry) == 1 and entry[0] == bench["per_layer"][-1]
+    assert len(entry) == 1      # later PRs append their own after it
     assert entry[0]["workloads"] == ["hiseq-fastqgz-seqstats"]
     assert entry[0]["moves"] == "scan_records_per_s"
     said = []

@@ -820,11 +820,18 @@ def test_exec_counters_rise_by_one_executes_worth(bam, small_vcf,
 def test_vcf_dispatch_wall_counts_a_dispatch_once(bam, small_vcf,
                                                   small_fastqs):
     _execute("vcf-stats", bam, small_vcf, small_fastqs)
-    with MetricsContext() as m:
-        _execute("vcf-stats", bam, small_vcf, small_fastqs)
-    w = m.snapshot()["wall_timers"]
-    groups = m.wall_calls["pipeline.dispatch_wall"]
-    assert m.wall_calls["vcf.dispatch_wall"] == groups > 1
-    # one is the span's own wall, the other the clock reads around it
-    assert w["vcf.dispatch_wall"] == pytest.approx(
-        w["pipeline.dispatch_wall"], rel=0.05, abs=groups * 1e-4)
+    # one is the span's own wall, the other the clock reads around it: a
+    # thread switch between the reads (one 5 ms interval of the
+    # interpreter's, seen once under six test workers) lands in one of the
+    # two, so a scan that reads apart is taken again, twice at most
+    for _attempt in range(3):
+        with MetricsContext() as m:
+            _execute("vcf-stats", bam, small_vcf, small_fastqs)
+        w = m.snapshot()["wall_timers"]
+        groups = m.wall_calls["pipeline.dispatch_wall"]
+        assert m.wall_calls["vcf.dispatch_wall"] == groups > 1
+        want = pytest.approx(w["pipeline.dispatch_wall"], rel=0.05,
+                             abs=groups * 1e-4)
+        if w["vcf.dispatch_wall"] == want:
+            break
+    assert w["vcf.dispatch_wall"] == want

@@ -207,36 +207,19 @@ def test_bcf_fast_scan_matches_generic(vcf, tmp_path):
     assert total == len(recs)
 
 
-def test_text_tokenizer_vectorized_matches_scalar():
-    """Differential fuzz: the NumPy grid tokenizer (+ its irregular-row
-    fallback) must match the per-line scalar parse byte-for-byte across
-    adversarial shapes: multi-allelic ALTs, wide ALTs, polyploid and
+def _fuzz_lines(rng, n_lines, n_samples):
+    """Adversarial VCF lines: multi-allelic ALTs, wide ALTs, polyploid and
     multi-digit genotypes, missing trailing fields, '.' everywhere."""
-    import random as _random
-
-    from hadoop_bam_tpu.formats.vcf import VCFHeader
-    from hadoop_bam_tpu.parallel.variant_pipeline import (
-        VariantGeometry, _pack_variant_tiles_from_text_scalar,
-        pack_variant_tiles_from_text,
-    )
-    header = VCFHeader.from_text(
-        "##fileformat=VCFv4.2\n"
-        "##contig=<ID=chr1,length=1000000>\n"
-        "##contig=<ID=chrX_alt,length=50000>\n"
-        '##FORMAT=<ID=GT,Number=1,Type=String,Description="G">\n'
-        "#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO\tFORMAT\t"
-        "s0\ts1\ts2\n")
-    rng = _random.Random(17)
     alts = ["A", "T", "A,C", "A,C,G,T,A,C,G,T,A",     # > _ALT_W wide
             "AT", "A,TT", ".", "<DEL>", "A,<INS>", "*"]
     gts = ["0/0", "0/1", "1|1", "./.", ".", "0", "2", "10/1", "0/1/1",
            "1", "0|0|1", "./0", "0/.", "", "1/2:99", "0/1:.:3"]
     formats = ["GT", "GT:GQ", "GQ", "GTX"]
     lines = []
-    for i in range(400):
+    for i in range(n_lines):
         chrom = rng.choice(["chr1", "chrX_alt", "chrUnknown"])
         pos = rng.randint(1, 999999)
-        nf = rng.choice([8, 9, 10, 11, 12])
+        nf = rng.choice([8, 9, 10, 11, 9 + n_samples])
         parts = [chrom, str(pos), ".", rng.choice(["A", "AT"]),
                  rng.choice(alts), "30",
                  rng.choice(["PASS", "q10", "."]), "DP=5"]
@@ -245,16 +228,130 @@ def test_text_tokenizer_vectorized_matches_scalar():
             for _ in range(nf - 9):
                 parts.append(rng.choice(gts))
         lines.append("\t".join(parts))
+    return lines
+
+
+def _wide_lines(rng, n_lines, n_samples):
+    """A call set's lines at ``n_samples`` — FORMAT ``GT``, every cell
+    ``digit sep digit`` — with each irregular kind mixed in: a multi-digit
+    allele, a haploid call, ``.`` and ``./.``, ``GT:DP`` subfields, a short
+    line, a long one, a wide ALT, no FORMAT at all, a carriage return."""
+    regular = ["0|0"] * 12 + ["0|1", "1|0", "1|1", "0/1", "2|1", "0|3"]
+    lines = []
+    for i in range(n_lines):
+        cells = [rng.choice(regular) for _ in range(n_samples)]
+        fmt, pos, alt = "GT", str(rng.randint(1, 999999)), "T"
+        kind = rng.choice(["regular"] * 6 + [
+            "multi-digit", "haploid", "dot", "dot-slash", "subfields",
+            "short", "long", "wide-alt", "no-format", "cr"])
+        at = rng.randrange(n_samples)
+        if kind == "multi-digit":
+            cells[at] = "10|1"
+        elif kind == "haploid":
+            cells[at] = "1"
+        elif kind == "dot":
+            cells[at] = "."
+        elif kind == "dot-slash":
+            cells[at] = "./."
+        elif kind == "subfields":
+            fmt, cells = "GT:DP", [c + ":7" for c in cells]
+        elif kind == "short":
+            cells = cells[:at]
+        elif kind == "long":
+            cells.append("0|1")
+        elif kind == "wide-alt":
+            alt = "A,C,G,T,A,C,G,T,A"
+        elif kind == "cr":
+            cells[-1] += "\r"
+        parts = [rng.choice(["chr1", "chrX_alt"]), pos, ".", "A", alt, "30",
+                 rng.choice(["PASS", "q10"]), "DP=5"]
+        if kind != "no-format":
+            parts += [fmt] + cells
+        lines.append("\t".join(parts))
+    return lines
+
+
+@pytest.mark.parametrize("twin", [False, True], ids=["native", "numpy"])
+@pytest.mark.parametrize("n_samples,make,n_lines", [
+    (3, _fuzz_lines, 400), (2504, _wide_lines, 160), (0, _fuzz_lines, 200)],
+    ids=["3-samples-fuzz", "2504-samples-mixed", "sites-only"])
+def test_text_tokenizer_vectorized_matches_scalar(n_samples, make, n_lines,
+                                                  twin, monkeypatch):
+    """Differential fuzz: the bulk tokeniser (the native pass and its
+    NumPy twin, + the irregular-row fallback) must match the per-line
+    scalar parse byte-for-byte, at 3 samples and at 2,504, with a trailing
+    newline and without, and count every record once under
+    ``vcf.text_bulk_records`` / ``vcf.text_scalar_records``."""
+    import random as _random
+
+    from hadoop_bam_tpu.formats.vcf import VCFHeader
+    from hadoop_bam_tpu.parallel.variant_pipeline import (
+        VariantGeometry, _pack_variant_tiles_from_text_scalar,
+        pack_variant_tiles_from_text,
+    )
+    from hadoop_bam_tpu.utils import native
+    from hadoop_bam_tpu.utils.metrics import base_metrics
+
+    if twin:
+        monkeypatch.setattr(native, "load", lambda: None)
+    elif native.load() is None:
+        pytest.skip("no native library on this host")
+    header = VCFHeader.from_text(
+        "##fileformat=VCFv4.2\n"
+        "##contig=<ID=chr1,length=1000000>\n"
+        "##contig=<ID=chrX_alt,length=50000>\n"
+        '##FORMAT=<ID=GT,Number=1,Type=String,Description="G">\n'
+        "#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO\tFORMAT"
+        + "".join(f"\ts{i}" for i in range(n_samples)) + "\n")
+    lines = make(_random.Random(17), n_lines, n_samples)
+    lines[5:5] = ["", "#a comment line", "too\tfew\tfields"]
     text = ("\n".join(lines) + "\n").encode()
-    geom = VariantGeometry(n_samples=3)
+    geom = VariantGeometry(n_samples=n_samples)
     want = _pack_variant_tiles_from_text_scalar(text, header, geom)
+    assert want["chrom"].shape[0] == n_lines
+    base_metrics().reset()
     got = pack_variant_tiles_from_text(text, header, geom)
     for k in want:
         assert (want[k] == got[k]).all(), k
-    # and without a trailing newline
-    got2 = pack_variant_tiles_from_text(text[:-1], header, geom)
+        assert want[k].dtype == got[k].dtype and want[k].shape == got[k].shape
+    c = base_metrics().snapshot()["counters"]
+    assert c["vcf.text_bulk_records"] + c.get("vcf.text_scalar_records", 0) \
+        == n_lines
+    assert c["vcf.text_numpy_records" if twin else
+             "vcf.text_native_records"] == n_lines
+    if make is _wide_lines:     # both paths are really taken
+        assert c["vcf.text_bulk_records"] > n_lines // 3
+        assert c["vcf.text_scalar_records"] > n_lines // 4
+    # and without a trailing newline, and as a view of a larger buffer
+    got2 = pack_variant_tiles_from_text(memoryview(text)[:-1], header, geom)
     for k in want:
         assert (want[k] == got2[k]).all(), k
+
+
+def test_native_pass_and_numpy_twin_return_the_same_arrays():
+    """``utils/native.py::vcf_tokenize`` and ``_vcf_tokenize_numpy``,
+    array for array (a row with ``bulk`` unset aside: neither promises
+    it), lines with a long head included."""
+    import random as _random
+
+    from hadoop_bam_tpu.parallel.variant_pipeline import _vcf_tokenize_numpy
+    from hadoop_bam_tpu.utils import native
+
+    if native.load() is None:
+        pytest.skip("no native library on this host")
+    rng = _random.Random(23)
+    for n_samples, make in ((3, _fuzz_lines), (40, _wide_lines)):
+        lines = make(rng, 300, n_samples)
+        lines[7] = lines[7].replace("DP=5", "DP=5;X=" + "A" * 5000)
+        lines[9] = "chr1\t5\t.\t" + "A" * 3000 + "\tT\t30\tPASS\tDP=5"
+        buf = np.frombuffer(("\n".join(lines)).encode(), np.uint8)
+        pad = max(8, (n_samples + 7) // 8 * 8)
+        a = native.vcf_tokenize(buf, n_samples, pad)
+        b = _vcf_tokenize_numpy(buf, n_samples, pad)
+        for x, y in zip(a[:3], b[:3]):
+            assert x.dtype == y.dtype and np.array_equal(x, y)
+        assert np.array_equal(a[3][a[2]], b[3][b[2]])
+        assert a[2].any() and not a[2].all()
 
 
 def test_variant_geometry_byte_budget_large_cohorts():

@@ -270,6 +270,124 @@ def test_bgzf_text_spans_every_boundary(vcf_files):
         assert got == want, f"cut={cut}"
 
 
+def _blocked_vcf_gz(path, payload: int, n_lines: int = 60,
+                    n_meta: int = 120):
+    """A ``.vcf.gz`` of fixed ``payload``-byte BGZF blocks (as bgzip cuts a
+    stream, regardless of lines) whose header is longer than a block and
+    whose lines are longer than a third of one.  Returns (text, lines,
+    block count)."""
+    rng = random.Random(payload)
+    meta = ["##fileformat=VCFv4.2", "##contig=<ID=chr20,length=64444167>"]
+    meta += [f"##INFO=<ID=K{i},Number=1,Type=Integer,Description=\"key {i} "
+             f"of a long header\">" for i in range(n_meta)]
+    meta.append("#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO")
+    lines = [f"chr20\t{100 + 7 * i}\t.\tA\tG\t40\tPASS\t"
+             + ";".join(f"K{k}={rng.randrange(10 ** 6)}"
+                        for k in range(rng.randrange(payload // 30,
+                                                     payload // 10)))
+             for i in range(n_lines)]
+    text = ("\n".join(meta + lines) + "\n").encode()
+    header_len = len(("\n".join(meta) + "\n").encode())
+    assert header_len > payload
+    assert min(len(ln) for ln in lines) > payload // 3
+    blocks = [bgzf.deflate_block(text[lo:lo + payload])
+              for lo in range(0, len(text), payload)]
+    with open(path, "wb") as f:
+        f.write(b"".join(blocks) + bgzf.EOF_BLOCK)
+    return text, lines, len(blocks)
+
+
+@pytest.mark.parametrize("payload", [1500, 1511, 4096])
+def test_bgzf_text_span_ownership_at_every_span_count(payload, tmp_path,
+                                                      monkeypatch):
+    """A line belongs to the span in which its first byte lies: for every
+    span count from 1 to 3 x the block count, the spans' texts are each
+    line of the file exactly once and in order (the first span owns the
+    header lines), and the native read is the Python read, byte for
+    byte."""
+    from hadoop_bam_tpu.split import vcf_planners
+    from hadoop_bam_tpu.utils import native
+    from hadoop_bam_tpu.utils.metrics import base_metrics
+
+    path = str(tmp_path / "blocked.vcf.gz")
+    text, lines, n_blocks = _blocked_vcf_gz(path, payload)
+    if not native.available():
+        pytest.skip("no native library on this host")
+    for num_spans in range(1, 3 * n_blocks + 1):
+        spans = plan_bgzf_text_spans(path, num_spans=num_spans)
+        assert spans[0].start == 0
+        assert spans[-1].end == os.path.getsize(path)
+        base_metrics().reset()
+        fast = [read_bgzf_text_span(path, s) for s in spans]
+        c = base_metrics().snapshot()["counters"]
+        assert c["vcf.native_read_spans"] == len(spans)
+        assert "vcf.python_read_spans" not in c
+        assert b"".join(fast) == text, num_spans
+        assert all(t == b"" or t.endswith(b"\n") for t in fast)
+        with monkeypatch.context() as m:
+            m.setattr(vcf_planners.native, "available", lambda: False)
+            slow = [read_bgzf_text_span(path, s) for s in spans]
+        assert slow == fast, num_spans
+    got = [ln for ln in b"".join(fast).decode().splitlines()
+           if not ln.startswith("#")]
+    assert got == lines
+
+
+def test_bgzf_text_span_last_line_without_newline_and_long_lines(tmp_path):
+    """A file whose last line has no newline, and a line longer than
+    several blocks (its tail is finished block by block)."""
+    from hadoop_bam_tpu.split import vcf_planners
+
+    head = "##fileformat=VCFv4.2\n#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO\n"
+    body = ["chr20\t%d\t.\tA\tG\t40\tPASS\t%s" % (10 + i, "X" * w)
+            for i, w in enumerate([50, 9000, 40, 70, 12000, 30])]
+    text = (head + "\n".join(body)).encode()           # no final newline
+    path = str(tmp_path / "long.vcf.gz")
+    with open(path, "wb") as f:
+        f.write(b"".join(bgzf.deflate_block(text[lo:lo + 2000])
+                         for lo in range(0, len(text), 2000))
+                + bgzf.EOF_BLOCK)
+    for num_spans in (1, 2, 3, 5, 8, 13, 21):
+        spans = plan_bgzf_text_spans(path, num_spans=num_spans)
+        fast = [read_bgzf_text_span(path, s) for s in spans]
+        assert b"".join(fast) == text, num_spans
+        real = vcf_planners.native.available
+        vcf_planners.native.available = lambda: False
+        try:
+            assert [read_bgzf_text_span(path, s) for s in spans] == fast
+        finally:
+            vcf_planners.native.available = real
+
+
+def test_bgzf_vcf_header_of_several_blocks_is_read_once(tmp_path,
+                                                        monkeypatch):
+    """``VcfDataset._read_header`` on a BGZF VCF: each of the header's
+    blocks is loaded once (it used to seek to 0 and re-read a chunk)."""
+    clear_sniff_caches()
+    path = str(tmp_path / "h.vcf.gz")
+    # a header of ~75 KB: the header reader asks for 64 KiB chunks
+    text, lines, n_blocks = _blocked_vcf_gz(path, 1500, n_lines=20,
+                                            n_meta=1200)
+    header_len = text.index(b"chr20\t100\t")
+    assert header_len > (1 << 16)
+    header_blocks = -(-header_len // 1500)
+    loads = []
+    real = bgzf.BGZFReader._load_block
+
+    def counting(self, coffset):
+        loads.append(coffset)
+        return real(self, coffset)
+
+    monkeypatch.setattr(bgzf.BGZFReader, "_load_block", counting)
+    ds = open_vcf(path)
+    assert len(ds.header.to_text().encode()) == header_len
+    # every block once, and no further than a chunk (64 KiB = 44 blocks)
+    # past the header's end
+    assert len(loads) == len(set(loads)), "a block was re-read"
+    assert header_blocks <= len(loads) <= header_blocks + 46
+    assert [r.pos for r in ds.records()][:3] == [100, 107, 114]
+
+
 # ---------------------------------------------------------------------------
 # writers + mergers
 # ---------------------------------------------------------------------------
