@@ -1,11 +1,29 @@
-"""Vectorized (columnar) BCF record decode: typed columns out, no
-per-record Python objects and no per-typed-value ``struct`` calls.
+"""Columnar BCF record decode: typed columns out, no per-record Python
+objects and no per-typed-value ``struct`` calls.
 
 The variant stats/tensor path needs columns — CHROM/POS/rlen/QUAL/
 n_allele/n_fmt, the PASS/SNP flag byte, and the GT dosage matrix — not
 ``VcfRecord`` objects.  This module decodes a whole span of concatenated
-BCF record bytes into exactly those columns with NumPy batch ops, the
-BCF twin of ``formats/cram_columns.py``:
+BCF record bytes into exactly those columns, the BCF twin of
+``formats/cram_columns.py``.
+
+**What runs** (since PR 39, where ``utils/native.load()`` gives the
+library — no flag, no option): a framed span is ONE native call,
+``native/hbam_native.cpp::hbam_bcf_span_columns``, with the interpreter
+lock released.  It walks a record at a time — the 24 fixed bytes, ID
+skipped, alleles -> the SNP test, FILTER -> PASS, INFO jumped by
+``l_shared``, the FORMAT keys walked to ``GT`` — and reduces the record's
+GT vector to its int8 dosage row with the loop ``hbam_bcf_gt_dosage`` runs
+for the record's own (width, ploidy): a loop the compiler vectorises over
+samples for ploidy 1 and 2, a generic one for every other layout, chosen a
+record from what the record states.  Every byte of a row is written once,
+pad columns included, so ``dosage`` is minted uninitialised.  The framing,
+where no starts are handed in, is ``hbam_bcf_chase`` — the chase the span
+readers make (``split/vcf_planners.py::_chase_frames``).
+
+**What stays as the oracle, and as the path of a host without the
+library**: the NumPy decode below, the statement of the semantics the
+native pass is pinned to byte for byte (tests/test_bcf_native_walk.py):
 
 * record framing is one cheap cursor walk over the ``l_shared``/
   ``l_indiv`` length prefixes (or arrives precomputed from the span
@@ -14,39 +32,40 @@ BCF twin of ``formats/cram_columns.py``:
   gather, so CHROM/POS/rlen/QUAL/n_info/n_allele/n_sample/n_fmt fall
   out as NumPy views;
 * the variable typed-value region (ID, alleles, FILTER, FORMAT keys
-  and descriptors) is decoded by a *lockstep cursor*: one int64 cursor
-  per record advances through the same structural position of every
-  record simultaneously, exploiting the length-prefixed typed-value
-  encoding [SPEC BCF2.2] — each structural step is O(1) NumPy ops over
-  all records instead of O(records) Python iterations.  The number of
-  steps is max(n_allele) + max(n_fmt) + 3, which real call sets keep
-  tiny (biallelic + GT:AD:DP-ish);
+  and descriptors) is decoded by a *lockstep cursor* (``_cursor_walk``):
+  one int64 cursor per record advances through the same structural
+  position of every record simultaneously, exploiting the
+  length-prefixed typed-value encoding [SPEC BCF2.2] — each structural
+  step is O(1) NumPy ops over all records instead of O(records) Python
+  iterations.  The number of steps is max(n_allele) + max(n_fmt) + 3,
+  which real call sets keep tiny (biallelic + GT:AD:DP-ish) — but each
+  step is a handful of small NumPy calls that give the interpreter lock
+  up and take it back, ~100 a span: with 32 pool threads decoding at
+  once that, not the arithmetic, was the first half of every BCF scan
+  (PERF.md section 6, PR 39);
 * INFO is never touched: the shared-block length prefix lets the
   cursor jump straight to the per-sample block;
 * GT payloads are reduced to the ALT-dosage matrix per (width, ploidy,
-  n_sample) layout group (one group for the overwhelmingly common
-  uniform-diploid case) by ONE native call a group,
-  ``hbam_bcf_gt_dosage`` in native/hbam_native.cpp, which reads each
-  record's genotypes where they lie and writes its int8 row with the
-  interpreter lock released — a loop the compiler vectorises over
-  samples for ploidy 1 and 2, a generic one for every other layout, the
-  choice made inside the kernel from what the records state.  The
-  semantics are exactly those of ``formats/bcf.scan_variant_columns`` /
-  ``VariantBatch.dosage_matrix``.  Without the native library the NumPy
-  ``_gt_group_dosage`` (a 2-D byte gather + view a slab, then a walk of
-  the ploidy axis) does the same work: it stays as the oracle the
-  kernel is pinned to and as the fallback.  A count of work on a CPU
-  (one thread, one 3,600-record span at 2,504 samples; not a speed):
-  the NumPy gather 30-45 us a record, 96 % of ``_decode_columns``; the
-  kernel's diploid int8 loop 0.36-0.78 us; ``_cursor_walk`` 1.0-1.8 us.
+  n_sample) layout group by ``_gt_group_dosage`` (a 2-D byte gather +
+  view a slab, then a walk of the ploidy axis).  The semantics are
+  exactly those of ``formats/bcf.scan_variant_columns`` /
+  ``VariantBatch.dosage_matrix``.
+
+A count of work on a CPU (one thread, one 3,268-record span at 2,504
+samples; not a speed): the NumPy walk + gather 35–45 ms a span (the
+gather 30-45 us a record before PR 31), the native call 3.4–4.7 ms,
+1.0–1.4 us a record.
 
 Eligibility: pathological geometry that would make the lockstep rounds
 degenerate (thousands of alleles or FORMAT fields per record, absurd
-GT ploidy) returns None via ``decode_bcf_columns`` and the caller falls
-back to the record-serial scanner, which handles anything.  Corruption
-— truncated records, undefined typed-value codes, overrunning vectors —
-raises ``BCFError`` loudly on BOTH paths; the columnar path never
-mis-decodes silently (tests/test_bcf_columns.py fuzzes this).
+GT ploidy, more samples than the tile) returns None via
+``decode_bcf_columns`` — the native pass declines exactly what the NumPy
+walk declines — and the caller falls back to the record-serial scanner,
+which handles anything.  Corruption — truncated records, undefined
+typed-value codes, overrunning vectors — raises ``BCFError`` loudly on
+EVERY path (the native pass's return code names the check and the
+record); no path mis-decodes silently (tests/test_bcf_columns.py and
+tests/test_bcf_native_walk.py fuzz this).
 
 Reference-side equivalent: htsjdk ``BCF2Codec`` as driven by
 hb/BCFRecordReader.java (SURVEY.md section 2.3); the columnar design is
@@ -107,13 +126,18 @@ def frame_record_starts(buf: bytes) -> np.ndarray:
     ``BCFError`` if the final record overruns or trailing bytes remain.
     """
     n = len(buf)
-    starts = []
-    unpack = struct.Struct("<II").unpack_from
-    p = 0
-    while p + 8 <= n:
-        starts.append(p)
-        l_shared, l_indiv = unpack(buf, p)
-        p += 8 + l_shared + l_indiv
+    if native.available():
+        # the same chase the span readers make, in native code
+        starts, p, _need = native.bcf_chase(np.frombuffer(buf, np.uint8),
+                                            0, n)
+    else:
+        starts = []
+        unpack = struct.Struct("<II").unpack_from
+        p = 0
+        while p + 8 <= n:
+            starts.append(p)
+            l_shared, l_indiv = unpack(buf, p)
+            p += 8 + l_shared + l_indiv
     if p != n:
         raise BCFError("truncated BCF record in columnar frame")
     return np.asarray(starts, np.int64)
@@ -309,11 +333,7 @@ def _cursor_walk(b: np.ndarray, header: VCFHeader,
     is_pass = one & (fval == 0)
 
     # ---- per-sample block (INFO is jumped over wholesale) ---------------
-    strings = header.string_dictionary()
-    try:
-        gt_key = strings.index("GT")
-    except ValueError:
-        gt_key = -1
+    gt_key = _gt_key(header)
     q = end_shared
     gt_typ = np.zeros(n, np.int64)          # 0 = no GT seen
     gt_count = np.zeros(n, np.int64)
@@ -394,6 +414,34 @@ def _gt_group_dosage(b: np.ndarray, rows: np.ndarray, offs: np.ndarray,
         dosage[part, :ns] = np.minimum(d, 127).astype(np.int8)
 
 
+def _gt_key(header: VCFHeader) -> int:
+    """"GT" in the header's string dictionary, or -1."""
+    try:
+        return header.string_dictionary().index("GT")
+    except ValueError:
+        return -1
+
+
+def _decode_columns_native(b: np.ndarray, header: VCFHeader,
+                           samples_pad: int, starts: np.ndarray
+                           ) -> Dict[str, np.ndarray]:
+    """The walk and the GT -> dosage of a framed span as ONE native call
+    (``native/hbam_native.cpp::hbam_bcf_span_columns``), the interpreter
+    lock released: what ``_cursor_walk`` + ``_gt_group_dosage`` compute,
+    byte for byte, with their checks and their two refusals."""
+    with METRICS.span("vcf.gt_dosage_wall"):
+        got = native.bcf_span_columns(
+            b, starts, _gt_key(header), samples_pad, _MAX_ALLELE_ROUNDS,
+            _MAX_FMT_ROUNDS, _MAX_GT_PLOIDY)
+    if got is None:
+        raise _Ineligible("geometry the native walk declines")
+    cols, n_gt = got
+    METRICS.count("vcf.walk_native_records", int(starts.size))
+    if n_gt:
+        METRICS.count("vcf.gt_native_records", n_gt)
+    return cols
+
+
 def _decode_columns(buf: bytes, header: VCFHeader, samples_pad: int,
                     starts: Optional[np.ndarray]) -> Dict[str, np.ndarray]:
     b = np.frombuffer(buf, np.uint8)
@@ -403,6 +451,9 @@ def _decode_columns(buf: bytes, header: VCFHeader, samples_pad: int,
     n = starts.size
     if n == 0:
         return _empty_columns(samples_pad)
+    if native.available():
+        return _decode_columns_native(b, header, samples_pad, starts)
+    METRICS.count("vcf.walk_numpy_records", n)
     wk = _cursor_walk(b, header, starts)
     chrom, pos0, rlen, qual = (wk["chrom"], wk["pos0"], wk["rlen"],
                                wk["qual"])
@@ -420,20 +471,15 @@ def _decode_columns(buf: bytes, header: VCFHeader, samples_pad: int,
     if bool((have & (n_sample > samples_pad)).any()):
         raise _Ineligible("record carries more samples than the tile")
     if bool(have.any()):
-        # one native call a layout group, the interpreter lock released;
-        # the NumPy twin where this host has no native library
-        use_native = native.available()
-        group_dosage = (native.bcf_gt_dosage if use_native
-                        else _gt_group_dosage)
         with METRICS.span("vcf.gt_dosage_wall"):
             combo = (gt_typ << 48) | (gt_count << 24) | n_sample
             for c in np.unique(combo[have]):
                 rows = np.flatnonzero(have & (combo == c))
                 r0 = rows[0]
-                group_dosage(b, rows, gt_off[rows], int(gt_typ[r0]),
-                             int(gt_count[r0]), int(n_sample[r0]), dosage)
-        METRICS.count("vcf.gt_native_records" if use_native
-                      else "vcf.gt_numpy_records", int(have.sum()))
+                _gt_group_dosage(b, rows, gt_off[rows], int(gt_typ[r0]),
+                                 int(gt_count[r0]), int(n_sample[r0]),
+                                 dosage)
+        METRICS.count("vcf.gt_numpy_records", int(have.sum()))
 
     return {
         "chrom": chrom.astype(np.int32),

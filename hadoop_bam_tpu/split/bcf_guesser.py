@@ -9,11 +9,27 @@ accepted when a chain of consecutive records validates: sane ``l_shared`` /
 ``l_indiv`` block lengths, CHROM index within the header's contig dictionary,
 0-based POS >= -1, non-negative rlen (formats/bcf.plausible_record_start),
 for MIN_CHAIN records or until the inspection window/EOF ends.
+
+What runs (since PR 39, where ``utils/native.load()`` gives the library;
+no flag, no option): the candidate test of a window is one native scan
+with an early exit, ``native/hbam_native.cpp::hbam_bcf_guess`` — a real
+record start is found after a record's length of candidates, not after a
+sweep of all 65,280.  A BGZF window's first block is asked alone first
+(one inflate where the window takes four): the scan says whether its
+answer leaned on where its bytes end, and only then is the whole window
+inflated and asked.  What stays as the oracle, and as the path of a host
+without the library: ``_plausible_offsets`` (the design shift vs the
+reference's per-offset decode loop: five fields gathered at every
+candidate offset in ~70 NumPy passes) + ``_chain_ok``.  Both give the same
+virtual offset for every byte offset asked
+(tests/test_bcf_native_walk.py), so the spans a file is cut into do not
+depend on the host.  Counters: ``vcf.guess_native`` / ``vcf.guess_numpy``,
+once a boundary.
 """
 from __future__ import annotations
 
 import struct
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -22,6 +38,8 @@ from hadoop_bam_tpu.formats.bcf import plausible_record_start
 from hadoop_bam_tpu.formats.vcf import VCFHeader
 from hadoop_bam_tpu.formats.virtual_offset import make_voffset
 from hadoop_bam_tpu.split.bgzf_guesser import BGZFSplitGuesser
+from hadoop_bam_tpu.utils import native
+from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.seekable import as_byte_source
 
 MIN_CHAIN = 3
@@ -41,6 +59,8 @@ class BCFSplitGuesser:
     def guess_next_record_start(self, offset: int) -> Optional[int]:
         """Smallest confirmed record-start virtual offset at or after byte
         ``offset``; None if none found before EOF."""
+        METRICS.count("vcf.guess_native" if native.available()
+                      else "vcf.guess_numpy")
         if self._is_bgzf:
             return self._guess_bgzf(offset)
         return self._guess_raw(offset)
@@ -53,33 +73,68 @@ class BCFSplitGuesser:
             if coffset is None:
                 return None
             raw = self._src.pread(coffset, INSPECT_BLOCKS * bgzf.MAX_BLOCK_SIZE)
-            blocks, data, first_len = self._inflate_chain(raw)
-            if first_len > 0:
-                at_eof = (coffset + sum(b.block_size for b in blocks)
-                          >= self._src.size)
-                u = self._find_record(data, first_len, partial=at_eof)
-                if u is not None:
-                    return make_voffset(coffset, u)
+            blocks = self._parse_chain(raw)
+            u, decided = self._find_in_first_block(raw, blocks)
+            if not decided:
+                blocks, data = self._inflate_chain(raw, blocks)
+                if blocks and blocks[0].isize > 0:
+                    at_eof = (coffset + sum(b.block_size for b in blocks)
+                              >= self._src.size)
+                    u = self._find_record(data, blocks[0].isize,
+                                          partial=at_eof)
+            if u is not None:
+                return make_voffset(coffset, u)
             if not blocks:
                 return None
             coffset += blocks[0].block_size
             if coffset >= self._src.size:
                 return None
 
-    def _inflate_chain(self, raw: bytes):
-        blocks, chunks = [], []
+    @staticmethod
+    def _parse_chain(raw: bytes) -> list:
+        """The headers of the first blocks of ``raw`` that parse, at most
+        INSPECT_BLOCKS."""
+        blocks = []
         off = 0
         while off < len(raw) and len(blocks) < INSPECT_BLOCKS:
             try:
-                info = bgzf.parse_block_header(raw, off)
+                blocks.append(bgzf.parse_block_header(raw, off))
+            except bgzf.BGZFError:
+                break
+            off = blocks[-1].next_coffset
+        return blocks
+
+    def _find_in_first_block(self, raw: bytes, blocks: list
+                             ) -> Tuple[Optional[int], bool]:
+        """(the record start in the window's first block, True) where the
+        first block ALONE decides it: a record start a few KB in whose
+        chain of MIN_CHAIN records ends inside the block is the answer of
+        the whole window too (``hbam_bcf_guess`` says when its answer did
+        not lean on where its bytes end), and three of the window's four
+        inflates are saved.  (None, False) where it does not — a chain
+        that runs into the next block, no library — and the whole window
+        is asked."""
+        if not blocks or blocks[0].isize <= 0 or not native.available():
+            return None, False
+        try:
+            first = bgzf.inflate_block(raw, blocks[0], check_crc=False)
+        except bgzf.BGZFError:
+            return None, False
+        u, edge = native.bcf_guess(first, len(first), self._n_contigs,
+                                   MIN_CHAIN, False)
+        return (None if u < 0 else u), not edge
+
+    @staticmethod
+    def _inflate_chain(raw: bytes, blocks: list):
+        """(the blocks of the chain up to the first that does not inflate,
+        their inflated bytes)."""
+        chunks = []
+        for info in blocks:
+            try:
                 chunks.append(bgzf.inflate_block(raw, info, check_crc=False))
             except bgzf.BGZFError:
                 break
-            blocks.append(info)
-            off = info.next_coffset
-        if not blocks:
-            return [], b"", -1
-        return blocks, b"".join(chunks), len(chunks[0])
+        return blocks[:len(chunks)], b"".join(chunks)
 
     # -- raw container -------------------------------------------------------
     def _guess_raw(self, offset: int) -> Optional[int]:
@@ -99,6 +154,16 @@ class BCFSplitGuesser:
     # -- shared chain validation ---------------------------------------------
     def _find_record(self, data: bytes, first_len: int,
                      partial: bool) -> Optional[int]:
+        """The smallest offset in the window's first block (the raw
+        container: in the window) that passes the plausibility sweep and
+        starts a chain of valid records.  With the native library one
+        scan with an early exit (``hbam_bcf_guess``); ``_plausible_offsets``
+        + ``_chain_ok`` are its oracle and the path of a host without the
+        library — the same offset either way, so the same spans."""
+        if native.available():
+            u, _edge = native.bcf_guess(data, first_len, self._n_contigs,
+                                        MIN_CHAIN, partial)
+            return None if u < 0 else u
         for u in self._plausible_offsets(data, first_len):
             if self._chain_ok(data, int(u), partial):
                 return int(u)

@@ -414,7 +414,13 @@ def _chase_frames(buf, n0: int, grow) -> Tuple[object, int, np.ndarray]:
     """The cursor chase over the ``l_shared``/``l_indiv`` prefixes of the
     records that start in ``buf[:n0]``.  ``grow(need)`` returns the buffer
     made ``need`` bytes long, or as long as the file allows.  Returns
-    (the buffer, the length of the span's records in it, their starts)."""
+    (the buffer, the length of the span's records in it, their starts).
+
+    With the native library the chase is ``hbam_bcf_chase`` (one call, the
+    interpreter lock released; one more a ``grow``); the loop below is its
+    oracle and the path of a host without the library."""
+    if native.available():
+        return _chase_frames_native(buf, n0, grow)
     starts: List[int] = []
     p = 0
     while p < n0:
@@ -433,6 +439,30 @@ def _chase_frames(buf, n0: int, grow) -> Tuple[object, int, np.ndarray]:
         starts.append(p)
         p = end
     return buf, p, np.asarray(starts, np.int64)
+
+
+def _chase_frames_native(buf, n0: int, grow
+                         ) -> Tuple[object, int, np.ndarray]:
+    """``_chase_frames`` through ``utils/native.py::bcf_chase``: the native
+    chase stops at a record the buffer does not hold whole and says how
+    long the buffer has to be; ``grow`` makes it so (no view of ``buf`` is
+    held across it: a ``bytearray`` is resized) and the chase goes on from
+    there."""
+    parts: List[np.ndarray] = []
+    p = 0
+    while True:
+        starts, p, need = native.bcf_chase(np.frombuffer(buf, np.uint8),
+                                           p, n0)
+        parts.append(starts)
+        if not need:
+            break
+        buf = grow(need)
+        if need > len(buf):                     # EOF inside the tail record
+            if p + 8 <= len(buf):               # mid-body: keep the
+                parts.append(np.array([p], np.int64))   # partial; decode
+                p = len(buf)                    # raises.  A bare header
+            break                               # stub is dropped
+    return buf, p, parts[0] if len(parts) == 1 else np.concatenate(parts)
 
 
 def _read_bcf_span_frames(src, span: FileVirtualSpan, is_bgzf: bool

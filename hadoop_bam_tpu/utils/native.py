@@ -207,6 +207,21 @@ def load() -> Optional[ctypes.CDLL]:
             i8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64, ctypes.c_int32,
             ctypes.c_int32, ctypes.c_int64, i8sp, ctypes.c_int64,
             ctypes.c_int64]
+        f32p = ctypes.POINTER(ctypes.c_float)
+        i16p = ctypes.POINTER(ctypes.c_int16)
+        lib.hbam_bcf_chase.restype = ctypes.c_int64
+        lib.hbam_bcf_chase.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, i64p,
+            ctypes.c_int64, i64p, i64p]
+        lib.hbam_bcf_span_columns.restype = ctypes.c_int64
+        lib.hbam_bcf_span_columns.argtypes = [
+            i8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, i32p, i32p, i32p,
+            f32p, i16p, i16p, i8p, i8sp, ctypes.c_int64, i64p]
+        lib.hbam_bcf_guess.restype = ctypes.c_int64
+        lib.hbam_bcf_guess.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int32, ctypes.c_int32, i32p]
         lib.hbam_fastq_count_lines.restype = ctypes.c_int64
         lib.hbam_fastq_count_lines.argtypes = [i8p, ctypes.c_int64]
         lib.hbam_fastq_tokenize.restype = ctypes.c_int32
@@ -486,6 +501,128 @@ def bcf_gt_dosage(buf: np.ndarray, rows: np.ndarray, offs: np.ndarray,
     if rc:
         raise BCFError(f"GT vector of record {rc - 1} of its layout group "
                        "overruns the span")
+
+
+def bcf_chase(buf: np.ndarray, start: int, n0: int
+              ) -> "tuple[np.ndarray, int, int]":
+    """Native chase over the ``l_shared`` / ``l_indiv`` prefixes of BCF
+    records (the interpreter lock is released for the call): from
+    ``start``, every record that begins before ``n0`` and lies whole in
+    ``buf``.  Returns (their starts, where the chase stopped, need):
+    ``need`` is 0, or the length ``buf`` must have for the record at the
+    stop to be framed — its 8-byte header, or its whole body; the caller
+    grows the buffer and chases on from the stop."""
+    lib = load()
+    assert lib is not None
+    if buf.dtype != np.uint8 or buf.ndim != 1 or not buf.flags.c_contiguous:
+        raise ValueError("bcf_chase wants contiguous u8 bytes")
+    # a record is 8 bytes at the very least (both blocks empty: the
+    # decoder's to refuse); the pages the chase does not write stay
+    # untouched
+    cap = max(0, min(n0, int(buf.size)) - start) // 8 + 1
+    starts = np.empty(cap, dtype=np.int64)
+    out = np.zeros(2, dtype=np.int64)
+    n = int(lib.hbam_bcf_chase(
+        _ptr(buf, ctypes.c_uint8), int(buf.size), int(start), int(n0),
+        _ptr(starts, ctypes.c_int64), cap, _ptr(out, ctypes.c_int64),
+        _ptr(out[1:], ctypes.c_int64)))
+    if n < 0:
+        raise ValueError(f"bcf_chase refused its arguments ({n})")
+    return starts[:n].copy(), int(out[0]), int(out[1])
+
+
+# what hbam_bcf_span_columns returns below zero: the check that failed
+# (the messages are formats/bcf_columns.py::_cursor_walk's)
+_BCF_WALK_ERRORS = {
+    -2: "BCF record start out of range",
+    -3: "BCF shared block shorter than its fixed fields",
+    -4: "truncated BCF record in columnar scan",
+    -5: "typed-value descriptor overruns record",
+    -6: "extended count overruns record",
+    -7: "malformed extended-count scalar",
+    -8: "negative typed-value count",
+    -9: "unknown typed-value type",
+    -10: "typed value overruns record",
+    -11: "allele is not a char vector",
+    -12: "allele overruns record",
+    -13: "FILTER vector overruns record",
+    -14: "malformed FORMAT key",
+    -15: "FORMAT key overruns record",
+    -16: "FORMAT data overruns record",
+}
+
+
+def bcf_span_columns(buf: np.ndarray, starts: np.ndarray, gt_key: int,
+                     samples_pad: int, max_allele: int, max_fmt: int,
+                     max_ploidy: int
+                     ) -> "Optional[tuple[Dict[str, np.ndarray], int]]":
+    """A framed BCF span -> (the columns of ``formats/bcf_columns.py::
+    decode_bcf_columns``, its records with a GT vector) in one native
+    call, the interpreter lock released: the typed-value walk of every
+    record and its GT vector reduced to the int8 dosage row, each byte of
+    ``dosage`` written once (so it is minted uninitialised).  ``None``
+    where the kernel declines the span's geometry (more alleles / FORMAT
+    fields / GT ploidy than the bounds given, more samples than
+    ``samples_pad``): the record scanner's to read.  Corrupt input raises
+    ``BCFError`` naming the check and the record; it is never decoded
+    loosely."""
+    from hadoop_bam_tpu.formats.bcf import BCFError
+    lib = load()
+    assert lib is not None
+    if buf.dtype != np.uint8 or buf.ndim != 1 or not buf.flags.c_contiguous:
+        raise ValueError("bcf_span_columns wants contiguous u8 bytes")
+    starts = np.ascontiguousarray(starts, np.int64)
+    n = int(starts.size)
+    cols = {
+        "chrom": np.empty(n, np.int32), "pos": np.empty(n, np.int32),
+        "rlen": np.empty(n, np.int32), "qual": np.empty(n, np.float32),
+        "n_allele": np.empty(n, np.int16), "n_fmt": np.empty(n, np.int16),
+        "flags": np.empty(n, np.uint8),
+        "dosage": np.empty((n, samples_pad), np.int8),
+    }
+    info = np.zeros(2, dtype=np.int64)
+    rc = int(lib.hbam_bcf_span_columns(
+        _ptr(buf, ctypes.c_uint8), int(buf.size),
+        _ptr(starts, ctypes.c_int64), n, int(gt_key), int(max_allele),
+        int(max_fmt), int(max_ploidy), _ptr(cols["chrom"], ctypes.c_int32),
+        _ptr(cols["pos"], ctypes.c_int32), _ptr(cols["rlen"], ctypes.c_int32),
+        _ptr(cols["qual"], ctypes.c_float),
+        _ptr(cols["n_allele"], ctypes.c_int16),
+        _ptr(cols["n_fmt"], ctypes.c_int16),
+        _ptr(cols["flags"], ctypes.c_uint8),
+        _ptr(cols["dosage"], ctypes.c_int8), int(samples_pad),
+        _ptr(info, ctypes.c_int64)))
+    if rc == 1:
+        return None
+    if rc in _BCF_WALK_ERRORS:
+        raise BCFError(f"{_BCF_WALK_ERRORS[rc]} (record {int(info[0])} of "
+                       "the span)")
+    if rc:
+        raise ValueError(f"bcf_span_columns refused its arguments ({rc})")
+    return cols, int(info[1])
+
+
+def bcf_guess(data, first_len: int, n_contigs: int, min_chain: int,
+              partial: bool) -> "tuple[int, bool]":
+    """The split guesser's candidate test in one native scan with an early
+    exit (the interpreter lock is released for the call): the smallest
+    offset in ``data[:first_len]`` that passes the plausibility sweep and
+    starts a chain of ``min_chain`` valid records — ``split/bcf_guesser.py
+    ::_plausible_offsets`` + ``_chain_ok``, offset for offset; -1 where
+    there is none.  The flag beside it says the answer leaned on where
+    ``data`` ends: without it, more bytes behind ``data`` (and another
+    ``partial``) give the same answer."""
+    lib = load()
+    assert lib is not None
+    a = _src_u8(data)
+    if a.dtype != np.uint8 or a.ndim != 1 or not a.flags.c_contiguous:
+        raise ValueError("bcf_guess wants contiguous u8 bytes")
+    edge = ctypes.c_int32(0)
+    u = int(lib.hbam_bcf_guess(
+        _ptr(a, ctypes.c_uint8), int(a.size), int(first_len),
+        int(n_contigs), int(min_chain), int(bool(partial)),
+        ctypes.byref(edge)))
+    return u, bool(edge.value)
 
 
 def fastq_tokenize(text, nibble: np.ndarray, seq_stride: int,

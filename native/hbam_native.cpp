@@ -537,12 +537,20 @@ void gt_dosage_row(const uint8_t* g, int64_t ns, int32_t ploidy,
   }
 }
 
+using GtRowFn = void (*)(const uint8_t*, int64_t, int32_t, int8_t*);
+
+// the row loop of a width for a ploidy the records state
+template <typename T>
+inline GtRowFn gt_row_for(int32_t ploidy) {
+  return ploidy == 2 ? gt_dosage_row<T, 2>
+       : ploidy == 1 ? gt_dosage_row<T, 1> : gt_dosage_row<T, 0>;
+}
+
 template <typename T>
 void gt_dosage_rows(const uint8_t* buf, const int64_t* offs,
                     const int64_t* rows, int64_t n, int32_t ploidy,
                     int64_t ns, int8_t* out, int64_t out_stride) {
-  auto* row = ploidy == 2 ? gt_dosage_row<T, 2>
-            : ploidy == 1 ? gt_dosage_row<T, 1> : gt_dosage_row<T, 0>;
+  const GtRowFn row = gt_row_for<T>(ploidy);
   for (int64_t i = 0; i < n; ++i)
     row(buf + offs[i], ns, ploidy, out + rows[i] * out_stride);
 }
@@ -587,6 +595,340 @@ int64_t hbam_bcf_gt_dosage(const uint8_t* buf, int64_t buf_len,
     gt_dosage_rows<int32_t>(buf, offs, rows, n, ploidy, n_sample, out,
                             out_stride);
   return 0;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The BCF record walker: the length-prefixed structure of BCF2 records
+// [SPEC BCF2.2] walked a record at a time, for the three places a scan walks
+// one — the span read's frame chase (split/vcf_planners.py::_chase_frames,
+// formats/bcf_columns.py::frame_record_starts), the columnar decode of a
+// framed span (formats/bcf_columns.py::_cursor_walk + _gt_group_dosage) and
+// the split guesser's candidate test (split/bcf_guesser.py::
+// _plausible_offsets + _chain_ok).  The NumPy / Python code named there is
+// the statement of the semantics, the oracle these are tested against and
+// the path of a host without this library.  No threads: the callers' pool
+// threads run them with the interpreter lock released.
+// ---------------------------------------------------------------------------
+namespace {
+
+template <typename T>
+inline T bcf_load(const uint8_t* p) {
+  T v;
+  std::memcpy(&v, p, sizeof(T));
+  return v;
+}
+
+// what hbam_bcf_span_columns returns: 0, "declined" (geometry the columnar
+// path leaves to the record scanner), or the check that failed
+enum : int64_t {
+  kBcfOk = 0,
+  kBcfDeclined = 1,
+  kBcfArgs = -1,            // arguments the kernel cannot take
+  kBcfStartRange = -2,      // record start out of range
+  kBcfSharedShort = -3,     // shared block shorter than its fixed fields
+  kBcfTruncated = -4,       // record runs past the buffer
+  kBcfDescOverrun = -5,     // typed-value descriptor overruns record
+  kBcfExtOverrun = -6,      // extended count overruns record
+  kBcfExtMalformed = -7,    // malformed extended-count scalar
+  kBcfNegCount = -8,        // negative typed-value count
+  kBcfUnknownType = -9,     // reserved typed-value type code
+  kBcfValueOverrun = -10,   // typed value overruns record
+  kBcfAlleleNotChar = -11,  // allele is not a char vector
+  kBcfAlleleOverrun = -12,  // allele overruns record
+  kBcfFilterOverrun = -13,  // FILTER vector overruns record
+  kBcfFmtKey = -14,         // malformed FORMAT key
+  kBcfFmtKeyOverrun = -15,  // FORMAT key overruns record
+  kBcfFmtDataOverrun = -16, // FORMAT data overruns record
+};
+
+// element byte width a typed-value type code [SPEC BCF2.2 6.3.3]; -1 marks
+// the reserved codes
+constexpr int8_t kBcfElemSize[16] = {0, 1, 2, 4, -1, 4, -1, 1,
+                                     -1, -1, -1, -1, -1, -1, -1, -1};
+
+inline bool bcf_is_int(int32_t typ) { return typ >= 1 && typ <= 3; }
+
+// sign-extended typed int of an int type at p (its bytes checked by the caller)
+inline int64_t bcf_typed_int(const uint8_t* p, int32_t typ) {
+  return typ == 1 ? bcf_load<int8_t>(p)
+       : typ == 2 ? bcf_load<int16_t>(p) : bcf_load<int32_t>(p);
+}
+
+struct BcfDesc {
+  int64_t count;
+  int32_t typ;
+  int64_t q;        // the cursor after the descriptor
+};
+
+// One typed-value descriptor at q (formats/bcf_columns.py::_read_descriptor):
+// count and type, the real count read from the typed scalar int that follows
+// where the nibble says 15.  0, or the check that failed.
+inline int64_t bcf_descriptor(const uint8_t* b, int64_t q, int64_t rec_end,
+                              BcfDesc* d) {
+  if (q >= rec_end) return kBcfDescOverrun;
+  d->count = b[q] >> 4;
+  d->typ = b[q] & 0x0F;
+  d->q = q + 1;
+  if (d->count == 15) {
+    if (q + 1 >= rec_end) return kBcfExtOverrun;
+    const int32_t etyp = b[q + 1] & 0x0F;
+    if ((b[q + 1] >> 4) != 1 || !bcf_is_int(etyp)) return kBcfExtMalformed;
+    const int64_t esize = kBcfElemSize[etyp];
+    if (q + 2 + esize > rec_end) return kBcfExtOverrun;
+    d->count = bcf_typed_int(b + q + 2, etyp);
+    if (d->count < 0) return kBcfNegCount;
+    d->q = q + 2 + esize;
+  }
+  return 0;
+}
+
+// what one record's walk found past its fixed fields
+struct BcfWalked {
+  bool snp, pass;
+  int32_t gt_typ;           // 0: no GT vector
+  int64_t gt_count, gt_off;
+};
+
+// The typed-value walk of one record (formats/bcf_columns.py::_cursor_walk, a
+// record at a time): ID skipped, alleles -> the SNP test, FILTER -> PASS,
+// INFO jumped by l_shared, the FORMAT keys walked to GT.  Every cursor is
+// checked against the record's end before the byte is read.
+inline int64_t bcf_walk_record(const uint8_t* b, int64_t start,
+                               int64_t end_shared, int64_t rec_end,
+                               int64_t n_allele, int64_t n_fmt,
+                               int64_t n_sample, int64_t gt_key,
+                               BcfWalked* w) {
+  BcfDesc d;
+  int64_t rc = bcf_descriptor(b, start + 32, rec_end, &d);        // ID
+  if (rc) return rc;
+  if (kBcfElemSize[d.typ] < 0) return kBcfUnknownType;
+  int64_t q = d.q + kBcfElemSize[d.typ] * d.count;
+  if (q > rec_end) return kBcfValueOverrun;
+
+  bool snp = n_allele >= 2;
+  for (int64_t k = 0; k < n_allele; ++k) {
+    if ((rc = bcf_descriptor(b, q, rec_end, &d))) return rc;
+    if (d.typ != 7) return kBcfAlleleNotChar;
+    if (d.q + d.count > rec_end) return kBcfAlleleOverrun;
+    // REF only needs length 1; an ALT must also be a base
+    bool ok = d.count == 1;
+    if (ok && k > 0) {
+      const uint8_t base = b[d.q];
+      ok = base == 'A' || base == 'C' || base == 'G' || base == 'T' ||
+           base == 'N';
+    }
+    snp = snp && ok;
+    q = d.q + d.count;
+  }
+  w->snp = snp;
+
+  if ((rc = bcf_descriptor(b, q, rec_end, &d))) return rc;       // FILTER
+  if (kBcfElemSize[d.typ] < 0) return kBcfUnknownType;
+  if (d.q + kBcfElemSize[d.typ] * d.count > rec_end)
+    return kBcfFilterOverrun;
+  // PASS == exactly the one int value 0
+  w->pass = bcf_is_int(d.typ) && d.count == 1 &&
+            bcf_typed_int(b + d.q, d.typ) == 0;
+
+  w->gt_typ = 0;
+  w->gt_count = w->gt_off = 0;
+  q = end_shared;
+  // an n_fmt that overruns the block is tolerated as the record path
+  // tolerates it: the walk stops at the block's end
+  for (int64_t j = 0; j < n_fmt && q < rec_end; ++j) {
+    if ((rc = bcf_descriptor(b, q, rec_end, &d))) return rc;
+    if (!bcf_is_int(d.typ) || d.count != 1) return kBcfFmtKey;
+    if (d.q + kBcfElemSize[d.typ] > rec_end) return kBcfFmtKeyOverrun;
+    const int64_t key = bcf_typed_int(b + d.q, d.typ);
+    if ((rc = bcf_descriptor(b, d.q + kBcfElemSize[d.typ], rec_end, &d)))
+      return rc;
+    if (kBcfElemSize[d.typ] < 0) return kBcfUnknownType;
+    // 4 x 2^31 x 2^24 at most: no overflow
+    const int64_t data_len = kBcfElemSize[d.typ] * d.count * n_sample;
+    if (data_len > rec_end - d.q) return kBcfFmtDataOverrun;
+    if (gt_key >= 0 && key == gt_key && bcf_is_int(d.typ) && n_sample > 0) {
+      w->gt_typ = d.typ;
+      w->gt_count = d.count;
+      w->gt_off = d.q;
+    }
+    q = d.q + data_len;
+  }
+  return 0;
+}
+
+// formats/bcf.py::plausible_record_start (the caller has p + 32 <= n)
+inline bool bcf_plausible(const uint8_t* b, int64_t p, int64_t n_contigs) {
+  const uint32_t l_shared = bcf_load<uint32_t>(b + p);
+  const uint32_t l_indiv = bcf_load<uint32_t>(b + p + 4);
+  if (l_shared < 24 || l_shared > (1u << 24) || l_indiv > (1u << 24))
+    return false;
+  const int32_t chrom = bcf_load<int32_t>(b + p + 8);
+  if (chrom < 0 || chrom >= n_contigs) return false;
+  if (bcf_load<int32_t>(b + p + 12) < -1 || bcf_load<int32_t>(b + p + 16) < 0)
+    return false;
+  return bcf_load<uint16_t>(b + p + 26) <= 1024;          // n_allele
+}
+
+}  // namespace
+
+extern "C" {
+
+// The chase over the l_shared / l_indiv prefixes (split/vcf_planners.py::
+// _chase_frames): from ``from``, every record that starts before n0 and lies
+// whole in buf[0, buf_len).  Writes the starts (``starts`` may be null: it
+// only counts), ``*end`` = where the chase stopped (the end of the last whole
+// record) and ``*need`` = 0, or the buffer length the record at ``*end``
+// needs: its 8-byte header, or its whole body — the caller grows the buffer
+// and calls again from ``*end``.  Returns the number of records, -1 for
+// arguments it cannot take, -2 when ``cap`` starts do not hold them.
+int64_t hbam_bcf_chase(const uint8_t* buf, int64_t buf_len, int64_t from,
+                       int64_t n0, int64_t* starts, int64_t cap,
+                       int64_t* end, int64_t* need) {
+  if (buf_len < 0 || from < 0 || cap < 0) return -1;
+  int64_t p = from, n = 0;
+  *need = 0;
+  while (p < n0) {
+    if (p > buf_len - 8) { *need = p + 8; break; }
+    const int64_t stop = p + 8 + int64_t{bcf_load<uint32_t>(buf + p)} +
+                         int64_t{bcf_load<uint32_t>(buf + p + 4)};
+    if (stop > buf_len) { *need = stop; break; }
+    if (starts) {
+      if (n >= cap) return -2;
+      starts[n] = p;
+    }
+    ++n;
+    p = stop;
+  }
+  *end = p;
+  return n;
+}
+
+// A framed BCF span -> the columns of formats/bcf_columns.py::
+// decode_bcf_columns, in one call: record i starts at buf[starts[i]]; its 24
+// fixed bytes give chrom, pos (1-based), rlen, qual (the typed MISSING float
+// -> NaN), n_allele, n_fmt; bcf_walk_record gives flags (bit 0 PASS, bit 1
+// SNP) and where its GT vector lies; and the GT vector is reduced to row i
+// of ``dosage`` [n, stride] by the loop hbam_bcf_gt_dosage runs for the
+// record's own (type, ploidy) — every byte of the row written once, -1 past
+// n_sample and throughout a record with no GT.  ``gt_key`` is "GT" in the
+// header's string dictionary, or negative.
+//
+// The order of checks is the NumPy walk's: every record's frame first (start
+// in range, l_shared >= 24, the record inside the buffer), then the geometry
+// the lockstep walk declines (max_allele / max_fmt rounds), then every
+// record's typed values, and last the GT geometry it declines (max_ploidy,
+// more samples than the row).  So the same input gives the same answer:
+// kBcfOk, kBcfDeclined (nothing useful written; the caller's record scanner
+// reads the span), or the negative check that failed with info[0] = the
+// record.  info[1] = records with a GT vector.
+int64_t hbam_bcf_span_columns(
+    const uint8_t* buf, int64_t buf_len, const int64_t* starts, int64_t n,
+    int64_t gt_key, int64_t max_allele, int64_t max_fmt, int64_t max_ploidy,
+    int32_t* chrom, int32_t* pos, int32_t* rlen, float* qual,
+    int16_t* n_allele, int16_t* n_fmt, uint8_t* flags, int8_t* dosage,
+    int64_t stride, int64_t* info) {
+  if (buf_len < 0 || n < 0 || stride < 0) return kBcfArgs;
+  info[0] = info[1] = 0;
+  bool declined = false;
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t s = starts[i];
+    info[0] = i;
+    if (s < 0 || s > buf_len - 32) return kBcfStartRange;
+    const int64_t l_shared = bcf_load<uint32_t>(buf + s);
+    if (l_shared < 24) return kBcfSharedShort;
+    if (s + 8 + l_shared + int64_t{bcf_load<uint32_t>(buf + s + 4)} > buf_len)
+      return kBcfTruncated;
+    declined |= bcf_load<uint16_t>(buf + s + 26) > max_allele ||
+                buf[s + 31] > max_fmt;
+  }
+  if (declined) return kBcfDeclined;
+
+  int64_t n_gt = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t s = starts[i];
+    const uint8_t* r = buf + s;
+    const int64_t end_shared = s + 8 + int64_t{bcf_load<uint32_t>(r)};
+    const int64_t rec_end = end_shared + int64_t{bcf_load<uint32_t>(r + 4)};
+    const uint32_t ns_nf = bcf_load<uint32_t>(r + 28);
+    const int64_t n_sample = ns_nf & 0xFFFFFF;
+    const int64_t na = bcf_load<uint16_t>(r + 26), nf = ns_nf >> 24;
+    BcfWalked w;
+    const int64_t rc = bcf_walk_record(buf, s, end_shared, rec_end, na, nf,
+                                       n_sample, gt_key, &w);
+    if (rc) { info[0] = i; return rc; }
+    chrom[i] = bcf_load<int32_t>(r + 8);
+    pos[i] = static_cast<int32_t>(bcf_load<uint32_t>(r + 12) + 1u);
+    rlen[i] = bcf_load<int32_t>(r + 16);
+    uint32_t qbits = bcf_load<uint32_t>(r + 20);
+    if (qbits == 0x7F800001u) qbits = 0x7FC00000u;    // MISSING -> NaN
+    std::memcpy(qual + i, &qbits, 4);
+    n_allele[i] = static_cast<int16_t>(na);
+    n_fmt[i] = static_cast<int16_t>(nf);
+    flags[i] = static_cast<uint8_t>((w.pass ? 1 : 0) | (w.snp ? 2 : 0));
+    if (declined) continue;             // only the checks still count
+    int8_t* row = dosage + i * stride;
+    if (!w.gt_typ) {
+      std::memset(row, 0xFF, static_cast<size_t>(stride));
+      continue;
+    }
+    ++n_gt;
+    if (w.gt_count > max_ploidy || n_sample > stride) {
+      declined = true;
+      continue;
+    }
+    const int32_t ploidy = static_cast<int32_t>(w.gt_count);
+    const GtRowFn gt_row = w.gt_typ == 1 ? gt_row_for<int8_t>(ploidy)
+                         : w.gt_typ == 2 ? gt_row_for<int16_t>(ploidy)
+                                         : gt_row_for<int32_t>(ploidy);
+    gt_row(buf + w.gt_off, n_sample, ploidy, row);
+    std::memset(row + n_sample, 0xFF, static_cast<size_t>(stride - n_sample));
+  }
+  info[1] = n_gt;
+  return declined ? kBcfDeclined : kBcfOk;
+}
+
+// The split guesser's candidate test (split/bcf_guesser.py::_find_record):
+// the smallest offset u in data[0, min(first_len, n - 32)) that passes the
+// plausibility sweep (_plausible_offsets: sane block lengths, CHROM inside
+// the contig dictionary, POS >= -1, rlen >= 0) and from which a chain of
+// ``min_chain`` records validates (_chain_ok).  ``partial``: the window
+// reaches EOF, so a chain must end exactly at its end.  -1 where there is
+// none.  One scan with an early exit: a real record start is found after a
+// record's length of candidates.  ``*edge`` = 1 where the answer leaned on
+// the window's end (a chain that reached it, a candidate too near it to be
+// tested): a longer window of the same bytes could answer otherwise.  With
+// ``*edge`` 0 it could not, whatever ``partial`` is — what lets the caller
+// ask a short window first.
+int64_t hbam_bcf_guess(const uint8_t* data, int64_t n, int64_t first_len,
+                       int64_t n_contigs, int32_t min_chain, int32_t partial,
+                       int32_t* edge) {
+  const int64_t hi = first_len < n - 32 ? first_len : n - 32;
+  *edge = 0;
+  for (int64_t u = 0; u < hi; ++u) {
+    // the sweep's mask is a little stricter than the chain's test on the
+    // block lengths (< 2^24 where that allows 2^24): a candidate passes both
+    if (bcf_load<uint32_t>(data + u) >= (1u << 24) ||
+        bcf_load<uint32_t>(data + u + 4) >= (1u << 24))
+      continue;
+    int64_t p = u;
+    int32_t count = 0;
+    bool ok = true;
+    while (count < min_chain) {
+      if (p == n) { *edge = 1; ok = count >= 1 || partial; break; }
+      if (p + 32 > n) { *edge = 1; ok = !partial && count >= 1; break; }
+      if (!bcf_plausible(data, p, n_contigs)) { ok = false; break; }
+      const int64_t nxt = p + 8 + int64_t{bcf_load<uint32_t>(data + p)} +
+                          int64_t{bcf_load<uint32_t>(data + p + 4)};
+      if (nxt > n) { *edge = 1; ok = !partial && count >= 1; break; }
+      p = nxt;
+      ++count;
+    }
+    if (ok) return u;
+  }
+  if (hi < first_len) *edge = 1;
+  return -1;
 }
 
 }  // extern "C"

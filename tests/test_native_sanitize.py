@@ -21,8 +21,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The subprocess body: build fixtures in memory and push them through every
 # native entry point (BGZF header walk, inflate, CRC, record walks,
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
-# kernel, the FASTQ tokenise + pack, the DEFLATE block finder / symbol
-# decoder / resolve).
+# kernel, the BCF record walker (chase / span columns / guess), the FASTQ
+# tokenise + pack, the DEFLATE block finder / symbol decoder / resolve).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -172,6 +172,75 @@ for typ, dt in _GT_DTYPES.items():
                 except BCFError:
                     pass
             assert got.tobytes() == want.tobytes()
+
+# the BCF record walker (chase, span columns, guess): every built record and
+# every corruption of tests/test_bcf_native_walk.py from a buffer that ends
+# on its last byte, a span cut at every byte (the chase and the guess must
+# not look past the cut: ASan's to see), one flipped byte anywhere, starts
+# past the buffer, windows shorter than a record head, and four Python
+# threads walking one buffer with the interpreter lock released (TSan)
+sys.path.insert(0, "tests")
+import test_bcf_native_walk as W
+from hadoop_bam_tpu.formats.bcf_columns import (
+    _MAX_ALLELE_ROUNDS, _MAX_FMT_ROUNDS, _MAX_GT_PLOIDY, decode_bcf_columns)
+def own(b):
+    return np.frombuffer(bytes(b), np.uint8).copy()     # ends on its last byte
+def walk(b, starts, pad=W.PAD):
+    try:
+        return native.bcf_span_columns(
+            own(b), np.asarray(starts, np.int64), 3, pad,
+            _MAX_ALLELE_ROUNDS, _MAX_FMT_ROUNDS, _MAX_GT_PLOIDY)
+    except BCFError:
+        return BCFError
+whdr = W._header()
+for name, recs in W.BUILT.items():
+    span = b"".join(recs)
+    starts_w, end_w, need_w = native.bcf_chase(own(span), 0, len(span))
+    assert (end_w, need_w) == (len(span), 0) and starts_w.size == len(recs)
+    assert walk(span, starts_w) not in (None, BCFError), name
+for name, (span, starts_w) in W.CORRUPT.items():
+    if starts_w is None:
+        starts_w = [0] if name != "truncated-record" else [0, len(W._GOOD)]
+    assert walk(span, starts_w) is BCFError, name
+span = b"".join(W.BUILT["a-span-of-every-layout"]) * 2
+clean, _, _ = native.bcf_chase(own(span), 0, len(span))
+for cut in range(len(span) + 1):
+    part = own(span[:cut])
+    got_s, end_w, need_w = native.bcf_chase(part, 0, cut)
+    assert end_w <= cut and (need_w == 0) == (end_w == cut)
+    assert (got_s == clean[:got_s.size]).all()
+    for partial in (False, True):
+        u, _edge = native.bcf_guess(part, cut, 3, 3, partial)
+        assert u < 0 or u in clean
+    if cut % 7 == 0:
+        walk(span[:cut], clean)         # starts past the cut: refused
+assert walk(span, [len(span)]) is BCFError
+assert walk(span, [len(span) - 31]) is BCFError
+assert walk(span, [-(1 << 62)]) is BCFError and walk(span, [1 << 62]) is BCFError
+assert native.bcf_guess(own(b"\x18" * 31), 31, 3, 3, False)[0] == -1
+assert native.bcf_guess(np.empty(0, np.uint8), 5, 3, 3, True)[0] == -1
+assert native.bcf_chase(np.empty(0, np.uint8), 0, 0)[0].size == 0
+assert native.bcf_chase(own(span), len(span) + 5, len(span) + 9)[2] > 0
+for _ in range(300):
+    bad = bytearray(span)
+    bad[rng.randrange(len(bad))] ^= 1 << rng.randrange(8)
+    walk(bad, clean)
+    walk(bad, clean, pad=3)
+    chased, _, _ = native.bcf_chase(own(bad), 0, len(bad))
+    walk(bad, chased)
+shared_w = own(span * 40)
+starts_w, _, _ = native.bcf_chase(shared_w, 0, shared_w.size)
+want_w = decode_bcf_columns(shared_w, whdr, W.PAD, starts_w)
+outs_w = [None] * 4
+def walk_thread(k):
+    outs_w[k] = decode_bcf_columns(shared_w, whdr, W.PAD)
+ts = [threading.Thread(target=walk_thread, args=(k,)) for k in range(4)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(60)
+for got_w in outs_w:
+    assert all(got_w[k].tobytes() == want_w[k].tobytes() for k in want_w)
 
 # FASTQ text -> payload tiles in one pass: the tiles of the NumPy twin on a
 # text that ends on its buffer's last byte; the same text cut at every byte
