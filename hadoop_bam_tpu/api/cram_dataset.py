@@ -4,10 +4,15 @@ The dataset face of hb/CRAMInputFormat.java + hb/CRAMRecordReader.java
 (SURVEY.md section 2.3, [VER? 7.1+]): spans align to container boundaries,
 each span decodes independently, and the reference source is resolved from
 config (``cram_reference_source_path`` — the analog of
-``hadoopbam.cram.reference-source-path``).
+``hadoopbam.cram.reference-source-path``): a ``.fai``-indexed,
+memory-mapped FASTA, so opening the dataset costs the index (and the
+header container), not the genome or the file.  ``cram_span_tiles`` is
+the unit ``hbam seq-stats`` runs a span through on ``plan.execute``
+(``parallel/pipeline.py::_cram_stats_impl``).
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, Iterator, List, Optional
 
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
@@ -19,6 +24,7 @@ from hadoop_bam_tpu.formats.cramio import read_cram_header
 from hadoop_bam_tpu.formats.sam import SamRecord
 from hadoop_bam_tpu.split.cram_planner import plan_cram_spans, read_cram_span
 from hadoop_bam_tpu.split.spans import FileByteSpan
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 
 class CramDataset:
@@ -64,35 +70,18 @@ class CramDataset:
         """Device-resident read batches (same layout as
         FastqDataset.tensor_batches) decoded from CRAM containers.
 
-        Columnar fast path: spans decode straight to columns
-        (read_cram_span_columns — the vectorized slice decoder, no
-        CramRecord objects) whose seq/qual runs pack directly into
-        tiles; slices outside the vectorizable layout fall back to the
-        record decoder with identical output."""
-        from hadoop_bam_tpu.api.read_datasets import (
-            ragged_to_payload_tiles,
-        )
+        Columnar fast path: each span decodes straight into tiles
+        (``cram_span_tiles`` — the vectorized slice decoder, no
+        CramRecord objects); slices outside the vectorizable layout fall
+        back to the record decoder with identical output."""
         from hadoop_bam_tpu.parallel.pipeline import (
             stream_read_tensor_batches,
         )
-        from hadoop_bam_tpu.split.cram_planner import (
-            read_cram_span_columns,
-        )
-
-        def tiles(span, geom):
-            cols = read_cram_span_columns(self.path, span,
-                                          header=self.header,
-                                          ref_source=self._ref_source)
-            # qual_lens gate == the CF_QUAL_STORED gate in _to_sam:
-            # without stored quals the column is already empty
-            return ragged_to_payload_tiles(
-                cols["seq_cat"], cols["seq_lens"], cols["qual_cat"],
-                cols["qual_lens"], geom.seq_stride, geom.qual_stride,
-                geom.max_len, qual_offset=0)
 
         yield from stream_read_tensor_batches(
             self.spans(num_spans) if spans is None else spans, None,
-            self.config, mesh, geometry, tiles_fn=tiles,
+            self.config, mesh, geometry,
+            tiles_fn=lambda span, geom: cram_span_tiles(self, span, geom),
             quarantine=quarantine, fmt="cram")
 
     def flagstat(self, mesh=None) -> Dict[str, int]:
@@ -112,6 +101,25 @@ class CramDataset:
         self._plan = [FileByteSpan.from_dict(d) for d in state["plan"]] \
             or None
         self._next_span = int(state["next_span"])
+
+
+def cram_span_tiles(ds: CramDataset, span: FileByteSpan, geometry):
+    """One span's (seq, qual, lengths) payload tiles: its containers read
+    with one read, each slice's columns (the columnar slice decoder, only
+    the blocks it asks for decompressed; the record decoder, converted,
+    where a slice's layout needs it) packed 4-bit into the span's rows
+    (``split/cram_planner.py::read_cram_span_tiles``; the qual_lens gate
+    is the CF_QUAL_STORED gate of ``_to_sam``).  ``cram.decode_busy_ns``
+    is the thread's CPU time for the whole of it."""
+    from hadoop_bam_tpu.split.cram_planner import read_cram_span_tiles
+
+    t_cpu = time.thread_time_ns()
+    try:
+        return read_cram_span_tiles(ds.path, span, header=ds.header,
+                                    ref_source=ds._ref_source,
+                                    geometry=geometry)
+    finally:
+        METRICS.count("cram.decode_busy_ns", time.thread_time_ns() - t_cpu)
 
 
 def open_cram(path: str, config: HBamConfig = DEFAULT_CONFIG) -> CramDataset:

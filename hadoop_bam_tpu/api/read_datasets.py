@@ -558,24 +558,43 @@ def qseq_text_to_payload_tiles(text: bytes, seq_stride: int,
 def ragged_to_payload_tiles(seq_cat: bytes, seq_lens: np.ndarray,
                             qual_cat: bytes, qual_lens: np.ndarray,
                             seq_stride: int, qual_stride: int,
-                            max_len: int, qual_offset: int = 0
+                            max_len: int, qual_offset: int = 0,
+                            out=None
                             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Concatenated ragged sequences/qualities -> payload tiles, fully
     vectorized (the packing half of fastq_text_to_payload_tiles, for
     producers that already hold decoded bytes — e.g. CRAM records).
 
-    ``qual_cat`` holds per-record quality runs of ``qual_lens`` bytes;
-    ``qual_offset`` is subtracted (0 when the bytes are already raw
-    Phred, 33 for printable ASCII).  Records with no quality simply have
-    qual_lens 0 — their tile rows stay zero."""
+    ``qual_cat`` holds per-record quality runs of ``qual_lens`` bytes
+    (bytes or a uint8 array, as ``seq_cat``); ``qual_offset`` is
+    subtracted (0 when the bytes are already raw Phred, 33 for printable
+    ASCII).  Records with no quality simply have qual_lens 0 — their tile
+    rows stay zero.  ``out`` = zeroed (seq, qual, lengths) rows to fill in
+    place (a span's slices packed into one span-sized set of tiles)."""
     n = seq_lens.size
-    seq = np.zeros((n, seq_stride), dtype=np.uint8)
-    qual = np.zeros((n, qual_stride), dtype=np.uint8)
-    lengths = np.minimum(seq_lens, max_len).astype(np.int32)
+    if out is None:
+        seq = np.zeros((n, seq_stride), dtype=np.uint8)
+        qual = np.zeros((n, qual_stride), dtype=np.uint8)
+        lengths = np.minimum(seq_lens, max_len).astype(np.int32)
+    else:
+        seq, qual, lengths = out
+        np.minimum(seq_lens, max_len, out=lengths, casting="unsafe")
     if n == 0:
         return seq, qual, lengths
     sbuf = np.frombuffer(seq_cat, dtype=np.uint8)
     qbuf = np.frombuffer(qual_cat, dtype=np.uint8)
+    rl, ql = int(seq_lens[0]), int(qual_lens[0])
+    if (not qual_offset and seq.flags.c_contiguous
+            and qual.flags.c_contiguous
+            and int(seq_lens.min()) == int(seq_lens.max()) == rl
+            and int(qual_lens.min()) == int(qual_lens.max())
+            and ql in (0, rl) and sbuf.size == n * rl
+            and qbuf.size == n * ql and native.available()):
+        # one read length (the overwhelmingly common case): one native
+        # pass, the interpreter lock released
+        native.pack_reads(sbuf, qbuf, n, rl, ql, _NIBBLE_CODE, max_len,
+                          seq, qual)
+        return seq, qual, lengths
     s0 = np.cumsum(seq_lens, dtype=np.int64) - seq_lens
     q0 = np.cumsum(qual_lens, dtype=np.int64) - qual_lens
 

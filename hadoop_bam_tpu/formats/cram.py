@@ -22,9 +22,14 @@ from __future__ import annotations
 
 import os
 import struct
+import time
 import zlib
 from dataclasses import dataclass, field
 from typing import BinaryIO, Iterator, List, Optional, Tuple
+
+import numpy as np
+
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 CRAM_MAGIC = b"CRAM"
 CRAM_MAJOR = 3
@@ -263,19 +268,28 @@ class Block:
     def from_raw(cls, raw: "RawBlock",
                  data: Optional[bytes] = None) -> "Block":
         """Materialize from a parsed-but-compressed block; ``data``
-        overrides decompression (the batched rANS path)."""
+        overrides decompression (the batched rANS path).  A block parsed
+        lazily has its CRC32 checked here, before it is decompressed."""
         aux = None
         if data is None:
-            if raw.method == FQZCOMP:
-                # capture the codec's own per-record lengths: the slice
-                # decoder cross-checks them against the RL series (the
-                # fqzcomp desync tripwire)
-                from hadoop_bam_tpu.formats.cram_fqzcomp import fqz_decode
-                aux = []
-                data = fqz_decode(raw.payload, raw.rsize, lens_out=aux)
-            else:
-                data = decompress_block_payload(raw.method, raw.payload,
-                                                raw.rsize)
+            raw.verify()
+            payload = bytes(raw.payload)
+            t_cpu = time.thread_time_ns()
+            with METRICS.span("cram.entropy_wall"):
+                if raw.method == FQZCOMP:
+                    # capture the codec's own per-record lengths: the
+                    # slice decoder cross-checks them against the RL
+                    # series (the fqzcomp desync tripwire)
+                    from hadoop_bam_tpu.formats.cram_fqzcomp import (
+                        fqz_decode,
+                    )
+                    aux = []
+                    data = fqz_decode(payload, raw.rsize, lens_out=aux)
+                else:
+                    data = decompress_block_payload(raw.method, payload,
+                                                    raw.rsize)
+            METRICS.count("cram.entropy_busy_ns",
+                          time.thread_time_ns() - t_cpu)
         if len(data) != raw.rsize:
             raise CRAMError(
                 f"block inflated to {len(data)} bytes, expected "
@@ -286,16 +300,29 @@ class Block:
 
 @dataclass
 class RawBlock:
-    """A block header + still-compressed payload (CRC already checked) —
-    the unit the batched entropy decoders consume."""
+    """A block header + still-compressed payload — the unit the batched
+    entropy decoders consume.  Parsed eagerly its CRC is already checked;
+    parsed lazily (``parse_raw_block(..., lazy=True)``) the payload is a
+    view of the container's bytes and ``crc`` is checked by ``verify``,
+    when the block is first decompressed."""
     method: int
     content_type: int
     content_id: int
     payload: bytes
     rsize: int
+    crc: Optional[int] = None          # still to check over ``covered``
+    covered: Optional[memoryview] = None
+
+    def verify(self) -> None:
+        if self.crc is None:
+            return
+        if zlib.crc32(self.covered) & 0xFFFFFFFF != self.crc:
+            raise CRAMError("block CRC32 mismatch")
+        self.crc = self.covered = None
 
 
-def parse_raw_block(buf: bytes, pos: int) -> Tuple[RawBlock, int]:
+def parse_raw_block(buf: bytes, pos: int,
+                    lazy: bool = False) -> Tuple[RawBlock, int]:
     start = pos
     method = buf[pos]
     ctype = buf[pos + 1]
@@ -303,6 +330,14 @@ def parse_raw_block(buf: bytes, pos: int) -> Tuple[RawBlock, int]:
     cid, pos = read_itf8(buf, pos)
     csize, pos = read_itf8(buf, pos)
     rsize, pos = read_itf8(buf, pos)
+    if lazy:
+        if csize < 0 or pos + csize + 4 > len(buf):
+            raise CRAMError("truncated block payload")
+        view = memoryview(buf)
+        (crc,) = struct.unpack_from("<I", buf, pos + csize)
+        raw = RawBlock(method, ctype, cid, view[pos:pos + csize], rsize,
+                       crc, view[start:pos + csize])
+        return raw, pos + csize + 4
     payload = bytes(buf[pos:pos + csize])
     if len(payload) != csize:
         raise CRAMError("truncated block payload")
@@ -312,6 +347,89 @@ def parse_raw_block(buf: bytes, pos: int) -> Tuple[RawBlock, int]:
     if zlib.crc32(buf[start:pos - 4]) & 0xFFFFFFFF != crc:
         raise CRAMError("block CRC32 mismatch")
     return RawBlock(method, ctype, cid, payload, rsize), pos
+
+
+class LazyBlock:
+    """A block of a lazily read container: header fields at once, the
+    payload checked and decompressed the first time ``data`` is read — so
+    a consumer that never asks for a content id never pays its entropy
+    decode (``cram.blocks_read_bytes`` / ``cram.blocks_skipped_bytes``
+    count the compressed bytes of each kind)."""
+
+    __slots__ = ("raw", "_block")
+
+    def __init__(self, raw: RawBlock):
+        self.raw = raw
+        self._block: Optional[Block] = None
+
+    content_type = property(lambda self: self.raw.content_type)
+    content_id = property(lambda self: self.raw.content_id)
+    method = property(lambda self: self.raw.method)
+
+    @property
+    def touched(self) -> bool:
+        return self._block is not None
+
+    def _get(self) -> Block:
+        if self._block is None:
+            self._block = Block.from_raw(self.raw)
+            METRICS.count("cram.blocks_read_bytes", len(self.raw.payload))
+        return self._block
+
+    @property
+    def data(self) -> bytes:
+        return self._get().data
+
+    @property
+    def aux(self) -> Optional[list]:
+        return self._get().aux
+
+
+def decompress_nx16_blocks(blocks, cids) -> None:
+    """Decompress, in ONE native call with the interpreter lock released,
+    every rANS Nx16 block of a lazily read container whose content id is
+    in ``cids`` and that nobody has decompressed yet — what a decoder is
+    about to ask for, a slice's worth of streams at once instead of a
+    Python round trip a block.  CRC32s are checked first.  A stream the
+    batch cannot decode is left to its own first read, which words the
+    error or takes the Python decoder."""
+    from hadoop_bam_tpu.utils import native
+
+    todo = [b for b in blocks
+            if isinstance(b, LazyBlock) and not b.touched
+            and b.method == RANSNx16 and b.content_id in cids]
+    if not todo or not native.available():
+        return
+    for b in todo:
+        b.raw.verify()
+    # untouched arrays: their pages are first written by the decoder,
+    # with the lock released (a bytearray is zeroed under it)
+    outs = [np.empty(b.raw.rsize, np.uint8) for b in todo]
+    t_cpu = time.thread_time_ns()
+    with METRICS.span("cram.entropy_wall"):
+        rc = native.rans_nx16_decode_batch([b.raw.payload for b in todo],
+                                           outs)
+    METRICS.count("cram.entropy_busy_ns", time.thread_time_ns() - t_cpu)
+    read = decoded = 0
+    for b, out, r in zip(todo, outs, rc):
+        if r == 0:
+            b._block = Block(b.raw.content_type, b.raw.content_id, out,
+                             b.raw.method)
+            read += len(b.raw.payload)
+            decoded += len(out)
+    if decoded:
+        METRICS.count("cram.blocks_read_bytes", read)
+        METRICS.count("cram.nx16_native_bytes", decoded)
+
+
+def count_skipped_blocks(blocks) -> int:
+    """Count (``cram.blocks_skipped_bytes``) and return the compressed
+    bytes of a lazily read container's blocks nobody decompressed."""
+    skipped = sum(len(b.raw.payload) for b in blocks
+                  if isinstance(b, LazyBlock) and not b.touched)
+    if skipped:
+        METRICS.count("cram.blocks_skipped_bytes", skipped)
+    return skipped
 
 
 def decompress_block_payload(method: int, payload: bytes, rsize: int) -> bytes:
@@ -329,8 +447,10 @@ def decompress_block_payload(method: int, payload: bytes, rsize: int) -> bytes:
         from hadoop_bam_tpu.formats.cram_codecs import rans4x8_decode
         return rans4x8_decode(payload)
     if method == RANSNx16:
-        from hadoop_bam_tpu.formats.cram_codecs_nx16 import rans_nx16_decode
-        return rans_nx16_decode(payload, rsize)
+        from hadoop_bam_tpu.formats.cram_codecs_nx16 import (
+            rans_nx16_decode_block,
+        )
+        return rans_nx16_decode_block(payload, rsize)
     if method == NAME_TOK:
         from hadoop_bam_tpu.formats.cram_name_tok3 import tok3_decode
         return tok3_decode(payload, rsize)
@@ -396,7 +516,7 @@ class ContainerHeader:
 @dataclass
 class Container:
     header: ContainerHeader
-    blocks: List[Block]
+    blocks: List[Block]         # LazyBlock each when read lazily
     offset: int = 0             # absolute file offset of the container start
 
 
@@ -431,21 +551,24 @@ assert len(EOF_CONTAINER) == 38, len(EOF_CONTAINER)
 # ---------------------------------------------------------------------------
 
 def read_container(buf: bytes, pos: int,
-                   rans_backend: Optional[str] = None
-                   ) -> Tuple[Container, int]:
+                   rans_backend: Optional[str] = None,
+                   lazy: bool = False) -> Tuple[Container, int]:
     """Parse one container.  All rANS blocks decode in ONE batch — the
     intra-container block parallelism the device decoder (ops/rans.py)
     exploits; ``rans_backend`` (default env HBAM_RANS_BACKEND or "host")
-    picks where."""
+    picks where.  ``lazy`` leaves every block compressed (``LazyBlock``):
+    a block is checked and decompressed when its ``data`` is first read."""
     offset = pos
     hdr, pos = ContainerHeader.from_buffer(buf, pos)
     end = pos + hdr.length
     raws: List[RawBlock] = []
     while pos < end:
-        raw, pos = parse_raw_block(buf, pos)
+        raw, pos = parse_raw_block(buf, pos, lazy=lazy)
         raws.append(raw)
     if pos != end:
         raise CRAMError("container blocks overran the declared length")
+    if lazy:
+        return Container(hdr, [LazyBlock(r) for r in raws], offset), pos
 
     backend = rans_backend or os.environ.get("HBAM_RANS_BACKEND", "host")
     if backend not in ("host", "device", "auto"):

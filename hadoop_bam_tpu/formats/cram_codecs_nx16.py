@@ -495,7 +495,44 @@ def rans_nx16_encode(data: bytes, flags: int = 0) -> bytes:
 def rans_nx16_decode(payload: bytes, out_size: Optional[int] = None
                      ) -> bytes:
     """Decode one rANS Nx16 stream.  ``out_size`` is required when the
-    stream carries the NOSZ flag (the CRAM block header supplies it)."""
+    stream carries the NOSZ flag (the CRAM block header supplies it).
+
+    One native call a stream with the interpreter lock released
+    (``utils/native.py::rans_nx16_decode``, every flag combination)
+    wherever the library loads; the Python decoder below runs on hosts
+    without it and for any stream the pass refuses, and is the tests'
+    oracle.  Decoded bytes are counted by path
+    (``cram.nx16_native_bytes`` / ``cram.nx16_python_bytes``)."""
+    return bytes(rans_nx16_decode_block(payload, out_size))
+
+
+def rans_nx16_decode_block(payload: bytes, out_size: Optional[int] = None
+                           ) -> "bytes | bytearray":
+    """``rans_nx16_decode`` for a CRAM block's payload: the native pass
+    writes straight into the ``bytearray`` it returns, with no copy of
+    the (often megabytes of) output into ``bytes``."""
+    from hadoop_bam_tpu.utils import native
+    from hadoop_bam_tpu.utils.metrics import METRICS
+
+    with normalize_truncation("rANS Nx16"):
+        if payload and native.available():
+            size = out_size
+            if not payload[0] & NX16_NOSZ:
+                size, _ = var_get_u32(payload, 1)
+            if size is None:
+                raise RansError("NOSZ stream needs an external size")
+            buf = bytearray(size)
+            if native.rans_nx16_decode(payload, size, into=buf) is not None:
+                METRICS.count("cram.nx16_native_bytes", size)
+                return buf
+        out = _rans_nx16_decode(payload, out_size)
+        METRICS.count("cram.nx16_python_bytes", len(out))
+        return out
+
+
+def rans_nx16_decode_python(payload: bytes,
+                            out_size: Optional[int] = None) -> bytes:
+    """The Python decoder alone (the oracle the native pass is held to)."""
     with normalize_truncation("rANS Nx16"):
         return _rans_nx16_decode(payload, out_size)
 
@@ -524,8 +561,10 @@ def _rans_nx16_decode(payload: bytes, out_size: Optional[int] = None
         outs = []
         for j in range(X):
             sub_len = (out_size - j + X - 1) // X
-            outs.append(rans_nx16_decode(
-                payload[pos:pos + clens[j]], sub_len))
+            sub = payload[pos:pos + clens[j]]
+            if not sub:
+                raise RansError("empty rANS Nx16 stream")
+            outs.append(_rans_nx16_decode(sub, sub_len))
             pos += clens[j]
         out = np.zeros(out_size, dtype=np.uint8)
         for j in range(X):

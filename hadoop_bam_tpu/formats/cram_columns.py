@@ -29,10 +29,12 @@ the TPU-shaped replacement for its per-record object assembly.
 """
 from __future__ import annotations
 
+import threading
 from typing import Dict, List, Optional
 
 import numpy as np
 
+from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.formats.cram_decode import (
     ByteArrayLenEncoding, ByteArrayStopEncoding, CF_DETACHED,
     CF_QUAL_STORED, CF_UNKNOWN_BASES, CompressionHeader, CRAMError,
@@ -49,6 +51,34 @@ _KNOWN_CODES = (frozenset(_ARRAY_FEATURE_SERIES)
 # read-consuming codes and their length source: arrays consume len(val),
 # X/B/i consume 1, everything else consumes 0 read bases
 _ONE_BASE_CODES = frozenset(b"XBi")
+
+
+# the data series the columnar decoder reads (never RN, the tags, TL or
+# the detached-mate MF / NS / NP / TS)
+COLUMNAR_SERIES = ("BF", "CF", "RI", "RL", "AP", "RG", "NF", "MQ", "FN",
+                   "FC", "FP", "DL", "RS", "PD", "HC", "BA", "QS", "BS",
+                   "BB", "QQ", "IN", "SC")
+
+
+def columnar_cids(comp: CompressionHeader) -> set:
+    """The content ids of the blocks the columnar decoder reads."""
+    from hadoop_bam_tpu.formats.cram_decode import _encoding_cids
+    return {cid for name in COLUMNAR_SERIES
+            if name in comp.data_series
+            for cid in _encoding_cids(comp.data_series[name])}
+
+
+_SCRATCH = threading.local()
+
+
+def _scratch_bases(n: int) -> np.ndarray:
+    """n bytes of this thread's reused base buffer (its pages touched
+    once, not every slice): the ``as_arrays`` caller packs a slice's
+    bases before it decodes the next."""
+    buf = getattr(_SCRATCH, "bases", None)
+    if buf is None or buf.size < n:
+        buf = _SCRATCH.bases = np.empty(max(n, 1 << 21), np.uint8)
+    return buf[:n]
 
 
 class _Ineligible(Exception):
@@ -242,7 +272,8 @@ def decode_slice_columns(comp: CompressionHeader, slice_hdr: SliceHeader,
                          ref_names: List[str],
                          ref_source: Optional[ReferenceSource] = None,
                          want_names: bool = False,
-                         codec_rec_lens=None) -> Optional[dict]:
+                         codec_rec_lens=None,
+                         as_arrays: bool = False) -> Optional[dict]:
     """One slice as columns, or None when only the record path can decode it.
 
     Returns {n, bf, cf, ref_id, rl, pos, mapq, read_group, seq_cat,
@@ -251,17 +282,22 @@ def decode_slice_columns(comp: CompressionHeader, slice_hdr: SliceHeader,
     lengths are ``seq_lens``/``qual_lens`` (0 encodes "*").  Output is
     byte-identical to assembling the same columns from
     ``decode_slice_records`` — tests/test_cram_columns.py pins this.
+    ``as_arrays`` hands ``seq_cat`` / ``qual_cat`` over as the uint8
+    arrays they were built in, not as copies in ``bytes``: views of this
+    thread's scratch buffer and of the QS block, to be consumed before
+    the thread decodes its next slice.
     """
     try:
         return _decode_columns(comp, slice_hdr, core, external, ref_names,
-                               ref_source, want_names, codec_rec_lens)
+                               ref_source, want_names, codec_rec_lens,
+                               as_arrays)
     except _Ineligible:
         return None
 
 
 def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
-                    want_names, codec_rec_lens=None):
-    pre = _predecode_fixed(comp, slice_hdr, external)
+                    want_names, codec_rec_lens=None, as_arrays=False):
+    pre = _predecode_fixed(comp, slice_hdr, external, record_fields=False)
     if pre is None:
         raise _Ineligible("fixed series not batch-decodable")
     n = slice_hdr.n_records
@@ -432,7 +468,9 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
     # ---- seq assembly ----------------------------------------------------
     seq_starts = np.cumsum(rl) - rl
     total_bases = int(rl.sum())
-    seq_flat = np.full(total_bases, ord("?"), np.uint8)
+    seq_flat = (_scratch_bases(total_bases) if as_arrays
+                else np.empty(total_bases, np.uint8))
+    seq_flat.fill(ord("?"))
 
     # unmapped records: BA block verbatim
     if unmapped_idx.size:
@@ -445,11 +483,12 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
 
     # reference fill for gaps/tails + 'X' substitution bases
     unknown_bases = (cf & CF_UNKNOWN_BASES) != 0
-    _fill_reference(
-        seq_flat, seq_starts, comp, slice_hdr, ref_names, ref_source,
-        mapped_idx, rl_mapped, pos, ref_id, unknown_bases,
-        fn, seg_firsts, seg_lens, rec_of_feat, fpos, gap, read_len,
-        ref_len, tail, masks, bulk)
+    with METRICS.span("cram.ref_fill_wall"):
+        _fill_reference(
+            seq_flat, seq_starts, comp, slice_hdr, ref_names, ref_source,
+            mapped_idx, rl_mapped, pos, ref_id, unknown_bases,
+            fn, seg_firsts, seg_lens, rec_of_feat, fpos, gap, read_len,
+            ref_len, tail, masks, bulk)
 
     # feature payload overlay (after ref fill, matching loop order)
     for code in (0x62, 0x49, 0x53):                  # 'b', 'I', 'S'
@@ -468,15 +507,21 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
     qual_lens = rl * qual_stored
     qual_starts = np.cumsum(qual_lens) - qual_lens
     total_quals = int(qual_lens.sum())
-    qual_flat = np.empty(total_quals, np.uint8)
+    qual_flat = np.empty(0, np.uint8)
     stored_idx = np.flatnonzero(qual_stored)
+    overlays = total_fn and bool((masks[0x71] | masks[0x51]
+                                  | masks[0x42]).any())
     if stored_idx.size:
         vals = _ragged_gather(qs_stream,
                               qs_rec_start[stored_idx]
                               + qs_feat_per_rec[stored_idx],
                               rl[stored_idx])
-        _ragged_copy(qual_flat, qual_starts[stored_idx], rl[stored_idx],
-                     vals)
+        if as_arrays and not overlays and vals.size == total_quals:
+            qual_flat = vals             # the QS stream itself: no copy
+        else:
+            qual_flat = np.empty(total_quals, np.uint8)
+            _ragged_copy(qual_flat, qual_starts[stored_idx],
+                         rl[stored_idx], vals)
     # overlays: only records with stored quals surface a qual column, so
     # scatter only into those segments.  Overlay writes CAN collide (a
     # 'Q' then an overlapping zero-advance 'q'), and the record path
@@ -516,17 +561,16 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
     drop = (unknown_bases & mapped) | (rl == 0)
     seq_lens[drop] = 0
     if drop.any():
-        keep_mask = np.repeat(~drop, rl)
-        seq_cat = seq_flat[keep_mask].tobytes()
         # seq starts must be recomputed by the consumer from seq_lens
-    else:
-        seq_cat = seq_flat.tobytes()
+        seq_flat = seq_flat[np.repeat(~drop, rl)]
 
     out = {
         "n": n, "bf": bf, "cf": cf, "ref_id": ref_id, "rl": rl,
         "pos": pos, "mapq": mapq, "read_group": rg,
-        "seq_cat": seq_cat, "seq_lens": seq_lens,
-        "qual_cat": qual_flat.tobytes(), "qual_lens": qual_lens,
+        "seq_cat": seq_flat if as_arrays else seq_flat.tobytes(),
+        "seq_lens": seq_lens,
+        "qual_cat": qual_flat if as_arrays else qual_flat.tobytes(),
+        "qual_lens": qual_lens,
     }
     if want_names:
         out.update(_decode_names(comp, bulk, n, cf))
@@ -675,6 +719,8 @@ def _fill_reference(seq_flat, seq_starts, comp, slice_hdr, ref_names,
                             codes)
         return
 
+    from hadoop_bam_tpu.utils import native
+
     pos_mapped = pos[mapped_idx]
     rid_mapped = ref_id[mapped_idx]
     take = ~unk_mapped & (ref_consumed > 0)
@@ -699,39 +745,31 @@ def _fill_reference(seq_flat, seq_starts, comp, slice_hdr, ref_names,
         hi = int((pos_mapped[sel] + ref_consumed[sel]).max())
         if hi - lo > (1 << 31):
             raise _Ineligible("reference window too large")
-        chunk = ref_source.get(name, lo, hi - lo)
-        ref_arr = np.frombuffer(chunk.encode("latin-1"), np.uint8)
+        ref_arr = ref_source.get_bytes(name, lo, hi - lo)
         base_of_rec = pos_mapped - lo        # junk outside sel, never used
         sel_feat = sel[feat_mpos] if total_fn else np.zeros(0, bool)
 
-        def gather(ref_offs, dst_idx):
-            if ref_offs.size == 0:
-                return
-            if bool(((ref_offs < 0) | (ref_offs >= ref_arr.size)).any()):
+        def copy_runs(dst_at, src_at, lens):
+            """seq_flat runs from the reference window, one native call
+            (this path runs only where the library loads: it needs the
+            native ITF8 batch decoder too)."""
+            if not native.copy_runs(seq_flat, ref_arr, dst_at, src_at, lens):
                 raise _Ineligible("reference run out of range")
-            seq_flat[dst_idx] = ref_arr[ref_offs]
 
         if need_gap:
             gm = (gap > 0) & sel_feat
             if bool(gm.any()):
                 # the gap spans read positions [fpos-gap, fpos)
-                dst = _ragged_targets(
-                    seq_starts[rec_of_feat[gm]] + (fpos - gap)[gm] - 1,
-                    gap[gm])
-                roff = _ragged_targets(
-                    base_of_rec[feat_mpos[gm]] + ref_before_gap[gm],
-                    gap[gm])
-                gather(roff, dst)
+                copy_runs(seq_starts[rec_of_feat[gm]] + (fpos - gap)[gm] - 1,
+                          base_of_rec[feat_mpos[gm]] + ref_before_gap[gm],
+                          gap[gm])
         if need_tail:
             tm = sel & (tail > 0)
             if bool(tm.any()):
-                dst = _ragged_targets(
-                    seq_starts[mapped_idx[tm]] + rl_mapped[tm] - tail[tm],
-                    tail[tm])
-                roff = _ragged_targets(
-                    base_of_rec[tm] + ref_consumed[tm] - tail[tm],
-                    tail[tm])
-                gather(roff, dst)
+                copy_runs(seq_starts[mapped_idx[tm]] + rl_mapped[tm]
+                          - tail[tm],
+                          base_of_rec[tm] + ref_consumed[tm] - tail[tm],
+                          tail[tm])
         if need_x:
             xm = x_mask & sel_feat
             if bool(xm.any()):

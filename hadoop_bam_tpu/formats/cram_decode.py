@@ -552,29 +552,47 @@ class ReferenceSource:
     def get(self, ref_name: str, start: int, length: int) -> str:
         raise NotImplementedError
 
+    def get_bytes(self, ref_name: str, start: int, length: int
+                  ) -> np.ndarray:
+        """``get`` as uint8 bases (what the columnar decoder gathers
+        from); sources that hold bytes override it."""
+        return np.frombuffer(self.get(ref_name, start, length).encode(
+            "latin-1"), np.uint8)
+
 
 class FastaReferenceSource(ReferenceSource):
+    """A FASTA as samtools holds one (``formats/fasta.py::IndexedFasta``):
+    its ``.fai`` — built in one pass and written beside it when absent —
+    and the file memory-mapped, so opening costs the index and a fetch
+    the bytes of its range, not the genome."""
+
     def __init__(self, path_or_text):
-        from hadoop_bam_tpu.formats.fasta import parse_fasta
-        if isinstance(path_or_text, (bytes, bytearray)):
-            data = bytes(path_or_text)
-        else:
-            with open(path_or_text, "rb") as f:
-                data = f.read()
-        self.seqs: Dict[str, str] = {}
-        for frag in parse_fasta(data, line_fragments=False):
-            self.seqs[frag.contig] = frag.sequence
+        from hadoop_bam_tpu.formats.fasta import IndexedFasta
+        from hadoop_bam_tpu.utils.metrics import METRICS
+
+        with METRICS.span("cram.reference_wall"):
+            self._fasta = IndexedFasta(path_or_text)
+
+    def get_bytes(self, ref_name: str, start: int, length: int
+                  ) -> np.ndarray:
+        """1-based ``start``; with ``get``'s Python-slice semantics."""
+        from hadoop_bam_tpu.utils.metrics import METRICS
+
+        if ref_name not in self._fasta:
+            raise CRAMError(f"reference contig {ref_name!r} not in source")
+        lo, hi, _ = slice(start - 1, start - 1 + length).indices(
+            self._fasta.length(ref_name))
+        with METRICS.span("cram.reference_wall"):
+            return self._fasta.fetch(ref_name, lo, hi)
 
     def get(self, ref_name: str, start: int, length: int) -> str:
-        seq = self.seqs.get(ref_name)
-        if seq is None:
-            raise CRAMError(f"reference contig {ref_name!r} not in source")
-        return seq[start - 1:start - 1 + length]
+        return self.get_bytes(ref_name, start, length).tobytes().decode(
+            "latin-1")
 
 
 class _EmbeddedReference(ReferenceSource):
     def __init__(self, bases: bytes, offset: int):
-        self.bases = bases.decode("ascii")
+        self.bases = bytes(bases).decode("ascii")
         self.offset = offset   # 1-based position of bases[0]
 
     def get(self, ref_name: str, start: int, length: int) -> str:
@@ -594,8 +612,13 @@ def _encoding_cids(enc: Encoding) -> List[int]:
 
 
 def _predecode_fixed(comp: CompressionHeader, slice_hdr: SliceHeader,
-                     external: Dict[int, bytes]) -> Optional[Dict]:
+                     external: Dict[int, bytes],
+                     record_fields: bool = True) -> Optional[Dict]:
     """Batch-decode the fixed int series of one slice, or None.
+
+    ``record_fields=False`` (the columnar stats path) leaves out what only
+    a SAM record needs — the detached-mate series MF / NS / NP / TS and
+    the tag-line index TL — so their blocks are never asked for.
 
     Eligible when the native ITF8 batch decoder is loadable and every
     fixed series is either a constant (0-bit Huffman, the spec idiom) or
@@ -658,11 +681,12 @@ def _predecode_fixed(comp: CompressionHeader, slice_hdr: SliceHeader,
     detached = (out["CF"] & CF_DETACHED) != 0
     downstream = ~detached & ((out["CF"] & CF_HAS_MATE_DOWNSTREAM) != 0)
     mapped = (out["BF"] & 0x4) == 0
-    counts = {"RL": n, "AP": n, "RG": n, "TL": n,
-              "MF": int(detached.sum()), "NS": int(detached.sum()),
-              "NP": int(detached.sum()), "TS": int(detached.sum()),
+    counts = {"RL": n, "AP": n, "RG": n,
               "NF": int(downstream.sum()),
               "MQ": int(mapped.sum()), "FN": int(mapped.sum())}
+    if record_fields:
+        n_det = int(detached.sum())
+        counts.update(TL=n, MF=n_det, NS=n_det, NP=n_det, TS=n_det)
     if multiref:
         counts["RI"] = n
     for name, k in counts.items():
@@ -670,9 +694,9 @@ def _predecode_fixed(comp: CompressionHeader, slice_hdr: SliceHeader,
         if v is None:
             return None
         out[name] = v
-    tl = out["TL"]
-    if tl.size and (int(tl.min()) < 0
-                    or int(tl.max()) >= len(comp.tag_dict)):
+    tl = out.get("TL")
+    if tl is not None and tl.size and (
+            int(tl.min()) < 0 or int(tl.max()) >= len(comp.tag_dict)):
         raise CRAMError(f"TL index {int(tl.max())} outside tag dictionary")
     if comp.ap_delta:
         out["POS"] = slice_hdr.start + np.cumsum(
@@ -743,6 +767,10 @@ def decode_slice_records(comp: CompressionHeader, slice_hdr: SliceHeader,
                          ref_names: List[str],
                          ref_source: Optional[ReferenceSource] = None,
                          codec_rec_lens=None) -> List[CramRecord]:
+    # a block the columnar batch decoded is a uint8 array: the cursors
+    # read bytes
+    external = {cid: d if isinstance(d, (bytes, bytearray)) else bytes(d)
+                for cid, d in external.items()}
     st = DecodeState(BitReader(core),
                      {cid: ByteCursor(d) for cid, d in external.items()})
     if slice_hdr.embedded_ref_id >= 0 and ref_source is None:

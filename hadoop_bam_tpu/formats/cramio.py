@@ -12,12 +12,14 @@ hb/KeyIgnoringCRAMRecordWriter.java (SURVEY.md sections 2.3/2.4).
 from __future__ import annotations
 
 import struct
+from collections.abc import Mapping
 from typing import BinaryIO, Dict, Iterator, List, Optional, Tuple, Union
 
 from hadoop_bam_tpu.formats.bam import SAMHeader
 from hadoop_bam_tpu.formats.cram import (
     Block, CRAMError, COMPRESSION_HEADER, Container, CORE_DATA,
-    EOF_CONTAINER, EXTERNAL_DATA, FILE_HEADER, FileDefinition, GZIP,
+    EOF_CONTAINER, EXTERNAL_DATA, FILE_HEADER, FQZCOMP, FileDefinition,
+    GZIP,
     MAPPED_SLICE_HEADER, read_container, scan_container_offsets,
 )
 from hadoop_bam_tpu.formats.cram_decode import (
@@ -134,9 +136,28 @@ def _read_all(source) -> bytes:
         return f.read()
 
 
+def _read_head(path: str) -> bytes:
+    """The file definition and the first (header) container of a CRAM on
+    disk — the bytes ``read_cram_header`` needs, not the file."""
+    from hadoop_bam_tpu.formats.cram import ContainerHeader
+
+    with open(path, "rb") as f:
+        buf = f.read(1 << 16)
+        try:
+            hdr, after = ContainerHeader.from_buffer(buf,
+                                                     FileDefinition.SIZE)
+        except (IndexError, ValueError, struct.error):
+            return buf + f.read()        # let the parser word the error
+        need = after + max(0, hdr.length)
+        if need > len(buf):
+            buf += f.read(need - len(buf))
+    return buf
+
+
 def read_cram_header(source) -> Tuple[SAMHeader, int]:
     """Returns (header, offset of the first data container)."""
-    buf = _read_all(source)
+    buf = (_read_all(source) if isinstance(source, (bytes, bytearray))
+           else _read_head(source))
     FileDefinition.from_bytes(buf)
     cont, after = read_container(buf, FileDefinition.SIZE)
     for blk in cont.blocks:
@@ -145,6 +166,29 @@ def read_cram_header(source) -> Tuple[SAMHeader, int]:
             text = blk.data[4:4 + l_text].decode("ascii", "replace")
             return SAMHeader.from_sam_text(text.rstrip("\x00")), after
     raise CRAMError("first container carries no FILE_HEADER block")
+
+
+class ExternalBlocks(Mapping):
+    """content id -> one slice's EXTERNAL payload.  A block of a lazily
+    read container (``read_container(..., lazy=True)``) is decompressed
+    on the first lookup of its id, so a decoder that never asks for an id
+    — the columnar stats path and the read names, tags and detached-mate
+    series — never pays for it; membership and ``len`` touch nothing."""
+
+    def __init__(self, blocks: Dict[int, Block]):
+        self._blocks = blocks
+
+    def __getitem__(self, cid: int) -> bytes:
+        return self._blocks[cid].data
+
+    def __contains__(self, cid) -> bool:
+        return cid in self._blocks
+
+    def __iter__(self):
+        return iter(self._blocks)
+
+    def __len__(self) -> int:
+        return len(self._blocks)
 
 
 def iter_container_slices(cont: Container):
@@ -169,16 +213,17 @@ def iter_container_slices(cont: Container):
         if len(body) != slice_hdr.n_blocks:
             raise CRAMError("slice block count overruns container")
         core = b""
-        external: Dict[int, bytes] = {}
+        ext_blocks: Dict[int, Block] = {}
         codec_rec_lens: Dict[int, list] = {}
         for b in body:
             if b.content_type == CORE_DATA:
                 core = b.data
             elif b.content_type == EXTERNAL_DATA:
-                external[b.content_id] = b.data
-                if b.aux:
+                ext_blocks[b.content_id] = b
+                if b.method == FQZCOMP and b.aux:
                     codec_rec_lens[b.content_id] = b.aux
-        yield comp, slice_hdr, core, external, codec_rec_lens
+        yield (comp, slice_hdr, core, ExternalBlocks(ext_blocks),
+               codec_rec_lens)
         i += 1 + slice_hdr.n_blocks
 
 

@@ -1767,34 +1767,88 @@ def cram_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
                         spans=None,
                         quarantine: Optional[QuarantineManifest] = None,
                         ) -> Dict[str, object]:
-    """GC / quality / base stats over a CRAM — the CRAM member of the
-    seq-stats driver family, fed by the columnar slice decoder
-    (CramDataset.tensor_batches) through the same fused stats step as
-    the BAM/FASTQ drivers."""
-    from hadoop_bam_tpu.api.cram_dataset import open_cram
+    """GC / quality / base stats over a CRAM — what ``hbam seq-stats
+    x.cram --reference x.fa`` runs: a thin plan builder
+    (``plan/builders.py::cram_stats_plan``) over the one executor, whose
+    runner (``_cram_stats_impl``) decodes container-aligned spans with
+    the columnar slice decoder into the FASTQ plan's tiles and step.  The
+    reference is ``config.cram_reference_source_path``."""
+    from hadoop_bam_tpu.plan import builders
+    from hadoop_bam_tpu.plan import executor as plan_executor
+
+    plan = builders.cram_stats_plan(path, config, geometry=geometry)
+    return plan_executor.execute(plan, config=config, mesh=mesh,
+                                 geometry=geometry, spans=spans,
+                                 quarantine=quarantine)
+
+
+def _cram_stats_impl(path: str, mesh: Optional[Mesh] = None,
+                     config: HBamConfig = DEFAULT_CONFIG,
+                     geometry: Optional[PayloadGeometry] = None,
+                     spans=None,
+                     quarantine: Optional[QuarantineManifest] = None,
+                     ) -> Dict[str, object]:
+    """The CRAM payload-stats implementation (executor runner).  A span is
+    a run of whole containers read with one read; the pool decodes it
+    (``api/cram_dataset.py::cram_span_tiles``: the blocks the columnar
+    decoder asks for, native rANS Nx16, bases gathered from the
+    memory-mapped reference) under the span retry policy, and from the
+    tiles on it is ``_read_stats_impl``'s feed and step."""
+    from hadoop_bam_tpu.api.cram_dataset import cram_span_tiles, open_cram
     from hadoop_bam_tpu.parallel.mesh import make_mesh
 
     if mesh is None:
         mesh = make_mesh()
+    n_dev = int(np.prod(mesh.devices.shape))
     if geometry is None:
         geometry = PayloadGeometry()
     ds = open_cram(path, config)
     if spans is None:
-        # pipeline-grain spans so container decode overlaps dispatch
-        # (the 128 MiB job grain would serialize them)
-        n_dev = int(np.prod(mesh.devices.shape))
         with METRICS.span("cram.plan_wall"):
-            spans = ds.spans(num_spans=pipeline_span_count(path, n_dev,
-                                                           config))
-    step = make_read_stats_step(mesh, geometry)
-    totals = _StatTotals()
+            spans = ds.spans(
+                num_spans=pipeline_span_count(path, n_dev, config))
+    spans = list(spans)
     if quarantine is None:
         quarantine = QuarantineManifest()
-    for b in ds.tensor_batches(mesh=mesh, geometry=geometry, spans=spans,
-                               quarantine=quarantine):
+    if quarantine.total_spans is None:
+        quarantine.total_spans = len(spans)
+    step = make_read_stats_step(mesh, geometry)
+    sharding = NamedSharding(mesh, P("data"))
+    pool = decode_pool(config)
+    totals = _StatTotals()
+
+    def decode(span):
+        with METRICS.wall_timer("pipeline.host_decode_wall"), \
+                METRICS.span("cram.host_decode_wall"):
+            out = decode_with_retry(
+                lambda s: cram_span_tiles(ds, s, geometry), span, config,
+                quarantine=quarantine)
+        return out if out is not None else (
+            np.empty((0, geometry.seq_stride), np.uint8),
+            np.empty((0, geometry.qual_stride), np.uint8),
+            np.empty((0,), np.int32))
+
+    # a span is in memory after its one read and its decode is compute,
+    # much of it NumPy under the interpreter lock: as for a text stream's
+    # tokenise, more spans in flight than cores only contend for the lock
+    # (and hold their columns), so the window is the text stream's
+    stream = _iter_windowed(pool, spans, decode, text_stream_window(),
+                            config=config)
+    specs = (geometry.seq_stride, geometry.qual_stride, (None, np.int32))
+    fp = FeedPipeline(n_dev, geometry.tile_records, specs,
+                      block_n=geometry.block_n,
+                      fixed_shape=geometry.fixed_shape, balance=True,
+                      config=config, fmt="cram")
+
+    def dispatch(arrays, counts):
+        args = [jax.device_put(a, sharding) for a in arrays]
+        c = jax.device_put(counts, sharding)
         with METRICS.span("cram.kernel_wall"):
-            totals.add(*step(b["seq_packed"], b["qual"], b["lengths"],
-                             b["n_records"]))
+            totals.add(*step(*args, c))  # async; drained once at the end
+        METRICS.count("pipeline.records", int(counts.sum()))
+        return (*args, c)  # in-flight handles: the ring waits before reuse
+
+    fp.feed(stream, dispatch)
     return _attach_quarantine(_payload_stats_result(totals), quarantine)
 
 

@@ -95,6 +95,35 @@ def read_stats_plan(path: str, config: Optional[HBamConfig] = None,
         sink=SinkIR.of("seq_stats"))
 
 
+def cram_stats_plan(path: str, config: Optional[HBamConfig] = None,
+                    geometry=None) -> PlanIR:
+    """CRAM payload stats (``hbam seq-stats x.cram --reference x.fa``):
+    spans at container boundaries at the pipeline grain, each decoded by
+    the columnar slice decoder — only the blocks it reads decompressed,
+    bases rebuilt from the reference and the features — into the same
+    4-bit seq + qual row tiles as the FASTQ plan, through the same
+    reduction.  The reference (``config.cram_reference_source_path``) is
+    part of the plan's identity."""
+    from hadoop_bam_tpu.parallel.pipeline import (
+        PayloadGeometry, pipeline_grain,
+    )
+
+    cfg = config if config is not None else DEFAULT_CONFIG
+    g = geometry if geometry is not None else PayloadGeometry()
+    ref = cfg.cram_reference_source_path
+    return PlanIR(
+        source=SourceIR(path, "cram"),
+        spans=SpansIR.auto(span_bytes=pipeline_grain(cfg)),
+        ops=(op_node("cram_decode",
+                     reference=os.path.abspath(ref) if ref else ""),
+             op_node("payload_pack", max_len=g.max_len,
+                     seq_stride=g.seq_stride, qual_stride=g.qual_stride,
+                     tile_records=g.tile_records,
+                     fixed_shape=g.fixed_shape),
+             op_node("seq_stats_reduce")),
+        sink=SinkIR.of("seq_stats"))
+
+
 def variant_stats_plan(path: str, config: Optional[HBamConfig] = None,
                        geometry=None) -> PlanIR:
     """VCF/BCF variant stats: pack (chrom, pos, flags, dosage) tiles,

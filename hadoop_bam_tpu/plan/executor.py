@@ -284,9 +284,15 @@ def _run_flagstat(plan: PlanIR, cfg: HBamConfig, kw: Dict):
 
 def _run_seq_stats(plan: PlanIR, cfg: HBamConfig, kw: Dict):
     """Payload stats, the runner by the source's format: BAM through the
-    fused decode feed, FASTQ / QSEQ text through the chunk tokeniser."""
+    fused decode feed, FASTQ / QSEQ text through the chunk tokeniser, CRAM
+    through the columnar slice decoder."""
     from hadoop_bam_tpu.parallel import pipeline
 
+    if plan.source.fmt == "cram":
+        return pipeline._cram_stats_impl(
+            plan.source.path, mesh=kw.get("mesh"), config=cfg,
+            geometry=kw.get("geometry"), spans=kw.get("spans"),
+            quarantine=kw.get("quarantine"))
     if plan.source.fmt in ("fastq", "qseq"):
         return pipeline._read_stats_impl(
             plan.source.path, plan.source.fmt, mesh=kw.get("mesh"),

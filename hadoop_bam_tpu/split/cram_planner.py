@@ -13,13 +13,17 @@ from __future__ import annotations
 import struct
 from typing import List, Optional, Tuple
 
+import numpy as np
+
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.formats.bam import SAMHeader
 from hadoop_bam_tpu.formats.cram import (
-    CRAMError, FileDefinition, read_container, scan_container_offsets,
+    CRAMError, FileDefinition, count_skipped_blocks,
+    decompress_nx16_blocks, read_container, scan_container_offsets,
 )
 from hadoop_bam_tpu.formats.cramio import decode_container, read_cram_header
 from hadoop_bam_tpu.split.spans import FileByteSpan
+from hadoop_bam_tpu.utils.metrics import METRICS
 
 
 def scan_cram_containers(source) -> List[Tuple[int, int, int]]:
@@ -112,24 +116,27 @@ def plan_cram_spans(path: str, *, num_spans: Optional[int] = None,
     return spans
 
 
-def _iter_span_containers(source, span: FileByteSpan):
+def _iter_span_containers(source, span: FileByteSpan, lazy: bool = False):
     """Containers whose start lies in [span.start, span.end) — the shared
     walk behind both the SAM and the pre-SAM span readers.
 
     Spans are container-aligned (plan_cram_spans ends every span exactly
-    on a container boundary), so only the span's own byte range is read
-    — a whole-file read per span would make total I/O quadratic in file
-    size once a file is planned into many pipeline-grain spans."""
+    on a container boundary), so only the span's own byte range is read,
+    with one read — a whole-file read per span would make total I/O
+    quadratic in file size once a file is planned into many
+    pipeline-grain spans.  ``lazy`` hands the containers over with their
+    blocks still compressed (``formats/cram.py::LazyBlock``)."""
     if isinstance(source, (bytes, bytearray)):
         buf = bytes(source)[span.start:span.end]
     else:
         with open(source, "rb") as f:
             f.seek(span.start)
             buf = f.read(max(0, span.end - span.start))
+    METRICS.count("cram.compressed_bytes", len(buf))
     pos = 0
     n = len(buf)
     while pos < n:
-        cont, pos = read_container(buf, pos)
+        cont, pos = read_container(buf, pos, lazy=lazy)
         if cont.header.is_eof:
             break
         yield cont
@@ -159,6 +166,59 @@ def read_cram_span_raw(source, span: FileByteSpan, *, header: SAMHeader,
     return out
 
 
+def read_cram_span_tiles(source, span: FileByteSpan, *, header: SAMHeader,
+                         ref_source, geometry):
+    """One span straight to payload tiles (seq [n, seq_stride], qual
+    [n, qual_stride], lengths [n]): each slice's columns — the columnar
+    decoder's arrays, only the blocks it asks for decompressed; the
+    record decoder, converted, where a slice's layout needs it — packed
+    into its own rows of the span's tiles, with no span-level copy of the
+    bases or qualities."""
+    from hadoop_bam_tpu.api.read_datasets import ragged_to_payload_tiles
+    from hadoop_bam_tpu.formats.cram_columns import (
+        columnar_cids, decode_slice_columns, records_to_columns,
+    )
+    from hadoop_bam_tpu.formats.cram_decode import decode_slice_records
+    from hadoop_bam_tpu.formats.cramio import iter_container_slices
+
+    conts = list(_iter_span_containers(source, span, lazy=True))
+    n = sum(c.header.n_records for c in conts)
+    seq = np.zeros((n, geometry.seq_stride), np.uint8)
+    qual = np.zeros((n, geometry.qual_stride), np.uint8)
+    lengths = np.zeros(n, np.int32)
+    at = 0
+    for cont in conts:
+        for comp, slice_hdr, core, external, codec_lens \
+                in iter_container_slices(cont):
+            decompress_nx16_blocks(cont.blocks, columnar_cids(comp))
+            cols = decode_slice_columns(comp, slice_hdr, core, external,
+                                        header.ref_names, ref_source,
+                                        codec_rec_lens=codec_lens,
+                                        as_arrays=True)
+            if cols is None:
+                cols = records_to_columns(decode_slice_records(
+                    comp, slice_hdr, core, external, header.ref_names,
+                    ref_source, codec_rec_lens=codec_lens))
+                METRICS.count("cram.record_path_records", cols["n"])
+            else:
+                METRICS.count("cram.columnar_records", cols["n"])
+            end = at + cols["n"]
+            if end > n:
+                raise CRAMError("slices hold more records than their "
+                                "container headers say")
+            ragged_to_payload_tiles(
+                cols["seq_cat"], cols["seq_lens"], cols["qual_cat"],
+                cols["qual_lens"], geometry.seq_stride,
+                geometry.qual_stride, geometry.max_len,
+                out=(seq[at:end], qual[at:end], lengths[at:end]))
+            at = end
+        count_skipped_blocks(cont.blocks)
+    if at != n:
+        raise CRAMError(f"slices hold {at} records, their container "
+                        f"headers {n}")
+    return seq, qual, lengths
+
+
 def read_cram_span_columns(source, span: FileByteSpan, *,
                            header: SAMHeader, ref_source=None,
                            want_names: bool = False) -> dict:
@@ -172,7 +232,7 @@ def read_cram_span_columns(source, span: FileByteSpan, *,
     from hadoop_bam_tpu.formats.cramio import iter_container_slices
 
     parts = []
-    for cont in _iter_span_containers(source, span):
+    for cont in _iter_span_containers(source, span, lazy=True):
         for comp, slice_hdr, core, external, codec_lens \
                 in iter_container_slices(cont):
             cols = decode_slice_columns(comp, slice_hdr, core, external,
@@ -185,5 +245,9 @@ def read_cram_span_columns(source, span: FileByteSpan, *,
                                          header.ref_names, ref_source,
                                          codec_rec_lens=codec_lens),
                     want_names=want_names)
+                METRICS.count("cram.record_path_records", cols["n"])
+            else:
+                METRICS.count("cram.columnar_records", cols["n"])
             parts.append(cols)
+        count_skipped_blocks(cont.blocks)
     return concat_columns(parts)

@@ -441,9 +441,12 @@ def cmd_explain(args) -> int:
     if args.op == "flagstat":
         plan = builders.flagstat_plan(args.path, cfg)
     elif args.op == "seq-stats":
-        from hadoop_bam_tpu.parallel.pipeline import TEXT_READ_EXTS
-        build = builders.read_stats_plan \
-            if args.path.lower().endswith(TEXT_READ_EXTS) \
+        from hadoop_bam_tpu.parallel.pipeline import (
+            CRAM_EXTS, TEXT_READ_EXTS,
+        )
+        path = args.path.lower()
+        build = builders.read_stats_plan if path.endswith(TEXT_READ_EXTS) \
+            else builders.cram_stats_plan if path.endswith(CRAM_EXTS) \
             else builders.seq_stats_plan
         plan = build(args.path, cfg)
     elif args.op == "vcf-stats":
@@ -1395,7 +1398,10 @@ def build_parser() -> argparse.ArgumentParser:
     sq.add_argument("--reference",
                     help="FASTA reference for reference-compressed CRAM "
                          "(the hadoopbam.cram.reference-source-path "
-                         "analog)")
+                         "analog): indexed by its .fai (built in one pass "
+                         "and written beside it when absent) and "
+                         "memory-mapped, so a scan reads the ranges its "
+                         "slices cover, not the genome")
     sq.set_defaults(fn=cmd_seq_stats, uses_device=True)
 
     vst = sub.add_parser("vcf-stats",

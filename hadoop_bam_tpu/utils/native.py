@@ -187,6 +187,21 @@ def load() -> Optional[ctypes.CDLL]:
             fn.restype = ctypes.c_int
             fn.argtypes = [i8p, ctypes.c_int64, ctypes.c_int64,
                            u32p, u32p, i8p, i8p, ctypes.c_int64]
+        lib.hbam_pack_reads.restype = None
+        lib.hbam_pack_reads.argtypes = [
+            i8p, i8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, i8p,
+            ctypes.c_int64, i8p, ctypes.c_int64, i8p, ctypes.c_int64]
+        lib.hbam_copy_runs.restype = ctypes.c_int64
+        lib.hbam_copy_runs.argtypes = [
+            i8p, ctypes.c_int64, i8p, ctypes.c_int64, i64p, i64p, i64p,
+            ctypes.c_int64]
+        lib.hbam_rans_nx16_decode_batch.restype = ctypes.c_int64
+        u64p = ctypes.POINTER(ctypes.c_uint64)
+        lib.hbam_rans_nx16_decode_batch.argtypes = [
+            u64p, i64p, u64p, i64p, i32p, ctypes.c_int64]
+        lib.hbam_rans_nx16_decode.restype = ctypes.c_int
+        lib.hbam_rans_nx16_decode.argtypes = [
+            i8p, ctypes.c_int64, i8p, ctypes.c_int64]
         lib.hbam_itf8_decode_batch.restype = ctypes.c_int64
         lib.hbam_itf8_decode_batch.argtypes = [
             i8p, ctypes.c_int64, ctypes.c_int64, i32p]
@@ -445,6 +460,89 @@ def rans_decode(order: int, buf: np.ndarray, ptr: int, freqs: np.ndarray,
             "corrupt rANS stream (ran out of bytes)" if rc == -1 else
             "corrupt rANS stream (final-state integrity check failed)")
     return out
+
+
+_NX16_ERRORS = {-1: "truncated rANS Nx16 stream (ran out of bytes)",
+                 -2: "corrupt rANS Nx16 stream (final-state integrity "
+                     "check failed)",
+                 -3: "corrupt rANS Nx16 stream (malformed)"}
+
+
+def rans_nx16_decode(payload, out_size: int,
+                     into: Optional[bytearray] = None
+                     ) -> Optional[np.ndarray]:
+    """One whole rANS Nx16 stream (``out_size``: its decoded size) in one
+    native call, the interpreter lock released, into a new array or the
+    caller's ``into`` (of ``out_size`` bytes; the array returned is a view
+    of it); None where the pass refuses the stream (the caller runs the
+    Python decoder).  Raises RansError on a truncated or corrupt stream."""
+    lib = load()
+    assert lib is not None
+    src = np.frombuffer(payload, dtype=np.uint8)
+    out = (np.empty(out_size, dtype=np.uint8) if into is None
+           else np.frombuffer(into, dtype=np.uint8))
+    rc = lib.hbam_rans_nx16_decode(_ptr(src, ctypes.c_uint8), src.size,
+                                   _ptr(out, ctypes.c_uint8), out_size)
+    if rc == 0:
+        return out
+    if rc in _NX16_ERRORS:
+        from hadoop_bam_tpu.formats.cram_codecs import RansError
+        raise RansError(_NX16_ERRORS[rc])
+    return None
+
+
+def rans_nx16_decode_batch(payloads: list, outs: list) -> np.ndarray:
+    """Many whole rANS Nx16 streams in ONE native call, the interpreter
+    lock released: ``payloads[i]`` (bytes-like) decodes into ``outs[i]``
+    (a writable buffer of its decoded size).  Returns each stream's code
+    (0, or a negative ``hbam_rans_nx16_decode`` code: the caller re-runs
+    those one at a time to word the error or take the Python decoder)."""
+    lib = load()
+    assert lib is not None
+    srcs = [np.frombuffer(p, np.uint8) for p in payloads]
+    dsts = [np.frombuffer(o, np.uint8) for o in outs]
+    addr = lambda arrs: np.array(  # noqa: E731
+        [a.ctypes.data for a in arrs], np.uint64)
+    size = lambda arrs: np.array([a.size for a in arrs], np.int64)  # noqa
+    src_a, src_n, dst_a, dst_n = addr(srcs), size(srcs), addr(dsts), \
+        size(dsts)
+    rc = np.zeros(len(srcs), np.int32)
+    lib.hbam_rans_nx16_decode_batch(
+        _ptr(src_a, ctypes.c_uint64), _ptr(src_n, ctypes.c_int64),
+        _ptr(dst_a, ctypes.c_uint64), _ptr(dst_n, ctypes.c_int64),
+        _ptr(rc, ctypes.c_int32), rc.size)
+    return rc
+
+
+def pack_reads(seq: np.ndarray, qual: np.ndarray, n: int, rl: int,
+               ql: int, lut: np.ndarray, max_len: int, seq_out: np.ndarray,
+               qual_out: np.ndarray) -> None:
+    """n reads of one length into zeroed payload tile rows, one native
+    pass (``hbam_pack_reads``), the interpreter lock released."""
+    lib = load()
+    assert lib is not None
+    lib.hbam_pack_reads(
+        _ptr(seq, ctypes.c_uint8), _ptr(qual, ctypes.c_uint8), n, rl, ql,
+        _ptr(lut, ctypes.c_uint8), max_len, _ptr(seq_out, ctypes.c_uint8),
+        seq_out.shape[1], _ptr(qual_out, ctypes.c_uint8),
+        qual_out.shape[1])
+
+
+def copy_runs(dst: np.ndarray, src: np.ndarray, dst_at: np.ndarray,
+              src_at: np.ndarray, lens: np.ndarray) -> bool:
+    """dst[dst_at[i]:+lens[i]] = src[src_at[i]:+lens[i]] for every run, one
+    native call; False (nothing copied past the first bad run) when a run
+    falls outside either buffer."""
+    lib = load()
+    assert lib is not None
+    d_at = np.ascontiguousarray(dst_at, np.int64)
+    s_at = np.ascontiguousarray(src_at, np.int64)
+    ln = np.ascontiguousarray(lens, np.int64)
+    src = np.ascontiguousarray(src, np.uint8)
+    return lib.hbam_copy_runs(
+        _ptr(dst, ctypes.c_uint8), dst.size, _ptr(src, ctypes.c_uint8),
+        src.size, _ptr(d_at, ctypes.c_int64), _ptr(s_at, ctypes.c_int64),
+        _ptr(ln, ctypes.c_int64), ln.size) == 0
 
 
 def itf8_decode_batch(buf: np.ndarray, count: int

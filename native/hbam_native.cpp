@@ -2007,3 +2007,506 @@ int hbam_fused_finish(void* h, int64_t* tail, int64_t* n_rows,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// rANS Nx16 decode (CRAM 3.1 block method 5 [SPEC CRAMcodecs]): a whole
+// stream, transforms included, in one call.  Mirrors
+// formats/cram_codecs_nx16.py byte for byte — that module stays the oracle
+// and the fallback: the same uint7 sizes, the same RLE'd alphabet grammar,
+// the same frequency renormalisation, ONE 16-bit renormalisation step a
+// symbol, N = 4 or 32 interleaved states (order-1: N contiguous fragments),
+// and the STRIPE / RLE / PACK / CAT / NOSZ layouts as that module reads
+// them.  Returns 0, or kNxTrunc (ran out of bytes), kNxState (a final state
+// is not 2^15), kNxBad (malformed), kNxRefused (nesting the caller should
+// hand to the Python decoder).
+// ---------------------------------------------------------------------------
+
+namespace {
+
+constexpr int kNxTrunc = -1;
+constexpr int kNxState = -2;
+constexpr int kNxBad = -3;
+constexpr int kNxRefused = -4;
+constexpr int64_t kNxLow = 1 << 15;
+constexpr uint8_t kNxOrder1 = 0x01, kNxX32 = 0x04, kNxStripe = 0x08,
+                  kNxNosz = 0x10, kNxCat = 0x20, kNxRle = 0x40,
+                  kNxPack = 0x80;
+
+int nx_var(const uint8_t* b, int64_t n, int64_t* pos, int64_t* v) {
+  int64_t x = 0;
+  for (int k = 0;; ++k) {
+    if (*pos >= n) return kNxTrunc;
+    uint8_t c = b[(*pos)++];
+    if (k >= 8) return kNxBad;          // past 56 bits: no real size
+    x = (x << 7) | (c & 0x7F);
+    if (!(c & 0x80)) break;
+  }
+  *v = x;
+  return 0;
+}
+
+// The ascending-symbol alphabet with its run bytes (cram_codecs.py's
+// _read_symbol_table grammar).
+int nx_alphabet(const uint8_t* b, int64_t n, int64_t* pos,
+                std::vector<int>* syms) {
+  syms->clear();
+  if (*pos >= n) return kNxTrunc;
+  int j = b[(*pos)++];
+  int rle = 0;
+  for (;;) {
+    syms->push_back(j);
+    if (rle > 0) {
+      --rle;
+      ++j;
+    } else {
+      if (*pos >= n) return kNxTrunc;
+      int nxt = b[(*pos)++];
+      if (nxt == j + 1) {
+        if (*pos >= n) return kNxTrunc;
+        rle = b[(*pos)++];
+        j = nxt;
+      } else if (nxt == 0) {
+        break;
+      } else {
+        j = nxt;
+      }
+    }
+  }
+  return 0;
+}
+
+// One frequency table, renormalised to 1 << shift as _read_freqs_nx16 does
+// (floor scaling, present symbols at least 1, the drift on the first
+// largest).
+int nx_freqs(const uint8_t* b, int64_t n, int64_t* pos, int shift,
+             int64_t* freqs) {
+  std::vector<int> syms;
+  int rc = nx_alphabet(b, n, pos, &syms);
+  if (rc) return rc;
+  std::memset(freqs, 0, 256 * sizeof(int64_t));
+  for (int s : syms) {
+    int64_t f;
+    if ((rc = nx_var(b, n, pos, &f))) return rc;
+    if (s > 255 || f > (int64_t(1) << 32)) return kNxBad;
+    freqs[s] = f;
+  }
+  int64_t total = 0;
+  for (int s = 0; s < 256; ++s) total += freqs[s];
+  const int64_t want = int64_t(1) << shift;
+  if (total != want && total > 0) {
+    int64_t sum = 0;
+    for (int s = 0; s < 256; ++s) {
+      int64_t c = freqs[s];
+      int64_t f = c * want / total;
+      if (c > 0 && f == 0) f = 1;
+      freqs[s] = f;
+      sum += f;
+    }
+    int64_t drift = want - sum;
+    if (drift != 0) {
+      int jmax = 0;
+      for (int s = 1; s < 256; ++s)
+        if (freqs[s] > freqs[jmax]) jmax = s;
+      if (freqs[jmax] + drift < 1) return kNxBad;
+      freqs[jmax] += drift;
+    }
+  }
+  return 0;
+}
+
+// cum[257] and the slot -> symbol map of one table (NumPy's clipped slice
+// assignment: slots past the table are dropped, earlier rows kept).
+void nx_tables(const int64_t* freqs, int shift, int64_t* cum,
+               uint8_t* slot2sym) {
+  const int64_t size = int64_t(1) << shift;
+  cum[0] = 0;
+  for (int s = 0; s < 256; ++s) cum[s + 1] = cum[s] + freqs[s];
+  for (int s = 0; s < 256; ++s) {
+    if (!freqs[s]) continue;
+    int64_t lo = cum[s] < size ? cum[s] : size;
+    int64_t hi = cum[s + 1] < size ? cum[s + 1] : size;
+    for (int64_t k = lo; k < hi; ++k) slot2sym[k] = static_cast<uint8_t>(s);
+  }
+}
+
+inline int nx_renorm(const uint8_t* b, int64_t n, int64_t* pos, int64_t* x) {
+  if (*x < kNxLow) {
+    if (*pos + 1 >= n) return kNxTrunc;
+    *x = (*x << 16) | (b[*pos] | (int64_t(b[*pos + 1]) << 8));
+    *pos += 2;
+  }
+  return 0;
+}
+
+int nx_states(const uint8_t* b, int64_t n, int64_t* pos, int N,
+              int64_t* states) {
+  if (*pos + 4 * N > n) return kNxTrunc;
+  for (int j = 0; j < N; ++j) {
+    uint32_t s;
+    std::memcpy(&s, b + *pos + 4 * j, 4);
+    states[j] = s;
+  }
+  *pos += 4 * N;
+  return 0;
+}
+
+int nx_order0(const uint8_t* b, int64_t n, int64_t pos, int64_t out_size,
+              int N, int shift, uint8_t* out) {
+  int64_t freqs[256], cum[257];
+  int rc = nx_freqs(b, n, &pos, shift, freqs);
+  if (rc) return rc;
+  std::vector<uint8_t> slot2sym(size_t(1) << shift, 0);
+  nx_tables(freqs, shift, cum, slot2sym.data());
+  int64_t states[32];
+  if ((rc = nx_states(b, n, &pos, N, states))) return rc;
+  const int64_t mask = (int64_t(1) << shift) - 1;
+  const uint8_t* s2s = slot2sym.data();
+  int j = 0;
+  for (int64_t i = 0; i < out_size; ++i) {
+    int64_t x = states[j];
+    int64_t m = x & mask;
+    uint8_t s = s2s[m];
+    out[i] = s;
+    x = freqs[s] * (x >> shift) + m - cum[s];
+    if ((rc = nx_renorm(b, n, &pos, &x))) return rc;
+    states[j] = x;
+    if (++j == N) j = 0;
+  }
+  for (int k = 0; k < N; ++k)
+    if (states[k] != kNxLow) return kNxState;
+  return 0;
+}
+
+int nx_order1(const uint8_t* b, int64_t n, int64_t pos, int64_t out_size,
+              int N, uint8_t* out) {
+  if (pos >= n) return kNxTrunc;
+  const int lead = b[pos++];
+  const int shift = lead >> 4;
+  const int64_t size = int64_t(1) << shift;
+  std::vector<uint8_t> tbl;
+  const uint8_t* tb = b;
+  int64_t tn = n, tpos = pos;
+  int rc;
+  if (lead & 1) {
+    // the context tables are themselves an order-0 4-way stream
+    int64_t ulen, clen;
+    if ((rc = nx_var(b, n, &pos, &ulen))) return rc;
+    if ((rc = nx_var(b, n, &pos, &clen))) return rc;
+    if (ulen > (int64_t(1) << 24)) return kNxRefused;
+    int64_t avail = n - pos < clen ? n - pos : clen;
+    tbl.assign(size_t(ulen), 0);
+    if ((rc = nx_order0(b + pos, avail, 0, ulen, 4, 12, tbl.data())))
+      return rc;
+    pos += clen;
+    tb = tbl.data();
+    tn = ulen;
+    tpos = 0;
+  }
+  std::vector<int> ctxs;
+  if ((rc = nx_alphabet(tb, tn, &tpos, &ctxs))) return rc;
+  std::vector<int64_t> freqs(256 * 256, 0), cums(256 * 257, 0);
+  std::vector<uint8_t> slot2sym(size_t(256) * size_t(size), 0);
+  for (int c : ctxs) {
+    if (c > 255) return kNxBad;
+    if ((rc = nx_freqs(tb, tn, &tpos, shift, &freqs[size_t(c) * 256])))
+      return rc;
+    nx_tables(&freqs[size_t(c) * 256], shift, &cums[size_t(c) * 257],
+              &slot2sym[size_t(c) * size_t(size)]);
+  }
+  if (!(lead & 1)) pos = tpos;
+  int64_t states[32];
+  if ((rc = nx_states(b, n, &pos, N, states))) return rc;
+  const int64_t mask = size - 1;
+  const int64_t q = out_size / N;
+  int ctx[32];
+  for (int j = 0; j < N; ++j) ctx[j] = 0;
+  auto step = [&](int j, int64_t at) -> int {
+    int64_t x = states[j];
+    int64_t m = x & mask;
+    const int c = ctx[j];
+    uint8_t s = slot2sym[size_t(c) * size_t(size) + size_t(m)];
+    out[at] = s;
+    x = freqs[size_t(c) * 256 + s] * (x >> shift) + m
+        - cums[size_t(c) * 257 + s];
+    int r = nx_renorm(b, n, &pos, &x);
+    states[j] = x;
+    ctx[j] = s;
+    return r;
+  };
+  // every fragment advances one symbol a round, in j order, while all are
+  // running; the last (the longest) then runs alone
+  for (int64_t i = 0; i < q; ++i)
+    for (int j = 0; j < N; ++j)
+      if ((rc = step(j, j * q + i))) return rc;
+  for (int64_t at = (N - 1) * q + q; at < out_size; ++at)
+    if ((rc = step(N - 1, at))) return rc;
+  for (int k = 0; k < N; ++k)
+    if (states[k] != kNxLow) return kNxState;
+  return 0;
+}
+
+int64_t nx_packed_size(int64_t n, int64_t nsym) {
+  if (nsym <= 1) return 0;
+  if (nsym <= 2) return (n + 7) / 8;
+  if (nsym <= 4) return (n + 3) / 4;
+  return (n + 1) / 2;
+}
+
+// Decode one stream of ``out_size`` bytes into out[0, out_size): the size
+// the stream states (or, under NOSZ, the caller's) must be out_size.
+int nx_decode(const uint8_t* b, int64_t n, int64_t out_size, uint8_t* out,
+              int depth) {
+  if (depth > 4) return kNxRefused;
+  if (n <= 0) return kNxBad;
+  int64_t pos = 0;
+  const uint8_t flags = b[pos++];
+  int rc;
+  if (!(flags & kNxNosz)) {
+    int64_t own;
+    if ((rc = nx_var(b, n, &pos, &own))) return rc;
+    if (own != out_size) return kNxBad;
+  }
+  if (out_size == 0) return 0;
+
+  if (flags & kNxStripe) {
+    if (pos >= n) return kNxTrunc;
+    const int X = b[pos++];
+    if (X == 0) std::memset(out, 0, size_t(out_size));
+    std::vector<int64_t> clens(X);
+    for (int j = 0; j < X; ++j)
+      if ((rc = nx_var(b, n, &pos, &clens[j]))) return rc;
+    std::vector<uint8_t> sub;
+    for (int j = 0; j < X; ++j) {
+      const int64_t sub_len = (out_size - j + X - 1) / X;
+      const int64_t avail = pos >= n ? 0
+          : (n - pos < clens[j] ? n - pos : clens[j]);
+      if (avail <= 0) return kNxBad;
+      sub.assign(size_t(sub_len), 0);
+      if ((rc = nx_decode(b + pos, avail, sub_len, sub.data(), depth + 1)))
+        return rc;
+      for (int64_t i = 0; i < sub_len; ++i) out[j + i * X] = sub[i];
+      pos += clens[j];
+    }
+    return 0;
+  }
+
+  const uint8_t* pack_syms = nullptr;
+  int64_t nsym = 0;
+  if (flags & kNxPack) {
+    if (pos >= n) return kNxTrunc;
+    nsym = b[pos++];
+    pack_syms = b + pos;
+    if (pos + nsym > n) return kNxTrunc;
+    pos += nsym;
+  }
+  const int64_t packed = (flags & kNxPack) ? nx_packed_size(out_size, nsym)
+                                           : out_size;
+  std::vector<uint8_t> rle_meta_buf;
+  const uint8_t* rle_meta = nullptr;
+  int64_t rle_meta_len = 0, lit_len = 0;
+  if (flags & kNxRle) {
+    int64_t mlen;
+    if ((rc = nx_var(b, n, &pos, &mlen))) return rc;
+    if (mlen & 1) {
+      mlen >>= 1;
+      if (pos + mlen > n) return kNxTrunc;
+      rle_meta = b + pos;
+      rle_meta_len = mlen;
+      pos += mlen;
+    } else {
+      mlen >>= 1;
+      int64_t clen;
+      if ((rc = nx_var(b, n, &pos, &clen))) return rc;
+      // the symbols and a run length of at most 5 bytes a literal: more
+      // than that is no stream this size could hold
+      if (mlen > 257 + 5 * packed) return kNxBad;
+      rle_meta_buf.assign(size_t(mlen), 0);
+      if ((rc = nx_order0(b, n, pos, mlen, 4, 12, rle_meta_buf.data())))
+        return rc;
+      rle_meta = rle_meta_buf.data();
+      rle_meta_len = mlen;
+      pos += clen;
+    }
+    if ((rc = nx_var(b, n, &pos, &lit_len))) return rc;
+    if (lit_len > packed) return kNxBad;   // a literal is >= 1 output byte
+  }
+  const int64_t stage_size = (flags & kNxRle) ? lit_len : packed;
+
+  // the entropy stage writes straight into the output when no transform
+  // follows it
+  const bool direct = !(flags & (kNxRle | kNxPack));
+  std::vector<uint8_t> stage_buf;
+  uint8_t* stage;
+  if (direct) {
+    stage = out;
+  } else {
+    stage_buf.assign(size_t(stage_size), 0);
+    stage = stage_buf.data();
+  }
+  if (flags & kNxCat) {
+    if (pos > n || n - pos < stage_size) return kNxTrunc;
+    std::memcpy(stage, b + pos, size_t(stage_size));
+  } else {
+    const int N = (flags & kNxX32) ? 32 : 4;
+    rc = (flags & kNxOrder1) ? nx_order1(b, n, pos, stage_size, N, stage)
+                             : nx_order0(b, n, pos, stage_size, N, 12, stage);
+    if (rc) return rc;
+  }
+
+  std::vector<uint8_t> rle_out;
+  const uint8_t* cur = stage;
+  int64_t cur_len = stage_size;
+  if (flags & kNxRle) {
+    const int64_t target = packed;
+    if (rle_meta_len < 1) return kNxTrunc;
+    int64_t mp = 0;
+    int n_use = rle_meta[mp++];
+    if (n_use == 0) n_use = 256;
+    bool use[256] = {false};
+    for (int k = 0; k < n_use; ++k) {
+      if (mp >= rle_meta_len) return kNxTrunc;
+      use[rle_meta[mp++]] = true;
+    }
+    uint8_t* dst;
+    if (flags & kNxPack) {
+      rle_out.assign(size_t(target), 0);
+      dst = rle_out.data();
+    } else {
+      dst = out;
+    }
+    int64_t o = 0;
+    for (int64_t i = 0; i < stage_size; ++i) {
+      const uint8_t s = stage[i];
+      int64_t run = 1;
+      if (use[s]) {
+        int64_t r;
+        if ((rc = nx_var(rle_meta, rle_meta_len, &mp, &r))) return rc;
+        run = r + 1;
+      }
+      if (o + run > target) return kNxBad;   // expands past its size
+      std::memset(dst + o, s, size_t(run));
+      o += run;
+    }
+    if (o != target) return kNxBad;
+    cur = dst;
+    cur_len = target;
+  }
+  if (flags & kNxPack) {
+    uint8_t* dst = out;
+    if (nsym <= 1) {
+      if (nsym == 0) return kNxBad;
+      std::memset(dst, pack_syms[0], size_t(out_size));
+      return 0;
+    }
+    uint8_t table[256] = {0};
+    for (int64_t k = 0; k < nsym; ++k) table[k] = pack_syms[k];
+    const int bits = nsym <= 2 ? 1 : nsym <= 4 ? 2 : 4;
+    const int per = 8 / bits;
+    const int vmask = (1 << bits) - 1;
+    if (cur_len * per < out_size) return kNxBad;
+    for (int64_t i = 0; i < out_size; ++i)
+      dst[i] = table[(cur[i / per] >> (bits * (i % per))) & vmask];
+  }
+  return 0;
+}
+
+}  // namespace
+
+extern "C" {
+
+// One whole rANS Nx16 stream into out[out_size]: out_size is the stream's
+// own size, or the caller's when the stream says NOSZ.  Returns 0 or a
+// kNx* code (a stream that states another size is kNxBad).
+int hbam_rans_nx16_decode(const uint8_t* buf, int64_t n, uint8_t* out,
+                          int64_t out_size) {
+  try {
+    return nx_decode(buf, n, out_size, out, 0);
+  } catch (...) {            // an allocation refused: the caller falls back
+    return kNxRefused;
+  }
+}
+
+}  // extern "C"
+
+extern "C" {
+
+// dst[dst_at[i] : dst_at[i] + lens[i]] = src[src_at[i] : src_at[i] +
+// lens[i]] for every run i, in order (the CRAM columnar decoder's
+// reference fill: match runs gathered from the reference window into the
+// reads).  Returns 0, or -(1 + i) for the first run outside either buffer
+// (nothing after it is copied).
+int64_t hbam_copy_runs(uint8_t* dst, int64_t dst_n, const uint8_t* src,
+                       int64_t src_n, const int64_t* dst_at,
+                       const int64_t* src_at, const int64_t* lens,
+                       int64_t n) {
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t d = dst_at[i], s = src_at[i], l = lens[i];
+    if (l < 0 || d < 0 || s < 0 || d > dst_n - l || s > src_n - l)
+      return -(1 + i);
+    std::memcpy(dst + d, src + s, size_t(l));
+  }
+  return 0;
+}
+
+}  // extern "C"
+
+extern "C" {
+
+// n whole rANS Nx16 streams in one call: stream i is the src_len[i] bytes
+// at address src[i] and decodes to the dst_len[i] bytes at address dst[i].
+// rc[i] is hbam_rans_nx16_decode's return for stream i; returns how many
+// failed.
+int64_t hbam_rans_nx16_decode_batch(const uint64_t* src,
+                                    const int64_t* src_len,
+                                    const uint64_t* dst,
+                                    const int64_t* dst_len, int32_t* rc,
+                                    int64_t n) {
+  int64_t bad = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    try {
+      rc[i] = nx_decode(reinterpret_cast<const uint8_t*>(src[i]),
+                        src_len[i], dst_len[i],
+                        reinterpret_cast<uint8_t*>(dst[i]), 0);
+    } catch (...) {
+      rc[i] = kNxRefused;
+    }
+    bad += rc[i] != 0;
+  }
+  return bad;
+}
+
+}  // extern "C"
+
+extern "C" {
+
+// n reads of one length rl, bases (ASCII, n * rl) and Phred qualities
+// (n * ql, ql = rl or 0) -> payload tile rows: the first min(rl, max_len)
+// bases as 4-bit codes through lut, two a byte high nibble first, into
+// seq_out[i * seq_stride, + seq_stride); the first min(ql, max_len,
+// qual_stride) qualities into qual_out[i * qual_stride, ...).  Rows are
+// zero beyond what is written (the caller hands zeroed rows).  The
+// uniform-length branch of api/read_datasets.py::ragged_to_payload_tiles.
+void hbam_pack_reads(const uint8_t* seq, const uint8_t* qual, int64_t n,
+                     int64_t rl, int64_t ql, const uint8_t* lut,
+                     int64_t max_len, uint8_t* seq_out, int64_t seq_stride,
+                     uint8_t* qual_out, int64_t qual_stride) {
+  const int64_t L = rl < max_len ? rl : max_len;
+  int64_t ks = (L + 1) / 2;
+  if (ks > seq_stride) ks = seq_stride;
+  int64_t kq = ql < max_len ? ql : max_len;
+  if (kq > qual_stride) kq = qual_stride;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint8_t* s = seq + i * rl;
+    uint8_t* o = seq_out + i * seq_stride;
+    for (int64_t k = 0; k < ks; ++k) {
+      const int64_t j = 2 * k;
+      const uint8_t hi = lut[s[j]];
+      const uint8_t lo = j + 1 < L ? lut[s[j + 1]] : 0;
+      o[k] = static_cast<uint8_t>((hi << 4) | lo);
+    }
+    if (kq > 0)
+      std::memcpy(qual_out + i * qual_stride, qual + i * ql, size_t(kq));
+  }
+}
+
+}  // extern "C"

@@ -877,3 +877,175 @@ def test_cram31_tok3_frames_decode_via_oracle():
     # every stream fully consumed: nothing the oracle failed to model
     for (p, t), (data, cur) in streams.items():
         assert cur == len(data), (p, t)
+
+
+# ---------------------------------------------------------------------------
+# rANS Nx16 in full — every flag — as a second clean-room decoder, for the
+# frames the CRAM 3.1 benchmark generator (tests/cram31_reference.py)
+# writes: PACK / RLE / ORDER-1 / CAT / NOSZ / STRIPE / X32.  It shares no
+# code with formats/cram_codecs_nx16.py: the table grammar, the order-1
+# context walk and the transform layouts are re-derived here from the
+# CRAMcodecs text (the PACK / RLE / STRIPE metadata as the module reads
+# them, [SPEC-recalled], see test_cram31_divergence_notes).
+# ---------------------------------------------------------------------------
+
+def _oracle_alphabet(buf, pos):
+    """Ascending symbols, a run byte after two consecutive ones."""
+    out, j, run = [], buf[pos], 0
+    pos += 1
+    while True:
+        out.append(j)
+        if run:
+            run -= 1
+            j += 1
+            continue
+        nxt = buf[pos]
+        pos += 1
+        if nxt == 0:
+            return out, pos
+        if nxt == j + 1:
+            run = buf[pos]
+            pos += 1
+        j = nxt
+
+
+def _oracle_table(buf, pos, total):
+    syms, pos = _oracle_alphabet(buf, pos)
+    freqs = [0] * 256
+    for s in syms:
+        freqs[s], pos = _uint7_get(buf, pos)
+    assert sum(freqs) == total, "the oracle reads normalised tables only"
+    return freqs, pos
+
+
+def _oracle_core(buf, pos, n, N, order):
+    """The entropy stage: N states, 16-bit renormalisation; order 0
+    deals symbol i to state i % N, order 1 gives state j the j-th of N
+    equal fragments (the last takes the rest) with context = the
+    fragment's previous symbol."""
+    if order == 0:
+        tables = {0: _oracle_table(buf, pos, 4096)}
+        pos = tables[0][1]
+        shift = 12
+    else:
+        lead = buf[pos]
+        pos += 1
+        shift = lead >> 4
+        assert not lead & 1, "compressed order-1 tables"
+        ctxs, pos = _oracle_alphabet(buf, pos)
+        tables = {}
+        for c in ctxs:
+            tables[c] = _oracle_table(buf, pos, 1 << shift)
+            pos = tables[c][1]
+    states = list(struct.unpack_from(f"<{N}I", buf, pos))
+    pos += 4 * N
+    out = bytearray(n)
+    mask = (1 << shift) - 1
+    q = n // N
+    if order == 0:
+        schedule = [(i % N, i, 0) for i in range(n)]
+    else:
+        schedule = [(j, j * q + i) for i in range(q) for j in range(N)]
+        schedule += [(N - 1, at) for at in range(N * q, n)]
+    ctx = [0] * N
+    for item in schedule:
+        j, at = item[0], item[1]
+        freqs = tables[ctx[j] if order else 0][0]
+        x = states[j]
+        slot = x & mask
+        cum, s = 0, 0
+        while cum + freqs[s] <= slot:
+            cum += freqs[s]
+            s += 1
+        out[at] = s
+        x = freqs[s] * (x >> shift) + slot - cum
+        if x < (1 << 15):
+            x = (x << 16) | struct.unpack_from("<H", buf, pos)[0]
+            pos += 2
+        states[j] = x
+        ctx[j] = s
+    assert all(x == 1 << 15 for x in states)
+    return bytes(out)
+
+
+def _oracle_nx16_full(payload, size=None):
+    flags, pos = payload[0], 1
+    if not flags & 0x10:
+        size, pos = _uint7_get(payload, pos)
+    if size == 0:
+        return b""
+    if flags & 0x08:                                   # STRIPE
+        x = payload[pos]
+        pos += 1
+        clens = []
+        for _ in range(x):
+            c, pos = _uint7_get(payload, pos)
+            clens.append(c)
+        out = bytearray(size)
+        for j in range(x):
+            sub = _oracle_nx16_full(payload[pos:pos + clens[j]],
+                                    len(range(j, size, x)))
+            out[j::x] = sub
+            pos += clens[j]
+        return bytes(out)
+    syms = None
+    if flags & 0x80:                                   # PACK
+        nsym = payload[pos]
+        syms = payload[pos + 1:pos + 1 + nsym]
+        pos += 1 + nsym
+    if flags & 0x40:                                   # RLE
+        mlen, pos = _uint7_get(payload, pos)
+        assert mlen & 1, "compressed RLE metadata"
+        meta = payload[pos:pos + (mlen >> 1)]
+        pos += mlen >> 1
+        stage_n, pos = _uint7_get(payload, pos)
+    elif syms is not None:
+        bits = 0 if len(syms) <= 1 else 1 if len(syms) <= 2 else \
+            2 if len(syms) <= 4 else 4
+        stage_n = -(-size * bits // 8)
+    else:
+        stage_n = size
+    if flags & 0x20:
+        stage = payload[pos:pos + stage_n]
+    else:
+        stage = _oracle_core(payload, pos, stage_n,
+                             32 if flags & 0x04 else 4, flags & 0x01)
+    if flags & 0x40:
+        n_use = meta[0] or 256
+        use, mp, out = set(meta[1:1 + n_use]), 1 + n_use, bytearray()
+        for s in stage:
+            run = 1
+            if s in use:
+                r, mp = _uint7_get(meta, mp)
+                run += r
+            out += bytes([s]) * run
+        stage = bytes(out)
+    if syms is not None:
+        bits = 0 if len(syms) <= 1 else 1 if len(syms) <= 2 else \
+            2 if len(syms) <= 4 else 4
+        if bits == 0:
+            return bytes(syms[:1]) * size
+        per = 8 // bits
+        stage = bytes(syms[(stage[i // per] >> (bits * (i % per)))
+                           & ((1 << bits) - 1)] for i in range(size))
+    assert len(stage) == size
+    return bytes(stage)
+
+
+@pytest.mark.parametrize("flags", [0x00, 0x01, 0x04, 0x05, 0x08, 0x20,
+                                   0x40, 0x41, 0x80, 0x81, 0xC0, 0xC1,
+                                   0x10, 0x0C])
+def test_rans_nx16_full_oracle_against_the_module_encoder(flags):
+    """The full oracle agrees with the module's own encoder on every flag
+    it writes (NOSZ with the size given out of band)."""
+    import random
+
+    from hadoop_bam_tpu.formats.cram_codecs_nx16 import rans_nx16_encode
+
+    rng = random.Random(flags)
+    for n, alpha in ((300, b"AC"), (700, b"!#+5"), (1000, b"ACGTN"),
+                     (2000, bytes(range(40)))):
+        data = bytes(sorted(rng.choice(alpha) for _ in range(n))) \
+            if n == 700 else bytes(rng.choice(alpha) for _ in range(n))
+        frame = rans_nx16_encode(data, flags)
+        assert _oracle_nx16_full(frame, n) == data

@@ -94,6 +94,48 @@ for order in (0, 1):
     got = cram_codecs.rans4x8_decode(enc)
     assert got == payload, order
 
+# rANS Nx16: every transform, one stream a call and a batch of them, and
+# every prefix and a byte flip of each frame (errors only, never a read
+# past the buffer); the run copy and the read pack of the CRAM path
+from hadoop_bam_tpu.formats import cram_codecs_nx16 as nx
+nx_frames = []
+for nx_flags in (0x00, 0x01, 0x04, 0x05, 0x08, 0x09, 0x20, 0x40, 0x41,
+                 0x80, 0x81, 0xC0, 0xC1, 0x0C):
+    nx_data = (bytes(sorted(payload[:1500])) if nx_flags & 0x40
+               else payload[:1500])
+    nx_frame = nx.rans_nx16_encode(nx_data, nx_flags)
+    assert native.rans_nx16_decode(nx_frame, len(nx_data)).tobytes() \
+        == nx_data
+    nx_frames.append((nx_frame, nx_data))
+nx_outs = [np.empty(len(d), np.uint8) for _f, d in nx_frames]
+assert not native.rans_nx16_decode_batch([f for f, _d in nx_frames],
+                                         nx_outs).any()
+assert all(o.tobytes() == d for o, (_f, d) in zip(nx_outs, nx_frames))
+for nx_frame, nx_data in nx_frames:
+    for cut in range(1, len(nx_frame), max(1, len(nx_frame) // 40)):
+        try:
+            native.rans_nx16_decode(nx_frame[:cut], len(nx_data))
+        except cram_codecs.RansError:
+            pass
+    nx_bad = bytearray(nx_frame)
+    nx_bad[len(nx_bad) // 2] ^= 0x55
+    try:
+        native.rans_nx16_decode(bytes(nx_bad), len(nx_data))
+    except cram_codecs.RansError:
+        pass
+nx_dst = np.zeros(64, np.uint8)
+assert native.copy_runs(nx_dst, np.arange(64, dtype=np.uint8),
+                        np.array([0, 60]), np.array([10, 0]),
+                        np.array([20, 4]))
+assert not native.copy_runs(nx_dst, np.arange(64, dtype=np.uint8),
+                            np.array([50]), np.array([0]), np.array([20]))
+from hadoop_bam_tpu.api.read_datasets import ragged_to_payload_tiles
+nx_seq = np.frombuffer(bytes(rng.choice(b"ACGTN") for _ in range(151 * 40)),
+                       np.uint8)
+_s, _q, nx_len = ragged_to_payload_tiles(nx_seq, np.full(40, 151), nx_seq,
+                                         np.full(40, 151), 96, 160, 160)
+assert (nx_len == 151).all()
+
 # fused single-pass decode: 4 workers over 1-block chunks maximizes
 # frontier/drain contention (inflate workers racing the walk), streamed
 # consumption, the CRC fold, and the early-cancel join path
