@@ -11,9 +11,14 @@ decodes a whole slice into exactly those columns with NumPy batch ops:
   byte order of each EXTERNAL stream is a pure function of the predecoded
   BF/CF/RL/FN/FC columns, so one pass of cumsums yields every record's
   slice of every stream;
-* seq/qual reconstruction (gap fill from the reference, feature overlay)
-  is NumPy scatter/gather over flat base arrays instead of the
-  per-record/per-base loop in ``cram_decode._decode_mapped``.
+* seq/qual reconstruction (gap fill from the reference, substitutions,
+  feature and quality overlays) is ONE native call a slice with the
+  interpreter lock released (``native/hbam_native.cpp::
+  hbam_cram_slice_rebuild``: ``cram_decode._decode_mapped``'s loop over the
+  whole slice), with NumPy scatter/gather over flat base arrays
+  (``_rebuild_numpy``) as its oracle and the path of a multi-reference
+  slice; ``cram.walk_native_records`` / ``cram.walk_numpy_records`` count
+  the slices' reads by path.
 
 Eligibility mirrors the htslib-default layout the predecode already
 requires (external or constant series, exclusive content ids, core block
@@ -29,6 +34,7 @@ the TPU-shaped replacement for its per-record object assembly.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Dict, List, Optional
 
@@ -47,6 +53,9 @@ _INT_FEATURE_SERIES = {0x44: "DL", 0x4E: "RS", 0x50: "PD", 0x48: "HC"}
 _KNOWN_CODES = (frozenset(_ARRAY_FEATURE_SERIES)
                 | frozenset(_INT_FEATURE_SERIES)
                 | frozenset(b"XBiQ"))
+# 1 at every byte that is no feature code
+_UNKNOWN_CODE = np.ones(256, np.int64)
+_UNKNOWN_CODE[sorted(_KNOWN_CODES)] = 0
 
 # read-consuming codes and their length source: arrays consume len(val),
 # X/B/i consume 1, everything else consumes 0 read bases
@@ -220,6 +229,23 @@ class _Bulk:
             raise _Ineligible("byte stream truncated")
         return np.frombuffer(block, np.uint8)
 
+    def whole(self, name: str, count: int = 0) -> Optional[np.ndarray]:
+        """The series' whole EXTERNAL block — or, given ``count``, a
+        constant's ``count`` bytes, as ``raw`` reads them — for a consumer
+        that checks what it reads against the length itself; None where
+        the series cannot be read at computed offsets (absent, another
+        codec, a content id it shares)."""
+        enc = self.comp.data_series.get(name)
+        if count and isinstance(enc, HuffmanEncoding) \
+                and enc._const is not None:
+            return np.full(count, enc._const & 0xFF, np.uint8)
+        if not isinstance(enc, ExternalEncoding):
+            return None
+        cid = enc.content_id
+        if self.cid_users.get(cid, 0) != 1 or cid not in self.external:
+            return None
+        return np.frombuffer(self.external[cid], np.uint8)
+
     def arrays(self, name: str, count: int):
         """(lens int64[count], vals uint8[sum lens]) of one byte-array
         series, in stream order."""
@@ -330,28 +356,149 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
     ref_id = (ri.astype(np.int64) if ri is not None
               else np.full(n, slice_hdr.ref_seq_id, np.int64))
 
+    total_fn = int(pre["FN"].sum())
+    if total_fn and "FC" not in pre:
+        raise _Ineligible("feature streams not batch-decodable")
+    fc = (pre["FC"].astype(np.uint8) if total_fn
+          else np.zeros(0, np.uint8))
+    fp = pre["FP"] if total_fn else np.zeros(0, np.int32)
+    counts = np.bincount(fc, minlength=256)
+    unknown = np.flatnonzero(counts * _UNKNOWN_CODE)
+    if unknown.size:
+        raise CRAMError(f"unknown feature code {chr(int(unknown[0]))!r}")
+
+    bulk = _Bulk(comp, external, _cid_user_counts(comp))
+    arrays = {code: bulk.arrays(series, int(counts[code]))
+              for code, series in _ARRAY_FEATURE_SERIES.items()}
+    int_vals = {}
+    for code, series in _INT_FEATURE_SERIES.items():
+        int_vals[code] = bulk.ints(series, int(counts[code]))
+        if code in (0x44, 0x4E) and int_vals[code].size \
+                and int(int_vals[code].min()) < 0:
+            raise _Ineligible("negative deletion/skip length")
+
+    # one native call rebuilds a slice of one reference; the NumPy twin a
+    # multi-reference slice, and any slice whose arguments the call refuses
+    rebuilt = None
+    if slice_hdr.ref_seq_id != -2:
+        rebuilt = _rebuild_native(
+            comp, slice_hdr, pre, bf, cf, rl, fc, fp, counts, arrays,
+            int_vals, bulk, ref_names, ref_source, codec_rec_lens, as_arrays)
+    native_walk = rebuilt is not None
+    if not native_walk:
+        rebuilt = _rebuild_numpy(
+            comp, slice_hdr, pre, bf, cf, rl, pos, ref_id, fc, fp, arrays,
+            int_vals, bulk, ref_names, ref_source, codec_rec_lens, as_arrays)
+    seq_flat, seq_lens, qual_flat, qual_lens, mapq = rebuilt
+
+    out = {
+        "n": n, "bf": bf, "cf": cf, "ref_id": ref_id, "rl": rl,
+        "pos": pos, "mapq": mapq, "read_group": rg,
+        "seq_cat": seq_flat if as_arrays else seq_flat.tobytes(),
+        "seq_lens": seq_lens,
+        "qual_cat": qual_flat if as_arrays else qual_flat.tobytes(),
+        "qual_lens": qual_lens,
+    }
+    if want_names:
+        out.update(_decode_names(comp, bulk, n, cf))
+    METRICS.count("cram.walk_native_records" if native_walk
+                  else "cram.walk_numpy_records", n)
+    return out
+
+
+def _rebuild_native(comp, slice_hdr, pre, bf, cf, rl, fc, fp, counts, arrays,
+                    int_vals, bulk, ref_names, ref_source, codec_rec_lens,
+                    as_arrays):
+    """(seq_flat, seq_lens, qual_flat, qual_lens, mapq) of a slice of one
+    reference in one native call with the interpreter lock released
+    (``utils/native.py::cram_slice_rebuild``; a second where the slice
+    reads the reference: the window is fetched between them), or None
+    where the call refuses its arguments.  Sends the slice to the record
+    path, or raises, where ``_rebuild_numpy`` does."""
+    from hadoop_bam_tpu.utils import native
+
+    stored = (cf & CF_QUAL_STORED) != 0
+    n_bq = int(counts[0x42] + counts[0x51])           # 'B', 'Q'
+    # a stream is asked for only where the slice reads it, as the twin
+    # asks: a block nobody reads stays compressed and counted as skipped
+    qs_total = int(rl[stored].sum()) + n_bq
+    ba_total = int(rl[(bf & 0x4) != 0].sum()) + int(counts[0x42]
+                                                   + counts[0x69])
+    n_x = int(counts[0x58])
+    streams = [bulk.whole("QS") if qs_total else None,
+               bulk.whole("BA") if ba_total else None,
+               bulk.whole("BS", n_x) if n_x else None]
+    for code in _ARRAY_FEATURE_SERIES:
+        streams.extend(arrays[code])
+    streams += [int_vals[0x44], int_vals[0x4E]]
+    total = int(rl.sum())
+    seq_out = (_scratch_bases(total) if as_arrays
+               else np.empty(total, np.uint8))
+    # with no overlay and no 'B' / 'Q' byte ahead of them, the stored
+    # qualities are the QS stream's first bytes as they lie
+    overlays = n_bq or counts[0x71]
+    qual_out = np.empty(total, np.uint8) if overlays else None
+    table = (_substitution_table(bytes(comp.substitution_matrix)) if n_x
+             else _NO_SUBSTITUTIONS)
+
+    def rebuild(ref=None, ref_lo=0):
+        return native.cram_slice_rebuild(
+            pre["BF"], pre["CF"], pre["RL"], pre["POS"], pre["FN"],
+            pre["MQ"], fc, fp, streams, table, ref_source is not None, ref,
+            ref_lo, seq_out, qual_out)
+
+    with METRICS.span("cram.ref_fill_wall"):
+        got = rebuild()
+        if got is None:
+            return None
+        if got[0] == native.CRAM_GEOMETRY:
+            raise _Ineligible("features outside their reads or QS short")
+        if codec_rec_lens:
+            # fqzcomp desync tripwire — shared with the record path
+            from hadoop_bam_tpu.formats.cram_decode import check_fqz_rec_lens
+            lens = rl[stored]
+            check_fqz_rec_lens(comp, codec_rec_lens,
+                               [int(v) for v in lens[lens > 0]],
+                               qs_feat_bytes=n_bq)
+        if got[0] == native.CRAM_NEED_REF:
+            lo, hi = int(got[1][0]), int(got[1][1])
+            if hi - lo > (1 << 31):
+                raise _Ineligible("reference window too large")
+            rid = slice_hdr.ref_seq_id
+            name = ref_names[rid] if 0 <= rid < len(ref_names) else "*"
+            got = rebuild(ref_source.get_bytes(name, lo, hi - lo), lo)
+            if got is None:
+                return None
+    rc, info, seq_lens, qual_lens, mapq = got
+    if rc == native.CRAM_BAD_SUBST:
+        raise CRAMError("invalid substitution code")
+    if rc != native.CRAM_OK:
+        raise _Ineligible("BA / BS short, or a read outside the reference")
+    seq_flat = seq_out[:int(info[2])]
+    if qual_out is not None:
+        qual_flat = qual_out[:int(info[3])]
+    else:
+        qs = streams[0] if streams[0] is not None else np.zeros(0, np.uint8)
+        qual_flat = qs[:int(info[3])]
+    return seq_flat, seq_lens, qual_flat, qual_lens, mapq
+
+
+def _rebuild_numpy(comp, slice_hdr, pre, bf, cf, rl, pos, ref_id, fc, fp,
+                   arrays, int_vals, bulk, ref_names, ref_source,
+                   codec_rec_lens, as_arrays):
+    """``_rebuild_native`` with NumPy batch ops: the oracle the native call
+    is tested against, and the path of a multi-reference slice."""
+    n = slice_hdr.n_records
     mapped = (bf & 0x4) == 0
     mapped_idx = np.flatnonzero(mapped)
     unmapped_idx = np.flatnonzero(~mapped)
     fn = pre["FN"].astype(np.int64)          # per mapped record
     total_fn = int(fn.sum())
-    if total_fn and "FC" not in pre:
-        raise _Ineligible("feature streams not batch-decodable")
-    fc = (pre["FC"].astype(np.uint8) if total_fn
-          else np.zeros(0, np.uint8))
-    fp = (pre["FP"].astype(np.int64) if total_fn
-          else np.zeros(0, np.int64))
+    fp = fp.astype(np.int64)
 
     mapq = np.zeros(n, np.int64)
     if mapped_idx.size:
         mapq[mapped_idx] = pre["MQ"].astype(np.int64)
-
-    unknown = set(int(c) for c in np.unique(fc)) - set(_KNOWN_CODES)
-    if unknown:
-        raise CRAMError(
-            f"unknown feature code {chr(sorted(unknown)[0])!r}")
-
-    bulk = _Bulk(comp, external, _cid_user_counts(comp))
 
     # ---- per-feature geometry -------------------------------------------
     rec_of_feat = np.repeat(mapped_idx, fn)          # sorted ascending
@@ -362,19 +509,8 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
     masks = {c: fc == c for c in
              (0x62, 0x71, 0x49, 0x53, 0x58, 0x42, 0x69, 0x51,
               0x44, 0x4E, 0x50, 0x48)}
-
-    arr_lens = {}
-    arr_vals = {}
-    for code, series in _ARRAY_FEATURE_SERIES.items():
-        cnt = int(masks[code].sum())
-        arr_lens[code], arr_vals[code] = bulk.arrays(series, cnt)
-    int_vals = {}
-    for code, series in _INT_FEATURE_SERIES.items():
-        cnt = int(masks[code].sum())
-        int_vals[code] = bulk.ints(series, cnt)
-        if code in (0x44, 0x4E) and int_vals[code].size \
-                and int(int_vals[code].min()) < 0:
-            raise _Ineligible("negative deletion/skip length")
+    arr_lens = {code: arrays[code][0] for code in _ARRAY_FEATURE_SERIES}
+    arr_vals = {code: arrays[code][1] for code in _ARRAY_FEATURE_SERIES}
 
     # read-consumed length of every feature
     read_len = np.zeros(total_fn, np.int64)
@@ -563,18 +699,7 @@ def _decode_columns(comp, slice_hdr, core, external, ref_names, ref_source,
     if drop.any():
         # seq starts must be recomputed by the consumer from seq_lens
         seq_flat = seq_flat[np.repeat(~drop, rl)]
-
-    out = {
-        "n": n, "bf": bf, "cf": cf, "ref_id": ref_id, "rl": rl,
-        "pos": pos, "mapq": mapq, "read_group": rg,
-        "seq_cat": seq_flat if as_arrays else seq_flat.tobytes(),
-        "seq_lens": seq_lens,
-        "qual_cat": qual_flat if as_arrays else qual_flat.tobytes(),
-        "qual_lens": qual_lens,
-    }
-    if want_names:
-        out.update(_decode_names(comp, bulk, n, cf))
-    return out
+    return seq_flat, seq_lens, qual_flat, qual_lens, mapq
 
 
 def records_to_columns(records, want_names: bool = False) -> dict:
@@ -781,6 +906,26 @@ def _fill_reference(seq_flat, seq_starts, comp, slice_hdr, ref_names,
                                     ref_arr[roff], bs_codes[xm[x_mask]])
 
 
+_NO_SUBSTITUTIONS = np.zeros((5, 4), np.uint8)
+
+
+@functools.lru_cache(maxsize=64)
+def _substitution_table(matrix: bytes) -> np.ndarray:
+    """table[row, code] -> the substituted base byte, rows A/C/G/T/N; 0
+    marks a code the matrix byte never produces (malformed), matching
+    substitute_base's raise.  Reversed j so the FIRST matching j wins on
+    duplicate codes, exactly like the scalar loop."""
+    table = np.zeros((5, 4), np.uint8)
+    for ri in range(5):
+        byte = matrix[ri]
+        candidates = [b for b in _BASES if b != _BASES[ri]]
+        for j in range(3, -1, -1):
+            code = (byte >> (6 - 2 * j)) & 3
+            table[ri, code] = ord(candidates[j])
+    table.flags.writeable = False
+    return table
+
+
 def _substitute_vec(matrix: bytes, ref_bases: np.ndarray,
                     codes: np.ndarray) -> np.ndarray:
     """Vectorized substitution-matrix application [SPEC section 10.6]."""
@@ -789,17 +934,7 @@ def _substitute_vec(matrix: bytes, ref_bases: np.ndarray,
     for i, b in enumerate(_BASES):
         row_of[ord(b)] = i
         row_of[ord(b.lower())] = i
-    # table[row, code] -> substituted base byte; 0 marks a code the matrix
-    # byte never produces (malformed), matching substitute_base's raise.
-    # Reversed j so the FIRST matching j wins on duplicate codes, exactly
-    # like the scalar loop.
-    table = np.zeros((5, 4), np.uint8)
-    for ri in range(5):
-        byte = matrix[ri]
-        candidates = [b for b in _BASES if b != _BASES[ri]]
-        for j in range(3, -1, -1):
-            code = (byte >> (6 - 2 * j)) & 3
-            table[ri, code] = ord(candidates[j])
+    table = _substitution_table(bytes(matrix))
     if codes.size and int(codes.max(initial=0)) > 3:
         raise CRAMError("invalid substitution code")
     out = table[row_of[ref_bases], codes]

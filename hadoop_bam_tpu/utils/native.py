@@ -248,6 +248,12 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_vcf_tokenize.argtypes = [
             i8p, ctypes.c_int64, ctypes.c_int64, i64p, i32p, i8p, i8sp,
             ctypes.c_int64, ctypes.c_int64]
+        lib.hbam_cram_slice_rebuild.restype = ctypes.c_int64
+        lib.hbam_cram_slice_rebuild.argtypes = [
+            ctypes.c_int64, i32p, i32p, i32p, i64p, i32p, i32p,
+            ctypes.c_int64, i8p, i32p, ctypes.c_int64, u64p, i64p, i8p,
+            ctypes.c_int32, i8p, ctypes.c_int64, ctypes.c_int64, i8p,
+            ctypes.c_int64, i8p, ctypes.c_int64, i64p, i64p, i64p, i64p]
         u16p = ctypes.POINTER(ctypes.c_uint16)
         lib.hbam_deflate_find_block.restype = ctypes.c_int64
         lib.hbam_deflate_find_block.argtypes = [
@@ -543,6 +549,83 @@ def copy_runs(dst: np.ndarray, src: np.ndarray, dst_at: np.ndarray,
         _ptr(dst, ctypes.c_uint8), dst.size, _ptr(src, ctypes.c_uint8),
         src.size, _ptr(d_at, ctypes.c_int64), _ptr(s_at, ctypes.c_int64),
         _ptr(ln, ctypes.c_int64), ln.size) == 0
+
+
+# hbam_cram_slice_rebuild's payload streams, in its order (QS, BA, BS, then
+# each byte array's lengths and values, then the D / N lengths) and what it
+# returns other than -1 (arguments it cannot take)
+CRAM_STREAMS = ("QS", "BA", "BS", "BB_len", "BB", "QQ_len", "QQ", "IN_len",
+                "IN", "SC_len", "SC", "DL", "RS")
+_CRAM_STREAM_DTYPES = tuple(np.int64 if k.endswith("_len") or k in ("DL", "RS")
+                            else np.uint8 for k in CRAM_STREAMS)
+CRAM_OK, CRAM_NEED_REF, CRAM_GEOMETRY, CRAM_DECLINED, CRAM_BAD_SUBST = range(5)
+
+
+def cram_slice_rebuild(bf: np.ndarray, cf: np.ndarray, rl: np.ndarray,
+                       pos: np.ndarray, fn: np.ndarray, mq: np.ndarray,
+                       fc: np.ndarray, fp: np.ndarray, streams: list,
+                       table: np.ndarray, have_source: bool,
+                       ref: Optional[np.ndarray], ref_lo: int,
+                       seq_out: np.ndarray, qual_out: Optional[np.ndarray]
+                       ) -> Optional[tuple]:
+    """A CRAM slice's bases and qualities rebuilt from its predecoded
+    columns in one native call, the interpreter lock released
+    (``hbam_cram_slice_rebuild``): the columns of ``formats/cram_columns.py::
+    _rebuild_numpy``.  ``streams`` holds the ``CRAM_STREAMS`` in order, each
+    an array (uint8; int64 for the lengths) or ``None`` where the series
+    cannot be read at computed offsets.  Returns (code, info, seq_lens,
+    qual_lens, mapq): ``CRAM_OK`` with ``seq_out[:info[2]]`` and
+    ``qual_out[:info[3]]`` written; ``CRAM_NEED_REF`` with the reference
+    window [info[0], info[1]) to fetch and call again with; or the check
+    that failed.  ``None`` where the pass refuses its arguments."""
+    lib = load()
+    assert lib is not None
+    i32 = [np.ascontiguousarray(a, np.int32) for a in (bf, cf, rl, fn, mq,
+                                                        fp)]
+    fc = np.ascontiguousarray(fc, np.uint8)
+    pos = np.ascontiguousarray(pos, np.int64)
+    n = int(i32[0].size)
+    if any(a.size != n for a in (i32[1], i32[2], pos)) \
+            or i32[3].size != i32[4].size or fc.size != i32[5].size \
+            or len(streams) != len(CRAM_STREAMS):
+        return None
+    for out in (seq_out, qual_out):
+        if out is not None and (out.dtype != np.uint8 or out.ndim != 1
+                                or not out.flags.c_contiguous
+                                or not out.flags.writeable):
+            raise ValueError("cram_slice_rebuild writes contiguous u8 rows")
+    if ref is not None:
+        ref = np.ascontiguousarray(ref, np.uint8)
+    held = [np.ascontiguousarray(a, dt) if a is not None else None
+            for a, dt in zip(streams, _CRAM_STREAM_DTYPES)]
+    addr = np.array([a.ctypes.data if a is not None else 0 for a in held],
+                    np.uint64)
+    size = np.array([a.size if a is not None else -1 for a in held],
+                    np.int64)
+    table = np.ascontiguousarray(table, np.uint8)
+    if table.size != 20:
+        return None
+    seq_lens, qual_lens, mapq = (np.empty(n, np.int64) for _ in range(3))
+    info = np.zeros(4, np.int64)
+    u8 = ctypes.POINTER(ctypes.c_uint8)
+    rc = int(lib.hbam_cram_slice_rebuild(
+        n, _ptr(i32[0], ctypes.c_int32), _ptr(i32[1], ctypes.c_int32),
+        _ptr(i32[2], ctypes.c_int32), _ptr(pos, ctypes.c_int64),
+        _ptr(i32[3], ctypes.c_int32), _ptr(i32[4], ctypes.c_int32),
+        int(i32[3].size), _ptr(fc, ctypes.c_uint8),
+        _ptr(i32[5], ctypes.c_int32), int(fc.size),
+        _ptr(addr, ctypes.c_uint64), _ptr(size, ctypes.c_int64),
+        _ptr(table, ctypes.c_uint8), int(bool(have_source)),
+        _ptr(ref, ctypes.c_uint8) if ref is not None else u8(),
+        int(ref.size) if ref is not None else 0, int(ref_lo),
+        _ptr(seq_out, ctypes.c_uint8), int(seq_out.size),
+        _ptr(qual_out, ctypes.c_uint8) if qual_out is not None else u8(),
+        int(qual_out.size) if qual_out is not None else 0,
+        _ptr(seq_lens, ctypes.c_int64), _ptr(qual_lens, ctypes.c_int64),
+        _ptr(mapq, ctypes.c_int64), _ptr(info, ctypes.c_int64)))
+    if rc < 0:
+        return None
+    return rc, info, seq_lens, qual_lens, mapq
 
 
 def itf8_decode_batch(buf: np.ndarray, count: int

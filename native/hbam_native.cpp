@@ -2510,3 +2510,364 @@ void hbam_pack_reads(const uint8_t* seq, const uint8_t* qual, int64_t n,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// A CRAM slice's bases and qualities rebuilt from its predecoded columns
+// [SPEC CRAM3 section 10.6]: the record loop of formats/cram_decode.py::
+// _decode_mapped run over a whole slice, features in the order that decoder
+// consumes them.  formats/cram_columns.py::_rebuild_numpy is the statement of
+// the semantics — which checks send a slice to the record path, in which
+// order, and which raise — the oracle this is tested against and the path of
+// multi-reference slices.  No threads: the decode pool's threads run it with
+// the interpreter lock released.
+// ---------------------------------------------------------------------------
+namespace {
+
+// the payload streams, in hbam_cram_slice_rebuild's src / src_n order: bytes,
+// but int64 for the byte arrays' lengths and the D / N lengths
+enum : int {
+  kCsQS = 0, kCsBA, kCsBS,
+  kCsBBLen, kCsBBVal, kCsQQLen, kCsQQVal, kCsINLen, kCsINVal, kCsSCLen,
+  kCsSCVal, kCsDL, kCsRS, kCsStreams
+};
+
+// what hbam_cram_slice_rebuild returns
+enum : int64_t {
+  kCramOk = 0,
+  kCramNeedRef = 1,     // fetch the reference window info[0], info[1] first
+  kCramGeometry = 2,    // features or the QS stream: the record path's slice
+  kCramDeclined = 3,    // the BA / BS streams or the reference: the same
+  kCramBadSubst = 4,    // a BS code the substitution matrix cannot take
+  kCramArgs = -1,       // arguments that disagree with each other
+};
+
+constexpr int32_t kCfQualStored = 0x1, kCfUnknownBases = 0x8;
+
+inline int cram_base_row(uint8_t b) {
+  switch (b) {
+    case 'A': case 'a': return 0;
+    case 'C': case 'c': return 1;
+    case 'G': case 'g': return 2;
+    case 'T': case 't': return 3;
+    default: return 4;
+  }
+}
+
+// the read and reference lengths of one feature, its array's length taken
+// from (and its cursor moved along) the stream its code reads
+struct CramCursors {
+  const int64_t* len[4];        // BB, QQ, IN, SC lengths
+  int64_t len_n[4];
+  int64_t at[4] = {0, 0, 0, 0};
+  int64_t val_at[4] = {0, 0, 0, 0};
+  const int64_t *dl, *rs;
+  int64_t dl_n, rs_n, dl_at = 0, rs_at = 0;
+  int64_t ba = 0, qs = 0, bs = 0;
+};
+
+inline int cram_array_slot(int32_t code) {
+  switch (code) {
+    case 'b': return 0;
+    case 'q': return 1;
+    case 'I': return 2;
+    case 'S': return 3;
+    default: return -1;
+  }
+}
+
+}  // namespace
+
+extern "C" {
+
+// Slice columns -> its reads' bases and qualities, the columns of
+// formats/cram_columns.py::decode_slice_columns.  Record i has flags bf[i],
+// cf[i], length rl[i], 1-based position pos[i]; the k-th mapped record has
+// mq[k] and fn[k] features, whose codes fc[] and position deltas fp[] run in
+// record order.  src[s] / src_n[s] is payload stream s (kCs*; src_n < 0: a
+// series that cannot be read at computed offsets).  table[5 * 4] is the
+// substitution matrix as base bytes by (reference row A C G T N, code), 0
+// where the matrix yields none.
+//
+// have_source 0: there is no reference.  Else ref[0, ref_n) holds the
+// reference from position ref_lo on; with ref == nullptr the window is not
+// fetched yet: where the slice reads the reference the call returns
+// kCramNeedRef with the window [info[0], info[1]) — positions, of the reads
+// that consume reference, from the first read's start to the furthest end —
+// and writes nothing; the caller fetches it and calls again.
+//
+// Writes, on kCramOk: seq_out[0, info[2]) the bases of every record whose
+// sequence is kept (not a mapped one with unknown bases, not empty),
+// seq_lens / qual_lens / mapq per record, and — qual_out != nullptr —
+// qual_out[0, info[3]) the qualities of the records that store them, their
+// 'B' / 'Q' / 'q' overlays applied in feature order.
+//
+// The checks run in the NumPy rebuild's order, so one input gives one
+// answer: kCramGeometry (overlapping features, a feature outside its read or
+// overrunning it, a QS stream shorter than the slice reads), kCramDeclined
+// (BA or BS short; a read that needs the reference with no source; a run
+// outside the window), kCramBadSubst (a BS code on a read with unknown bases
+// against the N row; then, after every run was found inside the window, on
+// the other reads against the reference's base).
+int64_t hbam_cram_slice_rebuild(
+    int64_t n, const int32_t* bf, const int32_t* cf, const int32_t* rl,
+    const int64_t* pos, const int32_t* fn, const int32_t* mq,
+    int64_t n_mapped, const uint8_t* fc, const int32_t* fp, int64_t n_feat,
+    const uint64_t* src, const int64_t* src_n, const uint8_t* table,
+    int32_t have_source, const uint8_t* ref, int64_t ref_n, int64_t ref_lo,
+    uint8_t* seq_out, int64_t seq_cap, uint8_t* qual_out, int64_t qual_cap,
+    int64_t* seq_lens, int64_t* qual_lens, int64_t* mapq, int64_t* info) {
+  if (n < 0 || n_mapped < 0 || n_feat < 0 || seq_cap < 0 || qual_cap < 0)
+    return kCramArgs;
+  const uint8_t* qs_src = reinterpret_cast<const uint8_t*>(src[kCsQS]);
+  const uint8_t* ba_src = reinterpret_cast<const uint8_t*>(src[kCsBA]);
+  const uint8_t* bs_src = reinterpret_cast<const uint8_t*>(src[kCsBS]);
+  const uint8_t* vals[4];
+  int64_t vals_n[4];
+  CramCursors c0;
+  for (int a = 0; a < 4; ++a) {
+    c0.len[a] = reinterpret_cast<const int64_t*>(src[kCsBBLen + 2 * a]);
+    c0.len_n[a] = src_n[kCsBBLen + 2 * a];
+    vals[a] = reinterpret_cast<const uint8_t*>(src[kCsBBVal + 2 * a]);
+    vals_n[a] = src_n[kCsBBVal + 2 * a];
+  }
+  c0.dl = reinterpret_cast<const int64_t*>(src[kCsDL]);
+  c0.rs = reinterpret_cast<const int64_t*>(src[kCsRS]);
+  c0.dl_n = src_n[kCsDL];
+  c0.rs_n = src_n[kCsRS];
+  const int64_t qs_n = src_n[kCsQS], ba_n = src_n[kCsBA], bs_n = src_n[kCsBS];
+
+  // pass 1: the geometry of every feature, what each stream must hold, the
+  // reads that need the reference and the window they span
+  {
+    CramCursors c = c0;
+    int64_t mi = 0, k = 0;
+    bool need = false, need_known = false, bad_unknown = false, take = false;
+    int64_t lo = std::numeric_limits<int64_t>::max();
+    int64_t hi = std::numeric_limits<int64_t>::min();
+    for (int64_t i = 0; i < n; ++i) {
+      const int64_t len = rl[i];
+      if (len < 0) return kCramArgs;
+      const bool stored = cf[i] & kCfQualStored;
+      if (bf[i] & 0x4) {
+        c.ba += len;
+        c.qs += stored ? len : 0;
+        continue;
+      }
+      if (mi >= n_mapped) return kCramArgs;
+      const int64_t nf = fn[mi++];
+      if (nf < 0 || nf > n_feat - k) return kCramArgs;
+      const bool unknown = cf[i] & kCfUnknownBases;
+      int64_t fpos = 0, prev_end = 1, ref_used = 0;
+      bool rec_need = false;
+      for (int64_t j = 0; j < nf; ++j, ++k) {
+        fpos += fp[k];
+        const int32_t code = fc[k];
+        int64_t rlen = 0, flen = 0, qlen = 0;
+        const int a = cram_array_slot(code);
+        if (a >= 0) {
+          if (c.at[a] >= c.len_n[a]) return kCramArgs;
+          const int64_t l = c.len[a][c.at[a]++];
+          if (l < 0 || l > vals_n[a] - c.val_at[a]) return kCramArgs;
+          c.val_at[a] += l;
+          if (code == 'q') qlen = l; else rlen = l;
+          if (code == 'b') flen = l;
+        } else {
+          switch (code) {
+            case 'X':
+              rlen = flen = 1;
+              rec_need = true;
+              if (unknown && c.bs < bs_n) {
+                const uint8_t b = bs_src[c.bs];
+                bad_unknown |= b > 3 || table[4 * 4 + b] == 0;
+              }
+              ++c.bs;
+              break;
+            case 'B': rlen = flen = 1; ++c.ba; ++c.qs; break;
+            case 'i': rlen = 1; ++c.ba; break;
+            case 'Q': ++c.qs; break;
+            case 'D':
+              if (c.dl_at >= c.dl_n) return kCramArgs;
+              flen = c.dl[c.dl_at++];
+              break;
+            case 'N':
+              if (c.rs_at >= c.rs_n) return kCramArgs;
+              flen = c.rs[c.rs_at++];
+              break;
+            case 'P': case 'H': break;
+            default: return kCramArgs;
+          }
+        }
+        const int64_t gap = fpos - prev_end;
+        if (gap < 0 || fpos < 1 || fpos - 1 + (rlen > 1 ? rlen : 1) > len ||
+            fpos - 1 + qlen > len)
+          return kCramGeometry;
+        rec_need |= gap > 0;
+        ref_used += gap + flen;
+        prev_end = fpos + rlen;
+      }
+      const int64_t tail = len - (prev_end - 1);
+      if (tail < 0) return kCramGeometry;
+      rec_need |= tail > 0;
+      ref_used += tail;
+      c.qs += stored ? len : 0;
+      need |= rec_need;
+      if (unknown) continue;
+      need_known |= rec_need;
+      if (ref_used > 0) {
+        take = true;
+        lo = pos[i] < lo ? pos[i] : lo;
+        hi = pos[i] + ref_used > hi ? pos[i] + ref_used : hi;
+      }
+    }
+    if (mi != n_mapped || k != n_feat) return kCramArgs;
+    if (c.qs > 0 && c.qs > qs_n) return kCramGeometry;
+    if (c.ba > 0 && c.ba > ba_n) return kCramDeclined;
+    if (need) {
+      if (!have_source && need_known) return kCramDeclined;
+      if (c.bs > 0 && c.bs > bs_n) return kCramDeclined;
+      if (bad_unknown) return kCramBadSubst;
+      if (have_source && take && ref == nullptr) {
+        info[0] = lo;
+        info[1] = hi;
+        return kCramNeedRef;
+      }
+    }
+    if (c.qs > 0 && qs_src == nullptr) return kCramArgs;
+  }
+
+  // pass 2: every record rebuilt; a run outside the reference window sends
+  // the slice to the record path wherever it lies, a BS code the matrix
+  // cannot take raises only where no run does
+  if (ref == nullptr) ref_n = 0;
+  CramCursors c = c0;
+  int64_t mi = 0, k = 0, seq_w = 0, qual_w = 0;
+  bool bad_subst = false;
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t len = rl[i];
+    const bool stored = cf[i] & kCfQualStored;
+    qual_lens[i] = stored ? len : 0;
+    if (bf[i] & 0x4) {
+      mapq[i] = 0;
+      seq_lens[i] = len;
+      if (len > seq_cap - seq_w) return kCramArgs;
+      if (len) std::memcpy(seq_out + seq_w, ba_src + c.ba, size_t(len));
+      seq_w += len;
+      c.ba += len;
+      if (stored) {
+        if (qual_out) {
+          if (len > qual_cap - qual_w) return kCramArgs;
+          if (len) std::memcpy(qual_out + qual_w, qs_src + c.qs, size_t(len));
+        }
+        qual_w += len;
+        c.qs += len;
+      }
+      continue;
+    }
+    mapq[i] = mq[mi];
+    const int64_t nf = fn[mi++];
+    const bool keep = !(cf[i] & kCfUnknownBases) && len > 0;
+    seq_lens[i] = keep ? len : 0;
+    if (keep && len > seq_cap - seq_w) return kCramArgs;
+    uint8_t* s = seq_out + seq_w;
+    const int64_t base = pos[i] - ref_lo;
+    // where this record's overlays start: its 'B' / 'Q' bytes in QS, its
+    // 'q' arrays in QQ
+    const int64_t k_rec = k, qs_rec = c.qs, qq_rec = c.at[1],
+                  qq_val_rec = c.val_at[1];
+    int64_t fpos = 0, rp = 1, ref_off = 0;
+    for (int64_t j = 0; j < nf; ++j, ++k) {
+      fpos += fp[k];
+      const int32_t code = fc[k];
+      const int64_t gap = fpos - rp;
+      if (gap > 0) {
+        if (keep) {
+          if (base + ref_off < 0 || base + ref_off > ref_n - gap)
+            return kCramDeclined;
+          std::memcpy(s + rp - 1, ref + base + ref_off, size_t(gap));
+        }
+        ref_off += gap;
+        rp += gap;
+      }
+      const int a = cram_array_slot(code);
+      if (a >= 0) {
+        const int64_t l = c.len[a][c.at[a]++];
+        if (code != 'q') {
+          if (keep && l)
+            std::memcpy(s + rp - 1, vals[a] + c.val_at[a], size_t(l));
+          rp += l;
+          if (code == 'b') ref_off += l;
+        }
+        c.val_at[a] += l;
+        continue;
+      }
+      switch (code) {
+        case 'X': {
+          const uint8_t b = bs_src[c.bs++];
+          if (keep) {
+            if (base + ref_off < 0 || base + ref_off >= ref_n)
+              return kCramDeclined;
+            const int row = cram_base_row(ref[base + ref_off]);
+            const uint8_t sub = b > 3 ? 0 : table[row * 4 + b];
+            bad_subst |= sub == 0;
+            s[rp - 1] = sub;
+          }
+          ++ref_off;
+          ++rp;
+          break;
+        }
+        case 'B':
+          if (keep) s[rp - 1] = ba_src[c.ba];
+          ++c.ba;
+          ++c.qs;
+          ++ref_off;
+          ++rp;
+          break;
+        case 'i':
+          if (keep) s[rp - 1] = ba_src[c.ba];
+          ++c.ba;
+          ++rp;
+          break;
+        case 'Q': ++c.qs; break;
+        case 'D': ref_off += c.dl[c.dl_at++]; break;
+        case 'N': ref_off += c.rs[c.rs_at++]; break;
+        default: break;                 // 'P', 'H'
+      }
+    }
+    const int64_t tail = len - (rp - 1);
+    if (tail > 0 && keep) {
+      if (base + ref_off < 0 || base + ref_off > ref_n - tail)
+        return kCramDeclined;
+      std::memcpy(s + rp - 1, ref + base + ref_off, size_t(tail));
+    }
+    if (keep) seq_w += len;
+    if (!stored) continue;
+    if (qual_out) {
+      if (len > qual_cap - qual_w) return kCramArgs;
+      uint8_t* q = qual_out + qual_w;
+      if (len) std::memcpy(q, qs_src + c.qs, size_t(len));
+      // the overlays in feature order: 'B' / 'Q' from the QS bytes ahead of
+      // the stored qualities, 'q' from QQ
+      int64_t qs_at = qs_rec, qq_at = qq_val_rec, qq_len = qq_rec;
+      int64_t p = 0;
+      for (int64_t j = 0; j < nf; ++j) {
+        p += fp[k_rec + j];
+        const int32_t code = fc[k_rec + j];
+        if (code == 'B' || code == 'Q') {
+          q[p - 1] = qs_src[qs_at++];
+        } else if (code == 'q') {
+          const int64_t l = c.len[1][qq_len++];
+          if (l) std::memcpy(q + p - 1, vals[1] + qq_at, size_t(l));
+          qq_at += l;
+        }
+      }
+    }
+    qual_w += len;
+    c.qs += len;
+  }
+  if (bad_subst) return kCramBadSubst;
+  info[2] = seq_w;
+  info[3] = qual_w;
+  return kCramOk;
+}
+
+}  // extern "C"

@@ -21,8 +21,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The subprocess body: build fixtures in memory and push them through every
 # native entry point (BGZF header walk, inflate, CRC, record walks,
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
-# kernel, the BCF record walker (chase / span columns / guess), the FASTQ
-# tokenise + pack, the DEFLATE block finder / symbol decoder / resolve).
+# kernel, the BCF record walker (chase / span columns / guess), the CRAM
+# slice rebuild, the FASTQ tokenise + pack, the DEFLATE block finder /
+# symbol decoder / resolve).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -283,6 +284,46 @@ for t in ts:
     t.join(60)
 for got_w in outs_w:
     assert all(got_w[k].tobytes() == want_w[k].tobytes() for k in want_w)
+
+# the CRAM slice rebuild: every fixture of tests/test_cram_native_walk.py and
+# every cut of each payload stream, from streams that end on their buffer's
+# last byte (a read past one is ASan's to see), the native walk and the NumPy
+# twin one outcome; four Python threads rebuilding one slice with the
+# interpreter lock released (TSan)
+import test_cram_native_walk as CW
+def cram_owned(built, cut_cid=None, cut=None):
+    comp, hdr, core, ext = built
+    ext = {k: np.frombuffer(bytes(v if k != cut_cid else v[:cut]),
+                            np.uint8).copy() for k, v in ext.items()}
+    return comp, hdr, core, ext
+for name, mk in CW.SLICES.items():
+    for ref in (CW.REF, CW.LOWER, None):
+        CW._both(cram_owned(mk().build()), ref)
+for case in CW.GEOMETRY:
+    cb = CW.Slice()
+    cb.add(rl=10, ap=9, features=CW.GEOMETRY[case])
+    assert CW._both(cram_owned(cb.build()), CW.REF) is None
+cb = CW.Slice()
+CW._every_feature(cb)
+cb.add(bf=0x4, rl=6, ap=0, ba=b"ACGTNN")
+CW._every_feature(cb, ap=70, name=b"two")
+cbuilt = cb.build()
+for series, cid in CW._stream_cids(cbuilt).items():
+    for cut in range(len(cbuilt[3][cid])):
+        CW._both(cram_owned(cbuilt, cid, cut), CW.REF)
+cbuilt = cram_owned(CW._random_slice(random.Random(8), 300).build())
+cwant = CW._plain(CW._decode(cbuilt, CW.REF))
+cgot = [None] * 4
+def cram_thread(k):
+    cgot[k] = [CW._plain(CW._decode(cbuilt, CW.REF)) for _ in range(10)]
+ts = [threading.Thread(target=cram_thread, args=(k,)) for k in range(4)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(120)
+for per in cgot:
+    for g in per:
+        CW._same(g, cwant)
 
 # FASTQ text -> payload tiles in one pass: the tiles of the NumPy twin on a
 # text that ends on its buffer's last byte; the same text cut at every byte
