@@ -25,11 +25,14 @@ all; only the phenotype is replicated.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Dict, Optional
 
 import numpy as np
 
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
+from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.errors import PlanError
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.stepcache import named_step
@@ -234,6 +237,9 @@ GWAS_MAF_PERCENT = 1       # the GRM's site filter, as a whole percentage
 EIGH_BLOCK = 16
 EIGH_RESIDUAL = 1e-13
 EIGH_MIN_PRODUCTS = 12
+# grm_buffer(): the A buffer kept between leases
+_GRM_LOCK = threading.Lock()
+_grm_kept: Optional[np.ndarray] = None
 
 
 def read_traits_tsv(path: str, samples):
@@ -371,14 +377,56 @@ def make_gwas_assoc_step(n_samples: int, n_traits: int,
     return step
 
 
-def grm_from_accumulators(acc, r, c: float, n_grm: int,
-                         n_samples: int) -> np.ndarray:
-    """A [S, S] float64 from what pass 1 accumulated: ``(T^T G - (r - c)
-    1^T) / |C|`` read from the upper triangle and mirrored."""
+@contextlib.contextmanager
+def grm_buffer(n_samples: int):
+    """Lease the [S, S] float64 buffer kept for a job's A across jobs (50 MB
+    at S = 2,504, above the allocator's mmap threshold: a fresh one is
+    faulted in page by page, which can cost more than the pass that fills
+    it).  A job holds it until ``covariates()`` has
+    returned; a new one is minted where S changed or the kept one is out on
+    lease, so two jobs in one process never share one.  A job that fails
+    inside the lease gives nothing back."""
+    global _grm_kept
+    s = int(n_samples)
+    with _GRM_LOCK:
+        buf, _grm_kept = _grm_kept, None
+    if buf is not None and buf.shape == (s, s):
+        METRICS.count("gwas.grm_buffer_reused")
+    else:
+        buf = np.empty((s, s))
+        METRICS.count("gwas.grm_buffer_minted")
+    yield buf
+    with _GRM_LOCK:
+        _grm_kept = buf
+
+
+def grm_from_accumulators(acc, r, c: float, n_grm: int, n_samples: int,
+                          out: Optional[np.ndarray] = None) -> np.ndarray:
+    """A [S, S] float64 from what pass 1 accumulated (host arrays), written
+    into ``out`` where given: one native pass (``utils/native.py::
+    grm_finish``) where ``native.load()`` gives a library, else the NumPy
+    body; bitwise the same either way."""
+    if out is None:
+        out = np.empty((int(n_samples),) * 2)
+    if native.load() is not None:
+        METRICS.count("gwas.grm_native_jobs")
+        return native.grm_finish(acc, r, c, n_grm, n_samples, out)
+    METRICS.count("gwas.grm_numpy_jobs")
+    return _grm_from_accumulators_numpy(acc, r, c, n_grm, n_samples, out)
+
+
+def _grm_from_accumulators_numpy(acc, r, c: float, n_grm: int,
+                                 n_samples: int,
+                                 out: Optional[np.ndarray] = None
+                                 ) -> np.ndarray:
+    """``(T^T G - (r - c) 1^T) / |C|`` read from the upper triangle and
+    mirrored: the statement of ``hbam_grm_finish``, its oracle and the path
+    of a host without the native library."""
     s = int(n_samples)
     upper = np.triu(np.asarray(acc, np.float64)[:s, :s]
                     - (np.asarray(r, np.float64)[:s] - float(c))[:, None])
-    return (upper + np.triu(upper, 1).T) / max(int(n_grm), 1)
+    return np.divide(upper + np.triu(upper, 1).T, max(int(n_grm), 1),
+                     out=out)
 
 
 def _leading_eigenpairs(a: np.ndarray):

@@ -1036,17 +1036,25 @@ def _variant_gwas_impl(path: str, traits: str, mesh: Optional[Mesh] = None,
         header, st = _variant_gwas_load(path, mesh, config, geometry,
                                         header, spans, prefetch)
     n_sites = st.rows
-    with METRICS.span("gwas.grm_wall"):
-        n_grm = int(st.n_grm)
-        a = gwas.grm_from_accumulators(st.acc, st.r, float(st.c), n_grm,
-                                       n_s)
-        st.acc = st.r = None
-    if n_grm == 0:
-        raise PlanError(f"{path}: no site passes the GRM's filter (SNP, "
-                        f"no missing call, MAF >= "
-                        f"{gwas.GWAS_MAF_PERCENT} %)")
-    with METRICS.span("gwas.eigh_wall"):
-        eigenvalues, q = gwas.covariates(a)
+    # A lives in the kept buffer until the covariates are out of it; then
+    # the next job's A is written there, so no name of this job keeps it
+    with gwas.grm_buffer(n_s) as a_out:
+        with METRICS.span("gwas.grm_wall"):
+            with METRICS.span("gwas.grm_readback_wall"):
+                acc, r, c, n_grm = jax.device_get(
+                    (st.acc, st.r, st.c, st.n_grm))
+            st.acc = st.r = None
+            n_grm = int(n_grm)
+            with METRICS.span("gwas.grm_finish_wall"):
+                a = gwas.grm_from_accumulators(acc, r, float(c), n_grm, n_s,
+                                               out=a_out)
+        if n_grm == 0:
+            raise PlanError(f"{path}: no site passes the GRM's filter (SNP, "
+                            f"no missing call, MAF >= "
+                            f"{gwas.GWAS_MAF_PERCENT} %)")
+        with METRICS.span("gwas.eigh_wall"):
+            eigenvalues, q = gwas.covariates(a)
+        del a
     with METRICS.span("gwas.pheno_wall"):
         yt = y - q @ (q.T @ y)
         sigma2 = (yt * yt).sum(axis=0) / n_s

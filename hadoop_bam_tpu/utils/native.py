@@ -268,6 +268,11 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_crc32_combine.restype = ctypes.c_uint32
         lib.hbam_crc32_combine.argtypes = [
             ctypes.c_uint32, ctypes.c_uint32, ctypes.c_int64]
+        f64p = ctypes.POINTER(ctypes.c_double)
+        lib.hbam_grm_finish.restype = ctypes.c_int64
+        lib.hbam_grm_finish.argtypes = [
+            f32p, ctypes.c_int64, f32p, ctypes.c_double, ctypes.c_int64,
+            ctypes.c_int64, f64p]
         if hasattr(lib, "hbam_fused_start"):
             lib.hbam_fused_start.restype = ctypes.c_void_p
             lib.hbam_fused_start.argtypes = [
@@ -880,6 +885,31 @@ def vcf_tokenize(text, n_sample: int, samples_pad: int
     if n != cap:
         raise ValueError(f"vcf_tokenize counted {cap} records and wrote {n}")
     return bounds, ntab, bulk.view(bool), dosage
+
+
+def grm_finish(acc: np.ndarray, r: np.ndarray, c: float, n_grm: int,
+               n_samples: int, out: np.ndarray) -> np.ndarray:
+    """The GWAS job's A [S, S] float64 written into ``out`` in one native
+    pass, the interpreter lock released (``hbam_grm_finish``): bitwise
+    ``cohort/gwas.py::_grm_from_accumulators_numpy`` of the float32 ``acc``
+    [Sp, Sp] (upper triangle read) and ``r`` [Sp], the scalar ``c`` and the
+    site count ``n_grm``.  Returns ``out``."""
+    lib = load()
+    assert lib is not None
+    s = int(n_samples)
+    acc = np.ascontiguousarray(acc, np.float32)
+    r = np.ascontiguousarray(r, np.float32)
+    sp = acc.shape[1] if acc.ndim == 2 else -1
+    if acc.shape != (sp, sp) or r.shape[0] < s \
+            or out.shape != (s, s) or out.dtype != np.float64 \
+            or not out.flags.c_contiguous or not out.flags.writeable:
+        raise ValueError("grm_finish wants acc [Sp, Sp] f32, r [Sp] f32 and "
+                         "a writable contiguous out [S, S] f64, S <= Sp")
+    if lib.hbam_grm_finish(_ptr(acc, ctypes.c_float), sp,
+                           _ptr(r, ctypes.c_float), float(c), int(n_grm), s,
+                           _ptr(out, ctypes.c_double)) != 0:
+        raise ValueError(f"grm_finish refused S = {s} for Sp = {sp}")
+    return out
 
 
 # A DEFLATE window, and the symbols ``deflate_decode_symbols`` writes where

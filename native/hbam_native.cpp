@@ -8,6 +8,7 @@
 // leaving vectorizable decode to the TPU.  Exposed via plain C ABI for ctypes.
 //
 // Build: g++ -O3 -march=native -shared -fPIC -pthread hbam_native.cpp -lz
+#include <algorithm>
 #include <atomic>
 #include <chrono>
 #include <condition_variable>
@@ -2868,6 +2869,64 @@ int64_t hbam_cram_slice_rebuild(
   info[2] = seq_w;
   info[3] = qual_w;
   return kCramOk;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The GWAS job's GRM finish: A [s, s] float64 from what pass 1 accumulated
+// on the chip (cohort/gwas.py::grm_from_accumulators; ops/gwas_pallas.py::
+// grm_accumulate says what the accumulators hold).  _grm_from_accumulators_
+// numpy is the statement of the semantics and the oracle this is tested
+// against.  No threads: one pass of ~6 M elements on the verb's thread, the
+// interpreter lock released.
+// ---------------------------------------------------------------------------
+namespace {
+
+constexpr int64_t kGrmTile = 64;    // 64 x 64 float64 = 32 KiB of stack
+
+}  // namespace
+
+extern "C" {
+
+// out[j, k] = out[k, j] = ((double)acc[j, k] - ((double)r[j] - c)) /
+// max(n_grm, 1) for j <= k, read from the upper triangle of the row-major
+// [sp, sp] float32 acc (its lower triangle is never read).  The matrix is
+// walked in square tiles on and above the diagonal: a tile is computed into
+// a stack buffer while its rows are written, then its transpose is written
+// row by row from that buffer, so neither the mirror's reads nor its writes
+// stride over the whole matrix.  The float64 operations are NumPy's, in its
+// order: the "+ 0.0" is its mirror's add of the zero triangle (it turns
+// -0.0 into +0.0), the divide a true divide: the result is bitwise the
+// NumPy body's.  Returns 0, or -1 for sizes that disagree.
+int64_t hbam_grm_finish(const float* acc, int64_t sp, const float* r,
+                        double c, int64_t n_grm, int64_t s, double* out) {
+  if (s < 0 || sp < s) return -1;
+  const double n = double(n_grm > 1 ? n_grm : 1);
+  double tile[kGrmTile][kGrmTile];
+  for (int64_t j0 = 0; j0 < s; j0 += kGrmTile) {
+    const int64_t j1 = std::min(j0 + kGrmTile, s);
+    for (int64_t k0 = j0; k0 < s; k0 += kGrmTile) {
+      const int64_t k1 = std::min(k0 + kGrmTile, s);
+      for (int64_t j = j0; j < j1; ++j) {
+        const double rj = double(r[j]) - c;
+        const float* a = acc + j * sp;
+        double* row = out + j * s;
+        double* t = tile[j - j0];
+        for (int64_t k = std::max(k0, j); k < k1; ++k) {
+          const double v = ((double(a[k]) - rj) + 0.0) / n;
+          t[k - k0] = v;
+          row[k] = v;
+        }
+      }
+      for (int64_t k = k0; k < k1; ++k) {
+        double* row = out + k * s;
+        const int64_t j_end = std::min(j1, k);
+        for (int64_t j = j0; j < j_end; ++j) row[j] = tile[j - j0][k - k0];
+      }
+    }
+  }
+  return 0;
 }
 
 }  // extern "C"

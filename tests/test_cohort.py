@@ -37,6 +37,7 @@ from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.cohort import (
     CohortDataset, CohortManifest, as_manifest, cohort_gwas, load_manifest,
 )
+from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.errors import CorruptDataError, PlanError
 
 pytestmark = pytest.mark.cohort
@@ -459,13 +460,26 @@ def _grm_inputs(seed, n_sites, n_samples):
     return g
 
 
+needs_native = pytest.mark.skipif(not native.available(),
+                                  reason="native library unavailable")
+# grm_from_accumulators' two paths: the native pass, and the NumPy body a
+# host without the library runs
+GRM_PATHS = [pytest.param("native", marks=needs_native), "numpy"]
+
+
+@pytest.mark.parametrize("path", GRM_PATHS)
 @pytest.mark.parametrize("n_sites,n_samples,pad", [
     (40, 12, 4), (300, 31, 1), (64, 50, 14)])
-def test_grm_from_accumulators_is_gctas_a(n_sites, n_samples, pad):
+def test_grm_from_accumulators_is_gctas_a(n_sites, n_samples, pad, path,
+                                          monkeypatch):
     """What pass 1 accumulates — T^T G on and above the diagonal, the
-    vector r, the scalar c — mirrors back into A = Z^T Z / |C|."""
+    vector r, the scalar c — mirrors back into A = Z^T Z / |C|, by either
+    path, which counts itself once a call."""
     from hadoop_bam_tpu.cohort.gwas import grm_from_accumulators
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
 
+    if path == "numpy":
+        monkeypatch.setattr(native, "load", lambda: None)
     g = _grm_inputs(n_sites, n_sites, n_samples).astype(np.float64)
     p = g.sum(axis=1) / (2 * n_samples)
     z = (g - 2 * p[:, None]) / np.sqrt(2 * p * (1 - p))[:, None]
@@ -477,12 +491,115 @@ def test_grm_from_accumulators_is_gctas_a(n_sites, n_samples, pad):
     t = (gp - m[:, None]) * w[:, None]
     acc = np.triu(t.T @ gp) + np.tril(np.full((sp, sp), 7.0), -1)
     r, c = (w * m) @ gp, float((w * m * m).sum())
-    got = grm_from_accumulators(acc.astype(np.float32),
-                                r.astype(np.float32), c, n_sites,
-                                n_samples)
+    with MetricsContext() as mc:
+        got = grm_from_accumulators(acc.astype(np.float32),
+                                    r.astype(np.float32), c, n_sites,
+                                    n_samples)
+    counters = mc.snapshot()["counters"]
+    assert counters == {f"gwas.grm_{path}_jobs": 1}
     assert got.shape == (n_samples, n_samples)
     assert np.array_equal(got, got.T)
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+
+
+def _accumulators(seed, s, sp):
+    """Float32 accumulators at the magnitudes of a kgp3 job's, with a zero
+    of either sign left at row 0 (r_0 = c): the mirror must make both +0."""
+    rng = np.random.default_rng(seed)
+    acc = (rng.standard_normal((sp, sp)) * 5e4).astype(np.float32)
+    r = (rng.standard_normal(sp) * 5e4).astype(np.float32)
+    c = float(r[0]) if sp else 0.0
+    if s >= 3:
+        acc[0, :3] = [-0.0, 0.0, -0.0]
+    return acc, r, c
+
+
+@needs_native
+@pytest.mark.parametrize("s,sp,n_grm", [
+    (2504, 2560, 52_429),       # the kgp3 cohort, lane-padded
+    (131, 256, 17),             # not a multiple of the 64-row tile
+    (64, 64, 3), (65, 128, 1), (1, 128, 9), (0, 128, 4),
+    (7, 7, 0)])                 # no site in C: divided by 1, as NumPy
+def test_native_grm_finish_is_the_numpy_body_bit_for_bit(s, sp, n_grm):
+    from hadoop_bam_tpu.cohort.gwas import _grm_from_accumulators_numpy
+
+    acc, r, c = _accumulators(s + sp, s, sp)
+    want = _grm_from_accumulators_numpy(acc, r, c, n_grm, s)
+    out = np.full((s, s), np.nan)
+    got = native.grm_finish(acc, r, c, n_grm, s, out)
+    assert got is out and np.array_equal(got, want)
+    # signed zeros too: the same bits, element for element
+    assert np.array_equal(got.view(np.uint64), want.view(np.uint64))
+
+
+@needs_native
+def test_native_grm_finish_refuses_shapes_it_cannot_write():
+    acc, r, c = _accumulators(3, 40, 64)
+    for s, out in ((65, np.empty((65, 65))),            # S > Sp
+                   (40, np.empty((40, 40), np.float32)),
+                   (40, np.empty((40, 41))),
+                   (40, np.empty((40, 80))[:, ::2])):
+        with pytest.raises(ValueError):
+            native.grm_finish(acc, r, c, 5, s, out)
+
+
+def _grm_counters(mc):
+    c = mc.snapshot()["counters"]
+    return c.get("gwas.grm_buffer_reused", 0), \
+        c.get("gwas.grm_buffer_minted", 0)
+
+
+@pytest.mark.parametrize("path", GRM_PATHS)
+def test_the_grm_buffer_is_kept_across_jobs_and_minted_per_s(path,
+                                                              monkeypatch):
+    from hadoop_bam_tpu.cohort import gwas
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
+    if path == "numpy":
+        monkeypatch.setattr(native, "load", lambda: None)
+    monkeypatch.setattr(gwas, "_grm_kept", None)
+    acc, r, c = _accumulators(11, 40, 64)
+    want = gwas._grm_from_accumulators_numpy(acc, r, c, 6, 40)
+    with MetricsContext() as mc:
+        with gwas.grm_buffer(40) as first:
+            a = gwas.grm_from_accumulators(acc, r, c, 6, 40, out=first)
+            assert a is first and np.array_equal(a, want)
+        with gwas.grm_buffer(40) as second:      # the next job, same S
+            a = gwas.grm_from_accumulators(acc, r, c, 6, 40, out=second)
+            assert a is first and np.array_equal(a, want)
+        assert _grm_counters(mc) == (1, 1)
+        with gwas.grm_buffer(30) as other:       # another S: a new one
+            assert other.shape == (30, 30)
+        assert _grm_counters(mc) == (1, 2)
+        with gwas.grm_buffer(40) as again:       # and the S=30 one is kept
+            assert again is not first and again.shape == (40, 40)
+        assert _grm_counters(mc) == (1, 3)
+
+
+def test_a_grm_on_lease_is_not_overwritten_by_a_second_job(monkeypatch):
+    from hadoop_bam_tpu.cohort import gwas
+    from hadoop_bam_tpu.utils.metrics import MetricsContext
+
+    monkeypatch.setattr(gwas, "_grm_kept", None)
+    acc, r, c = _accumulators(12, 40, 64)
+    acc2, r2, c2 = _accumulators(13, 40, 64)
+    with MetricsContext() as mc:
+        with gwas.grm_buffer(40) as held:
+            a = gwas.grm_from_accumulators(acc, r, c, 6, 40, out=held)
+            kept = a.copy()
+            # a second job while the first still holds its A
+            with gwas.grm_buffer(40) as mine:
+                assert mine is not held
+                gwas.grm_from_accumulators(acc2, r2, c2, 9, 40, out=mine)
+            assert np.array_equal(a, kept)
+        assert _grm_counters(mc) == (0, 2)
+        # a job that fails inside its lease gives nothing back
+        with pytest.raises(PlanError):
+            with gwas.grm_buffer(40) as lost:
+                raise PlanError("no site passes the GRM's filter")
+        with gwas.grm_buffer(40) as next_one:
+            assert next_one is not lost
+        assert _grm_counters(mc) == (1, 3)
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])

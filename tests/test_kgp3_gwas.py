@@ -21,6 +21,8 @@ import pytest
 import kgp3_gwas_reference as R
 import kgp3_reference as K
 
+from hadoop_bam_tpu.utils import native
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "benchmark", "configs",
                        "kgp3-chr20-gwas-x1.json"), encoding="utf-8") as _fh:
@@ -371,11 +373,25 @@ def test_two_jobs_count_their_work_and_build_their_steps_once(tmp_path):
     assert c["gwas.resident_bytes"] % (2 * 128) == 0
     walls = snap["wall_timers"]
     for span in ("gwas.pheno_wall", "gwas.load_wall", "gwas.grm_wall",
+                 "gwas.grm_readback_wall", "gwas.grm_finish_wall",
                  "gwas.eigh_wall", "gwas.assoc_wall", "vcf.plan_wall",
                  "vcf.dispatch_wall", "pipeline.feed_wall"):
         assert walls[span] > 0, span
     assert walls["pipeline.feed_wall"] <= walls["gwas.load_wall"] \
         <= walls["plan.execute_wall"]
+    # the GRM's one readback and its finish lie inside its wall
+    assert walls["gwas.grm_readback_wall"] + walls["gwas.grm_finish_wall"] \
+        <= walls["gwas.grm_wall"]
+    # A is written into one buffer kept from the first job to the second
+    # (the first job may reuse one an earlier test left at this S)
+    assert c.get("gwas.grm_buffer_reused", 0) \
+        + c.get("gwas.grm_buffer_minted", 0) == 2
+    assert c["gwas.grm_buffer_reused"] >= 1
+    if native.load() is not None:
+        assert c["gwas.grm_native_jobs"] == 2 and "gwas.grm_numpy_jobs" \
+            not in c
+    else:
+        assert c["gwas.grm_numpy_jobs"] == 2
 
 
 def test_the_load_step_names_its_phases_and_donates_its_state():

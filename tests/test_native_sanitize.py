@@ -23,7 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
 # kernel, the BCF record walker (chase / span columns / guess), the CRAM
 # slice rebuild, the FASTQ tokenise + pack, the DEFLATE block finder /
-# symbol decoder / resolve).
+# symbol decoder / resolve, the GWAS job's GRM finish).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
 # packer (FeedPipeline's pack thread racing the dispatch consumer over
@@ -445,6 +445,32 @@ for blob in (gzip.compress(ftext, 4) + gzip.compress(ftext[:5000], 1),
         assert "truncated" in str(e) and blob[-1:] != b"\0", e
     finally:
         gzs.close()
+
+# the GWAS job's GRM finish: accumulators and outputs that end on their
+# buffer's last byte (a tile written or read past either is ASan's to see),
+# S a whole number of tiles, one short of one, one past one, S = Sp; the
+# NumPy body the oracle; four threads finishing one accumulator into four
+# outputs with the interpreter lock released (TSan)
+from hadoop_bam_tpu.cohort.gwas import _grm_from_accumulators_numpy
+grng = np.random.default_rng(5)
+for s, sp in ((128, 128), (127, 128), (65, 130), (1, 1), (0, 4)):
+    acc = grng.standard_normal((sp, sp)).astype(np.float32)
+    r = grng.standard_normal(sp).astype(np.float32)
+    got = native.grm_finish(acc, r, 0.25, 7, s, np.empty((s, s)))
+    assert np.array_equal(got, _grm_from_accumulators_numpy(acc, r, 0.25, 7,
+                                                            s))
+acc = grng.standard_normal((130, 130)).astype(np.float32)
+r = grng.standard_normal(130).astype(np.float32)
+gouts = [np.empty((127, 127)) for _ in range(4)]
+ts = [threading.Thread(target=native.grm_finish,
+                       args=(acc, r, 0.5, 3, 127, o)) for o in gouts]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(120)
+    assert not t.is_alive()
+want = _grm_from_accumulators_numpy(acc, r, 0.5, 3, 127)
+assert all(np.array_equal(o, want) for o in gouts)
 
 # staging packer: the FeedPipeline's background pack thread races the
 # dispatching consumer over reused ring slots — drive it with a host
