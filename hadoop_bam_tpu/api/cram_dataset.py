@@ -8,7 +8,7 @@ config (``cram_reference_source_path`` — the analog of
 memory-mapped FASTA, so opening the dataset costs the index (and the
 header container), not the genome or the file.  ``cram_span_tiles`` is
 the unit ``hbam seq-stats`` runs a span through on ``plan.execute``
-(``parallel/pipeline.py::_cram_stats_impl``).
+(``parallel/pipeline.py::_read_stats_impl``).
 """
 from __future__ import annotations
 

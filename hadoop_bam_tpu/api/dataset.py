@@ -132,35 +132,15 @@ class BamDataset:
         to ``tile_records`` instead of shrinking (every batch shares one
         shape, at the cost of padding transfer on the last batch).
         """
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from hadoop_bam_tpu.parallel.mesh import make_mesh
         from hadoop_bam_tpu.parallel.pipeline import (
-            PayloadGeometry, iter_payload_tile_groups,
+            PayloadGeometry, bam_payload_batches,
         )
 
-        if mesh is None:
-            mesh = make_mesh()
         if geometry is None:
             geometry = PayloadGeometry()
-        n_dev = int(np.prod(mesh.devices.shape))
-        sharding = NamedSharding(mesh, P("data"))
-        spans = self.spans(num_spans)
-
-        def emit(arrays, counts):
-            # the device dict doubles as the ring slot's in-flight
-            # transfer handle (staging.FeedPipeline.stream contract)
-            return {
-                "prefix": jax.device_put(arrays[0], sharding),
-                "seq_packed": jax.device_put(arrays[1], sharding),
-                "qual": jax.device_put(arrays[2], sharding),
-                "n_records": jax.device_put(counts, sharding),
-            }
-
-        yield from iter_payload_tile_groups(
-            self.path, spans, geometry, n_dev, self.config,
-            header=self.header, emit_fn=emit)
+        yield from bam_payload_batches(self.path, self.spans(num_spans),
+                                       mesh, geometry, self.config,
+                                       self.header)
 
     def query(self, region: str) -> Iterator[SamRecord]:
         """Random access via a ``.bai``/``.csi`` sidecar: yields records

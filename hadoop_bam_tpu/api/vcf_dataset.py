@@ -178,23 +178,18 @@ class VcfDataset:
         columns 0.  (Before the staging-ring feed, shards of the final
         group that received no spans were zero-filled — dosage 0 read
         as a hom-ref call; mask by ``n_records`` either way.)"""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from hadoop_bam_tpu.parallel.mesh import make_mesh
-        from hadoop_bam_tpu.parallel.pipeline import _iter_windowed
+        from hadoop_bam_tpu.parallel.scan import ScanFeed, _iter_windowed
         from hadoop_bam_tpu.parallel.variant_pipeline import (
-            VariantGeometry, pack_variant_tiles, variant_feed,
+            VariantGeometry, pack_variant_tiles,
         )
         from hadoop_bam_tpu.utils.pools import decode_pool, decode_pool_size
 
-        if mesh is None:
-            mesh = make_mesh()
         if geometry is None:
             geometry = VariantGeometry(n_samples=self.header.n_samples)
-        n_dev = int(np.prod(mesh.devices.shape))
-        cap = geometry.tile_records
-        sharding = NamedSharding(mesh, P("data"))
+        # fixed_shape keeps the historical contract that every variant
+        # tensor batch carries full tile_records rows
+        scan = ScanFeed("vcf", self.config, mesh, None,
+                        geometry.tile_records, fixed_shape=True)
         spans = self.spans(num_spans)
         pool = decode_pool(self.config)
 
@@ -212,26 +207,11 @@ class VcfDataset:
                 VariantBatch(self.read_span(span), self.header),
                 geometry)
 
-        stream = _iter_windowed(pool, spans, decode,
-                                2 * decode_pool_size(self.config),
-                                config=self.config)
-        # variant_feed peeks the first span's dict for the schema (same
-        # genericity as the old serial tiler); fixed_shape keeps the
-        # historical contract that every variant tensor batch carries
-        # full tile_records rows
-        keys, fp, tuples = variant_feed(stream, n_dev, cap, self.config,
-                                        fixed_shape=True, fmt="vcf")
-        if fp is None:
-            return
-
-        def emit(arrays, counts) -> Dict:
-            # the device dict doubles as the slot's in-flight handle
-            out = {k: jax.device_put(a, sharding)
-                   for k, a in zip(keys, arrays)}
-            out["n_records"] = jax.device_put(counts, sharding)
-            return out
-
-        yield from fp.stream(tuples, emit)
+        # the first span's columns name the schema; the decode is the
+        # dataset's own, outside the scan verbs' span retry policy
+        yield from scan.batches(_iter_windowed(
+            pool, spans, decode, 2 * decode_pool_size(self.config),
+            config=self.config))
 
     def variant_stats(self, mesh=None, geometry=None) -> Dict:
         """Distributed variant/SNP/PASS counts, mean ALT allele frequency,

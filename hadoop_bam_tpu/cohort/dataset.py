@@ -3,8 +3,8 @@
 The cohort twin of ``api.vcf_dataset.VcfDataset``: where that class
 tiles ONE file's variants, this one streams k single-sample files
 through the position join (cohort/join.py) and tiles the JOINED columns
-onto the mesh through the same shared ``variant_feed``/``FeedPipeline``
-machinery — so sentinel padding (-1 dosage / NaN qual), ring-slot
+onto the mesh through the same scan feed (``parallel/scan.py``) and
+``FeedPipeline`` machinery — so sentinel padding (-1 dosage / NaN qual), ring-slot
 reuse, and the in-flight transfer discipline are all inherited, not
 re-implemented.
 """

@@ -10,8 +10,8 @@ group harmonized (cohort/harmonize.py) and packed into
     dosage i8 [n, samples_pad] (-1 missing),
     qual f32 [n, samples_pad] (NaN missing)
 
-— exactly the schema the shared ``variant_feed``/``FeedPipeline``
-machinery tiles onto the mesh (the PR-4 sentinel convention rides the
+— exactly the schema the shared scan feed (``parallel/scan.py``)
+tiles onto the mesh (the PR-4 sentinel convention rides the
 TileSpec pads).
 
 **Per-input-file fault domains** (this is a policy boundary module,

@@ -23,8 +23,8 @@ and device decoders, so parity tests pin all three to each other.
 
 Backend selection: ``rans_decode_batch(payloads, backend=...)`` with
 "host" (native C++/NumPy per stream — the throughput default),
-"device" (this module), or "auto" (host; the honest measurement in
-BASELINE.md shows where each wins).
+"device" (this module), or "auto" (host: one stream at a time on the
+host has the lower latency).
 """
 from __future__ import annotations
 
@@ -284,9 +284,8 @@ def rans_decode_batch(payloads: Sequence[bytes],
     """Decode a batch of rANS 4x8 streams.
 
     backend="host": native C++/NumPy, stream at a time (default under
-    "auto" — single-stream latency wins on the host; see BASELINE.md for
-    the measured device/host crossover).  backend="device": the batched
-    VPU decode above."""
+    "auto" — single-stream latency wins on the host).  backend="device":
+    the batched VPU decode above."""
     if backend == "device":
         return rans_decode_batch_device(payloads)
     return [rans4x8_decode(p) for p in payloads]

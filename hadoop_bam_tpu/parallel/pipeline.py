@@ -18,8 +18,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import dataclasses
-import functools
-import logging
+import itertools
 import os
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -30,52 +29,40 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hadoop_bam_tpu.parallel.mesh import shard_map
-from hadoop_bam_tpu.parallel.staging import (
-    FeedPipeline, TileSpec, bucket_cap,
+# the scan feed; its window and failure policy stay importable from here
+from hadoop_bam_tpu.parallel.scan import (  # noqa: F401 — re-exports
+    ScanFeed, _iter_windowed, decode_with_retry,
 )
+from hadoop_bam_tpu.parallel.staging import bucket_cap
 
 from hadoop_bam_tpu.config import (
     DEFAULT_CONFIG, HBamConfig, resolve_inflate_backend,
 )
 # plane gating lives in plan/executor.py (the ONE predicate table; the
 # planroute lint rule PL101 keeps gate conditionals out of this module).
-# _use_fused/_fused_stream_gate keep their historical names here for the
-# span-level decoders and the existing import surface.
-from hadoop_bam_tpu.plan.executor import (  # noqa: F401 — re-exports
-    FLAGSTAT_DAG, PAYLOAD_DAG, _fused_stream_gate, _use_fused,
-    select_plane,
-)
-from hadoop_bam_tpu.plan.ir import SourceIR
+# _use_fused keeps its historical name here for the span-level decoders.
+from hadoop_bam_tpu.plan.executor import _use_fused, select_plane
 from hadoop_bam_tpu.formats.bam import SAMHeader
-from hadoop_bam_tpu.obs.trace import active_recorder
 from hadoop_bam_tpu.ops import inflate as inflate_ops
 from hadoop_bam_tpu.ops.flagstat import flagstat_from_columns
 from hadoop_bam_tpu.ops.unpack_bam import (
     ALL_FIELDS, FLAGSTAT_PROJECTION, PREFIX, projection_ranges,
-    projection_row_bytes, unpack_fixed_fields, unpack_fixed_fields_tile,
-    unpack_projected_tile,
+    projection_row_bytes, unpack_fixed_fields, unpack_projected_tile,
 )
 from hadoop_bam_tpu.resilience import chaos
-from hadoop_bam_tpu.resilience.domains import (
-    DemotionLadder, check_quarantine_gate, decode_ladder,
-    quarantine_run_ok,
-)
-from hadoop_bam_tpu.split.planners import plan_bam_spans
+from hadoop_bam_tpu.resilience.domains import decode_ladder
 from hadoop_bam_tpu.split.spans import FileVirtualSpan
 from hadoop_bam_tpu.utils import errors as hberrors
 from hadoop_bam_tpu.utils.errors import PlanError, classify_error
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import (
-    SPAN_BUFFERS, decode_pool, decode_pool_size, stream_window_cap,
-    submit as pool_submit, text_stream_window,
+    SPAN_BUFFERS, decode_pool_size, stream_window_cap, text_stream_window,
 )
 from hadoop_bam_tpu.utils.resilient import (
     QuarantineManifest, RetryPolicy, RetryingByteSource,
 )
 from hadoop_bam_tpu.utils.seekable import as_byte_source, scoped_byte_source
 from hadoop_bam_tpu.utils.stepcache import named_step
-
-logger = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,27 +279,6 @@ def _decode_span_core(source, span: FileVirtualSpan,
 # automatic fallback (no native library, non-native backends,
 # config.use_fused_decode=False, and the rare cut-final-record span).
 # ---------------------------------------------------------------------------
-
-def _close_stream(item) -> None:
-    """_iter_windowed cleanup hook: join a fused chunk stream's native
-    workers; buffered results (plain arrays/tuples) need nothing."""
-    close = getattr(item, "close", None)
-    if close is not None:
-        close()
-
-
-def _flatten_span_stream(items) -> Iterator[Tuple[np.ndarray, ...]]:
-    """Uniform FeedPipeline input from mixed decode results: buffered
-    arrays/tuples pass through as one-span items; fused chunk streams
-    flatten into their per-chunk tuples."""
-    for item in items:
-        if isinstance(item, np.ndarray):
-            yield (item,)
-        elif isinstance(item, tuple):
-            yield item
-        else:
-            yield from item
-
 
 def _stream_window(window: int) -> int:
     """Cap the in-flight window for STREAMED fused decode: each windowed
@@ -887,11 +853,6 @@ def parse_config_intervals(config: HBamConfig, header):
                            header.ref_names if header else None)
 
 
-def _span_retry_policy(config: HBamConfig) -> RetryPolicy:
-    from hadoop_bam_tpu.utils.resilient import span_retry_policy
-    return span_retry_policy(config)
-
-
 def _resilient_source(path, config: HBamConfig):
     """What a driver's decode stages read through, opened ONCE a scan and
     shared by its pool tasks (positioned reads keep no seek state): the
@@ -912,455 +873,18 @@ def _resilient_source(path, config: HBamConfig):
         deadline_s=config.io_read_deadline_s))
 
 
-def decode_with_retry(fn: Callable, span: FileVirtualSpan,
-                      config: HBamConfig,
-                      quarantine: Optional[QuarantineManifest] = None,
-                      policy: Optional[RetryPolicy] = None,
-                      ladder: Optional[DemotionLadder] = None):
-    """Span-level failure policy (SURVEY.md section 5), fault-classified.
-
-    A span is a self-describing, idempotent unit of work — the retry
-    mechanism is re-decoding it, as MapReduce re-runs a map task — but
-    unlike the reference, failures are classified (utils/errors.py) and
-    each class gets its own policy:
-
-    - TRANSIENT: re-attempted up to ``config.span_retries`` times with
-      jittered exponential backoff (``policy`` injectable, so tests assert
-      the exact schedule without real sleeps);
-    - CORRUPT: fails fast with ZERO re-decodes — a CRC mismatch or
-      malformed record chain never heals, re-reading it only wastes the
-      budget;
-    - PLAN: always raised — a misconfigured run must not be retried or
-      quietly skipped as if the data were bad.
-
-    Once the policy is exhausted, ``skip_bad_spans`` decides between
-    raising and quarantine+skip: the span is recorded in ``quarantine``
-    (file, virtual-offset range, error class, attempts) and None returned.
-    Counters: ``pipeline.bad_spans`` ticks ONLY on an actual skip;
-    ``pipeline.transient_retries`` counts re-attempts;
-    ``pipeline.corrupt_spans`` counts corrupt failures.  The manifest's
-    circuit breaker (``config.max_bad_span_fraction``) raises
-    CircuitBreakerError when the run has quarantined too much of its plan
-    to stay meaningful.
-
-    With a ``ladder`` (resilience/domains.py) the CORRUPT branch grows a
-    demotion step and ``fn`` takes ``(span, plane)``: a span failing
-    corrupt on plane P re-decodes at the next plane down — byte-identical
-    but more battle-tested — instead of failing outright.  Blame is
-    oracle-confirmed: only when the LOWER plane succeeds on the same span
-    is the failure charged to P's fault domain (repeated charges open
-    P's breaker, demoting the whole run until a half-open probe heals
-    it); when every plane fails, the bytes — not the plane — are bad,
-    no domain is charged, and the classic raise/quarantine applies."""
-    if policy is None:
-        policy = _span_retry_policy(config)
-    last: Optional[BaseException] = None
-    kind = hberrors.CORRUPT
-    attempts = 0
-    transient_tries = 0
-    plane = ladder.plane() if ladder is not None else None
-    blamed: List[Tuple[str, BaseException]] = []
-    while attempts <= policy.retries + len(blamed):
-        attempts += 1
-        try:
-            out = fn(span) if ladder is None else fn(span, plane)
-            if ladder is not None:
-                for bad_plane, exc in blamed:
-                    # a lower plane just decoded these bytes: the upper
-                    # plane's failure was plane-local — charge it
-                    ladder.confirm_failure(bad_plane, exc)
-                    METRICS.count("pipeline.span_demotions")
-                ladder.record_success(plane)
-            return out
-        except Exception as e:  # noqa: BLE001 — policy boundary
-            last = e
-            kind = classify_error(e)
-            if kind == hberrors.PLAN:
-                raise
-            if kind != hberrors.TRANSIENT:
-                if ladder is not None:
-                    nxt = ladder.next_lower(plane)
-                    if nxt is not None and ladder.demotable(plane, e):
-                        logger.warning(
-                            "span %s failed on the %s plane (%s); "
-                            "re-decoding on %s", span, plane, e, nxt)
-                        blamed.append((plane, e))
-                        plane = nxt
-                        continue
-                METRICS.count("pipeline.corrupt_spans")
-                break
-            if transient_tries < policy.retries:
-                METRICS.count("pipeline.transient_retries")
-                d = policy.delay(transient_tries)
-                transient_tries += 1
-                logger.debug("transient fault on span %s (attempt %d/%d), "
-                             "retrying in %.3fs: %s", span, attempts,
-                             policy.retries + 1, d, e)
-                policy.sleep(d)
-                continue
-            break
-    if config.skip_bad_spans:
-        METRICS.count("pipeline.bad_spans")
-        logger.warning("skipping bad span %s after %d attempt(s) [%s]: %s",
-                       span, attempts, kind, last)
-        if quarantine is not None:
-            quarantine.add(span, last, kind, attempts)
-            quarantine.check_circuit(config)  # may raise CircuitBreakerError
-        return None
-    raise last
-
-
-# how long a QUEUED candidate's hard-timeout anchor is held, as a
-# multiple of pool_task_timeout_s: long enough that a backlogged-but-
-# healthy pool (queue waits of a few task durations) never false-fires,
-# short enough that a fully-wedged pool — where re-submissions can
-# never dequeue — still exhausts the budget and surfaces as
-# TransientIOError instead of hanging forever
-_QUEUED_GRACE = 8.0
-
-
-def _iter_windowed(pool: cf.ThreadPoolExecutor, items: Sequence,
-                   fn: Callable, window: int,
-                   cleanup: Optional[Callable] = None,
-                   config: Optional[HBamConfig] = None,
-                   what: str = "span decode") -> Iterator:
-    """Submit ``fn(item)`` to the pool with bounded in-flight futures and
-    yield results in order.  Bounds host memory: at most ``window`` decoded
-    spans exist at once (a plain list of futures would retain every span's
-    rows for the whole run — concurrent.futures keeps results referenced).
-
-    On early close (a consumer abandoning the stream), queued-but-unstarted
-    futures are cancelled — the SHARED decode pool (utils/pools.py) never
-    shuts down, so without the cancel an abandoned window of decodes would
-    keep running to completion for nothing.  ``cleanup`` is called on
-    results that already materialized but will never be yielded (the fused
-    chunk streams hold live native jobs — closing them joins the workers
-    instead of leaving that to GC).
-
-    With a ``config``, the consumer grows the straggler + hang defense
-    (jobs/speculate.py):
-
-    - **speculation** (``config.speculative_decode``): a unit outliving
-      the job's soft deadline — p95 of a decaying per-job latency
-      histogram x ``straggler_multiplier`` — gets a second copy raced on
-      the pool; the FIRST result wins and the loser is cancelled or
-      reaped through ``cleanup`` (``jobs.speculative_launched`` /
-      ``jobs.speculative_won``).  Safe because ``fn`` is an idempotent,
-      side-effect-free span decode — the MapReduce speculative-execution
-      contract.
-    - **hard timeout** (``config.pool_task_timeout_s``): a future
-      outliving it is abandoned (a wedged worker thread cannot be
-      killed, only orphaned) and the item re-submitted, once per
-      ``span_retries``; exhaustion surfaces ``TransientIOError`` into
-      the caller's existing retry/breaker machinery instead of blocking
-      forever (``pool.task_timeouts`` / ``jobs.timeout_resubmits``).
-      The deadline covers ACTIVE wait on a runnable task — time spent
-      queued behind a backlogged-but-healthy pool, or running
-      overlapped before the consumer reached this entry, does not
-      count (see ``_await``'s two-clock note).
-
-    Without a config (or with both knobs off before any soft deadline
-    exists) the await path is the plain blocking ``Future.result()``.
-
-    Every unit carries its own clock (``utils/pools.TaskStamps``, written
-    by the worker) and the consumer says what it waited for:
-
-    - a head that is NOT done when the consumer arrives is
-      ``feed.head_wait`` — a span on the pulling thread while a recorder
-      is active (the packer's, or the dispatch thread's under
-      ``variant_feed``'s peek), two clock reads otherwise — and at its
-      end, from the stamps alone, three walls: ``feed.head_queued`` (the
-      part before the head's ``started``: no pool thread was free),
-      ``feed.head_running`` (the rest: it was on a thread and not done)
-      and ``feed.ready_behind_head`` (the part during which a LATER unit
-      of the window had already finished: what a hand-off out of order
-      would not have waited).  A head that is done costs one ``done()``;
-    - every unit taken adds ``feed.units`` and, from its stamps, the
-      sums ``feed.unit_queued_ns`` / ``unit_run_ns`` / ``unit_held_ns``
-      (finished -> taken) and — of units run while a recorder was active,
-      whose thread usage the worker took — ``unit_cpu_ns`` /
-      ``unit_sys_ns``.  Of a speculated or re-submitted unit the
-      winner's stamps count.
-    """
-    from collections import deque
-
-    from hadoop_bam_tpu.utils.resilient import call_with_retry
-
-    it = iter(items)
-    # entries: [item, future, speculated?, index]; the future's stamps
-    # (submitted / started / finished, the run's rusage) are the unit's
-    dq: "deque[list]" = deque()
-    # transient SUBMISSION failures (a saturated executor, an injected
-    # pool.submit chaos fault) retry briefly instead of killing the
-    # whole driver run — the task itself has its own failure policy
-    submit_policy = RetryPolicy(retries=3, backoff_base_s=0.01,
-                                backoff_max_s=0.1)
-
-    timeout_s = config.pool_task_timeout_s if config is not None else None
-    timeout_s = float(timeout_s) if timeout_s else None
-    max_resubmits = int(config.span_retries or 0) \
-        if timeout_s is not None else 0
-    latency = None
-    if config is not None and bool(config.speculative_decode):
-        from hadoop_bam_tpu.jobs.speculate import UnitLatency
-        latency = UnitLatency.from_config(config)
-
-    def _submit(item) -> cf.Future:
-        # pools.submit, not pool.submit: the task carries the caller's
-        # MetricsContext onto the worker thread and records its queue
-        # wait + run into the pool.task_* histograms
-        return call_with_retry(lambda: pool_submit(pool, fn, item),
-                               submit_policy, what="decode pool submit",
-                               counter="pool.submit_retries")
-
-    def _reap(f: cf.Future) -> None:
-        # done-callback: covers futures already finished AND ones
-        # still running at teardown (fires on the worker thread when
-        # they complete) without blocking this thread on .result()
-        if f.cancelled():
-            return
-        try:
-            cleanup(f.result())
-        except Exception:  # noqa: BLE001 — best-effort teardown
-            pass
-
-    def _abandon(f: cf.Future) -> None:
-        if not f.cancel() and cleanup is not None:
-            f.add_done_callback(_reap)
-
-    def _await(entry) -> object:
-        """Resolve one entry under the defense policy (docstring); the
-        future that won is left in ``entry[1]``."""
-        if timeout_s is None and latency is None:
-            return entry[1].result()           # undefended fast path
-        # candidates: the primary plus at most one speculative twin plus
-        # timeout re-submissions.  Two clocks on purpose:
-        # - the DEADLINE anchor starts when this await begins (a decode
-        #   that ran overlapped while earlier entries were consumed is
-        #   not "stuck") and is refreshed while the future is still
-        #   queued — otherwise a healthy-but-backlogged pool would burn
-        #   the hard-timeout budget on queue wait (re-submissions land
-        #   at the back of the same queue) and the soft deadline would
-        #   speculate on tasks that never started (a twin queued behind
-        #   the original can only lose);
-        # - the future's SUBMIT stamp feeds the latency histogram:
-        #   turnaround, which can only over-estimate, keeps the
-        #   p95-derived soft deadline conservative.
-        now = time.perf_counter()
-        # fields: [future, deadline anchor, is_spec,
-        # first-observed-queued stamp (None until seen pending)]
-        cands = [[entry[1], now, False, None]]
-        resubmits = 0
-        while True:
-            for c in list(cands):
-                if not c[0].done():
-                    continue
-                try:
-                    out = c[0].result()
-                except Exception:  # noqa: BLE001 — policy boundary
-                    # one copy failing while another runs must not kill
-                    # the race — keep waiting on the survivor; but when
-                    # the last candidate FAILS (vs times out), raise:
-                    # the decode genuinely ran and failed, its own
-                    # retry policy is spent, and burning the timeout
-                    # re-submission budget on a known-failing span
-                    # would just duplicate the failure
-                    cands.remove(c)
-                    if not cands:
-                        raise
-                    continue
-                if latency is not None:
-                    latency.observe(time.perf_counter()
-                                    - c[0].stamps.submitted)
-                if c[2]:
-                    METRICS.count("jobs.speculative_won")
-                for o in cands:
-                    if o is not c:
-                        _abandon(o[0])
-                entry[1] = c[0]
-                return out
-            now = time.perf_counter()
-            for c in cands:
-                if not c[0].running() and not c[0].done():
-                    if c[3] is None:
-                        c[3] = now
-                    # still queued: hold the deadline anchor — but only
-                    # within a bounded grace.  Unbounded holding would
-                    # make a FULLY-wedged pool (every worker stuck, so
-                    # re-submissions never dequeue) immortal — the
-                    # exact forever-hang this knob exists to end; a
-                    # merely-backlogged pool drains within the grace
-                    if timeout_s is None or \
-                            now - c[3] <= timeout_s * _QUEUED_GRACE:
-                        c[1] = now
-            if timeout_s is not None:
-                for c in list(cands):
-                    if now - c[1] > timeout_s:
-                        METRICS.count("pool.task_timeouts")
-                        _abandon(c[0])
-                        cands.remove(c)
-            if not cands:
-                if resubmits >= max_resubmits:
-                    from hadoop_bam_tpu.utils.errors import (
-                        TransientIOError,
-                    )
-                    raise TransientIOError(
-                        f"{what} exceeded the {timeout_s:g}s "
-                        f"pool_task_timeout_s deadline "
-                        f"{resubmits + 1} time(s) — worker(s) presumed "
-                        f"wedged") from None
-                resubmits += 1
-                METRICS.count("jobs.timeout_resubmits")
-                cands.append([_submit(entry[0]), time.perf_counter(),
-                              False, None])
-                now = time.perf_counter()
-            soft = latency.soft_deadline_s() if latency is not None \
-                else None
-            if soft is not None and not entry[2] and len(cands) == 1 \
-                    and now - cands[0][1] > soft:
-                entry[2] = True
-                METRICS.count("jobs.speculative_launched")
-                cands.append([_submit(entry[0]), time.perf_counter(),
-                              True, None])
-            # sleep until the nearest deadline (or a coarse slice that
-            # keeps the undeadlined wait cheap), woken early by any
-            # candidate completing
-            waits = [0.25]
-            if timeout_s is not None:
-                waits += [c[1] + timeout_s - now for c in cands]
-            if soft is not None and not entry[2]:
-                waits += [cands[0][1] + soft - now]
-            elif latency is not None and soft is None:
-                waits += [float(latency.min_s)]
-            cf.wait([c[0] for c in cands],
-                    timeout=max(0.005, min(waits)),
-                    return_when=cf.FIRST_COMPLETED)
-
-    def _head_walls(entry, t_arrive: float, traced: bool) -> dict:
-        """The wait that just ended, split by the stamps alone (nothing
-        polled while it ran): before the head's ``started`` it was queued,
-        after it running; from the earliest ``finished`` among the units
-        still in the window, a finished unit sat behind it."""
-        t_end = time.perf_counter()
-        waited = t_end - t_arrive
-        queued = min(max(entry[1].stamps.started - t_arrive, 0.0), waited)
-        done = [f for f in (e[1].stamps.finished for e in dq)
-                if f is not None and f < t_end]
-        behind = t_end - max(t_arrive, min(done)) if done else 0.0
-        if not traced:
-            METRICS.add_wall("feed.head_wait", waited)
-        for name, sec, t0 in (
-                ("feed.head_queued", queued, t_arrive),
-                ("feed.head_running", waited - queued, t_arrive + queued),
-                ("feed.ready_behind_head", behind, t_end - behind)):
-            if sec > 0.0:
-                METRICS.add_wall(name, sec, t0=t0)
-        return {"queued_s": queued, "running_s": waited - queued,
-                "ready_behind_s": behind, "behind_done": len(done)}
-
-    def _take(entry) -> object:
-        """The consumer takes the head unit (docstring: the head wait and
-        the unit's counters)."""
-        if entry[1].done():
-            out = _await(entry)
-        else:
-            t_arrive = time.perf_counter()
-            if active_recorder() is not None:
-                with METRICS.span("feed.head_wait", unit=entry[3]) as late:
-                    out = _await(entry)
-                    late.update(_head_walls(entry, t_arrive, True))
-            else:
-                out = _await(entry)
-                _head_walls(entry, t_arrive, False)
-        st = entry[1].stamps
-        sums = [("units", 1),
-                ("unit_queued_ns", (st.started - st.submitted) * 1e9),
-                ("unit_run_ns", (st.finished - st.started) * 1e9),
-                ("unit_held_ns", (time.perf_counter() - st.finished) * 1e9)]
-        if st.usage is not None:     # taken while a recorder was active
-            user, sys_ns = st.usage
-            sums += [("unit_cpu_ns", user + sys_ns), ("unit_sys_ns", sys_ns)]
-        for name, n in sums:
-            METRICS.count(f"feed.{name}", int(n))
-        return out
-
-    try:
-        for item in it:
-            dq.append([item, _submit(item), False, len(dq)])
-            if len(dq) >= window:
-                break
-        n_units = len(dq)
-        while dq:
-            entry = dq.popleft()
-            for item in it:
-                dq.append([item, _submit(item), False, n_units])
-                n_units += 1
-                break
-            yield _take(entry)
-    finally:
-        for entry in dq:
-            _abandon(entry[1])
-
-
-def _iter_prefix_tiles(row_arrays, cap: int, row_bytes: int = PREFIX
-                       ) -> Iterator[Tuple[np.ndarray, int]]:
-    """Repack a stream of per-span row arrays into [cap, row_bytes] tiles.
-
-    Spans have data-dependent record counts; the jit contract wants static
-    shapes.  Rather than padding each span to the worst case (the old span
-    path's memset + transfer tax), concatenate across span boundaries and
-    emit full tiles — only the final tile carries padding.
-
-    This is the SERIAL tiler: the hot drivers feed through
-    parallel/staging.FeedPipeline (in-place ring packing, no per-tile
-    allocation); this stays as the reference implementation the
-    byte-identity property tests compare the ring against."""
-    from collections import deque
-
-    # deque, not a list: parts.pop(0) is O(len) per pop, which turns a
-    # many-small-span plan (thousands of parts per tile) quadratic
-    parts: "deque[np.ndarray]" = deque()
-    have = 0
-
-    def emit(take: int) -> Tuple[np.ndarray, int]:
-        nonlocal have
-        # full tiles are fully overwritten — only the padded final tile
-        # needs zeroing
-        tile = (np.empty if take == cap else np.zeros)(
-            (cap, row_bytes), dtype=np.uint8)
-        filled = 0
-        while filled < take:
-            head = parts[0]
-            k = min(take - filled, head.shape[0])
-            tile[filled:filled + k] = head[:k]
-            if k == head.shape[0]:
-                parts.popleft()
-            else:
-                parts[0] = head[k:]
-            filled += k
-        have -= take
-        return tile, take
-
-    for prefix in row_arrays:
-        if prefix.shape[0]:
-            parts.append(prefix)
-            have += prefix.shape[0]
-        while have >= cap:
-            yield emit(cap)
-    if have:
-        yield emit(have)
-
-
 def _iter_tile_tuples(array_tuples, cap: int, specs: Sequence
                       ) -> Iterator[Tuple[Tuple[np.ndarray, ...], int]]:
-    """Like _iter_prefix_tiles but over tuples of row arrays kept in
-    lockstep (prefix/seq/qual/lengths share record order and counts).
+    """Repack a stream of tuples of row arrays kept in lockstep
+    (prefix/seq/qual/lengths share record order and counts) into
+    ``cap``-row tiles across span boundaries; only the final tile is
+    padded.
 
     ``specs``: per-array spec — an int width (uint8 [cap, w] tile) or a
     (width_or_None, dtype) pair; width None means a 1-D [cap] tile.
 
-    Serial tiler, like _iter_prefix_tiles: coverage still drives it, and
-    the FeedPipeline byte-identity tests use it as the oracle."""
+    The serial tiler: the FeedPipeline byte-identity tests use it as the
+    oracle."""
     from collections import deque
 
     norm = [(s, np.uint8) if isinstance(s, int) else tuple(s)
@@ -1405,110 +929,93 @@ def _iter_tile_tuples(array_tuples, cap: int, specs: Sequence
 _bucket_cap = bucket_cap
 
 
-def iter_payload_tile_groups(path: str, spans: Sequence[FileVirtualSpan],
-                             geometry: PayloadGeometry, n_dev: int,
-                             config: HBamConfig = DEFAULT_CONFIG,
-                             prefetch: int = 2,
-                             header=None,
-                             quarantine: Optional[QuarantineManifest] = None,
-                             balance: bool = False,
-                             emit_fn=None,
-                             ) -> Iterator:
-    """Stream payload tile groups ready for a device mesh: yields
-    ([prefix, seq, qual] each [n_dev, rows, w] uint8, counts [n_dev]
-    int32), where rows == geometry.tile_records for every full group and
-    the FINAL partial group may shrink to a smaller bucket (_bucket_cap).
-    The shared batching core of seq_stats_file and
-    BamDataset.tensor_batches — shared decode pool with a bounded
-    window, staging-ring group packing (parallel/staging.py: rows write
-    in place, partial tiles zero only their own tail), span retry/skip
-    per the config's failure policy.
-
-    ``emit_fn(arrays, counts)``, when given, runs per group inside the
-    FeedPipeline (its return value is yielded AND becomes the ring
-    slot's in-flight transfer handle — see staging.FeedPipeline.stream);
-    both in-repo consumers pass one.  Without it, the yielded arrays
-    are caller-owned copies (the historical contract — this fallback
-    only exists for external callers, so it pays the copy rather than
-    hand out ring views that the packer will overwrite)."""
-    cap = geometry.tile_records
-    widths = (PREFIX, geometry.seq_stride, geometry.qual_stride)
-    check_crc = bool(config.check_crc)
+def _bam_span_rows(scan: ScanFeed, path: str, spans, header, prefetch: int,
+                   host: Callable, mode: str, **fused) -> Iterator:
+    """A BAM scan's spans decoded on the plane ``select_plane`` chose —
+    the unit the flagstat and payload families share.  Where the plan may
+    stream, a span's fused native job hands its ``mode`` chunks
+    (``_iter_fused_span_chunks``, ``fused`` its shape) to the packer as
+    the walk lands them; otherwise ``host(src, span, check_crc, backend,
+    intervals, config)`` — the family's ``decode_span_*_host`` — decodes
+    it whole into its tuple of row arrays, two-pass on the zlib rung.
+    Corrupt failures on the native rung re-decode on zlib (byte-identical)
+    under the demotion ladder, and oracle-confirmed blame opens the native
+    domain's breaker."""
+    config = scan.config
     intervals = parse_config_intervals(config, header)
-    # same fast-fail quarantine gate as flagstat_file: a file whose last
-    # run tripped the bad-span circuit sheds here while it is OPEN
-    check_quarantine_gate(path, config)
-    src = _resilient_source(path, config)
-    spans = list(spans)
-    if quarantine is not None and quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
-
-    # the ONE routing decision (plan/executor.py): the inflate backend
-    # as asked, and chunk streaming behind the shared fused-stream gate
-    decision = select_plane(SourceIR(path, "bam"), PAYLOAD_DAG, config,
-                            intervals=intervals)
-    host_backend = decision.plane
-    # same demotion ladder as flagstat's host path: corrupt failures on
-    # the native rung re-decode on zlib (byte-identical) and oracle-
-    # confirmed blame opens the native domain's breaker
+    decision = select_plane(config, intervals=intervals)
     ladder = decode_ladder(path, decision.plane, config) \
         if config.adaptive_planes else None
-
-    # same chunk-streaming shape as flagstat_file: fused spans hand their
-    # prefix/seq/qual chunks to the packer as the native walk lands them
-    stream_fused = decision.stream_fused
-    if stream_fused:
+    src = _resilient_source(path, config)
+    check_crc = bool(config.check_crc)
+    window = max(1, prefetch) * decode_pool_size(config)
+    if decision.stream_fused:
         window = _stream_window(window)
 
-    def decode(span):
-        def inner(s, plane=None):
-            hb = host_backend if plane is None else plane
-            if hb in ("auto", "native"):
-                chaos.fire("decode.native", span=str(s))
-            if stream_fused and hb in ("auto", "native"):
+    def decode(span, plane=None):
+        # ladder-aware: decode_with_retry drives ``plane`` down the
+        # demotion ladder on corrupt failures (None = the selected plane)
+        hb = decision.plane if plane is None else plane
+        if hb in ("auto", "native"):
+            # chaos point for plane-local native faults — fires INSIDE the
+            # retry/ladder boundary, so injected faults retry/demote
+            # exactly like real ones
+            chaos.fire("decode.native", span=str(span))
+            if decision.stream_fused:
+                # the tail-cut fallback runs LATER, on the consumer thread:
+                # it re-reads the span, so it gets its own pass through the
+                # retry policy (transients there must heal exactly like the
+                # eager fetch's do)
                 return _iter_fused_span_chunks(
-                    src, s, "payload", geometry=geometry,
-                    check_crc=check_crc, config=config,
+                    src, span, mode, check_crc=check_crc, config=config,
                     fallback_fn=lambda: decode_with_retry(
-                        lambda s2: decode_span_payload_host(
-                            src, s2, geometry, check_crc, header=header,
-                            config=_fused_off(config))[:3],
-                        s, config))
-            prefix, seq, qual, _v = decode_span_payload_host(
-                src, s, geometry, check_crc, hb,
-                intervals=intervals, header=header,
-                config=config if hb != "zlib" else _fused_off(config))
-            return prefix, seq, qual
-        with METRICS.timer("pipeline.host_decode"), \
-                METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("bam.host_decode_wall"):
-            out = decode_with_retry(inner, span, config,
-                                    quarantine=quarantine, ladder=ladder)
-        return out if out is not None else (
-            np.empty((0, PREFIX), np.uint8),
-            np.empty((0, geometry.seq_stride), np.uint8),
-            np.empty((0, geometry.qual_stride), np.uint8))
+                        lambda s: host(src, s, check_crc, decision.plane,
+                                       None, _fused_off(config)),
+                        span, config), **fused)
+        return host(src, span, check_crc, hb, intervals,
+                    config if hb != "zlib" else _fused_off(config))
 
-    stream = _flatten_span_stream(
-        _iter_windowed(pool, spans, decode, window,
-                       cleanup=_close_stream, config=config))
-    # balance=True only for psum'd stats consumers (seq_stats_file);
-    # tensor_batches keeps the serial row placement, so public batches
-    # stay byte-stable across releases
-    fp = FeedPipeline(n_dev, cap, [TileSpec((w,), np.uint8) for w in widths],
-                      block_n=geometry.block_n,
-                      fixed_shape=geometry.fixed_shape, balance=balance,
-                      config=config, fmt="bam")
-    if emit_fn is not None:
-        yield from fp.stream(stream, emit_fn)
-    else:
-        for arrays, counts in fp.groups(stream):
-            yield [a.copy() for a in arrays], counts.copy()
-    # reached only when the whole span plan decoded without tripping the
-    # bad-span circuit: heals a half-open quarantine gate
-    quarantine_run_ok(path, config)
+    return scan.decoded(spans, decode, window, ladder=ladder,
+                        chunk_streams=True)
+
+
+def _payload_scan(path: str, config: HBamConfig, mesh,
+                  geometry: PayloadGeometry, *, balance: bool = False,
+                  quarantine: Optional[QuarantineManifest] = None
+                  ) -> ScanFeed:
+    """The feed of a BAM payload scan: prefix + 4-bit seq + qual rows in
+    tiles of ``geometry.tile_records``; the final partial group shrinks to
+    a dispatch bucket unless ``geometry.fixed_shape``."""
+    return ScanFeed("bam", config, mesh,
+                    (PREFIX, geometry.seq_stride, geometry.qual_stride),
+                    geometry.tile_records, block_n=geometry.block_n,
+                    fixed_shape=geometry.fixed_shape, balance=balance,
+                    quarantine=quarantine, gate=path)
+
+
+def _payload_rows(scan: ScanFeed, path: str, spans,
+                  geometry: PayloadGeometry, header, prefetch: int):
+    """A BAM payload scan's decoded (prefix, seq, qual) rows."""
+    def host(src, span, check_crc, backend, intervals, cfg):
+        return decode_span_payload_host(
+            src, span, geometry, check_crc, backend, intervals=intervals,
+            header=header, config=cfg)[:3]
+
+    return _bam_span_rows(scan, path, spans, header, prefetch, host,
+                          "payload", geometry=geometry)
+
+
+def bam_payload_batches(path: str, spans: Sequence[FileVirtualSpan], mesh,
+                        geometry: PayloadGeometry, config: HBamConfig,
+                        header) -> Iterator[Dict]:
+    """``BamDataset.tensor_batches``: the payload scan's groups as device
+    dicts ``prefix`` / ``seq_packed`` / ``qual`` / ``n_records``, rows in
+    the serial placement (no ``balance``) so public batches stay
+    byte-stable across releases."""
+    scan = _payload_scan(path, config, mesh, geometry)
+    yield from scan.batches(
+        _payload_rows(scan, path, spans, geometry, header, 2),
+        ("prefix", "seq_packed", "qual"))
 
 
 class _StatTotals:
@@ -1622,6 +1129,11 @@ def make_seq_stats_step(mesh: Mesh, geometry: PayloadGeometry,
     return step
 
 
+def _read_specs(geometry: PayloadGeometry) -> Tuple:
+    """A read-payload tile: 4-bit seq, qual, and each read's length."""
+    return (geometry.seq_stride, geometry.qual_stride, (None, np.int32))
+
+
 def stream_read_tensor_batches(spans, read_span_fn, config: HBamConfig,
                                mesh: Optional[Mesh],
                                geometry: "Optional[PayloadGeometry]",
@@ -1639,54 +1151,23 @@ def stream_read_tensor_batches(spans, read_span_fn, config: HBamConfig,
     producer — the columnar fast path (CRAM uses it to skip SAM record
     materialization entirely)."""
     from hadoop_bam_tpu.api.read_datasets import fragments_to_payload_tiles
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
 
-    if mesh is None:
-        mesh = make_mesh()
     if geometry is None:
         geometry = PayloadGeometry()
-    n_dev = int(np.prod(mesh.devices.shape))
-    cap = geometry.tile_records
-    sharding = NamedSharding(mesh, P("data"))
-    spans = list(spans)
-    if quarantine is not None and quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    pool = decode_pool(config)
+    scan = ScanFeed(fmt, config, mesh, _read_specs(geometry),
+                    geometry.tile_records, block_n=geometry.block_n,
+                    fixed_shape=geometry.fixed_shape, quarantine=quarantine)
 
-    def decode(span):
-        def inner(s):
-            if tiles_fn is not None:
-                return tiles_fn(s, geometry)
-            return fragments_to_payload_tiles(
-                read_span_fn(s), geometry.seq_stride,
-                geometry.qual_stride, geometry.max_len)
-        with METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span(f"{fmt}.host_decode_wall"):
-            out = decode_with_retry(inner, span, config,
-                                    quarantine=quarantine)
-        return out if out is not None else (
-            np.empty((0, geometry.seq_stride), np.uint8),
-            np.empty((0, geometry.qual_stride), np.uint8),
-            np.empty((0,), np.int32))
+    def decode(s):
+        if tiles_fn is not None:
+            return tiles_fn(s, geometry)
+        return fragments_to_payload_tiles(
+            read_span_fn(s), geometry.seq_stride,
+            geometry.qual_stride, geometry.max_len)
 
-    stream = _iter_windowed(pool, spans, decode,
-                            2 * decode_pool_size(config), config=config)
-    specs = (geometry.seq_stride, geometry.qual_stride, (None, np.int32))
-    fp = FeedPipeline(n_dev, cap, specs, block_n=geometry.block_n,
-                      fixed_shape=geometry.fixed_shape, config=config,
-                      fmt=fmt)
-
-    def emit(arrays, counts) -> Dict:
-        # the returned device dict doubles as the slot's in-flight
-        # transfer handle (FeedPipeline.stream contract)
-        return {
-            "seq_packed": jax.device_put(arrays[0], sharding),
-            "qual": jax.device_put(arrays[1], sharding),
-            "lengths": jax.device_put(arrays[2], sharding),
-            "n_records": jax.device_put(counts, sharding),
-        }
-
-    yield from fp.stream(stream, emit)
+    yield from scan.batches(
+        scan.decoded(spans, decode, 2 * decode_pool_size(config)),
+        ("seq_packed", "qual", "lengths"))
 
 
 def make_read_stats_step(mesh: Mesh, geometry: PayloadGeometry,
@@ -1770,7 +1251,7 @@ def cram_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
     """GC / quality / base stats over a CRAM — what ``hbam seq-stats
     x.cram --reference x.fa`` runs: a thin plan builder
     (``plan/builders.py::cram_stats_plan``) over the one executor, whose
-    runner (``_cram_stats_impl``) decodes container-aligned spans with
+    runner (``_read_stats_impl``) decodes container-aligned spans with
     the columnar slice decoder into the FASTQ plan's tiles and step.  The
     reference is ``config.cram_reference_source_path``."""
     from hadoop_bam_tpu.plan import builders
@@ -1780,76 +1261,6 @@ def cram_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
     return plan_executor.execute(plan, config=config, mesh=mesh,
                                  geometry=geometry, spans=spans,
                                  quarantine=quarantine)
-
-
-def _cram_stats_impl(path: str, mesh: Optional[Mesh] = None,
-                     config: HBamConfig = DEFAULT_CONFIG,
-                     geometry: Optional[PayloadGeometry] = None,
-                     spans=None,
-                     quarantine: Optional[QuarantineManifest] = None,
-                     ) -> Dict[str, object]:
-    """The CRAM payload-stats implementation (executor runner).  A span is
-    a run of whole containers read with one read; the pool decodes it
-    (``api/cram_dataset.py::cram_span_tiles``: the blocks the columnar
-    decoder asks for, native rANS Nx16, bases gathered from the
-    memory-mapped reference) under the span retry policy, and from the
-    tiles on it is ``_read_stats_impl``'s feed and step."""
-    from hadoop_bam_tpu.api.cram_dataset import cram_span_tiles, open_cram
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
-
-    if mesh is None:
-        mesh = make_mesh()
-    n_dev = int(np.prod(mesh.devices.shape))
-    if geometry is None:
-        geometry = PayloadGeometry()
-    ds = open_cram(path, config)
-    if spans is None:
-        with METRICS.span("cram.plan_wall"):
-            spans = ds.spans(
-                num_spans=pipeline_span_count(path, n_dev, config))
-    spans = list(spans)
-    if quarantine is None:
-        quarantine = QuarantineManifest()
-    if quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    step = make_read_stats_step(mesh, geometry)
-    sharding = NamedSharding(mesh, P("data"))
-    pool = decode_pool(config)
-    totals = _StatTotals()
-
-    def decode(span):
-        with METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("cram.host_decode_wall"):
-            out = decode_with_retry(
-                lambda s: cram_span_tiles(ds, s, geometry), span, config,
-                quarantine=quarantine)
-        return out if out is not None else (
-            np.empty((0, geometry.seq_stride), np.uint8),
-            np.empty((0, geometry.qual_stride), np.uint8),
-            np.empty((0,), np.int32))
-
-    # a span is in memory after its one read and its decode is compute,
-    # much of it NumPy under the interpreter lock: as for a text stream's
-    # tokenise, more spans in flight than cores only contend for the lock
-    # (and hold their columns), so the window is the text stream's
-    stream = _iter_windowed(pool, spans, decode, text_stream_window(),
-                            config=config)
-    specs = (geometry.seq_stride, geometry.qual_stride, (None, np.int32))
-    fp = FeedPipeline(n_dev, geometry.tile_records, specs,
-                      block_n=geometry.block_n,
-                      fixed_shape=geometry.fixed_shape, balance=True,
-                      config=config, fmt="cram")
-
-    def dispatch(arrays, counts):
-        args = [jax.device_put(a, sharding) for a in arrays]
-        c = jax.device_put(counts, sharding)
-        with METRICS.span("cram.kernel_wall"):
-            totals.add(*step(*args, c))  # async; drained once at the end
-        METRICS.count("pipeline.records", int(counts.sum()))
-        return (*args, c)  # in-flight handles: the ring waits before reuse
-
-    fp.feed(stream, dispatch)
-    return _attach_quarantine(_payload_stats_result(totals), quarantine)
 
 
 def fastq_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
@@ -1872,36 +1283,36 @@ def fastq_seq_stats_file(path: str, mesh: Optional[Mesh] = None,
                                  prefetch=prefetch, quarantine=quarantine)
 
 
-def _read_stats_impl(path: str, fmt: str, mesh: Optional[Mesh] = None,
-                     config: HBamConfig = DEFAULT_CONFIG,
-                     geometry: Optional[PayloadGeometry] = None,
-                     spans=None,
-                     prefetch: int = 2,
-                     quarantine: Optional[QuarantineManifest] = None,
-                     ) -> Dict[str, object]:
-    """The text-read payload-stats implementation (executor runner; ``fmt``
-    is the plan's source format, "fastq" | "qseq").
+def _read_unit(path: str, fmt: str, config: HBamConfig,
+               geometry: PayloadGeometry, prefetch: int):
+    """What a read-payload scan decodes, by the source's format:
+    ``(dataset, decode, window, stream)``, ``stream(spans)`` the chunks a
+    compressed text file is cut into as it inflates (None where the units
+    are the spans).
 
-    The unit of work is a ``TextChunk``: a plain file is many spans of one
-    chunk each, read and tokenised on the pool under the span retry
-    policy; a gzip'd file is one span whose chunks one thread inflates in
-    order (``iter_span_chunks``) while the pool tokenises the ones before.
-    From the chunk onward both run the same lines into the same feed.  A
-    streamed chunk is not re-readable — its tiles may be on the device
-    before a later chunk fails — so nothing of a stream is retried or
-    quarantined: an error ends the scan."""
+    CRAM: a span is a run of whole containers read with one read and
+    decoded by ``api/cram_dataset.py::cram_span_tiles`` (the blocks the
+    columnar decoder asks for, native rANS Nx16, bases gathered from the
+    memory-mapped reference).  FASTQ / QSEQ: a plain file's span is read
+    and tokenised on the pool; a gzip'd file is one span whose chunks one
+    thread inflates in order (``iter_span_chunks``) while the pool
+    tokenises the ones before."""
     from hadoop_bam_tpu.api.read_datasets import (
         fastq_text_to_payload_tiles, fragments_to_payload_tiles,
         open_fastq, open_qseq, qseq_text_to_payload_tiles,
     )
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
 
-    if mesh is None:
-        mesh = make_mesh()
-    n_dev = int(np.prod(mesh.devices.shape))
-    if geometry is None:
-        geometry = PayloadGeometry()
-    cap = geometry.tile_records
+    if fmt == "cram":
+        from hadoop_bam_tpu.api.cram_dataset import cram_span_tiles, open_cram
+
+        ds = open_cram(path, config)
+        # a span is in memory after its one read and its decode is
+        # compute, much of it NumPy under the interpreter lock: as for a
+        # text stream's tokenise, more spans in flight than cores only
+        # contend for the lock (and hold their columns), so the window is
+        # the text stream's
+        return (ds, lambda s: cram_span_tiles(ds, s, geometry),
+                text_stream_window(), None)
     is_qseq = fmt == "qseq"
     ds = open_qseq(path, config) if is_qseq else open_fastq(path, config)
     # Vectorized tokenize (no per-read Python objects) whenever the config
@@ -1915,36 +1326,15 @@ def _read_stats_impl(path: str, fmt: str, mesh: Optional[Mesh] = None,
         fast_tiles = not config.fastq_filter_failed_qc
         qual_offset = config.fastq_base_quality_encoding.value
         text_to_tiles = fastq_text_to_payload_tiles
-    if spans is None:
-        with METRICS.span(f"{fmt}.plan_wall"):
-            spans = ds.spans(
-                num_spans=pipeline_span_count(path, n_dev, config))
-    spans = list(spans)
-    if quarantine is None:
-        quarantine = QuarantineManifest()
-    if quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    step = make_read_stats_step(mesh, geometry)
-    sharding = NamedSharding(mesh, P("data"))
-    pool = decode_pool(config)
     # plain spans wait for their reads, so more of them than cores are in
-    # flight; a stream's chunks are pure compute behind one inflater, and
-    # their decode is not the idempotent unit the straggler defence may
-    # run twice (it gives the chunk's text up)
+    # flight; a stream's chunks are pure compute behind one inflater
     streamed = ds.is_compressed()
-    window = text_stream_window() if streamed \
-        else max(1, prefetch) * decode_pool_size(config)
     grain = pipeline_grain(config)
-    totals = _StatTotals()
 
-    def chunks():
-        for span in spans:
-            yield from ds.iter_span_chunks(span, grain)
-
-    def decode(chunk):
-        def inner(_span):
+    def decode(unit):
+        try:
             with METRICS.span(f"{fmt}.fetch_wall"):
-                text = chunk.text()
+                text = unit.text() if streamed else ds.read_span_text(unit)
             t_cpu = time.thread_time_ns()
             try:
                 with METRICS.span(f"{fmt}.tokenize_wall"):
@@ -1958,42 +1348,53 @@ def _read_stats_impl(path: str, fmt: str, mesh: Optional[Mesh] = None,
             finally:
                 METRICS.count(f"{fmt}.tokenize_busy_ns",
                               time.thread_time_ns() - t_cpu)
-        with METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span(f"{fmt}.host_decode_wall"):
-            if chunk.streamed:
-                try:
-                    return inner(chunk.span)
-                finally:
-                    chunk.done()
-            out = decode_with_retry(inner, chunk.span, config,
-                                    quarantine=quarantine)
-        return out if out is not None else (
-            np.empty((0, geometry.seq_stride), np.uint8),
-            np.empty((0, geometry.qual_stride), np.uint8),
-            np.empty((0,), np.int32))
+        finally:
+            if streamed:
+                unit.done()
 
-    stream = _iter_windowed(pool, chunks(), decode, window,
-                            config=None if streamed else config)
-    # the shared feed: in-place ring packing replaces the old per-group
-    # np.stack of freshly zero-padded shards, and each device only pays
-    # copy work for its own rows (the per-device bucket-cap behavior the
-    # BAM payload path already had); balance spreads the final partial
-    # group over all shards (stats are psum'd, placement-invariant)
-    specs = (geometry.seq_stride, geometry.qual_stride, (None, np.int32))
-    fp = FeedPipeline(n_dev, cap, specs, block_n=geometry.block_n,
-                      fixed_shape=geometry.fixed_shape, balance=True,
-                      config=config, fmt=fmt)
+    if not streamed:
+        return ds, decode, max(1, prefetch) * decode_pool_size(config), None
+    return (ds, decode, text_stream_window(),
+            lambda spans: itertools.chain.from_iterable(
+                ds.iter_span_chunks(s, grain) for s in spans))
 
-    def dispatch(arrays, counts):
-        args = [jax.device_put(a, sharding) for a in arrays]
-        c = jax.device_put(counts, sharding)
-        with METRICS.span(f"{fmt}.kernel_wall"):
-            totals.add(*step(*args, c))  # async; drained once at the end
-        METRICS.count("pipeline.records", int(counts.sum()))
-        return (*args, c)  # in-flight handles: the ring waits before reuse
 
-    fp.feed(stream, dispatch)
-    return _attach_quarantine(_payload_stats_result(totals), quarantine)
+def _read_stats_impl(path: str, fmt: str, mesh: Optional[Mesh] = None,
+                     config: HBamConfig = DEFAULT_CONFIG,
+                     geometry: Optional[PayloadGeometry] = None,
+                     spans=None,
+                     prefetch: int = 2,
+                     quarantine: Optional[QuarantineManifest] = None,
+                     ) -> Dict[str, object]:
+    """The read-payload stats implementation (executor runner; ``fmt`` is
+    the plan's source format, "fastq" | "qseq" | "cram").  The formats
+    differ only in the unit (``_read_unit``); from its tiles on they run
+    the same feed and step.  A streamed chunk is not re-readable — its
+    tiles may be on the device before a later chunk fails — so nothing of
+    a stream is retried or quarantined: an error ends the scan."""
+    if geometry is None:
+        geometry = PayloadGeometry()
+    # balance spreads the final partial group over all shards (stats are
+    # psum'd, placement-invariant)
+    scan = ScanFeed(fmt, config, mesh, _read_specs(geometry),
+                    geometry.tile_records, block_n=geometry.block_n,
+                    fixed_shape=geometry.fixed_shape, balance=True,
+                    quarantine=QuarantineManifest() if quarantine is None
+                    else quarantine)
+    ds, decode, window, stream = _read_unit(path, fmt, config, geometry,
+                                            prefetch)
+    if spans is None:
+        with METRICS.span(f"{fmt}.plan_wall"):
+            spans = ds.spans(
+                num_spans=pipeline_span_count(path, scan.n_dev, config))
+    spans = list(spans)
+    step = make_read_stats_step(scan.mesh, geometry)
+    totals = _StatTotals()
+    # async; drained once at the end
+    scan.run(scan.decoded(spans, decode, window,
+                          stream=None if stream is None else stream(spans)),
+             lambda args, _counts: totals.add(*step(*args)))
+    return _attach_quarantine(_payload_stats_result(totals), scan.quarantine)
 
 
 def seq_stats_file(path: str, mesh: Optional[Mesh] = None,
@@ -2027,52 +1428,26 @@ def _seq_stats_impl(path: str, mesh: Optional[Mesh] = None,
                     prefetch: int = 2,
                     quarantine: Optional[QuarantineManifest] = None,
                     ) -> Dict[str, object]:
-    """The payload-stats mesh-feed implementation (executor runner):
-    iter_payload_tile_groups decode/pack under the shared routing
-    decision, fused Pallas kernel per tile group, 64-bit host drain."""
+    """The payload-stats mesh-feed implementation (executor runner): the
+    BAM span unit under the shared routing decision, fused Pallas kernel
+    per tile group, 64-bit host drain."""
     from hadoop_bam_tpu.formats.bamio import read_bam_header
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
 
-    if mesh is None:
-        mesh = make_mesh()
-    n_dev = int(np.prod(mesh.devices.shape))
     if geometry is None:
         geometry = PayloadGeometry()
-    cap = geometry.tile_records
-    assert cap % geometry.block_n == 0
+    assert geometry.tile_records % geometry.block_n == 0
     if header is None:
         header, _ = read_bam_header(path)
-
+    scan = _payload_scan(path, config, mesh, geometry, balance=True,
+                         quarantine=QuarantineManifest() if quarantine is None
+                         else quarantine)
     if spans is None:
-        span_bytes = 8 << 20
-        src = as_byte_source(path)
-        n_spans = max(n_dev, int(np.ceil(src.size / span_bytes)))
-        src.close()
-        from hadoop_bam_tpu.split.planners import plan_spans_cached
-        with METRICS.span("bam.plan_wall", spans=n_spans):
-            spans = plan_spans_cached(path, header, config,
-                                      num_spans=n_spans)
-
-    step = make_seq_stats_step(mesh, geometry)
-    sharding = NamedSharding(mesh, P("data"))
+        spans = _plan_bam_scan(path, header, config, scan.n_dev, 8 << 20)
+    step = make_seq_stats_step(scan.mesh, geometry)
     totals = _StatTotals()
-    if quarantine is None:
-        quarantine = QuarantineManifest()
-    def emit(arrays, counts):
-        # the group generator packs on its own thread (FeedPipeline);
-        # this runs on the dispatch side of the double buffer, and the
-        # returned device arrays are the slot's in-flight handles
-        args = [jax.device_put(a, sharding) for a in arrays]
-        c = jax.device_put(counts, sharding)
-        with METRICS.span("bam.kernel_wall"):
-            totals.add(*step(*args, c))   # async; drained once at the end
-        return (*args, c)
-
-    for _ in iter_payload_tile_groups(
-            path, spans, geometry, n_dev, config, prefetch, header=header,
-            quarantine=quarantine, balance=True, emit_fn=emit):
-        pass
-    return _attach_quarantine(_payload_stats_result(totals), quarantine)
+    scan.run(_payload_rows(scan, path, spans, geometry, header, prefetch),
+             lambda args, _counts: totals.add(*step(*args)))
+    return _attach_quarantine(_payload_stats_result(totals), scan.quarantine)
 
 
 def flagstat_file(path: str, mesh: Optional[Mesh] = None,
@@ -2101,6 +1476,18 @@ def flagstat_file(path: str, mesh: Optional[Mesh] = None,
                                  quarantine=quarantine)
 
 
+def _plan_bam_scan(path: str, header, config: HBamConfig, n_dev: int,
+                   span_bytes: int) -> List[FileVirtualSpan]:
+    """A whole-BAM scan's span plan: about ``span_bytes`` of the file a
+    span, one a device at least."""
+    from hadoop_bam_tpu.split.planners import plan_spans_cached
+
+    with scoped_byte_source(path) as src:
+        n_spans = max(n_dev, int(np.ceil(src.size / span_bytes)))
+    with METRICS.span("bam.plan_wall", spans=n_spans):
+        return plan_spans_cached(path, header, config, num_spans=n_spans)
+
+
 def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
                    config: HBamConfig = DEFAULT_CONFIG,
                    geometry: Optional[DecodeGeometry] = None,
@@ -2121,34 +1508,27 @@ def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
     bounds peak host memory.
     """
     from hadoop_bam_tpu.formats.bamio import read_bam_header
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
     from hadoop_bam_tpu.ops.flagstat import FLAGSTAT_FIELDS
 
-    if mesh is None:
-        mesh = make_mesh()
-    n_dev = int(np.prod(mesh.devices.shape))
     if geometry is None:
         geometry = DecodeGeometry()
-    cap = geometry.tile_records
     if header is None:
         header, _ = read_bam_header(path)
-
+    projection = FLAGSTAT_PROJECTION
+    row_bytes = projection_row_bytes(projection)
     # the upgraded quarantine circuit: a file whose last run tripped the
     # bad-span-fraction breaker fast-fails here while OPEN (retry_after
     # hint attached) instead of re-planning a doomed run; HALF_OPEN lets
-    # this run through as the probe and a clean finish heals it
-    check_quarantine_gate(path, config)
-    intervals = parse_config_intervals(config, header)
-    # THE routing decision (plan/executor.select_plane)
-    decision = select_plane(SourceIR(path, "bam"), FLAGSTAT_DAG, config,
-                            intervals=intervals)
-    host_backend = decision.plane
-    # the demotion ladder: plane-local faults demote native -> zlib
-    # mid-run with byte-identical results and heal back through
-    # half-open probes (resilience/domains.py)
-    ladder = decode_ladder(path, host_backend, config) \
-        if config.adaptive_planes else None
-
+    # this run through as the probe and a clean finish heals it.
+    # balance: the final partial group spreads across all shards and
+    # shrinks to a dispatch bucket — a file smaller than one full group
+    # otherwise lands entirely on device 0 and ships n_dev*cap rows of
+    # padding (the 8-device inverse-scaling tax); the bucket ladder
+    # bounds the extra jit shapes at two.
+    scan = ScanFeed("bam", config, mesh, (row_bytes,),
+                    geometry.tile_records, balance=True,
+                    quarantine=QuarantineManifest() if quarantine is None
+                    else quarantine, gate=path)
     if spans is None:
         # Span size trades host-decode parallelism (smaller = more spans
         # in flight) against the per-span start (one read, one header
@@ -2156,120 +1536,35 @@ def _flagstat_impl(path: str, mesh: Optional[Mesh] = None,
         # span boundaries, so this does NOT couple to the device
         # geometry.  4 MiB is the size every chip number in PERF.md was
         # taken at; it has not been swept on that host.
-        span_bytes = 4 << 20
-        src = as_byte_source(path)
-        n_spans = max(n_dev, int(np.ceil(src.size / span_bytes)))
-        src.close()
-        from hadoop_bam_tpu.split.planners import plan_spans_cached
-        with METRICS.span("bam.plan_wall", spans=n_spans):
-            spans = plan_spans_cached(path, header, config,
-                                      num_spans=n_spans)
-
-    projection = FLAGSTAT_PROJECTION
-    row_bytes = projection_row_bytes(projection)
-    step = make_flagstat_tile_step(mesh, projection=projection)
-    sharding = NamedSharding(mesh, P("data"))
-    spans = list(spans)
-    if quarantine is None:
-        quarantine = QuarantineManifest()
-    if quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
-    src = _resilient_source(path, config)
-    pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
+        spans = _plan_bam_scan(path, header, config, scan.n_dev, 4 << 20)
+    step = make_flagstat_tile_step(scan.mesh, projection=projection)
     totals_vec = None
-    check_crc = bool(config.check_crc)
 
-    # Chunk-streamed fused decode: each pool worker starts its span's
-    # native job (fetch inside the retry boundary) and hands back a lazy
-    # chunk iterator; the FeedPipeline's packer consumes row chunks the
-    # moment the native walk publishes them, so staging tiles pack while
-    # the span's tail is still inflating.  Gated off (in select_plane,
-    # with the other routing gates) when skip_bad_spans needs
-    # span-granular quarantine or when interval filtering needs the
-    # whole span's offsets.
-    stream_fused = decision.stream_fused
-    if stream_fused:
-        window = _stream_window(window)
-    ranges = projection_ranges(projection)
-
-    def decode(span):
-        def inner(s, plane=None):
-            # ladder-aware: decode_with_retry drives ``plane`` down the
-            # demotion ladder on corrupt failures (None = static config
-            # plane, the ladder-off path)
-            hb = host_backend if plane is None else plane
-            if hb in ("auto", "native"):
-                # chaos point for plane-local native faults — fires
-                # INSIDE the retry/ladder boundary, so injected faults
-                # retry/demote exactly like real ones
-                chaos.fire("decode.native", span=str(s))
-            if stream_fused and hb in ("auto", "native"):
-                # the tail-cut fallback runs LATER, on the consumer
-                # thread: it re-reads the span, so it gets its own pass
-                # through the retry policy (transients there must heal
-                # exactly like the eager fetch's do)
-                return _iter_fused_span_chunks(
-                    src, s, "rows", sel=ranges, row_bytes=row_bytes,
-                    check_crc=check_crc, config=config,
-                    fallback_fn=lambda: decode_with_retry(
-                        lambda s2: (decode_span_prefix_host(
-                            src, s2, check_crc, host_backend, projection,
-                            want_voffs=False, header=header,
-                            config=_fused_off(config))[0],),
-                        s, config))
-            rows, _voffs = decode_span_prefix_host(
-                src, s, check_crc, hb, projection,
-                want_voffs=False, intervals=intervals, header=header,
-                config=config if hb != "zlib" else _fused_off(config))
-            return rows
-        with METRICS.timer("pipeline.host_decode"), \
-                METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("bam.host_decode_wall"):
-            out = decode_with_retry(inner, span, config,
-                                    quarantine=quarantine, ladder=ladder)
-        return out if out is not None \
-            else np.empty((0, row_bytes), dtype=np.uint8)
-
-    def row_stream():
-        return _flatten_span_stream(
-            _iter_windowed(pool, spans, decode, window,
-                           cleanup=_close_stream, config=config))
-    # Ring-staged groups + NO blocking between dispatches: the packer
-    # thread writes rows straight into a leased [n_dev, cap, row] slot
-    # (no per-group allocation, no np.stack, no pad memset) while THIS
-    # thread issues device_put/step for the previous group (sequential
-    # single-thread issue; concurrent puts not measured on the current
-    # machine), and the single device_get at the end drains the whole
-    # async queue.  balance: the final partial
-    # group spreads across all shards and shrinks to a dispatch bucket
-    # — a file smaller than one full group otherwise lands entirely on
-    # device 0 and ships n_dev*cap rows of padding (the 8-device
-    # inverse-scaling tax); the bucket ladder bounds the extra jit
-    # shapes at two.
-    fp = FeedPipeline(n_dev, cap, (TileSpec((row_bytes,), np.uint8),),
-                      balance=True, config=config, fmt="bam")
-
-    def dispatch(arrays, counts):
+    def consume(args, _counts):
         nonlocal totals_vec
-        t = jax.device_put(arrays[0], sharding)
-        c = jax.device_put(counts, sharding)
-        with METRICS.span("bam.kernel_wall"):
-            vec = step(t, c)
-            totals_vec = vec if totals_vec is None \
-                else _ADD(totals_vec, vec)
-        return t, c      # in-flight handles: the ring waits before reuse
+        vec = step(*args)
+        totals_vec = vec if totals_vec is None else _ADD(totals_vec, vec)
 
-    fp.feed(row_stream(), dispatch)
+    def host(src, span, check_crc, backend, intervals, cfg):
+        return (decode_span_prefix_host(
+            src, span, check_crc, backend, projection, want_voffs=False,
+            intervals=intervals, header=header, config=cfg)[0],)
+
+    # chunk-streamed where the plan may stream: the packer takes a span's
+    # rows as its native walk publishes them
+    scan.run(_bam_span_rows(scan, path, spans, header, prefetch, host,
+                            "rows", sel=projection_ranges(projection),
+                            row_bytes=row_bytes),
+             consume)
     if totals_vec is None:
         host = np.zeros(len(FLAGSTAT_FIELDS), dtype=np.int64)
     else:
         with METRICS.timer("pipeline.device_drain"), \
                 METRICS.span("bam.combine_wall"):
             host = np.asarray(jax.device_get(totals_vec), dtype=np.int64)
-    quarantine_run_ok(path, config)
     return _attach_quarantine(
-        {k: int(host[i]) for i, k in enumerate(FLAGSTAT_FIELDS)}, quarantine)
+        {k: int(host[i]) for i, k in enumerate(FLAGSTAT_FIELDS)},
+        scan.quarantine)
 
 
 # Coverage row layout: the fixed-field projection (offsets sourced from
@@ -2395,12 +1690,8 @@ def coverage_file(path: str, region, mesh: Optional[Mesh] = None,
     outside the region mask to zero on device.
     """
     from hadoop_bam_tpu.formats.bamio import read_bam_header
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
     from hadoop_bam_tpu.split.intervals import Interval, resolve_interval
 
-    if mesh is None:
-        mesh = make_mesh()
-    n_dev = int(np.prod(mesh.devices.shape))
     if header is None:
         header, _ = read_bam_header(path)
     if not isinstance(region, Interval):
@@ -2418,6 +1709,11 @@ def coverage_file(path: str, region, mesh: Optional[Mesh] = None,
                          f"tile larger regions across calls")
     win_start = region.start - 1          # 0-based half-open window
 
+    # full-width ring tiles, their HEIGHT fixed (the step is cached per
+    # (window, op width)); dispatch cuts each group down to its real op
+    # width before it crosses the link
+    scan = ScanFeed("bam", config, mesh, (_cigar_row_bytes(max_cigar),),
+                    tile_records, fixed_shape=True, quarantine=quarantine)
     if spans is None:
         # pass the Interval OBJECT to the planner — round-tripping it
         # through the config string form would misparse contig names
@@ -2425,61 +1721,25 @@ def coverage_file(path: str, region, mesh: Optional[Mesh] = None,
         from hadoop_bam_tpu.split.bai import plan_interval_spans
         with METRICS.span("bam.plan_wall"):
             spans = plan_interval_spans(path, [region], header)
-            if spans is None:               # no .bai sidecar: whole file
-                span_bytes = 4 << 20
-                src = as_byte_source(path)
-                n_spans = max(n_dev, int(np.ceil(src.size / span_bytes)))
-                src.close()
-                from hadoop_bam_tpu.split.planners import plan_spans_cached
-                spans = plan_spans_cached(path, header, config,
-                                          num_spans=n_spans)
+        if spans is None:                   # no .bai sidecar: whole file
+            spans = _plan_bam_scan(path, header, config, scan.n_dev,
+                                   4 << 20)
 
-    sharding = NamedSharding(mesh, P("data"))
-    rep = NamedSharding(mesh, P())
+    rep = NamedSharding(scan.mesh, P())
     check_crc = bool(config.check_crc)
-    row_w = _cigar_row_bytes(max_cigar)
     window_depth = None                   # [n_dev, window], device-sharded
     tref = jax.device_put(np.int32(target_refid), rep)
     wstart = jax.device_put(np.int32(win_start), rep)
-
-    spans = list(spans)
-    if quarantine is not None and quarantine.total_spans is None:
-        quarantine.total_spans = len(spans)
     src = _resilient_source(path, config)
-    pool = decode_pool(config)
+    nc_off = _CIGAR_ROW_HDR - 4
 
-    def decode(span):
-        def inner(s):
-            return decode_span_cigar_rows(src, s, max_cigar,
-                                          check_crc, config=config)
-        with METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("bam.host_decode_wall"):
-            out = decode_with_retry(inner, span, config,
-                                    quarantine=quarantine)
-        return out if out is not None else np.zeros((0, row_w),
-                                                    np.uint8)
-
-    stream = _iter_windowed(pool, spans, decode,
-                            max(1, prefetch) * decode_pool_size(config),
-                            config=config)
-    # full-width ring tiles; dispatch slices each group down to its real
-    # pow2-bucketed op width before it crosses the link (fixed_shape:
-    # the HEIGHT never shrinks — the step is cached per (window, mc))
-    # count_bytes=False: this dispatch ships a width-sliced cut of the
-    # ring views, so it counts the real transferred bytes itself
-    fp = FeedPipeline(n_dev, tile_records,
-                      (TileSpec((row_w,), np.uint8),),
-                      fixed_shape=True, count_bytes=False, config=config,
-                      fmt="bam")
-
-    def dispatch(arrays, counts):
+    def cut(arrays, counts):
         # most records carry far fewer ops than max_cigar; slice the
         # tile to the group's real op width (pow2-bucketed so the jit
         # cache stays small) before it crosses the link
         tiles = arrays[0]
         mc = 1
-        nc_off = _CIGAR_ROW_HDR - 4
-        for dev in range(n_dev):
+        for dev in range(scan.n_dev):
             c = int(counts[dev])
             if c:
                 t = tiles[dev]
@@ -2491,21 +1751,19 @@ def coverage_file(path: str, region, mesh: Optional[Mesh] = None,
                 f"record with {mc} cigar ops exceeds "
                 f"max_cigar={max_cigar}; pass a larger max_cigar")
         mc = min(max_cigar, max(8, 1 << (mc - 1).bit_length()))
-        w = _cigar_row_bytes(mc)
-        step = make_coverage_step(mesh, window, mc)
-        cut = tiles[:, :, :w]
-        METRICS.count("pipeline.dispatch_bytes",
-                      int(cut.nbytes) + int(counts.nbytes))
-        t = jax.device_put(cut, sharding)
-        c = jax.device_put(counts, sharding)
-        with METRICS.span("bam.kernel_wall"):
-            out = step(t, c, tref, wstart)
-            nonlocal window_depth
-            window_depth = out if window_depth is None else \
-                window_depth + out    # shard-local add, no collective
-        return t, c      # in-flight handles: the ring waits before reuse
+        return (tiles[:, :, :_cigar_row_bytes(mc)],)
 
-    fp.feed(((r,) for r in stream), dispatch)
+    def consume(args, _counts):
+        nonlocal window_depth
+        mc = (args[0].shape[2] - _CIGAR_ROW_HDR) // 4
+        out = make_coverage_step(scan.mesh, window, mc)(*args, tref, wstart)
+        window_depth = out if window_depth is None else \
+            window_depth + out    # shard-local add, no collective
+
+    scan.run(scan.decoded(
+        spans, lambda s: (decode_span_cigar_rows(src, s, max_cigar, check_crc,
+                                                 config=config),),
+        max(1, prefetch) * decode_pool_size(config)), consume, cut=cut)
     if window_depth is None:
         return np.zeros(window, np.int32)
     # one cross-device reduce at the end instead of one psum per dispatch

@@ -32,9 +32,8 @@ Two mechanisms replace it:
   ``stream()`` powers the generator-shaped ``tensor_batches`` APIs.
 
 Wall-clock accounting rides along: ``pipeline.feed_wall`` (whole feed),
-``pipeline.dispatch_wall`` (host wall inside dispatch calls) and
-the ``pipeline.dispatch_bytes`` counter feed the bench's
-``overlap_efficiency`` ratio — the thread-summed ``METRICS.timer``
+``pipeline.dispatch_wall`` (host wall inside dispatch calls) and the
+``pipeline.dispatch_bytes`` counter — the thread-summed ``METRICS.timer``
 values cannot show overlap, the wall spans can.  Each thread's time is
 partitioned by spans (all carry a profiler annotation while a recorder
 is active): the dispatch thread's by ``feed.wait_group`` +
@@ -60,26 +59,6 @@ from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.obs.context import take_execute_start
 from hadoop_bam_tpu.obs.trace import active_recorder
 from hadoop_bam_tpu.utils.metrics import METRICS
-
-
-def committed_device_put(array, sharding=None):
-    """``jax.device_put`` that returns only after the host->device copy
-    is COMPLETE.  Plain device_put may return while the DMA is still
-    reading the host buffer (PJRT immutable-until-transfer-completes
-    semantics on real TPUs); for a BORROWED ring-slot view that window
-    is an aliasing hazard — the slot is released when dispatch returns
-    and the packer may overwrite it mid-transfer.  Blocking on the
-    RESULT bounds the wait to the transfer itself; compute steps
-    launched afterwards stay async, and the packer keeps assembling the
-    next group on its own thread throughout.  Every feed-path
-    device_put of ring-backed memory must go through here
-    (``jnp.asarray`` is outright forbidden: it aliases host memory on
-    the CPU backend)."""
-    import jax
-
-    out = jax.device_put(array, sharding)
-    jax.block_until_ready(out)
-    return out
 
 
 def bucket_cap(count: int, cap: int, block_n: int = 256) -> int:
@@ -322,16 +301,6 @@ class FeedPipeline:
         self.fmt = fmt
         self.dispatches = 0
         self.dispatch_bytes = 0
-        self._device_wall = 0.0
-        self._total_wall = 0.0
-
-    @property
-    def overlap_efficiency(self) -> float:
-        """Device-busy wall / total feed wall for the last run — the
-        ratio the bench reports to prove the overlap is real (1.0 means
-        the host never made the dispatch side wait)."""
-        return (self._device_wall / self._total_wall
-                if self._total_wall > 0 else 0.0)
 
     # -- packer side (its own thread) ---------------------------------------
 
@@ -506,7 +475,6 @@ class FeedPipeline:
         ctx = contextvars.copy_context()
         packer = threading.Thread(target=lambda: ctx.run(pack),
                                   name="hbam-feed-pack", daemon=True)
-        self._device_wall = 0.0
         self.dispatches = 0
         self.dispatch_bytes = 0
         t0 = time.perf_counter()
@@ -532,23 +500,13 @@ class FeedPipeline:
         finally:
             cancel.set()
             packer.join()
-            self._total_wall = time.perf_counter() - t0
-            METRICS.add_wall(f"{self.name}.feed_wall", self._total_wall,
+            wall = time.perf_counter() - t0
+            METRICS.add_wall(f"{self.name}.feed_wall", wall,
                              t0=t0, args={"groups": self.dispatches})
             if self.fmt:
-                METRICS.add_wall(f"{self.fmt}.feed_wall", self._total_wall)
+                METRICS.add_wall(f"{self.fmt}.feed_wall", wall)
         if errs:
             raise errs[0]
-
-    def groups(self, stream: Iterable[Tuple[np.ndarray, ...]]
-               ) -> Iterator[Tuple[Tuple[np.ndarray, ...], np.ndarray]]:
-        """Yield borrowed ``(arrays, counts)`` group batches (valid until
-        the generator is advanced).  NOTE: this pass-through path has no
-        in-flight transfer tracking — a consumer that hands these views
-        to jax itself must use ``committed_device_put`` (or copy first);
-        ``stream``/``feed`` consumers get the tracking for free."""
-        for _slot, arrays, counts in self._slots(stream):
-            yield arrays, counts
 
     @contextlib.contextmanager
     def _account(self, arrays: Tuple[np.ndarray, ...],
@@ -572,7 +530,6 @@ class FeedPipeline:
             # never for a feed that no plan.execute stands around
             METRICS.add_wall("feed.first_dispatch_wait", t0 - t_exec,
                              t0=t_exec)
-        self._device_wall += dt
         self.dispatches += 1
         METRICS.count_per_device(f"{self.name}.device_rows", counts)
         if n is not None:

@@ -17,26 +17,25 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import itertools
 import threading
 import time
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from hadoop_bam_tpu.parallel.mesh import shard_map
-from hadoop_bam_tpu.parallel.staging import FeedPipeline
+from hadoop_bam_tpu.parallel.scan import ScanFeed
 
 from hadoop_bam_tpu.config import DEFAULT_CONFIG, HBamConfig
 from hadoop_bam_tpu.formats.vcf import VariantBatch, VCFHeader
 from hadoop_bam_tpu.parallel.pipeline import (
-    _STEP_CACHE, _StatTotals, _iter_windowed, pipeline_span_count,
+    _STEP_CACHE, _StatTotals, pipeline_span_count,
 )
 from hadoop_bam_tpu.utils.metrics import METRICS
-from hadoop_bam_tpu.utils.pools import decode_pool, decode_pool_size
+from hadoop_bam_tpu.utils.pools import decode_pool_size
 from hadoop_bam_tpu.utils.stepcache import named_step
 
 # dispatch-bucket granularity for variant tiles (no Pallas block
@@ -478,109 +477,6 @@ def _fixed_field_columns(buf: np.ndarray, bounds: np.ndarray,
     return cols, odd
 
 
-def _iter_variant_tiles(cols_stream, cap: int, geometry: VariantGeometry
-                        ) -> Iterator[Tuple[Dict[str, np.ndarray], int]]:
-    """Repack a stream of per-span column dicts into cap-row tiles
-    (cross-span concatenation; only the final tile is padded).
-
-    The tile schema is taken from the first span's dict, so the feed
-    accepts both the stats schema (chrom/pos/flags/dosage) and extended
-    columnar dicts (e.g. formats/bcf_columns.py's rlen/qual/n_allele/
-    n_fmt columns) without either side hard-coding the other.
-
-    Serial tiler — the live drivers feed through the shared
-    parallel/staging.FeedPipeline (via _variant_feed_specs below); this
-    stays as the byte-identity oracle for its tests."""
-    from collections import deque
-
-    # deque: parts.pop(0) was O(n^2) on many-small-span plans
-    parts: "deque[Dict[str, np.ndarray]]" = deque()
-    have = 0
-    proto: Dict[str, np.ndarray] = {}
-
-    def empty_tile() -> Dict[str, np.ndarray]:
-        out = {}
-        for k, v in proto.items():
-            shape = (cap,) + v.shape[1:]
-            if k == "dosage":
-                out[k] = np.full(shape, -1, v.dtype)
-            elif k == "qual":
-                out[k] = np.full(shape, np.nan, v.dtype)
-            else:
-                out[k] = np.zeros(shape, v.dtype)
-        return out
-
-    def emit(take: int) -> Tuple[Dict[str, np.ndarray], int]:
-        nonlocal have
-        tile = empty_tile()
-        filled = 0
-        while filled < take:
-            head = parts[0]
-            m = min(take - filled, head["chrom"].shape[0])
-            for k in tile:
-                tile[k][filled:filled + m] = head[k][:m]
-            if m == head["chrom"].shape[0]:
-                parts.popleft()
-            else:
-                parts[0] = {k: v[m:] for k, v in head.items()}
-            filled += m
-        have -= take
-        return tile, take
-
-    for cols in cols_stream:
-        if not proto:
-            proto = cols
-        if cols["chrom"].shape[0]:
-            parts.append(cols)
-            have += cols["chrom"].shape[0]
-        while have >= cap:
-            yield emit(cap)
-    if have:
-        yield emit(have)
-
-
-def _variant_feed_specs(proto: Dict[str, np.ndarray]):
-    """Key order + TileSpecs for feeding schema-dict variant tiles
-    through the shared FeedPipeline (parallel/staging.py).  The schema
-    comes from the first span's dict — same genericity as
-    _iter_variant_tiles — and pads mirror its empty_tile: -1 for
-    dosage, NaN for qual, 0 elsewhere."""
-    from hadoop_bam_tpu.parallel.staging import TileSpec
-
-    keys = list(proto)
-    specs = []
-    for k in keys:
-        v = proto[k]
-        pad = -1 if k == "dosage" else (np.nan if k == "qual" else 0)
-        specs.append(TileSpec(tuple(v.shape[1:]), v.dtype, pad))
-    return keys, specs
-
-
-def variant_feed(cols_stream, n_dev: int, cap: int,
-                 config: HBamConfig = DEFAULT_CONFIG, **fp_kwargs):
-    """Peek the first span's column dict for the tile schema and build
-    the shared feed over it.  Returns ``(keys, fp, tuples)`` — or
-    ``(None, None, None)`` for an empty stream — where ``tuples`` is
-    the dict stream re-threaded as key-ordered array tuples for
-    ``fp.feed``/``fp.stream``.  The one place the
-    stats driver and VcfDataset.tensor_batches share their wiring, so
-    schema handling cannot drift between them.
-
-    The peek runs on the CALLER's thread (the dispatch thread of a scan)
-    before the feed exists, so none of the feed's own waits holds it: a
-    windowed stream's ``feed.head_wait`` (``_iter_windowed``) times it on
-    that thread, and ``feed.first_dispatch_wait`` holds it whole."""
-    stream = iter(cols_stream)
-    first = next(stream, None)
-    if first is None:
-        return None, None, None
-    keys, specs = _variant_feed_specs(first)
-    fp = FeedPipeline(n_dev, cap, specs, config=config, **fp_kwargs)
-    tuples = (tuple(d[k] for k in keys)
-              for d in itertools.chain([first], stream))
-    return keys, fp, tuples
-
-
 def make_variant_stats_step(mesh: Mesh, geometry: VariantGeometry,
                             axis: str = "data"):
     """Jitted sharded step: variant tiles -> psum'd stats vector
@@ -765,78 +661,52 @@ def variant_stats_file(path: str, mesh: Optional[Mesh] = None,
                                  spans=spans, prefetch=prefetch)
 
 
+# a variant step's arguments, by the tile schema's names
+_TILE_ARGS = ("chrom", "pos", "flags", "dosage", "n_records")
+
+
 def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
                        geometry: Optional[VariantGeometry],
                        header: Optional[VCFHeader], spans, prefetch: int,
-                       make_dispatch) -> VCFHeader:
+                       make_consume) -> VCFHeader:
     """What every whole-file variant driver runs: open the file, plan its
-    spans, decode them in the pool (retried), repack the rows into tile
-    groups and hand each group to the driver's own dispatch.
+    spans, and run them through the scan feed (``parallel/scan.py``):
+    decoded in the pool (retried), the rows repacked into tile groups
+    (the balanced final group spread over all shards and shrunk to a
+    dispatch bucket), each group to the driver's own step.
 
-    ``make_dispatch(ds, header, mesh, geometry)`` is called once the spans
-    are planned and returns ``dispatch(named, counts)``: ``named`` maps the
-    tile schema's keys to the group's borrowed ``[n_dev, bucket, ...]``
-    views, and what it returns (the device arrays made from them) is the
-    ring slot's in-flight handle.  Returns the header."""
+    ``make_consume(ds, header, mesh, geometry)`` is called once the spans
+    are planned and returns ``consume(args, counts)`` (``ScanFeed.run``):
+    ``args`` maps the tile schema's names and ``n_records`` to the group's
+    device arrays.  Returns the header."""
     from hadoop_bam_tpu.api.dispatch import VCFContainer
     from hadoop_bam_tpu.api.vcf_dataset import open_vcf
-    from hadoop_bam_tpu.parallel.mesh import make_mesh
 
     ds = open_vcf(path, config)
     if header is None:
         header = ds.header
-    if mesh is None:
-        mesh = make_mesh()
-    n_dev = int(np.prod(mesh.devices.shape))
     if geometry is None:
         geometry = VariantGeometry(n_samples=header.n_samples)
-    cap = geometry.tile_records
-
+    scan = ScanFeed("vcf", config, mesh, None, geometry.tile_records,
+                    block_n=_VARIANT_BLOCK_N, balance=True)
     if spans is None:
         with METRICS.span("vcf.plan_wall"):
             spans = ds.spans(
-                num_spans=variant_span_count(ds, n_dev, config))
-    dispatch_group = make_dispatch(ds, header, mesh, geometry)
-    pool = decode_pool(config)
-    window = max(1, prefetch) * decode_pool_size(config)
-    from hadoop_bam_tpu.parallel.pipeline import decode_with_retry
-
+                num_spans=variant_span_count(ds, scan.n_dev, config))
+    consume = make_consume(ds, header, scan.mesh, geometry)
     is_text = ds.container is not VCFContainer.BCF
     alive = _TextAlive()
 
-    def decode(span):
-        def inner(s):
-            # per-stage wall spans (Metrics.wall_timer: overlapping pool
-            # threads union, so values are wall seconds, not thread-sums)
-            # feed the bench's vcf_stage_seconds row
-            if is_text:     # fast tokenizer, no record objects
-                return text_span_stat_columns(ds, s, header, geometry,
-                                              alive)
-            return bcf_span_stat_columns(ds.path, s, header, geometry,
-                                         ds._is_bgzf_bcf)
-        with METRICS.wall_timer("pipeline.host_decode_wall"), \
-                METRICS.span("vcf.host_decode_wall"):
-            out = decode_with_retry(inner, span, config)
-        if out is not None:
-            return out
-        return pack_variant_tiles(VariantBatch([], header), geometry)
+    def decode(s):
+        if is_text:     # fast tokenizer, no record objects
+            return text_span_stat_columns(ds, s, header, geometry, alive)
+        return bcf_span_stat_columns(ds.path, s, header, geometry,
+                                     ds._is_bgzf_bcf)
 
-    stream = _iter_windowed(pool, spans, decode, window, config=config)
-    # ring-fed groups (variant_feed peeks the schema): rows write in
-    # place, a skewed device no longer makes the other seven copy its
-    # padding, and the balanced FINAL group spreads over all shards and
-    # shrinks to a dispatch bucket
-    keys, fp, tuples = variant_feed(stream, n_dev, cap, config,
-                                    block_n=_VARIANT_BLOCK_N,
-                                    balance=True, fmt="vcf")
-    if fp is not None:
-        def dispatch(arrays, counts):
-            # timed by FeedPipeline._account (pipeline. / vcf.dispatch_wall)
-            handles = dispatch_group(dict(zip(keys, arrays)), counts)
-            METRICS.count("pipeline.records", int(counts.sum()))
-            return handles  # in-flight: the ring waits on them
-
-        fp.feed(tuples, dispatch)
+    scan.run(scan.decoded(
+        spans, decode, max(1, prefetch) * decode_pool_size(config),
+        empty=pack_variant_tiles(VariantBatch([], header), geometry)),
+        consume)
     if is_text:
         METRICS.count("vcf.text_peak_bytes", alive.peak)
     return header
@@ -851,21 +721,14 @@ def _variant_stats_impl(path: str, mesh: Optional[Mesh] = None,
     """The variant-stats mesh-feed implementation (executor runner)."""
     totals = _StatTotals()
 
-    def make_dispatch(ds, header, mesh, geometry):
+    def make_consume(ds, header, mesh, geometry):
         step = make_variant_stats_step(mesh, geometry)
-        sharding = NamedSharding(mesh, P("data"))
-
-        def dispatch(named, counts):
-            args = [jax.device_put(named[k], sharding)
-                    for k in ("chrom", "pos", "flags", "dosage")]
-            c = jax.device_put(counts, sharding)
-            totals.add(*step(*args, c))  # async; drained at the end
-            return (*args, c)
-
-        return dispatch
+        # async; drained at the end
+        return lambda args, _counts: totals.add(
+            *step(*(args[k] for k in _TILE_ARGS)))
 
     header = _scan_variant_file(path, mesh, config, geometry, header, spans,
-                                prefetch, make_dispatch)
+                                prefetch, make_consume)
     return _variant_stats_result(totals, header)
 
 
@@ -964,7 +827,7 @@ def _variant_gwas_load(path: str, mesh: Optional[Mesh], config: HBamConfig,
     dev = mesh.devices.flat[0]
     st = GwasResident()
 
-    def make_dispatch(ds, header, mesh, geometry):
+    def make_consume(ds, header, mesh, geometry):
         tile = geometry.tile_records
         want = int(np.ceil(_estimate_variant_sites(ds)
                            * (1.0 + _GWAS_HEADROOM)))
@@ -988,25 +851,22 @@ def _variant_gwas_load(path: str, mesh: Optional[Mesh], config: HBamConfig,
         METRICS.count("gwas.resident_bytes", int(st.resident.nbytes))
         step = make_gwas_load_step(header.n_samples)
 
-        def dispatch(named, counts):
-            bucket = named["dosage"].shape[1]
+        def consume(args, counts):
+            bucket = args["dosage"].shape[1]
             if st.rows + bucket > capacity:
                 raise PlanError(
                     f"{ds.path}: more sites than its first records' size "
                     f"foretold ({capacity} rows were reserved)")
-            args = [jax.device_put(named[k], dev)
-                    for k in ("chrom", "pos", "flags", "dosage")]
-            c = jax.device_put(counts, dev)
             (st.resident, st.sites, st.acc, st.r, st.c,
              st.n_grm) = step(st.resident, st.sites, st.acc, st.r, st.c,
-                              st.n_grm, *args, c, np.int32(st.rows))
+                              st.n_grm, *(args[k] for k in _TILE_ARGS),
+                              np.int32(st.rows))
             st.rows += int(counts[0])
-            return (*args, c)
 
-        return dispatch
+        return consume
 
     header = _scan_variant_file(path, mesh, config, geometry, header, spans,
-                                prefetch, make_dispatch)
+                                prefetch, make_consume)
     return header, st
 
 
@@ -1071,7 +931,8 @@ def _variant_gwas_impl(path: str, traits: str, mesh: Optional[Mesh] = None,
         w[:n_s, n_t:n_t + q.shape[1]] = q
         isig = np.zeros(np_, np.float32)
         isig[:n_t] = 1.0 / sigma2
-        dev = st.resident.devices().pop()
+        # devices() may hand back the sharding's own set: read, never pop
+        dev = next(iter(st.resident.devices()))
         step = gwas.make_gwas_assoc_step(n_s, n_t, return_table)
         # split on the host: under jit a TPU may drop the round trip
         out = step(st.resident,

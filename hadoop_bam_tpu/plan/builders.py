@@ -168,11 +168,10 @@ def serve_tile_plan(path: str, kind: str = "bam",
                     end_voffset: int = 0) -> PlanIR:
     """One cold serve-tile build: decode a coalesced chunk's virtual-
     offset range and pack the (rid, pos1, end1) interval tile the
-    region-serve filter consumes (serve/tiles.py).  The serving loop
-    consumes ``select_plane`` on this DAG directly (a tile build is not
-    an executor sink — the loop owns ring/cache placement); the builder
-    exists for the ``hbam explain serve-tile`` surface and the digest
-    contract."""
+    region-serve filter consumes (serve/tiles.py).  A tile build is not
+    an executor sink — the serving loop owns ring/cache placement; the
+    builder exists for the ``hbam explain serve-tile`` surface and the
+    digest contract."""
     return PlanIR(
         source=SourceIR(path, kind, role="chunk"),
         spans=SpansIR.pin([(path, start_voffset, end_voffset)]),

@@ -15,8 +15,8 @@ Two jobs live here, and ONLY here:
    (``plan/builders.py``); ``execute`` dispatches the compiled plan to
    its family runner, counting executions and stamping the
    ``plan.execute_wall`` span, and owns the generic wiring — the cohort
-   tensor feed is wired HERE (FeedPipeline + sharded device_put), and
-   the query-chunk runner owns ``decode_with_retry`` + the
+   tensor feed is wired HERE (the scan feed's lazy form), and the
+   query-chunk runner owns ``decode_with_retry`` + the
    ``query.decode_wall``/chunk metrics taxonomy.  Family runners that
    need the mesh-feed machinery of ``parallel/pipeline.py`` delegate to
    its ``_*_impl`` functions, which consume the decision this module
@@ -39,7 +39,7 @@ from typing import Dict, Iterator, Optional, Tuple
 from hadoop_bam_tpu.config import (
     DECODE_PLANES, DEFAULT_CONFIG, HBamConfig, resolve_inflate_backend,
 )
-from hadoop_bam_tpu.plan.ir import PlanIR, SourceIR, TensorOpIR, op_node
+from hadoop_bam_tpu.plan.ir import PlanIR
 from hadoop_bam_tpu.utils.errors import PlanError
 from hadoop_bam_tpu.obs import context as trace_ctx
 from hadoop_bam_tpu.obs.trace import active_recorder
@@ -81,30 +81,7 @@ def _use_fused(config: Optional[HBamConfig],
             and inflate_ops.fused_available())
 
 
-def _fused_stream_gate(config: Optional[HBamConfig], intervals) -> bool:
-    """Chunk-streaming eligibility, shared by every driver that feeds
-    fused chunks to the FeedPipeline (ONE place, so a new
-    streaming-incompatible condition cannot be added to one driver and
-    missed in another): fused on, no interval filtering (the row mask
-    needs the whole span's offsets), and no skip_bad_spans (quarantine
-    is span-granular; a streamed span's early chunks would already be
-    dispatched when a late chunk turns out corrupt)."""
-    cfg = config if config is not None else DEFAULT_CONFIG
-    return (_use_fused(cfg) and intervals is None
-            and not cfg.skip_bad_spans)
-
-
-# canonical op DAGs of the in-repo scan/serve families (plan/builders.py
-# carries the fully-parameterized versions; these minimal twins are what
-# the mesh-feed impls pass to select_plane when invoked directly)
-FLAGSTAT_DAG = (op_node("project"), op_node("flagstat_reduce"))
-PAYLOAD_DAG = (op_node("payload_pack"), op_node("seq_stats_reduce"))
-VARIANT_DAG = (op_node("variant_pack"), op_node("variant_stats_reduce"))
-SERVE_TILE_DAG = (op_node("chunk_decode"), op_node("tile_build"))
-
-
-def select_plane(source: SourceIR, ops: Tuple[TensorOpIR, ...],
-                 config: Optional[HBamConfig], *,
+def select_plane(config: Optional[HBamConfig], *,
                  intervals=None) -> PlaneDecision:
     """THE plane-selection predicate table (module docstring).
 
@@ -112,9 +89,8 @@ def select_plane(source: SourceIR, ops: Tuple[TensorOpIR, ...],
     the gates test identity, matching the drivers' historical
     ``intervals is None``).  Native-library absence gates the fused
     mode (``fused_available`` implies native); the span decoders fall
-    to zlib themselves when the library is absent.  ``source`` and
-    ``ops`` name the plan being routed; no gate left reads them
-    (ROADMAP D4)."""
+    to zlib themselves when the library is absent.  Every family decides
+    through these same gates."""
     from hadoop_bam_tpu.ops import inflate as inflate_ops
 
     cfg = config if config is not None else DEFAULT_CONFIG
@@ -182,6 +158,10 @@ def select_chunk_source(*, tile_cached: bool, fleet_owned: bool,
     return "peer", "peer-owned chunk: fetch decoded columns"
 
 
+# the driver families ``plane_report`` shows a decision for
+PLANE_FAMILIES = ("flagstat", "payload", "variant", "serve")
+
+
 def plane_report(config: Optional[HBamConfig] = None) -> Dict[str, Dict]:
     """Display-only decision table per driver family for this process +
     config — the ``hbam serve`` health surface.  Never touches files;
@@ -189,17 +169,8 @@ def plane_report(config: Optional[HBamConfig] = None) -> Dict[str, Dict]:
     is set."""
     cfg = config if config is not None else DEFAULT_CONFIG
     intervals = () if getattr(cfg, "bam_intervals", None) else None
-    # the SAME DAG constants the drivers route with — rebuilding them
-    # here would be exactly the per-surface drift this module removes
-    fams = {
-        "flagstat": (SourceIR("<bam>", "bam"), FLAGSTAT_DAG),
-        "payload": (SourceIR("<bam>", "bam"), PAYLOAD_DAG),
-        "variant": (SourceIR("<bcf>", "bcf"), VARIANT_DAG),
-        "serve": (SourceIR("<bam>", "bam"), SERVE_TILE_DAG),
-    }
-    return {name: select_plane(src, ops, cfg,
-                               intervals=intervals).to_doc()
-            for name, (src, ops) in fams.items()}
+    return {name: select_plane(cfg, intervals=intervals).to_doc()
+            for name in PLANE_FAMILIES}
 
 
 # ---------------------------------------------------------------------------
@@ -284,16 +255,12 @@ def _run_flagstat(plan: PlanIR, cfg: HBamConfig, kw: Dict):
 
 def _run_seq_stats(plan: PlanIR, cfg: HBamConfig, kw: Dict):
     """Payload stats, the runner by the source's format: BAM through the
-    fused decode feed, FASTQ / QSEQ text through the chunk tokeniser, CRAM
-    through the columnar slice decoder."""
+    fused decode feed; FASTQ / QSEQ (text through the chunk tokeniser)
+    and CRAM (the columnar slice decoder) through the read-payload
+    runner, which picks the unit by the format."""
     from hadoop_bam_tpu.parallel import pipeline
 
-    if plan.source.fmt == "cram":
-        return pipeline._cram_stats_impl(
-            plan.source.path, mesh=kw.get("mesh"), config=cfg,
-            geometry=kw.get("geometry"), spans=kw.get("spans"),
-            quarantine=kw.get("quarantine"))
-    if plan.source.fmt in ("fastq", "qseq"):
+    if plan.source.fmt in ("fastq", "qseq", "cram"):
         return pipeline._read_stats_impl(
             plan.source.path, plan.source.fmt, mesh=kw.get("mesh"),
             config=cfg, geometry=kw.get("geometry"), spans=kw.get("spans"),
@@ -382,44 +349,21 @@ def _run_chunk_columns(plan: PlanIR, cfg: HBamConfig, kw: Dict):
 def _run_cohort_batches(plan: PlanIR, cfg: HBamConfig,
                         kw: Dict) -> Iterator[Dict]:
     """The cohort tensor feed, wired by the executor: joined site
-    chunks through the shared ``variant_feed``/FeedPipeline with the
-    sharded device_put emit whose returned dict doubles as the ring
-    slot's in-flight handle.  A generator, so a dataset whose
-    ``tensor_batches`` is built but never iterated starts no join (and
-    opens no journal)."""
+    chunks through the scan feed's lazy form (``parallel/scan.py``; the
+    first chunk's columns name the schema), every batch padded to the
+    full tile height.  A generator, so a dataset whose ``tensor_batches``
+    is built but never iterated starts no join (and opens no journal)."""
     dataset = kw["dataset"]
 
     def gen():
-        import jax
-        import numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from hadoop_bam_tpu.parallel.scan import ScanFeed
 
-        from hadoop_bam_tpu.parallel.mesh import make_mesh
-        from hadoop_bam_tpu.parallel.variant_pipeline import variant_feed
-
-        mesh = kw.get("mesh")
-        if mesh is None:
-            mesh = make_mesh()
         geometry = kw.get("geometry")
         if geometry is None:
             geometry = dataset.geometry
-        n_dev = int(np.prod(mesh.devices.shape))
-        sharding = NamedSharding(mesh, P("data"))
-
-        keys, fp, tuples = variant_feed(dataset.site_chunks(), n_dev,
-                                        geometry.tile_records, cfg,
-                                        fixed_shape=True, fmt="cohort")
-        if fp is None:
-            return
-
-        def emit(arrays, counts) -> Dict:
-            # the device dict doubles as the slot's in-flight handle
-            out = {k: jax.device_put(a, sharding)
-                   for k, a in zip(keys, arrays)}
-            out["n_records"] = jax.device_put(counts, sharding)
-            return out
-
-        yield from fp.stream(tuples, emit)
+        scan = ScanFeed("cohort", cfg, kw.get("mesh"), None,
+                        geometry.tile_records, fixed_shape=True)
+        yield from scan.batches(dataset.site_chunks())
 
     return gen()
 

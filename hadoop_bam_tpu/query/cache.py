@@ -8,7 +8,7 @@ so replacing a file on disk can never serve stale decoded chunks (the
 lint rule QE501 flags raw-path-only keys in this package).  Eviction is
 by byte budget, strict LRU; counters ride utils/metrics.py
 (``query.cache_hits`` / ``query.cache_misses`` / ``query.cache_evictions``)
-so the bench can report hit rates without private hooks.
+so hit rates read off a metrics snapshot without private hooks.
 """
 from __future__ import annotations
 
@@ -169,7 +169,7 @@ class ChunkCache:
 
     def stats(self) -> Dict[str, float]:
         """THIS cache's hit/miss/eviction counters and occupancy — what
-        ``bench.py`` reports as the region query row's hit rate.  (The
+        the query engine's ``stats()`` reports as its hit rate.  (The
         process-wide ``query.cache_*`` METRICS counters aggregate over
         every cache; a multi-engine server must not have one engine's
         traffic distort another's stats.)"""

@@ -485,8 +485,7 @@ def cmd_explain(args) -> int:
     # the gate sees parsed intervals at run time; a set-but-unparsed
     # config string is the same gate signal for explain purposes
     intervals = () if cfg.bam_intervals else None
-    decision = select_plane(plan.source, plan.ops, cfg,
-                            intervals=intervals)
+    decision = select_plane(cfg, intervals=intervals)
     if args.json:
         print(_json.dumps({"plan": plan.to_doc(),
                            "digest": plan.digest(),
@@ -792,7 +791,7 @@ def cmd_serve(args) -> int:
 
 def cmd_metrics(args) -> int:
     """Render or re-export a metrics snapshot written by
-    ``--metrics-json`` (or by bench.py): human text, Prometheus text
+    ``--metrics-json``: human text, Prometheus text
     exposition, or passthrough JSON.  Multiple snapshots merge with the
     same semantics as the mesh-wide allgather (counter sums, histogram
     bucket merges, wall maxima)."""

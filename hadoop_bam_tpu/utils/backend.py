@@ -1,7 +1,7 @@
 """Which JAX platform this process runs on, and where its compile cache is.
 
 Two rules shared by every entry point that touches a device (the ``hbam``
-device verbs, ``bench.py``, ``chip_smoke.py``):
+device verbs, ``chip_smoke.py``):
 
 - **The platform is what JAX gives.**  No code path switches platform.  A
   process that lands on the CPU without having asked for it — JAX falls

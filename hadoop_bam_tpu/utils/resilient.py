@@ -8,7 +8,7 @@ Three building blocks of the fault-classified resilience layer:
   schedules without real sleeps.
 - ``FaultInjectingByteSource``: the chaos twin — a deterministic fault
   schedule (transient errors, slow reads, truncations, bit flips) applied to
-  an intact source, usable from tests and ``bench.py`` via the registry hook
+  an intact source, usable from tests and drives via the registry hook
   (``install_chaos``) that ``as_byte_source`` consults for path sources.
 - ``QuarantineManifest``: the structured skip record ``decode_with_retry``
   fills under ``skip_bad_spans`` (file, virtual-offset range, error class,
@@ -289,7 +289,7 @@ class FaultInjectingByteSource(ByteSource):
 
 # Registry hook: install_chaos(path, ...) makes every ByteSource that
 # as_byte_source() opens for that path go through a FaultInjectingByteSource
-# — zero plumbing through the drivers, usable from tests and bench.py.
+# — zero plumbing through the drivers, usable from tests and drives.
 _CHAOS: Dict[str, Tuple[List[FaultSpec], Callable[[float], None],
                         Optional[SeededFaultSchedule]]] = {}
 

@@ -1215,8 +1215,8 @@ _PL_GOOD = '''
 from hadoop_bam_tpu.plan.executor import select_plane
 
 
-def run(config, source, ops, intervals, quarantine):
-    decision = select_plane(source, ops, config, intervals=intervals)
+def run(config, intervals, quarantine):
+    decision = select_plane(config, intervals=intervals)
     if decision.stream_fused:          # consuming the decision: fine
         pass
     if config.skip_bad_spans:          # solo read: failure policy,

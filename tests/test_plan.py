@@ -136,11 +136,6 @@ def test_pinned_spans_and_param_normalization():
 # plane selection: the gate matrix
 # ---------------------------------------------------------------------------
 
-_FLAG_SRC = SourceIR("x.bam", "bam")
-_FLAG_OPS = (op_node("project"), op_node("flagstat_reduce"))
-_PAYLOAD_OPS = (op_node("payload_pack"), op_node("seq_stats_reduce"))
-
-
 def _cfg(**kw):
     return dataclasses.replace(HBamConfig(), **kw)
 
@@ -151,8 +146,7 @@ def _rejected(decision):
 
 def test_select_native_clean_path():
     from hadoop_bam_tpu.ops.inflate import fused_available
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="native"))
+    d = select_plane(_cfg(inflate_backend="native"))
     assert d.plane == "native"
     assert d.use_fused == fused_available()
     assert d.stream_fused == fused_available()
@@ -160,7 +154,7 @@ def test_select_native_clean_path():
 
 
 def test_select_zlib_pins_portable_plane():
-    d = select_plane(_FLAG_SRC, _FLAG_OPS, _cfg(inflate_backend="zlib"))
+    d = select_plane(_cfg(inflate_backend="zlib"))
     assert d.plane == "zlib"
     assert not d.use_fused and not d.stream_fused
     rej = _rejected(d)
@@ -168,8 +162,7 @@ def test_select_zlib_pins_portable_plane():
 
 
 def test_select_fused_stream_rejected_by_intervals():
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="native"), intervals=[()])
+    d = select_plane(_cfg(inflate_backend="native"), intervals=[()])
     assert d.plane == "native"
     # the sweep itself stays eligible; only chunk streaming is gated
     from hadoop_bam_tpu.ops.inflate import fused_available
@@ -179,8 +172,7 @@ def test_select_fused_stream_rejected_by_intervals():
 
 
 def test_select_fused_stream_rejected_by_skip_bad_spans():
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="native", skip_bad_spans=True))
+    d = select_plane(_cfg(inflate_backend="native", skip_bad_spans=True))
     assert d.plane == "native"
     from hadoop_bam_tpu.ops.inflate import fused_available
     assert d.use_fused == fused_available() and not d.stream_fused
@@ -188,37 +180,38 @@ def test_select_fused_stream_rejected_by_skip_bad_spans():
         assert "quarantine" in _rejected(d)["fused-stream"]
 
 
-@pytest.mark.parametrize("src,ops", [
-    (SourceIR("x.bam", "bam"),
-     (op_node("payload_pack"), op_node("seq_stats_reduce"))),
-    (SourceIR("x.bcf", "bcf"),
-     (op_node("variant_pack"), op_node("variant_stats_reduce"))),
-    (SourceIR("x.bam", "bam", role="chunk"),
-     (op_node("chunk_decode"), op_node("tile_build"))),
-])
-def test_select_families_share_the_gate_matrix(src, ops, monkeypatch):
+@pytest.mark.parametrize("family", ["payload", "variant", "serve"])
+def test_select_families_share_the_gate_matrix(family, monkeypatch):
     """Every family decides through the SAME gates as flagstat:
     intervals and skip_bad_spans gate chunk streaming, zlib pins the
     portable plane with the sweep off — reason strings included (the
-    `hbam explain` surface)."""
+    `hbam explain` surface and the `hbam serve` health report)."""
     from hadoop_bam_tpu.ops import inflate as inflate_ops
+    from hadoop_bam_tpu.plan.executor import plane_report
     monkeypatch.setattr(inflate_ops, "fused_available", lambda: True)
 
-    d = select_plane(src, ops, _cfg(), intervals=[()])
-    assert d == select_plane(_FLAG_SRC, _FLAG_OPS, _cfg(), intervals=[()])
+    def report(**kw):
+        rep = plane_report(_cfg(**kw))
+        assert rep[family] == rep["flagstat"]
+        return rep[family]
+
+    d = select_plane(_cfg(), intervals=[()])
+    assert report(bam_intervals="chr1") == d.to_doc()
     assert d.plane == "native" and d.use_fused and not d.stream_fused
     assert "whole span's offsets" in _rejected(d)["fused-stream"]
 
-    d = select_plane(src, ops, _cfg(skip_bad_spans=True))
+    d = select_plane(_cfg(skip_bad_spans=True))
+    assert report(skip_bad_spans=True) == d.to_doc()
     assert d.plane == "native" and d.use_fused and not d.stream_fused
     assert "quarantine" in _rejected(d)["fused-stream"]
 
-    d = select_plane(src, ops, _cfg(inflate_backend="zlib"),
-                     intervals=[()])
+    d = select_plane(_cfg(inflate_backend="zlib"), intervals=[()])
+    assert report(inflate_backend="zlib", bam_intervals="chr1") == d.to_doc()
     assert d.plane == "zlib" and not d.use_fused and not d.stream_fused
     assert set(_rejected(d)) == {"fused", "native"}
 
-    d = select_plane(src, ops, _cfg())
+    d = select_plane(_cfg())
+    assert report() == d.to_doc()
     assert d.plane == "native" and d.use_fused and d.stream_fused
     assert _rejected(d) == {}
 
@@ -237,7 +230,7 @@ def test_inflate_backend_device_is_refused(bam, capsys):
     assert INFLATE_BACKENDS == ("auto", "native", "zlib")
     path, header, _ = bam
     cfg = _cfg(inflate_backend="device")
-    for run in (lambda: select_plane(_FLAG_SRC, _FLAG_OPS, cfg),
+    for run in (lambda: select_plane(cfg),
                 lambda: flagstat_file(path, config=cfg, header=header),
                 lambda: variant_stats_file(path + ".bcf", config=cfg)):
         with pytest.raises(PlanError) as ei:
@@ -254,16 +247,14 @@ def test_inflate_backend_device_is_refused(bam, capsys):
 def test_select_native_missing_disables_fused(monkeypatch):
     from hadoop_bam_tpu.ops import inflate as inflate_ops
     monkeypatch.setattr(inflate_ops, "fused_available", lambda: False)
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="native"))
+    d = select_plane(_cfg(inflate_backend="native"))
     assert d.plane == "native"
     assert not d.use_fused and not d.stream_fused
     assert "unavailable" in _rejected(d)["fused"]
 
 
 def test_select_fused_off_by_config():
-    d = select_plane(_FLAG_SRC, _FLAG_OPS,
-                     _cfg(inflate_backend="native",
+    d = select_plane(_cfg(inflate_backend="native",
                           use_fused_decode=False))
     assert not d.use_fused and not d.stream_fused
     assert "use_fused_decode" in _rejected(d)["fused"]
@@ -362,13 +353,16 @@ def test_query_chunk_plan_path_identical(bam, tmp_path):
 
 def test_cohort_plan_path_identical(tmp_path):
     """tensor_batches (plan path, executor-wired feed) vs an inline
-    replica of the pre-refactor wiring (variant_feed + device_put)."""
+    replica of the pre-refactor wiring (the first chunk's schema, a
+    FeedPipeline, device_put)."""
+    import itertools
+
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from hadoop_bam_tpu.cohort import CohortDataset
     from hadoop_bam_tpu.parallel.mesh import make_mesh
-    from hadoop_bam_tpu.parallel.variant_pipeline import variant_feed
+    from hadoop_bam_tpu.parallel.staging import FeedPipeline, TileSpec
 
     hdr = ("##fileformat=VCFv4.2\n"
            "##contig=<ID=c1,length=100000>\n"
@@ -394,10 +388,16 @@ def test_cohort_plan_path_identical(tmp_path):
     mesh = make_mesh()
     n_dev = int(np.prod(mesh.devices.shape))
     sharding = NamedSharding(mesh, P("data"))
-    keys, fp, tuples = variant_feed(ds2.site_chunks(), n_dev,
-                                    ds2.geometry.tile_records,
-                                    ds2.config, fixed_shape=True,
-                                    fmt="cohort")
+    chunks = ds2.site_chunks()
+    first = next(chunks)
+    keys = list(first)
+    pads = {"dosage": -1, "qual": np.nan}
+    fp = FeedPipeline(n_dev, ds2.geometry.tile_records,
+                      [TileSpec(first[k].shape[1:], first[k].dtype,
+                                pads.get(k, 0)) for k in keys],
+                      config=ds2.config, fixed_shape=True, fmt="cohort")
+    tuples = (tuple(d[k] for k in keys)
+              for d in itertools.chain([first], chunks))
 
     def emit(arrays, counts):
         out = {k: jax.device_put(a, sharding)

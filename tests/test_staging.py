@@ -310,7 +310,6 @@ def test_overlap_accounting_and_dispatch_bytes():
     assert fp.dispatches == 4
     # [2, 16, 4] u8 + [2] i32 per group
     assert fp.dispatch_bytes == 4 * (2 * 16 * 4 + 8)
-    assert 0.0 < fp.overlap_efficiency <= 1.0
 
     # wall_timer union semantics: overlapping same-name spans count once
     m = Metrics()
