@@ -123,25 +123,35 @@ def pack_variant_tiles_from_text(text, header: VCFHeader,
 
     One tokeniser for narrow and wide files, its work in proportion to
     the bytes and never to lines x samples.  One pass finds each record
-    line, its first nine tabs and — where FORMAT is exactly ``GT`` and
-    the sample block is the regular ``digit sep digit`` cells of a call
-    set — its dosage row: ``utils/native.py::vcf_tokenize`` with the
-    interpreter lock released, ``_vcf_tokenize_numpy`` on a host without
-    the library (counted: ``vcf.text_native_records`` /
-    ``vcf.text_numpy_records``).  CHROM, POS and the flags come from the
-    nine tabs by a few NumPy gathers (``_fixed_field_columns``).  A line
-    that is not regular (a multi-digit allele, a haploid or missing call,
-    ``GT:...`` subfields, a short line, an ALT wider than its gather, a
-    non-digit POS) is read by the scalar parse below, which stays the
-    statement of the semantics (``vcf.text_bulk_records`` /
-    ``vcf.text_scalar_records``; asserted equal by tests)."""
+    line, its first nine tabs and its dosage row where the sample block
+    is a shape it reads: FORMAT exactly ``GT`` with the regular ``digit
+    sep digit`` cells of a phased call set, or a keyed line — FORMAT
+    ``GT:`` and more keys, every line a caller such as GATK writes
+    (``GT:AD:DP:GQ:PL``) — whose cells each lead with ``digit sep
+    digit``, a half-missing ``./1`` or a no-call ``./.`` / ``.`` before
+    their first ':'.  That pass is ``utils/native.py::vcf_tokenize`` with
+    the interpreter lock released, or ``_vcf_tokenize_numpy`` on a host
+    without the library, which reads no keyed line (counted:
+    ``vcf.text_native_records`` / ``vcf.text_numpy_records``).  CHROM,
+    POS and the flags come from the nine tabs by a few NumPy gathers
+    (``_fixed_field_columns``).  A line the pass does not read (a
+    multi-digit allele, a haploid call, a missing call in a ``GT``-only
+    line, a FORMAT not led by ``GT``, a short or long line, an ALT wider
+    than its gather, a non-digit POS) is read by the scalar parse below,
+    which stays the statement of the semantics (``vcf.text_bulk_records``
+    / ``vcf.text_scalar_records``; asserted equal by tests).  Of the bulk
+    records, the keyed ones are counted once a span as
+    ``vcf.text_keyed_records`` and their no-call cells as
+    ``vcf.text_nocall_cells``."""
     from hadoop_bam_tpu.utils import native
 
     buf = np.frombuffer(text, dtype=np.uint8)
     S, pad = geometry.n_samples, geometry.samples_pad
+    keyed = nocall = 0
     with METRICS.span("vcf.gt_dosage_wall"):
         if native.load() is not None:
-            bounds, ntab, bulk, dosage = native.vcf_tokenize(buf, S, pad)
+            bounds, ntab, bulk, dosage, keyed, nocall = native.vcf_tokenize(
+                buf, S, pad)
             METRICS.count("vcf.text_native_records", len(ntab))
         else:
             bounds, ntab, bulk, dosage = _vcf_tokenize_numpy(buf, S, pad)
@@ -150,6 +160,13 @@ def pack_variant_tiles_from_text(text, header: VCFHeader,
     cols["dosage"] = dosage
     rows = np.flatnonzero(odd | ~bulk)
     if rows.size:
+        if keyed:
+            # a keyed line the pass read whose fixed fields send it to the
+            # scalar parse after all is no bulk record: out of the counts
+            lost = rows[bulk[rows] & _keyed_lines(buf, bounds[rows],
+                                                  ntab[rows])]
+            keyed -= lost.size
+            nocall -= int(np.count_nonzero(dosage[lost, :S] < 0))
         mv = memoryview(buf)
         patch = _pack_variant_tiles_from_text_scalar(
             b"\n".join(mv[s:e] for s, e in
@@ -159,7 +176,19 @@ def pack_variant_tiles_from_text(text, header: VCFHeader,
             cols[k][rows] = patch[k]
     METRICS.count("vcf.text_bulk_records", len(ntab) - rows.size)
     METRICS.count("vcf.text_scalar_records", int(rows.size))
+    METRICS.count("vcf.text_keyed_records", keyed)
+    METRICS.count("vcf.text_nocall_cells", nocall)
     return cols
+
+
+def _keyed_lines(buf: np.ndarray, bounds: np.ndarray, ntab: np.ndarray
+                 ) -> np.ndarray:
+    """Per line: its FORMAT starts ``GT:`` and sample fields follow (the
+    native pass's keyed branch)."""
+    at = np.minimum(bounds[:, 8] + 1, max(buf.size - 3, 0))
+    return (ntab == 9) & (bounds[:, 9] - bounds[:, 8] > 3) \
+        & (buf[at] == ord("G")) & (buf[at + 1] == ord("T")) \
+        & (buf[at + 2] == ord(":"))
 
 
 def _pack_variant_tiles_from_text_scalar(text: bytes, header: VCFHeader,

@@ -247,7 +247,7 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_vcf_tokenize.restype = ctypes.c_int64
         lib.hbam_vcf_tokenize.argtypes = [
             i8p, ctypes.c_int64, ctypes.c_int64, i64p, i32p, i8p, i8sp,
-            ctypes.c_int64, ctypes.c_int64]
+            ctypes.c_int64, ctypes.c_int64, i64p]
         lib.hbam_cram_slice_rebuild.restype = ctypes.c_int64
         lib.hbam_cram_slice_rebuild.argtypes = [
             ctypes.c_int64, i32p, i32p, i32p, i64p, i32p, i32p,
@@ -850,19 +850,25 @@ def fastq_tokenize(text, nibble: np.ndarray, seq_stride: int,
     return (seq, qual, lengths) if rc == 0 else None
 
 
-def vcf_tokenize(text, n_sample: int, samples_pad: int
-                 ) -> "tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]":
+def vcf_tokenize(text, n_sample: int, samples_pad: int) -> tuple:
     """A VCF text span's record lines in one native pass over the bytes,
     the interpreter lock released: (bounds [n, 11] i64 — a line's start,
     its first nine tabs (its end where it has fewer), its end; ntab [n]
-    i32, nine at most; bulk [n] bool; dosage [n, samples_pad] i8).  Where
-    ``bulk`` is set the dosage row is final: -1 throughout for a line with
-    no ``GT`` FORMAT, the ALT dosages of a line whose FORMAT is exactly
-    ``GT`` and whose ``n_sample`` cells are all ``digit sep digit``.  The
-    other rows are not written: the line goes to the caller's scalar
-    parse.  What
+    i32, nine at most; bulk [n] bool; dosage [n, samples_pad] i8; keyed;
+    nocall).  Where ``bulk`` is set the dosage row is final: -1
+    throughout for a line with no ``GT`` FORMAT; the ALT dosages of a line
+    whose FORMAT is exactly ``GT`` and whose ``n_sample`` cells are all
+    ``digit sep digit``; and of a keyed line — FORMAT ``GT:`` and more
+    keys, as GATK writes ``GT:AD:DP:GQ:PL`` — whose ``n_sample`` cells
+    each lead with ``digit sep digit``, a half-missing ``./1`` or a
+    no-call ``./.`` / ``.`` (-1) before the cell's first ':'.  ``keyed``
+    counts those keyed lines, ``nocall`` the no-call cells in them.  The
+    other rows are not written: a multi-digit allele, a haploid call, a
+    line with too few or too many cells or a FORMAT not led by ``GT`` goes
+    to the caller's scalar parse.  The first four arrays are what
     ``parallel/variant_pipeline.py::_vcf_tokenize_numpy`` returns, array
-    for array."""
+    for array, but for keyed lines, which the twin leaves to the scalar
+    parse (``bulk`` unset)."""
     lib = load()
     assert lib is not None
     buf = _src_u8(text)
@@ -871,20 +877,23 @@ def vcf_tokenize(text, n_sample: int, samples_pad: int
     p_text = _ptr(buf, ctypes.c_uint8)
     cap = int(lib.hbam_vcf_tokenize(p_text, int(buf.size), int(n_sample),
                                     None, None, None, None,
-                                    int(samples_pad), 0))
+                                    int(samples_pad), 0, None))
     if cap < 0:
         raise ValueError(f"vcf_tokenize refused its arguments ({cap})")
     bounds = np.empty((cap, 11), dtype=np.int64)
     ntab = np.empty(cap, dtype=np.int32)
     bulk = np.empty(cap, dtype=np.uint8)
     dosage = np.empty((cap, samples_pad), dtype=np.int8)
+    counts = np.zeros(2, dtype=np.int64)
     n = int(lib.hbam_vcf_tokenize(
         p_text, int(buf.size), int(n_sample), _ptr(bounds, ctypes.c_int64),
         _ptr(ntab, ctypes.c_int32), _ptr(bulk, ctypes.c_uint8),
-        _ptr(dosage, ctypes.c_int8), int(samples_pad), cap))
+        _ptr(dosage, ctypes.c_int8), int(samples_pad), cap,
+        _ptr(counts, ctypes.c_int64)))
     if n != cap:
         raise ValueError(f"vcf_tokenize counted {cap} records and wrote {n}")
-    return bounds, ntab, bulk.view(bool), dosage
+    return bounds, ntab, bulk.view(bool), dosage, int(counts[0]), \
+        int(counts[1])
 
 
 def grm_finish(acc: np.ndarray, r: np.ndarray, c: float, n_grm: int,

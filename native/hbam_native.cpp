@@ -23,6 +23,10 @@
 
 #include <zlib.h>
 
+#if defined(__SSE2__)
+#include <emmintrin.h>
+#endif
+
 // libdeflate inflates raw DEFLATE ~2x faster than zlib; the build probes for
 // it (utils/native.py) and falls back to plain zlib when absent.
 #if defined(HBAM_USE_LIBDEFLATE)
@@ -943,6 +947,82 @@ int64_t hbam_bcf_guess(const uint8_t* data, int64_t n, int64_t first_len,
 // line of fewer than eight fields are no record.  No threads: the callers'
 // pool threads run it with the interpreter lock released.
 // ---------------------------------------------------------------------------
+namespace {
+
+// Bit j set where p[j] is a tab, j < 64.
+inline uint64_t tab_mask64(const uint8_t* p) {
+#if defined(__SSE2__)
+  const __m128i tab = _mm_set1_epi8('\t');
+  uint64_t m = 0;
+  for (int j = 0; j < 4; ++j) {
+    const __m128i v =
+        _mm_loadu_si128(reinterpret_cast<const __m128i*>(p + 16 * j));
+    m |= uint64_t{static_cast<uint16_t>(
+             _mm_movemask_epi8(_mm_cmpeq_epi8(v, tab)))}
+         << (16 * j);
+  }
+  return m;
+#else
+  uint64_t m = 0;
+  for (int j = 0; j < 64; ++j) m |= uint64_t{p[j] == '\t'} << j;
+  return m;
+#endif
+}
+
+// The GT that leads the keyed cell at g, the block ending at ``end``:
+// ``a/b`` or ``a|b`` of two digits gives (a > 0) + (b > 0); a '.' in
+// either half, or a bare '.', gives -1 (a no-call); -2 for any other GT
+// (a multi-digit allele, a haploid call, an empty cell, a '\r' after a last
+// bare GT).
+inline int keyed_gt(const uint8_t* g, const uint8_t* end) {
+  const int64_t room = end - g;
+  if (room >= 3 && (g[1] == '/' || g[1] == '|') &&
+      (room == 3 || g[3] == ':' || g[3] == '\t')) {
+    const unsigned a = static_cast<unsigned>(g[0]) - '0',
+                   b = static_cast<unsigned>(g[2]) - '0';
+    if (a <= 9 && b <= 9) return (a > 0) + (b > 0);
+    if ((a <= 9 || g[0] == '.') && (b <= 9 || g[2] == '.')) return -1;
+    return -2;
+  }
+  if (room >= 1 && g[0] == '.' &&
+      (room == 1 || g[1] == ':' || g[1] == '\t'))
+    return -1;
+  return -2;
+}
+
+// A keyed sample block [g, end) — the cells of a line whose FORMAT is ``GT:``
+// and more keys, as every caller writes them (``0/1:12,9:21:99:230,0,310``)
+// — read for its dosages: a cell starts the block or follows a tab, and its
+// GT is its bytes up to the first ':' (``keyed_gt``).  Work follows the
+// bytes: a 64-byte stretch's tabs found at once, then each cell's few GT
+// bytes; AD/DP/GQ/PL are never read one by one.  Returns the no-call cells
+// of a block of exactly ``n_sample`` cells whose GTs all read, -1 for any
+// other block: the caller's scalar parse reads that line, and ``out`` holds
+// nothing.
+int64_t vcf_keyed_walk(const uint8_t* g, const uint8_t* end, int64_t n_sample,
+                       int8_t* out) {
+  int64_t cells = 0, nocall = 0;
+  auto cell = [&](const uint8_t* c) {
+    if (cells == n_sample) return false;       // more cells than samples
+    const int d = keyed_gt(c, end);
+    out[cells++] = static_cast<int8_t>(d);
+    nocall += d == -1;
+    return d > -2;
+  };
+  if (!cell(g)) return -1;
+  const int64_t len = end - g;
+  int64_t at = 0;
+  for (; at + 64 <= len; at += 64) {
+    for (uint64_t m = tab_mask64(g + at); m; m &= m - 1)
+      if (!cell(g + at + __builtin_ctzll(m) + 1)) return -1;
+  }
+  for (; at < len; ++at)
+    if (g[at] == '\t' && !cell(g + at + 1)) return -1;
+  return cells == n_sample ? nocall : -1;
+}
+
+}  // namespace
+
 extern "C" {
 
 // Every record line of text[0, n), in order, into row i of
@@ -950,24 +1030,32 @@ extern "C" {
 //                      where it has fewer), its end (the '\n', or n);
 //   ntab   [cap]     : tabs found, nine at most;
 //   bulk   [cap]     : 1 where the row of ``dosage`` is final, 0 where the
-//                      line's sample fields are not the regular shape and
+//                      line's sample fields are not a shape read here and
 //                      the caller's scalar parse has to read the line;
 //   dosage [cap, stride] int8 : -1 in every column of a line with no FORMAT
 //                      that starts ``GT``; of a line whose FORMAT is exactly
 //                      ``GT`` and whose sample block is n_sample cells
 //                      ``digit sep digit`` (sep '/' or '|') joined by tabs —
 //                      4 n_sample - 1 bytes, the shape of nearly every line
-//                      of a call set — (a > 0) + (b > 0) a sample, -1 in the
-//                      columns past n_sample.  A row with bulk 0 is not
-//                      written.
-// Work follows the bytes: nine ``memchr`` for tabs, one for the line end and
-// one pass over the sample block a line, whatever n_sample is.  Returns the
-// number of records, -1 for arguments it cannot take, -2 when ``cap`` rows
-// do not hold them (nothing outside the arrays is written either way).
-// With ``bounds`` null it only counts the records: what sizes the arrays.
+//                      of a phased call set — (a > 0) + (b > 0) a sample;
+//                      of a keyed line — FORMAT ``GT:`` and more keys, the
+//                      shape of every line a caller such as GATK writes —
+//                      what ``vcf_keyed_walk`` reads, no-calls as -1; -1 in
+//                      the columns past n_sample.  A row with bulk 0 holds
+//                      nothing the caller may read.
+//   counts [2]       : where not null, += the keyed lines with bulk 1 and
+//                      the no-call cells in them.
+// The branch is chosen once a line from FORMAT: a ``GT`` line takes the
+// fixed-stride loop, a keyed line the walk.  Work follows the bytes: nine
+// ``memchr`` for tabs, one for the line end and one pass over the sample
+// block a line, whatever n_sample is.  Returns the number of records, -1
+// for arguments it cannot take, -2 when ``cap`` rows do not hold them
+// (nothing outside the arrays is written either way).  With ``bounds`` null
+// it only counts the records: what sizes the arrays.
 int64_t hbam_vcf_tokenize(const uint8_t* text, int64_t n, int64_t n_sample,
                           int64_t* bounds, int32_t* ntab, uint8_t* bulk,
-                          int8_t* dosage, int64_t stride, int64_t cap) {
+                          int8_t* dosage, int64_t stride, int64_t cap,
+                          int64_t* counts) {
   if (n < 0 || cap < 0 || n_sample < 0 || n_sample > stride ||
       n_sample > (int64_t{1} << 40))
     return -1;
@@ -1009,6 +1097,15 @@ int64_t hbam_vcf_tokenize(const uint8_t* text, int64_t n, int64_t n_sample,
     uint8_t ok = 1;
     if (!has_gt) {
       std::memset(out, 0xFF, static_cast<size_t>(stride));
+    } else if (t[8] - t[7] - 1 > 2 && text[t[7] + 3] == ':') {
+      const int64_t nocall =
+          vcf_keyed_walk(text + t[8] + 1, text + e, n_sample, out);
+      ok = nocall >= 0;
+      if (ok) {
+        std::memset(out + n_sample, 0xFF,
+                    static_cast<size_t>(stride - n_sample));
+        if (counts) { counts[0] += 1; counts[1] += nocall; }
+      }
     } else if (t[8] - t[7] - 1 != 2 || e - t[8] - 1 != block) {
       ok = 0;
     } else {

@@ -22,7 +22,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # native entry point (BGZF header walk, inflate, CRC, record walks,
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
 # kernel, the BCF record walker (chase / span columns / guess), the CRAM
-# slice rebuild, the FASTQ tokenise + pack, the DEFLATE block finder /
+# slice rebuild, the FASTQ tokenise + pack, the VCF text tokenise (its
+# GT-only loop and keyed walk), the DEFLATE block finder /
 # symbol decoder / resolve, the GWAS job's GRM finish).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
@@ -374,6 +375,60 @@ for t in ts:
 for t in ts:
     t.join(60)
 assert len(fq_ok) == 4
+
+# the VCF text tokenise (hbam_vcf_tokenize): GT-only and keyed lines
+# (FORMAT GT:AD:DP:GQ:PL: calls, no-calls bare and keyed, half-missing,
+# multi-allelic, multi-digit and haploid GTs, a short line, a cell of 800
+# bytes past a 64-byte stretch), the text cut at every byte of its first
+# lines and its last (a line that ends inside a cell, a cell that runs past
+# the text: ASan's to see), each copy ending on its last byte; the native
+# pass and its fallback against the scalar parse; four threads at once
+from hadoop_bam_tpu.formats.vcf import VCFHeader
+from hadoop_bam_tpu.parallel import variant_pipeline as vp
+vs = 12
+vhdr = VCFHeader.from_text(
+    "##fileformat=VCFv4.2\n##contig=<ID=chr20,length=64444167>\n"
+    "#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO\tFORMAT"
+    + "".join("\ts%d" % i for i in range(vs)) + "\n")
+vgeom = vp.VariantGeometry(n_samples=vs)
+vcells = ["0/0:31,0:31:93:0,93,930", "0/1:14,12:26:99:350,0,420",
+          "1/1:0,30:30:90:900,90,0", "./.", "./.:0,0:0:.:0,0,0",
+          "./1:3,4:7:20:90,0,80", "1/2:0,9,8:17:99:600,300,280,0,0,300",
+          ".", "10/1:1,1:2:3:4,5,6", "1", "0/0:" + "9," * 400 + "9:9:9:0"]
+def vline(i):
+    fmt = rng.choice(["GT", "GT:AD:DP:GQ:PL", "GT:AD:DP:GQ:PL"])
+    cells = [rng.choice(vcells[:8] * 4 + vcells[8:]) for _ in range(vs)]
+    if fmt == "GT":
+        cells = [rng.choice(["0/0", "0|1", "1/1"]) for _ in range(vs)]
+    if i % 9 == 4:
+        cells = cells[:-1]
+    return "\t".join(["chr20", str(1000 + i), ".", "A", "C,*", "50.5",
+                      rng.choice(["PASS", "VQSRTrancheSNP99.80to100.00"]),
+                      "AC=1", fmt] + cells)
+vtext = ("\n".join(vline(i) for i in range(60)) + "\n").encode()
+def vcheck(text):
+    own = np.frombuffer(bytes(text), np.uint8).copy()   # ends on its last byte
+    got = vp.pack_variant_tiles_from_text(own, vhdr, vgeom)
+    want = vp._pack_variant_tiles_from_text_scalar(bytes(text), vhdr, vgeom)
+    for k in want:
+        assert (got[k] == want[k]).all(), (k, bytes(text[-40:]))
+assert native.vcf_tokenize(np.frombuffer(vtext, np.uint8).copy(), vs, 16)[4]
+for cut in list(range(0, 600)) + list(range(len(vtext) - 900, len(vtext))):
+    vcheck(vtext[:cut])
+for odd in (b"", b"\n", b"a\t1\tc\td\te\tf\tg\th\tGT:AD\t",
+            b"chr20\t1\t.\tA\tC\t5\tPASS\t.\tGT:AD\t" + b"\t" * vs):
+    vcheck(odd)
+v_ok = []
+def v_many():
+    for _ in range(20):
+        vcheck(vtext)
+    v_ok.append(1)
+ts = [threading.Thread(target=v_many) for _ in range(4)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(60)
+assert len(v_ok) == 4
 
 # DEFLATE inside a member (a gzip'd FASTQ's inflate workers): the block
 # finder over a buffer that ends anywhere (a header read past the end is

@@ -331,10 +331,14 @@ def test_text_tokenizer_vectorized_matches_scalar(n_samples, make, n_lines,
 def test_native_pass_and_numpy_twin_return_the_same_arrays():
     """``utils/native.py::vcf_tokenize`` and ``_vcf_tokenize_numpy``,
     array for array (a row with ``bulk`` unset aside: neither promises
-    it), lines with a long head included."""
+    it), lines with a long head included — but for the keyed lines
+    (FORMAT ``GT:`` and more keys), which only the native pass reads and
+    the twin leaves to the scalar parse."""
     import random as _random
 
-    from hadoop_bam_tpu.parallel.variant_pipeline import _vcf_tokenize_numpy
+    from hadoop_bam_tpu.parallel.variant_pipeline import (
+        _keyed_lines, _vcf_tokenize_numpy,
+    )
     from hadoop_bam_tpu.utils import native
 
     if native.load() is None:
@@ -349,9 +353,16 @@ def test_native_pass_and_numpy_twin_return_the_same_arrays():
         a = native.vcf_tokenize(buf, n_samples, pad)
         b = _vcf_tokenize_numpy(buf, n_samples, pad)
         for x, y in zip(a[:3], b[:3]):
-            assert x.dtype == y.dtype and np.array_equal(x, y)
-        assert np.array_equal(a[3][a[2]], b[3][b[2]])
+            assert x.dtype == y.dtype and x.shape == y.shape
+        assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
+        keyed = _keyed_lines(buf, a[0], a[1])
+        assert np.array_equal(a[2] & ~keyed, b[2])
+        assert not (b[2] & keyed).any()
+        assert a[4] == int((a[2] & keyed).sum())
+        assert a[5] == int((a[3][a[2] & keyed, :n_samples] < 0).sum())
+        assert np.array_equal(a[3][b[2]], b[3][b[2]])
         assert a[2].any() and not a[2].all()
+        assert (a[2] & keyed).any()
 
 
 def test_variant_geometry_byte_budget_large_cohorts():
