@@ -34,6 +34,7 @@ from hadoop_bam_tpu.formats.vcf import VariantBatch, VCFHeader
 from hadoop_bam_tpu.parallel.pipeline import (
     _STEP_CACHE, _StatTotals, pipeline_span_count,
 )
+from hadoop_bam_tpu.utils import native
 from hadoop_bam_tpu.utils.metrics import METRICS
 from hadoop_bam_tpu.utils.pools import decode_pool_size
 from hadoop_bam_tpu.utils.stepcache import named_step
@@ -142,9 +143,11 @@ def pack_variant_tiles_from_text(text, header: VCFHeader,
     / ``vcf.text_scalar_records``; asserted equal by tests).  Of the bulk
     records, the keyed ones are counted once a span as
     ``vcf.text_keyed_records`` and their no-call cells as
-    ``vcf.text_nocall_cells``."""
-    from hadoop_bam_tpu.utils import native
+    ``vcf.text_nocall_cells``.
 
+    A scan on a host with the library takes ``span_columns_native``
+    instead; this composition is what a host without it runs, and that
+    path's oracle."""
     buf = np.frombuffer(text, dtype=np.uint8)
     S, pad = geometry.n_samples, geometry.samples_pad
     keyed = nocall = 0
@@ -167,17 +170,58 @@ def pack_variant_tiles_from_text(text, header: VCFHeader,
                                                   ntab[rows])]
             keyed -= lost.size
             nocall -= int(np.count_nonzero(dosage[lost, :S] < 0))
-        mv = memoryview(buf)
-        patch = _pack_variant_tiles_from_text_scalar(
-            b"\n".join(mv[s:e] for s, e in
-                       bounds[rows][:, (0, 10)].tolist()) + b"\n",
-            header, geometry)
-        for k in cols:
-            cols[k][rows] = patch[k]
-    METRICS.count("vcf.text_bulk_records", len(ntab) - rows.size)
-    METRICS.count("vcf.text_scalar_records", int(rows.size))
+        _patch_scalar_rows(cols, buf, rows, bounds[rows][:, (0, 10)],
+                           header, geometry)
+    _count_text_rows(len(ntab), int(rows.size), keyed, nocall)
+    return cols
+
+
+def _patch_scalar_rows(cols: Dict[str, np.ndarray], buf: np.ndarray,
+                       rows: np.ndarray, lines: np.ndarray,
+                       header: VCFHeader, geometry: VariantGeometry) -> None:
+    """Rows ``rows`` of ``cols`` from the scalar parse of their lines
+    (``lines``: each one's start and end in ``buf``)."""
+    mv = memoryview(buf)
+    patch = _pack_variant_tiles_from_text_scalar(
+        b"\n".join(mv[s:e] for s, e in lines.tolist()) + b"\n",
+        header, geometry)
+    for k in cols:
+        cols[k][rows] = patch[k]
+
+
+def _count_text_rows(n: int, scalar: int, keyed: int, nocall: int) -> None:
+    METRICS.count("vcf.text_bulk_records", n - scalar)
+    METRICS.count("vcf.text_scalar_records", scalar)
     METRICS.count("vcf.text_keyed_records", keyed)
     METRICS.count("vcf.text_nocall_cells", nocall)
+
+
+def span_columns_native(text, records: int, header: VCFHeader,
+                        geometry: VariantGeometry,
+                        contigs: "native.ContigTable"
+                        ) -> Dict[str, np.ndarray]:
+    """``pack_variant_tiles_from_text``'s columns, equal to them byte for
+    byte, from ONE native pass over the span's lines with the interpreter
+    lock released (``utils/native.py::vcf_span_columns``): the walk and
+    dosage rows of ``hbam_vcf_tokenize`` with CHROM looked up in the
+    scan's contig table, POS parsed and the PASS / SNP flags set beside
+    them, in place of ``_fixed_field_columns``' gathers.  ``records`` is
+    the count of record lines the read found (-1: the pass counts them
+    first).  The scalar parse reads the lines the pass refuses, as there;
+    but for an ALT wider than ``_ALT_W``, whose SNP flag the pass sets at
+    any width.  Counted as that path counts, and once a span as
+    ``vcf.text_span_native_spans``."""
+    with METRICS.span("vcf.gt_dosage_wall"):
+        cols, refused, keyed, nocall = native.vcf_span_columns(
+            text, records, geometry.n_samples, geometry.samples_pad,
+            contigs)
+    n = len(cols["flags"])
+    METRICS.count("vcf.text_native_records", n)
+    if refused.shape[0]:
+        _patch_scalar_rows(cols, np.frombuffer(text, np.uint8),
+                           refused[:, 0], refused[:, 1:], header, geometry)
+    _count_text_rows(n, int(refused.shape[0]), keyed, nocall)
+    METRICS.count("vcf.text_span_native_spans")
     return cols
 
 
@@ -300,14 +344,26 @@ class _TextAlive:
 
 
 def text_span_stat_columns(ds, span, header: VCFHeader,
-                           geometry: VariantGeometry, alive: _TextAlive
+                           geometry: VariantGeometry, alive: _TextAlive,
+                           contigs: "Optional[native.ContigTable]" = None
                            ) -> Dict[str, np.ndarray]:
-    """One text-VCF span -> stats tile columns: the span's lines read
-    (``VcfDataset.span_text``: a BGZF file's through one positioned read
-    and one native inflate into a leased buffer) and tokenised
-    (``pack_variant_tiles_from_text``) — the text twin of
-    ``bcf_span_stat_columns``, with its spans and counters; ``alive``
-    is the scan's account of text in flight."""
+    """One text-VCF span -> stats tile columns — the text twin of
+    ``bcf_span_stat_columns``, with its spans and counters; ``alive`` is
+    the scan's account of text in flight.
+
+    ``contigs`` (the scan's contig table, built where the native library
+    is) decides the path.  With it the span goes from its compressed bytes
+    to its columns in native calls with the interpreter lock released: a
+    BGZF file's lines read and counted (``split/vcf_planners.py::
+    bgzf_text_span_lines``: two calls), any other container's by
+    ``VcfDataset.span_text``, then ``span_columns_native`` (one call; one
+    more to count lines the read did not).  Without it the Python
+    composition runs — ``VcfDataset.span_text`` and
+    ``pack_variant_tiles_from_text``, the native pass's oracle — counted
+    ``vcf.text_span_python_spans``."""
+    from hadoop_bam_tpu.api.dispatch import VCFContainer
+    from hadoop_bam_tpu.split.vcf_planners import bgzf_text_span_lines
+
     t_cpu = time.thread_time_ns()
     held = 0
     try:
@@ -315,11 +371,21 @@ def text_span_stat_columns(ds, span, header: VCFHeader,
         # the stack unwinds: every column below is memory of its own
         with contextlib.ExitStack() as leased:
             with METRICS.span("vcf.inflate_wall"):
-                text = leased.enter_context(ds.span_text(span))
+                if contigs is not None \
+                        and ds.container is VCFContainer.VCF_BGZF:
+                    text, records = leased.enter_context(
+                        bgzf_text_span_lines(ds.path, span))
+                else:
+                    text = leased.enter_context(ds.span_text(span))
+                    records = -1
             held = len(text)
             METRICS.count("vcf.inflated_bytes", held)
             alive.add(held)
             with METRICS.span("vcf.tokenize_wall"):
+                if contigs is not None:
+                    return span_columns_native(text, records, header,
+                                               geometry, contigs)
+                METRICS.count("vcf.text_span_python_spans")
                 return pack_variant_tiles_from_text(text, header, geometry)
     finally:
         alive.add(-held)
@@ -725,10 +791,14 @@ def _scan_variant_file(path: str, mesh: Optional[Mesh], config: HBamConfig,
     consume = make_consume(ds, header, scan.mesh, geometry)
     is_text = ds.container is not VCFContainer.BCF
     alive = _TextAlive()
+    # CHROM's table for the native text pass: once a scan, never a span
+    contigs = native.contig_table(header.contigs) \
+        if is_text and native.load() is not None else None
 
     def decode(s):
         if is_text:     # fast tokenizer, no record objects
-            return text_span_stat_columns(ds, s, header, geometry, alive)
+            return text_span_stat_columns(ds, s, header, geometry, alive,
+                                          contigs)
         return bcf_span_stat_columns(ds.path, s, header, geometry,
                                      ds._is_bgzf_bcf)
 

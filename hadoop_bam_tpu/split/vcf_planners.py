@@ -105,39 +105,52 @@ def bgzf_text_span(source, span: FileByteSpan
                    ) -> Iterator[memoryview]:
     """``with bgzf_text_span(...) as text``: all text lines *starting*
     within the span's compressed block range — the input of the text
-    tokeniser (parallel/variant_pipeline.pack_variant_tiles_from_text).
+    tokeniser (parallel/variant_pipeline.pack_variant_tiles_from_text);
+    ``bgzf_text_span_lines`` without the count of its records."""
+    with bgzf_text_span_lines(source, span) as (text, _records):
+        yield text
+
+
+@contextlib.contextmanager
+def bgzf_text_span_lines(source, span: FileByteSpan
+                         ) -> Iterator[Tuple[memoryview, int]]:
+    """``with bgzf_text_span_lines(...) as (text, records)``: the lines of
+    ``bgzf_text_span`` and the count of record lines among them, or -1
+    where the read did not count them.
 
     A line belongs to the span in which its first byte lies: the span
     whose blocks [span.start, span.end) hold that byte.  So the partial
     line at the span's head is the previous span's (told by the last byte
-    of the block before ``span.start``, ``_prev_block_last_byte``), the
-    span's last line is read to its end in the blocks that follow, the
-    first span owns the header lines, and the union of a plan's spans is
-    every line of the file exactly once, whatever the span count.
+    of the block before ``span.start``), the span's last line is read to
+    its end in the blocks that follow, the first span owns the header
+    lines, and the union of a plan's spans is every line of the file
+    exactly once, whatever the span count.
 
     The host decides the path.  With the native library the span is read
-    as the BCF read reads one (``_lease_bgzf_text``): then ``text`` is a
-    view of a buffer leased from the span-buffer pool, good until the
-    ``with`` ends — whatever outlives it must be a copy.  Without it the
-    blocks are inflated one by one in Python (``_inflate_text_python``)
-    and ``text`` is a view of their ``bytes``.  Counters:
-    ``vcf.native_read_spans`` / ``vcf.python_read_spans``."""
+    by native calls with the interpreter lock released
+    (``_lease_bgzf_text``): then ``text`` is a view of a buffer leased
+    from the span-buffer pool, good until the ``with`` ends — whatever
+    outlives it must be a copy.  Without it the blocks are inflated one by
+    one in Python (``_inflate_text_python``, ``_prev_block_last_byte``,
+    ``_owned_text``: the statement of the rules) and ``text`` is a view of
+    their ``bytes``.  Counters: ``vcf.native_read_spans`` /
+    ``vcf.python_read_spans``."""
     with scoped_byte_source(source) as src:
         leased = _lease_bgzf_text(src, span)
         if leased is None:
             METRICS.count("vcf.python_read_spans")
-            lease = NO_LEASE
             buf, base_len = _inflate_text_python(src, span)
-        else:
-            METRICS.count("vcf.native_read_spans")
-            lease, buf, base_len = leased
-        try:
             skip_first = False
             if span.start > 0 and base_len:
                 prev = _prev_block_last_byte(src, span.start)
                 skip_first = prev is not None and prev != 0x0A
             lo, hi = _owned_text(buf, base_len, skip_first)
-            yield memoryview(buf)[lo:hi]
+            yield memoryview(buf)[lo:hi], -1
+            return
+        METRICS.count("vcf.native_read_spans")
+        lease, text, records = leased
+        try:
+            yield text, records
         finally:
             lease.release()
 
@@ -210,68 +223,102 @@ def _inflate_text_python(src, span: FileByteSpan) -> Tuple[bytes, int]:
     return b"".join(chunks), len(base)
 
 
+# room leased behind a span's own text for the blocks that finish its last
+# line: four blocks, where a line of a 3,202-sample GATK call set is ~83 KB
+_TAIL_ROOM = 4 * bgzf.MAX_BLOCK_SIZE
+
+
 def _lease_bgzf_text(src, span: FileByteSpan
                      ) -> Optional[Tuple[SpanBuffer, memoryview, int]]:
-    """A BGZF text span read the way the BCF read reads one: ONE
-    positioned read of the span's compressed range and the block after it
-    (``ops.inflate.fetch_span_raw``: where the span's last line nearly
-    always ends), the native header walk, ONE native inflate of all those
-    blocks into a buffer leased from the span-buffer pool, the
-    interpreter lock released.  One native thread: the pool's threads are
-    the parallelism.  ISIZE is verified and CRC32 is not, as
-    ``bgzf.inflate_block(check_crc=False)`` does.  A last line that runs
-    past the block after the span (a line longer than a block) is
-    finished block by block.
+    """A BGZF text span read by native calls with the interpreter lock
+    released (``utils/native.py::vcf_text_span_read``): ONE positioned
+    read of the span's compressed range with a block's worth before it and
+    after it, then ``hbam_vcf_text_span_read`` twice — the header walk
+    that sizes the text, then the inflate of the span's blocks into a
+    buffer leased from the span-buffer pool, the probe of the block
+    before, the blocks after inflated until the last line ends, the trim
+    to the lines the span owns and the count of its record lines.  ISIZE
+    is verified and CRC32 is not, as ``bgzf.inflate_block(check_crc=
+    False)`` does.  A last line that runs past what that read holds or
+    the lease's room takes is finished block by block in Python and its
+    records are not counted (``vcf.text_span_tail_spans``).
 
-    Returns (lease, text of the span's blocks + the tail, the length of
-    the span's own text); the caller releases the lease.  Returns None —
-    nothing leased, the Python path decides and raises its own
-    ``BGZFError`` — without the native library, for an empty span and
-    when the block chain does not parse or inflate."""
+    Returns (lease, the span's own lines, their record lines or -1); the
+    caller releases the lease.  Returns None — nothing leased, the Python
+    path decides and raises its own ``BGZFError`` — without the native
+    library, for an empty span and when the block chain does not parse or
+    inflate."""
     end = min(span.end, src.size)
     if not native.available() or span.start >= end:
         return None
+    r0 = max(0, span.start - bgzf.MAX_BLOCK_SIZE)
     lease = raw_lease = NO_LEASE
     try:
-        # end_u = 1: the read takes the block AT ``end`` along
-        raw, end_block_size, next_c, raw_lease = inflate_ops.fetch_span_raw(
-            src, FileVirtualSpan(span.path, span.start << 16,
-                                 (end << 16) | 1))
-        if not raw:
+        with METRICS.span("bam.fetch_wall", nbytes=end - span.start):
+            raw, raw_lease = _pread_leased(
+                src, r0, min(src.size, end + bgzf.MAX_BLOCK_SIZE) - r0)
+        args = (raw, span.start - r0, end - span.start, src.size - r0)
+        rc, info = native.vcf_text_span_read(*args, None)
+        if rc == 1:
+            lease = SPAN_BUFFERS.lease(info[0] + _TAIL_ROOM)
+            rc, info = native.vcf_text_span_read(*args, lease.array)
+        if rc < 0:
+            lease.release()
             return None
-        table = inflate_ops.block_table(raw)
-        isize = table["isize"]
-        total = int(isize.sum())
-        base_len = total - (int(isize[-1]) if end_block_size else 0)
-        lease = SPAN_BUFFERS.lease(total)
-        inflate_ops.inflate_span(raw, table, backend="native", n_threads=1,
-                                 out=lease.array)
-    except BaseException as e:
-        lease.release()
-        if isinstance(e, bgzf.BGZFError):
-            return None
-        raise
-    finally:
-        raw_lease.release()     # nothing below reads the compressed bytes
-    try:
-        view = memoryview(lease.array)
-        if base_len and view[base_len - 1] != 0x0A \
-                and _find_newline(view, base_len, total) < 0:
-            for ext in _tail_blocks(src, next_c):
-                if total + len(ext) > lease.array.size:
-                    bigger = SPAN_BUFFERS.lease(2 * (total + len(ext)))
-                    bigger.array[:total] = lease.array[:total]
-                    lease.release()
-                    lease = bigger
-                lease.array[total:total + len(ext)] = \
-                    np.frombuffer(ext, np.uint8)
-                total += len(ext)
-                if b"\n" in ext:
-                    break
-        return lease, memoryview(lease.array)[:total], base_len
     except BaseException:
         lease.release()
         raise
+    finally:
+        raw_lease.release()     # nothing below reads the compressed bytes
+    total, _base_len, lo, hi, records, resume = info
+    if rc == 2:
+        METRICS.count("vcf.text_span_tail_spans")
+        lease, hi = _read_tail(src, lease, total, r0 + resume)
+        records = -1
+    return lease, memoryview(lease.array)[lo:hi], records
+
+
+def _pread_leased(src, offset: int, n: int) -> Tuple[np.ndarray, SpanBuffer]:
+    """``src``'s bytes [offset, offset + n) clipped to the file: read into
+    a buffer leased from the span-buffer pool where the source can fill
+    one in place, else ``pread``.  Returns (bytes, lease)."""
+    pread_into = getattr(src, "pread_into", None)
+    if pread_into is None:
+        return np.frombuffer(src.pread(offset, n), np.uint8), NO_LEASE
+    lease = SPAN_BUFFERS.lease(n)
+    try:
+        got = pread_into(offset, memoryview(lease.array)[:n])
+    except BaseException:
+        lease.release()
+        raise
+    return lease.array[:got], lease
+
+
+def _read_tail(src, lease: SpanBuffer, total: int, coffset: int
+               ) -> Tuple[SpanBuffer, int]:
+    """A span's last line that runs past the block after it, finished
+    block by block from ``coffset`` into ``lease`` after its ``total``
+    bytes (a bigger lease where they do not fit).  Returns (the lease,
+    the end of the line: after its newline, or the text's end); releases
+    the lease where it raises."""
+    start = total
+    try:
+        for ext in _tail_blocks(src, coffset):
+            if total + len(ext) > lease.array.size:
+                bigger = SPAN_BUFFERS.lease(2 * (total + len(ext)))
+                bigger.array[:total] = lease.array[:total]
+                lease.release()
+                lease = bigger
+            lease.array[total:total + len(ext)] = np.frombuffer(ext,
+                                                                np.uint8)
+            total += len(ext)
+            if b"\n" in ext:
+                break
+    except BaseException:
+        lease.release()
+        raise
+    nl = _find_newline(memoryview(lease.array), start, total)
+    return lease, total if nl < 0 else nl + 1
 
 
 # ---------------------------------------------------------------------------

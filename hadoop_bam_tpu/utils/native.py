@@ -22,7 +22,7 @@ import os
 import platform
 import subprocess
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -248,6 +248,18 @@ def load() -> Optional[ctypes.CDLL]:
         lib.hbam_vcf_tokenize.argtypes = [
             i8p, ctypes.c_int64, ctypes.c_int64, i64p, i32p, i8p, i8sp,
             ctypes.c_int64, ctypes.c_int64, i64p]
+        lib.hbam_contig_table.restype = ctypes.c_int64
+        lib.hbam_contig_table.argtypes = [
+            i8p, i64p, ctypes.c_int64, i32p, ctypes.c_int64]
+        lib.hbam_vcf_span_columns.restype = ctypes.c_int64
+        lib.hbam_vcf_span_columns.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, i8p, i64p, i32p,
+            ctypes.c_int64, i32p, i32p, i8p, i8sp, ctypes.c_int64, i64p,
+            ctypes.c_int64, i64p]
+        lib.hbam_vcf_text_span_read.restype = ctypes.c_int64
+        lib.hbam_vcf_text_span_read.argtypes = [
+            i8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, i8p, ctypes.c_int64, i64p]
         lib.hbam_cram_slice_rebuild.restype = ctypes.c_int64
         lib.hbam_cram_slice_rebuild.argtypes = [
             ctypes.c_int64, i32p, i32p, i32p, i64p, i32p, i32p,
@@ -894,6 +906,99 @@ def vcf_tokenize(text, n_sample: int, samples_pad: int) -> tuple:
         raise ValueError(f"vcf_tokenize counted {cap} records and wrote {n}")
     return bounds, ntab, bulk.view(bool), dosage, int(counts[0]), \
         int(counts[1])
+
+
+class ContigTable(NamedTuple):
+    """A header's contigs as ``hbam_vcf_span_columns`` looks CHROM up:
+    the names' bytes end to end, where each starts (and the end), and the
+    hash slots of ``hbam_contig_table``.  Built once a scan."""
+    names: np.ndarray
+    offsets: np.ndarray
+    slots: np.ndarray
+
+
+def contig_table(contigs: Sequence[str]) -> ContigTable:
+    """The ``{name.encode(): index}`` map of ``contigs`` as a
+    ``ContigTable`` (one native call): the later of two equal names wins,
+    as it does in the dict."""
+    lib = load()
+    assert lib is not None
+    enc = [c.encode() for c in contigs]
+    offsets = np.zeros(len(enc) + 1, np.int64)
+    np.cumsum([len(e) for e in enc], out=offsets[1:])
+    names = np.frombuffer(b"".join(enc) + b"\0", np.uint8)
+    slots = np.empty(1 << (2 * len(enc)).bit_length(), np.int32)
+    rc = int(lib.hbam_contig_table(
+        _ptr(names, ctypes.c_uint8), _ptr(offsets, ctypes.c_int64),
+        len(enc), _ptr(slots, ctypes.c_int32), int(slots.size)))
+    if rc:
+        raise ValueError(f"contig_table refused its arguments ({rc})")
+    return ContigTable(names, offsets, slots)
+
+
+def vcf_text_span_read(raw, at: int, want: int, file_end: int,
+                       out: Optional[np.ndarray]
+                       ) -> "tuple[int, tuple[int, ...]]":
+    """``hbam_vcf_text_span_read`` on the compressed bytes ``raw``, the
+    interpreter lock released: (its return code, its six-entry ``info``
+    as ints — total, base_len, lo, hi, records, resume).  ``out`` is the
+    buffer the span's text is inflated into, or None to ask the size
+    first."""
+    lib = load()
+    assert lib is not None
+    buf = _src_u8(raw)
+    info = np.zeros(6, dtype=np.int64)
+    rc = int(lib.hbam_vcf_text_span_read(
+        _ptr(buf, ctypes.c_uint8), int(buf.size), int(at), int(want),
+        int(file_end), None if out is None else _ptr(out, ctypes.c_uint8),
+        0 if out is None else int(out.size), _ptr(info, ctypes.c_int64)))
+    return rc, tuple(info.tolist())
+
+
+def vcf_span_columns(text, records: int, n_sample: int, samples_pad: int,
+                     contigs: ContigTable) -> tuple:
+    """A VCF text span's stats columns in one native pass, the
+    interpreter lock released: (cols — chrom [n] i32, pos [n] i32, flags
+    [n] u8, dosage [n, samples_pad] i8 —, refused [k, 3] i64 of (row,
+    line start, line end), keyed, nocall).  ``records`` is the count of
+    record lines the read found, or negative to have one more native
+    call count them.  A refused row's columns hold nothing: the caller's
+    scalar parse reads its line.  ``keyed`` and ``nocall`` count the keyed
+    lines not refused and their no-call cells."""
+    lib = load()
+    assert lib is not None
+    buf = _src_u8(text)
+    if buf.dtype != np.uint8 or buf.ndim != 1 or not buf.flags.c_contiguous:
+        raise ValueError("vcf_span_columns wants contiguous u8 text")
+    p_text = _ptr(buf, ctypes.c_uint8)
+    table = (_ptr(contigs.names, ctypes.c_uint8),
+             _ptr(contigs.offsets, ctypes.c_int64),
+             _ptr(contigs.slots, ctypes.c_int32), int(contigs.slots.size))
+    if records < 0:
+        records = int(lib.hbam_vcf_span_columns(
+            p_text, int(buf.size), int(n_sample), *table, None, None, None,
+            None, int(samples_pad), None, 0, None))
+        if records < 0:
+            raise ValueError(
+                f"vcf_span_columns refused its arguments ({records})")
+    cols = {"chrom": np.empty(records, np.int32),
+            "pos": np.empty(records, np.int32),
+            "flags": np.empty(records, np.uint8),
+            "dosage": np.empty((records, samples_pad), np.int8)}
+    refused = np.empty((records, 3), np.int64)
+    counts = np.zeros(3, dtype=np.int64)
+    n = int(lib.hbam_vcf_span_columns(
+        p_text, int(buf.size), int(n_sample), *table,
+        _ptr(cols["chrom"], ctypes.c_int32),
+        _ptr(cols["pos"], ctypes.c_int32),
+        _ptr(cols["flags"], ctypes.c_uint8),
+        _ptr(cols["dosage"], ctypes.c_int8), int(samples_pad),
+        _ptr(refused, ctypes.c_int64), records,
+        _ptr(counts, ctypes.c_int64)))
+    if n != records:
+        raise ValueError(f"vcf_span_columns was told {records} records "
+                         f"and wrote {n}")
+    return cols, refused[:int(counts[2])], int(counts[0]), int(counts[1])
 
 
 def grm_finish(acc: np.ndarray, r: np.ndarray, c: float, n_grm: int,

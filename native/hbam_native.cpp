@@ -33,6 +33,50 @@
 #include <libdeflate.h>
 #endif
 
+namespace {
+
+// One BGZF block header at src[p], inside src[0, n): the rules of
+// formats/bgzf.py parse_block_header — gzip magic with FEXTRA, every FEXTRA
+// subfield walked for the BC subfield, BSIZE covering header + footer, the
+// whole block inside the buffer, ISIZE <= 64 KiB.  Returns the block's size
+// and fills where its DEFLATE payload lies and its ISIZE, or -1 for a header
+// it does not accept.
+inline int64_t bgzf_header(const uint8_t* src, int64_t n, int64_t p,
+                           int64_t* cdata_off, int32_t* cdata_len,
+                           uint32_t* isize) {
+  auto u16 = [&](int64_t q) {
+    return static_cast<int64_t>(src[q]) | (static_cast<int64_t>(src[q + 1]) << 8);
+  };
+  if (n - p < 18) return -1;                           // truncated header
+  if (src[p] != 0x1f || src[p + 1] != 0x8b || src[p + 2] != 0x08 ||
+      src[p + 3] != 0x04) return -1;                   // bad magic / flags
+  const int64_t xtra_end = p + 12 + u16(p + 10);
+  if (n < xtra_end) return -1;                         // truncated FEXTRA
+  int64_t bsize = -1;
+  for (int64_t q = p + 12; q + 4 <= xtra_end; q += 4 + u16(q + 2)) {
+    if (src[q] == 66 && src[q + 1] == 67 && u16(q + 2) == 2) {
+      if (q + 6 <= n) bsize = u16(q + 4);
+      break;
+    }
+  }
+  if (bsize < 0) return -1;                            // no BC subfield
+  const int64_t block_size = bsize + 1;
+  if (block_size < xtra_end - p + 8) return -1;        // BSIZE too small
+  if (n - p < block_size) return -1;                   // truncated body
+  const int64_t end = p + block_size;
+  const uint32_t isz = static_cast<uint32_t>(src[end - 4]) |
+                       (static_cast<uint32_t>(src[end - 3]) << 8) |
+                       (static_cast<uint32_t>(src[end - 2]) << 16) |
+                       (static_cast<uint32_t>(src[end - 1]) << 24);
+  if (isz > 0x10000u) return -1;                       // ISIZE > 64 KiB
+  *cdata_off = xtra_end;
+  *cdata_len = static_cast<int32_t>(block_size - (xtra_end - p) - 8);
+  *isize = isz;
+  return block_size;
+}
+
+}  // namespace
+
 extern "C" {
 
 // Inflate n_blocks independent raw-DEFLATE streams concurrently.
@@ -217,9 +261,7 @@ int64_t hbam_walk_bam_payload(const uint8_t* buf, int64_t n, int64_t start,
 // Walk the chain of BGZF block headers in src[offset, n): the columnar
 // block table of a compressed span in one call (ops/inflate.py
 // block_table).  Accepts exactly the headers formats/bgzf.py
-// parse_block_header accepts — gzip magic with FEXTRA, every FEXTRA
-// subfield walked for the BC subfield, BSIZE covering header + footer, the
-// whole block inside the buffer, ISIZE <= 64 KiB — and writes one row per
+// parse_block_header accepts (``bgzf_header``) and writes one row per
 // block.  Stops at the first header it does not accept, or when ``cap`` rows
 // are written; *stop receives that header's offset (n after a clean walk).
 // It names no fault: the caller re-parses from *stop in Python, which
@@ -228,40 +270,20 @@ int64_t hbam_block_table(const uint8_t* src, int64_t n, int64_t offset,
                          int64_t* coffset, int64_t* cdata_off,
                          int32_t* cdata_len, int32_t* isize, int64_t cap,
                          int64_t* stop) {
-  auto u16 = [&](int64_t p) {
-    return static_cast<int64_t>(src[p]) | (static_cast<int64_t>(src[p + 1]) << 8);
-  };
   int64_t count = 0;
   int64_t p = offset;
   while (p < n && count < cap) {
-    if (n - p < 18) break;                             // truncated header
-    if (src[p] != 0x1f || src[p + 1] != 0x8b || src[p + 2] != 0x08 ||
-        src[p + 3] != 0x04) break;                     // bad magic / flags
-    const int64_t xtra_end = p + 12 + u16(p + 10);
-    if (n < xtra_end) break;                           // truncated FEXTRA
-    int64_t bsize = -1;
-    for (int64_t q = p + 12; q + 4 <= xtra_end; q += 4 + u16(q + 2)) {
-      if (src[q] == 66 && src[q + 1] == 67 && u16(q + 2) == 2) {
-        if (q + 6 <= n) bsize = u16(q + 4);
-        break;
-      }
-    }
-    if (bsize < 0) break;                              // no BC subfield
-    const int64_t block_size = bsize + 1;
-    if (block_size < xtra_end - p + 8) break;          // BSIZE too small
-    if (n - p < block_size) break;                     // truncated body
-    const int64_t end = p + block_size;
-    const uint32_t isz = static_cast<uint32_t>(src[end - 4]) |
-                         (static_cast<uint32_t>(src[end - 3]) << 8) |
-                         (static_cast<uint32_t>(src[end - 2]) << 16) |
-                         (static_cast<uint32_t>(src[end - 1]) << 24);
-    if (isz > 0x10000u) break;                         // ISIZE > 64 KiB
+    int64_t off;
+    int32_t len;
+    uint32_t isz;
+    const int64_t block_size = bgzf_header(src, n, p, &off, &len, &isz);
+    if (block_size < 0) break;
     coffset[count] = p;
-    cdata_off[count] = xtra_end;
-    cdata_len[count] = static_cast<int32_t>(block_size - (xtra_end - p) - 8);
+    cdata_off[count] = off;
+    cdata_len[count] = len;
     isize[count] = static_cast<int32_t>(isz);
     ++count;
-    p = end;
+    p += block_size;
   }
   if (stop) *stop = p;
   return count;
@@ -1021,6 +1043,147 @@ int64_t vcf_keyed_walk(const uint8_t* g, const uint8_t* end, int64_t n_sample,
   return cells == n_sample ? nocall : -1;
 }
 
+// One line of text[0, n) from s: its first nine tabs into t (*nt of them) and
+// its end *e (the '\n', or n).  Returns where the next line starts.
+inline int64_t vcf_line(const uint8_t* text, int64_t n, int64_t s, int64_t* t,
+                        int32_t* nt, int64_t* e) {
+  // the first nine tabs, then the line end from the last of them on: a '\n'
+  // met first ends the line and the hunt
+  int32_t k = 0;
+  int64_t at = s, end = -1;
+  while (k < 9) {
+    const uint8_t* p = text + at;
+    const uint8_t* stop = text + n;
+    while (p < stop && *p != '\t' && *p != '\n') ++p;     // ~150 bytes a line
+    if (p == stop) { end = n; break; }
+    if (*p == '\n') { end = p - text; break; }
+    t[k++] = p - text;
+    at = p - text + 1;
+  }
+  if (end < 0) {
+    const void* nl = std::memchr(text + at, '\n', static_cast<size_t>(n - at));
+    end = nl ? static_cast<const uint8_t*>(nl) - text : n;
+  }
+  *nt = k;
+  *e = end;
+  return end + 1;
+}
+
+// A line [s, e) with nt tabs is a record: not empty, not '#', >= 8 fields.
+inline bool vcf_record(const uint8_t* text, int64_t s, int64_t e, int32_t nt) {
+  return e != s && text[s] != '#' && nt >= 7;
+}
+
+// The record lines of text[0, n).
+inline int64_t vcf_count_records(const uint8_t* text, int64_t n) {
+  int64_t rows = 0;
+  for (int64_t pos = 0; pos < n;) {
+    const int64_t s = pos;
+    int64_t t[9], e;
+    int32_t nt;
+    pos = vcf_line(text, n, s, t, &nt, &e);
+    rows += vcf_record(text, s, e, nt);
+  }
+  return rows;
+}
+
+// The dosage row ``out`` [stride] of a record line (tabs t, nt of them, end
+// e), as hbam_vcf_tokenize states it.  Returns 1 where the row is final, 0
+// where the caller's scalar parse has to read the line (``out`` then holds
+// nothing it may read); *nocall = the no-call cells of a keyed line whose row
+// is final, -1 for any other line.
+inline uint8_t vcf_dosage_row(const uint8_t* text, const int64_t* t,
+                              int32_t nt, int64_t e, int64_t n_sample,
+                              int8_t* out, int64_t stride, int64_t* nocall) {
+  *nocall = -1;
+  // FORMAT is field 8: [t[7] + 1, t[8]); sample fields need the ninth tab
+  const bool has_gt = n_sample > 0 && nt == 9 && t[8] - t[7] - 1 >= 2 &&
+                      text[t[7] + 1] == 'G' && text[t[7] + 2] == 'T';
+  if (!has_gt) {
+    std::memset(out, 0xFF, static_cast<size_t>(stride));
+    return 1;
+  }
+  if (t[8] - t[7] - 1 > 2 && text[t[7] + 3] == ':') {
+    const int64_t nc = vcf_keyed_walk(text + t[8] + 1, text + e, n_sample, out);
+    if (nc < 0) return 0;
+    std::memset(out + n_sample, 0xFF, static_cast<size_t>(stride - n_sample));
+    *nocall = nc;
+    return 1;
+  }
+  if (t[8] - t[7] - 1 != 2 || e - t[8] - 1 != 4 * n_sample - 1) return 0;
+  const uint8_t* g = text + t[8] + 1;
+  uint32_t bad = 0;
+  for (int64_t i = 0; i + 1 < n_sample; ++i) {     // it vectorises
+    uint32_t v;
+    std::memcpy(&v, g + 4 * i, 4);
+    const uint32_t c0 = v & 0xFF, c1 = (v >> 8) & 0xFF,
+                   c2 = (v >> 16) & 0xFF, c3 = v >> 24;
+    bad |= static_cast<uint32_t>(c0 - '0' > 9u) |
+           static_cast<uint32_t>(c2 - '0' > 9u) |
+           static_cast<uint32_t>((c1 != '/') & (c1 != '|')) |
+           static_cast<uint32_t>(c3 != '\t');
+    out[i] = static_cast<int8_t>((c0 > '0') + (c2 > '0'));
+  }
+  const uint8_t* last = g + 4 * (n_sample - 1);
+  const uint32_t c0 = last[0], c1 = last[1], c2 = last[2];
+  bad |= static_cast<uint32_t>(c0 - '0' > 9u) |
+         static_cast<uint32_t>(c2 - '0' > 9u) |
+         static_cast<uint32_t>((c1 != '/') & (c1 != '|'));
+  out[n_sample - 1] = static_cast<int8_t>((c0 > '0') + (c2 > '0'));
+  std::memset(out + n_sample, 0xFF, static_cast<size_t>(stride - n_sample));
+  return !bad;
+}
+
+// The contig table (hbam_contig_table): FNV-1a over a name's bytes, linear
+// probing over a power-of-two slot array of contig indices, -1 empty.
+inline uint64_t contig_hash(const uint8_t* p, int64_t len) {
+  uint64_t h = 0xcbf29ce484222325ull;
+  for (int64_t i = 0; i < len; ++i) h = (h ^ p[i]) * 0x100000001b3ull;
+  return h;
+}
+
+inline int64_t contig_slot(const uint8_t* names, const int64_t* off,
+                           const int32_t* slots, int64_t mask,
+                           const uint8_t* p, int64_t len) {
+  for (int64_t i = static_cast<int64_t>(contig_hash(p, len)) & mask;;
+       i = (i + 1) & mask) {
+    const int32_t c = slots[i];
+    if (c < 0 || (off[c + 1] - off[c] == len &&
+                  std::memcmp(names + off[c], p, static_cast<size_t>(len)) == 0))
+      return i;
+  }
+}
+
+// POS [p, q) by formats' decimal rule (parallel/variant_pipeline.py::
+// _fixed_field_columns): 1 to 10 digits whose value fits int32, else the
+// line is odd (the scalar parse reads it, or raises what it raises).
+inline bool vcf_pos(const uint8_t* p, const uint8_t* q, int32_t* pos) {
+  const int64_t len = q - p;
+  if (len <= 0 || len > 10) return false;
+  int64_t v = 0;
+  for (; p < q; ++p) {
+    const unsigned d = static_cast<unsigned>(*p) - '0';
+    if (d > 9) return false;
+    v = v * 10 + d;
+  }
+  if (v > std::numeric_limits<int32_t>::max()) return false;
+  *pos = static_cast<int32_t>(v);
+  return true;
+}
+
+// ALT [p, q) is single ACGTN bases joined by commas.
+inline bool vcf_snp_alt(const uint8_t* p, const uint8_t* q) {
+  const int64_t len = q - p;
+  if (len % 2 == 0) return false;
+  for (int64_t j = 0; j < len; ++j) {
+    const uint8_t c = p[j];
+    const bool ok = j % 2 ? c == ',' :
+        (c == 'A' || c == 'C' || c == 'G' || c == 'T' || c == 'N');
+    if (!ok) return false;
+  }
+  return true;
+}
+
 }  // namespace
 
 extern "C" {
@@ -1059,82 +1222,280 @@ int64_t hbam_vcf_tokenize(const uint8_t* text, int64_t n, int64_t n_sample,
   if (n < 0 || cap < 0 || n_sample < 0 || n_sample > stride ||
       n_sample > (int64_t{1} << 40))
     return -1;
-  const int64_t block = 4 * n_sample - 1;
+  if (!bounds) return vcf_count_records(text, n);
   int64_t rows = 0;
   for (int64_t pos = 0; pos < n;) {
     const int64_t s = pos;
-    // the first nine tabs, then the line end from the last of them on: a
-    // '\n' met first ends the line and the hunt
-    int64_t t[9];
-    int32_t nt = 0;
-    int64_t at = s, e = -1;
-    while (nt < 9) {
-      const uint8_t* p = text + at;
-      const uint8_t* stop = text + n;
-      while (p < stop && *p != '\t' && *p != '\n') ++p;   // ~150 bytes a line
-      if (p == stop) { e = n; break; }
-      if (*p == '\n') { e = p - text; break; }
-      t[nt++] = p - text;
-      at = p - text + 1;
-    }
-    if (e < 0) {
-      const void* nl = std::memchr(text + at, '\n', static_cast<size_t>(n - at));
-      e = nl ? static_cast<const uint8_t*>(nl) - text : n;
-    }
-    pos = e + 1;
-    if (e == s || text[s] == '#' || nt < 7) continue;
-    if (!bounds) { ++rows; continue; }
+    int64_t t[9], e;
+    int32_t nt;
+    pos = vcf_line(text, n, s, t, &nt, &e);
+    if (!vcf_record(text, s, e, nt)) continue;
     if (rows >= cap) return -2;
     int64_t* b = bounds + rows * 11;
     b[0] = s;
     for (int k = 0; k < 9; ++k) b[1 + k] = k < nt ? t[k] : e;
     b[10] = e;
     ntab[rows] = nt;
-    int8_t* out = dosage + rows * stride;
-    // FORMAT is field 8: [t[7] + 1, t[8]); sample fields need the ninth tab
-    const bool has_gt = n_sample > 0 && nt == 9 && t[8] - t[7] - 1 >= 2 &&
-                        text[t[7] + 1] == 'G' && text[t[7] + 2] == 'T';
-    uint8_t ok = 1;
-    if (!has_gt) {
-      std::memset(out, 0xFF, static_cast<size_t>(stride));
-    } else if (t[8] - t[7] - 1 > 2 && text[t[7] + 3] == ':') {
-      const int64_t nocall =
-          vcf_keyed_walk(text + t[8] + 1, text + e, n_sample, out);
-      ok = nocall >= 0;
-      if (ok) {
-        std::memset(out + n_sample, 0xFF,
-                    static_cast<size_t>(stride - n_sample));
-        if (counts) { counts[0] += 1; counts[1] += nocall; }
-      }
-    } else if (t[8] - t[7] - 1 != 2 || e - t[8] - 1 != block) {
-      ok = 0;
-    } else {
-      const uint8_t* g = text + t[8] + 1;
-      uint32_t bad = 0;
-      for (int64_t i = 0; i + 1 < n_sample; ++i) {   // it vectorises
-        uint32_t v;
-        std::memcpy(&v, g + 4 * i, 4);
-        const uint32_t c0 = v & 0xFF, c1 = (v >> 8) & 0xFF,
-                       c2 = (v >> 16) & 0xFF, c3 = v >> 24;
-        bad |= static_cast<uint32_t>(c0 - '0' > 9u) |
-               static_cast<uint32_t>(c2 - '0' > 9u) |
-               static_cast<uint32_t>((c1 != '/') & (c1 != '|')) |
-               static_cast<uint32_t>(c3 != '\t');
-        out[i] = static_cast<int8_t>((c0 > '0') + (c2 > '0'));
-      }
-      const uint8_t* last = g + 4 * (n_sample - 1);
-      const uint32_t c0 = last[0], c1 = last[1], c2 = last[2];
-      bad |= static_cast<uint32_t>(c0 - '0' > 9u) |
-             static_cast<uint32_t>(c2 - '0' > 9u) |
-             static_cast<uint32_t>((c1 != '/') & (c1 != '|'));
-      out[n_sample - 1] = static_cast<int8_t>((c0 > '0') + (c2 > '0'));
-      std::memset(out + n_sample, 0xFF, static_cast<size_t>(stride - n_sample));
-      ok = !bad;
-    }
-    bulk[rows] = ok;
+    int64_t nocall;
+    bulk[rows] = vcf_dosage_row(text, t, nt, e, n_sample,
+                                dosage + rows * stride, stride, &nocall);
+    if (nocall >= 0 && counts) { counts[0] += 1; counts[1] += nocall; }
     ++rows;
   }
   return rows;
+}
+
+// The contig table of a header, built once (utils/native.py::contig_table):
+// contig c is names[off[c], off[c + 1]); slots [n_slots], a power of two
+// over n, gets the index of each distinct name — the later of two equal
+// names, as a ``{name: index}`` dict has it — and -1 elsewhere.  Returns 0,
+// or -1 for arguments it cannot take.
+int64_t hbam_contig_table(const uint8_t* names, const int64_t* off,
+                          int64_t n, int32_t* slots, int64_t n_slots) {
+  if (n < 0 || n_slots <= n || (n_slots & (n_slots - 1)) ||
+      n > std::numeric_limits<int32_t>::max())
+    return -1;
+  std::fill(slots, slots + n_slots, -1);
+  for (int64_t c = 0; c < n; ++c)
+    slots[contig_slot(names, off, slots, n_slots - 1, names + off[c],
+                      off[c + 1] - off[c])] = static_cast<int32_t>(c);
+  return 0;
+}
+
+// A span's text -> the stats columns of parallel/variant_pipeline.py::
+// pack_variant_tiles_from_text in one pass over the lines, the walk and the
+// dosage rows of hbam_vcf_tokenize with the fixed fields beside them.  Row i
+// of a record line gets
+//   chrom [cap] i32 : CHROM's index in the contig table (names, off, slots;
+//                     hbam_contig_table), -1 for a name it does not hold;
+//   pos   [cap] i32 : POS, 1 to 10 digits that fit int32;
+//   flags [cap] u8  : bit 0 FILTER exactly ``PASS``; bit 1 REF one base and
+//                     ALT single ``ACGTN`` bases joined by commas, at any
+//                     ALT width;
+//   dosage [cap, stride] i8 : hbam_vcf_tokenize's row.
+// A line whose POS is no such number, or whose row hbam_vcf_tokenize leaves
+// to the scalar parse, is refused: refused [cap, 3] gets (row, the line's
+// start, its end) and its columns hold nothing the caller may read.
+// counts [3] = the keyed lines not refused, their no-call cells, the refused
+// lines.  Returns the number of records, -1 for arguments it cannot take, -2
+// when ``cap`` rows do not hold them.  With ``chrom`` null it only counts the
+// records.  No threads: the callers' pool threads run it with the
+// interpreter lock released.
+int64_t hbam_vcf_span_columns(
+    const uint8_t* text, int64_t n, int64_t n_sample, const uint8_t* names,
+    const int64_t* off, const int32_t* slots, int64_t n_slots, int32_t* chrom,
+    int32_t* pos, uint8_t* flags, int8_t* dosage, int64_t stride,
+    int64_t* refused, int64_t cap, int64_t* counts) {
+  if (n < 0 || cap < 0 || n_sample < 0 || n_sample > stride ||
+      n_sample > (int64_t{1} << 40) || n_slots < 1 ||
+      (n_slots & (n_slots - 1)))
+    return -1;
+  if (!chrom) return vcf_count_records(text, n);
+  int64_t rows = 0, keyed = 0, nocalls = 0, n_refused = 0;
+  int64_t last_s = 0, last_len = -1;        // the CHROM of the line before
+  int32_t last_c = -1;
+  for (int64_t p = 0; p < n;) {
+    const int64_t s = p;
+    int64_t t[9], e;
+    int32_t nt;
+    p = vcf_line(text, n, s, t, &nt, &e);
+    if (!vcf_record(text, s, e, nt)) continue;
+    if (rows >= cap) return -2;
+    const int64_t clen = t[0] - s;
+    if (clen != last_len ||
+        std::memcmp(text + s, text + last_s, static_cast<size_t>(clen))) {
+      const int64_t slot =
+          contig_slot(names, off, slots, n_slots - 1, text + s, clen);
+      last_c = slots[slot];
+      last_s = s;
+      last_len = clen;
+    }
+    chrom[rows] = last_c;
+    bool ok = vcf_pos(text + t[0] + 1, text + t[1], pos + rows);
+    const bool pass = t[6] - t[5] - 1 == 4 &&
+                      std::memcmp(text + t[5] + 1, "PASS", 4) == 0;
+    const bool snp = t[3] - t[2] - 1 == 1 &&
+                     vcf_snp_alt(text + t[3] + 1, text + t[4]);
+    flags[rows] = static_cast<uint8_t>((pass ? 1 : 0) | (snp ? 2 : 0));
+    int64_t nocall;
+    ok &= vcf_dosage_row(text, t, nt, e, n_sample, dosage + rows * stride,
+                         stride, &nocall) != 0;
+    if (!ok) {
+      int64_t* r = refused + n_refused * 3;
+      r[0] = rows;
+      r[1] = s;
+      r[2] = e;
+      ++n_refused;
+    } else if (nocall >= 0) {
+      ++keyed;
+      nocalls += nocall;
+    }
+    ++rows;
+  }
+  counts[0] = keyed;
+  counts[1] = nocalls;
+  counts[2] = n_refused;
+  return rows;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// A BGZF text span from one positioned read to the lines it owns (split/
+// vcf_planners.py::_lease_bgzf_text; ``_inflate_text_python``,
+// ``_prev_block_last_byte`` and ``_owned_text`` are the statement of the
+// rules).  A line belongs to the span that holds its first byte.
+// ---------------------------------------------------------------------------
+namespace {
+
+// One block's payload inflated into out[0, isize); false where it does not.
+inline bool inflate_block_at(const uint8_t* raw, int64_t cdata_off,
+                             int32_t cdata_len, uint32_t isize, uint8_t* out) {
+  const int64_t dst_off = 0;
+  const int32_t want = static_cast<int32_t>(isize);
+  return hbam_inflate_batch(raw, &cdata_off, &cdata_len, 1, out, &dst_off,
+                            &want, 1) == 0;
+}
+
+// The last inflated byte of the block that ends exactly at raw[at], -1 where
+// there is none or it is empty: the candidates of formats/bgzf.py
+// find_block_starts_numpy in raw[0, at) (gzip magic, 18 header bytes before
+// ``at``, XLEN 6 with the BC subfield first or XLEN 7..255) in order, the
+// first whose header parses, whose block ends at ``at`` and inflates.
+inline int bgzf_prev_last_byte(const uint8_t* raw, int64_t n_raw,
+                               int64_t at) {
+  std::vector<uint8_t> block;
+  for (int64_t i = 0; i + 18 <= at; ++i) {
+    const void* hit = std::memchr(raw + i, 0x1f, static_cast<size_t>(at - 17 - i));
+    if (!hit) break;
+    i = static_cast<const uint8_t*>(hit) - raw;
+    if (raw[i + 1] != 0x8b || raw[i + 2] != 0x08 || raw[i + 3] != 0x04)
+      continue;
+    const int64_t xlen = raw[i + 10] | (int64_t{raw[i + 11]} << 8);
+    const bool standard = xlen == 6 && raw[i + 12] == 66 &&
+                          raw[i + 13] == 67 && raw[i + 14] == 2 &&
+                          raw[i + 15] == 0;
+    if (!standard && !(xlen > 6 && xlen < 256)) continue;
+    int64_t cdata_off;
+    int32_t cdata_len;
+    uint32_t isize;
+    const int64_t size =
+        bgzf_header(raw, n_raw, i, &cdata_off, &cdata_len, &isize);
+    if (size < 0 || i + size != at) continue;
+    block.resize(isize + 1);
+    if (!inflate_block_at(raw, cdata_off, cdata_len, isize, block.data()))
+      continue;
+    return isize ? block[isize - 1] : -1;
+  }
+  return -1;
+}
+
+}  // namespace
+
+extern "C" {
+
+// raw[0, n_raw) holds the file's bytes from some offset on: the span's blocks
+// start at raw[at] and follow each other while one starts before
+// raw[at + want]; the block that ends at raw[at] (where at > 0) lies in
+// raw[0, at); the file ends at raw[file_end].  The read
+//   1. walks the span's block headers (``bgzf_header``);
+//   2. inflates the span's blocks into out[0, base_len), one after another;
+//   3. where at > 0 and base_len > 0, finds the last byte of the block that
+//      ends at raw[at] (``bgzf_prev_last_byte``): a byte other than '\n'
+//      means the first line began before the span and is not its own;
+//   4. ends the span's text at its last line's end: at base_len where
+//      out[base_len - 1] is '\n', else after the first '\n' of the blocks
+//      after the span, inflated one by one behind base_len while they lie
+//      whole in raw and fit in ``out``, or at the file's end;
+//   5. counts the record lines (``vcf_record``) of the owned text.
+// info [6] = total (the inflated bytes in ``out``), base_len, lo, hi
+// (out[lo, hi) is the owned text), records, resume (where in raw the blocks
+// not read yet start).  Returns
+//    0  done;
+//    1  ``out`` is null or shorter than base_len: info[0, 1] only, nothing
+//       inflated — the caller leases base_len bytes and some room for the
+//       blocks after the span, and calls again;
+//    2  the span's last line runs on past what raw holds or ``out`` can
+//       take: out[0, total) holds the text read so far and info[2] lo; the
+//       caller reads on from ``resume`` block by block to find hi, and
+//       counts the records;
+//   -1  a block header of the span does not parse, -2 a block does not
+//       inflate: the caller's Python read raises what it raises.
+// No threads: the callers' pool threads run it with the interpreter lock
+// released.
+int64_t hbam_vcf_text_span_read(const uint8_t* raw, int64_t n_raw,
+                                int64_t at, int64_t want, int64_t file_end,
+                                uint8_t* out, int64_t out_cap,
+                                int64_t* info) {
+  if (n_raw < 0 || at < 0 || want < 0 || at + want > n_raw) return -1;
+  std::vector<int64_t> cdata_off, dst_off;
+  std::vector<int32_t> cdata_len, isize;
+  int64_t base_len = 0;
+  int64_t p = at;
+  while (p < at + want) {
+    int64_t off;
+    int32_t len;
+    uint32_t isz;
+    const int64_t size = bgzf_header(raw, n_raw, p, &off, &len, &isz);
+    if (size < 0) return -1;
+    cdata_off.push_back(off);
+    cdata_len.push_back(len);
+    isize.push_back(static_cast<int32_t>(isz));
+    dst_off.push_back(base_len);
+    base_len += isz;
+    p += size;
+  }
+  info[0] = info[1] = base_len;
+  info[2] = info[3] = info[4] = 0;
+  info[5] = p;
+  if (!out || out_cap < base_len) return 1;
+  if (!isize.empty() &&
+      hbam_inflate_batch(raw, cdata_off.data(), cdata_len.data(),
+                         static_cast<int32_t>(isize.size()), out,
+                         dst_off.data(), isize.data(), 1))
+    return -2;
+  if (base_len == 0) return 0;
+  int64_t lo = 0;
+  if (at > 0) {
+    const int prev = bgzf_prev_last_byte(raw, n_raw, at);
+    if (prev >= 0 && prev != '\n') {
+      const void* nl = std::memchr(out, '\n', static_cast<size_t>(base_len));
+      lo = nl ? static_cast<const uint8_t*>(nl) - out + 1 : base_len;
+      if (lo >= base_len) return 0;              // one line covers the span
+    }
+  }
+  info[2] = lo;
+  int64_t total = base_len, hi = base_len;
+  if (out[base_len - 1] != '\n') {
+    for (;;) {
+      if (p >= file_end) {                       // the file ends in the line
+        hi = total;
+        break;
+      }
+      int64_t off;
+      int32_t len;
+      uint32_t isz;
+      const int64_t size = bgzf_header(raw, n_raw, p, &off, &len, &isz);
+      if (size < 0 || out_cap - total < isz) {
+        info[0] = total;
+        info[5] = p;
+        return 2;
+      }
+      if (!inflate_block_at(raw, off, len, isz, out + total)) return -2;
+      const void* nl = std::memchr(out + total, '\n', isz);
+      total += isz;
+      p += size;
+      if (nl) {
+        hi = static_cast<const uint8_t*>(nl) - out + 1;
+        break;
+      }
+    }
+  }
+  info[0] = total;
+  info[3] = hi;
+  info[4] = vcf_count_records(out + lo, hi - lo);
+  info[5] = p;
+  return 0;
 }
 
 }  // extern "C"

@@ -23,7 +23,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # packed/payload walks, deflate, rANS 4x8 + Nx16, the BCF GT -> dosage
 # kernel, the BCF record walker (chase / span columns / guess), the CRAM
 # slice rebuild, the FASTQ tokenise + pack, the VCF text tokenise (its
-# GT-only loop and keyed walk), the DEFLATE block finder /
+# GT-only loop and keyed walk), the BGZF text span read and the text span
+# columns with their contig table, the DEFLATE block finder /
 # symbol decoder / resolve, the GWAS job's GRM finish).
 # Multi-threaded calls are explicit so ASan sees the pthread paths.  It then drives the two
 # Python-threaded planes TSan should watch end to end: the staging
@@ -429,6 +430,50 @@ for t in ts:
 for t in ts:
     t.join(60)
 assert len(v_ok) == 4
+
+# the BGZF text span read (hbam_vcf_text_span_read): spans of a file of
+# small blocks whose lines run over several, the compressed bytes cut short
+# after the span (a header or body of a following block missing), the room
+# behind the text from none to a block; the span columns (hbam_contig_table,
+# hbam_vcf_span_columns) over the tokenise's text cut at every byte of its
+# head; four threads at once
+from hadoop_bam_tpu.formats import bgzf
+tfile = b"".join(b"chr20\t%d\t.\tA\tG\t40\tPASS\t%s\n" % (i, b"X" * (i * 37 % 3000))
+                 for i in range(80))
+tblocks = [bgzf.deflate_block(tfile[lo:lo + 1000])
+           for lo in range(0, len(tfile), 1000)]
+traw = b"".join(tblocks) + bgzf.EOF_BLOCK
+tstarts = np.cumsum([0] + [len(b) for b in tblocks]).tolist()
+def tread():
+    for i in range(0, len(tblocks), 3):
+        for j in (i, i + 1, i + 4):
+            a, e = tstarts[i], tstarts[min(j, len(tblocks))]
+            r0 = max(0, a - 65536)
+            for cut in (len(traw), e + 30, e):
+                raw = np.frombuffer(traw[r0:cut], np.uint8).copy()
+                args = (raw, a - r0, e - a, len(traw) - r0)
+                rc, info = native.vcf_text_span_read(*args, None)
+                assert rc == 1, rc
+                for room in (0, 100, 1 << 16):
+                    out = np.empty(info[0] + room, np.uint8)
+                    rc, got = native.vcf_text_span_read(*args, out)
+                    assert rc in (0, 2) and got[2] <= got[0], (rc, got)
+vtable = native.contig_table(vhdr.contigs + ["chr%d" % i for i in range(40)])
+def tcols():
+    for cut in list(range(0, 400)) + [len(vtext)]:
+        own = np.frombuffer(vtext[:cut], np.uint8).copy()
+        native.vcf_span_columns(own, -1, vs, 16, vtable)
+t_ok = []
+def t_many():
+    tread()
+    tcols()
+    t_ok.append(1)
+ts = [threading.Thread(target=t_many) for _ in range(4)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(120)
+assert len(t_ok) == 4
 
 # DEFLATE inside a member (a gzip'd FASTQ's inflate workers): the block
 # finder over a buffer that ends anywhere (a header read past the end is
